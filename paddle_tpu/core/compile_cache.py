@@ -14,9 +14,11 @@ framework-level equivalent, keyed by our own ProgramDesc fingerprint:
   ``jax.experimental.serialize_executable`` into content-addressed
   entry files.  A warm process skips lowering-trace AND XLA compile;
   first step costs one deserialize (~ms).
-- **Tier B** — XLA-level reuse: ``jax_compilation_cache_dir`` is
-  pointed at ``<dir>/xla`` so paths tier A cannot serialize (platform
-  limitations) still skip the XLA compile on re-trace.
+- **Tier B** — XLA-level reuse: JAX's own persistent compilation
+  cache, which is ALWAYS on and whose directory is decided in exactly
+  one place (:func:`wire_jax_cache`): ``JAX_COMPILATION_CACHE_DIR``
+  when the environment sets it, else a fixed directory inside the
+  checkout.  It never lives under ``FLAGS_compile_cache_dir``.
 
 Store discipline is robustness-grade: entries are written atomically
 (unique tmp + ``os.replace``); loads of corrupted / truncated /
@@ -35,8 +37,8 @@ from a different environment are skipped with a counted
 ``version_skew`` — a jax upgrade invalidates the cache instead of
 crashing it.
 
-Everything is gated on ``FLAGS_compile_cache_dir``: unset (default)
-⇒ no disk I/O, no threads, byte-for-byte the previous behavior.
+Tier A is gated on ``FLAGS_compile_cache_dir``: unset (default) ⇒ no
+entry files, no threads.
 
 SECURITY: entry payloads deserialize through pickle (the transport
 ``jax.experimental.serialize_executable`` uses), so loading an entry
@@ -70,7 +72,8 @@ _metrics = None
 _lock = threading.Lock()
 _tmp_counter = 0
 _env_digest_cache: Optional[str] = None
-_jax_cache_wired = False
+_jax_hit_listener = False
+_jax_hits = 0
 
 
 def _cm():
@@ -148,40 +151,46 @@ def max_bytes() -> int:
         return 0
 
 
-def wire_jax_cache() -> bool:
-    """Tier B: point jax's own persistent compilation cache at
-    ``<dir>/xla`` so even executables tier A cannot serialize get
-    XLA-level reuse across processes.  One flag read when disabled;
-    idempotent; config names are probed so a jax without them degrades
-    to tier A only."""
-    global _jax_cache_wired
-    d = cache_dir()
-    if not d or _jax_cache_wired:
-        return _jax_cache_wired
-    try:
-        # we create the dir (0700 — entries are pickle on load, see the
-        # module docstring) BEFORE jax can, whose cache writes would
-        # otherwise create it with default permissions
-        os.makedirs(d, mode=0o700, exist_ok=True)
-    except OSError:
-        pass
+def default_jax_cache_dir() -> str:
+    """Where JAX's persistent cache lives when the environment does not
+    say: ``<checkout>/.jax_compile_cache`` (git-ignored), derived from
+    this package's location and from nothing else — the path is part of
+    the cache key's world, so a directory named after a pid, a time or
+    a temp name would never hit."""
+    pkg = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(pkg), ".jax_compile_cache")
+
+
+def wire_jax_cache() -> str:
+    """Tier B, and THE one place this program decides where JAX's
+    persistent compilation cache lives.  ``JAX_COMPILATION_CACHE_DIR``
+    set: JAX reads it itself and nothing here (or anywhere else) sets
+    another directory.  Unset: :func:`default_jax_cache_dir`.  Returns
+    the directory in use; idempotent."""
+    global _jax_hit_listener
     import jax
-    try:
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(d, "xla"))
-        _jax_cache_wired = True
-    except Exception:
-        return False
-    # cache every executable: the restart win is the point, and the
-    # LRU cap (not a compile-time floor) bounds the footprint.  These
-    # knobs are tuning only — a jax without them still has tier B on
-    for knob, val in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                      ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(knob, val)
-        except Exception:
-            pass
-    return _jax_cache_wired
+    if not _jax_hit_listener:
+        _jax_hit_listener = True
+        jax.monitoring.register_event_listener(_on_jax_event)
+    d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if d:
+        return d
+    d = default_jax_cache_dir()
+    if jax.config.jax_compilation_cache_dir != d:
+        jax.config.update("jax_compilation_cache_dir", d)
+    return d
+
+
+def _on_jax_event(event: str, **_kw) -> None:
+    global _jax_hits
+    if event == "/jax/compilation_cache/cache_hits":
+        _jax_hits += 1
+
+
+def jax_cache_hits() -> int:
+    """Compiles served by JAX's persistent cache so far in this process
+    (counted from the first :func:`wire_jax_cache`)."""
+    return _jax_hits
 
 
 # ---------------------------------------------------------------------------
@@ -358,8 +367,12 @@ def store(key: str, compiled, meta: Optional[dict] = None) -> Optional[str]:
         payload, in_tree, out_tree = _se.serialize(compiled)
         blob = pickle.dumps((payload, in_tree, out_tree),
                             protocol=pickle.HIGHEST_PROTOCOL)
+        # the devices the executable was compiled for, in assignment
+        # order: load() must hand exactly these back to jax (see there)
         hdr = {"format": FORMAT_VERSION, "key": key,
-               "created": time.time(), "payload_bytes": len(blob)}
+               "created": time.time(), "payload_bytes": len(blob),
+               "devices": [d.id for d in
+                           compiled.runtime_executable().local_devices()]}
         hdr.update(env_info())
         if meta:
             hdr["meta"] = meta
@@ -385,6 +398,21 @@ def _env_matches(hdr: dict) -> bool:
     info = env_info()
     return (int(hdr.get("format", -1)) == FORMAT_VERSION
             and all(hdr.get(k) == v for k, v in info.items()))
+
+
+def deserialize_entry(hdr: dict, blob: bytes):
+    """``jax.stages.Compiled`` from one entry's header + payload, loaded
+    onto the devices it was compiled for: left to its default, jax loads
+    an executable over EVERY device of the backend, and a one-device
+    executable in a multi-device process then dies at its first
+    dispatch ("expected ... to have N shards")."""
+    import jax
+    from jax.experimental import serialize_executable as _se
+    payload, in_tree, out_tree = pickle.loads(blob)
+    by_id = {d.id: d for d in jax.devices()}
+    return _se.deserialize_and_load(
+        payload, in_tree, out_tree,
+        execution_devices=[by_id[i] for i in hdr["devices"]])
 
 
 def load(key: str, count_miss: bool = True):
@@ -435,9 +463,7 @@ def load(key: str, count_miss: bool = True):
         return None
     try:
         t0 = time.perf_counter_ns()
-        payload, in_tree, out_tree = pickle.loads(blob)
-        from jax.experimental import serialize_executable as _se
-        compiled = _se.deserialize_and_load(payload, in_tree, out_tree)
+        compiled = deserialize_entry(hdr, blob)
         ms = (time.perf_counter_ns() - t0) / 1e6
     except Exception as e:
         # payload unpickles garbage / XLA refuses the executable: same
@@ -566,8 +592,7 @@ def _statusz() -> dict:
     d = cache_dir()
     if not d:
         return {"enabled": False}
-    out = {"enabled": True, "dir": d, "max_bytes": max_bytes(),
-           "jax_cache_wired": _jax_cache_wired}
+    out = {"enabled": True, "dir": d, "max_bytes": max_bytes()}
     out.update(store_stats(d))
     return out
 

@@ -7,12 +7,16 @@ Exposes BlockingQueue, RecordIOWriter/Scanner — the native data-path pieces
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 from typing import Optional
 
 _NATIVE_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "native")
 _SO = os.path.abspath(os.path.join(_NATIVE_DIR, "libpaddle_tpu_native.so"))
+# digest of the sources the .so was built from: staleness is decided by
+# content, because after a copy or a checkout mtimes order nothing
+_STAMP = _SO + ".src"
 
 _lib: Optional[ctypes.CDLL] = None
 
@@ -21,19 +25,25 @@ def load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    deps = [os.path.abspath(os.path.join(_NATIVE_DIR, f))
-            for f in ("paddle_tpu_native.cc", "Makefile")]
-    stale = (not os.path.exists(_SO)
-             or any(os.path.exists(d)
-                    and os.path.getmtime(d) > os.path.getmtime(_SO)
-                    for d in deps))
-    if stale:
+    digest = hashlib.sha256()
+    for f in ("paddle_tpu_native.cc", "Makefile"):
+        with open(os.path.join(_NATIVE_DIR, f), "rb") as src:
+            digest.update(src.read())
+    digest = digest.hexdigest()
+    try:
+        with open(_STAMP) as f:
+            built_from = f.read().strip()
+    except OSError:
+        built_from = None
+    if not os.path.exists(_SO) or built_from != digest:
         try:
             subprocess.run(["make", "-C", os.path.abspath(_NATIVE_DIR), "-B"],
                            check=True, capture_output=True, text=True)
         except subprocess.CalledProcessError as e:
             raise RuntimeError(
                 f"native lib build failed:\n{e.stdout}\n{e.stderr}") from e
+        with open(_STAMP, "w") as f:
+            f.write(digest)
     lib = ctypes.CDLL(_SO)
     # queue
     lib.ptq_queue_create.restype = ctypes.c_void_p

@@ -121,7 +121,7 @@ TRACE_CTX_FLAG = 0x80
 _CONNECT_TIMEOUT = 120.0
 
 # RPC latency buckets (ms): LAN round trips through multi-second
-# sync-barrier waits and tunneled DCN links
+# sync-barrier waits and slow DCN links
 _RPC_MS_BUCKETS = (0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
                    250.0, 500.0, 1000.0, 2500.0, 5000.0, 10000.0, 30000.0)
 

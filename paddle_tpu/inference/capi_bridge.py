@@ -61,16 +61,6 @@ def _np_dtype(name: str):
 
 
 def create(model_dir: str) -> int:
-    import os
-
-    if os.environ.get("PT_CAPI_JAX_PLATFORM"):
-        # the env-var JAX_PLATFORMS route is dead once a PJRT plugin has
-        # registered; honor an explicit platform request in-process (the
-        # C smoke test runs on the forced-CPU mesh this way)
-        import jax
-
-        jax.config.update("jax_platforms",
-                          os.environ["PT_CAPI_JAX_PLATFORM"])
     from .predictor import AnalysisConfig, create_predictor
 
     pred = create_predictor(AnalysisConfig(model_dir))

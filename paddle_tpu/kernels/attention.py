@@ -35,6 +35,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ..platform import pallas_interpret
 
 NEG_INF = -1e30
 LOG2E = 1.4426950408889634  # kernels run softmax in exp2 units (see below)
@@ -119,8 +123,7 @@ def _sds(shape, dtype, like):
     shard_map's varying-mesh-axes (vma) checking: outputs vary over the
     same mesh axes as the operand ``like`` (ring attention calls the
     kernels per shard inside shard_map)."""
-    typeof = getattr(jax, "typeof", None)   # absent before jax 0.6
-    vma = getattr(typeof(like), "vma", None) if typeof is not None else None
+    vma = getattr(jax.typeof(like), "vma", None)
     if vma:
         return jax.ShapeDtypeStruct(shape, dtype, vma=vma)
     return jax.ShapeDtypeStruct(shape, dtype)
@@ -300,13 +303,6 @@ def _flash_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
         lse_ref[0, pl.dslice(qi * block_q, block_q)] = lse[:, 0].astype(lse_ref.dtype)
 
 
-try:  # pallas import kept lazy-safe for exotic builds
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
-
 # NOTE(perf A/B, r3): CompilerParams(dimension_semantics=("parallel",
 # "parallel", "arbitrary")) measured ~20% SLOWER at T=8192 than the
 # default on this chip, as did per-tile lax.cond causal-mask branching —
@@ -396,7 +392,7 @@ def _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate=0.0,
     if sm_scale is None:
         sm_scale = float(1.0 / np.sqrt(q.shape[-1]))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     B, H, Tq, D = q.shape
     qf, kf, vf, maskf, Tq_p, Tk_p, has_mask = _prep_padded(
         q, k, v, kv_mask, block_q, block_k)
@@ -445,8 +441,6 @@ def mha_pallas(q, k, v, kv_mask=None, causal=False, sm_scale=None,
                block_q=None, block_k=None, interpret=None,
                dropout_rate=0.0, dropout_seed=None):
     """Flash-attention forward via pallas_call; grid (B*H, Tq/block_q)."""
-    if not _HAVE_PALLAS:
-        return mha_xla(q, k, v, kv_mask, causal, sm_scale)
     out, _ = _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate,
                          dropout_seed, block_q, block_k, interpret)
     return out
@@ -558,7 +552,7 @@ def _pallas_bwd(q, k, v, kv_mask, out, lse, g, causal, sm_scale,
     if sm_scale is None:
         sm_scale = float(1.0 / np.sqrt(q.shape[-1]))
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = pallas_interpret()
     B, H, Tq, D = q.shape
     qf, kf, vf, maskf, Tq_p, Tk_p, has_mask = _prep_padded(
         q, k, v, kv_mask, block_q, block_k)
@@ -654,18 +648,11 @@ def flash_attention(q, k, v, kv_mask, causal=False, sm_scale=None,
     """Flash attention with optional in-kernel attention-prob dropout.
     ``dropout_seed``: int32 scalar/array; required when dropout_rate > 0
     (vary it per training step for fresh masks)."""
-    if not _HAVE_PALLAS:
-        return mha_xla(q, k, v, kv_mask, causal, sm_scale,
-                       dropout_rate=dropout_rate, dropout_seed=dropout_seed)
     return mha_pallas(q, k, v, kv_mask, causal, sm_scale,
                       dropout_rate=dropout_rate, dropout_seed=dropout_seed)
 
 
 def _fa_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate, dropout_seed):
-    if not _HAVE_PALLAS:
-        out = mha_xla(q, k, v, kv_mask, causal, sm_scale,
-                      dropout_rate=dropout_rate, dropout_seed=dropout_seed)
-        return out, (q, k, v, kv_mask, dropout_seed, out, None)
     out, lse = _pallas_fwd(q, k, v, kv_mask, causal, sm_scale,
                            dropout_rate, dropout_seed)
     return out, (q, k, v, kv_mask, dropout_seed, out, lse)
@@ -673,14 +660,6 @@ def _fa_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate, dropout_seed):
 
 def _fa_bwd(causal, sm_scale, dropout_rate, res, g):
     q, k, v, kv_mask, dropout_seed, out, lse = res
-    if lse is None:  # no-pallas fallback: XLA recompute, same seed
-        def f(q, k, v):
-            return mha_xla(q, k, v, kv_mask, causal, sm_scale,
-                           dropout_rate=dropout_rate,
-                           dropout_seed=dropout_seed)
-        _, vjp_fn = jax.vjp(f, q, k, v)
-        dq, dk, dv = vjp_fn(g)
-        return dq, dk, dv, None, None
     dq, dk, dv = _pallas_bwd(q, k, v, kv_mask, out, lse, g, causal, sm_scale,
                              dropout_rate, dropout_seed)
     return dq, dk, dv, None, None
@@ -709,11 +688,8 @@ def _pair_seed(seed0, q_idx, kv_idx):
 def _pvary(x, axis_name):
     """Mark a freshly-created (replicated) array as varying over the ring
     axis so it can enter ppermute/scan carries under shard_map's vma
-    checking; identity where pvary is unavailable."""
-    try:
-        return jax.lax.pvary(x, axis_name)
-    except (AttributeError, TypeError):
-        return x
+    checking."""
+    return jax.lax.pcast(x, axis_name, to="varying")
 
 
 def _mass_lse(lse):
@@ -750,15 +726,8 @@ def _ring_pair_fwd(q, k_blk, v_blk, m_blk, causal, sm_scale, rate, seed):
 def ring_attention(q, k, v, kv_mask, axis_name: str, causal=False,
                    sm_scale=None, dropout_rate=0.0, dropout_seed=None):
     """Blockwise ring attention (called under shard_map with the sequence
-    dimension of q/k/v sharded over ``axis_name``).  Dispatches to the
-    flash-kernel ring (``_ring_flash``); builds without pallas fall back
-    to the pure-jnp blockwise ring (``_ring_xla``, differentiates
-    through shard_map/ppermute natively).
-
-    See ``_ring_flash`` for the kernel-path design."""
-    if not _HAVE_PALLAS:
-        return _ring_xla(q, k, v, kv_mask, axis_name, causal, sm_scale,
-                         dropout_rate, dropout_seed)
+    dimension of q/k/v sharded over ``axis_name``): the flash-kernel ring,
+    see ``_ring_flash`` for the design."""
     return _ring_flash(q, k, v, kv_mask, axis_name, causal, sm_scale,
                        dropout_rate, dropout_seed)
 
@@ -829,15 +798,6 @@ def _ring_fwd(q, k, v, kv_mask, axis_name, causal, sm_scale,
 
         if causal:
             o_p, lse_p = lax.cond(kv_i < idx, compute, skip, None)
-        elif jax.default_backend() != "tpu":
-            # interpret-mode pallas: a BARE pallas call inside this scan
-            # makes XLA's SPMD partitioner reject the module with
-            # "PartitionId instruction is not supported" (the causal
-            # branch never hits it because its call sits under lax.cond).
-            # Route through a cond with a traced always-true predicate so
-            # the off-TPU lowering matches the shape XLA accepts; TPU
-            # keeps the straight-line call.
-            o_p, lse_p = lax.cond(kv_i >= 0, compute, skip, None)
         else:
             o_p, lse_p = compute(None)
         o_a, lse_a = _merge_partial(o_a, lse_a, o_p, lse_p)
@@ -910,13 +870,6 @@ def _ring_vjp_bwd(axis_name, causal, sm_scale, dropout_rate, res, g):
 
         if causal:
             dq_p, dk_p, dv_p = lax.cond(kv_i < idx, compute, skip, None)
-        elif jax.default_backend() != "tpu":
-            # same routing as the forward scan (PR 6): a BARE pallas call
-            # inside this scan makes XLA's SPMD partitioner reject the
-            # off-TPU module with "PartitionId instruction is not
-            # supported"; a traced always-true cond lowers to the shape
-            # XLA accepts.  TPU keeps the straight-line call.
-            dq_p, dk_p, dv_p = lax.cond(kv_i >= 0, compute, skip, None)
         else:
             dq_p, dk_p, dv_p = compute(None)
         return (k_c, v_c, m_c, dk_a + dk_p, dv_a + dv_p, dq_a + dq_p), None
@@ -957,15 +910,21 @@ def _decode_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
     frontier are skipped (index maps clamp to the frontier block, so
     the pipeline issues no copies for them either).
 
-    ``quantized``: the cache blocks are int8 codes and two extra [1, H]
+    ``quantized``: the cache blocks are int8 codes and two extra [H, 1]
     scale refs follow the v ref (per-block-per-head abs-max from the
     parallel scale pool, same block-table index map) — the block is
     dequantized IN VMEM right after the copy lands (``code * s/127``),
     so HBM traffic per block is halved while scores still run in f32.
 
-    Scores run in f32 natural units (a decode step is dispatch-bound,
-    not VPU-bound — the flash kernel's exp2/ones-lane folds buy nothing
-    at one query row per slot and would cost clarity)."""
+    One query row per slot leaves the MXU nothing to do, so scores and
+    the PV sum are VPU multiply-reduces over the cache block in its
+    stored [bs, H, D] layout: a lane reduce over D for the scores, a
+    leading-dim reduce over the block's tokens for max / sum / PV.
+    (Mosaic refuses the batched ``dot_general`` over the MIDDLE axis the
+    first version used: "failed to parse TPU_DotDimensionNumbersAttr
+    parameter 'lhs_non_contracting_dims'", PERF.md Bring-up.)  Scores
+    run in f32 natural units (a decode step is dispatch-bound — the
+    flash kernel's exp2/ones-lane folds buy nothing here)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -987,26 +946,20 @@ def _decode_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
         k_blk = k_ref[0].astype(jnp.float32)               # [bs, H, D]
         v_blk = v_ref[0].astype(jnp.float32)
         if quantized:
-            k_blk = k_blk * (ks_ref[0][None, :, None] * _INV_QMAX)
-            v_blk = v_blk * (vs_ref[0][None, :, None] * _INV_QMAX)
-        # per-head scores over this block's tokens: [H, bs]
-        scores = jax.lax.dot_general(
-            q, k_blk, (((1,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
+            k_blk = k_blk * (ks_ref[0] * _INV_QMAX)[None]  # [1, H, 1]
+            v_blk = v_blk * (vs_ref[0] * _INV_QMAX)[None]
+        # per-token per-head scores: [bs, H, 1]
+        scores = jnp.sum(k_blk * q[None], axis=-1, keepdims=True)
         pos = j * block_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 1)
+            jnp.int32, scores.shape, 0)
         scores = jnp.where(pos < cl, scores, NEG_INF)
-        m, acc = m_scr[:], acc_scr[:]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        p = jnp.exp(scores - m_new)                         # [H, bs]
+        m = m_scr[:]                                       # [H, 1]
+        m_new = jnp.maximum(m, jnp.max(scores, axis=0))
+        p = jnp.exp(scores - m_new[None])                  # [bs, H, 1]
         alpha = jnp.exp(m - m_new)
         m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        # [H, bs] @ [bs, H, D] batched over H -> [H, D]
-        pv = jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        acc_scr[:] = acc * alpha + pv
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=0)
+        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(p * v_blk, axis=0)
 
     @pl.when(j == last)
     def _finish():
@@ -1066,7 +1019,7 @@ def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
 
     def scale_map(s, j, bt, cl):
         jc = jnp.minimum(j, jnp.maximum((cl[s] - 1) // bs, 0))
-        return (bt[s, jc], 0)
+        return (bt[s, jc], 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, H, D), lambda s, j, bt, cl: (s, 0, 0)),
@@ -1076,10 +1029,12 @@ def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
     operands = [bt, cl, q, k_cache, v_cache]
     if quantized:
         # per-block-per-head scale rows ride the same prefetched block
-        # table as the code blocks they dequantize
-        in_specs += [pl.BlockSpec((1, H), scale_map),
-                     pl.BlockSpec((1, H), scale_map)]
-        operands += [k_scale, v_scale]
+        # table as the code blocks they dequantize; as [N_blocks, H, 1]
+        # so the (1, H, 1) block's minor dims equal the array's (a
+        # (1, H) block of [N_blocks, H] breaks the TPU (8, 128) rule)
+        in_specs += [pl.BlockSpec((1, H, 1), scale_map),
+                     pl.BlockSpec((1, H, 1), scale_map)]
+        operands += [k_scale[..., None], v_scale[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -1102,17 +1057,6 @@ def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
     )(*operands)
 
 
-def _count_decode(name: str, n: int = 1) -> None:
-    from ..observability import stats as _obs_stats
-    _obs_stats.scope("decode").counter(name).inc(n)
-
-
-# trace-time latch: a build fault disables the kernel for the process
-# (counted ONCE per fault site, like kernels/sparse.py's per-stage
-# fallbacks — a kernel fault can never fail a decode step)
-_decode_attn_broken = False
-
-
 def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
                      sm_scale=None, interpret=None, impl=None,
                      k_scale=None, v_scale=None):
@@ -1129,98 +1073,24 @@ def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     abs-max pools when the cache stores int8 codes
     (``FLAGS_decode_kv_dtype=int8``); both paths dequantize with
     ``code * s/127`` — the kernel in VMEM after the block copy lands,
-    the XLA fallback after the gather.
+    the XLA path after the gather.
 
-    ``impl``: None (pallas with counted XLA fallback — the
-    kernels/sparse.py contract), "xla" (force the gather path),
-    "pallas" (no fallback; tests).  Off-TPU the kernel runs in Pallas
-    interpret mode like the flash kernels."""
-    global _decode_attn_broken
+    ``impl``: None / "pallas" (the kernel; interpret mode off-TPU like
+    the flash kernels) or "xla" (the gather path, also the parity
+    reference).  There is no fallback between them: a kernel the
+    compiler refuses fails the dispatch that contains it (Mosaic's
+    refusals arrive when jit lowers or compiles the step, long after
+    this function returned — a trace-time ``try`` here never saw one)."""
     if sm_scale is None:
         sm_scale = float(1.0 / np.sqrt(q.shape[-1]))
-    if impl == "pallas" and not _HAVE_PALLAS:
-        # the no-fallback contract must not pass vacuously on a build
-        # without pallas (a parity test would compare XLA to XLA)
-        raise RuntimeError(
-            "decode_attention(impl='pallas'): pallas is unavailable "
-            "in this build")
-    if impl == "xla" or not _HAVE_PALLAS or \
-            (impl is None and _decode_attn_broken):
+    if impl == "xla":
         return paged_attention_xla(q, k_cache, v_cache, block_tables,
                                    context_lens, sm_scale,
                                    k_scale=k_scale, v_scale=v_scale)
+    if impl not in (None, "pallas"):
+        raise ValueError(f"unknown decode attention impl {impl!r}")
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    try:
-        return _paged_attn_pallas(q, k_cache, v_cache, block_tables,
-                                  context_lens, sm_scale, interpret,
-                                  k_scale=k_scale, v_scale=v_scale)
-    except Exception:
-        if impl == "pallas":
-            raise
-        _decode_attn_broken = True
-        _count_decode("attn_fallbacks")
-        return paged_attention_xla(q, k_cache, v_cache, block_tables,
-                                   context_lens, sm_scale,
-                                   k_scale=k_scale, v_scale=v_scale)
-
-
-def _ring_xla(q, k, v, kv_mask, axis_name, causal=False, sm_scale=None,
-              dropout_rate=0.0, dropout_seed=None):
-    """Pure-jnp blockwise ring (no-pallas fallback): K/V rotate via
-    ppermute with online-softmax merging; per-pair scores materialize as
-    [B,H,S/sp,S/sp] f32 (still O(S/sp) per device).  Counter-hash
-    dropout keyed per shard pair (same bits family as mha_xla)."""
-    if sm_scale is None:
-        sm_scale = float(1.0 / np.sqrt(q.shape[-1]))
-    if kv_mask is None:
-        # fresh arrays are replicated; the ppermute'd scan carry needs the
-        # mask varying over the ring axis (shard_map vma check)
-        kv_mask = _pvary(jnp.ones((q.shape[0], k.shape[2]), jnp.float32),
-                         axis_name)
-    sp = lax.psum(1, axis_name)
-    idx = lax.axis_index(axis_name)
-    S_local = q.shape[2]
-
-    def partial_attn(k_blk, v_blk, m_blk, kv_idx):
-        s = (jnp.einsum("bhqd,bhkd->bhqk", q, k_blk).astype(jnp.float32)
-             * sm_scale)
-        s = jnp.where(m_blk[:, None, None, :] > 0, s, NEG_INF)
-        if causal:
-            qi = jnp.arange(S_local)[:, None] + idx * S_local
-            ki = jnp.arange(S_local)[None, :] + kv_idx * S_local
-            s = jnp.where(qi >= ki, s, NEG_INF)
-        m_new = jnp.max(s, axis=-1, keepdims=True)
-        p = jnp.exp(s - m_new)
-        l_new = jnp.sum(p, axis=-1, keepdims=True)
-        if dropout_rate and dropout_rate > 0.0:
-            seed = (jnp.zeros((), jnp.int32) if dropout_seed is None
-                    else jnp.asarray(dropout_seed, jnp.int32).reshape(()))
-            p = p * _hash_dropout(
-                seed, idx * 131071 + kv_idx, p.shape, dropout_rate)
-        o_new = jnp.einsum("bhqk,bhkd->bhqd", p, v_blk.astype(jnp.float32))
-        return m_new, l_new, o_new
-
-    perm = [(i, (i + 1) % sp) for i in range(sp)]
-
-    def step(carry, _):
-        m, l, o, k_cur, v_cur, mask_cur, kv_idx = carry
-        m_p, l_p, o_p = partial_attn(k_cur, v_cur, mask_cur, kv_idx)
-        m_new = jnp.maximum(m, m_p)
-        alpha = jnp.exp(m - m_new)
-        beta = jnp.exp(m_p - m_new)
-        l_new = l * alpha + l_p * beta
-        o_new = o * alpha + o_p * beta
-        k_nxt = lax.ppermute(k_cur, axis_name, perm)
-        v_nxt = lax.ppermute(v_cur, axis_name, perm)
-        mask_nxt = lax.ppermute(mask_cur, axis_name, perm)
-        kv_nxt = lax.ppermute(kv_idx, axis_name, perm)
-        return (m_new, l_new, o_new, k_nxt, v_nxt, mask_nxt, kv_nxt), None
-
-    qf = q.astype(jnp.float32)
-    m0 = jnp.full_like(qf[..., :1], NEG_INF)
-    l0 = jnp.zeros_like(qf[..., :1])
-    o0 = jnp.zeros_like(qf)
-    carry = (m0, l0, o0, k, v, kv_mask, idx)
-    (m, l, o, *_), _ = lax.scan(step, carry, None, length=sp)
-    return (o / jnp.maximum(l, 1e-30)).astype(q.dtype)
+        interpret = pallas_interpret()
+    return _paged_attn_pallas(q, k_cache, v_cache, block_tables,
+                              context_lens, sm_scale, interpret,
+                              k_scale=k_scale, v_scale=v_scale)

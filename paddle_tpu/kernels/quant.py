@@ -48,15 +48,10 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 from ..observability import stats as _obs_stats
-from ..observability import trace as _obs_trace
-
-try:  # pallas import kept lazy-safe for exotic builds
-    from jax.experimental import pallas as pl  # noqa: F401
-    _HAVE_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAVE_PALLAS = False
+from ..platform import pallas_interpret
 
 __all__ = [
     "enabled_for",
@@ -96,17 +91,16 @@ _EPILOGUE_ACTS = {
 # problems take the XLA dequantized path (still quantized math)
 _VMEM_BUDGET_BYTES = 8 << 20
 
-_telemetry_on = _obs_trace.flags_on
-
 # pull-mirror of the quant.* counters so /quantz renders without
-# scraping the metrics registry (and regardless of FLAGS_runtime_stats)
+# scraping the metrics registry
 _COUNTERS: Dict[str, int] = {}
 
 
 def _count(name: str, n: int = 1) -> None:
+    # unconditional (not gated on FLAGS_runtime_stats): trace-time only,
+    # and an uncounted fallback is a kernel that silently never ran
     _COUNTERS[name] = _COUNTERS.get(name, 0) + n
-    if _telemetry_on():
-        _obs_stats.scope("quant").counter(name).inc(n)
+    _obs_stats.scope("quant").counter(name).inc(n)
 
 
 def enabled_for(ctx) -> bool:
@@ -125,10 +119,6 @@ def count_runtime_disable() -> None:
     only reachable on a real TPU backend) is recovered by re-lowering
     without the int8 kernels; counted so the degrade is loud."""
     _count("runtime_disables")
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -193,9 +183,6 @@ def int8_fc(x, w_q, w_scale, in_scale: float = 0.0, bias=None,
 
     ``x`` f32 [M, K]; ``w_q`` int8 [K, N]; ``w_scale`` f32 [N];
     ``bias`` f32 [N] or None; ``act`` one of the epilogue set."""
-    if not _HAVE_PALLAS:
-        _count("matmul_fallbacks")
-        return None
     try:
         if x.ndim != 2 or w_q.ndim != 2 or act not in _EPILOGUE_ACTS:
             raise ValueError("int8_fc needs 2-D operands / known act")
@@ -208,7 +195,7 @@ def int8_fc(x, w_q, w_scale, in_scale: float = 0.0, bias=None,
         if m * k + k * n + 4 * (m * n + 2 * n) > _VMEM_BUDGET_BYTES:
             raise ValueError("int8_fc operands exceed the VMEM budget")
         if interpret is None:
-            interpret = _interpret()
+            interpret = pallas_interpret()
         xq, sx = _quantize_act(x, in_scale)
         dq = (sx * w_scale.astype(jnp.float32) / (QMAX * QMAX))
         b = (bias.astype(jnp.float32) if bias is not None

@@ -92,13 +92,10 @@ def _pm():
 
 
 def cost_dict(compiled) -> dict:
-    """``cost_analysis()`` across jax versions: list-of-dict (0.4.x) or
-    plain dict (newer); {} when the executable cannot report.  Public:
-    bench.py attributes its timed executables through this."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return dict(ca or {})
+    """``cost_analysis()`` as a plain dict; {} when the executable
+    cannot report.  Public: bench.py attributes its timed executables
+    through this."""
+    return dict(compiled.cost_analysis() or {})
 
 
 def _memory_dict(compiled) -> dict:

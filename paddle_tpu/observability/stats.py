@@ -30,7 +30,7 @@ import time
 from typing import Dict, Iterable, Optional, Sequence, Tuple
 
 # default latency buckets in MILLISECONDS: sub-ms dispatches up through
-# multi-second XLA compiles / tunneled RPC round trips
+# multi-second XLA compiles / slow RPC round trips
 DEFAULT_MS_BUCKETS: Tuple[float, ...] = (
     0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0, 100.0,
     250.0, 500.0, 1000.0, 2500.0, 5000.0, 10000.0)

@@ -83,12 +83,7 @@ def _fused_attention(ctx, ins, attrs):
 
             seed_in = (seed if seed is not None
                        else jnp.zeros((1,), jnp.int32))
-            # jax.shard_map is the modern spelling; older jax only has
-            # the experimental location
-            shard_map = getattr(jax, "shard_map", None)
-            if shard_map is None:
-                from jax.experimental.shard_map import shard_map
-            out = shard_map(
+            out = jax.shard_map(
                 ring, mesh=mesh,
                 in_specs=(qspec, qspec, qspec, mspec, sspec),
                 out_specs=qspec)(q, k, v, kv_mask, seed_in)

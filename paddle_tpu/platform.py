@@ -17,29 +17,6 @@ from typing import Dict, List, Union
 
 import jax
 
-# jax version shim: ``jax.shard_map`` is the modern spelling; on older
-# jax only ``jax.experimental.shard_map.shard_map`` exists.  Alias it so
-# kernel code (and tests) can use one spelling across the supported
-# range.
-if not hasattr(jax, "shard_map"):  # pragma: no cover - version-dependent
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map
-        import functools as _functools
-
-        @_functools.wraps(_shard_map)
-        def _shard_map_compat(*args, **kwargs):
-            # the old experimental shard_map has no replication rule for
-            # pallas_call and rejects kernels under its check_rep=True
-            # default; the modern jax.shard_map handles this via vma.
-            # Default the check off so kernel-bearing bodies work the
-            # same across versions (callers may still pass it).
-            kwargs.setdefault("check_rep", False)
-            return _shard_map(*args, **kwargs)
-
-        jax.shard_map = _shard_map_compat
-    except Exception:
-        pass
-
 
 class CPUPlace:
     """Host-device tag (place.h:36)."""
@@ -99,12 +76,8 @@ class DeviceContext:
 
     def __init__(self, place: Place):
         self.place = place
-        devices = jax.devices()
         if isinstance(place, TPUPlace):
-            if place.device_id >= len(devices):
-                raise ValueError(
-                    f"{place!r}: only {len(devices)} device(s) visible")
-            self.device = devices[place.device_id]
+            self.device = tpu_device(place)
         else:
             self.device = jax.devices("cpu")[0] if _has_cpu() else None
 
@@ -127,6 +100,22 @@ class DeviceContext:
 
     def __repr__(self):
         return f"DeviceContext({self.place!r}, {self.platform})"
+
+
+def tpu_device(place: TPUPlace):
+    """The ``jax.Device`` a :class:`TPUPlace` names, or an error: a TPU
+    place must never resolve to whatever backend happens to be the
+    default (a CPU run "on TPUPlace" would pass without a word)."""
+    devices = jax.devices()
+    if devices[0].platform != "tpu":
+        raise RuntimeError(
+            f"{place!r} requested but JAX found no TPU: the default "
+            f"backend is {devices[0].platform!r} "
+            f"({len(devices)} device(s)); use CPUPlace()/None to run on it")
+    if place.device_id >= len(devices):
+        raise ValueError(
+            f"{place!r}: only {len(devices)} device(s) visible")
+    return devices[place.device_id]
 
 
 def _has_cpu() -> bool:
@@ -234,3 +223,11 @@ def device_count() -> int:
 def tpu_places(device_ids: List[int] = None) -> List[TPUPlace]:
     ids = device_ids if device_ids is not None else range(len(jax.devices()))
     return [TPUPlace(i) for i in ids]
+
+
+def pallas_interpret() -> bool:
+    """THE decision whether a Pallas kernel is interpreted: compiled by
+    Mosaic when JAX's default backend is a TPU, interpreted everywhere
+    else (the CPU test mesh).  Every kernel's ``interpret=None`` default
+    resolves here, so "ran on a TPU" can never mean "was interpreted"."""
+    return jax.default_backend() != "tpu"

@@ -64,16 +64,6 @@ class _NativeTrainer:
 
 
 def create(model_dir: str) -> int:
-    import os
-
-    if os.environ.get("PT_CAPI_JAX_PLATFORM"):
-        # env-var JAX_PLATFORMS is dead once a PJRT plugin registered;
-        # honor an explicit platform request in-process (the C train
-        # smoke runs on forced CPU this way)
-        import jax
-
-        jax.config.update("jax_platforms",
-                          os.environ["PT_CAPI_JAX_PLATFORM"])
     return _registry.add(_NativeTrainer(model_dir))
 
 

@@ -63,9 +63,6 @@ def _build_transpiler():
 
 def main():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     role = os.environ["PADDLE_TRAINING_ROLE"]
 
     if role == "MASTER":

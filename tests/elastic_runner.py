@@ -12,9 +12,6 @@ import numpy as np
 
 def main():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     import paddle_tpu as fluid
     from paddle_tpu.core.executor import Executor, Scope
     from paddle_tpu.distributed import notify_complete

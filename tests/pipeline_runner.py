@@ -49,9 +49,6 @@ def transpile(prog, startup, loss):
 
 def main():
     sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    if os.environ.get("JAX_PLATFORMS"):
-        import jax
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
     from paddle_tpu.pipeline.rpc import PipelineStageWorker
 
     stage = int(os.environ["PIPE_STAGE"])

@@ -55,7 +55,7 @@ def test_capi_mnist_end_to_end(tmp_path):
     site = os.path.dirname(os.path.dirname(np.__file__))
     env["PYTHONPATH"] = os.pathsep.join(
         [REPO, site, env.get("PYTHONPATH", "")])
-    env["PT_CAPI_JAX_PLATFORM"] = "cpu"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([os.path.join(NATIVE, "test_capi_mnist"), model_dir],
                        capture_output=True, text=True, env=env, timeout=300)
     assert r.returncode == 0, (r.stdout[-400:], r.stderr[-800:])

@@ -1,0 +1,128 @@
+"""chip_smoke.py: it must refuse to run without a TPU, and its phase
+functions must work — rehearsed here at tiny size on the CPU mesh (the
+script's ``__main__`` has no size switch; the phases take their sizes as
+arguments)."""
+import json
+import os
+import subprocess
+import sys
+import time
+
+import jax
+import pytest
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, REPO)
+
+import chip_smoke  # noqa: E402
+
+TINY = dict(vocab=64, d_model=32, n_head=2, d_ffn=64, n_layer=1)
+
+
+@pytest.fixture(autouse=True)
+def compile_log():
+    if chip_smoke.LOG is None:
+        chip_smoke.LOG = chip_smoke.CompileLog()
+    return chip_smoke.LOG
+
+
+def test_exits_nonzero_at_once_without_a_tpu():
+    """``JAX_PLATFORMS=cpu python chip_smoke.py``: non-zero exit within
+    seconds, the platform found named on stderr, no result line."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    t0 = time.monotonic()
+    r = subprocess.run([sys.executable, os.path.join(REPO, "chip_smoke.py")],
+                       env=env, cwd=REPO, capture_output=True, text=True,
+                       timeout=120)
+    assert time.monotonic() - t0 < 60
+    assert r.returncode != 0
+    assert "'cpu'" in r.stderr and "TPU" in r.stderr
+    assert r.stdout.strip() == ""
+
+
+def test_fails_outside_the_repo(tmp_path):
+    """Alone in a directory (no ``paddle_tpu`` beside it) the script
+    cannot pass: non-zero exit, no JSON."""
+    import shutil
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    env = {k: v for k, v in os.environ.items() if k != "PYTHONPATH"}
+    r = subprocess.run([sys.executable, "chip_smoke.py"], env=env,
+                       cwd=str(tmp_path), capture_output=True, text=True,
+                       timeout=120)
+    assert r.returncode != 0
+    assert not r.stdout.strip().startswith("{")
+
+
+def test_trainer_phase_tiny():
+    out = chip_smoke.phase_trainer(None, batch=4, max_len=8, dtype="float32",
+                                   dropout=0.1, steps=3, scan_steps=2,
+                                   **TINY)
+    assert out["last_loss"] < out["first_loss"]
+    assert out["executor_compiles"] == 3 and out["steps"] == 3 + 2 * 2
+
+
+def test_kernels_phase_tiny():
+    """Off-chip the kernels are forced in interpret mode and compared
+    with the XLA lowering exactly as on the chip; only the Mosaic
+    assertion is out of reach here."""
+    out = chip_smoke.phase_kernels(
+        None, on_chip=False,
+        rnn=dict(batch=8, seq=4, hidden=128, layers=3),
+        flash=(dict(batch=1, seq=128, n_head=2, head_dim=16),))
+    assert set(out) == {"lstm", "gru", "flash_d16_t128"}
+    for case in out.values():
+        assert case["mosaic_custom_call"] is False
+        assert case["max_rel_err"] <= case["tolerance"]
+
+
+def test_decode_server_phase_tiny():
+    out = chip_smoke.phase_decode_server(
+        on_chip=False, max_seq_len=64, max_slots=2,
+        prompt_lens=(3, 9, 5), new_tokens=(6, 3, 4), **TINY)
+    assert out["attn_impl"] == "pallas" and out["transport"] == "native"
+    assert out["requests"] == 6 and out["joins"] == 6
+    assert out["tokens_checked"] == 13
+    assert out["tokens_exact"] == 13      # f32 on the CPU: no near-ties
+
+
+def test_four_chip_phase_tiny():
+    """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
+    parity against the one-device run of the same program."""
+    sizes = dict(batch=4, max_len=8, dtype="float32", dropout=0.1, **TINY)
+    ref = chip_smoke.phase_trainer(None, steps=2, scan_steps=1, **sizes)
+    out = chip_smoke.phase_four_chip(None, jax.devices()[:4],
+                                     ref["first_loss"], steps=2, **sizes)
+    assert out["rel_diff"] <= 1e-2
+    assert len(out["mesh_losses"]) == 2
+
+
+def test_run_phase_records_failure_and_goes_on():
+    report = {"phases": {}}
+
+    def boom():
+        chip_smoke.check(False, "a check that does not hold")
+
+    assert chip_smoke.run_phase(report, "p", boom) is None
+    assert report["phases"]["p"]["status"] == "failed"
+    assert "does not hold" in report["phases"]["p"]["error"]
+    assert chip_smoke.run_phase(report, "q", lambda: {"x": 1}) == {"x": 1}
+    assert report["phases"]["q"]["status"] == "ok"
+    json.dumps(report)
+
+
+def test_result_line_is_exactly_ok_and_device():
+    """The driver parses the last stdout line: ``ok`` and ``device``
+    (``platform``, ``kind``, ``count``) and no other key — the detail
+    goes on the report line before it."""
+    report = {"ok": True, "phases": {"trainer": {"status": "ok"}},
+              "env": {"jax": "x"}, "fallback_counters": {},
+              "device": {"platform": "tpu", "kind": "TPU v5 lite",
+                         "count": 1}}
+    line = chip_smoke.result_line(report)
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}}
+    report["ok"] = False
+    assert json.loads(chip_smoke.result_line(report))["ok"] is False

@@ -410,7 +410,6 @@ _CHILD = r"""
 import json, os, sys, time
 import numpy as np
 import jax
-jax.config.update("jax_platforms", "cpu")
 import paddle_tpu as fluid
 from paddle_tpu.core.executor import Executor, Scope
 from paddle_tpu.models import mnist
@@ -420,7 +419,6 @@ from paddle_tpu.core import unique_name, compile_cache as cc
 mode = sys.argv[1]
 if mode == "plain":
     assert not cc.enabled()
-    assert jax.config.jax_compilation_cache_dir is None
 
 prog, startup = Program(), Program()
 with program_guard(prog, startup), unique_name.guard():
@@ -521,3 +519,88 @@ def test_concurrent_two_process_writers_atomic(tmp_path):
         cc.read_header(e["path"])  # every surviving entry is well-formed
     third = _run_child(str(script), "verify", cache=str(d))
     assert third["persistent_hits"] >= 2 and third["faults"] == 0
+
+
+# ---------------------------------------------------------------------------
+# where jax's own persistent cache lives: decided in ONE place
+# ---------------------------------------------------------------------------
+
+_DIR_CHILD = r"""
+import json
+import jax
+import paddle_tpu as fluid
+from paddle_tpu.core import compile_cache as cc
+fluid.Executor()
+print("DIR=" + json.dumps({"jax": jax.config.jax_compilation_cache_dir,
+                           "wired": cc.wire_jax_cache()}), flush=True)
+"""
+
+
+def _dir_child(tmp_path, cwd, **env_extra):
+    script = tmp_path / "dir_child.py"
+    script.write_text(_DIR_CHILD)
+    env = _child_env()
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    env.update(env_extra)
+    out = subprocess.run([sys.executable, str(script)], env=env, cwd=cwd,
+                         capture_output=True, text=True, timeout=300)
+    assert out.returncode == 0, out.stderr[-2000:]
+    line = next(l for l in out.stdout.splitlines() if l.startswith("DIR="))
+    return json.loads(line[len("DIR="):])
+
+
+@pytest.mark.parametrize("with_flag", [False, True])
+def test_env_var_places_jax_cache_and_nothing_moves_it(tmp_path, with_flag):
+    """``JAX_COMPILATION_CACHE_DIR`` set: jax's cache lives there after
+    an Executor is built — also when ``FLAGS_compile_cache_dir`` is set
+    (whose ``.ptcc`` tier keeps its own directory) — and nothing is
+    written under the in-checkout default."""
+    default = cc.default_jax_cache_dir()
+    before = sorted(os.listdir(default)) if os.path.isdir(default) else None
+    x = str(tmp_path / "x")
+    extra = {"JAX_COMPILATION_CACHE_DIR": x}
+    if with_flag:
+        extra["FLAGS_compile_cache_dir"] = str(tmp_path / "ptcc")
+    got = _dir_child(tmp_path, REPO, **extra)
+    assert got == {"jax": x, "wired": x}
+    after = sorted(os.listdir(default)) if os.path.isdir(default) else None
+    assert after == before
+
+
+def test_default_jax_cache_dir_is_fixed_and_in_the_checkout(tmp_path):
+    """Unset: the same path in two processes started from different
+    directories, inside the checkout, derived from the package's
+    location (never a temp name, a pid or a time)."""
+    a = _dir_child(tmp_path, REPO)
+    b = _dir_child(tmp_path, str(tmp_path),
+                   FLAGS_compile_cache_dir=str(tmp_path / "ptcc"))
+    want = os.path.join(REPO, ".jax_compile_cache")
+    assert a == b == {"jax": want, "wired": want}
+    assert cc.default_jax_cache_dir() == want
+
+
+def test_executables_jax_cache_served_are_not_stored_again(tmp_path):
+    """With both tiers on, an executable that jax's own cache loaded is
+    not serialized into a ``.ptcc`` entry: on XLA:CPU such an entry has
+    lost its kernels and kills the NEXT process at readback ("Function
+    ... not found").  Process 1 fills both tiers; the tier-A entries are
+    removed; process 2 compiles from jax's cache and must store nothing;
+    process 3 still runs clean."""
+    script = tmp_path / "cc_child.py"
+    script.write_text(_CHILD)
+    d = tmp_path / "ptcc"
+    d.mkdir()
+    both = {"JAX_ENABLE_COMPILATION_CACHE": "1",
+            "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "jax"),
+            "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0",
+            "JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES": "-1"}
+    first = _run_child(str(script), "fill", cache=str(d), extra_env=both)
+    assert cc.list_entries(str(d))
+    for e in cc.list_entries(str(d)):
+        os.remove(e["path"])
+    second = _run_child(str(script), "jaxhit", cache=str(d), extra_env=both)
+    assert second["persistent_hits"] == 0
+    assert cc.list_entries(str(d)) == []
+    third = _run_child(str(script), "again", cache=str(d), extra_env=both)
+    assert third["faults"] == 0
+    assert third["loss"] == pytest.approx(first["loss"], rel=1e-5)

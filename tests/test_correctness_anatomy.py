@@ -612,8 +612,8 @@ def test_e2e_corrupt_replica_named_and_quarantined(tmp_path):
     divergence sentinel NAMES it from the digest riders its leases
     carry, the supervisor confirms after hysteresis and DRAINs exactly
     that worker — while client traffic drops zero requests — and the
-    flight record carries detect -> name -> fail -> quarantine ->
-    drain in order."""
+    flight record carries detect -> fail -> quarantine -> drain in
+    order, with the naming somewhere beside it."""
     from paddle_tpu.distributed.supervisor import (DEAD, DRAINING, LIVE,
                                                    FleetSpec, RoleSpec,
                                                    Supervisor)
@@ -714,12 +714,17 @@ def test_e2e_corrupt_replica_named_and_quarantined(tmp_path):
             "supervisor.canary_quarantines").value - q0 == 1
         assert stats.counter(
             "supervisor.divergence_named").value - d0 >= 1
-        # the flight record carries the chain IN ORDER
+        # the flight record carries the canary chain IN ORDER: detect →
+        # confirmed fail → quarantine → drain all come out of one
+        # observation path (_observe_canary_locked), which is the order
+        # the code guarantees.  The divergence naming is an independent
+        # signal — digests ride the lease data and refresh on lease
+        # renewal — so it may land before or after the drain; it must be
+        # present and name only the liar (asserted below), not be ordered
         events = flight.events()
         msgs = [e["msg"] for e in events]
-        chain = ["supervisor_canary_detect", "supervisor_divergence_named",
-                 "supervisor_canary_fail", "supervisor_canary_quarantine",
-                 "supervisor_drain"]
+        chain = ["supervisor_canary_detect", "supervisor_canary_fail",
+                 "supervisor_canary_quarantine", "supervisor_drain"]
         idx = [msgs.index(m) for m in chain]
         assert idx == sorted(idx), list(zip(chain, idx))
         named = [e for e in events

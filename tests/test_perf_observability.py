@@ -701,7 +701,7 @@ def test_bench_compare_skip_and_analysis_awareness():
                   "b": {"images_per_sec": 100.0},
                   "scaling_dp8": {"eff_flops": 0.99},
                   "c": {"tokens_per_sec": 10.0}})
-    new = _round({"a": {"skipped": "tunnel probe failed"},
+    new = _round({"a": {"skipped": "budget"},
                   "b": {"images_per_sec": 99.0},
                   "scaling_dp8": {"eff_flops": 0.50, "analysis": True},
                   "c": {"error": "timeout"}})
@@ -756,7 +756,7 @@ def test_bench_compare_loads_driver_wrapper_and_finds_baseline(tmp_path):
     with open(str(tmp_path / "BENCH_r04.json"), "w") as f:
         json.dump({"round": 4, "tail": "died"}, f)
     # r05: every real config skipped; only the analysis entry "measured"
-    allskip = _round({"resnet50": {"skipped": "tunnel"},
+    allskip = _round({"resnet50": {"skipped": "budget"},
                       "scaling_dp8": {"eff_flops": 1.0}})
     with open(str(tmp_path / "BENCH_r05.json"), "w") as f:
         json.dump({"round": 5, "tail": json.dumps(allskip)}, f)
@@ -768,13 +768,6 @@ def test_bench_compare_loads_driver_wrapper_and_finds_baseline(tmp_path):
     assert base and os.path.basename(base) == "BENCH_r03.json"
     with pytest.raises(ValueError):
         bench_compare.load_round(str(tmp_path / "BENCH_r04.json"))
-
-
-def test_real_bench_rounds_baseline_is_r03():
-    """Against the repo's actual BENCH history: r05 (all-skip) and r04
-    (timeout) are passed over; r03 is the last measured round."""
-    base = bench_compare.find_baseline(REPO)
-    assert base and os.path.basename(base) == "BENCH_r03.json"
 
 
 def test_roofline_numbers_shared_arithmetic():

@@ -15,12 +15,25 @@ def test_places_and_pool():
     assert p0 == fluid.TPUPlace(0) and p0 != fluid.CPUPlace()
     assert fluid.CUDAPlace is fluid.TPUPlace  # compat alias
     pool = fluid.DeviceContextPool.instance()
-    ctx = pool.get(p0)
-    assert pool.get(fluid.TPUPlace(0)) is ctx  # keyed by place
-    assert ctx.platform  # cpu under tests, tpu on hardware
+    ctx = pool.get(fluid.CPUPlace())
+    assert pool.get(fluid.CPUPlace()) is ctx  # keyed by place
+    assert ctx.platform == "cpu"
     ctx.synchronize()
     assert fluid.device_count() >= 1
     assert len(fluid.tpu_places()) == fluid.device_count()
+
+
+def test_tpu_place_never_resolves_to_another_backend():
+    """A TPUPlace names a TPU or raises: on this CPU mesh both the
+    device context and the Executor refuse it with a message naming the
+    platform found, while None / CPUPlace keep running on the default
+    backend."""
+    with pytest.raises(RuntimeError, match="no TPU.*'cpu'"):
+        fluid.DeviceContextPool.instance().get(fluid.TPUPlace(0))
+    with pytest.raises(RuntimeError, match="no TPU.*'cpu'"):
+        fluid.Executor(fluid.TPUPlace())
+    assert fluid.Executor(fluid.CPUPlace()).place == fluid.CPUPlace()
+    assert fluid.Executor().place is None
 
 
 def test_flags_env_types_and_api():

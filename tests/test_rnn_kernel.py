@@ -81,6 +81,38 @@ def test_fused_lstm_grads_match_scan(masked):
                                    rtol=2e-4, atol=2e-4, err_msg=name)
 
 
+def test_fused_kernels_reverse_is_the_flipped_scan():
+    """``reverse=True`` walks time backward through the kernels' own
+    index maps and must equal flip -> forward kernel -> flip, forward
+    and backward, masked — with no flipped copy made (on the v5e XLA
+    returned wrong values for the flip+transpose the ops used to feed
+    the kernels with, PERF.md Bring-up)."""
+    xproj, w, h0, c0, mask = data(masked=True)
+    f = lambda a: jnp.flip(a, 1)  # noqa: E731
+    hs, cs = R.lstm_fused(xproj, w, h0, c0, mask, True, reverse=True)
+    hs_f, cs_f = R.lstm_fused(f(xproj), w, h0, c0, f(mask), True)
+    np.testing.assert_array_equal(np.asarray(hs), np.asarray(f(hs_f)))
+    np.testing.assert_array_equal(np.asarray(cs), np.asarray(f(cs_f)))
+    g = R.lstm_fused_grad(xproj, w, h0, c0, mask, hs, cs, 2.0 * hs, cs,
+                          True, reverse=True)
+    g_f = R.lstm_fused_grad(f(xproj), w, h0, c0, f(mask), hs_f, cs_f,
+                            2.0 * hs_f, cs_f, True)
+    for a, b in zip(g, (f(g_f[0]),) + tuple(g_f[1:])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+    xg = xproj[:, :, :3 * w.shape[0]]
+    wg = w[:, :3 * w.shape[0]]
+    hg = R.gru_fused(xg, wg, h0, mask, True, reverse=True)
+    hg_f = R.gru_fused(f(xg), wg, h0, f(mask), True)
+    np.testing.assert_array_equal(np.asarray(hg), np.asarray(f(hg_f)))
+    g = R.gru_fused_grad(xg, wg, h0, mask, hg, 2.0 * hg, True, reverse=True)
+    g_f = R.gru_fused_grad(f(xg), wg, h0, f(mask), hg_f, 2.0 * hg_f, True)
+    for a, b in zip(g, (f(g_f[0]),) + tuple(g_f[1:])):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-6, atol=1e-6)
+
+
 @pytest.mark.parametrize("reverse", [False, True])
 def test_lstm_op_pallas_parity_in_program(reverse):
     """The lstm op with use_pallas_kernel=True (interpret) reproduces the

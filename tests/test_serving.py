@@ -63,7 +63,7 @@ def _transformer_predictor(seed=1, T=8):
 
 def _mlp_predictor(seed=1):
     prog, startup = Program(), Program()
-    prog.random_seed = seed
+    prog.random_seed = startup.random_seed = seed
     with program_guard(prog, startup), unique_name.guard():
         x = L.data("x", [8])
         h = L.fc(x, 16, act="relu")
@@ -72,6 +72,16 @@ def _mlp_predictor(seed=1):
     with scope_guard(scope):
         exe.run(startup)
     return Predictor(prog, ["x"], [y.name], scope)
+
+
+def _same_answer(got, want) -> bool:
+    """A reply coalesced into a wider batch bucket equals the answer to
+    the same feed run alone only up to float rounding: XLA:CPU tiles and
+    accumulates a [4, 8] matmul differently from a [1, 8] one, so the
+    softmax outputs differ in the last bits across batch shapes.  The
+    bound is a few float32 ulps of these O(1) probabilities, far below
+    the gap between the two model versions the swap tests tell apart."""
+    return np.allclose(got, want, rtol=1e-5, atol=1e-6)
 
 
 def _mnist_req(rng, rows=1):
@@ -405,11 +415,12 @@ def test_hot_swap_under_load_zero_drops_zero_recompiles():
     counters = obs.stats.default_registry().to_dict()
     for k, v in base.items():
         assert counters.get(k, 0) == v, f"{k} moved during serving"
-    # every reply is exactly v1's or v2's answer for that feed
+    # every reply is v1's or v2's answer for that feed
     for idx, got in results:
-        ok1 = np.array_equal(got, want1[idx][:got.shape[0]])
-        ok2 = np.array_equal(got, want2[idx][:got.shape[0]])
+        ok1 = _same_answer(got, want1[idx][:got.shape[0]])
+        ok2 = _same_answer(got, want2[idx][:got.shape[0]])
         assert ok1 or ok2
+        assert not (ok1 and ok2), "the two versions must stay apart"
     # after the flip, new requests answer with v2
     out = np.asarray(mgr.infer("mlp", feeds[0], timeout=60)[0])
     np.testing.assert_array_equal(out, want2[0])
@@ -467,8 +478,8 @@ def test_serving_lite_server_client_swap_and_servingz():
                 except Exception as e:  # pragma: no cover
                     errs.append(repr(e))
                     return
-                assert (np.array_equal(out, want1[i % 8])
-                        or np.array_equal(out, want2[i % 8]))
+                assert (_same_answer(out, want1[i % 8])
+                        or _same_answer(out, want2[i % 8]))
                 with lock:
                     n_ok[0] += 1
                 i += 1

@@ -12,7 +12,7 @@ on-disk half of the cache:
 
 ``ls`` prints one line per tier-A entry (key, size, age, last use, the
 environment stamp that gates loads); ``stat`` summarizes occupancy
-(entries/bytes, tier-B ``xla/`` subdir bytes, oldest/newest use).
+(entries/bytes, oldest/newest use).
 ``verify`` checks every entry's framing + header and reports
 corrupted/truncated files (exit code 1 if any; ``--fix`` deletes them,
 ``--deep`` additionally unpickles and loads each executable — requires
@@ -131,22 +131,11 @@ def entry_lines(d):
 
 def stat_dir(d):
     entries = _list_entries(d)
-    xla_bytes = 0
-    xla_files = 0
-    for root, _, files in os.walk(os.path.join(d, "xla")):
-        for f in files:
-            try:
-                xla_bytes += os.path.getsize(os.path.join(root, f))
-                xla_files += 1
-            except OSError:
-                pass
     now = time.time()
     out = {
         "dir": d,
         "tier_a_entries": len(entries),
         "tier_a_bytes": sum(e["bytes"] for e in entries),
-        "tier_b_xla_files": xla_files,
-        "tier_b_xla_bytes": xla_bytes,
     }
     if entries:
         out["oldest_use_age_s"] = round(now - entries[0]["mtime"], 1)
@@ -186,18 +175,13 @@ def _deep_verify(path: str, hdr: dict) -> None:
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     if repo not in sys.path:
         sys.path.insert(0, repo)
-    import pickle
-
     from paddle_tpu.core import compile_cache as cc
     env = cc.env_info()
     skew = {k: (hdr.get(k), v) for k, v in env.items()
             if hdr.get(k) != v}
     if skew:
         raise ValueError(f"environment skew {skew}")
-    _, blob = cc._read_entry(path)
-    payload, in_tree, out_tree = pickle.loads(blob)
-    from jax.experimental import serialize_executable as se
-    se.deserialize_and_load(payload, in_tree, out_tree)
+    cc.deserialize_entry(*cc._read_entry(path))
 
 
 def prune_dir(d, cap=None):

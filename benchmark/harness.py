@@ -1,0 +1,461 @@
+"""The benchmark's loader, accounting and result line.
+
+Everything a cell is made of is data found by name: ``BENCHMARK.json``
+names a cell's configuration and traffic mix, ``benchmark/configs/<config>.json``
+and ``benchmark/traffic/<mix>.json`` hold them, a configuration's ``kind``
+names its driver module (``benchmark/drivers/<kind>.py``) and every per-layer
+metric has ``benchmark/metrics/<metric>.json`` naming its reader.  No cell,
+configuration, mix or metric is known to this code by a literal, so a later
+PR adds any of them as new files and entries and edits nothing that is here.
+
+Importing this module imports neither JAX nor the program.
+"""
+from __future__ import annotations
+
+import importlib.util
+import json
+import math
+import os
+import re
+import statistics
+from typing import Callable, Dict, List, Optional, Sequence
+
+MANIFEST = "BENCHMARK.json"
+NAME_RE = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
+UNIT_RE = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+
+# An operation fails only for one of these named classes (ISSUE 24, rule 2).
+# A token that differs from a reference is never one of them.
+FAILURE_CLASSES = ("shed", "too_long", "error", "timeout", "short")
+
+
+class ConfigurationError(ValueError):
+    """The manifest or one of the files it names cannot be run as written:
+    raised before anything is sent or trained."""
+
+
+def _read_json(path: str) -> dict:
+    try:
+        with open(path) as f:
+            return json.load(f)
+    except FileNotFoundError:
+        raise ConfigurationError(f"{path}: no such file") from None
+    except json.JSONDecodeError as e:
+        raise ConfigurationError(f"{path}: not JSON ({e})") from None
+
+
+def load_manifest(root: str) -> dict:
+    return _read_json(os.path.join(root, MANIFEST))
+
+
+def _under_paths(manifest: dict, rel: str) -> bool:
+    return any(rel == p or rel.startswith(p.rstrip("/") + "/")
+               for p in manifest["paths"])
+
+
+def metric_applies(metric: dict, workload: str) -> bool:
+    cells = metric.get("workloads")
+    return cells is None or workload in cells
+
+
+class Cell:
+    """One entry of ``workloads`` with everything it names, loaded."""
+
+    def __init__(self, root: str, manifest: dict, workload: str):
+        by_name = {w["name"]: w for w in manifest["workloads"]}
+        if workload not in by_name:
+            raise ConfigurationError(
+                f"no workload {workload!r} in {MANIFEST}; it has "
+                f"{sorted(by_name)}")
+        self.root = root
+        self.entry = by_name[workload]
+        self.name = workload
+        self.chips = int(self.entry["chips"])
+        cfg_entry = {c["name"]: c for c in manifest["configs"]}.get(
+            self.entry["config"])
+        if cfg_entry is None:
+            raise ConfigurationError(
+                f"workload {workload!r} names configuration "
+                f"{self.entry['config']!r}, which {MANIFEST} does not list")
+        self.config = _read_json(os.path.join(root, cfg_entry["file"]))
+        self.config_name = cfg_entry["name"]
+        # the benchmark's own directory is where the configuration lives
+        self.bench_dir = os.path.dirname(os.path.dirname(
+            os.path.join(root, cfg_entry["file"])))
+        self.mix_name = self.entry["traffic"]
+        self.mix = _read_json(os.path.join(self.bench_dir, "traffic",
+                                           self.mix_name + ".json"))
+        kind = self.config.get("kind")
+        if not isinstance(kind, str) or not NAME_RE.match(kind):
+            raise ConfigurationError(
+                f"{cfg_entry['file']}: 'kind' must name a driver module")
+        self.kind = kind
+        self.end_to_end = [m for m in manifest["end_to_end"]
+                           if metric_applies(m, workload)]
+        self.per_layer = [m for m in manifest["per_layer"]
+                          if metric_applies(m, workload)]
+
+    def driver(self):
+        path = os.path.join(self.bench_dir, "drivers", self.kind + ".py")
+        return load_module(path, f"benchmark_driver_{self.kind}")
+
+    def metric_file(self, metric: str) -> dict:
+        return _read_json(os.path.join(self.bench_dir, "metrics",
+                                       metric + ".json"))
+
+    def reader(self, metric: str) -> Callable:
+        """The ``read(ctx)`` function of a per-layer metric, found through
+        the metric's own file."""
+        spec = self.metric_file(metric)
+        rel = spec.get("reader")
+        if not isinstance(rel, str):
+            raise ConfigurationError(
+                f"metrics/{metric}.json names no 'reader'")
+        mod = load_module(os.path.join(self.root, rel),
+                          "benchmark_reader_" + re.sub(r"\W", "_", rel))
+        return lambda ctx: mod.read(ctx, **spec.get("args", {}))
+
+
+def load_module(path: str, name: str):
+    if not os.path.isfile(path):
+        raise ConfigurationError(f"{path}: no such module")
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def check_manifest(root: str, manifest: dict) -> List[str]:
+    """What the contract fixes about ``BENCHMARK.json`` that can be checked
+    without a chip; returns the faults found (none for a sound file)."""
+    faults: List[str] = []
+    need = {"command", "paths", "run_seconds", "configs", "workloads",
+            "end_to_end", "per_layer"}
+    if set(manifest) != need:
+        faults.append(f"keys {sorted(manifest)} are not exactly {sorted(need)}")
+        return faults
+
+    def name_ok(what, n):
+        if not isinstance(n, str) or not NAME_RE.match(n):
+            faults.append(f"{what} {n!r} is not a name")
+
+    seen: Dict[str, set] = {k: set() for k in
+                            ("config", "workload", "metric")}
+
+    def once(kind, n):
+        if n in seen[kind]:
+            faults.append(f"{kind} {n!r} appears twice")
+        seen[kind].add(n)
+
+    for c in manifest["configs"]:
+        name_ok("config", c.get("name"))
+        once("config", c.get("name"))
+        if set(c) != {"name", "source", "file", "reduced", "why"}:
+            faults.append(f"config {c.get('name')!r} has keys {sorted(c)}")
+        if not _under_paths(manifest, c.get("file", "")):
+            faults.append(f"config file {c.get('file')!r} is outside paths")
+        elif not os.path.isfile(os.path.join(root, c["file"])):
+            faults.append(f"config file {c['file']!r} does not exist")
+        for k in c.get("reduced", []):
+            name_ok("reduced key", k)
+    pairs = set()
+    for w in manifest["workloads"]:
+        name_ok("workload", w.get("name"))
+        once("workload", w.get("name"))
+        for k in ("config", "traffic"):
+            name_ok(k, w.get(k))
+        if set(w) != {"name", "config", "traffic", "chips", "why"}:
+            faults.append(f"workload {w.get('name')!r} has keys {sorted(w)}")
+        if w.get("chips") not in (1, 4):
+            faults.append(f"workload {w.get('name')!r}: chips {w.get('chips')}")
+        if not (1 <= len(w.get("why", "")) <= 200):
+            faults.append(f"workload {w.get('name')!r}: why is not 1-200 chars")
+        if (w.get("config"), w.get("traffic")) in pairs:
+            faults.append(f"pair {(w.get('config'), w.get('traffic'))} twice")
+        pairs.add((w.get("config"), w.get("traffic")))
+        if w.get("config") not in seen["config"]:
+            faults.append(f"workload {w.get('name')!r}: unknown config")
+    four = sum(1 for w in manifest["workloads"] if w.get("chips") == 4)
+    if four > max(1, len(manifest["workloads"]) // 4):
+        faults.append(f"{four} four-chip cells of {len(manifest['workloads'])}")
+    used = {w.get("config") for w in manifest["workloads"]}
+    for c in seen["config"] - used:
+        faults.append(f"config {c!r} is used by no cell")
+    e2e = set()
+    for m in manifest["end_to_end"]:
+        name_ok("metric", m.get("name"))
+        once("metric", m.get("name"))
+        e2e.add(m.get("name"))
+        if set(m) - {"workloads"} != {"name", "unit", "better", "bound",
+                                     "source"}:
+            faults.append(f"metric {m.get('name')!r} has keys {sorted(m)}")
+        if not (isinstance(m.get("bound"), (int, float))
+                and 0 < m["bound"] <= 0.1):
+            faults.append(f"metric {m.get('name')!r}: bound {m.get('bound')}")
+        if m.get("source") not in ("host_clock", "device_trace"):
+            faults.append(f"metric {m.get('name')!r}: source {m.get('source')}")
+    if "setup_s" not in e2e:
+        faults.append("no setup_s among end_to_end")
+    for m in manifest["per_layer"]:
+        name_ok("metric", m.get("name"))
+        once("metric", m.get("name"))
+        if set(m) - {"workloads"} != {"name", "unit", "better", "source",
+                                     "layer", "moves"}:
+            faults.append(f"metric {m.get('name')!r} has keys {sorted(m)}")
+        if m.get("source") not in SOURCES:
+            faults.append(f"metric {m.get('name')!r}: source {m.get('source')}")
+        if m.get("moves") not in e2e:
+            faults.append(f"metric {m.get('name')!r} moves {m.get('moves')!r}")
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if not UNIT_RE.match(str(m.get("unit", ""))):
+            faults.append(f"metric {m.get('name')!r}: unit {m.get('unit')!r}")
+        if m.get("better") not in ("lower", "higher"):
+            faults.append(f"metric {m.get('name')!r}: better {m.get('better')!r}")
+        for w in m.get("workloads", []):
+            if w not in seen["workload"]:
+                faults.append(f"metric {m.get('name')!r} lists cell {w!r}")
+    for w in manifest["workloads"]:
+        name = w.get("name")
+        mine_e2e = {m["name"] for m in manifest["end_to_end"]
+                    if metric_applies(m, name)}
+        mine_pl = [m for m in manifest["per_layer"] if metric_applies(m, name)]
+        if "setup_s" not in mine_e2e or len(mine_e2e) < 2:
+            faults.append(f"cell {name!r} reports {sorted(mine_e2e)}")
+        if not mine_pl:
+            faults.append(f"cell {name!r} reports no per-layer metric")
+        for m in mine_pl:
+            if m["moves"] not in mine_e2e:
+                faults.append(f"cell {name!r}: {m['name']!r} moves "
+                              f"{m['moves']!r}, which the cell does not report")
+    rs = manifest["run_seconds"]
+    if not (isinstance(rs, int) and 1 <= rs <= 51):
+        faults.append(f"run_seconds {rs!r}")
+    return faults
+
+
+# ---------------------------------------------------------------------------
+# arithmetic kept with the yardstick
+# ---------------------------------------------------------------------------
+
+def percentile(values: Sequence[float], q: float) -> float:
+    """The ``q``-quantile (0..1) by linear interpolation between order
+    statistics (numpy's default rule), on plain Python floats."""
+    xs = sorted(float(v) for v in values)
+    if not xs:
+        raise ValueError("percentile of nothing")
+    pos = q * (len(xs) - 1)
+    lo = math.floor(pos)
+    hi = min(lo + 1, len(xs) - 1)
+    return xs[lo] + (xs[hi] - xs[lo]) * (pos - lo)
+
+
+def spread(values: Sequence[float]) -> float:
+    """Distance between the first and third quartile as a share of the
+    median, by ``statistics.quantiles(values, n=4)`` — the driver's rule."""
+    q = statistics.quantiles(values, n=4)
+    return (q[2] - q[0]) / statistics.median(values)
+
+
+# ---------------------------------------------------------------------------
+# failure accounting (one implementation, every cell)
+# ---------------------------------------------------------------------------
+
+class Accounting:
+    """Operations sent within the window and what became of them.
+
+    ``attempted`` counts operations sent in the window; ``failed`` counts an
+    operation only under one of :data:`FAILURE_CLASSES`.  Operations sent
+    outside the window (a lead-in) are counted apart: a failure there makes
+    the run incorrect but is not one of the window's operations."""
+
+    def __init__(self):
+        self.attempted = 0
+        self.outside = 0
+        self.by_class = {c: 0 for c in FAILURE_CLASSES}
+        self.outside_by_class = {c: 0 for c in FAILURE_CLASSES}
+        self.examples: List[str] = []
+
+    def record(self, in_window: bool, failure: Optional[str],
+               detail: str = "") -> None:
+        if failure is not None and failure not in self.by_class:
+            raise ValueError(f"unknown failure class {failure!r}")
+        if in_window:
+            self.attempted += 1
+        else:
+            self.outside += 1
+        if failure is not None:
+            (self.by_class if in_window else self.outside_by_class)[failure] += 1
+            if len(self.examples) < 8:
+                self.examples.append(f"{failure}: {detail}"[:300])
+
+    @property
+    def failed(self) -> int:
+        return sum(self.by_class.values())
+
+    @property
+    def failed_outside(self) -> int:
+        return sum(self.outside_by_class.values())
+
+    def line(self) -> str:
+        cls = " ".join(f"{c}={n}" for c, n in self.by_class.items())
+        out = (f"bench failures: attempted={self.attempted} "
+               f"failed={self.failed} {cls}")
+        if self.outside:
+            out += (f" | outside the window: sent={self.outside} "
+                    f"failed={self.failed_outside}")
+        return out
+
+
+class Checks:
+    """The conditions of ``correct``; every one is printed, all must hold."""
+
+    def __init__(self):
+        self.items: List[tuple] = []
+
+    def add(self, name: str, ok: bool, detail: str = "") -> None:
+        self.items.append((name, bool(ok), detail))
+
+    @property
+    def ok(self) -> bool:
+        return all(ok for _, ok, _ in self.items)
+
+    def lines(self) -> List[str]:
+        return [f"bench check: {'ok  ' if ok else 'FAIL'} {n}"
+                + (f" — {d}" if d else "") for n, ok, d in self.items]
+
+
+# ---------------------------------------------------------------------------
+# what jax compiled, and what its persistent cache served
+# ---------------------------------------------------------------------------
+
+class CompileLog:
+    """Backend compiles and persistent-cache hits through ``jax.monitoring``,
+    as ``chip_smoke.CompileLog`` counts them (a copy: the yardstick may not
+    change with the program)."""
+
+    def __init__(self):
+        import jax
+        from jax._src.dispatch import BACKEND_COMPILE_EVENT
+        self.compiles = 0
+        self.compile_s = 0.0
+        self.cache_hits = 0
+        self._event = BACKEND_COMPILE_EVENT
+        jax.monitoring.register_event_duration_secs_listener(self._on_dur)
+        jax.monitoring.register_event_listener(self._on_event)
+
+    def _on_dur(self, event, duration, **_kw):
+        if event == self._event:
+            self.compiles += 1
+            self.compile_s += float(duration)
+
+    def _on_event(self, event, **_kw):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+
+    def mark(self) -> tuple:
+        return (self.compiles, self.cache_hits)
+
+
+# fallback counters of the main paths (chip_smoke.FALLBACK_COUNTERS, copied):
+# any non-zero value makes a run incorrect
+FALLBACK_COUNTERS = (
+    "decode.attn_fallbacks",
+    "sparse_fused.gather_fallbacks",
+    "sparse_fused.update_fallbacks",
+    "sparse_fused.runtime_disables",
+    "quant.matmul_fallbacks",
+    "quant.lower_fallbacks",
+    "quant.runtime_disables",
+    "compile_cache.faults",
+)
+
+ATTRIBUTION_FLAGS = ("perf_attribution", "phase_attribution",
+                     "capacity_attribution", "memory_attribution")
+
+
+def program_counters() -> dict:
+    from paddle_tpu.observability import stats
+    return stats.to_dict()
+
+
+def check_program_state(checks: Checks, window_mark: tuple,
+                        window_end_mark: tuple) -> int:
+    """Zero compiles inside the window, every fallback counter zero, every
+    attribution flag off.  Returns the compiles counted inside the window."""
+    from paddle_tpu.core import flags
+    window_compiles = window_end_mark[0] - window_mark[0]
+    checks.add("no compile inside the window", window_compiles == 0,
+               f"{window_compiles} backend compile(s)")
+    c = program_counters()
+    bad = {n: int(c.get(n, 0)) for n in FALLBACK_COUNTERS if c.get(n, 0)}
+    checks.add("every fallback counter zero", not bad, json.dumps(bad))
+    on = [f for f in ATTRIBUTION_FLAGS if flags.get_flags(f)]
+    checks.add("every attribution flag off", not on, ",".join(on))
+    return window_compiles
+
+
+# ---------------------------------------------------------------------------
+# the result line
+# ---------------------------------------------------------------------------
+
+def device_facts(devices, chips: int) -> dict:
+    """The device as JAX reports it and the peak memory of the fullest chip.
+    A TPU keeps two books: ``peak_bytes_in_use`` for live buffers (weights,
+    state, the KV pool, results) and ``peak_bytes_reserved`` for the
+    temporaries the largest program reserved while it ran.  What the chip had
+    to have free is their sum, and that is ``memory_peak_bytes``; the two
+    parts ride along for the per-layer readers."""
+    used = list(devices)[:chips]
+    rows = []
+    for d in used:
+        st = d.memory_stats() or {}
+        live = int(st.get("peak_bytes_in_use", 0))
+        temp = int(st.get("peak_bytes_reserved", 0))
+        rows.append((live + temp, live, temp))
+    total, live, temp = max(rows)
+    print(f"bench memory: fullest chip peak_bytes_in_use {live} + "
+          f"peak_bytes_reserved {temp} = {total}", flush=True)
+    return {"platform": str(used[0].platform), "kind": str(used[0].device_kind),
+            "count": len(devices), "memory_peak_bytes": total,
+            "live_peak_bytes": live, "temp_peak_bytes": temp}
+
+
+def result_line(correct: bool, acct: Accounting, metrics: Dict[str, dict],
+                device: dict, breakdown: Optional[dict] = None) -> str:
+    device = {k: v for k, v in device.items()
+              if k in ("platform", "kind", "count", "memory_peak_bytes",
+                       "busy_s", "window_s")}
+    out = {"correct": bool(correct), "attempted": int(acct.attempted),
+           "failed": int(acct.failed), "metrics": metrics, "device": device}
+    if breakdown is not None:
+        out["breakdown"] = breakdown
+    return json.dumps(out)
+
+
+def select_metrics(wanted: List[dict], values: Dict[str, float]) -> dict:
+    """``{name: {"value", "unit"}}`` for the manifest's metrics of this cell
+    that have a value; a value that is missing is left out, never invented."""
+    out = {}
+    for m in wanted:
+        v = values.get(m["name"])
+        if v is None or not math.isfinite(float(v)):
+            continue
+        out[m["name"]] = {"value": float(v), "unit": m["unit"]}
+    return out
+
+
+def read_per_layer(cell: Cell, ctx: dict) -> Dict[str, float]:
+    values = {}
+    for m in cell.per_layer:
+        try:
+            v = cell.reader(m["name"])(ctx)
+        except ConfigurationError:
+            raise
+        except Exception as e:  # one reader's fault must not lose the run
+            print(f"bench: reader of {m['name']} failed: {e!r}", flush=True)
+            v = None
+        if v is not None:
+            values[m["name"]] = float(v)
+    return values
+

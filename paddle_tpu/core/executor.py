@@ -15,6 +15,7 @@ static-shape/recompile-cache policy SURVEY.md §7 calls out.
 """
 from __future__ import annotations
 
+import re
 import threading
 import time
 import weakref
@@ -103,6 +104,13 @@ def _register_memory_pools() -> None:
     _compile_cache.register_memory_pool()
 
 
+def _program_name(key: str) -> str:
+    """The function name ``run_callable`` compiles ``key`` under:
+    ``decode/lm/prefill/128`` → ``fn_decode_lm_prefill_128``, which jax
+    shows as ``jit_fn_decode_lm_prefill_128`` in a device trace."""
+    return "fn_" + re.sub(r"\W+", "_", key).strip("_")
+
+
 def _em():
     """Cached executor metric handles: registering through the registry
     on every run costs a lock + dict round trip per metric; the handles
@@ -126,6 +134,12 @@ def _em():
             feed_bytes=sc.counter("feed_bytes"),
             fetch_bytes=sc.counter("fetch_bytes"),
             wall=sc.histogram("run_wall_ms"),
+            build=sc.counter(
+                "build_ms",
+                "host ms spent building executables, added to only on an "
+                "executable-cache miss: lowering + jax.jit construction + "
+                "the first, synchronous call (trace, compile or "
+                "persistent-cache load)"),
         )
         _exec_metrics = m
     return m
@@ -558,7 +572,8 @@ class Executor:
         # under this trace id, across processes (distributed/transport
         # carries the context on the wire).  Nested runs (device
         # segments, pserver optimize blocks) become child spans.
-        with _obs_trace.start_span("executor::step", cat="executor"):
+        with _obs_trace.start_span("executor::step", cat="executor"), \
+                _obs_trace.span("executor::run"):
             return self._run_traced(program, feed, fetch_list, scope,
                                     return_numpy, use_program_cache, sync)
 
@@ -596,9 +611,11 @@ class Executor:
         feed_names = sorted(feed)
         block = program.global_block
         feed_vals = []
-        for n in feed_names:
-            var = block.var_or_none(n)
-            feed_vals.append(self._put_feed(_as_device_array(feed[n], var)))
+        with _obs_trace.span("executor::feed"):
+            for n in feed_names:
+                var = block.var_or_none(n)
+                feed_vals.append(
+                    self._put_feed(_as_device_array(feed[n], var)))
 
         sig = self._feed_sig(feed_names, feed_vals)
         base = (program._uid, program._version, tuple(fetch_names),
@@ -627,22 +644,20 @@ class Executor:
         if entry is None:
             t_low0 = time.perf_counter_ns()
             with _obs_trace.start_span("executor::lower", cat="executor",
-                                       root=False):
+                                       root=False), \
+                    _obs_trace.span("executor::lower"):
                 entry = self._build_entry(
                     program, plan, sig, tuple(fetch_names), "run",
                     (feed_vals, donated_state, const_state, rng))
-            t_low1 = time.perf_counter_ns()
+            build_ms = lowering_ms + (time.perf_counter_ns() - t_low0) / 1e6
             # the AOT compile (entry.aot_ms) reports as compile_ms below;
             # keep it out of lowering_ms or a cold first step counts it twice
-            lowering_ms += max(
-                0.0, (t_low1 - t_low0) / 1e6 - (entry.aot_ms or 0.0))
+            lowering_ms = max(0.0, build_ms - (entry.aot_ms or 0.0))
             if use_program_cache:
                 self._cache[key] = entry
                 self._evict_cache_overflow()
             if tel:
                 self._note_cache_miss(base, sig)
-                if _obs_trace.enabled():
-                    _obs_trace.emit("executor::lower", t_low0, t_low1)
         elif tel:
             _em().hits.inc()
         plan, jitted = entry.plan, entry.jitted
@@ -659,9 +674,10 @@ class Executor:
             state_backup = [self._copy_state_val(v) for v in donated_state]
 
         compile_ms = 0.0
-        t_disp0 = time.perf_counter_ns() if tel else None
+        t_disp0 = time.perf_counter_ns() if not cache_hit else None
         with _obs_trace.start_span("executor::dispatch", cat="executor",
-                                   root=False):
+                                   root=False), \
+                _obs_trace.span("executor::dispatch"):
             try:
                 fetches, new_state, rng_out = jitted(feed_vals, donated_state,
                                                      const_state, rng)
@@ -679,18 +695,17 @@ class Executor:
                                                        donated_state)
                     fetches, new_state, rng_out = jitted(
                         feed_vals, donated_state, const_state, rng)
-        if tel:
-            t_disp1 = time.perf_counter_ns()
-            if not cache_hit:
-                # first call of a fresh executable: the synchronous part
-                # is jax trace + XLA compile (execution is async), so this
-                # wall time is the compile cost to within dispatch noise.
-                # AOT-compiled entries (persistent cache) measured their
-                # compile in the lower phase instead; disk hits paid none.
-                compile_ms = (entry.aot_ms if entry.aot_ms is not None
-                              else (t_disp1 - t_disp0) / 1e6)
-            if _obs_trace.enabled():
-                _obs_trace.emit("executor::dispatch", t_disp0, t_disp1)
+        if t_disp0 is not None:
+            # first call of a fresh executable: the synchronous part
+            # is jax trace + XLA compile (execution is async), so this
+            # wall time is the compile cost to within dispatch noise.
+            # AOT-compiled entries (persistent cache) measured their
+            # compile in the lower phase instead; disk hits paid none.
+            first_ms = (time.perf_counter_ns() - t_disp0) / 1e6
+            compile_ms = (entry.aot_ms if entry.aot_ms is not None
+                          else first_ms)
+            if tel:
+                _em().build.inc(build_ms + first_ms)
 
         self._numerics_guard(nc, state_backup, fetch_names, fetches,
                              plan, new_state, scope)
@@ -726,25 +741,26 @@ class Executor:
             print(f"[benchmark] executor run: "
                   f"{(time.perf_counter() - t0) * 1e3:.3f} ms")
 
-        if return_numpy:
-            if sync:
+        if not return_numpy:
+            out = list(fetches)
+        elif sync:
+            with _obs_trace.span("executor::fetch"):
                 out = [self._fetch_to_numpy(v) for v in fetches]
-            else:
-                # async dispatch: wrap plain-array fetches lazily so user
-                # step loops pipeline (one batched readback at first
-                # access).  Fetches that alias persistable state
-                # materialize NOW — the next run() donates that state's
-                # buffer, and a deferred read of a donated buffer would
-                # raise.
-                persist = set(plan.persist_writes) | set(plan.donated_reads)
-                out = []
+        else:
+            # async dispatch: wrap plain-array fetches lazily so user
+            # step loops pipeline (one batched readback at first
+            # access).  Fetches that alias persistable state
+            # materialize NOW — the next run() donates that state's
+            # buffer, and a deferred read of a donated buffer would
+            # raise.
+            persist = set(plan.persist_writes) | set(plan.donated_reads)
+            out = []
+            with _obs_trace.span("executor::fetch"):
                 for name, v in zip(fetch_names, fetches):
                     if (isinstance(v, jax.Array) and name not in persist):
                         out.append(LazyFetch(v))
                     else:
                         out.append(self._fetch_to_numpy(v))
-        else:
-            out = list(fetches)
         if tel:
             self._record_step(entry, key, cache_hit, lowering_ms,
                               compile_ms, feed_vals, fetches, t_run0, plan,
@@ -791,14 +807,23 @@ class Executor:
         No persistent-cache tier: a callable has no canonical program
         fingerprint to key a disk entry by.
 
+        The compiled program is named after ``key`` (``decode/lm/step``
+        → ``jit_fn_decode_lm_step``), so a device trace shows every
+        callable under its own name.
+
         Returns ``(outs, new_state)`` as device arrays (wrap in
         ``np.asarray`` to materialize)."""
-        feed = [v if isinstance(v, jax.Array) else jnp.asarray(v)
-                for v in feed]
-        state = list(state)
-        const = list(const)
+        with _obs_trace.span("executor::run_callable", key=key):
+            return self._run_callable(key, build_fn, feed, state, const)
+
+    def _run_callable(self, key, build_fn, feed, state, const):
         tel = _obs_trace.flags_on()
         t_run0 = time.perf_counter_ns() if tel else None
+        with _obs_trace.span("executor::feed"):
+            feed = [v if isinstance(v, jax.Array) else jnp.asarray(v)
+                    for v in feed]
+        state = list(state)
+        const = list(const)
         sig = (self._feed_sig([str(i) for i in range(len(feed))], feed)
                + self._feed_sig([f"s{i}" for i in range(len(state))], state)
                + self._feed_sig([f"c{i}" for i in range(len(const))], const))
@@ -809,7 +834,10 @@ class Executor:
         lowering_ms = 0.0
         if entry is None:
             t_low0 = time.perf_counter_ns()
-            jitted = jax.jit(build_fn(), donate_argnums=(1,))
+            with _obs_trace.span("executor::lower", key=key):
+                fn = build_fn()
+                fn.__name__ = fn.__qualname__ = _program_name(key)
+                jitted = jax.jit(fn, donate_argnums=(1,))
             lowering_ms = (time.perf_counter_ns() - t_low0) / 1e6
             entry = _CacheEntry(None, jitted)
             self._cache[mem_key] = entry
@@ -819,16 +847,18 @@ class Executor:
         elif tel:
             _em().hits.inc()
         compile_ms = 0.0
-        t_disp0 = time.perf_counter_ns() if tel else None
+        t_disp0 = time.perf_counter_ns() if not cache_hit else None
         with _obs_trace.start_span("executor::dispatch", cat="executor",
-                                   root=False):
+                                   root=False), \
+                _obs_trace.span("executor::dispatch", key=key):
             outs, new_state = entry.jitted(feed, state, const)
+        if t_disp0 is not None:
+            # first call of a fresh executable: the synchronous part
+            # is jax trace + XLA compile (execution is async)
+            compile_ms = (time.perf_counter_ns() - t_disp0) / 1e6
+            if tel:
+                _em().build.inc(lowering_ms + compile_ms)
         if tel:
-            t_disp1 = time.perf_counter_ns()
-            if not cache_hit:
-                # first call of a fresh executable: the synchronous part
-                # is jax trace + XLA compile (execution is async)
-                compile_ms = (t_disp1 - t_disp0) / 1e6
             m = _em()
             m.steps.inc()
             wall_ms = (time.perf_counter_ns() - t_run0) / 1e6
@@ -862,6 +892,11 @@ class Executor:
         reference's C++ executor loop over a pre-fed data queue — and the
         steady-state loop bench.py measures.
         """
+        with _obs_trace.span("executor::run_steps"):
+            return self._run_steps(program, feed, fetch_list, scope,
+                                   return_numpy)
+
+    def _run_steps(self, program, feed, fetch_list, scope, return_numpy):
         program = program if program is not None else default_main_program()
         feed = feed or {}
         from ..lod_tensor import LoDTensor
@@ -905,11 +940,12 @@ class Executor:
                 f"stacked feeds disagree on the step count: { {n: np.asarray(feed[n]).shape[0] for n in feed_names} }")
         (K,) = ks
         stacked = []
-        for n in feed_names:
-            var = block.var_or_none(n)
-            arr = np.asarray(feed[n])
-            steps = [_as_device_array(a, var) for a in arr]
-            stacked.append(jax.device_put(np.stack(steps)))
+        with _obs_trace.span("executor::feed"):
+            for n in feed_names:
+                var = block.var_or_none(n)
+                arr = np.asarray(feed[n])
+                steps = [_as_device_array(a, var) for a in arr]
+                stacked.append(jax.device_put(np.stack(steps)))
 
         sig = self._feed_sig(feed_names, stacked)
         base = (program._uid, program._version, tuple(fetch_names),
@@ -938,22 +974,21 @@ class Executor:
 
         if entry is None:
             t_low0 = time.perf_counter_ns()
-            build = self._make_scan_builder(program, plan)
-            entry = self._build_entry(
-                program, plan, sig, tuple(fetch_names), "run_steps",
-                (stacked, donated_state, const_state, rng), build_fn=build)
+            with _obs_trace.span("executor::lower"):
+                build = self._make_scan_builder(program, plan)
+                entry = self._build_entry(
+                    program, plan, sig, tuple(fetch_names), "run_steps",
+                    (stacked, donated_state, const_state, rng),
+                    build_fn=build)
             self._cache[key] = entry
             self._evict_cache_overflow()
-            t_low1 = time.perf_counter_ns()
+            build_ms = lowering_ms + (time.perf_counter_ns() - t_low0) / 1e6
             # AOT compile time reports as compile_ms, not lowering.
             # Unconditional like run()'s: _perf_wall_ms subtracts
             # lowering_ms from cold perf-record walls even when tel off
-            lowering_ms += max(
-                0.0, (t_low1 - t_low0) / 1e6 - (entry.aot_ms or 0.0))
+            lowering_ms = max(0.0, build_ms - (entry.aot_ms or 0.0))
             if tel:
                 self._note_cache_miss(base, sig)
-                if _obs_trace.enabled():
-                    _obs_trace.emit("executor::lower", t_low0, t_low1)
         elif tel:
             _em().hits.inc()
         plan, jitted = entry.plan, entry.jitted
@@ -965,11 +1000,12 @@ class Executor:
             state_backup = [self._copy_state_val(v) for v in donated_state]
 
         compile_ms = 0.0
-        t_disp0 = time.perf_counter_ns() if tel else None
+        t_disp0 = time.perf_counter_ns() if not cache_hit else None
         # run_steps admits no host ops, so the K-step dispatch IS the
         # step: one root span (head-sampled like run()'s)
         with _obs_trace.start_span("executor::step", cat="executor",
-                                   tags={"k_steps": K}):
+                                   tags={"k_steps": K}), \
+                _obs_trace.span("executor::dispatch", k_steps=K):
             try:
                 fetches, new_state, rng_out = jitted(stacked, donated_state,
                                                      const_state, rng)
@@ -989,13 +1025,12 @@ class Executor:
                                                          entry.plan))
                     fetches, new_state, rng_out = jitted(
                         stacked, donated_state, const_state, rng)
-        if tel:
-            t_disp1 = time.perf_counter_ns()
-            if not cache_hit:
-                compile_ms = (entry.aot_ms if entry.aot_ms is not None
-                              else (t_disp1 - t_disp0) / 1e6)
-            if _obs_trace.enabled():
-                _obs_trace.emit("executor::dispatch", t_disp0, t_disp1)
+        if t_disp0 is not None:
+            first_ms = (time.perf_counter_ns() - t_disp0) / 1e6
+            compile_ms = (entry.aot_ms if entry.aot_ms is not None
+                          else first_ms)
+            if tel:
+                _em().build.inc(build_ms + first_ms)
         self._numerics_guard(nc, state_backup, fetch_names, fetches,
                              plan, new_state, scope)
         for name, val in zip(plan.persist_writes, new_state):
@@ -1004,7 +1039,8 @@ class Executor:
         if plan.has_stateful:
             scope.set_var(RNG_STATE_VAR, rng_out)
         if return_numpy:
-            out = [np.asarray(v) for v in fetches]
+            with _obs_trace.span("executor::fetch"):
+                out = [np.asarray(v) for v in fetches]
         else:
             out = list(fetches)
         if tel:
@@ -1633,8 +1669,7 @@ class Executor:
                      compile_ms: float, feed_vals, fetches,
                      t_run0_ns: int, plan, donated_state,
                      program: Optional[Program] = None) -> None:
-        t_now = time.perf_counter_ns()
-        wall_ms = (t_now - t_run0_ns) / 1e6
+        wall_ms = (time.perf_counter_ns() - t_run0_ns) / 1e6
         meta = entry.meta
         if meta is None:
             # once per executable: the cache key pins every feed/fetch
@@ -1663,8 +1698,6 @@ class Executor:
         m.wall.observe(wall_ms)
         m.feed_bytes.inc(ss.feed_bytes)
         m.fetch_bytes.inc(ss.fetch_bytes)
-        if _obs_trace.enabled():
-            _obs_trace.emit("executor::run", t_run0_ns, t_now)
         self._post_step_telemetry(ss, plan, donated_state)
 
     def _post_step_telemetry(self, ss, plan, donated_state) -> None:

@@ -60,6 +60,13 @@ class BlockPlan:
 
 def analyze_block(program: Program, block_idx: int, feed_names: Sequence[str],
                   fetch_names: Sequence[str]) -> BlockPlan:
+    with _obs_trace.span("lowering::analyze"):
+        return _analyze_block(program, block_idx, feed_names, fetch_names)
+
+
+def _analyze_block(program: Program, block_idx: int,
+                   feed_names: Sequence[str],
+                   fetch_names: Sequence[str]) -> BlockPlan:
     t0 = time.perf_counter_ns() if _telemetry_on() else None
     plan = BlockPlan(block_idx, tuple(feed_names), tuple(fetch_names))
     seen_reads = set()
@@ -107,11 +114,8 @@ def analyze_block(program: Program, block_idx: int, feed_names: Sequence[str],
             seen_reads.add(n)
             plan.state_reads.append(n)
     if t0 is not None:
-        t1 = time.perf_counter_ns()
         _obs_stats.scope("lowering").histogram("analyze_ms").observe(
-            (t1 - t0) / 1e6)
-        if _obs_trace.enabled():
-            _obs_trace.emit("lowering::analyze", t0, t1)
+            (time.perf_counter_ns() - t0) / 1e6)
     return plan
 
 
@@ -224,7 +228,8 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
         env.update(zip(plan.feed_names, feed_vals))
         env.update(zip(donated, donated_state))
         env.update(zip(const, const_state))
-        lower_ops(ctx, program, block, env)
+        with _obs_trace.span("lowering::trace"):
+            lower_ops(ctx, program, block, env)
         if getattr(ctx, "sparse_fused_used", False):
             used["sparse_fused"] = True
         if getattr(ctx, "int8_fused_used", False):
@@ -232,11 +237,8 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
         fetches = [env[n] for n in plan.fetch_names]
         new_state = [env[n] for n in plan.persist_writes]
         if t0 is not None:
-            t1 = time.perf_counter_ns()
             _obs_stats.scope("lowering").histogram("trace_ms").observe(
-                (t1 - t0) / 1e6)
-            if _obs_trace.enabled():
-                _obs_trace.emit("lowering::trace", t0, t1)
+                (time.perf_counter_ns() - t0) / 1e6)
         return fetches, new_state, ctx.rng_key
 
     fn._sparse_fused_used = used

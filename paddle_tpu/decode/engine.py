@@ -89,6 +89,7 @@ from ..observability import memory as _memory
 from ..observability import phase as _phase
 from ..observability import stats as _obs_stats
 from ..observability import tenant as _tenant
+from ..observability import trace as _trace
 from ..serving.batcher import BucketLadder, Overloaded, RequestTooLong
 
 # decode request phases (FLAGS_phase_attribution): queue = submit ->
@@ -142,7 +143,7 @@ class DecodeRequest:
         # non-None value marks a queued request as a RESUME (re-prefill
         # prompt + resume_tokens[:-1], then continue token-exact)
         self.resume_tokens: Optional[List[int]] = None
-        self.t_enq = time.monotonic()
+        self.t_enq = time.perf_counter()   # the engine's one clock
         self.handle = DecodeHandle(rid)
         # phase timeline sharing the enqueue stamp (flag-gated; None
         # keeps the flag-off path allocation-free)
@@ -252,7 +253,7 @@ class _Slot:
         self.pos_next = prompt_len   # where the last sampled token's
         self.n_generated = 1         # K/V lands on the next step
         self.last_token = first_token
-        self.t_last = time.monotonic()
+        self.t_last = time.perf_counter()
         # prefix-cache / resume bookkeeping (0 / None on the legacy
         # path): positions [0, cached_tokens) are already resident in
         # adopted blocks; ``seq`` is the full token sequence prefill
@@ -392,6 +393,11 @@ class _EngineStats:
         self.blocks_free = sc.gauge("blocks_free")
         self.step_ms = sc.histogram("step_ms")
         self.prefill_ms = sc.histogram("prefill_ms")
+        self.queue_ms = sc.histogram(
+            "queue_ms",
+            help_str="submit -> the engine thread starts the request's "
+                     "prefill: wait for a slot, for blocks and for the "
+                     "dispatches ahead of it")
         self.token_ms = sc.histogram(
             "token_ms",
             help_str="per-stream inter-token interval (what a client "
@@ -598,15 +604,22 @@ class DecodeEngine:
     def _loop(self) -> None:
         while True:
             with self._lock:
-                while not self._closed and not self._pending and \
+                if not self._closed and not self._pending and \
                         not any(self._slots):
-                    self._lock.wait()
+                    with _trace.span("decode::wait_work"):
+                        while not self._closed and not self._pending \
+                                and not any(self._slots):
+                            self._lock.wait()
                 if self._closed:
                     pending = self._pending
                     self._pending = []
                     break_slots = [s for s in self._slots if s is not None]
                     break
-                admit = self._admissible_locked()
+            with _trace.span("decode::admit") as sp:
+                with self._lock:
+                    waiting = len(self._pending)
+                    admit = self._admissible_locked()
+                sp.annotate(admitted=len(admit), pending=waiting)
             for req in admit:
                 try:
                     self._prefill(req)
@@ -711,7 +724,8 @@ class DecodeEngine:
                                    cached_tokens=start,
                                    seq=seq if (start or resume) else None)
             if req.tl is not None and not resume:
-                req.tl.stamp("queue")   # queue wait ends at slot claim
+                # queue wait ends at slot claim
+                req.tl.stamp("queue", t=time.perf_counter())
             if not resume:
                 self.stats.joins.inc()   # every join has a matching
             out.append(req)              # leave through _retire
@@ -751,15 +765,23 @@ class DecodeEngine:
         if req.handle.cancelled:   # client vanished between admit and here
             self._retire(i, slot, "cancelled")
             return
+        queue_ms = (t0 - req.t_enq) * 1e3
+        self.stats.queue_ms.observe(queue_ms)
         resume = req.resume_tokens is not None
         start = slot.cached_tokens
         if resume or start > 0:
-            self._prefill_partial(i, slot, req, t0)
+            with _trace.span("decode::prefill_partial", rid=req.rid,
+                             queue_ms=queue_ms):
+                self._prefill_partial(i, slot, req, t0)
             return
         P = req.prompt.size
         bucket = self.prefill_ladder.snap(P)
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :P] = req.prompt
+        with _trace.span("decode::prefill", rid=req.rid, bucket=bucket,
+                         prompt=P, queue_ms=queue_ms):
+            self._prefill_full(i, slot, req, t0, P, bucket)
+
+    def _prefill_full(self, i: int, slot: _Slot, req: DecodeRequest,
+                      t0: float, P: int, bucket: int) -> None:
         model, quantized = self.model, self.cache.quantized
 
         def build():
@@ -774,46 +796,51 @@ class DecodeEngine:
                 return [tok, logits], [kc, vc]
             return fn
 
-        feed = [tokens,
-                np.int32(P),
-                self._tables[i].copy(),
-                np.uint32(req.sampling.seed & 0xFFFFFFFF),
-                np.float32(req.sampling.temperature),
-                np.int32(req.sampling.top_k)]
-        _debug_server.note_activity("decode")
-        # chaos hook: `delay:decode_prefill` sleeps here, inside the
-        # prefill phase / TTFT window (the SLO-watchdog test's lever)
-        _faults.event("decode_prefill")
+        with _trace.span("decode::prefill.feed"):
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :P] = req.prompt
+            feed = [tokens,
+                    np.int32(P),
+                    self._tables[i].copy(),
+                    np.uint32(req.sampling.seed & 0xFFFFFFFF),
+                    np.float32(req.sampling.temperature),
+                    np.int32(req.sampling.top_k)]
+            _debug_server.note_activity("decode")
+            # chaos hook: `delay:decode_prefill` sleeps here, inside the
+            # prefill phase / TTFT window (the SLO-watchdog test's lever)
+            _faults.event("decode_prefill")
         (tok, logits), new_state = self._exe.run_callable(
             f"decode/{self.name}/prefill/{bucket}", build, feed,
             state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
-        first = int(np.asarray(tok))
-        slot.last_token = first
-        slot.t_last = time.monotonic()
-        self.stats.prefills.inc()
-        self.stats.tokens.inc()
-        prefill_ms = (time.perf_counter() - t0) * 1e3
-        self.stats.prefill_ms.observe(prefill_ms)
-        if _capacity.enabled():
-            # the engine thread is serial: prefill wall IS busy time
-            self.stats.capacity_tracker().note(
-                "prefill", prefill_ms, bucket=bucket, work=1)
-        if _tenant.enabled():
-            # a prefill serves exactly one request: its whole wall is
-            # that tenant's device time
-            _tenant.account(req.tenant, prefill_tokens=P,
-                            device_ms=prefill_ms)
-        if req.tl is not None:
-            req.tl.stamp("prefill", t=slot.t_last)
-            lat = self.stats.latency()
-            lat.ttft_ms.observe((slot.t_last - req.t_enq) * 1e3)
-            lat.prefill_tokens.inc(P)
-            lat.pad_prefill_tokens.inc(bucket - P)
-        self._register_prefix(slot, req.prompt)
-        req.handle._emit(
-            first, np.asarray(logits) if self.capture_logits else None)
-        self._maybe_finish(i, slot, first)
+        with _trace.span("decode::prefill.wait"):
+            first = int(np.asarray(tok))
+            logits_np = np.asarray(logits) if self.capture_logits else None
+        with _trace.span("decode::prefill.emit"):
+            slot.last_token = first
+            slot.t_last = time.perf_counter()
+            self.stats.prefills.inc()
+            self.stats.tokens.inc()
+            prefill_ms = (slot.t_last - t0) * 1e3
+            self.stats.prefill_ms.observe(prefill_ms)
+            if _capacity.enabled():
+                # the engine thread is serial: prefill wall IS busy time
+                self.stats.capacity_tracker().note(
+                    "prefill", prefill_ms, bucket=bucket, work=1)
+            if _tenant.enabled():
+                # a prefill serves exactly one request: its whole wall is
+                # that tenant's device time
+                _tenant.account(req.tenant, prefill_tokens=P,
+                                device_ms=prefill_ms)
+            if req.tl is not None:
+                req.tl.stamp("prefill", t=slot.t_last)
+                lat = self.stats.latency()
+                lat.ttft_ms.observe((slot.t_last - req.t_enq) * 1e3)
+                lat.prefill_tokens.inc(P)
+                lat.pad_prefill_tokens.inc(bucket - P)
+            self._register_prefix(slot, req.prompt)
+            req.handle._emit(first, logits_np)
+            self._maybe_finish(i, slot, first)
 
     def _prefill_partial(self, i: int, slot: _Slot, req: DecodeRequest,
                          t0: float) -> None:
@@ -888,9 +915,9 @@ class DecodeEngine:
         (tok, logits), new_state = self._exe.run_callable(
             key, build, feed, state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
-        slot.t_last = time.monotonic()
+        slot.t_last = time.perf_counter()
         self.stats.prefills.inc()
-        prefill_ms = (time.perf_counter() - t0) * 1e3
+        prefill_ms = (slot.t_last - t0) * 1e3
         self.stats.prefill_ms.observe(prefill_ms)
         if _capacity.enabled():
             self.stats.capacity_tracker().note(
@@ -918,11 +945,12 @@ class DecodeEngine:
             self._pstats.preempt_resumes.inc()
             self._pstats.reprefill_tokens.inc(n)
             return
-        first = int(np.asarray(tok))
+        with _trace.span("decode::prefill_partial.wait"):
+            first = int(np.asarray(tok))
+            logits_np = np.asarray(logits) if self.capture_logits else None
         slot.last_token = first
         self.stats.tokens.inc()
-        req.handle._emit(
-            first, np.asarray(logits) if self.capture_logits else None)
+        req.handle._emit(first, logits_np)
         self._maybe_finish(i, slot, first)
 
     def _register_prefix(self, slot: _Slot, seq: np.ndarray) -> None:
@@ -944,35 +972,42 @@ class DecodeEngine:
             self._pstats.prefix_inserts.inc(inserted)
 
     def _decode_step(self) -> None:
+        with _trace.span("decode::step") as sp:
+            self._decode_step_traced(sp)
+
+    def _decode_step_traced(self, sp) -> None:
         t0 = time.perf_counter()
-        # retire cancelled slots FIRST: their blocks free before this
-        # step's admission sweep ran, and they must not burn a batch
-        # lane generating for a vanished reader
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot.req.handle.cancelled:
-                self._retire(i, slot, "cancelled")
-        if self._refc:
-            # overcommit growth + copy-on-write forks (may preempt)
-            self._ensure_blocks()
-        tokens = np.zeros((self.max_slots,), np.int32)
-        positions = np.zeros((self.max_slots,), np.int32)
-        seeds = np.zeros((self.max_slots,), np.uint32)
-        steps = np.zeros((self.max_slots,), np.int32)
-        temps = np.zeros((self.max_slots,), np.float32)
-        topks = np.zeros((self.max_slots,), np.int32)
-        tables = self._tables.copy()
-        live = []
-        for i, slot in enumerate(self._slots):
-            if slot is None:
-                tables[i, :] = 0   # trash block: masked garbage
-                continue
-            live.append(i)
-            tokens[i] = slot.last_token
-            positions[i] = slot.pos_next
-            seeds[i] = slot.req.sampling.seed & 0xFFFFFFFF
-            steps[i] = slot.n_generated   # this dispatch samples token
-            temps[i] = slot.req.sampling.temperature  # index n_generated
-            topks[i] = slot.req.sampling.top_k
+        with _trace.span("decode::step.retire"):
+            # retire cancelled slots FIRST: their blocks free before this
+            # step's admission sweep ran, and they must not burn a batch
+            # lane generating for a vanished reader
+            for i, slot in enumerate(self._slots):
+                if slot is not None and slot.req.handle.cancelled:
+                    self._retire(i, slot, "cancelled")
+            if self._refc:
+                # overcommit growth + copy-on-write forks (may preempt)
+                self._ensure_blocks()
+        with _trace.span("decode::step.feed"):
+            tokens = np.zeros((self.max_slots,), np.int32)
+            positions = np.zeros((self.max_slots,), np.int32)
+            seeds = np.zeros((self.max_slots,), np.uint32)
+            steps = np.zeros((self.max_slots,), np.int32)
+            temps = np.zeros((self.max_slots,), np.float32)
+            topks = np.zeros((self.max_slots,), np.int32)
+            tables = self._tables.copy()
+            live = []
+            for i, slot in enumerate(self._slots):
+                if slot is None:
+                    tables[i, :] = 0   # trash block: masked garbage
+                    continue
+                live.append(i)
+                tokens[i] = slot.last_token
+                positions[i] = slot.pos_next
+                seeds[i] = slot.req.sampling.seed & 0xFFFFFFFF
+                steps[i] = slot.n_generated   # this dispatch samples token
+                temps[i] = slot.req.sampling.temperature  # index n_generated
+                topks[i] = slot.req.sampling.top_k
+        sp.annotate(live=len(live))
         if not live:
             return
         model, impl = self.model, self._attn_impl
@@ -1004,11 +1039,19 @@ class DecodeEngine:
             [tokens, positions, tables, seeds, steps, temps, topks],
             state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
-        toks_np = np.asarray(toks)
-        logits_np = np.asarray(logits) if self.capture_logits else None
-        now = time.monotonic()
+        with _trace.span("decode::step.wait"):
+            toks_np = np.asarray(toks)
+            logits_np = np.asarray(logits) if self.capture_logits else None
+        with _trace.span("decode::step.emit"):
+            self._emit_step(live, toks_np, logits_np, t0)
+
+    def _emit_step(self, live: List[int], toks_np: np.ndarray,
+                   logits_np: Optional[np.ndarray], t0: float) -> None:
+        """Hand one step's tokens to their streams: counters, the
+        per-slot bookkeeping, ``handle._emit``, retirement."""
+        now = time.perf_counter()
         self.stats.steps.inc()
-        step_ms = (time.perf_counter() - t0) * 1e3
+        step_ms = (now - t0) * 1e3
         self.stats.step_ms.observe(step_ms)
         if _capacity.enabled():
             self.stats.capacity_tracker().note(
@@ -1156,11 +1199,12 @@ class DecodeEngine:
                 return [], [a.at[:, d].set(a[:, s]) for a in state]
             return fn
 
-        _, new_state = self._exe.run_callable(
-            f"decode/{self.name}/blkcopy", build,
-            [np.int32(src), np.int32(dst)],
-            state=self.cache.state(), const=[])
-        self.cache.update(new_state)
+        with _trace.span("decode::copy_block", src=src, dst=dst):
+            _, new_state = self._exe.run_callable(
+                f"decode/{self.name}/blkcopy", build,
+                [np.int32(src), np.int32(dst)],
+                state=self.cache.state(), const=[])
+            self.cache.update(new_state)
 
     def _update_pool_gauges(self) -> None:
         if not self._refc:
@@ -1203,7 +1247,7 @@ class DecodeEngine:
                 req.tenant,
                 cancellations=1 if reason == "cancelled" else 0,
                 resident_kv_bytes=-(len(slot.blocks) * self._block_bytes),
-                latency_ms=(time.monotonic() - req.t_enq) * 1e3)
+                latency_ms=(time.perf_counter() - req.t_enq) * 1e3)
         if req.tl is not None:
             lat = self.stats.latency()
             if reason == "cancelled":
@@ -1212,7 +1256,7 @@ class DecodeEngine:
             # close the decode phase (zero-width for a stream finished
             # at its first token) and fold the timeline in: the three
             # phases sum to this request's end-to-end wall
-            req.tl.stamp("decode")
+            req.tl.stamp("decode", t=time.perf_counter())
             lat.phases.observe(req.tl, rid=req.rid, finish=reason,
                                tokens=slot.n_generated)
         if _audit.enabled() and reason != "cancelled":
@@ -1425,6 +1469,9 @@ class DecodeEngine:
         if tsnap.get("count"):
             out["token_p50_ms"] = self.stats.token_ms.percentile(0.50)
             out["token_p99_ms"] = self.stats.token_ms.percentile(0.99)
+        if self.stats.queue_ms.count:
+            out["queue_p50_ms"] = self.stats.queue_ms.percentile(0.50)
+            out["queue_p99_ms"] = self.stats.queue_ms.percentile(0.99)
         lat = self.stats.lat
         if lat is not None:
             # the FLAGS_phase_attribution plane: TTFT/TBT tails,

@@ -411,6 +411,7 @@ def _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate=0.0,
         ones_lane=ones_lane, head_dim=D)
     out, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         out_shape=[
             _sds((B * H, Tq_p, D), q.dtype, qf),
             _sds((B * H, 1, Tq_p), jnp.float32, qf),
@@ -571,6 +572,7 @@ def _pallas_bwd(q, k, v, kv_mask, out, lse, g, causal, sm_scale,
         num_kb=num_kb, has_mask=has_mask)
     dq = pl.pallas_call(
         dq_kernel,
+        name="flash_bwd_dq",
         out_shape=_sds((B * H, Tq_p, D), q.dtype, qf),
         grid=(B * H, num_qb, num_kb),
         in_specs=[
@@ -605,6 +607,7 @@ def _pallas_bwd(q, k, v, kv_mask, out, lse, g, causal, sm_scale,
         num_qb=num_qb, has_mask=has_mask)
     dk, dv = pl.pallas_call(
         dkv_kernel,
+        name="flash_bwd_dkv",
         out_shape=[
             _sds((B * H, Tk_p, D), k.dtype, kf),
             _sds((B * H, Tk_p, D), v.dtype, kf),
@@ -1051,6 +1054,7 @@ def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
                                sm_scale=sm_scale, quantized=quantized)
     return pl.pallas_call(
         kernel,
+        name="paged_decode_attn",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
         interpret=interpret,

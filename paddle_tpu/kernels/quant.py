@@ -202,6 +202,7 @@ def int8_fc(x, w_q, w_scale, in_scale: float = 0.0, bias=None,
              else jnp.zeros((n,), jnp.float32))
         out = pl.pallas_call(
             functools.partial(_fc_kernel, act=act),
+            name="int8_fc",
             out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
             interpret=interpret,
         )(xq, w_q, dq.reshape(1, n), b.reshape(1, n))

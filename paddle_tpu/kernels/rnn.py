@@ -170,6 +170,7 @@ def lstm_fused(xproj, w, h0, c0, mask, interpret=None, reverse=False):
     kernel = functools.partial(_lstm_fwd_kernel, T=T)
     hs, cs = pl.pallas_call(
         kernel,
+        name="lstm_cell",
         out_shape=[jax.ShapeDtypeStruct((T, B, H), xproj.dtype),
                    jax.ShapeDtypeStruct((T, B, H), xproj.dtype)],
         grid=(T,),
@@ -207,6 +208,7 @@ def lstm_fused_grad(xproj, w, h0, c0, mask, hs, cs, dhs, dcs,
 
     dxs, dw, dh0, dc0 = pl.pallas_call(
         kernel,
+        name="lstm_cell_bwd",
         out_shape=[jax.ShapeDtypeStruct((T, B, H4), xproj.dtype),
                    jax.ShapeDtypeStruct((H, H4), w.dtype),
                    jax.ShapeDtypeStruct((B, H), xproj.dtype),
@@ -343,6 +345,7 @@ def gru_fused(xproj, w, h0, mask, interpret=None, reverse=False):
     kernel = functools.partial(_gru_fwd_kernel, T=T)
     hs = pl.pallas_call(
         kernel,
+        name="gru_cell",
         out_shape=jax.ShapeDtypeStruct((T, B, H), xproj.dtype),
         grid=(T,),
         in_specs=[
@@ -374,6 +377,7 @@ def gru_fused_grad(xproj, w, h0, mask, hs, dhs, interpret=None,
 
     dxs, dw, dh0 = pl.pallas_call(
         kernel,
+        name="gru_cell_bwd",
         out_shape=[jax.ShapeDtypeStruct((T, B, H3), xproj.dtype),
                    jax.ShapeDtypeStruct((H, H3), w.dtype),
                    jax.ShapeDtypeStruct((B, H), xproj.dtype)],

@@ -226,6 +226,7 @@ def fused_gather(tables, ids, interpret=None):
         )
         outs = pl.pallas_call(
             functools.partial(_gather_kernel, k=k),
+            name="fused_gather",
             grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct((n, int(t.shape[1])), t.dtype)
                        for t in tables],
@@ -313,6 +314,7 @@ def _rowwise_update(sr, tables, scalars, math_fn, interpret=None):
     aliases = {5 + t: t for t in range(k)}
     outs = pl.pallas_call(
         functools.partial(_update_kernel, k=k, math_fn=math_fn),
+        name="fused_sparse_update",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((h, d), t.dtype) for t in tables],
         input_output_aliases=aliases,

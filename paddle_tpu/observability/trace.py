@@ -1,12 +1,23 @@
-"""Runtime spans: profiler-stream spans + distributed trace propagation.
+"""Runtime spans: the one span primitive + distributed trace propagation.
 
 Two cooperating layers live here:
 
-**Profiler-stream spans** (the original role): the runtime feeds the
-``paddle_tpu.profiler`` event stream under a ``runtime::`` name prefix
-so ``profiler.chrome_trace()`` shows the lower→jit→dispatch pipeline
-interleaved with user ``train_step`` spans.  Recorded only when the
-profiler is armed AND ``FLAGS_runtime_stats`` is on.
+**Program spans** — :func:`span` is the one way the runtime marks a
+region (``executor::run``, ``executor::dispatch``, ``decode::step``,
+``decode::step.emit``, …).  It opens a ``jax.profiler.TraceAnnotation``
+(through ``profiler.RecordEvent``), which is a no-op unless a profiler
+session is live — whoever started it: ``profiler.start_profiler()`` after
+``enable_device_trace``, an operator's ``jax.profiler.start_trace``, the
+benchmark's ``--trace 1``.  The spans then land in the same
+``.xplane.pb`` and time base as the device's ``XLA Ops`` and
+``XLA Modules`` lines (the device plane leads the host's by a fraction
+of a millisecond on a v5e), so an idle gap of the device can be put
+under the name of what the host was doing.  Nesting on a thread gives the
+parent; keyword arguments carry the identifiers (``rid``, ``bucket``,
+``key``).  While ``paddle_tpu.profiler`` is armed the span is also filed
+in that module's event list under a ``runtime::`` prefix, so
+``profiler.chrome_trace()`` shows the lower→dispatch pipeline beside
+user ``train_step`` spans.  Gated by ``FLAGS_runtime_stats`` alone.
 
 **Distributed tracing** (Dapper-style): a :class:`SpanContext`
 (trace id, span id, sampled bit) rides a thread-local stack; the
@@ -19,14 +30,17 @@ pserver's apply land under ONE trace id across processes.  Completed
 spans go to a bounded in-memory ring (``FLAGS_trace_ring_spans``)
 served over the ``TRACE_PULL`` RPC and the ``/tracez`` debug page;
 ``stitch_chrome_trace`` merges per-worker rings into one
-Chrome/Perfetto JSON with real ``pid``/process-name metadata.
+Chrome/Perfetto JSON with real ``pid``/process-name metadata.  This is
+the cross-process tool; program spans are the on-chip one.
 
-Overhead discipline: with sampling off (``FLAGS_trace_sample_rate=0``,
-the default) ``start_span`` is a thread-local read plus two dict
-lookups and returns a shared no-op — no ring writes, no wire bytes.
-Span timestamps use ``time.time_ns()`` (the wall clock), the one clock
-processes on a host share, so stitched timelines align without offset
-fitting.
+Overhead discipline: with no profiler session and the profiler unarmed
+a program span costs about a microsecond (two small objects and an
+inactive annotation) and leaves nothing behind; with sampling off
+(``FLAGS_trace_sample_rate=0``, the default) ``start_span`` is a
+thread-local read plus two dict lookups and returns a shared no-op —
+no ring writes, no wire bytes.  Ring-span timestamps use
+``time.time_ns()`` (the wall clock), the one clock processes on a host
+share, so stitched timelines align without offset fitting.
 """
 from __future__ import annotations
 
@@ -48,6 +62,22 @@ from ..core import flags as _flags
 CATEGORY = "runtime"
 PREFIX = "runtime::"
 
+class _NoSpan:
+    """The shared no-op both kinds of span return when off."""
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc):
+        return False
+
+    def annotate(self, **args) -> None:
+        pass
+
+
+_NOOP = _NoSpan()
+NOOP = _NOOP  # callers that pre-check current() reuse the shared no-op
+
 
 def flags_on() -> bool:
     """The one FLAGS_runtime_stats gate — every instrumentation site
@@ -59,31 +89,18 @@ def flags_on() -> bool:
         return False
 
 
-def enabled() -> bool:
-    # profiler check first: it is False in steady state, so the common
-    # path is one dict lookup
-    return _profiler.is_profiler_enabled() and flags_on()
+class _RuntimeSpan(_profiler.RecordEvent):
+    cat = CATEGORY
+    prefix = PREFIX
 
 
-def emit(name: str, t0_ns: int, t1_ns: int) -> None:
-    """Record an already-timed runtime span (callers that measured a
-    region for stats anyway reuse the timestamps instead of nesting a
-    context manager)."""
-    _profiler._emit(PREFIX + name, t0_ns, t1_ns, cat=CATEGORY)
-
-
-@contextlib.contextmanager
-def span(name: str):
-    """``with trace.span("executor::lower"): ...`` — no-op when disabled."""
-    if not enabled():
-        yield
-        return
-    t0 = time.perf_counter_ns()
-    try:
-        yield
-    finally:
-        _profiler._emit(PREFIX + name, t0, time.perf_counter_ns(),
-                        cat=CATEGORY)
+def span(name: str, **args):
+    """``with trace.span("decode::step", live=3): ...`` — a region of the
+    runtime under its own name, wherever a profiler is listening (module
+    doc).  ``args`` become the annotation's arguments."""
+    if not flags_on():
+        return _NOOP
+    return _RuntimeSpan(name, **args)
 
 
 # ---------------------------------------------------------------------------
@@ -275,10 +292,6 @@ class Span:
 def _resize_ring_locked() -> None:
     global _ring
     _ring = deque(_ring, maxlen=_ring_capacity())
-
-
-_NOOP = contextlib.nullcontext()
-NOOP = _NOOP  # callers that pre-check current() reuse the shared no-op
 
 
 def start_span(name: str, cat: str = "runtime",

@@ -47,6 +47,7 @@ from ..core.program import OP_ROLE_ATTR, OpRole, Program, default_main_program
 from ..core.backward import grad_var_name
 from ..observability import audit as _audit
 from ..observability import stats as _obs_stats
+from ..observability import trace as _obs_trace
 from ..observability.step_stats import approx_nbytes as _approx_nbytes
 from .strategy import (
     BuildStrategy,
@@ -115,6 +116,12 @@ class ParallelExecutor(Executor):
     # -- public API (reference parallel_executor.py:169 signature) ---------
     def run(self, fetch_list=None, feed=None, feed_dict=None,
             return_numpy: bool = True, program=None, scope=None, **kwargs):
+        with _obs_trace.span("parallel_executor::run"):
+            return self._run(fetch_list, feed, feed_dict, return_numpy,
+                             program, scope)
+
+    def _run(self, fetch_list, feed, feed_dict, return_numpy, program,
+             scope):
         # ``program``/``scope`` kwargs: Executor._run_segmented (host-op
         # programs — send/recv/pserver IO) re-enters run() per device
         # segment, so the trainer-mesh + remote-pserver topology runs

@@ -4,11 +4,19 @@ Reference: ``paddle/fluid/platform/profiler.h:73`` (RAII RecordEvent/
 RecordBlock), ``profiler.py:221`` context managers, ``device_tracer.h``
 (CUPTI device records), ``tools/timeline.py`` Chrome-trace conversion.
 
-TPU mapping: host spans are recorded here (same report shape); device-side
-tracing delegates to the XLA profiler (``jax.profiler.start_trace`` →
-xplane/TensorBoard, the CUPTI analogue).  ``chrome_trace`` emits the
-catapult JSON directly — no separate conversion step needed, though
-tools/timeline.py exists for file-based workflows.
+TPU mapping: device-side tracing is the XLA profiler's
+(``enable_device_trace(dir)`` then ``start_profiler()`` starts a
+``jax.profiler`` session → ``.xplane.pb`` for Perfetto/TensorBoard, the
+CUPTI analogue).  Every ``RecordEvent`` and every runtime span
+(``observability.trace.span``) opens a ``jax.profiler.TraceAnnotation``,
+so while ANY profiler session is live — this module's, an operator's
+``jax.profiler.start_trace``, the benchmark's ``--trace 1`` — the host
+spans land in the same file and time base as the device's
+``XLA Ops``/``XLA Modules`` lines.  With no session the annotation is a
+no-op.  While ``start_profiler`` is armed the same spans are also filed
+in this module's own event list, which feeds the printed summary and
+``chrome_trace`` (the reference's report shape; ``tools/timeline.py``
+merges such files).
 """
 from __future__ import annotations
 
@@ -20,6 +28,8 @@ import threading
 import time
 from collections import defaultdict
 from typing import Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _state = {"enabled": False, "tracer_dir": None}
 _events: List[dict] = []
@@ -98,21 +108,41 @@ class RecordEvent:
 
     The decorator opens a FRESH span per call (never the shared instance
     state), so decorated functions are re-entrant and thread-safe.
+    Keyword arguments become the annotation's arguments (event stats in
+    the ``.xplane.pb``).
     """
 
-    def __init__(self, name: str):
+    cat = "op"     # the event list's category and name prefix: the
+    prefix = ""    # runtime's own spans (observability.trace) set both
+
+    def __init__(self, name: str, **args):
         self.name = name
+        self._args = args
+        self._ann = None
         self._t0 = None
 
     def __enter__(self):
+        # the annotation is a no-op unless a jax.profiler session is live;
+        # then the span lands beside the device ops, in their file
+        self._ann = TraceAnnotation(self.name, **self._args)
+        self._ann.__enter__()
         if _state["enabled"]:
             self._t0 = time.perf_counter_ns()
         return self
 
+    def annotate(self, **args) -> None:
+        """Add arguments known only once the region has run."""
+        if self._ann is not None:
+            self._ann.set_metadata(**args)
+
     def __exit__(self, *a):
         if self._t0 is not None:
-            _emit(self.name, self._t0, time.perf_counter_ns())
+            _emit(self.prefix + self.name, self._t0,
+                  time.perf_counter_ns(), cat=self.cat)
             self._t0 = None
+        ann, self._ann = self._ann, None
+        if ann is not None:
+            ann.__exit__(*a)
         return False
 
     def __call__(self, fn):

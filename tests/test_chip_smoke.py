@@ -77,6 +77,10 @@ def test_kernels_phase_tiny():
 
 
 def test_decode_server_phase_tiny():
+    # the phase reads the engine's counters, which are per model name and
+    # process-wide: an "lm" engine of an earlier test file must not count
+    from paddle_tpu import observability
+    observability.reset()
     out = chip_smoke.phase_decode_server(
         on_chip=False, max_seq_len=64, max_slots=2,
         prompt_lens=(3, 9, 5), new_tokens=(6, 3, 4), **TINY)

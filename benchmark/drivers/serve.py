@@ -139,6 +139,7 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
     params = make_params(cfg)
     engine, server, client = build_server(cfg, mix, params)
     acct, checks = harness.Accounting(), harness.Checks()
+    phases = harness.Phases(t_process_start)
     state = {}
     tracer = trace_reduce.Tracer(os.path.join(
         cell.root, ".bench_trace", cell.name)) if args.trace else None
@@ -160,14 +161,19 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
                         min(seconds, float(mix.get("trace_seconds", 5.0))),))
                 tracing.start()
 
+        phases.mark("setup")
         result = loadgen.run_load(client, MODEL, mix, requests, seconds,
                                   on_window=on_window)
+        phases.mark("lead_in_and_window", at=result.w1)
         if tracing:
             tracing.join(timeout=300.0)
+            phases.within("stop_trace", tracer.stop_s)
         peak = harness.device_facts(devices, cell.chips)
         z_end = engine.decodez()
         loadgen.account(result, acct)
+        phases.mark("drain")
         check_sample(checks, cfg, params, result, args.seed)
+        phases.mark("reference_check")
     finally:
         server.stop()
 
@@ -214,11 +220,16 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
                f"engine counter shed = {z_end['shed']}")
     checks.add("no failure outside the window", acct.failed_outside == 0,
                json.dumps(acct.outside_by_class))
+    phases.mark("report")
     summary = None
-    if tracer and tracer.raw:
-        tracer.add_host_spans(loadgen.host_spans(result))
-        summary = trace_reduce.reduce(
-            tracer.raw, (loadgen.SEND_SPAN, loadgen.RECV_SPAN))
+    if tracer:
+        tracer.read()       # after the drain: nothing is served any more
+        phases.mark("extract")
+        if tracer.raw:
+            tracer.add_host_spans(loadgen.host_spans(result))
+            summary = trace_reduce.reduce(
+                tracer.raw, (loadgen.SEND_SPAN, loadgen.RECV_SPAN))
+            phases.mark("reduce")
     ctx = {"trace": summary, "decodez": dz, "memory": peak,
            "lag_ms": result.lag_ms, "ttft_ms": ttft, "tbt_ms": tbt,
            "end_to_end": values,
@@ -226,4 +237,4 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
                        "cache_hits_in_setup": warm_mark[1]},
            "config": cfg, "mix": mix, "chips": cell.chips, "seconds": seconds}
     return {"acct": acct, "checks": checks, "values": values, "ctx": ctx,
-            "device": peak, "summary": summary}
+            "device": peak, "summary": summary, "phases": phases}

@@ -196,6 +196,7 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
         return got
 
     acct, checks = harness.Accounting(), harness.Checks()
+    phases = harness.Phases(t_process_start)
     for _ in range(2):                      # compiles or loads; set-up
         send()
         settle()
@@ -209,6 +210,7 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
     losses, sent, failure = [], 0, None
     n_warm_spans = len(spans)
     w0 = time.perf_counter()
+    phases.mark("setup", at=w0)
     mark0 = log.mark()
     try:
         while True:
@@ -233,8 +235,10 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
     for _ in range(K * (sent - len(losses))):
         acct.record(True, "error", failure)
     mark1 = log.mark()
+    phases.mark("window")
     if tracing:
         tracing.join(timeout=300.0)
+        phases.within("stop_trace", tracer.stop_s)
     peak = harness.device_facts(devices, chips)
     steps = sum(len(l) for l in losses)
     values = {"setup_s": w0 - t_process_start,
@@ -258,18 +262,24 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
                f"{losses[0].mean() if losses else float('nan'):.5f}, last "
                f"{losses[-1].mean() if losses else float('nan'):.5f}")
     window_compiles = harness.check_program_state(checks, mark0, mark1)
+    phases.mark("report")
     check_reference(checks, exe, scope, evalp, cfg, feed,
                     int(mix["sample_sequences"]))
+    phases.mark("reference_check")
     if pe is not None:
         pe.close()
     exe.close()
     summary = None
-    if tracer and tracer.raw:
-        tracer.add_host_spans(spans)
-        summary = trace_reduce.reduce(tracer.raw, (FEED_SPAN, FETCH_SPAN))
+    if tracer:
+        tracer.read()       # after the window: nothing is trained any more
+        phases.mark("extract")
+        if tracer.raw:
+            tracer.add_host_spans(spans)
+            summary = trace_reduce.reduce(tracer.raw, (FEED_SPAN, FETCH_SPAN))
+            phases.mark("reduce")
     ctx = {"trace": summary, "memory": peak, "end_to_end": values,
            "compile": {"in_window": window_compiles,
                        "cache_hits_in_setup": warm_mark[1]},
            "config": cfg, "mix": mix, "chips": chips, "seconds": seconds}
     return {"acct": acct, "checks": checks, "values": values, "ctx": ctx,
-            "device": peak, "summary": summary}
+            "device": peak, "summary": summary, "phases": phases}

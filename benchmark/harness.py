@@ -18,6 +18,7 @@ import math
 import os
 import re
 import statistics
+import time
 from typing import Callable, Dict, List, Optional, Sequence
 
 MANIFEST = "BENCHMARK.json"
@@ -325,6 +326,36 @@ class Checks:
                 + (f" — {d}" if d else "") for n, ok, d in self.items]
 
 
+class Phases:
+    """Where a run's wall time went.  The driver stops a run at a fixed limit
+    and its report names the run, not what it was doing; the ``bench time:``
+    line, printed by every run, names the phase.  ``mark`` closes the phase
+    that began at the mark before it; ``within`` notes a time taken inside
+    one of them (on another thread, or by one reader among many)."""
+
+    def __init__(self, t_start: float):
+        self.t_start = self.last = t_start
+        self.phases: List[tuple] = []
+        self.inside: List[tuple] = []
+
+    def mark(self, name: str, at: Optional[float] = None) -> None:
+        now = time.perf_counter() if at is None else at
+        self.phases.append((name, now - self.last))
+        self.last = now
+
+    def within(self, name: str, seconds: float) -> None:
+        self.inside.append((name, float(seconds)))
+
+    def line(self) -> str:
+        def fmt(items):
+            return " ".join(f"{n}={s:.1f}" for n, s in items)
+
+        out = (f"bench time: {time.perf_counter() - self.t_start:.1f} s since "
+               f"the process started: {fmt(self.phases)}")
+        return out + (f" | inside those: {fmt(self.inside)}"
+                      if self.inside else "")
+
+
 # ---------------------------------------------------------------------------
 # what jax compiled, and what its persistent cache served
 # ---------------------------------------------------------------------------
@@ -445,9 +476,12 @@ def select_metrics(wanted: List[dict], values: Dict[str, float]) -> dict:
     return out
 
 
-def read_per_layer(cell: Cell, ctx: dict) -> Dict[str, float]:
+def read_per_layer(cell: Cell, ctx: dict, phases: Phases) -> Dict[str, float]:
+    """Every per-layer metric of the cell through its own reader; a reader
+    that took more than a second is named on the ``bench time:`` line."""
     values = {}
     for m in cell.per_layer:
+        t0 = time.perf_counter()
         try:
             v = cell.reader(m["name"])(ctx)
         except ConfigurationError:
@@ -455,7 +489,10 @@ def read_per_layer(cell: Cell, ctx: dict) -> Dict[str, float]:
         except Exception as e:  # one reader's fault must not lose the run
             print(f"bench: reader of {m['name']} failed: {e!r}", flush=True)
             v = None
+        took = time.perf_counter() - t0
+        if took > 1.0:
+            phases.within("reader:" + m["name"], took)
         if v is not None:
             values[m["name"]] = float(v)
+    phases.mark("readers")
     return values
-

@@ -14,7 +14,8 @@ instant of the first device goes to exactly one of four terms:
 - ``none``: the thread is in no ``decode::`` span (between two of them).
 
 The four sum to ``device_idle_share`` by construction.  ``under`` picks the
-term that is reported.  Nothing where the trace has no ``decode::step``."""
+term that is reported; the partition itself is worked out once for a trace
+and kept with it.  Nothing where the trace has no ``decode::step``."""
 from benchmark import trace_reduce as tr
 from benchmark.metrics import program_spans
 
@@ -25,13 +26,19 @@ def read(ctx, under):
     raw = program_spans.load()
     if not raw:
         return None
+    parts = program_spans.derived(raw, "idle_partition", _partition_printed)
+    return parts[under] if parts else None
+
+
+def _partition_printed(raw):
+    """The partition, printed where it is worked out: once for a trace,
+    however many metrics read a term of it."""
     parts = partition(raw)
-    if parts is None:
-        return None
-    print("bench spans: device idle by the engine thread's span, % of the "
-          "window: " + " ".join(f"{k}={v:.3f}" for k, v in parts.items()),
-          flush=True)
-    return parts[under]
+    if parts:
+        print("bench spans: device idle by the engine thread's span, % of the "
+              "window: " + " ".join(f"{k}={v:.3f}" for k, v in parts.items()),
+              flush=True)
+    return parts
 
 
 def partition(raw):

@@ -8,7 +8,9 @@ the cell's own shapes (JAX's persistent cache at its fixed in-checkout path),
 measures for ``--seconds`` and prints, last, the one JSON object of the
 contract.  ``--trace 0`` reports the cell's end-to-end metrics with the
 profiler off; ``--trace 1`` is a run of its own that reports the per-layer
-metrics and the breakdown.
+metrics and the breakdown.  Either prints a ``bench time:`` line: where the
+run's wall time went, phase by phase, against the limit the driver stops a
+run at (PERF.md, section 2).
 """
 import time
 
@@ -82,6 +84,7 @@ def main(argv=None) -> int:
           f"{log.compile_s:.1f} s, {log.cache_hits} persistent-cache hit(s)",
           flush=True)
     device = dict(out["device"])
+    phases = out["phases"]
     breakdown = None
     if args.trace:
         summary = out["summary"]
@@ -92,10 +95,11 @@ def main(argv=None) -> int:
         device["busy_s"] = summary["busy_s"]
         device["window_s"] = summary["window_s"]
         breakdown = trace_reduce.breakdown(summary)
-        values = harness.read_per_layer(cell, out["ctx"])
+        values = harness.read_per_layer(cell, out["ctx"], phases)
         metrics = harness.select_metrics(cell.per_layer, values)
     else:
         metrics = harness.select_metrics(cell.end_to_end, out["values"])
+    print(phases.line(), flush=True)
     print(harness.result_line(checks.ok, acct, metrics, device, breakdown),
           flush=True)
     return 0

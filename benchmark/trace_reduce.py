@@ -107,18 +107,22 @@ def intersect(a: Sequence[Interval], b: Sequence[Interval]) -> List[Interval]:
 
 
 def subtract(a: Sequence[Interval], b: Sequence[Interval]) -> List[Interval]:
-    """``a`` minus ``b``, both sorted unions."""
-    out = []
+    """``a`` minus ``b``, both sorted unions, in one pass over the two: an
+    interval of ``b`` that ends before one of ``a`` starts lies before every
+    later one too, so the cursor into ``b`` never goes back."""
+    out, j = [], 0
     for s, e in a:
-        cur = s
-        for bs, be in b:
-            if be <= cur or bs >= e:
-                continue
+        while j < len(b) and b[j][1] <= s:
+            j += 1
+        cur, k = s, j
+        while k < len(b) and b[k][0] < e:
+            bs, be = b[k]
             if bs > cur:
                 out.append((cur, bs))
             cur = max(cur, be)
             if cur >= e:
                 break
+            k += 1
         if cur < e:
             out.append((cur, e))
     return out
@@ -230,6 +234,7 @@ class Tracer:
         self.raw: dict = {}
         self.xplane = ""
         self.t_open = 0.0
+        self.stop_s = 0.0       # what stop_trace() took, on window()'s thread
 
     @staticmethod
     def span(name: str):
@@ -245,11 +250,26 @@ class Tracer:
         jax.profiler.start_trace(self.out_dir, profiler_options=opts)
 
     def window(self, seconds: float) -> None:
-        """Trace ``seconds`` from now, then stop and read the trace."""
+        """Trace ``seconds`` from now, then stop the session.  Stopping bounds
+        the trace, so it happens here, on this thread, while the run's window
+        is still open; reading the file is Python that would hold the
+        interpreter against the system under test, so :meth:`read` does that
+        and the caller calls it once its window has closed and drained."""
+        import jax
         with self.span(WINDOW_SPAN):
             self.t_open = time.perf_counter()
             time.sleep(seconds)
-        self.stop()
+        t0 = time.perf_counter()
+        jax.profiler.stop_trace()
+        self.stop_s = time.perf_counter() - t0
+
+    def read(self) -> None:
+        """Find the file the stopped session wrote and extract it."""
+        found = sorted(glob.glob(os.path.join(
+            self.out_dir, "plugins", "profile", "*", "*.xplane.pb")))
+        if found:
+            self.xplane = found[-1]
+            self.raw = extract(found[-1])
 
     def add_host_spans(self, spans) -> None:
         """Put the benchmark's own ``(name, start, end)`` spans, stamped with
@@ -263,12 +283,3 @@ class Tracer:
         for name, t0, t1 in spans:
             self.raw["host"].append(
                 [name, wins[0][1] + (t0 - self.t_open) * 1e9, (t1 - t0) * 1e9])
-
-    def stop(self) -> None:
-        import jax
-        jax.profiler.stop_trace()
-        found = sorted(glob.glob(os.path.join(
-            self.out_dir, "plugins", "profile", "*", "*.xplane.pb")))
-        if found:
-            self.xplane = found[-1]
-            self.raw = extract(found[-1])

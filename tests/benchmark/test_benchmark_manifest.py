@@ -7,6 +7,7 @@ import json
 import os
 import shutil
 import sys
+import time
 
 import pytest
 
@@ -149,9 +150,11 @@ def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
     assert cell.config["max_seq_len"] == 256 and cell.mix["callers"] == 40
     cell.driver().validate(cell, float(manifest["run_seconds"]))
     assert [m["name"] for m in cell.per_layer if m["name"] == entry["name"]]
+    phases = harness.Phases(time.perf_counter())
     values = harness.read_per_layer(cell, {
         "decodez": {"steps": 21, "tokens": 0, "prefills": 0},
-        "compile": {"in_window": 0, "cache_hits_in_setup": 3}})
+        "compile": {"in_window": 0, "cache_hits_in_setup": 3}}, phases)
+    assert [n for n, _ in phases.phases] == ["readers"] and not phases.inside
     assert values["steps_twice.served"] == 42.0
     assert values["warm_cache_hits"] == 3.0
     # the cells that were there still load, and no file that was there changed

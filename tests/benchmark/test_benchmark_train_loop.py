@@ -62,6 +62,10 @@ def test_every_step_sent_in_the_window_is_counted_once_and_waited_for(
     failing = [l for l in out["checks"].lines()
                if "FAIL" in l and "lower at the end" not in l]
     assert not failing, failing
+    # where the run's time went, for the ``bench time:`` line of a --trace 0 run
+    assert [n for n, _ in out["phases"].phases] == [
+        "setup", "window", "report", "reference_check"]
+    assert out["phases"].line().startswith("bench time: ")
 
 
 @pytest.mark.parametrize("depth", [0, 5])

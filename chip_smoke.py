@@ -40,6 +40,7 @@ from __future__ import annotations
 import gc
 import json
 import os
+import re
 import sys
 import threading
 import time
@@ -477,23 +478,55 @@ def phase_decode_server(on_chip=True, vocab=32000, d_model=512, n_head=8,
         check(exact >= 0.9 * total,
               f"only {exact}/{total} greedy tokens equal the re-forward")
 
-        # the decode step's lowering, at the engine's shapes
+        # the decode step and one prefill, lowered at the engine's
+        # shapes with the pool donated as run_callable donates it: the
+        # kernel is Mosaic's, and the pool [L, NB, bs, H*Dh] stays in
+        # place — no program copies, slices or relays it, so each
+        # needs less scratch than one K pool and holds no copy of the
+        # pool's shape
         S, MB = engine.max_slots, engine.max_blocks_per_seq
+        Tb = engine.prefill_ladder.sizes[0]
         zi = jnp.zeros((S,), jnp.int32)
-        step = jax.jit(lambda pl, kc, vc, *a: model.decode_step(
-            pl, kc, vc, *a, attn_impl=attn_impl))
-        text = step.lower(
-            plist, engine.cache.k, engine.cache.v, zi, zi,
-            jnp.zeros((S, MB), jnp.int32), zi.astype(jnp.uint32), zi,
-            jnp.zeros((S,), jnp.float32), zi).as_text()
-        has_call = MOSAIC_CALL in text
+        i0, f0, u0 = jnp.int32(0), jnp.float32(0), jnp.uint32(0)
+        pool = engine.cache.k
+        programs = {
+            "step": (
+                lambda pl, kc, vc, *a: model.decode_step(
+                    pl, kc, vc, *a, attn_impl=attn_impl),
+                (zi, zi, jnp.zeros((S, MB), jnp.int32),
+                 zi.astype(jnp.uint32), zi, jnp.zeros((S,), jnp.float32),
+                 zi)),
+            "prefill": (
+                model.prefill,
+                (jnp.zeros((1, Tb), jnp.int32), i0,
+                 jnp.zeros((MB,), jnp.int32), u0, f0, i0)),
+        }
+        in_place = {"pool_bytes": int(pool.nbytes)}
+        pool_copy = re.compile(
+            r"\[%s\]\S* copy\(" % ",".join(map(str, pool.shape)))
+        for name, (fn, feed) in programs.items():
+            lowered = jax.jit(fn, donate_argnums=(1, 2)).lower(
+                plist, pool, engine.cache.v, *feed)
+            if name == "step":
+                has_call = MOSAIC_CALL in lowered.as_text()
+            compiled = lowered.compile()
+            temp = int(compiled.memory_analysis().temp_size_in_bytes)
+            copies = len(pool_copy.findall(compiled.as_text()))
+            in_place[name] = {"temp_bytes": temp, "pool_copies": copies}
+            if on_chip:   # off the chip the kernel is interpreted: a
+                # loop that carries the pool, which XLA does copy
+                check(temp < pool.nbytes and copies == 0,
+                      f"the decode {name} does not keep the pool in "
+                      f"place: {temp} bytes of scratch against a K pool "
+                      f"of {pool.nbytes}, {copies} copies of its shape")
         if on_chip:
             check(has_call, "no Mosaic custom call in the decode step")
         return {"attn_impl": attn_impl, "mosaic_custom_call": has_call,
                 "transport": transport_backend, "requests": 2 * len(prompt_lens),
                 "tokens_checked": total, "tokens_exact": exact,
                 "worst_logit_gap": worst_gap, "logit_scale": scale,
-                "joins": z["joins"], "steps": z["steps"]}
+                "joins": z["joins"], "steps": z["steps"],
+                "pool_in_place": in_place}
     finally:
         server.stop()
 

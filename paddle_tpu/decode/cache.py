@@ -1,11 +1,22 @@
 """Paged KV cache: fixed-size device blocks + a host-side allocator.
 
 The device half (:class:`PagedKVCache`) is two preallocated arrays
-``[num_layers, num_blocks, block_tokens, n_head, head_dim]`` (keys and
-values) that ride :meth:`Executor.run_callable` as donated state —
-every prefill/decode dispatch consumes the old buffers and returns the
-updated ones, so the cache is resident in device memory for the
-engine's whole life and no dispatch ever copies it to host.
+``[num_layers, num_blocks, block_tokens, n_head * head_dim]`` — ``[L,
+NB, bs, H*Dh]``, keys and values — that ride
+:meth:`Executor.run_callable` as donated state: every prefill/decode
+dispatch consumes the old buffers and returns the updated ones, so the
+cache is resident in device memory for the engine's whole life and no
+dispatch ever copies it to host — or on the device.  Heads are merged
+into the minor axis so that a block's ``[bs, H*Dh]`` fills whole
+(sublane, lane) tiles of the TPU (GPT-1: 16 x 768 f32 = 2 x 6 tiles of
+(8, 128)): the array then keeps its row-major layout in HBM, the
+per-layer scatters update it in place, and the paged kernel is handed
+the WHOLE pool with the layer in its index map (``kernels/attention.py
+decode_attention``).  With ``[..., H, Dh]`` = ``(12, 64)`` as minor
+dims the device stored the pool blocks-minor and every program relaid
+all of it to row-major and back — four copies of the pool per dispatch
+(PERF.md, PR 28).  Nothing may slice a layer out (``k[i]``) on a path
+that runs per token: index ``k[i, blocks]`` in one gather instead.
 
 The host half (:class:`BlockAllocator`) is a refcounted free list over
 block ids.  Block 0 is RESERVED as the trash block: padded prompt
@@ -277,13 +288,14 @@ class PrefixCache:
 
 
 class PagedKVCache:
-    """The device arrays (module doc).  ``state()`` hands the [k, v]
-    list to ``Executor.run_callable``; ``update()`` swaps in the
-    returned (donated-in-place) handles.
+    """The device arrays ``k``, ``v``: ``[L, NB, bs, H*Dh]`` (module
+    doc).  ``state()`` hands the [k, v] list to
+    ``Executor.run_callable``; ``update()`` swaps in the returned
+    (donated-in-place) handles.
 
     ``dtype="int8"`` (``FLAGS_decode_kv_dtype``) stores blocks
-    quantized: k/v pools become int8 and two parallel f32 scale pools
-    ``[num_layers, num_blocks, n_head]`` carry one abs-max scale per
+    quantized in the SAME layout: k/v pools become int8 and two
+    parallel f32 scale pools ``[L, NB, H]`` carry one abs-max scale per
     (block, head) — the qdq convention of ``kernels/quant.py``
     (``x ~= q * s / 127``).  ``state()`` then threads
     ``[k, v, k_scale, v_scale]`` so every dispatch moves the scale
@@ -309,7 +321,7 @@ class PagedKVCache:
         self.dtype = str(dtype)
         self.quantized = self.dtype == "int8"
         shape = (self.num_layers, self.num_blocks, self.block_tokens,
-                 self.num_heads, self.head_dim)
+                 self.num_heads * self.head_dim)
         if self.quantized:
             self.k = jnp.zeros(shape, jnp.int8)
             self.v = jnp.zeros(shape, jnp.int8)

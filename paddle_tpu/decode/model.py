@@ -200,8 +200,10 @@ class TransformerLM:
     # -- cache writes ------------------------------------------------------
     @staticmethod
     def _scatter_kv(cache, layer, blocks, offsets, rows):
-        """rows [N, H, Dh] into cache[layer] at (block, offset) pairs."""
-        return cache.at[layer, blocks, offsets].set(rows)
+        """rows [N, H, Dh] into the pool's layer at (block, offset)
+        pairs, as the pool's [N, H*Dh] lane-dense rows."""
+        return cache.at[layer, blocks, offsets].set(
+            rows.reshape(rows.shape[0], -1))
 
     @staticmethod
     def _scatter_kv_q(cache, scales, layer, blocks, offsets, rows, slot,
@@ -229,12 +231,12 @@ class TransformerLM:
             jnp.where(onehot[:, :, None], ha[:, None, :], 0.0),
             axis=0)                                      # [MB, H]
         touched = jnp.any(onehot, axis=0)                # [MB]
-        old = scales[layer][block_table]                 # [MB, H]
+        old = scales[layer, block_table]                 # [MB, H]
         new = jnp.where(touched[:, None],
                         jnp.maximum(blk_amax, SCALE_EPS), old)
         scales = scales.at[layer, block_table].set(new)
         q = kv_quantize(rows, new[slot])                 # [T, H, Dh] int8
-        cache = cache.at[layer, blocks, offsets].set(q)
+        cache = cache.at[layer, blocks, offsets].set(q.reshape(T, -1))
         return cache, scales
 
     @staticmethod
@@ -251,11 +253,12 @@ class TransformerLM:
         growth, and the scale only ever grows over a block's
         residency, so drift is bounded by the growth count, not the
         token count)."""
-        S = rows.shape[0]
+        S, H, Dh = rows.shape
         ha = kv_head_amax(rows)                          # [S, H]
         old = scales[layer, blocks]                      # [S, H]
         new = jnp.maximum(old, ha)                       # [S, H]
-        blk = cache[layer, blocks]                       # [S, bs, H, Dh]
+        blk = cache[layer, blocks]                       # [S, bs, H*Dh]
+        blk = blk.reshape(S, blk.shape[1], H, Dh)
         ratio = jnp.where(new > 0.0,
                           old / jnp.maximum(new, SCALE_EPS), 1.0)
         blk = jnp.clip(jnp.round(blk.astype(jnp.float32)
@@ -263,7 +266,7 @@ class TransformerLM:
                        -QMAX, QMAX).astype(jnp.int8)
         q = kv_quantize(rows, new)                       # [S, H, Dh]
         blk = blk.at[jnp.arange(S), offsets].set(q)
-        cache = cache.at[layer, blocks].set(blk)
+        cache = cache.at[layer, blocks].set(blk.reshape(S, -1, H * Dh))
         scales = scales.at[layer, blocks].set(new)
         return cache, scales
 
@@ -380,22 +383,23 @@ class TransformerLM:
             if ks is None:
                 kc = self._scatter_kv(kc, i, blocks, offsets, k)
                 vc = self._scatter_kv(vc, i, blocks, offsets, v)
-                ck = kc[i][block_table].reshape(MB * bs, cfg.n_head,
+                ck = kc[i, block_table].reshape(MB * bs, cfg.n_head,
                                                 cfg.head_dim)
-                cv = vc[i][block_table].reshape(MB * bs, cfg.n_head,
+                cv = vc[i, block_table].reshape(MB * bs, cfg.n_head,
                                                 cfg.head_dim)
             else:
                 kc, ks = self._scatter_kv_q(kc, ks, i, blocks, offsets,
                                             k, slot, valid, block_table)
                 vc, vs = self._scatter_kv_q(vc, vs, i, blocks, offsets,
                                             v, slot, valid, block_table)
+                heads = (MB, bs, cfg.n_head, cfg.head_dim)
                 ck = kv_dequantize(
-                    kc[i][block_table],
-                    ks[i][block_table][:, None, :]).reshape(
+                    kc[i, block_table].reshape(heads),
+                    ks[i, block_table][:, None, :]).reshape(
                         MB * bs, cfg.n_head, cfg.head_dim)
                 cv = kv_dequantize(
-                    vc[i][block_table],
-                    vs[i][block_table][:, None, :]).reshape(
+                    vc[i, block_table].reshape(heads),
+                    vs[i, block_table][:, None, :]).reshape(
                         MB * bs, cfg.n_head, cfg.head_dim)
             s = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),
                            ck.astype(jnp.float32)) * sc
@@ -443,14 +447,14 @@ class TransformerLM:
             if ks is None:
                 kc = self._scatter_kv(kc, i, blocks, offsets, k)
                 vc = self._scatter_kv(vc, i, blocks, offsets, v)
-                ctx = decode_attention(q, kc[i], vc[i], block_tables,
-                                       cl, impl=attn_impl)
+                ctx = decode_attention(q, kc, vc, block_tables, cl, i,
+                                       impl=attn_impl)
             else:
                 kc, ks = self._append_kv_q(kc, ks, i, blocks, offsets, k)
                 vc, vs = self._append_kv_q(vc, vs, i, blocks, offsets, v)
-                ctx = decode_attention(q, kc[i], vc[i], block_tables,
-                                       cl, impl=attn_impl,
-                                       k_scale=ks[i], v_scale=vs[i])
+                ctx = decode_attention(q, kc, vc, block_tables, cl, i,
+                                       impl=attn_impl,
+                                       k_scale=ks, v_scale=vs)
             h = self._post_attn(p, i, h, ctx.astype(h.dtype))
         logits = h @ p["out_proj"]
         toks = _sample(logits, seeds, steps, temperature, top_k)

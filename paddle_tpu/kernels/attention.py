@@ -901,33 +901,69 @@ _ring_flash.defvjp(_ring_vjp_fwd, _ring_vjp_bwd)
 _INV_QMAX = 1.0 / 127.0
 
 
+def _head_sums(x, head_dim: int):
+    """x [bs, H*D], heads merged into the lane axis → [bs, H*D], every
+    lane holding the sum over ITS head's D lanes.
+
+    Mosaic has no shape cast from [bs, H*D] to [bs, H, D] ("unsupported
+    shape cast"), so heads are told apart by lane masks inside aligned
+    lane chunks: 128 lanes (128 // D heads each) when D divides 128 and
+    H*D is a multiple of 128 — GPT-1's 12 x 64 is six chunks of two
+    heads — one head per chunk when D is a multiple of 128, else one
+    chunk holding every head (off-TPU sizes).  A chunk slice is whole
+    vregs, so the only cross-lane work is one lane reduce per head."""
+    bs, hd = x.shape
+    if 128 % head_dim == 0 and hd % 128 == 0:
+        width = 128
+    elif head_dim % 128 == 0:
+        width = head_dim
+    else:
+        width = hd
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bs, width), 1)
+    masks = [jnp.logical_and(lane >= g * head_dim, lane < (g + 1) * head_dim)
+             for g in range(width // head_dim)]
+    chunks = []
+    for c in range(hd // width):
+        chunk = x[:, c * width:(c + 1) * width]
+        out = jnp.zeros_like(chunk)
+        for mask in masks:
+            out = jnp.where(mask, jnp.sum(jnp.where(mask, chunk, 0.0),
+                                          axis=-1, keepdims=True), out)
+        chunks.append(out)
+    return jnp.concatenate(chunks, axis=-1)
+
+
 def _decode_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
-                        block_tokens: int, sm_scale: float,
+                        block_tokens: int, head_dim: int, sm_scale: float,
                         quantized: bool = False):
     """Grid (S, max_blocks): slot-major, blocks sequential minor — the
     online-softmax state for one slot lives in VMEM scratch across its
     block iterations (the flash discipline applied to the block TABLE
-    axis).  The K/V index maps read the scalar-prefetched block table,
-    so each grid step streams exactly ONE cache block — the gathered
-    block list is never materialized.  Blocks past the slot's context
-    frontier are skipped (index maps clamp to the frontier block, so
-    the pipeline issues no copies for them either).
+    axis).  The K/V index maps read the scalar-prefetched block table
+    and the static layer, so each grid step streams exactly ONE
+    [bs, H*D] block of the WHOLE pool — neither the layer's slice nor
+    the gathered block list is ever materialized.  Blocks past the
+    slot's context frontier are skipped (index maps clamp to the
+    frontier block, so the pipeline issues no copies for them either).
 
-    ``quantized``: the cache blocks are int8 codes and two extra [H, 1]
-    scale refs follow the v ref (per-block-per-head abs-max from the
-    parallel scale pool, same block-table index map) — the block is
-    dequantized IN VMEM right after the copy lands (``code * s/127``),
-    so HBM traffic per block is halved while scores still run in f32.
+    ``quantized``: the cache blocks are int8 codes and two extra
+    [1, H*D] scale refs follow the v ref (the slot's per-block-per-head
+    abs-max rows, a head's scale repeated over its D lanes, indexed by
+    slot and table position) — the block is dequantized IN VMEM right
+    after the copy lands (``code * s/127``), so HBM traffic per block
+    is a quarter of f32's while scores still run in f32.
 
-    One query row per slot leaves the MXU nothing to do, so scores and
-    the PV sum are VPU multiply-reduces over the cache block in its
-    stored [bs, H, D] layout: a lane reduce over D for the scores, a
-    leading-dim reduce over the block's tokens for max / sum / PV.
-    (Mosaic refuses the batched ``dot_general`` over the MIDDLE axis the
-    first version used: "failed to parse TPU_DotDimensionNumbersAttr
-    parameter 'lhs_non_contracting_dims'", PERF.md Bring-up.)  Scores
-    run in f32 natural units (a decode step is dispatch-bound — the
-    flash kernel's exp2/ones-lane folds buy nothing here)."""
+    One query row per slot leaves the MXU nothing to do, so everything
+    is VPU work on the block in its stored lane-dense [bs, H*D] layout:
+    q, the running max / sum and the PV accumulator are [1, H*D] rows
+    with a head's value repeated over its D lanes, scores are a
+    per-head lane sum of ``k * q`` (:func:`_head_sums`), and max / sum / PV
+    reduce over the block's tokens (the sublane axis).  (Mosaic refuses
+    the batched ``dot_general`` over the MIDDLE axis the first version
+    used: "failed to parse TPU_DotDimensionNumbersAttr parameter
+    'lhs_non_contracting_dims'", PERF.md Bring-up.)  Scores run in f32
+    natural units (a decode step is dispatch-bound — the flash kernel's
+    exp2/ones-lane folds buy nothing here)."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -945,24 +981,24 @@ def _decode_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
 
     @pl.when(j <= last)
     def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # [H, D]
-        k_blk = k_ref[0].astype(jnp.float32)               # [bs, H, D]
-        v_blk = v_ref[0].astype(jnp.float32)
+        q = q_ref[0].astype(jnp.float32) * sm_scale        # [1, H*D]
+        k_blk = k_ref[0, 0].astype(jnp.float32)            # [bs, H*D]
+        v_blk = v_ref[0, 0].astype(jnp.float32)
         if quantized:
-            k_blk = k_blk * (ks_ref[0] * _INV_QMAX)[None]  # [1, H, 1]
-            v_blk = v_blk * (vs_ref[0] * _INV_QMAX)[None]
-        # per-token per-head scores: [bs, H, 1]
-        scores = jnp.sum(k_blk * q[None], axis=-1, keepdims=True)
+            k_blk = k_blk * (ks_ref[0, 0] * _INV_QMAX)     # [1, H*D]
+            v_blk = v_blk * (vs_ref[0, 0] * _INV_QMAX)
+        scores = _head_sums(k_blk * q, head_dim)           # [bs, H*D]
         pos = j * block_tokens + jax.lax.broadcasted_iota(
             jnp.int32, scores.shape, 0)
         scores = jnp.where(pos < cl, scores, NEG_INF)
-        m = m_scr[:]                                       # [H, 1]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=0))
-        p = jnp.exp(scores - m_new[None])                  # [bs, H, 1]
+        m = m_scr[:]                                       # [1, H*D]
+        m_new = jnp.maximum(m, jnp.max(scores, axis=0, keepdims=True))
+        p = jnp.exp(scores - m_new)                        # [bs, H*D]
         alpha = jnp.exp(m - m_new)
         m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=0)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(p * v_blk, axis=0)
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
+        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(p * v_blk, axis=0,
+                                                  keepdims=True)
 
     @pl.when(j == last)
     def _finish():
@@ -971,28 +1007,32 @@ def _decode_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
 
 
 def paged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
-                        sm_scale=None, k_scale=None, v_scale=None):
+                        layer, sm_scale=None, k_scale=None, v_scale=None):
     """XLA gather fallback for :func:`decode_attention` (always
     available; also the parity reference the kernel is pinned to).
 
-    q: [S, H, D]; k_cache/v_cache: [N_blocks, bs, H, D] (one layer);
-    block_tables: [S, MB] int32; context_lens: [S] int32 → [S, H, D].
-    With ``k_scale``/``v_scale`` ([N_blocks, H] f32, the int8 cache's
-    parallel scale pools) the gathered codes are dequantized before the
-    softmax — same math as the kernel's VMEM dequant.
+    q: [S, H, D]; k_cache/v_cache: the WHOLE pool [L, N_blocks, bs,
+    H*D]; block_tables: [S, MB] int32; context_lens: [S] int32; layer:
+    static int → [S, H, D].  The gather indexes ``[layer,
+    block_tables]`` in one step, so no layer slice of the pool exists.
+    With ``k_scale``/``v_scale`` ([L, N_blocks, H] f32, the int8
+    cache's parallel scale pools) the gathered codes are dequantized
+    before the softmax — same math as the kernel's VMEM dequant.
     """
     if sm_scale is None:
         sm_scale = float(1.0 / np.sqrt(q.shape[-1]))
     S, H, D = q.shape
-    bs = k_cache.shape[1]
+    bs = k_cache.shape[2]
     MB = block_tables.shape[1]
-    k = k_cache[block_tables]                    # [S, MB, bs, H, D]
-    v = v_cache[block_tables]
+    k = k_cache[layer, block_tables]             # [S, MB, bs, H*D]
+    v = v_cache[layer, block_tables]
     if k_scale is not None:
-        k = (k.astype(jnp.float32)
-             * (k_scale[block_tables][:, :, None, :, None] * _INV_QMAX))
-        v = (v.astype(jnp.float32)
-             * (v_scale[block_tables][:, :, None, :, None] * _INV_QMAX))
+        ks = k_scale[layer, block_tables]        # [S, MB, H]
+        vs = v_scale[layer, block_tables]
+        k = (k.reshape(S, MB, bs, H, D).astype(jnp.float32)
+             * (ks[:, :, None, :, None] * _INV_QMAX))
+        v = (v.reshape(S, MB, bs, H, D).astype(jnp.float32)
+             * (vs[:, :, None, :, None] * _INV_QMAX))
     k = k.reshape(S, MB * bs, H, D)
     v = v.reshape(S, MB * bs, H, D)
     s = jnp.einsum("shd,sthd->sht", q.astype(jnp.float32),
@@ -1006,74 +1046,84 @@ def paged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
 
 
 def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
-                       sm_scale, interpret, k_scale=None, v_scale=None):
+                       layer, sm_scale, interpret, k_scale=None,
+                       v_scale=None):
     S, H, D = q.shape
-    bs = k_cache.shape[1]
+    bs, HD = k_cache.shape[2:]
     MB = block_tables.shape[1]
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
     quantized = k_scale is not None
 
-    def kv_map(s, j, bt, cl):
+    def live(s, j, cl):
         # clamp skipped past-frontier blocks to the frontier block: the
         # pipeline re-references the previous block, no copy issued
-        jc = jnp.minimum(j, jnp.maximum((cl[s] - 1) // bs, 0))
-        return (bt[s, jc], 0, 0, 0)
+        return jnp.minimum(j, jnp.maximum((cl[s] - 1) // bs, 0))
 
-    def scale_map(s, j, bt, cl):
-        jc = jnp.minimum(j, jnp.maximum((cl[s] - 1) // bs, 0))
-        return (bt[s, jc], 0, 0)
+    def kv_map(s, j, bt, cl):
+        return (layer, bt[s, live(s, j, cl)], 0, 0)
 
+    def row_map(s, j, bt, cl):
+        return (s, 0, 0)
+
+    # q and the output as [S, 1, H*D] rows: a (1, 1, H*D) block's minor
+    # dims equal the array's (a (1, H*D) block of [S, H*D] breaks the
+    # TPU (8, 128) rule)
     in_specs = [
-        pl.BlockSpec((1, H, D), lambda s, j, bt, cl: (s, 0, 0)),
-        pl.BlockSpec((1, bs, H, D), kv_map),
-        pl.BlockSpec((1, bs, H, D), kv_map),
+        pl.BlockSpec((1, 1, HD), row_map),
+        pl.BlockSpec((1, 1, bs, HD), kv_map),
+        pl.BlockSpec((1, 1, bs, HD), kv_map),
     ]
-    operands = [bt, cl, q, k_cache, v_cache]
+    operands = [bt, cl, q.reshape(S, 1, HD), k_cache, v_cache]
     if quantized:
-        # per-block-per-head scale rows ride the same prefetched block
-        # table as the code blocks they dequantize; as [N_blocks, H, 1]
-        # so the (1, H, 1) block's minor dims equal the array's (a
-        # (1, H) block of [N_blocks, H] breaks the TPU (8, 128) rule)
-        in_specs += [pl.BlockSpec((1, H, 1), scale_map),
-                     pl.BlockSpec((1, H, 1), scale_map)]
-        operands += [k_scale[..., None], v_scale[..., None]]
+        # the scale pools' minor dim is H, too narrow for a block of its
+        # own, so the slots' scale rows are gathered here ([S, MB, H],
+        # small) and spread over each head's D lanes; the kernel reads
+        # row (slot, table position) beside the code block it scales
+        def scale_map(s, j, bt, cl):
+            return (s, live(s, j, cl), 0, 0)
+
+        in_specs += [pl.BlockSpec((1, 1, 1, HD), scale_map)] * 2
+        operands += [
+            jnp.repeat(sc[layer, bt], D, axis=-1).reshape(S, MB, 1, HD)
+            for sc in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(S, MB),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, H, D), lambda s, j, bt, cl: (s, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, D), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, 1, HD), row_map),
+        scratch_shapes=[pltpu.VMEM((1, HD), jnp.float32)] * 3,
     )
     kernel = functools.partial(_decode_attn_kernel, block_tokens=bs,
-                               sm_scale=sm_scale, quantized=quantized)
-    return pl.pallas_call(
+                               head_dim=D, sm_scale=sm_scale,
+                               quantized=quantized)
+    out = pl.pallas_call(
         kernel,
         name="paged_decode_attn",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, 1, HD), q.dtype),
         interpret=interpret,
     )(*operands)
+    return out.reshape(S, H, D)
 
 
 def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
-                     sm_scale=None, interpret=None, impl=None,
+                     layer, sm_scale=None, interpret=None, impl=None,
                      k_scale=None, v_scale=None):
     """Paged decode attention: one query token per request against its
     gathered block list (scalar-prefetch block tables — module doc,
     ``_decode_attn_kernel``).
 
-    q: [S, H, D] (S decode slots); k_cache/v_cache: [N_blocks,
-    block_tokens, H, D] for ONE layer; block_tables: [S, MB] int32
+    q: [S, H, D] (S decode slots); k_cache/v_cache: the WHOLE pool
+    [L, N_blocks, block_tokens, H*D], every layer of it, handed over as
+    it lies in HBM; ``layer``: static int, which layer's blocks to read
+    (it goes into the kernel's index map — slicing ``k_cache[layer]``
+    first would copy the layer); block_tables: [S, MB] int32
     cache-block ids per slot; context_lens: [S] int32 valid tokens per
     slot (positions ≥ context_len masked).  Returns [S, H, D].
 
-    ``k_scale``/``v_scale``: [N_blocks, H] f32 per-block-per-head
+    ``k_scale``/``v_scale``: [L, N_blocks, H] f32 per-block-per-head
     abs-max pools when the cache stores int8 codes
     (``FLAGS_decode_kv_dtype=int8``); both paths dequantize with
     ``code * s/127`` — the kernel in VMEM after the block copy lands,
@@ -1087,14 +1137,15 @@ def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     this function returned — a trace-time ``try`` here never saw one)."""
     if sm_scale is None:
         sm_scale = float(1.0 / np.sqrt(q.shape[-1]))
+    layer = int(layer)
     if impl == "xla":
         return paged_attention_xla(q, k_cache, v_cache, block_tables,
-                                   context_lens, sm_scale,
+                                   context_lens, layer, sm_scale,
                                    k_scale=k_scale, v_scale=v_scale)
     if impl not in (None, "pallas"):
         raise ValueError(f"unknown decode attention impl {impl!r}")
     if interpret is None:
         interpret = pallas_interpret()
     return _paged_attn_pallas(q, k_cache, v_cache, block_tables,
-                              context_lens, sm_scale, interpret,
+                              context_lens, layer, sm_scale, interpret,
                               k_scale=k_scale, v_scale=v_scale)

@@ -88,6 +88,14 @@ def test_decode_server_phase_tiny():
     assert out["requests"] == 6 and out["joins"] == 6
     assert out["tokens_checked"] == 13
     assert out["tokens_exact"] == 13      # f32 on the CPU: no near-ties
+    # the pool-in-place record: the step and a prefill were compiled
+    # with the pool donated; a prefill holds no copy of the pool even
+    # here (only the interpreted kernel's loop copies it, off the chip)
+    rec = out["pool_in_place"]
+    assert rec["pool_bytes"] > 0 and set(rec) == {"pool_bytes", "step",
+                                                  "prefill"}
+    assert rec["prefill"]["pool_copies"] == 0
+    assert rec["prefill"]["temp_bytes"] < rec["pool_bytes"]
 
 
 def test_four_chip_phase_tiny():

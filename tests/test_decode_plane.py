@@ -56,7 +56,8 @@ def test_paged_cache_state_roundtrip():
     c = PagedKVCache(num_layers=2, num_heads=2, head_dim=8,
                      num_blocks=5, block_tokens=4)
     k, v = c.state()
-    assert k.shape == (2, 5, 4, 2, 8) and v.shape == k.shape
+    # [L, NB, bs, H*Dh]: heads merged into the minor (lane) axis
+    assert k.shape == (2, 5, 4, 16) and v.shape == k.shape
     c.update([k + 1, v])
     assert float(jnp.max(c.k)) == 1.0
     snap = c.snapshot()
@@ -67,30 +68,51 @@ def test_paged_cache_state_roundtrip():
 # decode-attention kernel
 # ---------------------------------------------------------------------------
 
-def _rand_paged(rng, S=3, H=2, D=16, bs=4, MB=4, N=8):
-    kc = jnp.asarray(rng.randn(N, bs, H, D).astype("float32"))
-    vc = jnp.asarray(rng.randn(N, bs, H, D).astype("float32"))
+def _rand_paged(rng, S=3, H=2, D=16, bs=4, MB=4, N=8, L=3):
+    """A whole pool [L, N, bs, H*D] (every layer different), three
+    slots: slot 0 holds ONE token and a block table full of trash
+    block 0 (an inactive decode slot), slot 2 a full context."""
+    kc = jnp.asarray(rng.randn(L, N, bs, H * D).astype("float32"))
+    vc = jnp.asarray(rng.randn(L, N, bs, H * D).astype("float32"))
     q = jnp.asarray(rng.randn(S, H, D).astype("float32"))
-    bt = jnp.asarray(rng.randint(0, N, (S, MB)).astype("int32"))
+    bt = rng.randint(0, N, (S, MB)).astype("int32")
+    bt[0, :] = 0
     cl = jnp.asarray(np.array([1, 7, 16], "int32"))
-    return q, kc, vc, bt, cl
+    return q, kc, vc, jnp.asarray(bt), cl
 
 
-def test_decode_attention_pallas_matches_xla_and_dense():
+# (H, D): every head in one odd-sized lane chunk (off-TPU sizes), two
+# heads per 128-lane chunk over two chunks (the chip's GPT-1 geometry,
+# 12 x 64, in small), one head per chunk
+_GEOMETRIES = [(2, 16), (4, 64), (2, 128)]
+
+
+@pytest.mark.parametrize("layer", [0, 1, 2])
+@pytest.mark.parametrize("H,D", _GEOMETRIES)
+def test_decode_attention_pallas_matches_xla_and_dense(H, D, layer):
+    """The kernel is handed the WHOLE pool and a static layer: for
+    every layer of a 3-layer pool it must read that layer's blocks and
+    no other's."""
     rng = np.random.RandomState(0)
-    q, kc, vc, bt, cl = _rand_paged(rng)
-    ox = AK.paged_attention_xla(q, kc, vc, bt, cl)
-    op = AK.decode_attention(q, kc, vc, bt, cl, impl="pallas")
+    q, kc, vc, bt, cl = _rand_paged(rng, H=H, D=D)
+    ox = AK.paged_attention_xla(q, kc, vc, bt, cl, layer)
+    op = AK.decode_attention(q, kc, vc, bt, cl, layer, impl="pallas")
+    assert ox.shape == op.shape == q.shape
     assert float(jnp.max(jnp.abs(ox - op))) < 1e-5
-    # dense reference for the full-context slot
-    D = q.shape[-1]
-    k_full = np.asarray(kc[bt[2]]).reshape(-1, 2, D)
-    v_full = np.asarray(vc[bt[2]]).reshape(-1, 2, D)
-    s = np.einsum("hd,thd->ht", np.asarray(q[2]), k_full) / np.sqrt(D)
-    p = np.exp(s - s.max(-1, keepdims=True))
-    p /= p.sum(-1, keepdims=True)
-    ref = np.einsum("ht,thd->hd", p, v_full)
-    assert np.abs(ref - np.asarray(ox[2])).max() < 1e-5
+    # dense reference for the full-context slot and the one-token slot
+    for slot in (2, 0):
+        n = int(cl[slot])
+        k_full = np.asarray(kc[layer, bt[slot]]).reshape(-1, H, D)[:n]
+        v_full = np.asarray(vc[layer, bt[slot]]).reshape(-1, H, D)[:n]
+        s = np.einsum("hd,thd->ht", np.asarray(q[slot]), k_full) / np.sqrt(D)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        ref = np.einsum("ht,thd->hd", p, v_full)
+        assert np.abs(ref - np.asarray(ox[slot])).max() < 1e-5
+    # another layer's blocks give another answer: the layer index is read
+    other = AK.decode_attention(q, kc, vc, bt, cl, (layer + 1) % 3,
+                                impl="pallas")
+    assert float(jnp.max(jnp.abs(other - op))) > 1e-3
 
 
 def test_decode_attention_kernel_fault_is_an_error(monkeypatch):
@@ -105,12 +127,12 @@ def test_decode_attention_kernel_fault_is_an_error(monkeypatch):
         raise RuntimeError("injected kernel build fault")
     monkeypatch.setattr(AK, "_paged_attn_pallas", boom)
     with pytest.raises(RuntimeError, match="injected kernel build fault"):
-        AK.decode_attention(q, kc, vc, bt, cl)
-    out = AK.decode_attention(q, kc, vc, bt, cl, impl="xla")
-    ox = AK.paged_attention_xla(q, kc, vc, bt, cl)
+        AK.decode_attention(q, kc, vc, bt, cl, 1)
+    out = AK.decode_attention(q, kc, vc, bt, cl, 1, impl="xla")
+    ox = AK.paged_attention_xla(q, kc, vc, bt, cl, 1)
     assert float(jnp.max(jnp.abs(out - ox))) == 0.0
     with pytest.raises(ValueError, match="unknown decode attention impl"):
-        AK.decode_attention(q, kc, vc, bt, cl, impl="auto")
+        AK.decode_attention(q, kc, vc, bt, cl, 1, impl="auto")
 
 
 # ---------------------------------------------------------------------------
@@ -144,6 +166,72 @@ def test_greedy_paged_decode_matches_full_reforward_with_join_leave():
                     f"token {step} diverged: paged {r['tokens'][step]} "
                     f"vs re-forward {ref_tok}")
                 toks.append(ref_tok)
+    finally:
+        eng.close()
+
+
+def _assert_greedy_is_reforward_argmax(lm, params, prompt, tokens):
+    """Teacher-forced: token k is the argmax of the full causal forward
+    over prompt + tokens[:k] (one padded shape, so one compile)."""
+    plist = lm.param_list(params)
+    width = TINY.max_seq_len
+    seq = np.zeros((1, width), np.int32)
+    n = len(prompt) + len(tokens)
+    seq[0, :n] = np.concatenate([prompt, np.asarray(tokens, np.int32)])
+    logits = np.asarray(lm.full_logits(
+        plist, jnp.asarray(seq), jnp.asarray([n], jnp.int32)))[0]
+    for k, tok in enumerate(tokens):
+        row = logits[len(prompt) + k - 1]
+        assert int(row.argmax()) == tok, (
+            f"token {k}: paged {tok} vs re-forward {int(row.argmax())} "
+            f"(gap {float(row.max() - row[tok]):.2e})")
+
+
+def test_every_pool_writer_and_reader_matches_full_reforward():
+    """One engine drives every program that touches the pool —
+    ``prefill``, the decode step, ``prefill_suffix`` on a prefix-cache
+    hit AND on a preemption resume, and a ``_copy_block`` COW fork —
+    and every greedy token is still the full re-forward's argmax."""
+    lm, params, eng = _engine("poolpaths", prefill_buckets=(8, 16),
+                              num_blocks=9, overcommit=True,
+                              prefix_cache=True)
+    alloc, bs = eng.cache.allocator, eng.cache.block_tokens
+    phantom = []
+    ensure = eng._ensure_blocks
+
+    def ensure_with_one_sharer():
+        # once: a second reference on a stream's partly written block,
+        # so this step must fork it (and carry its rows) before writing
+        if not phantom:
+            for slot in eng._slots:
+                if slot is None or not slot.pos_next % bs:
+                    continue
+                j = slot.pos_next // bs
+                if j < len(slot.blocks) and \
+                        alloc.refcount(slot.blocks[j]) == 1:
+                    alloc.incref(slot.blocks[j])
+                    phantom.append(slot.blocks[j])
+                    break
+        ensure()
+    eng._ensure_blocks = ensure_with_one_sharer
+    try:
+        pA = np.arange(1, 9, dtype=np.int32)            # 2 full blocks
+        first = eng.generate(pA, max_new_tokens=4)      # prefill + steps
+        prompts = [np.concatenate([pA, [9, 10]]).astype(np.int32),
+                   np.arange(20, 26, dtype=np.int32),
+                   np.arange(30, 36, dtype=np.int32)]
+        handles = [eng.submit(p, SamplingParams(max_new_tokens=10))
+                   for p in prompts]
+        results = [h.result(timeout=120) for h in handles]
+        assert all(r["finish"] == "length" for r in results)
+        for p, r in zip([pA] + prompts, [first] + results):
+            _assert_greedy_is_reforward_argmax(lm, params, p, r["tokens"])
+        ps = eng._pstats
+        assert ps.prefix_hits.value >= 1        # suffix prefill, cached
+        assert ps.preempt_resumes.value >= 1    # suffix prefill, resume
+        assert ps.cow_forks.value >= 1 and phantom
+        alloc.decref(phantom[0])                # the sharer lets go
+        assert alloc.leaked(eng.prefix.parked_blocks) == 0
     finally:
         eng.close()
 

@@ -1,0 +1,140 @@
+"""The KV pool stays in place — checked with the TPU's own compiler, for
+a v5e that is described and not attached (no chip, no chip time).
+
+What interpret mode cannot show: whether Mosaic accepts the paged
+kernel at GPT-1's geometry (12 heads of 64 merged into 768 lanes), and
+whether XLA:TPU keeps the donated pool ``[L, NB, bs, H*Dh]`` where it
+lies through the decode step and a prefill.  Before PR 28 each program
+relaid the whole pool four times (PERF.md, section 6); here each must
+compile with less scratch than one K pool and without a copy of the
+pool's shape.  Depth is cut to two layers and the vocabulary to 1,024 (the
+layout depends on neither); widths, block size and pool length are the
+benchmark's.
+
+The topology is described inside a fixture, never at import: one
+process at a time may load the TPU's library, and every xdist worker
+imports every test file.
+"""
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.decode import LMConfig, PagedKVCache, TransformerLM
+from paddle_tpu.decode.model import _param_names
+from paddle_tpu.kernels import attention as AK
+
+# tlm-gpt1w (benchmark/configs/tlm-gpt1w.json) at two layers and a small
+# vocabulary (neither touches the pool; both are most of the compile
+# time), and the serve mixes' engine: 64 slots, 16-token blocks, 2,049
+# blocks, 32 a slot
+CFG = LMConfig(vocab=1024, d_model=768, n_head=12, d_ffn=3072, n_layer=2,
+               max_seq_len=512)
+S, MB, NB, BS = 64, 32, 2049, 16
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def mosaic(monkeypatch):
+    """As on the chip: off it the kernels interpret themselves (compile
+    them), and tier-1 turns x64 on (the chip's processes never do)."""
+    monkeypatch.setattr(AK, "pallas_interpret", lambda: False)
+    with jax.enable_x64(False):
+        yield
+
+
+def _shapes(one_chip, kv_dtype):
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    D, F, V = CFG.d_model, CFG.d_ffn, CFG.vocab
+    by_suffix = {"emb": (V, D), "out_proj": (D, V), "fc1": (D, F),
+                 "fc2": (F, D), "wq": (D, D), "wk": (D, D), "wv": (D, D),
+                 "wo": (D, D)}
+    plist = [sds(by_suffix.get(n.split(".")[-1], (D,)), jnp.float32)
+             for n in _param_names(CFG)]
+    state = [sds(a.shape, a.dtype) for a in jax.eval_shape(
+        lambda: PagedKVCache(CFG.n_layer, CFG.n_head, CFG.head_dim, NB, BS,
+                             dtype=kv_dtype).state())]
+    i32, u32, f32 = jnp.int32, jnp.uint32, jnp.float32
+    feeds = {
+        "step": [sds((S,), i32), sds((S,), i32), sds((S, MB), i32),
+                 sds((S,), u32), sds((S,), i32), sds((S,), f32),
+                 sds((S,), i32)],
+        "prefill": [sds((1, 128), i32), sds((), i32), sds((MB,), i32),
+                    sds((), u32), sds((), f32), sds((), i32)],
+        "prefill_suffix": [sds((1, 64), i32), sds((), i32), sds((), i32),
+                           sds((MB,), i32), sds((), u32), sds((), f32),
+                           sds((), i32)],
+    }
+    return plist, state, feeds
+
+
+def _program(model, name, quantized):
+    """fn(feed, state, const) as the engine hands it to run_callable."""
+    call = {"step": lambda *a, **kw: model.decode_step(
+                *a, attn_impl="pallas", **kw),
+            "prefill": model.prefill,
+            "prefill_suffix": model.prefill_suffix}[name]
+
+    def fn(feed, state, const):
+        kw = dict(ks=state[2], vs=state[3]) if quantized else {}
+        out = call(const, state[0], state[1], *feed, **kw)
+        return list(out[-2:]), list(out[:-2])
+    return fn
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+@pytest.mark.parametrize("name", ["step", "prefill", "prefill_suffix"])
+def test_pool_is_neither_copied_nor_relaid(one_chip, mosaic, name, kv_dtype):
+    plist, state, feeds = _shapes(one_chip, kv_dtype)
+    fn = _program(TransformerLM(CFG), name, kv_dtype == "int8")
+    compiled = jax.jit(fn, donate_argnums=(1,)).lower(
+        feeds[name], state, plist).compile()
+    text = compiled.as_text()
+    pool = state[0]
+    pool_bytes = int(np.prod(pool.shape)) * pool.dtype.itemsize
+    dims = ",".join(map(str, pool.shape))
+    # the pool keeps the layout it was given, row-major and unpadded ...
+    assert re.search(r"\[%s\]\{3,2,1,0:T\(" % dims, text)
+    # ... no program copies it ...
+    copies = re.findall(r"\[%s\]\S* copy\(" % dims, text)
+    assert not copies, f"{len(copies)} copies of the pool in {name}"
+    # ... or a layer of it ...
+    layer = ",".join(map(str, pool.shape[1:]))
+    assert not re.search(r"= \w+\[%s\]" % layer, text), \
+        f"a layer slice of the pool is materialised in {name}"
+    # ... and its scratch is small beside one K pool
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes / 4, (temp, pool_bytes)
+    if name == "step":
+        assert text.count("tpu_custom_call") == CFG.n_layer
+
+
+def test_kernel_refuses_nothing_at_full_context_width(one_chip, mosaic):
+    """The kernel alone, on a 12-layer pool, last layer: Mosaic accepts
+    the (1, 1, bs, H*Dh) block and the per-head lane sums."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    pool = sds((12, NB, BS, CFG.d_model), jnp.float32)
+    compiled = jax.jit(
+        lambda q, k, v, bt, cl: AK.decode_attention(q, k, v, bt, cl, 11)
+    ).lower(sds((S, CFG.n_head, CFG.head_dim), jnp.float32), pool, pool,
+            sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20

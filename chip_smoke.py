@@ -63,6 +63,14 @@ FALLBACK_COUNTERS = (
     "compile_cache.faults",
 )
 
+# the XLA fallbacks of the latent-attention LM's kernels (kernels/moe.py,
+# kernels/mla.py): taken only by name (impl="xla"), counted when taken
+LATENT_FALLBACK_COUNTERS = (
+    "moe.grouped_swiglu_fallbacks",
+    "mla.decode_attn_fallbacks",
+    "mla.prefill_attn_fallbacks",
+)
+
 MOSAIC_CALL = "tpu_custom_call"
 
 
@@ -491,8 +499,8 @@ def phase_decode_server(on_chip=True, vocab=32000, d_model=512, n_head=8,
         pool = engine.cache.k
         programs = {
             "step": (
-                lambda pl, kc, vc, *a: model.decode_step(
-                    pl, kc, vc, *a, attn_impl=attn_impl),
+                lambda pl, state, *a: model.decode_step(
+                    pl, state, *a, attn_impl=attn_impl),
                 (zi, zi, jnp.zeros((S, MB), jnp.int32),
                  zi.astype(jnp.uint32), zi, jnp.zeros((S,), jnp.float32),
                  zi)),
@@ -505,8 +513,8 @@ def phase_decode_server(on_chip=True, vocab=32000, d_model=512, n_head=8,
         pool_copy = re.compile(
             r"\[%s\]\S* copy\(" % ",".join(map(str, pool.shape)))
         for name, (fn, feed) in programs.items():
-            lowered = jax.jit(fn, donate_argnums=(1, 2)).lower(
-                plist, pool, engine.cache.v, *feed)
+            lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+                plist, engine.cache.state(), *feed)
             if name == "step":
                 has_call = MOSAIC_CALL in lowered.as_text()
             compiled = lowered.compile()
@@ -529,6 +537,124 @@ def phase_decode_server(on_chip=True, vocab=32000, d_model=512, n_head=8,
                 "pool_in_place": in_place}
     finally:
         server.stop()
+
+
+# ---------------------------------------------------------------------------
+# phase 3b: the latent-attention expert LM through the same engine
+# ---------------------------------------------------------------------------
+
+def phase_latent_lm(on_chip=True, vocab=8192, hidden=512, heads=8, nope=128,
+                    rope=64, v_dim=128, rank=512, dense=1024, expert=256,
+                    experts=16, top_k=4, layers=3, max_seq_len=1024,
+                    max_slots=8, block_tokens=16, prefill_bucket=256,
+                    prompt_lens=(40, 200, 131, 256, 77),
+                    new_tokens=(12, 6, 20, 4, 9), dtype="bfloat16"):
+    """``decode.mla.MLATransformerLM`` (DeepSeek-V2's block at its head
+    sizes and latent rank, fewer and narrower experts) through
+    ``DecodeEngine``: every stream finishes, the greedy tokens are the full
+    forward's up to bf16 near-ties, the three new kernels compile with
+    Mosaic, the latent pool stays in place, and no kernel fell back."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.decode import DecodeEngine, SamplingParams
+    from paddle_tpu.decode.mla import MLAConfig, MLATransformerLM
+
+    cfg = MLAConfig(
+        vocab_size=vocab, hidden_size=hidden, num_hidden_layers=layers,
+        num_attention_heads=heads, qk_nope_head_dim=nope,
+        qk_rope_head_dim=rope, v_head_dim=v_dim, kv_lora_rank=rank,
+        intermediate_size=dense, moe_intermediate_size=expert,
+        n_routed_experts=experts, num_experts_per_tok=top_k,
+        rope_scaling={"factor": 40, "original_max_position_embeddings": 64,
+                      "beta_fast": 32, "beta_slow": 1, "mscale": 0.707,
+                      "mscale_all_dim": 0.707},
+        max_seq_len=max_seq_len, dtype=dtype)
+    model = MLATransformerLM(cfg)
+    params = model.init_params(seed=5)
+    c0 = counters()
+    engine = DecodeEngine(model, params, name="latent", max_slots=max_slots,
+                          block_tokens=block_tokens,
+                          prefill_buckets=[prefill_bucket],
+                          attn_impl="pallas", cache_dtype=dtype,
+                          prefix_cache=False, overcommit=False)
+    try:
+        rng = np.random.RandomState(0)
+        prompts = [rng.randint(0, vocab, (n,)).astype("int32")
+                   for n in prompt_lens]
+        handles = [engine.submit(p, SamplingParams(max_new_tokens=n))
+                   for p, n in zip(prompts, new_tokens)]
+        results = [h.result(timeout=900.0) for h in handles]
+        for i, r in enumerate(results):
+            check(len(r["tokens"]) == new_tokens[i]
+                  and r.get("finish") == "length",
+                  f"latent stream {i} ended early: {r}")
+        plist = model.param_list(params)
+        full = jax.jit(model.full_logits)
+        worst_gap = scale = 0.0
+        exact = total = 0
+        for p, r in zip(prompts, results):
+            toks = np.asarray(r["tokens"], np.int32)
+            seq = np.zeros((1, max_seq_len), np.int32)
+            seq[0, :p.size + toks.size] = np.concatenate([p, toks])
+            logits = np.asarray(full(plist, jnp.asarray(seq)))[0]
+            for k, tok in enumerate(toks):
+                row = logits[p.size + k - 1]
+                gap = float(row.max() - row[tok])
+                exact += int(gap == 0.0)
+                total += 1
+                worst_gap = max(worst_gap, gap)
+                scale = max(scale, float(np.max(np.abs(row))))
+        # bf16 activations through two attention paths and two row orders
+        # of the experts: a token must be the full forward's argmax to
+        # within 3% of the logit scale, and mostly exactly it
+        check(worst_gap <= 0.03 * scale,
+              f"a latent-LM token trails the full forward's argmax by "
+              f"{worst_gap:.4f} (logit scale {scale:.2f})")
+        check(exact >= 0.8 * total,
+              f"only {exact}/{total} latent-LM tokens equal the full forward")
+
+        S, MB = engine.max_slots, engine.max_blocks_per_seq
+        zi = jnp.zeros((S,), jnp.int32)
+        i0, f0, u0 = jnp.int32(0), jnp.float32(0), jnp.uint32(0)
+        pool = engine.cache.latent
+        programs = {
+            "step": (lambda pl, state, *a: model.decode_step(
+                         pl, state, *a, attn_impl="pallas"),
+                     (zi, zi, jnp.zeros((S, MB), jnp.int32),
+                      zi.astype(jnp.uint32), zi, jnp.zeros((S,), jnp.float32),
+                      zi), layers + (layers - 1)),
+            "prefill": (model.prefill,
+                        (jnp.zeros((1, prefill_bucket), jnp.int32), i0,
+                         jnp.zeros((MB,), jnp.int32), u0, f0, i0),
+                        layers + (layers - 1)),
+        }
+        in_place = {"pool_bytes": int(pool.nbytes)}
+        pool_copy = re.compile(
+            r"\[%s\]\S* copy\(" % ",".join(map(str, pool.shape)))
+        for name, (fn, feed, kernels) in programs.items():
+            lowered = jax.jit(fn, donate_argnums=(1,)).lower(
+                plist, engine.cache.state(), *feed)
+            calls = lowered.as_text().count(MOSAIC_CALL)
+            compiled = lowered.compile()
+            copies = len(pool_copy.findall(compiled.as_text()))
+            in_place[name] = {"pool_copies": copies, "mosaic_calls": calls}
+            if on_chip:     # off the chip the kernels are interpreted
+                check(copies == 0, f"the latent {name} copies the pool "
+                                   f"{copies} times")
+                check(calls == kernels,
+                      f"the latent {name} holds {calls} Mosaic calls, not "
+                      f"the {kernels} of its attention and expert layers")
+        fell = {n: counter_delta(c0, n) for n in LATENT_FALLBACK_COUNTERS}
+        check(not any(fell.values()), f"a new kernel fell back: {fell}")
+        z = engine.decodez()
+        check(z["cache"].get("kind") == "latent",
+              f"/decodez does not name the latent pool: {z['cache']}")
+        return {"tokens_checked": total, "tokens_exact": exact,
+                "worst_logit_gap": worst_gap, "logit_scale": scale,
+                "steps": z["steps"], "cache": z["cache"],
+                "pool_in_place": in_place, "fallbacks": fell}
+    finally:
+        engine.close()
 
 
 # ---------------------------------------------------------------------------
@@ -687,6 +813,7 @@ def main() -> int:
     trainer = run_phase(report, "trainer", phase_trainer, place)
     run_phase(report, "kernels", phase_kernels, place)
     run_phase(report, "decode_server", phase_decode_server)
+    run_phase(report, "latent_lm", phase_latent_lm)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])
@@ -698,8 +825,9 @@ def main() -> int:
             "status": f"not run: {len(devices)} device"}
 
     c = counters()
-    report["fallback_counters"] = {n: int(c.get(n, 0))
-                                   for n in FALLBACK_COUNTERS}
+    report["fallback_counters"] = {
+        n: int(c.get(n, 0))
+        for n in FALLBACK_COUNTERS + LATENT_FALLBACK_COUNTERS}
     report["jax_cache"] = {"hits": LOG.cache_hits, "compiles": LOG.compiles,
                            "compile_s": round(LOG.compile_s, 2)}
     failures += [f"phase {n}: {p.get('error')}"

@@ -28,6 +28,15 @@ package is that state plane, built on the repo's own primitives:
   token per slot against its gathered block list via scalar-prefetch
   block tables, with a counted XLA-gather fallback and interpret-mode
   CPU coverage (the ``kernels/sparse.py`` contract).
+- **A second model behind the same engine** (:mod:`mla`): DeepSeek-V2's
+  block — latent (MLA) attention over ONE latent pool
+  (:class:`~paddle_tpu.decode.cache.PagedLatentCache`, no V pool), YaRN
+  rotary positions, routed experts at top-k beside shared ones
+  (``kernels/mla.py``, ``kernels/moe.py``).  A model describes its cache
+  (``make_cache``), owns the state list its ``prefill`` / ``decode_step``
+  thread as ``(const, state, *feed) → (outs, state')``, and says what of
+  the block lifecycle it ``supports``; the engine has no branch on a
+  model's kind.
 - **On-device sampling** (:mod:`model`): greedy / top-k / temperature
   inside the decode dispatch; incremental beam search rides
   :class:`paddle_tpu.contrib.decoder.IncrementalBeamDecoder` (the
@@ -56,9 +65,10 @@ builds an engine gets no new arrays, threads, or sockets.
 from __future__ import annotations
 
 from .cache import (BlockAllocator, PagedKVCache,  # noqa: F401
-                    PrefixCache)
+                    PagedLatentCache, PrefixCache)
 from .model import (LMConfig, TransformerLM, load_lm,  # noqa: F401
                     save_lm)
+from .mla import MLAConfig, MLATransformerLM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -69,8 +79,9 @@ from ..serving.batcher import (Draining, Overloaded,  # noqa: F401
                                RequestTooLong)
 
 __all__ = [
-    "BlockAllocator", "PagedKVCache", "PrefixCache",
+    "BlockAllocator", "PagedKVCache", "PagedLatentCache", "PrefixCache",
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
+    "MLAConfig", "MLATransformerLM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

@@ -34,7 +34,7 @@ from typing import List, Optional
 
 import numpy as np
 
-from .cache import PagedKVCache, blocks_for
+from .cache import blocks_for
 from .model import TransformerLM
 from ..core import flags as _flags
 from ..core.executor import Executor
@@ -76,8 +76,11 @@ class PagedBeamDecoder:
             # during the parent gather — double the worst case
             factor = 1 if self.share_prefix else 2
             num_blocks = 1 + factor * self.beam_size * self.max_blocks_per_seq
-        self.cache = PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim,
-                                  num_blocks, bs, dtype="float32")
+        if "beam" not in model.supports:
+            raise ValueError(
+                f"beam session {name!r}: {type(model).__name__} does not "
+                f"support beam (block forks over its cache are untested)")
+        self.cache = model.make_cache(num_blocks, bs, dtype="float32")
         self._exe = executor if executor is not None \
             else Executor(training=False)
         self._plist = model.param_list(params)
@@ -222,9 +225,8 @@ class PagedBeamDecoder:
 
         def build_prefill():
             def fn(feed, state, const):
-                kc, vc, tok, logits = model.prefill(
-                    const, state[0], state[1], *feed)
-                return [logits], [kc, vc]
+                (_, logits), new_state = model.prefill(const, state, *feed)
+                return [logits], new_state
             return fn
 
         (logits0,), new_state = self._exe.run_callable(
@@ -254,9 +256,9 @@ class PagedBeamDecoder:
 
         def build_step():
             def fn(feed, state, const):
-                kc, vc, toks, logits = model.decode_step(
-                    const, state[0], state[1], *feed, attn_impl=impl)
-                return [logits], [kc, vc]
+                (_, logits), new_state = model.decode_step(
+                    const, state, *feed, attn_impl=impl)
+                return [logits], new_state
             return fn
 
         zeros_u = np.zeros((bw,), np.uint32)

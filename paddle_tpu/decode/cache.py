@@ -287,7 +287,44 @@ class PrefixCache:
         }
 
 
-class PagedKVCache:
+class _PagedPool:
+    """What every paged pool shares: the block geometry, the
+    :class:`BlockAllocator` and the base of ``snapshot()``.  A subclass
+    allocates its arrays and gives ``nbytes``, ``state()`` and
+    ``update()``."""
+
+    kind = "kv"
+    quantized = False
+
+    def __init__(self, num_layers: int, num_blocks: int,
+                 block_tokens: Optional[int], dtype):
+        self.num_layers = int(num_layers)
+        self.num_blocks = int(num_blocks)
+        self.block_tokens = int(
+            _flags.get_flags("decode_block_tokens")
+            if block_tokens is None else block_tokens)
+        if self.block_tokens < 1:
+            raise ValueError(f"block_tokens must be >= 1, got "
+                             f"{self.block_tokens}")
+        self.dtype = str(dtype)
+        self.allocator = BlockAllocator(self.num_blocks)
+
+    def max_context(self, max_blocks_per_seq: int) -> int:
+        return max_blocks_per_seq * self.block_tokens
+
+    def snapshot(self) -> dict:
+        snap = {
+            "num_blocks": self.num_blocks,
+            "block_tokens": self.block_tokens,
+            "free_blocks": self.allocator.free_blocks,
+            "bytes": self.nbytes,
+        }
+        if self.kind != "kv":
+            snap["kind"] = self.kind
+        return snap
+
+
+class PagedKVCache(_PagedPool):
     """The device arrays ``k``, ``v``: ``[L, NB, bs, H*Dh]`` (module
     doc).  ``state()`` hands the [k, v] list to
     ``Executor.run_callable``; ``update()`` swaps in the returned
@@ -308,17 +345,9 @@ class PagedKVCache:
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
                  num_blocks: int, block_tokens: Optional[int] = None,
                  dtype="float32"):
-        self.num_layers = int(num_layers)
+        super().__init__(num_layers, num_blocks, block_tokens, dtype)
         self.num_heads = int(num_heads)
         self.head_dim = int(head_dim)
-        self.num_blocks = int(num_blocks)
-        self.block_tokens = int(
-            _flags.get_flags("decode_block_tokens")
-            if block_tokens is None else block_tokens)
-        if self.block_tokens < 1:
-            raise ValueError(f"block_tokens must be >= 1, got "
-                             f"{self.block_tokens}")
-        self.dtype = str(dtype)
         self.quantized = self.dtype == "int8"
         shape = (self.num_layers, self.num_blocks, self.block_tokens,
                  self.num_heads * self.head_dim)
@@ -333,7 +362,6 @@ class PagedKVCache:
             self.v = jnp.zeros(shape, dtype)
             self.k_scale = None
             self.v_scale = None
-        self.allocator = BlockAllocator(self.num_blocks)
 
     @property
     def nbytes(self) -> int:
@@ -357,20 +385,54 @@ class PagedKVCache:
         else:
             self.k, self.v = new_state
 
-    def max_context(self, max_blocks_per_seq: int) -> int:
-        return max_blocks_per_seq * self.block_tokens
-
     def snapshot(self) -> dict:
-        snap = {
-            "num_blocks": self.num_blocks,
-            "block_tokens": self.block_tokens,
-            "free_blocks": self.allocator.free_blocks,
-            "bytes": self.nbytes,
-        }
+        snap = super().snapshot()
         if self.quantized:
             # new keys only under the flag: the f32 snapshot surface
             # stays byte-identical
             snap["dtype"] = self.dtype
             snap["scale_bytes"] = int(
                 self.k_scale.size) * self.k_scale.dtype.itemsize * 2
+        return snap
+
+
+class PagedLatentCache(_PagedPool):
+    """The latent pool of a model with latent (MLA) attention: ONE array
+    ``[L, NB, bs, W]`` and no V pool.  A token's row a layer is what its
+    attention reads back — the compressed ``c`` after its norm and the shared
+    rotary key after rotation — padded with zero lanes to a whole number of
+    128-lane tiles (``kernels/mla.row_width``: 512 + 64 → 640), so that a
+    block's ``[bs, W]`` fills whole tiles and the pool is updated in place
+    like the K/V pools (class doc above).  Same manager otherwise: the same
+    :class:`BlockAllocator`, block tables and ``snapshot()``, which adds
+    ``kind: latent`` and the row's widths."""
+
+    kind = "latent"
+
+    def __init__(self, num_layers: int, rank: int, rope_dim: int,
+                 row_width: int, num_blocks: int, block_tokens: int,
+                 dtype="bfloat16"):
+        if str(dtype) == "int8":
+            raise ValueError("the latent pool has no int8 form: its rows "
+                             "carry no per-block scale")
+        super().__init__(num_layers, num_blocks, block_tokens, dtype)
+        self.rank, self.rope_dim = int(rank), int(rope_dim)
+        self.row_width = int(row_width)
+        self.latent = jnp.zeros((self.num_layers, self.num_blocks,
+                                 self.block_tokens, self.row_width), dtype)
+
+    @property
+    def nbytes(self) -> int:
+        return int(self.latent.size) * self.latent.dtype.itemsize
+
+    def state(self) -> list:
+        return [self.latent]
+
+    def update(self, new_state: list) -> None:
+        (self.latent,) = new_state
+
+    def snapshot(self) -> dict:
+        snap = super().snapshot()
+        snap.update(dtype=self.dtype, rank=self.rank, rope_dim=self.rope_dim,
+                    row_width=self.row_width)
         return snap

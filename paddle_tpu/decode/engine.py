@@ -76,7 +76,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from .cache import PagedKVCache, PrefixCache, blocks_for
+from .cache import PrefixCache, blocks_for
 from .model import TransformerLM
 from ..core import flags as _flags
 from ..core.executor import Executor
@@ -460,8 +460,10 @@ class DecodeEngine:
         # "float32" default keeps the flags-off pool byte-identical
         if cache_dtype is None:
             cache_dtype = str(_flags.get_flags("decode_kv_dtype"))
-        self.cache = PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim,
-                                  num_blocks, bs, dtype=cache_dtype)
+        # the model describes its cache and owns the state list its
+        # three entry points thread (K/V pools, their scale pools, a
+        # latent pool): the engine passes ``cache.state()`` through
+        self.cache = model.make_cache(num_blocks, bs, dtype=cache_dtype)
         ladder = (prefill_buckets if prefill_buckets is not None
                   else BucketLadder.parse(
                       _flags.get_flags("decode_prefill_buckets")))
@@ -477,6 +479,9 @@ class DecodeEngine:
             else Executor(training=False)
         self._plist = model.param_list(params)
         self.stats = _EngineStats(name)
+        # what the model's programs return beside token and logits (a
+        # routed model's load figures) goes to the model's own observer
+        self._observer = model.observer(name, self.cache)
         # refcounted block lifecycle (module doc) — latched here; both
         # flags off keeps the legacy single-owner paths byte-identical
         self._prefix_on = bool(_flags.get_flags("decode_prefix_cache")
@@ -484,6 +489,15 @@ class DecodeEngine:
         self._overcommit_on = bool(_flags.get_flags("decode_overcommit")
                                    if overcommit is None else overcommit)
         self._refc = self._prefix_on or self._overcommit_on
+        asked = {"prefix_cache": self._prefix_on,
+                 "overcommit": self._overcommit_on}
+        refused = sorted(k for k, on in asked.items()
+                         if on and k not in model.supports)
+        if refused:
+            raise ValueError(
+                f"decode engine {name!r}: {type(model).__name__} does not "
+                f"support {', '.join(refused)} (it has no suffix prefill "
+                f"over its cache)")
         self.prefix = (PrefixCache(
             self.cache.allocator, bs,
             model_key=f"{name}/{cfg.vocab}x{cfg.d_model}x{cfg.n_layer}")
@@ -782,18 +796,11 @@ class DecodeEngine:
 
     def _prefill_full(self, i: int, slot: _Slot, req: DecodeRequest,
                       t0: float, P: int, bucket: int) -> None:
-        model, quantized = self.model, self.cache.quantized
+        model = self.model
 
         def build():
             def fn(feed, state, const):
-                if quantized:
-                    kc, vc, ks, vs, tok, logits = model.prefill(
-                        const, state[0], state[1], *feed,
-                        ks=state[2], vs=state[3])
-                    return [tok, logits], [kc, vc, ks, vs]
-                kc, vc, tok, logits = model.prefill(
-                    const, state[0], state[1], *feed)
-                return [tok, logits], [kc, vc]
+                return model.prefill(const, state, *feed)
             return fn
 
         with _trace.span("decode::prefill.feed"):
@@ -809,13 +816,14 @@ class DecodeEngine:
             # chaos hook: `delay:decode_prefill` sleeps here, inside the
             # prefill phase / TTFT window (the SLO-watchdog test's lever)
             _faults.event("decode_prefill")
-        (tok, logits), new_state = self._exe.run_callable(
+        (tok, logits, *extra), new_state = self._exe.run_callable(
             f"decode/{self.name}/prefill/{bucket}", build, feed,
             state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
         with _trace.span("decode::prefill.wait"):
             first = int(np.asarray(tok))
             logits_np = np.asarray(logits) if self.capture_logits else None
+            self._observer.prefill(extra, P, bucket)
         with _trace.span("decode::prefill.emit"):
             slot.last_token = first
             slot.t_last = time.perf_counter()
@@ -857,7 +865,7 @@ class DecodeEngine:
         seq = slot.seq if slot.seq is not None else req.prompt
         L = int(seq.size)
         start = slot.cached_tokens
-        model, quantized = self.model, self.cache.quantized
+        model = self.model
         if start > 0:
             n = L - start
             bucket = self._resume_ladder.snap(n)
@@ -866,15 +874,7 @@ class DecodeEngine:
 
             def build():
                 def fn(feed, state, const):
-                    if quantized:
-                        kc, vc, ks, vs, tok, logits = \
-                            model.prefill_suffix(
-                                const, state[0], state[1], *feed,
-                                ks=state[2], vs=state[3])
-                        return [tok, logits], [kc, vc, ks, vs]
-                    kc, vc, tok, logits = model.prefill_suffix(
-                        const, state[0], state[1], *feed)
-                    return [tok, logits], [kc, vc]
+                    return model.prefill_suffix(const, state, *feed)
                 return fn
 
             feed = [tokens,
@@ -893,14 +893,7 @@ class DecodeEngine:
 
             def build():
                 def fn(feed, state, const):
-                    if quantized:
-                        kc, vc, ks, vs, tok, logits = model.prefill(
-                            const, state[0], state[1], *feed,
-                            ks=state[2], vs=state[3])
-                        return [tok, logits], [kc, vc, ks, vs]
-                    kc, vc, tok, logits = model.prefill(
-                        const, state[0], state[1], *feed)
-                    return [tok, logits], [kc, vc]
+                    return model.prefill(const, state, *feed)
                 return fn
 
             feed = [tokens,
@@ -912,9 +905,10 @@ class DecodeEngine:
             key = f"decode/{self.name}/prefill/{bucket}"
         _debug_server.note_activity("decode")
         _faults.event("decode_prefill")
-        (tok, logits), new_state = self._exe.run_callable(
+        (tok, logits, *extra), new_state = self._exe.run_callable(
             key, build, feed, state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
+        self._observer.prefill(extra, n, bucket)
         slot.t_last = time.perf_counter()
         self.stats.prefills.inc()
         prefill_ms = (slot.t_last - t0) * 1e3
@@ -1011,18 +1005,11 @@ class DecodeEngine:
         if not live:
             return
         model, impl = self.model, self._attn_impl
-        quantized = self.cache.quantized
 
         def build():
             def fn(feed, state, const):
-                if quantized:
-                    kc, vc, ks, vs, toks, logits = model.decode_step(
-                        const, state[0], state[1], *feed,
-                        attn_impl=impl, ks=state[2], vs=state[3])
-                    return [toks, logits], [kc, vc, ks, vs]
-                kc, vc, toks, logits = model.decode_step(
-                    const, state[0], state[1], *feed, attn_impl=impl)
-                return [toks, logits], [kc, vc]
+                return model.decode_step(const, state, *feed,
+                                         attn_impl=impl)
             return fn
 
         _debug_server.note_activity("decode")
@@ -1034,7 +1021,7 @@ class DecodeEngine:
         # without real HBM pressure
         _faults.event("decode_step")
         _faults.oom_fault("decode_step")
-        (toks, logits), new_state = self._exe.run_callable(
+        (toks, logits, *extra), new_state = self._exe.run_callable(
             f"decode/{self.name}/step", build,
             [tokens, positions, tables, seeds, steps, temps, topks],
             state=self.cache.state(), const=self._plist)
@@ -1042,6 +1029,8 @@ class DecodeEngine:
         with _trace.span("decode::step.wait"):
             toks_np = np.asarray(toks)
             logits_np = np.asarray(logits) if self.capture_logits else None
+            self._observer.step(
+                extra, int(positions[live].sum()) + len(live))
         with _trace.span("decode::step.emit"):
             self._emit_step(live, toks_np, logits_np, t0)
 

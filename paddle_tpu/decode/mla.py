@@ -1,0 +1,591 @@
+"""A latent-attention (MLA) mixture-of-experts LM for the decode plane:
+DeepSeek-V2's block, configured by its published keys.
+
+``x ← x + attn(RMSNorm(x))``, ``x ← x + ffn(RMSNorm(x))``, a final RMSNorm,
+untied embedding and head, no bias anywhere.
+
+- **Attention** is multi-head latent attention.  A token's keys and values
+  are functions of one compressed row ``c`` (``kv_lora_rank`` wide, after its
+  own RMSNorm) and one rotary key ``k_pe`` shared by all heads, and that row
+  is ALL the cache holds (:class:`~paddle_tpu.decode.cache.PagedLatentCache`).
+  Two paths compute the same scores: a prompt's *prefill* expands ``k_nope``
+  and ``v`` from ``c`` and runs causal flash attention at 192 (q·k) and 128
+  (v) a head; a *decode step* never expands the cache — ``W_kvb``'s key part
+  is absorbed into the query and its value part applied after the weighted
+  sum of rows (``kernels/mla.py``).
+- **Positions** are rotary on the ``qk_rope_head_dim`` slice of each head, in
+  the half-split form, with YaRN's frequency interpolation
+  (:func:`yarn_inv_freq`) and its softmax temperature (:func:`softmax_scale`).
+- **Feed-forward** is SwiGLU: dense in the first ``first_k_dense_replace``
+  layers, then ``n_routed_experts`` routed experts at top-k of a float32
+  softmax router (weights the scores themselves unless ``norm_topk_prob``)
+  beside ``n_shared_experts`` shared ones as one wide SwiGLU
+  (``kernels/moe.py``).  Every assignment is computed; none is dropped.
+
+The model has :class:`~paddle_tpu.decode.model.TransformerLM`'s entry points
+— ``full_logits`` and ``prefill`` / ``decode_step`` as ``(const, state,
+*feed) → (outs, state')`` with ``state`` its cache's list — so a
+:class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.  Its
+programs return, beside token and logits, each MoE layer's load figures and
+chosen experts and the first MoE layer's routed experts' input and output at
+the rows the logits are taken at (what a check of the very programs that
+served needs to hold the experts alone against a reference; they stay on the
+device unless read); :class:`MLAObserver` turns the load figures into
+``decode.<engine>.*`` counters.  There is no suffix prefill over the latent
+pool yet, so ``supports`` is empty: an engine asked for a prefix cache or
+overcommit, and a beam session, refuse this model at build.
+
+Weights are ``dtype`` (bf16 as deployed); matmuls accumulate in float32;
+softmax, router scores and norm statistics are float32.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from .cache import PagedLatentCache
+from .model import MODEL_TYPES, _sample
+from ..kernels import mla as _mla
+from ..kernels import moe as _moe
+from ..observability import stats as _obs_stats
+from ..observability import trace as _trace
+
+MODEL_TYPE = "deepseek_v2"
+_ROPE_DEFAULT = {"factor": 1.0, "original_max_position_embeddings": 4096,
+                 "beta_fast": 32, "beta_slow": 1, "mscale": 1.0,
+                 "mscale_all_dim": 0.0}
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """The published keys this model reads, under their published names,
+    plus the deployment's per-stream ``max_seq_len`` and the weights'
+    ``dtype``."""
+
+    vocab_size: int
+    hidden_size: int = 64
+    num_hidden_layers: int = 3
+    num_attention_heads: int = 4
+    qk_nope_head_dim: int = 16
+    qk_rope_head_dim: int = 8
+    v_head_dim: int = 16
+    kv_lora_rank: int = 32
+    intermediate_size: int = 96
+    moe_intermediate_size: int = 32
+    n_routed_experts: int = 8
+    num_experts_per_tok: int = 3
+    n_shared_experts: int = 2
+    first_k_dense_replace: int = 1
+    norm_topk_prob: bool = False
+    routed_scaling_factor: float = 1.0
+    rms_norm_eps: float = 1e-6
+    rope_theta: float = 10000.0
+    rope_scaling: dict = dataclasses.field(
+        default_factory=lambda: dict(_ROPE_DEFAULT))
+    max_seq_len: int = 128
+    dtype: str = "bfloat16"
+
+    def to_dict(self) -> dict:
+        return dict(dataclasses.asdict(self), model_type=MODEL_TYPE)
+
+    @classmethod
+    def from_dict(cls, d: dict) -> "MLAConfig":
+        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
+                      if f.name in d})
+
+
+def _yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1.0 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, rs: dict) -> np.ndarray:
+    """``dim // 2`` rotary frequencies: below the correction range as they
+    are, above it divided by ``factor``, a linear ramp between."""
+    f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+    factor = float(rs["factor"])
+    if factor <= 1.0:
+        return f.astype(np.float32)
+    orig = float(rs["original_max_position_embeddings"])
+
+    def corr(beta):
+        return dim * math.log(orig / (beta * 2 * math.pi)) \
+            / (2 * math.log(theta))
+    lo = max(math.floor(corr(float(rs["beta_fast"]))), 0)
+    hi = min(math.ceil(corr(float(rs["beta_slow"]))), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - lo)
+                   / max(hi - lo, 1e-3), 0.0, 1.0)
+    return ((f / factor) * ramp + f * (1.0 - ramp)).astype(np.float32)
+
+
+def softmax_scale(cfg: MLAConfig) -> float:
+    rs = cfg.rope_scaling
+    m = _yarn_mscale(float(rs["factor"]), float(rs["mscale_all_dim"]))
+    return (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5 * m * m
+
+
+def rope_factor(cfg: MLAConfig) -> float:
+    """What YaRN multiplies cos and sin by: ``m(mscale) / m(mscale_all_dim)``
+    (1 where the two are equal, as published)."""
+    rs = cfg.rope_scaling
+    s = float(rs["factor"])
+    return _yarn_mscale(s, float(rs["mscale"])) \
+        / _yarn_mscale(s, float(rs["mscale_all_dim"]))
+
+
+def is_moe_layer(cfg: MLAConfig, i: int) -> bool:
+    return i >= cfg.first_k_dense_replace
+
+
+def param_shapes(cfg: MLAConfig) -> Dict[str, tuple]:
+    """name → (shape, std of a seeded random init; None: a norm weight)."""
+    D, H, V = cfg.hidden_size, cfg.num_attention_heads, cfg.vocab_size
+    dn, dr, dv, r = (cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                     cfg.v_head_dim, cfg.kv_lora_rank)
+    E, F = cfg.n_routed_experts, cfg.moe_intermediate_size
+    Fs = cfg.n_shared_experts * F
+    out = {"emb": ((V, D), 1.0), "final_norm": ((D,), None),
+           "head": ((D, V), D ** -0.5)}
+    for i in range(cfg.num_hidden_layers):
+        L = f"l{i}."
+        out.update({
+            L + "attn_norm": ((D,), None), L + "ffn_norm": ((D,), None),
+            L + "kv_norm": ((r,), None),
+            L + "wq": ((D, H * (dn + dr)), D ** -0.5),
+            L + "wkva": ((D, r + dr), D ** -0.5),
+            L + "wkvb": ((r, H * (dn + dv)), r ** -0.5),
+            L + "wo": ((H * dv, D), (H * dv) ** -0.5)})
+        if is_moe_layer(cfg, i):
+            out.update({
+                L + "router": ((D, E), D ** -0.5),
+                L + "e_gate": ((E, D, F), D ** -0.5),
+                L + "e_up": ((E, D, F), D ** -0.5),
+                L + "e_down": ((E, F, D), F ** -0.5),
+                L + "s_gate": ((D, Fs), D ** -0.5),
+                L + "s_up": ((D, Fs), D ** -0.5),
+                L + "s_down": ((Fs, D), Fs ** -0.5)})
+        else:
+            Fd = cfg.intermediate_size
+            out.update({L + "w_gate": ((D, Fd), D ** -0.5),
+                        L + "w_up": ((D, Fd), D ** -0.5),
+                        L + "w_down": ((Fd, D), Fd ** -0.5)})
+    return out
+
+
+def _mm(x, w):
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def _swiglu(x, wg, wu, wd):
+    g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    return _mm((jax.nn.silu(g) * u).astype(x.dtype), wd)
+
+
+class MLAObserver:
+    """``decode.<engine>.*`` series of a routed latent-attention model, fed
+    by what its programs return beside token and logits (``extra[0]``: each
+    MoE layer's ``[assignments, experts touched, largest load]``).  A
+    *dispatch* is one MoE layer of one program launch.  Each call is a span
+    (``decode::prefill.observe`` / ``decode::step.observe``, inside the
+    ``.wait`` of its launch) whose arguments are what it added to the
+    counters of the same names: the launch's own work, for a reader of a
+    trace that times that launch."""
+
+    def __init__(self, name: str, cache):
+        sc = _obs_stats.scope(f"decode.{name}")
+        self.prefill_assignments = sc.counter(
+            "prefill_routed_assignments", "token-expert assignments "
+            "computed by prefills (real prompt tokens only)")
+        self.step_assignments = sc.counter(
+            "step_routed_assignments", "token-expert assignments computed "
+            "by decode steps (live slots only)")
+        self.step_dispatches = sc.counter(
+            "step_moe_dispatches", "MoE layers run by decode steps")
+        self.step_touched = sc.counter(
+            "step_experts_touched", "experts with at least one row, summed "
+            "over the decode steps' MoE dispatches")
+        self.step_load_max_sum = sc.counter(
+            "step_expert_load_max_sum", "largest load of one expert, summed "
+            "over the decode steps' MoE dispatches")
+        self.load_max = sc.histogram(
+            "expert_load_max", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256,
+                                        512, 1024, 2048, 4096, 8192),
+            help_str="largest load of one expert a MoE dispatch (rows)")
+        self.prefill_real = sc.counter(
+            "prefill_real_tokens", "real prompt tokens prefilled")
+        self.prefill_pad = sc.counter(
+            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
+            "the prefill ladder")
+        self.prefill_sq = sc.counter(
+            "prefill_tokens_sq", "sum over prefills of the prompt length "
+            "squared (causal attention's work)")
+        self.context_tokens = sc.counter(
+            "step_context_tokens", "cached tokens the decode steps' "
+            "attention read, summed over steps (one layer)")
+        self.live_tokens = sc.gauge("latent_live_tokens")
+        sc.gauge("latent_pool_bytes").set(cache.nbytes)
+
+    def prefill(self, extra, prompt: int, bucket: int) -> None:
+        with _trace.span("decode::prefill.observe") as sp:
+            load = np.asarray(extra[0])
+            assignments = int(load[:, 0].sum())
+            self.prefill_assignments.inc(assignments)
+            for m in load[:, 2]:
+                self.load_max.observe(float(m))
+            self.prefill_real.inc(prompt)
+            self.prefill_pad.inc(bucket - prompt)
+            self.prefill_sq.inc(prompt * prompt)
+            sp.annotate(prefill_routed_assignments=assignments,
+                        prefill_tokens_sq=prompt * prompt)
+
+    def step(self, extra, live_tokens: int) -> None:
+        with _trace.span("decode::step.observe") as sp:
+            load = np.asarray(extra[0])
+            assignments, touched = (int(load[:, 0].sum()),
+                                    int(load[:, 1].sum()))
+            self.step_assignments.inc(assignments)
+            self.step_dispatches.inc(int(load.shape[0]))
+            self.step_touched.inc(touched)
+            self.step_load_max_sum.inc(int(load[:, 2].sum()))
+            for m in load[:, 2]:
+                self.load_max.observe(float(m))
+            self.context_tokens.inc(live_tokens)
+            self.live_tokens.set(live_tokens)
+            sp.annotate(step_routed_assignments=assignments,
+                        step_experts_touched=touched,
+                        step_context_tokens=live_tokens)
+
+
+class _MoEOuts:
+    """What a prefill or a step returns of its expert layers, collected a
+    layer at a time (:meth:`MLATransformerLM._layer`)."""
+
+    def __init__(self, cfg: MLAConfig):
+        self.cfg = cfg
+        self.loads, self.ids, self.probe = [], [], None
+
+    def add(self, load, ids, h, routed, rows) -> None:
+        self.loads.append(load)
+        self.ids.append(ids)
+        if self.probe is None:
+            self.probe = [h, routed] if rows is None else \
+                [h[rows], routed[rows]]
+
+    def outs(self, tokens: int) -> list:
+        """[load [n_moe, 3], ids [n_moe, tokens, K], x [rows, D], routed
+        [rows, D] float32]; a model with no expert layer returns them
+        empty."""
+        if self.loads:
+            return [jnp.stack(self.loads), jnp.stack(self.ids)] + self.probe
+        cfg = self.cfg
+        return [jnp.zeros((0, 3), jnp.int32),
+                jnp.zeros((0, tokens, cfg.num_experts_per_tok), jnp.int32),
+                jnp.zeros((0, cfg.hidden_size), jnp.dtype(cfg.dtype)),
+                jnp.zeros((0, cfg.hidden_size), jnp.float32)]
+
+
+class MLATransformerLM:
+    """One latent-attention MoE LM: config + the jit-ready functions.  As
+    with :class:`~paddle_tpu.decode.model.TransformerLM`, the one kernel
+    choice is the engine's ``attn_impl`` for the decode step's attention."""
+
+    supports = frozenset()
+
+    def __init__(self, config: MLAConfig):
+        self.config = config
+        self._inv_freq = jnp.asarray(yarn_inv_freq(
+            config.qk_rope_head_dim, config.rope_theta, config.rope_scaling))
+        self._rope_factor = rope_factor(config)
+        self._scale = softmax_scale(config)
+        self._row = _mla.row_width(config.kv_lora_rank,
+                                   config.qk_rope_head_dim)
+
+    # -- what an engine asks of a model ------------------------------------
+    @classmethod
+    def from_dict(cls, raw: dict) -> "MLATransformerLM":
+        return cls(MLAConfig.from_dict(raw))
+
+    def param_names(self) -> List[str]:
+        return list(param_shapes(self.config))
+
+    def make_cache(self, num_blocks: int, block_tokens: int,
+                   dtype: str = "float32") -> PagedLatentCache:
+        cfg = self.config
+        return PagedLatentCache(cfg.num_hidden_layers, cfg.kv_lora_rank,
+                                cfg.qk_rope_head_dim, self._row, num_blocks,
+                                block_tokens, dtype=dtype)
+
+    def observer(self, name: str, cache) -> MLAObserver:
+        return MLAObserver(name, cache)
+
+    # -- parameters --------------------------------------------------------
+    def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
+        """Seeded random weights (norm weights scattered about 1, so that a
+        norm left out shows)."""
+        rng = np.random.RandomState(seed)
+        dt = jnp.dtype(self.config.dtype)
+        out = {}
+        for name, (shape, std) in param_shapes(self.config).items():
+            w = (1.0 + 0.1 * rng.randn(*shape) if std is None
+                 else rng.randn(*shape) * std)
+            out[name] = np.asarray(w, np.float32).astype(dt)
+        return out
+
+    def param_list(self, params: Dict) -> List:
+        return [jnp.asarray(params[n]) for n in self.param_names()]
+
+    def _unpack(self, plist) -> Dict[str, jnp.ndarray]:
+        return dict(zip(self.param_names(), plist))
+
+    # -- shared layer math -------------------------------------------------
+    def _rms(self, x, w):
+        x32 = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+        return (x32 * jax.lax.rsqrt(var + self.config.rms_norm_eps)
+                * w.astype(jnp.float32)).astype(x.dtype)
+
+    def _rope(self, x, pos):
+        """x [..., dr] at positions pos [...] (broadcastable to x's leading
+        dims), half-split rotation."""
+        ang = pos[..., None].astype(jnp.float32) * self._inv_freq
+        cos = jnp.cos(ang) * self._rope_factor
+        sin = jnp.sin(ang) * self._rope_factor
+        x32 = x.astype(jnp.float32)
+        half = x.shape[-1] // 2
+        x1, x2 = x32[..., :half], x32[..., half:]
+        return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                               axis=-1).astype(x.dtype)
+
+    def _latent(self, p, i, x, pos):
+        """x [N, D] at positions pos [N] → q_nope [N, H, dn], q_pe [N, H, dr]
+        (rotated), c [N, r] (normed), k_pe [N, dr] (rotated): the last two
+        are what the cache holds."""
+        cfg = self.config
+        H, dn, dr, r = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                        cfg.qk_rope_head_dim, cfg.kv_lora_rank)
+        with jax.named_scope("mla_wq"):
+            q = _mm(x, p[f"l{i}.wq"]).reshape(x.shape[0], H, dn + dr)
+        with jax.named_scope("mla_wkva"):
+            kva = _mm(x, p[f"l{i}.wkva"])
+            c = self._rms(kva[:, :r], p[f"l{i}.kv_norm"])
+        with jax.named_scope("mla_rope"):
+            k_pe = self._rope(kva[:, r:], pos)
+            q_pe = self._rope(q[..., dn:], pos[:, None])
+        return q[..., :dn], q_pe, c, k_pe
+
+    def _wkvb(self, p, i):
+        cfg = self.config
+        w = p[f"l{i}.wkvb"].reshape(cfg.kv_lora_rank,
+                                    cfg.num_attention_heads,
+                                    cfg.qk_nope_head_dim + cfg.v_head_dim)
+        return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
+
+    def _expand(self, p, i, c):
+        """c [N, r] → k_nope [N, H, dn], v [N, H, dv]."""
+        wk, wv = self._wkvb(p, i)
+        with jax.named_scope("mla_wkvb"):
+            k = jnp.einsum("nr,rhd->nhd", c, wk,
+                           preferred_element_type=jnp.float32)
+            v = jnp.einsum("nr,rhd->nhd", c, wv,
+                           preferred_element_type=jnp.float32)
+        return k.astype(c.dtype), v.astype(c.dtype)
+
+    def _rows(self, c, k_pe, dtype):
+        """The cache rows of N tokens: ``[c | k_pe | 0]`` [N, W]."""
+        pad = self._row - c.shape[-1] - k_pe.shape[-1]
+        return jnp.concatenate(
+            [c, k_pe, jnp.zeros((c.shape[0], pad), c.dtype)],
+            axis=-1).astype(dtype)
+
+    def _attn_out(self, p, i, ctx):
+        """ctx [N, H, dv] → [N, D]."""
+        with jax.named_scope("mla_wo"):
+            return _mm(ctx.reshape(ctx.shape[0], -1), p[f"l{i}.wo"])
+
+    def _ffn(self, p, i, x, valid):
+        """x [N, D] → (ffn(x) [N, D], load [3] int32, ids [N, K] int32, the
+        routed experts' part alone [N, D] float32); a dense layer has none
+        of the last three."""
+        cfg = self.config
+        L = f"l{i}."
+        if not is_moe_layer(cfg, i):
+            with jax.named_scope("dense_ffn"):
+                return _swiglu(x, p[L + "w_gate"], p[L + "w_up"],
+                               p[L + "w_down"]), None, None, None
+        with jax.named_scope("moe_router"):
+            logits = jnp.dot(x, p[L + "router"],
+                             preferred_element_type=jnp.float32)
+            ids, w = _moe.route_topk(logits, cfg.num_experts_per_tok,
+                                     cfg.routed_scaling_factor,
+                                     cfg.norm_topk_prob)
+        with jax.named_scope("moe_routed"):
+            y, load = _moe.routed_experts(x, ids, w, valid, p[L + "e_gate"],
+                                          p[L + "e_up"], p[L + "e_down"])
+        with jax.named_scope("moe_shared"):
+            sh = _swiglu(x, p[L + "s_gate"], p[L + "s_up"], p[L + "s_down"])
+        return (y + sh.astype(jnp.float32)).astype(x.dtype), load, ids, y
+
+    def _layer(self, p, i, x, valid, attend, moe: Optional["_MoEOuts"] = None,
+               rows=None):
+        """One block over rows x [N, D]; ``attend(h)`` is the attention of
+        the normed rows (the paths differ only there).  ``moe`` collects an
+        expert layer's outputs, the first one's experts' input and output at
+        ``rows`` (None: every row) among them."""
+        x = x + attend(self._rms(x, p[f"l{i}.attn_norm"]))
+        h = self._rms(x, p[f"l{i}.ffn_norm"])
+        f, load, ids, routed = self._ffn(p, i, h, valid)
+        if moe is not None and load is not None:
+            moe.add(load, ids, h, routed, rows)
+        return x + f
+
+    def _head(self, p, x):
+        with jax.named_scope("lm_head"):
+            return jnp.dot(self._rms(x, p["final_norm"]), p["head"],
+                           preferred_element_type=jnp.float32)
+
+    # -- full forward (the parity anchor) ----------------------------------
+    def full_logits(self, plist, tokens, lengths=None):
+        """tokens [B, T] int32 → logits [B, T, V] float32: plain causal
+        attention by the expanded formula, no cache."""
+        p = self._unpack(plist)
+        cfg = self.config
+        B, T = tokens.shape
+        pos = jnp.tile(jnp.arange(T, dtype=jnp.int32), B)
+        valid = jnp.ones((B * T,), bool) if lengths is None else \
+            (jnp.arange(T)[None, :] < lengths[:, None]).reshape(B * T)
+        qi = jnp.arange(T)
+        mask = (qi[:, None] >= qi[None, :])[None, None]
+
+        def heads(a):                       # [B*T, H, d] → [B, H, T, d]
+            return a.reshape(B, T, *a.shape[1:]).transpose(0, 2, 1, 3)
+
+        x = p["emb"][tokens.reshape(B * T)]
+        for i in range(cfg.num_hidden_layers):
+            def attend(h, i=i):
+                q_nope, q_pe, c, k_pe = self._latent(p, i, h, pos)
+                k_nope, v = self._expand(p, i, c)
+                q = heads(jnp.concatenate([q_nope, q_pe], -1))
+                k = heads(jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_pe[:, None], q_pe.shape)],
+                    -1))
+                s = jnp.einsum("bhqd,bhkd->bhqk", q.astype(jnp.float32),
+                               k.astype(jnp.float32)) * self._scale
+                w = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
+                ctx = jnp.einsum("bhqk,bhkd->bqhd", w,
+                                 heads(v).astype(jnp.float32))
+                return self._attn_out(
+                    p, i, ctx.reshape(B * T, *ctx.shape[2:]).astype(h.dtype))
+            x = self._layer(p, i, x, valid, attend)
+        return self._head(p, x).reshape(B, T, -1)
+
+    # -- prefill -----------------------------------------------------------
+    def prefill(self, plist, state, tokens, length, block_table, seed,
+                temperature, top_k):
+        """state ``[latent pool]``, tokens [1, Tb] (bucket-padded), length []
+        int32, block_table [MB] int32 → ([next_token [], logits [V], load
+        [n_moe, 3], ids [n_moe, Tb, K], the first MoE layer's experts' input
+        [1, D] and routed output [1, D] float32 at the last prompt
+        position], state').  Every real position's row lands in the
+        request's blocks, pad positions in trash block 0; pad tokens are
+        routed to no expert."""
+        cfg = self.config
+        p = self._unpack(plist)
+        (pool,) = state
+        Tb = tokens.shape[1]
+        bs = pool.shape[2]
+        MB = block_table.shape[0]
+        pos = jnp.arange(Tb, dtype=jnp.int32)
+        valid = pos < length
+        blocks = jnp.where(valid, block_table[jnp.minimum(pos // bs, MB - 1)],
+                           0)
+        offsets = pos % bs
+        last = jnp.maximum(length - 1, 0)
+        moe = _MoEOuts(cfg)
+        x = p["emb"][tokens[0]]
+        for i in range(cfg.num_hidden_layers):
+            def attend(h, i=i):
+                nonlocal pool
+                q_nope, q_pe, c, k_pe = self._latent(p, i, h, pos)
+                with jax.named_scope("mla_cache_write"):
+                    pool = pool.at[i, blocks, offsets].set(
+                        self._rows(c, k_pe, pool.dtype))
+                k_nope, v = self._expand(p, i, c)
+                q = jnp.concatenate([q_nope, q_pe], -1)
+                k = jnp.concatenate(
+                    [k_nope, jnp.broadcast_to(k_pe[:, None], q_pe.shape)],
+                    -1)
+                with jax.named_scope("mla_attn"):
+                    ctx = _mla.prefill_attention(
+                        q.transpose(1, 0, 2), k.transpose(1, 0, 2),
+                        v.transpose(1, 0, 2), self._scale)
+                return self._attn_out(p, i, ctx.transpose(1, 0, 2))
+            x = self._layer(p, i, x, valid, attend, moe, last[None])
+        logits = self._head(p, x[last][None])[0]
+        with jax.named_scope("sampling"):
+            tok = _sample(logits[None], seed[None],
+                          jnp.zeros((1,), jnp.int32), temperature[None],
+                          top_k[None])[0]
+        return [tok, logits] + moe.outs(Tb), [pool]
+
+    # -- decode step -------------------------------------------------------
+    def decode_step(self, plist, state, tokens, positions, block_tables,
+                    seeds, steps, temperature, top_k, attn_impl=None):
+        """state ``[latent pool]``, tokens / positions [S], block_tables
+        [S, MB] → ([next_tokens [S], logits [S, V], load [n_moe, 3], ids
+        [n_moe, S, K], the first MoE layer's experts' input [S, D] and routed
+        output [S, D] float32], state').  A slot without a stream feeds an
+        all-zero block table (block 0 is never a stream's): it writes and
+        reads the trash block and is routed to no expert."""
+        cfg = self.config
+        p = self._unpack(plist)
+        (pool,) = state
+        S = tokens.shape[0]
+        bs = pool.shape[2]
+        r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+        cl = positions + 1
+        live = block_tables[:, 0] != 0
+        blocks = block_tables[jnp.arange(S), positions // bs]
+        offsets = positions % bs
+        moe = _MoEOuts(cfg)
+        x = p["emb"][tokens]
+        for i in range(cfg.num_hidden_layers):
+            def attend(h, i=i):
+                nonlocal pool
+                q_nope, q_pe, c, k_pe = self._latent(p, i, h, positions)
+                with jax.named_scope("mla_cache_write"):
+                    pool = pool.at[i, blocks, offsets].set(
+                        self._rows(c, k_pe, pool.dtype))
+                wk, wv = self._wkvb(p, i)
+                with jax.named_scope("mla_wkvb"):
+                    q_abs = jnp.einsum(
+                        "shd,rhd->shr", q_nope, wk,
+                        preferred_element_type=jnp.float32).astype(h.dtype)
+                q_row = jnp.concatenate(
+                    [q_abs, q_pe, jnp.zeros(
+                        q_pe.shape[:2] + (self._row - r - dr,), h.dtype)],
+                    -1)
+                with jax.named_scope("mla_attn"):
+                    u = _mla.decode_attention(q_row, pool, block_tables, cl,
+                                              i, r, self._scale,
+                                              impl=attn_impl)
+                with jax.named_scope("mla_wkvb"):
+                    ctx = jnp.einsum(
+                        "shr,rhd->shd", u.astype(h.dtype), wv,
+                        preferred_element_type=jnp.float32).astype(h.dtype)
+                return self._attn_out(p, i, ctx)
+            x = self._layer(p, i, x, live, attend, moe)
+        logits = self._head(p, x)
+        with jax.named_scope("sampling"):
+            toks = _sample(logits, seeds, steps, temperature, top_k)
+        return [toks, logits] + moe.outs(S), [pool]
+
+
+MODEL_TYPES[MODEL_TYPE] = MLATransformerLM.from_dict
+
+__all__ = ["MLAConfig", "MLATransformerLM", "MLAObserver", "param_shapes",
+           "yarn_inv_freq", "softmax_scale", "rope_factor", "is_moe_layer"]

@@ -36,12 +36,13 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .cache import PagedKVCache
 from ..kernels.attention import decode_attention, paged_attention_xla
 from ..kernels.quant import (QMAX, SCALE_EPS, kv_dequantize, kv_head_amax,
                              kv_quantize)
@@ -89,6 +90,29 @@ def _pos_table(max_len: int, d_model: int) -> np.ndarray:
     return table.astype("float32")
 
 
+def _split_state(state):
+    """``[kc, vc]`` or, quantized, ``[kc, vc, ks, vs]`` → all four (the
+    scale pools None when the cache stores floats)."""
+    kc, vc = state[0], state[1]
+    ks, vs = (state[2], state[3]) if len(state) == 4 else (None, None)
+    return kc, vc, ks, vs
+
+
+def _join_state(kc, vc, ks, vs) -> list:
+    return [kc, vc] if ks is None else [kc, vc, ks, vs]
+
+
+class NoObserver:
+    """The observer of a model whose programs return a token and logits
+    only (:meth:`TransformerLM.observer`)."""
+
+    def prefill(self, extra, prompt: int, bucket: int) -> None:
+        pass
+
+    def step(self, extra, live_tokens: int) -> None:
+        pass
+
+
 def _param_names(cfg: LMConfig) -> List[str]:
     names = ["emb"]
     for i in range(cfg.n_layer):
@@ -107,10 +131,36 @@ class TransformerLM:
     ``save_lm``/``load_lm``); the engine device-puts them once and
     passes them as ``const`` through ``Executor.run_callable``."""
 
+    # what of the engine's refcounted block lifecycle the model's entry
+    # points can serve (an engine asked for another refuses at build)
+    supports = frozenset({"prefix_cache", "overcommit", "beam"})
+
     def __init__(self, config: LMConfig):
         self.config = config
         self._pos = jnp.asarray(_pos_table(config.max_seq_len,
                                            config.d_model))
+
+    # -- what an engine asks of a model ------------------------------------
+    @classmethod
+    def from_dict(cls, raw: dict) -> "TransformerLM":
+        return cls(LMConfig.from_dict(raw))
+
+    def param_names(self) -> List[str]:
+        return _param_names(self.config)
+
+    def make_cache(self, num_blocks: int, block_tokens: int,
+                   dtype: str = "float32") -> PagedKVCache:
+        """The state this model's streams need: K and V of every layer,
+        paged.  Its ``state()`` is the ``state`` of the three entry
+        points."""
+        cfg = self.config
+        return PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim,
+                            num_blocks, block_tokens, dtype=dtype)
+
+    def observer(self, name: str, cache) -> NoObserver:
+        """Reads what the programs return beside token and logits, for
+        the engine ``name``: here nothing."""
+        return NoObserver()
 
     # -- parameters --------------------------------------------------------
     def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -271,12 +321,12 @@ class TransformerLM:
         return cache, scales
 
     # -- prefill -----------------------------------------------------------
-    def prefill(self, plist, kc, vc, tokens, length, block_table,
-                seed, temperature, top_k, ks=None, vs=None):
-        """tokens [1, Tb] (bucket-padded), length [] int32, block_table
-        [MB] int32 → (kc', vc', next_token [] int32, logits [V]) — or,
-        with the int8 scale pools ``ks``/``vs`` threaded (quantized
-        cache), (kc', vc', ks', vs', next_token, logits).
+    def prefill(self, plist, state, tokens, length, block_table,
+                seed, temperature, top_k):
+        """state ``[kc, vc]`` — or, with the int8 scale pools threaded
+        (quantized cache), ``[kc, vc, ks, vs]`` — tokens [1, Tb]
+        (bucket-padded), length [] int32, block_table [MB] int32 →
+        ([next_token [] int32, logits [V]], state').
 
         One full causal forward over the padded prompt; every real
         position's K/V lands in the request's blocks, pad positions
@@ -289,6 +339,7 @@ class TransformerLM:
         decode step."""
         cfg = self.config
         p = self._unpack(plist)
+        kc, vc, ks, vs = _split_state(state)
         Tb = tokens.shape[1]
         bs = kc.shape[2]
         MB = block_table.shape[0]
@@ -326,23 +377,19 @@ class TransformerLM:
         tok = _sample(logits[None], seed[None],
                       jnp.zeros((1,), jnp.int32), temperature[None],
                       top_k[None])[0]
-        if ks is None:
-            return kc, vc, tok, logits
-        return kc, vc, ks, vs, tok, logits
+        return [tok, logits], _join_state(kc, vc, ks, vs)
 
     # -- suffix prefill (prefix-cache hits / preemption resume) ------------
-    def prefill_suffix(self, plist, kc, vc, tokens, start, length,
-                       block_table, seed, temperature, top_k,
-                       ks=None, vs=None):
-        """tokens [1, Sb] (bucket-padded suffix), start [] int32 (how
-        many leading positions are already resident in the cache —
-        block-aligned prefix-cache hits), length [] int32 (total real
-        sequence length; the suffix is positions start..length-1),
-        block_table [MB] int32 → (kc', vc', next_token [] int32,
-        logits [V]); with the int8 scale pools ``ks``/``vs`` threaded,
-        (kc', vc', ks', vs', next_token, logits) and the gathered
-        context (cached prefix INCLUDED) is dequantized per block
-        before the dense masked attention.
+    def prefill_suffix(self, plist, state, tokens, start, length,
+                       block_table, seed, temperature, top_k):
+        """state as :meth:`prefill`, tokens [1, Sb] (bucket-padded
+        suffix), start [] int32 (how many leading positions are already
+        resident in the cache — block-aligned prefix-cache hits), length
+        [] int32 (total real sequence length; the suffix is positions
+        start..length-1), block_table [MB] int32 → ([next_token [] int32,
+        logits [V]], state'); with the int8 scale pools threaded the
+        gathered context (cached prefix INCLUDED) is dequantized per
+        block before the dense masked attention.
 
         The prompt's cached prefix is NOT recomputed: suffix K/V is
         scattered into the request's blocks first, then — because
@@ -361,6 +408,7 @@ class TransformerLM:
         :meth:`prefill` (token index 0)."""
         cfg = self.config
         p = self._unpack(plist)
+        kc, vc, ks, vs = _split_state(state)
         Sb = tokens.shape[1]
         bs = kc.shape[2]
         MB = block_table.shape[0]
@@ -412,21 +460,18 @@ class TransformerLM:
         tok = _sample(logits[None], seed[None],
                       jnp.zeros((1,), jnp.int32), temperature[None],
                       top_k[None])[0]
-        if ks is None:
-            return kc, vc, tok, logits
-        return kc, vc, ks, vs, tok, logits
+        return [tok, logits], _join_state(kc, vc, ks, vs)
 
     # -- decode step (the continuous-batching hot dispatch) ----------------
-    def decode_step(self, plist, kc, vc, tokens, positions, block_tables,
-                    seeds, steps, temperature, top_k, attn_impl=None,
-                    ks=None, vs=None):
-        """tokens [S] int32 (each slot's last token), positions [S]
-        int32 (where that token sits), block_tables [S, MB] int32,
-        seeds [S] uint32 + steps [S] int32 (per-request sampling
-        identity — see :func:`_sample`) → (kc', vc', next_tokens [S],
-        logits [S, V]); with the int8 scale pools ``ks``/``vs``
-        threaded, (kc', vc', ks', vs', next_tokens, logits) and the
-        paged attention dequantizes per-block-per-head in the kernel.
+    def decode_step(self, plist, state, tokens, positions, block_tables,
+                    seeds, steps, temperature, top_k, attn_impl=None):
+        """state as :meth:`prefill`, tokens [S] int32 (each slot's last
+        token), positions [S] int32 (where that token sits),
+        block_tables [S, MB] int32, seeds [S] uint32 + steps [S] int32
+        (per-request sampling identity — see :func:`_sample`) →
+        ([next_tokens [S], logits [S, V]], state'); with the int8 scale
+        pools threaded the paged attention dequantizes
+        per-block-per-head in the kernel.
 
         Writes each slot's K/V at (position // bs, position % bs) via
         its block table, then attends over positions 0..position
@@ -436,6 +481,7 @@ class TransformerLM:
         fixed shapes, no branches."""
         cfg = self.config
         p = self._unpack(plist)
+        kc, vc, ks, vs = _split_state(state)
         bs = kc.shape[2]
         cl = positions + 1
         blocks = block_tables[jnp.arange(tokens.shape[0]),
@@ -458,9 +504,7 @@ class TransformerLM:
             h = self._post_attn(p, i, h, ctx.astype(h.dtype))
         logits = h @ p["out_proj"]
         toks = _sample(logits, seeds, steps, temperature, top_k)
-        if ks is None:
-            return kc, vc, toks, logits
-        return kc, vc, ks, vs, toks, logits
+        return [toks, logits], _join_state(kc, vc, ks, vs)
 
 
 def _hash_uniform(seeds, steps, kk):
@@ -516,32 +560,57 @@ _CONFIG_FILE = "decode_config.json"
 _PARAMS_FILE = "params.npz"
 
 
-def save_lm(dirname: str, config: LMConfig, params: Dict) -> None:
+# ``model_type`` of a saved config → builder of its model from the config's
+# dict; a model module other than this one adds itself on import
+MODEL_TYPES: Dict[str, Callable] = {}
+
+_BF16_KEY = "::bf16"     # npz has no bfloat16: such arrays go as uint16 views
+
+
+def save_lm(dirname: str, config, params: Dict) -> None:
     """Write a decode-servable model dir (config JSON + params npz);
-    atomic per file (tmp + replace) like io.py's save discipline."""
+    atomic per file (tmp + replace) like io.py's save discipline.
+    ``config`` is an :class:`LMConfig` or another model's config whose
+    ``to_dict()`` carries the ``model_type`` that tells :func:`load_lm`
+    which model to build."""
     os.makedirs(dirname, exist_ok=True)
     cpath = os.path.join(dirname, _CONFIG_FILE)
     with open(cpath + ".tmp", "w") as f:
         json.dump(config.to_dict(), f, indent=2)
     os.replace(cpath + ".tmp", cpath)
     ppath = os.path.join(dirname, _PARAMS_FILE)
-    np.savez(ppath + ".tmp.npz",
-             **{k: np.asarray(v) for k, v in params.items()})
+    arrays = {}
+    for k, v in params.items():
+        v = np.asarray(v)
+        if v.dtype == jnp.bfloat16:
+            arrays[k + _BF16_KEY] = v.view(np.uint16)
+        else:
+            arrays[k] = v
+    np.savez(ppath + ".tmp.npz", **arrays)
     os.replace(ppath + ".tmp.npz", ppath)
 
 
 def load_lm(dirname: str):
-    """(TransformerLM, params dict) from a :func:`save_lm` dir."""
+    """(model, params dict) from a :func:`save_lm` dir: a
+    :class:`TransformerLM`, or the model that registered the config's
+    ``model_type`` in :data:`MODEL_TYPES`."""
     with open(os.path.join(dirname, _CONFIG_FILE)) as f:
-        cfg = LMConfig.from_dict(json.load(f))
+        raw = json.load(f)
     with np.load(os.path.join(dirname, _PARAMS_FILE)) as z:
-        params = {k: z[k].copy() for k in z.files}
-    missing = [n for n in _param_names(cfg) if n not in params]
+        params = {}
+        for k in z.files:
+            if k.endswith(_BF16_KEY):
+                params[k[:-len(_BF16_KEY)]] = z[k].view(jnp.bfloat16)
+            else:
+                params[k] = z[k].copy()
+    model = MODEL_TYPES.get(raw.get("model_type"),
+                            TransformerLM.from_dict)(raw)
+    missing = [n for n in model.param_names() if n not in params]
     if missing:
         raise ValueError(f"model dir {dirname!r} is missing params: "
                          f"{missing[:4]}{'...' if len(missing) > 4 else ''}")
-    return TransformerLM(cfg), params
+    return model, params
 
 
 __all__ = ["LMConfig", "TransformerLM", "save_lm", "load_lm",
-           "paged_attention_xla", "TOPK_MAX"]
+           "paged_attention_xla", "TOPK_MAX", "MODEL_TYPES", "NoObserver"]

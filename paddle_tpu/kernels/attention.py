@@ -343,7 +343,7 @@ def _prep_padded(q, k, v, kv_mask, block_q, block_k):
     Tq_p, Tk_p = q4.shape[2], k4.shape[2]
     qf = q4.reshape(B * H, Tq_p, D)
     kf = k4.reshape(B * H, Tk_p, D)
-    vf = v4.reshape(B * H, Tk_p, D)
+    vf = v4.reshape(B * H, Tk_p, v.shape[-1])
     if kv_mask is None and pad_k == 0:
         # never read (has_mask=False); one block wide — the mask index
         # map pins block (b, 0, 0), so no larger buffer is ever touched
@@ -397,9 +397,12 @@ def _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate=0.0,
     qf, kf, vf, maskf, Tq_p, Tk_p, has_mask = _prep_padded(
         q, k, v, kv_mask, block_q, block_k)
     num_kb = Tk_p // block_k
+    # v may be narrower than q and k (latent attention's prefill: 192-wide
+    # scores, 128-wide values); the output takes v's width
+    Dv = v.shape[-1]
     # ones-lane denominator (measured +28% on the D=64 seq-8192 fwd)
-    ones_lane = _lane_pack_ok(D, dropout_rate)
-    D_v = D + 1 if ones_lane else D
+    ones_lane = _lane_pack_ok(Dv, dropout_rate)
+    D_v = Dv + 1 if ones_lane else Dv
     if ones_lane:
         vf = _append_ones_lane(vf)
 
@@ -408,12 +411,12 @@ def _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate=0.0,
         _flash_fwd_kernel, block_k=block_k, sm_scale=sm_scale,
         causal=causal, dropout_rate=float(dropout_rate),
         block_q=block_q, num_kb=num_kb, has_mask=has_mask,
-        ones_lane=ones_lane, head_dim=D)
+        ones_lane=ones_lane, head_dim=Dv)
     out, lse = pl.pallas_call(
         kernel,
         name="flash_fwd",
         out_shape=[
-            _sds((B * H, Tq_p, D), q.dtype, qf),
+            _sds((B * H, Tq_p, Dv), q.dtype, qf),
             _sds((B * H, 1, Tq_p), jnp.float32, qf),
         ],
         grid=(B * H, Tq_p // block_q, num_kb),
@@ -425,7 +428,7 @@ def _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate=0.0,
             pl.BlockSpec((None, 1, block_k), mask_map),
         ],
         out_specs=[
-            pl.BlockSpec((None, block_q, D), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((None, block_q, Dv), lambda b, i, j: (b, i, 0)),
             pl.BlockSpec((None, 1, Tq_p), lambda b, i, j: (b, 0, 0)),
         ],
         scratch_shapes=[
@@ -435,7 +438,7 @@ def _pallas_fwd(q, k, v, kv_mask, causal, sm_scale, dropout_rate=0.0,
         ],
         interpret=interpret,
     )(_seed_arr(dropout_seed), qf, kf, vf, maskf)
-    return out.reshape(B, H, Tq_p, D)[:, :, :Tq, :], lse
+    return out.reshape(B, H, Tq_p, Dv)[:, :, :Tq, :], lse
 
 
 def mha_pallas(q, k, v, kv_mask=None, causal=False, sm_scale=None,

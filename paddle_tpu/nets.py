@@ -143,6 +143,13 @@ def switch_moe(input, num_experts, d_ffn, capacity_factor=1.25,
       capacity limit this batch (overflow tokens pass through as
       zeros); a rising value means the router is hot-spotting or
       ``capacity_factor`` is too small.
+
+    This is the TRAINER's mixture (top-1, a capacity, dense one-hot
+    dispatch over a mesh axis).  Serving computes every assignment and
+    drops none: the decode plane's routed experts — top-k of a softmax
+    router by sort, a grouped SwiGLU over the experts — are
+    :func:`paddle_tpu.kernels.moe.routed_experts`
+    (``decode/mla.py`` is its caller).
     """
     from .core import unique_name
 

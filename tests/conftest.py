@@ -31,3 +31,26 @@ def pytest_configure(config):
         "markers", "chaos_lite: tier-1-safe chaos scenarios (one "
         "kill-promote pserver run + master lease-replay); the full flap "
         "matrix stays slow")
+
+
+import pytest  # noqa: E402
+
+# process-wide counters that "must be zero" wherever a run is judged (the
+# benchmark drivers and chip_smoke.py read their absolute values)
+_MUST_BE_ZERO = ("fallbacks", "faults", "runtime_disables")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _a_module_leaves_no_injected_fault_behind():
+    """A module that injects faults (a corrupted cache entry, a kernel taken
+    by its fallback on purpose) raises process-wide counters; the modules
+    that a worker happens to run after it must not read them as their own —
+    which files share a worker is the scheduler's choice."""
+    yield
+    from paddle_tpu.observability import stats
+    registry = stats.default_registry()
+    for name in registry.names():
+        if name.endswith(_MUST_BE_ZERO):
+            metric = registry.get(name)
+            if getattr(metric, "kind", "") == "counter":
+                metric.reset()

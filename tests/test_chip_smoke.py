@@ -98,6 +98,20 @@ def test_decode_server_phase_tiny():
     assert rec["prefill"]["temp_bytes"] < rec["pool_bytes"]
 
 
+def test_latent_lm_phase_tiny():
+    out = chip_smoke.phase_latent_lm(
+        on_chip=False, vocab=64, hidden=32, heads=2, nope=16, rope=8,
+        v_dim=16, rank=32, dense=48, expert=16, experts=4, top_k=2, layers=2,
+        max_seq_len=64, max_slots=2, block_tokens=4, prefill_bucket=16,
+        prompt_lens=(5, 16, 9), new_tokens=(6, 3, 4), dtype="float32")
+    assert out["tokens_checked"] == 13 and out["tokens_exact"] == 13
+    assert out["cache"]["kind"] == "latent" and out["cache"]["row_width"] == 128
+    assert not any(out["fallbacks"].values())
+    rec = out["pool_in_place"]
+    assert set(rec) == {"pool_bytes", "step", "prefill"}
+    assert rec["prefill"]["pool_copies"] == 0
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

@@ -85,7 +85,7 @@ def _shapes(one_chip, kv_dtype):
     return plist, state, feeds
 
 
-def _program(model, name, quantized):
+def _program(model, name):
     """fn(feed, state, const) as the engine hands it to run_callable."""
     call = {"step": lambda *a, **kw: model.decode_step(
                 *a, attn_impl="pallas", **kw),
@@ -93,9 +93,7 @@ def _program(model, name, quantized):
             "prefill_suffix": model.prefill_suffix}[name]
 
     def fn(feed, state, const):
-        kw = dict(ks=state[2], vs=state[3]) if quantized else {}
-        out = call(const, state[0], state[1], *feed, **kw)
-        return list(out[-2:]), list(out[:-2])
+        return call(const, state, *feed)
     return fn
 
 
@@ -103,7 +101,7 @@ def _program(model, name, quantized):
 @pytest.mark.parametrize("name", ["step", "prefill", "prefill_suffix"])
 def test_pool_is_neither_copied_nor_relaid(one_chip, mosaic, name, kv_dtype):
     plist, state, feeds = _shapes(one_chip, kv_dtype)
-    fn = _program(TransformerLM(CFG), name, kv_dtype == "int8")
+    fn = _program(TransformerLM(CFG), name)
     compiled = jax.jit(fn, donate_argnums=(1,)).lower(
         feeds[name], state, plist).compile()
     text = compiled.as_text()

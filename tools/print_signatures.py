@@ -84,6 +84,7 @@ MODULES = [
     "paddle_tpu.decode",
     "paddle_tpu.decode.cache",
     "paddle_tpu.decode.model",
+    "paddle_tpu.decode.mla",
     "paddle_tpu.decode.engine",
     "paddle_tpu.decode.server",
     "paddle_tpu.decode.client",

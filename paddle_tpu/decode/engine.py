@@ -63,8 +63,30 @@ past their adopted prefix, so forks are the beam decoder's path
 (:mod:`paddle_tpu.decode.beam`); the step-side check is the safety
 invariant that makes that true by construction.
 
+Token fan-out.  Reading a step and telling its streams are two
+things.  At the read the engine BOOKS the step (span
+``decode::step.book``): counters, slot state, each token onto its
+handle's record, retirement — so the admission sweep that follows sees
+the freed slots and blocks.  The wake-ups (one ``queue.put`` a live
+stream, each setting off a server thread and a reader) go onto
+``_fanout`` and out under ``decode::step.emit`` right AFTER the next
+step's dispatch: that herd then runs while the device computes and this
+thread waits with the interpreter released, instead of holding the next
+dispatch back with the device idle.  The order ``… wait n → book n →
+admit → prefill(s) → retire → feed → dispatch n+1 → emit n → wait n+1``
+keeps step n read and observed before step n+1 is dispatched.  When no
+step will follow (the last stream left, an error, ``close()``) the list
+goes out at once, before anything else is told to a handle; a FIN queues
+behind whatever is pending, so a stream always gets token … token, FIN;
+a prefill's own first token goes out at once (it is the TTFT).  The
+price: a step's tokens reach their streams one feed + dispatch and the
+prefills admitted in between later — ``fanout_delay_ms`` says how much.
+
 Observability: ``decode.<name>.*`` counters/gauges/histograms plus the
-``/decodez`` debug page (:func:`DecodeEngine.decodez`).
+``/decodez`` debug page (:func:`DecodeEngine.decodez`); among them
+``fanout_delay_ms`` (histogram: read of a step → its tokens handed out,
+for the steps handed out behind a dispatch) and ``fanout_immediate``
+(hand-outs with no step in flight).
 """
 from __future__ import annotations
 
@@ -97,6 +119,13 @@ from ..serving.batcher import BucketLadder, Overloaded, RequestTooLong
 # minus queue wait), decode = first token -> stream finished.  The
 # three sum to the request's end-to-end wall by construction
 DECODE_PHASES = ("queue", "prefill", "decode")
+
+# a step's tokens wait for one feed + dispatch and the prefills admitted
+# before it: a few ms to a few tens, which the default ladder's 5/10/25/50
+# would not tell apart
+_FANOUT_MS_BUCKETS = (0.5, 1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 8.0, 10.0, 12.5,
+                      15.0, 20.0, 25.0, 35.0, 50.0, 75.0, 100.0, 150.0,
+                      250.0, 500.0, 1000.0, 5000.0)
 
 
 class SamplingParams:
@@ -168,10 +197,16 @@ class DecodeHandle:
         self._cancelled = threading.Event()
 
     # -- engine side -------------------------------------------------------
-    def _emit(self, token: int, logits: Optional[np.ndarray]) -> None:
+    # A token is BOOKED the moment the engine has read it (the record
+    # that preemption, the audit hash and ``result()`` read) and EMITTED
+    # when its reader is woken; a decode step's tokens are emitted one
+    # dispatch later than they are booked (module doc, "Token fan-out")
+    def _book(self, token: int, logits: Optional[np.ndarray]) -> None:
         self._tokens.append(int(token))
         if logits is not None:
             self._logits.append(logits)
+
+    def _emit(self, token: int) -> None:
         self._q.put(int(token))
 
     def _finish(self, reason: str) -> None:
@@ -402,6 +437,15 @@ class _EngineStats:
             "token_ms",
             help_str="per-stream inter-token interval (what a client "
                      "perceives as per-token latency)")
+        self.fanout_delay_ms = sc.histogram(
+            "fanout_delay_ms", buckets=_FANOUT_MS_BUCKETS,
+            help_str="read of a decode step -> its tokens handed to "
+                     "their streams, for the steps handed out behind "
+                     "the next step's dispatch (steps - count went out "
+                     "at once)")
+        self.fanout_immediate = sc.counter(
+            "fanout_immediate", "token hand-outs made with no step in "
+            "flight (the last stream left, an error, close)")
 
     def latency(self) -> _LatencyStats:
         """The flag-gated bundle (lazy: see :class:`_LatencyStats`)."""
@@ -525,6 +569,11 @@ class DecodeEngine:
                                 np.int32)
         self._rid = itertools.count(1)
         self._closed = False
+        # token fan-out (module doc): what the last step booked and no
+        # stream has been told yet, as ordered (bound method, argument)
+        # calls; only the engine thread touches it
+        self._fanout: List[tuple] = []
+        self._fanout_t_read = 0.0
         # memory anatomy (FLAGS_memory_attribution): the KV block pool
         # registers on the process MemoryLedger — pool bytes, per-state
         # block counts (incl. parked LRU blocks), bytes-per-resident-
@@ -644,8 +693,12 @@ class DecodeEngine:
                 try:
                     self._decode_step()
                 except Exception as e:   # noqa: BLE001
+                    # no step is in flight: what was computed goes out
+                    # before a stream is requeued or failed
+                    self._flush_fanout()
                     if not self._recover_oom(e):
                         self._fail_all(e)
+        self._flush_fanout()
         for req in pending:
             req.handle._fail(RuntimeError("decode engine closed"))
         for slot in break_slots:
@@ -662,7 +715,7 @@ class DecodeEngine:
             dropped = self._pending.pop(0)
             if dropped.tl is not None:
                 self.stats.latency().cancelled.inc()
-            dropped.handle._finish("cancelled")
+            self._tell_finish(dropped.handle, "cancelled")
         bs = self.cache.block_tokens
         for i, slot in enumerate(self._slots):
             if slot is not None or not self._pending:
@@ -847,7 +900,8 @@ class DecodeEngine:
                 lat.prefill_tokens.inc(P)
                 lat.pad_prefill_tokens.inc(bucket - P)
             self._register_prefix(slot, req.prompt)
-            req.handle._emit(first, logits_np)
+            req.handle._book(first, logits_np)
+            req.handle._emit(first)   # at once: this wake-up is the TTFT
             self._maybe_finish(i, slot, first)
 
     def _prefill_partial(self, i: int, slot: _Slot, req: DecodeRequest,
@@ -944,7 +998,8 @@ class DecodeEngine:
             logits_np = np.asarray(logits) if self.capture_logits else None
         slot.last_token = first
         self.stats.tokens.inc()
-        req.handle._emit(first, logits_np)
+        req.handle._book(first, logits_np)
+        req.handle._emit(first)
         self._maybe_finish(i, slot, first)
 
     def _register_prefix(self, slot: _Slot, seq: np.ndarray) -> None:
@@ -1003,6 +1058,7 @@ class DecodeEngine:
                 topks[i] = slot.req.sampling.top_k
         sp.annotate(live=len(live))
         if not live:
+            self._flush_fanout()   # every stream was retired: no dispatch
             return
         model, impl = self.model, self._attn_impl
 
@@ -1026,19 +1082,29 @@ class DecodeEngine:
             [tokens, positions, tables, seeds, steps, temps, topks],
             state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
+        # the PREVIOUS step's tokens go out now: the wake-ups, and the
+        # stream and reader threads they set off, run while the device
+        # computes this step and this thread waits for it below with
+        # the interpreter released
+        self._flush_fanout(step_in_flight=True)
         with _trace.span("decode::step.wait"):
             toks_np = np.asarray(toks)
             logits_np = np.asarray(logits) if self.capture_logits else None
             self._observer.step(
                 extra, int(positions[live].sum()) + len(live))
-        with _trace.span("decode::step.emit"):
-            self._emit_step(live, toks_np, logits_np, t0)
+        with _trace.span("decode::step.book"):
+            self._book_step(live, toks_np, logits_np, t0)
+        if not any(s is not None for s in self._slots):
+            self._flush_fanout()   # the last stream left: no step follows
 
-    def _emit_step(self, live: List[int], toks_np: np.ndarray,
+    def _book_step(self, live: List[int], toks_np: np.ndarray,
                    logits_np: Optional[np.ndarray], t0: float) -> None:
-        """Hand one step's tokens to their streams: counters, the
-        per-slot bookkeeping, ``handle._emit``, retirement."""
+        """Book one step's tokens at the read: counters, the per-slot
+        state, ``handle._book``, retirement (slot and blocks free for
+        the admission sweep that follows).  Waking the streams is left
+        on ``self._fanout`` for :meth:`_flush_fanout`."""
         now = time.perf_counter()
+        self._fanout_t_read = now
         self.stats.steps.inc()
         step_ms = (now - t0) * 1e3
         self.stats.step_ms.observe(step_ms)
@@ -1068,9 +1134,37 @@ class DecodeEngine:
             if lat is not None:
                 lat.tbt_ms.observe((now - slot.t_last) * 1e3)
             slot.t_last = now
-            slot.req.handle._emit(
+            handle = slot.req.handle
+            handle._book(
                 tok, logits_np[i] if logits_np is not None else None)
+            self._fanout.append((handle._emit, tok))
             self._maybe_finish(i, slot, tok)
+
+    def _flush_fanout(self, step_in_flight: bool = False) -> None:
+        """Hand out what ``self._fanout`` holds, in order.  Behind a
+        step's dispatch (``step_in_flight``) this costs the device
+        nothing; everywhere else no step will follow soon enough, and
+        the tokens go out before anything else is told to a handle."""
+        if not self._fanout:
+            return
+        with _trace.span("decode::step.emit"):
+            for tell, what in self._fanout:
+                tell(what)
+            self._fanout.clear()
+        if step_in_flight:
+            self.stats.fanout_delay_ms.observe(
+                (time.perf_counter() - self._fanout_t_read) * 1e3)
+        else:
+            self.stats.fanout_immediate.inc()
+            with self._lock:
+                self._lock.notify_all()   # drain() waits for the hand-out
+
+    def _tell_finish(self, handle: DecodeHandle, reason: str) -> None:
+        """FIN, never ahead of a token: behind whatever is pending."""
+        if self._fanout:
+            self._fanout.append((handle._finish, reason))
+        else:
+            handle._finish(reason)
 
     # -- refcounted block lifecycle (prefix cache / overcommit) ------------
     def _ensure_blocks(self) -> None:
@@ -1259,9 +1353,10 @@ class DecodeEngine:
                 h = _audit.fold_token(h, t)
             _audit.note_stream(self.name, "",
                                _audit.request_hash(req.prompt), h)
-        req.handle._finish(reason)
+        self._tell_finish(req.handle, reason)
 
     def _release(self, req: DecodeRequest, slot_idx, error) -> None:
+        self._flush_fanout()
         parked_before = (self.prefix.parked_blocks
                          if self.prefix is not None else 0)
         released = 0
@@ -1414,6 +1509,7 @@ class DecodeEngine:
             "joins": self.stats.joins.value,
             "leaves": self.stats.leaves.value,
             "shed": self.stats.shed.value,
+            "fanout_immediate": self.stats.fanout_immediate.value,
         }
         if self._refc:
             # the refcounted block lifecycle (flag-latched; absent
@@ -1461,6 +1557,10 @@ class DecodeEngine:
         if self.stats.queue_ms.count:
             out["queue_p50_ms"] = self.stats.queue_ms.percentile(0.50)
             out["queue_p99_ms"] = self.stats.queue_ms.percentile(0.99)
+        fan = self.stats.fanout_delay_ms
+        if fan.count:
+            out["fanout_delay_p50_ms"] = fan.percentile(0.50)
+            out["fanout_delay_p99_ms"] = fan.percentile(0.99)
         lat = self.stats.lat
         if lat is not None:
             # the FLAGS_phase_attribution plane: TTFT/TBT tails,
@@ -1483,7 +1583,8 @@ class DecodeEngine:
         """Wait until every accepted request has finished."""
         deadline = time.monotonic() + timeout
         with self._lock:
-            while self._pending or any(s is not None for s in self._slots):
+            while self._pending or self._fanout or \
+                    any(s is not None for s in self._slots):
                 left = deadline - time.monotonic()
                 if left <= 0:
                     return False

@@ -308,6 +308,11 @@ def test_decodez_payload_and_drain():
         assert eng.drain(timeout=10)
         z = eng.decodez()
         assert z["tokens"] == 3 and z["leaves"] == 1
+        # two steps: the first's token went out behind the second's
+        # dispatch, the second's at once (no step followed)
+        assert z["steps"] == 2 and z["fanout_immediate"] == 1
+        assert eng.stats.fanout_delay_ms.count == 1
+        assert 0 < z["fanout_delay_p50_ms"] <= z["fanout_delay_p99_ms"]
         assert z["cache"]["free_blocks"] == eng.cache.num_blocks - 1
         assert z["slots"] == [None] * eng.max_slots
         assert z["prefill_buckets"] == [8, 16]
@@ -354,6 +359,251 @@ def test_cancel_frees_slot_and_blocks_mid_stream():
         assert out["finish"] == "cancelled"
         assert len(out["tokens"]) < 25
         eng.drain(timeout=10)
+        assert eng.cache.allocator.free_blocks == eng.cache.num_blocks - 1
+        z = eng.decodez()
+        assert z["joins"] == z["leaves"] == 1
+    finally:
+        eng.close()
+
+
+# ---------------------------------------------------------------------------
+# token fan-out: a step's tokens go out behind the next step's dispatch
+# ---------------------------------------------------------------------------
+
+def _recorded(eng, monkeypatch):
+    """The engine thread's own order of events, as a list: every
+    ``run_callable`` once it has returned (``("dispatch", kind)``), every
+    step's read (``("read",)``: the observer runs right after it, inside
+    the wait), and what each handle is told: ``("book", rid, k)``,
+    ``("emit", rid, k, live)`` with the slots live at that moment, and
+    ``("fin", rid, reason)``."""
+    from paddle_tpu.decode.engine import DecodeHandle
+    log, booked, emitted = [], {}, {}
+    run, observe = eng._exe.run_callable, eng._observer.step
+    book, emit, fin = (DecodeHandle._book, DecodeHandle._emit,
+                       DecodeHandle._finish)
+
+    def run_callable(key, *a, **kw):
+        out = run(key, *a, **kw)
+        log.append(("dispatch", key.split("/")[2]))
+        return out
+
+    def step(*a, **kw):
+        log.append(("read",))
+        return observe(*a, **kw)
+
+    def _book(self, token, logits):
+        k = booked[self.rid] = booked.get(self.rid, -1) + 1
+        log.append(("book", self.rid, k))
+        book(self, token, logits)
+
+    def _emit(self, token):
+        k = emitted[self.rid] = emitted.get(self.rid, -1) + 1
+        log.append(("emit", self.rid, k,
+                    sum(s is not None for s in eng._slots)))
+        emit(self, token)
+
+    def _finish(self, reason):
+        log.append(("fin", self.rid, reason))
+        fin(self, reason)
+
+    monkeypatch.setattr(eng._exe, "run_callable", run_callable)
+    monkeypatch.setattr(eng._observer, "step", step)
+    monkeypatch.setattr(DecodeHandle, "_book", _book)
+    monkeypatch.setattr(DecodeHandle, "_emit", _emit)
+    monkeypatch.setattr(DecodeHandle, "_finish", _finish)
+    return log
+
+
+def test_a_lone_streams_tokens_go_out_behind_the_next_dispatch(monkeypatch):
+    """The whole order of one stream of four tokens: the prefill's token
+    at once; each step's token after the NEXT step's dispatch and before
+    its read; the last step's token and FIN at once, with no later
+    request to set them off."""
+    lm, params, eng = _engine("fan_lone")
+    try:
+        log = _recorded(eng, monkeypatch)
+        h = eng.submit(np.arange(5, dtype=np.int32),
+                       SamplingParams(max_new_tokens=4))
+        streamed = list(h)                    # ends at FIN: no hang
+        out = h.result(timeout=30)
+        rid = h.rid
+        assert log == [
+            ("dispatch", "prefill"), ("book", rid, 0), ("emit", rid, 0, 1),
+            ("dispatch", "step"), ("read",), ("book", rid, 1),
+            ("dispatch", "step"), ("emit", rid, 1, 1), ("read",),
+            ("book", rid, 2),
+            ("dispatch", "step"), ("emit", rid, 2, 1), ("read",),
+            ("book", rid, 3), ("emit", rid, 3, 0), ("fin", rid, "length")]
+        assert streamed == out["tokens"] == h.tokens and len(streamed) == 4
+    finally:
+        eng.close()
+
+
+@pytest.mark.parametrize("sampled", [False, True],
+                         ids=["greedy", "seeded"])
+def test_no_stream_is_woken_between_a_read_and_the_next_dispatch(
+        monkeypatch, sampled):
+    """Five streams over three slots, joining and leaving: no token of a
+    step is handed out between that step's read and the next step's
+    dispatch unless the batch has emptied; every stream gets token ...
+    token, FIN in order; and the tokens are the model's own — each the
+    sampler's choice (by seed and index) from logits that equal the full
+    re-forward's — so they are what the order before this one streamed."""
+    from paddle_tpu.decode.model import _sample
+    lm, params, eng = _engine("fan_many_" + ("s" if sampled else "g"),
+                              capture_logits=True)
+    try:
+        log = _recorded(eng, monkeypatch)
+        rng = np.random.RandomState(1)
+        prompts = [rng.randint(0, TINY.vocab, n).astype(np.int32)
+                   for n in (3, 7, 5, 11, 2)]
+        sps = [SamplingParams(max_new_tokens=m, seed=11 + i,
+                              temperature=0.8 if sampled else 0.0,
+                              top_k=6 if sampled else 0)
+               for i, m in enumerate((6, 3, 8, 1, 5))]
+        handles = [eng.submit(p, sp) for p, sp in zip(prompts, sps)]
+        streamed = [list(h) for h in handles]
+        results = [h.result(timeout=120) for h in handles]
+        assert eng.drain(timeout=30)
+        behind = 0
+        for at, ev in enumerate(log):
+            if ev[0] != "emit" or ev[2] == 0:
+                continue                   # a prefill's token: at once
+            last = [e for e in log[:at] if e[0] in ("read", "dispatch")
+                    and e[-1] != "prefill"][-1]
+            if last == ("dispatch", "step"):
+                behind += 1                # this step is in flight
+            else:
+                assert ev[3] == 0, (at, ev)    # at once: no slot live
+        assert behind >= 8
+        plist = lm.param_list(params)
+        for h, p, sp, got, r in zip(handles, prompts, sps, streamed,
+                                    results):
+            mine = [e for e in log if e[0] in ("book", "emit", "fin")
+                    and e[1] == h.rid]
+            n = sp.max_new_tokens
+            assert [e[0] for e in mine if e[0] != "book"] == \
+                ["emit"] * n + ["fin"]
+            for k in range(n):             # booked before it is emitted
+                assert mine.index(("book", h.rid, k)) < min(
+                    i for i, e in enumerate(mine)
+                    if e[:3] == ("emit", h.rid, k))
+            assert got == r["tokens"] == h.tokens and len(got) == n
+            assert r["finish"] == "length"
+            toks = list(p)
+            for k, row in enumerate(h.logits):
+                full = lm.full_logits(
+                    plist, jnp.asarray(np.asarray(toks, np.int32)[None]))
+                assert np.abs(np.asarray(full[0, -1]) - row).max() < 1e-4
+                want = int(np.asarray(_sample(
+                    jnp.asarray(row[None]),
+                    jnp.asarray([sp.seed], jnp.uint32),
+                    jnp.asarray([k], jnp.int32),
+                    jnp.asarray([sp.temperature], jnp.float32),
+                    jnp.asarray([sp.top_k], jnp.int32)))[0])
+                assert want == got[k], (h.rid, k)
+                toks.append(got[k])
+    finally:
+        eng.close()
+
+
+def test_drain_waits_for_the_last_hand_out(monkeypatch):
+    """``drain()`` is true only once the last step's tokens and FIN have
+    gone out, however long the hand-out takes after the slot is free."""
+    import time
+    lm, params, eng = _engine("fan_drain")
+    try:
+        flush = eng._flush_fanout
+
+        def slow_flush(step_in_flight=False):
+            if not step_in_flight and eng._fanout:
+                time.sleep(0.3)            # slot free, tokens not yet out
+            flush(step_in_flight)
+        monkeypatch.setattr(eng, "_flush_fanout", slow_flush)
+        h = eng.submit(np.arange(4, dtype=np.int32),
+                       SamplingParams(max_new_tokens=3))
+        deadline = time.monotonic() + 30
+        while any(s is not None for s in eng._slots) or eng._pending \
+                or not h.tokens:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        assert eng.drain(timeout=30)
+        assert h._done.is_set()
+        assert h.result(timeout=0)["tokens"] == list(h) and len(h.tokens) == 3
+    finally:
+        eng.close()
+
+
+def test_close_and_an_engine_error_hand_out_what_was_computed_first():
+    """A token the engine has read is never dropped: ``close()`` with a
+    step's tokens still pending, and a dispatch that raises, both hand
+    them out before the stream is failed."""
+    lm, params, eng = _engine("fan_close")
+    try:
+        h = eng.submit(np.arange(4, dtype=np.int32),
+                       SamplingParams(max_new_tokens=25))
+        assert h.next_token(timeout=30) is not None
+        eng.close()
+        got = [h.tokens[0]]
+        with pytest.raises(RuntimeError, match="closed"):
+            for tok in h:
+                got.append(tok)
+        assert got == h.tokens and 1 <= len(got) < 25
+    finally:
+        eng.close()
+    lm, params, eng = _engine("fan_error")
+    try:
+        run, calls = eng._exe.run_callable, []
+
+        def run_callable(key, *a, **kw):
+            calls.append(key)
+            if calls.count("decode/fan_error/step") == 3:
+                raise ValueError("the third step is refused")
+            return run(key, *a, **kw)
+        eng._exe.run_callable = run_callable
+        h = eng.submit(np.arange(4, dtype=np.int32),
+                       SamplingParams(max_new_tokens=25))
+        got = []
+        with pytest.raises(ValueError, match="third step"):
+            for tok in h:
+                got.append(tok)
+        assert got == h.tokens and len(got) == 3   # the prefill's + two
+        z = eng.decodez()
+        assert z["joins"] == z["leaves"] == 1 and z["fanout_immediate"] == 1
+        assert eng.cache.allocator.free_blocks == eng.cache.num_blocks - 1
+        eng._exe.run_callable = run
+        assert len(eng.generate(np.arange(4, dtype=np.int32),
+                                max_new_tokens=3)["tokens"]) == 3
+    finally:
+        eng.close()
+
+
+def test_a_cancel_between_a_read_and_its_hand_out_keeps_the_order(
+        monkeypatch):
+    """The client goes away right after the engine has read a step and
+    before that step's token is handed out: the token still goes out,
+    FIN ("cancelled") after it, and the slot and its blocks are freed."""
+    lm, params, eng = _engine("fan_cancel")
+    try:
+        log = _recorded(eng, monkeypatch)
+        book, box = eng._book_step, {}
+
+        def book_then_cancel(*a, **kw):
+            book(*a, **kw)
+            if len(box["h"].tokens) == 3:
+                assert eng._fanout            # its token is pending
+                box["h"].cancel()
+        monkeypatch.setattr(eng, "_book_step", book_then_cancel)
+        box["h"] = h = eng.submit(np.arange(4, dtype=np.int32),
+                                  SamplingParams(max_new_tokens=25))
+        got = list(h)
+        out = h.result(timeout=30)
+        assert out["finish"] == "cancelled"
+        assert got == out["tokens"] == h.tokens and len(got) == 3
+        assert log[-2:] == [("emit", h.rid, 2, 0),
+                            ("fin", h.rid, "cancelled")]
+        assert eng.drain(timeout=10)
         assert eng.cache.allocator.free_blocks == eng.cache.num_blocks - 1
         z = eng.decodez()
         assert z["joins"] == z["leaves"] == 1

@@ -296,12 +296,30 @@ def test_overcommit_preempt_resume_is_loss_free():
         ref.close()
     _, _, eng = _engine("toc_small", prefill_buckets=(8,),
                         num_blocks=9, overcommit=True)
+    preempt, pending_at_preempt = eng._preempt_newest, []
+
+    def preempt_recorded():
+        # (victim's tokens still to be handed out, victim's tokens booked)
+        victim = max((s for s in eng._slots if s is not None),
+                     key=lambda s: s.req.rid).req.handle
+        pending_at_preempt.append(
+            (sum(1 for tell, _ in eng._fanout if tell.__self__ is victim),
+             len(victim.tokens)))
+        preempt()
+    eng._preempt_newest = preempt_recorded
     try:
         handles = [eng.submit(p, SamplingParams(max_new_tokens=10))
                    for p in prompts]
+        streamed = [list(h) for h in handles]
         got = [h.result(timeout=120) for h in handles]
         assert [g["tokens"] for g in got] == want
+        assert streamed == want     # each token reached its reader once
         assert all(g["finish"] == "length" for g in got)
+        # the eviction came between a step's read and its hand-out: the
+        # victim's newest token was booked, not yet streamed, and is
+        # part of what the re-prefill resumes from
+        assert pending_at_preempt and all(
+            n == 1 and booked >= 2 for n, booked in pending_at_preempt)
         ps = eng._pstats
         assert ps.preempts.value >= 1
         assert ps.preempt_resumes.value >= 1

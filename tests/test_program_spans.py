@@ -118,15 +118,29 @@ def _thread_with(traced, name):
 
 def test_a_decode_step_holds_its_children_in_order_on_one_thread(traced):
     spans = _thread_with(traced, "decode::step")   # one line has them all
-    steps = [s for s in spans if s[0] == "decode::step"]
+    steps = sorted((s for s in spans if s[0] == "decode::step"),
+                   key=lambda s: s[1])
     assert len(steps) >= 3
+    at_once = [True]          # nothing is pending before the first step
     for step in steps:
         kids = [k for k in _children(spans, step)
                 if k[0].startswith("decode::step.")
                 or k[0] == "executor::dispatch"]
-        assert [k[0] for k in kids] == [
-            "decode::step.retire", "decode::step.feed", "executor::dispatch",
-            "decode::step.wait", "decode::step.emit"]
+        names = [k[0] for k in kids]
+        # the step before's tokens go out between this step's dispatch
+        # and its wait (a batch's first step has none to hand out), and
+        # a batch's last step hands its own out after booking them
+        assert names[:3] == ["decode::step.retire", "decode::step.feed",
+                             "executor::dispatch"]
+        assert names[3:] in (
+            ["decode::step.wait", "decode::step.book"],
+            ["decode::step.emit", "decode::step.wait", "decode::step.book"],
+            ["decode::step.wait", "decode::step.book", "decode::step.emit"],
+            ["decode::step.emit", "decode::step.wait", "decode::step.book",
+             "decode::step.emit"])
+        # ... behind the dispatch exactly when the step before left them
+        assert (names[3] == "decode::step.emit") == (not at_once[-1])
+        at_once.append(names[-1] == "decode::step.emit")
         for a, b in zip(kids, kids[1:]):
             assert a[2] <= b[1]                    # never overlapping
         assert step[3]["live"] >= 1
@@ -140,6 +154,10 @@ def test_a_decode_step_holds_its_children_in_order_on_one_thread(traced):
         assert inner in (["executor::feed", "executor::dispatch"],
                          ["executor::feed", "executor::lower",
                           "executor::dispatch"])      # the first: a miss
+    # each of the two batches ended with a step that no step followed; a
+    # stream's three steps cannot all be such
+    assert sum(at_once[1:]) >= 2 and at_once[-1]
+    assert not all(at_once)
 
 
 def test_a_prefill_span_carries_its_request_and_its_queue_wait(traced):

@@ -1646,7 +1646,7 @@ def _bench_decode_inner():
 
 def bench_decode_prefix():
     """Prefix caching + overcommit (the refcounted block lifecycle,
-    ``FLAGS_decode_prefix_cache`` / ``FLAGS_decode_overcommit``) vs the
+    ``prefix_cache=True`` / ``overcommit=True``) vs the
     single-owner baseline, two legs:
 
     - **shared prefix**: 64 requests sharing an 87% system prompt
@@ -1946,7 +1946,7 @@ def _bench_decode_prefix_inner():
 
 
 def bench_decode_kv_int8():
-    """Quantized KV residency (``FLAGS_decode_kv_dtype=int8``) vs the
+    """Quantized KV residency (``cache_dtype="int8"``) vs the
     fp32 cache at the SAME pool byte budget, under overcommit.
 
     The int8 cache stores paged blocks as int8 codes plus a

@@ -75,14 +75,6 @@ define_flag("check_nan_inf", False,
             "post-block granularity under whole-block XLA compilation)")
 define_flag("benchmark", False,
             "log per-run wall time from the executor (executor.cc:399)")
-define_flag("eager_delete_tensor_gb", -1.0,
-            "accepted for API parity; device memory lifetime is owned by "
-            "XLA buffer assignment")
-define_flag("fraction_of_gpu_memory_to_use", 0.92,
-            "accepted for API parity; HBM is managed by the XLA runtime")
-define_flag("cpu_deterministic", False,
-            "accepted for parity; lowerings are deterministic by "
-            "construction (threaded PRNG state)")
 define_flag("rpc_deadline", 120.0,
             "pserver transport connect deadline in seconds "
             "(distributed/transport.py)")
@@ -90,8 +82,6 @@ define_flag("rpc_transport", "native",
             "pserver byte-transport backend: 'native' (C framed-TCP in "
             "native/paddle_tpu_native.cc, the reference's C++ gRPC layer "
             "role) or 'python' (stdlib sockets fallback)")
-define_flag("paddle_num_threads", 1,
-            "accepted for parity; host threading is owned by XLA")
 define_flag("sparse_dense_update_max_elems", 32_000_000,
             "lazy sparse optimizers (adam/momentum/adagrad) use the "
             "masked-dense update (2 scatters + full-table elementwise; "
@@ -284,72 +274,6 @@ define_flag("serving_queue_delay_slo_ms", 0.0,
             "request cannot be answered within this many ms, it is shed "
             "with a typed Overloaded reply.  0 (default) disables the "
             "estimate — only the serving_max_queue_rows bound sheds")
-define_flag("decode_block_tokens", 16,
-            "paged-KV-cache block size in TOKENS for the autoregressive "
-            "decode plane (paddle_tpu/decode): per-request key/value "
-            "state lives in fixed-size device blocks drawn from a "
-            "preallocated pool, so admission/eviction moves block-table "
-            "ENTRIES, never compiled shapes.  Latched when a "
-            "DecodeEngine is built")
-define_flag("decode_max_slots", 8,
-            "decode-batch width of the continuous-batching decode step "
-            "(paddle_tpu/decode/engine.py): requests join and leave a "
-            "running batch of this many slots at token granularity; the "
-            "slot count is a compiled shape, so it is fixed per engine "
-            "(inactive slots ride along masked into the reserved trash "
-            "block)")
-define_flag("decode_prefill_buckets", "16,32,64,128",
-            "prompt-length bucket ladder for the decode plane's split "
-            "prefill dispatch (the serving_buckets discipline applied "
-            "to the TIME axis): a joining prompt pads to the smallest "
-            "bucket that fits, so a handful of prefill executables "
-            "cover all prompt lengths and a long new prompt never "
-            "recompiles (or stalls) the running decode step")
-define_flag("decode_max_queue", 64,
-            "admission-control bound on a decode engine's pending "
-            "request queue: past it, new generation requests are shed "
-            "with the serving plane's typed Overloaded reply (counted "
-            "in decode.shed) instead of queueing into timeout")
-define_flag("decode_prefix_cache", False,
-            "content-addressed prefix caching for the decode plane "
-            "(paddle_tpu/decode/cache.py PrefixCache): full prompt "
-            "blocks are keyed by a rolling hash of (model, token ids "
-            "to the block boundary); admission walks the new prompt's "
-            "block-aligned prefix against the cache and adopts hits "
-            "as refcounted copy-on-write references, so a shared "
-            "system prompt prefills ONCE and later requests prefill "
-            "only their suffix.  Zero-refcount cached blocks park in "
-            "an LRU and are reclaimed under pool pressure.  Latched "
-            "when a DecodeEngine is built; off (default): legacy "
-            "full-reservation behavior, byte-identical")
-define_flag("decode_overcommit", False,
-            "lazy block reservation + preemption for the decode plane "
-            "(paddle_tpu/decode/engine.py): admission reserves only "
-            "ceil((P+1)/block_tokens) blocks instead of the full "
-            "prompt+max_new worst case and grows one block per decode "
-            "step; when growth cannot allocate, the newest running "
-            "stream is preempted (blocks freed, generated tokens kept "
-            "host-side) and re-admitted head-of-line via suffix "
-            "re-prefill — token-for-token identical to an "
-            "uninterrupted run (counter-hash sampling is positional). "
-            "Latched when a DecodeEngine is built; off (default): "
-            "full reservation at admission, byte-identical")
-define_flag("decode_kv_dtype", "float32",
-            "storage dtype of the paged decode KV cache "
-            "(paddle_tpu/decode/cache.py PagedKVCache): 'int8' stores "
-            "key/value blocks quantized to int8 with per-block-per-head "
-            "abs-max scales in a parallel f32 scale pool, quartering the "
-            "KV bytes per token (~0.53x incl. scales) so overcommit "
-            "admission fits ~2x the resident sequences per HBM byte; the "
-            "paged decode-attention kernel dequantizes blocks in VMEM "
-            "(counted XLA dequantize-gather fallback on any build "
-            "fault).  Prefix-cache hashing, COW forking, preemption/"
-            "re-prefill and the block-pool accounting move block IDS "
-            "only, so they operate on quantized blocks unchanged — the "
-            "scale pool rides the same block axis (COW copies the scale "
-            "row with the block).  Latched when a DecodeEngine is "
-            "built; 'float32' (default) keeps the cache layout, state "
-            "threading and metric surface byte-identical")
 define_flag("int8_inference", False,
             "serving-plane kill-switch default for int8 inference: when "
             "on, create_predictor appends the 'quantize_int8' "

@@ -10,7 +10,7 @@ whole prefix, so latency scales quadratically in output length.  This
 package is that state plane, built on the repo's own primitives:
 
 - **Paged KV cache** (:mod:`cache`): per-request key/value state lives
-  in device memory as fixed-size blocks (``FLAGS_decode_block_tokens``)
+  in device memory as fixed-size blocks (the engine's ``block_tokens``)
   drawn from a preallocated pool; a request holds a block TABLE, so
   admission/eviction moves table entries and never changes a compiled
   shape.  The cache arrays ride
@@ -21,7 +21,7 @@ package is that state plane, built on the repo's own primitives:
   and leave a running decode batch at token granularity — the serving
   batcher's bucket-ladder discipline applied to the TIME axis.
   Prefill dispatches are SPLIT from the decode step (their own
-  prompt-length bucket ladder, ``FLAGS_decode_prefill_buckets``), so a
+  prompt-length bucket ladder, the engine's ``prefill_buckets``), so a
   long new prompt never stalls in-flight streams.
 - **Pallas decode-attention kernel**
   (:func:`paddle_tpu.kernels.attention.decode_attention`): one query
@@ -44,14 +44,15 @@ package is that state plane, built on the repo's own primitives:
   and :class:`~paddle_tpu.decode.beam.PagedBeamDecoder` runs its beams
   as copy-on-write references into the paged cache (the parent gather
   becomes a block-table operation, not a state copy).
-- **Refcounted block lifecycle** (``FLAGS_decode_prefix_cache`` /
-  ``FLAGS_decode_overcommit``, both latched per engine): blocks carry
-  refcounts; full prompt blocks are content-addressed in a
+- **Two admission policies** over one refcounted block lifecycle (the
+  engine's ``prefix_cache=True`` / ``overcommit=True``): with
+  ``prefix_cache`` full prompt blocks are content-addressed in a
   :class:`~paddle_tpu.decode.cache.PrefixCache` so shared system
   prompts prefill once (later requests prefill only their suffix);
-  admission may overcommit the pool, with decode-step growth and
-  newest-stream preemption + token-exact re-prefill resume under
-  pressure.  Both flags off: byte-identical legacy behavior.
+  with ``overcommit`` admission reserves lazily, with decode-step
+  growth and newest-stream preemption + token-exact re-prefill resume
+  under pressure.  With neither, a request reserves its worst case at
+  admission and every block has one owner.
 - **Streaming serving** (:mod:`server` / :mod:`client`): tokens stream
   to clients over a new framed ``DECODE`` msg type on the existing
   zero-copy transport (multi-frame replies — the transport's STREAM

@@ -35,8 +35,8 @@ from typing import List, Optional
 import numpy as np
 
 from .cache import blocks_for
+from .engine import DEFAULT_BLOCK_TOKENS
 from .model import TransformerLM
-from ..core import flags as _flags
 from ..core.executor import Executor
 
 
@@ -68,8 +68,8 @@ class PagedBeamDecoder:
                              else max(self.beam_size, 2))
         self.share_prefix = bool(share_prefix)
         self._attn_impl = attn_impl
-        bs = int(_flags.get_flags("decode_block_tokens")
-                 if block_tokens is None else block_tokens)
+        bs = int(DEFAULT_BLOCK_TOKENS if block_tokens is None
+                 else block_tokens)
         self.max_blocks_per_seq = blocks_for(cfg.max_seq_len, bs)
         if num_blocks is None:
             # unshared lanes transiently hold old + adopted copies

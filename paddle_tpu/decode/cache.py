@@ -26,8 +26,8 @@ the price of one wasted block buys shape-stable admission/eviction
 (the whole point of paging: a request joining or leaving moves
 block-table entries, never compiled shapes).
 
-Every allocated block carries a refcount.  With the legacy reservation
-policy each block has exactly one owner, so ``alloc``/``release`` behave
+Every allocated block carries a refcount.  With full reservation
+(no admission policy) each block has exactly one owner, so ``alloc``/``release`` behave
 (and order the free list) exactly as the original single-owner free
 list did.  Prefix sharing and beam forking raise refcounts above one:
 a block referenced by several streams is immutable to all of them —
@@ -36,10 +36,10 @@ either returns to the free list or, when a :class:`PrefixCache` claims
 it, is *parked* in the cache's LRU so a later prompt with the same
 content can revive it without re-prefilling.
 
-Sizing: under the legacy policy a request admitted with prompt length
+Sizing: by default a request admitted with prompt length
 P and output budget M reserves ``ceil((P + M) / block_tokens)`` blocks
 up front — admission is the only point that can fail for lack of
-memory.  Under ``FLAGS_decode_overcommit`` admission reserves only
+memory.  An engine built with ``overcommit=True`` reserves only
 ``ceil((P + 1) / block_tokens)`` and grows one block per step; a
 failed growth triggers preemption (engine doc).
 """
@@ -49,8 +49,6 @@ from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax.numpy as jnp
-
-from ..core import flags as _flags
 
 
 def blocks_for(tokens: int, block_tokens: int) -> int:
@@ -297,12 +295,10 @@ class _PagedPool:
     quantized = False
 
     def __init__(self, num_layers: int, num_blocks: int,
-                 block_tokens: Optional[int], dtype):
+                 block_tokens: int, dtype):
         self.num_layers = int(num_layers)
         self.num_blocks = int(num_blocks)
-        self.block_tokens = int(
-            _flags.get_flags("decode_block_tokens")
-            if block_tokens is None else block_tokens)
+        self.block_tokens = int(block_tokens)
         if self.block_tokens < 1:
             raise ValueError(f"block_tokens must be >= 1, got "
                              f"{self.block_tokens}")
@@ -330,7 +326,7 @@ class PagedKVCache(_PagedPool):
     ``Executor.run_callable``; ``update()`` swaps in the returned
     (donated-in-place) handles.
 
-    ``dtype="int8"`` (``FLAGS_decode_kv_dtype``) stores blocks
+    ``dtype="int8"`` (the engine's ``cache_dtype``) stores blocks
     quantized in the SAME layout: k/v pools become int8 and two
     parallel f32 scale pools ``[L, NB, H]`` carry one abs-max scale per
     (block, head) — the qdq convention of ``kernels/quant.py``
@@ -343,7 +339,7 @@ class PagedKVCache(_PagedPool):
     byte-identical to the unquantized build."""
 
     def __init__(self, num_layers: int, num_heads: int, head_dim: int,
-                 num_blocks: int, block_tokens: Optional[int] = None,
+                 num_blocks: int, block_tokens: int,
                  dtype="float32"):
         super().__init__(num_layers, num_blocks, block_tokens, dtype)
         self.num_heads = int(num_heads)

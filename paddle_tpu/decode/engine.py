@@ -4,8 +4,8 @@ The decode-plane hot loop.  One scheduler thread drives two kinds of
 dispatch against one :class:`~paddle_tpu.core.executor.Executor`:
 
 - **prefill** — one dispatch per JOINING request, prompt padded to the
-  smallest bucket on the prefill ladder (``FLAGS_decode_prefill_buckets``
-  — the serving batcher's bucket discipline applied to the time axis).
+  smallest bucket on the prefill ladder (``prefill_buckets`` — the
+  serving batcher's bucket discipline applied to the time axis).
   It writes the prompt's K/V into the request's cache blocks and samples
   the first token, so a joining stream emits immediately.  Prefill is a
   SEPARATE executable from the decode step: a long new prompt costs the
@@ -24,19 +24,24 @@ load of varying prompt and output lengths is ZERO compiles — the
 acceptance pin.
 
 Admission control (the batcher discipline): a bounded pending queue
-(``FLAGS_decode_max_queue``) sheds with the serving plane's typed
+(``max_queue``) sheds with the serving plane's typed
 :class:`Overloaded`; an over-budget prompt/output (off the ladder, or
 past the block-table context bound) is a typed
 :class:`RequestTooLong`.  Block reservation happens at admission —
 ``ceil((prompt+max_new)/block_tokens)`` blocks up front — so a running
 stream can never hit cache OOM mid-generation.
 
-Two latched flags rebuild the block lifecycle on the refcounted
-allocator (:mod:`paddle_tpu.decode.cache`); both off (default) keeps
-every code path, allocation order and metric series byte-identical to
-the legacy engine:
+One block lifecycle, two admission policies.  Every engine draws its
+blocks from the refcounted allocator (:mod:`paddle_tpu.decode.cache`),
+checks before each step that every live slot's write target is present
+and private (:meth:`DecodeEngine._ensure_blocks`), keeps the pool gauges
+(``blocks_referenced`` / ``blocks_cached`` / ``blocks_leaked``) and
+shows ``block_pool`` on ``/decodez``.  With neither policy a block has
+one owner from admission to retirement, so that check finds nothing to
+do; an engine is built with either or both of (refused at construction
+where ``model.supports`` lacks the name):
 
-- ``FLAGS_decode_prefix_cache`` — admission walks the prompt's
+- ``prefix_cache=True`` — admission walks the prompt's
   block-aligned prefix against a content-addressed
   :class:`~paddle_tpu.decode.cache.PrefixCache` and ADOPTS hits as
   refcounted references, so a shared system prompt prefills once and
@@ -45,7 +50,7 @@ the legacy engine:
   after prefill; zero-ref cached blocks park in an LRU reclaimed under
   pool pressure.  Hits are capped one block short of the prompt so the
   suffix is never empty (the last position's logits seed the stream).
-- ``FLAGS_decode_overcommit`` — admission reserves only
+- ``overcommit=True`` — admission reserves only
   ``ceil((P+1)/block_tokens)`` blocks and the decode step grows one
   block as a stream crosses each block boundary; when growth cannot
   allocate, the NEWEST running stream is preempted (blocks decref'd,
@@ -100,7 +105,6 @@ import numpy as np
 
 from .cache import PrefixCache, blocks_for
 from .model import TransformerLM
-from ..core import flags as _flags
 from ..core.executor import Executor
 from ..distributed import faults as _faults
 from ..kernels import quant as _quant_kernels
@@ -113,6 +117,14 @@ from ..observability import stats as _obs_stats
 from ..observability import tenant as _tenant
 from ..observability import trace as _trace
 from ..serving.batcher import BucketLadder, Overloaded, RequestTooLong
+
+# what an engine (and a beam session) is built with where its constructor
+# is given nothing; ``prefix_cache`` and ``overcommit`` default to off
+DEFAULT_MAX_SLOTS = 8
+DEFAULT_MAX_QUEUE = 64
+DEFAULT_BLOCK_TOKENS = 16
+DEFAULT_PREFILL_BUCKETS = (16, 32, 64, 128)
+DEFAULT_CACHE_DTYPE = "float32"
 
 # decode request phases (FLAGS_phase_attribution): queue = submit ->
 # slot claimed, prefill = slot -> first token emitted (the TTFT tail
@@ -282,18 +294,17 @@ class _Slot:
 
     def __init__(self, req: DecodeRequest, blocks: List[int],
                  prompt_len: int, first_token: int,
-                 cached_tokens: int = 0, seq: Optional[np.ndarray] = None):
+                 cached_tokens: int, seq: np.ndarray):
         self.req = req
         self.blocks = blocks
         self.pos_next = prompt_len   # where the last sampled token's
         self.n_generated = 1         # K/V lands on the next step
         self.last_token = first_token
         self.t_last = time.perf_counter()
-        # prefix-cache / resume bookkeeping (0 / None on the legacy
-        # path): positions [0, cached_tokens) are already resident in
-        # adopted blocks; ``seq`` is the full token sequence prefill
-        # must make resident (prompt, or prompt+generated[:-1] on a
-        # preemption resume)
+        # positions [0, cached_tokens) are already resident in adopted
+        # blocks (prefix hits; else 0); ``seq`` is the full token
+        # sequence prefill must make resident (the prompt, or
+        # prompt + generated[:-1] on a preemption resume)
         self.cached_tokens = cached_tokens
         self.seq = seq
 
@@ -361,12 +372,10 @@ class _LatencyStats:
 
 
 class _PrefixStats:
-    """Refcounted-pool metric bundle: prefix-cache hit accounting,
-    copy-on-write forks, preemption/resume accounting and the pool
-    leak invariant.  Created only when ``FLAGS_decode_prefix_cache``
-    or ``FLAGS_decode_overcommit`` latched on at engine construction,
-    so a flags-off process registers none of these series (the
-    byte-identical metric-surface pin)."""
+    """Block-pool metric bundle of every engine: prefix-cache hit
+    accounting, copy-on-write forks, preemption/resume accounting and
+    the pool leak invariant (the counters of a policy an engine was not
+    built with stay 0)."""
 
     def __init__(self, name: str):
         sc = _obs_stats.scope(f"decode.{name}")
@@ -488,29 +497,27 @@ class DecodeEngine:
         self.model = model
         self.name = name
         cfg = model.config
-        self.max_slots = int(_flags.get_flags("decode_max_slots")
-                             if max_slots is None else max_slots)
-        self.max_queue = int(_flags.get_flags("decode_max_queue")
-                             if max_queue is None else max_queue)
-        bs = int(_flags.get_flags("decode_block_tokens")
-                 if block_tokens is None else block_tokens)
+        self.max_slots = int(DEFAULT_MAX_SLOTS if max_slots is None
+                             else max_slots)
+        self.max_queue = int(DEFAULT_MAX_QUEUE if max_queue is None
+                             else max_queue)
+        bs = int(DEFAULT_BLOCK_TOKENS if block_tokens is None
+                 else block_tokens)
         # block TABLE width: enough blocks per slot for a full-length
         # context — a compiled shape, so it derives from max_seq_len
         self.max_blocks_per_seq = blocks_for(cfg.max_seq_len, bs)
         if num_blocks is None:
             num_blocks = 1 + self.max_slots * self.max_blocks_per_seq
         # KV storage dtype latches at engine build (the compiled state
-        # shape): ctor arg wins, else FLAGS_decode_kv_dtype; the
-        # "float32" default keeps the flags-off pool byte-identical
+        # shape)
         if cache_dtype is None:
-            cache_dtype = str(_flags.get_flags("decode_kv_dtype"))
+            cache_dtype = DEFAULT_CACHE_DTYPE
         # the model describes its cache and owns the state list its
         # three entry points thread (K/V pools, their scale pools, a
         # latent pool): the engine passes ``cache.state()`` through
         self.cache = model.make_cache(num_blocks, bs, dtype=cache_dtype)
         ladder = (prefill_buckets if prefill_buckets is not None
-                  else BucketLadder.parse(
-                      _flags.get_flags("decode_prefill_buckets")))
+                  else DEFAULT_PREFILL_BUCKETS)
         sizes = sorted({int(b) for b in
                         (ladder.sizes if isinstance(ladder, BucketLadder)
                          else ladder) if int(b) <= cfg.max_seq_len})
@@ -526,14 +533,10 @@ class DecodeEngine:
         # what the model's programs return beside token and logits (a
         # routed model's load figures) goes to the model's own observer
         self._observer = model.observer(name, self.cache)
-        # refcounted block lifecycle (module doc) — latched here; both
-        # flags off keeps the legacy single-owner paths byte-identical
-        self._prefix_on = bool(_flags.get_flags("decode_prefix_cache")
-                               if prefix_cache is None else prefix_cache)
-        self._overcommit_on = bool(_flags.get_flags("decode_overcommit")
-                                   if overcommit is None else overcommit)
-        self._refc = self._prefix_on or self._overcommit_on
-        asked = {"prefix_cache": self._prefix_on,
+        # the two admission policies (module doc), latched here: an
+        # engine with a prefix cache has ``self.prefix``
+        self._overcommit_on = bool(overcommit)
+        asked = {"prefix_cache": bool(prefix_cache),
                  "overcommit": self._overcommit_on}
         refused = sorted(k for k, on in asked.items()
                          if on and k not in model.supports)
@@ -545,21 +548,20 @@ class DecodeEngine:
         self.prefix = (PrefixCache(
             self.cache.allocator, bs,
             model_key=f"{name}/{cfg.vocab}x{cfg.d_model}x{cfg.n_layer}")
-            if self._prefix_on else None)
-        self._pstats = _PrefixStats(name) if self._refc else None
-        if self._refc:
-            # suffix / resume bucket ladder: a prefix-hit suffix (or a
-            # preemption re-prefill, whose length can exceed the
-            # prefill ladder) snaps onto block-size doublings so a
-            # handful of executables cover every residual length
-            limit = self.max_context()
-            sizes2 = set(self.prefill_ladder.sizes)
-            b2 = bs
-            while b2 < limit:
-                sizes2.add(b2)
-                b2 *= 2
-            sizes2.add(limit)
-            self._resume_ladder = BucketLadder(sorted(sizes2))
+            if prefix_cache else None)
+        self._pstats = _PrefixStats(name)
+        # suffix / resume bucket ladder: a prefix-hit suffix (or a
+        # preemption re-prefill, whose length can exceed the prefill
+        # ladder) snaps onto block-size doublings so a handful of
+        # executables cover every residual length
+        limit = self.max_context()
+        sizes2 = set(self.prefill_ladder.sizes)
+        b2 = bs
+        while b2 < limit:
+            sizes2.add(b2)
+            b2 *= 2
+        sizes2.add(limit)
+        self._resume_ladder = BucketLadder(sorted(sizes2))
 
         self._lock = threading.Condition()
         self._pending: List[DecodeRequest] = []
@@ -788,8 +790,7 @@ class DecodeEngine:
             row[:len(blocks)] = blocks
             self._slots[i] = _Slot(req, blocks, L,
                                    first_token=-1,   # token set by prefill
-                                   cached_tokens=start,
-                                   seq=seq if (start or resume) else None)
+                                   cached_tokens=start, seq=seq)
             if req.tl is not None and not resume:
                 # queue wait ends at slot claim
                 req.tl.stamp("queue", t=time.perf_counter())
@@ -799,8 +800,7 @@ class DecodeEngine:
         self.stats.queue.set(len(self._pending))
         self.stats.blocks_free.set(self.cache.allocator.free_blocks)
         self.stats.active.set(sum(s is not None for s in self._slots))
-        if self._refc:
-            self._update_pool_gauges()
+        self._update_pool_gauges()
         return out
 
     def _alloc_blocks(self, n: int) -> Optional[List[int]]:
@@ -827,6 +827,16 @@ class DecodeEngine:
 
     # -- dispatches --------------------------------------------------------
     def _prefill(self, req: DecodeRequest) -> None:
+        """Make positions ``[start, L)`` of the slot's sequence resident
+        and sample what follows.  A fresh prompt is ``start == 0`` on the
+        prefill ladder; behind prefix hits (``start > 0``) only the
+        suffix dispatches (:meth:`TransformerLM.prefill_suffix`); a
+        preemption resume re-prefills ``prompt + generated[:-1]`` on the
+        wider resume ladder and DISCARDS the sampled token, restoring
+        the slot to its pre-eviction state — the next decode step
+        re-samples token index ``n_generated``, which the positional
+        counter-hash makes identical to the token the stream would have
+        produced uninterrupted."""
         t0 = time.perf_counter()
         i, slot = self._slot_of(req)
         if req.handle.cancelled:   # client vanished between admit and here
@@ -834,33 +844,39 @@ class DecodeEngine:
             return
         queue_ms = (t0 - req.t_enq) * 1e3
         self.stats.queue_ms.observe(queue_ms)
-        resume = req.resume_tokens is not None
         start = slot.cached_tokens
-        if resume or start > 0:
-            with _trace.span("decode::prefill_partial", rid=req.rid,
-                             queue_ms=queue_ms):
-                self._prefill_partial(i, slot, req, t0)
-            return
-        P = req.prompt.size
-        bucket = self.prefill_ladder.snap(P)
+        fresh = start == 0 and req.resume_tokens is None
+        ladder = self.prefill_ladder if fresh else self._resume_ladder
+        bucket = ladder.snap(slot.seq.size - start)
         with _trace.span("decode::prefill", rid=req.rid, bucket=bucket,
-                         prompt=P, queue_ms=queue_ms):
-            self._prefill_full(i, slot, req, t0, P, bucket)
+                         prompt=int(slot.seq.size),
+                         queue_ms=queue_ms) as sp:
+            if not fresh:
+                sp.annotate(start=start)
+            self._prefill_traced(i, slot, req, t0, bucket)
 
-    def _prefill_full(self, i: int, slot: _Slot, req: DecodeRequest,
-                      t0: float, P: int, bucket: int) -> None:
-        model = self.model
+    def _prefill_traced(self, i: int, slot: _Slot, req: DecodeRequest,
+                        t0: float, bucket: int) -> None:
+        seq, start, resume = slot.seq, slot.cached_tokens, req.resume_tokens
+        L = int(seq.size)
+        n = L - start             # the positions this dispatch computes
+        if start == 0:
+            entry, program = self.model.prefill, "prefill"
+            where = [np.int32(L)]
+        else:
+            entry, program = self.model.prefill_suffix, "prefill_sfx"
+            where = [np.int32(start), np.int32(L)]
 
         def build():
             def fn(feed, state, const):
-                return model.prefill(const, state, *feed)
+                return entry(const, state, *feed)
             return fn
 
         with _trace.span("decode::prefill.feed"):
             tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :P] = req.prompt
+            tokens[0, :n] = seq[start:]
             feed = [tokens,
-                    np.int32(P),
+                    *where,
                     self._tables[i].copy(),
                     np.uint32(req.sampling.seed & 0xFFFFFFFF),
                     np.float32(req.sampling.temperature),
@@ -870,18 +886,16 @@ class DecodeEngine:
             # prefill phase / TTFT window (the SLO-watchdog test's lever)
             _faults.event("decode_prefill")
         (tok, logits, *extra), new_state = self._exe.run_callable(
-            f"decode/{self.name}/prefill/{bucket}", build, feed,
+            f"decode/{self.name}/{program}/{bucket}", build, feed,
             state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
         with _trace.span("decode::prefill.wait"):
             first = int(np.asarray(tok))
             logits_np = np.asarray(logits) if self.capture_logits else None
-            self._observer.prefill(extra, P, bucket)
+            self._observer.prefill(extra, n, bucket)
         with _trace.span("decode::prefill.emit"):
-            slot.last_token = first
             slot.t_last = time.perf_counter()
             self.stats.prefills.inc()
-            self.stats.tokens.inc()
             prefill_ms = (slot.t_last - t0) * 1e3
             self.stats.prefill_ms.observe(prefill_ms)
             if _capacity.enabled():
@@ -891,116 +905,31 @@ class DecodeEngine:
             if _tenant.enabled():
                 # a prefill serves exactly one request: its whole wall is
                 # that tenant's device time
-                _tenant.account(req.tenant, prefill_tokens=P,
+                _tenant.account(req.tenant, prefill_tokens=n,
                                 device_ms=prefill_ms)
-            if req.tl is not None:
+            if req.tl is not None and resume is None:
                 req.tl.stamp("prefill", t=slot.t_last)
                 lat = self.stats.latency()
                 lat.ttft_ms.observe((slot.t_last - req.t_enq) * 1e3)
-                lat.prefill_tokens.inc(P)
-                lat.pad_prefill_tokens.inc(bucket - P)
-            self._register_prefix(slot, req.prompt)
+                lat.prefill_tokens.inc(n)
+                lat.pad_prefill_tokens.inc(bucket - n)
+            self._register_prefix(slot, seq)
+            if resume is not None:
+                # restore the evicted stream's exact slot state; the
+                # freshly sampled token is a DISCARD (the client already
+                # has its successor, resume[-1])
+                slot.pos_next = L
+                slot.n_generated = len(resume)
+                slot.last_token = int(resume[-1])
+                req.resume_tokens = None
+                self._pstats.preempt_resumes.inc()
+                self._pstats.reprefill_tokens.inc(n)
+                return
+            slot.last_token = first
+            self.stats.tokens.inc()
             req.handle._book(first, logits_np)
             req.handle._emit(first)   # at once: this wake-up is the TTFT
             self._maybe_finish(i, slot, first)
-
-    def _prefill_partial(self, i: int, slot: _Slot, req: DecodeRequest,
-                         t0: float) -> None:
-        """Prefill with a resident prefix (prefix-cache hits) and/or a
-        preemption resume: only positions [start, L) dispatch, via
-        :meth:`TransformerLM.prefill_suffix` (a full re-prefill when
-        start == 0 rides the dense :meth:`TransformerLM.prefill` on
-        the wider resume ladder).  On resume the sampled token is
-        DISCARDED and the slot restored to its pre-eviction state —
-        the next decode step re-samples token index n_generated, which
-        the positional counter-hash makes identical to the token the
-        stream would have produced uninterrupted."""
-        resume = req.resume_tokens is not None
-        seq = slot.seq if slot.seq is not None else req.prompt
-        L = int(seq.size)
-        start = slot.cached_tokens
-        model = self.model
-        if start > 0:
-            n = L - start
-            bucket = self._resume_ladder.snap(n)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :n] = seq[start:]
-
-            def build():
-                def fn(feed, state, const):
-                    return model.prefill_suffix(const, state, *feed)
-                return fn
-
-            feed = [tokens,
-                    np.int32(start),
-                    np.int32(L),
-                    self._tables[i].copy(),
-                    np.uint32(req.sampling.seed & 0xFFFFFFFF),
-                    np.float32(req.sampling.temperature),
-                    np.int32(req.sampling.top_k)]
-            key = f"decode/{self.name}/prefill_sfx/{bucket}"
-        else:
-            n = L
-            bucket = self._resume_ladder.snap(L)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :L] = seq
-
-            def build():
-                def fn(feed, state, const):
-                    return model.prefill(const, state, *feed)
-                return fn
-
-            feed = [tokens,
-                    np.int32(L),
-                    self._tables[i].copy(),
-                    np.uint32(req.sampling.seed & 0xFFFFFFFF),
-                    np.float32(req.sampling.temperature),
-                    np.int32(req.sampling.top_k)]
-            key = f"decode/{self.name}/prefill/{bucket}"
-        _debug_server.note_activity("decode")
-        _faults.event("decode_prefill")
-        (tok, logits, *extra), new_state = self._exe.run_callable(
-            key, build, feed, state=self.cache.state(), const=self._plist)
-        self.cache.update(new_state)
-        self._observer.prefill(extra, n, bucket)
-        slot.t_last = time.perf_counter()
-        self.stats.prefills.inc()
-        prefill_ms = (slot.t_last - t0) * 1e3
-        self.stats.prefill_ms.observe(prefill_ms)
-        if _capacity.enabled():
-            self.stats.capacity_tracker().note(
-                "prefill", prefill_ms, bucket=bucket, work=1)
-        if _tenant.enabled():
-            _tenant.account(req.tenant, prefill_tokens=n,
-                            device_ms=prefill_ms)
-        if req.tl is not None and not resume:
-            req.tl.stamp("prefill", t=slot.t_last)
-            lat = self.stats.latency()
-            lat.ttft_ms.observe((slot.t_last - req.t_enq) * 1e3)
-            lat.prefill_tokens.inc(n)
-            lat.pad_prefill_tokens.inc(bucket - n)
-        self._register_prefix(slot, seq)
-        if resume:
-            # restore the evicted stream's exact slot state; the
-            # freshly sampled token is a DISCARD (it re-derives
-            # resume_tokens[start's] successor which the client
-            # already has)
-            gen = req.resume_tokens
-            slot.pos_next = L
-            slot.n_generated = len(gen)
-            slot.last_token = int(gen[-1])
-            req.resume_tokens = None
-            self._pstats.preempt_resumes.inc()
-            self._pstats.reprefill_tokens.inc(n)
-            return
-        with _trace.span("decode::prefill_partial.wait"):
-            first = int(np.asarray(tok))
-            logits_np = np.asarray(logits) if self.capture_logits else None
-        slot.last_token = first
-        self.stats.tokens.inc()
-        req.handle._book(first, logits_np)
-        req.handle._emit(first)
-        self._maybe_finish(i, slot, first)
 
     def _register_prefix(self, slot: _Slot, seq: np.ndarray) -> None:
         """Advertise the slot's freshly prefilled FULL blocks in the
@@ -1033,9 +962,8 @@ class DecodeEngine:
             for i, slot in enumerate(self._slots):
                 if slot is not None and slot.req.handle.cancelled:
                     self._retire(i, slot, "cancelled")
-            if self._refc:
-                # overcommit growth + copy-on-write forks (may preempt)
-                self._ensure_blocks()
+            # overcommit growth + copy-on-write forks (may preempt)
+            self._ensure_blocks()
         with _trace.span("decode::step.feed"):
             tokens = np.zeros((self.max_slots,), np.int32)
             positions = np.zeros((self.max_slots,), np.int32)
@@ -1250,8 +1178,7 @@ class DecodeEngine:
         # the supervisor-respawned replica must come back with a clean
         # pool invariant (the chaos_lite pin)
         _faults.event("decode_preempt")
-        parked_before = (self.prefix.parked_blocks
-                         if self.prefix is not None else 0)
+        parked_before = self._parked()
         with self._lock:
             self._slots[v] = None
             self.cache.allocator.release(slot.blocks)
@@ -1289,11 +1216,13 @@ class DecodeEngine:
                 state=self.cache.state(), const=[])
             self.cache.update(new_state)
 
+    def _parked(self) -> int:
+        """Zero-ref blocks the prefix cache holds (none without one)."""
+        return self.prefix.parked_blocks if self.prefix is not None else 0
+
     def _update_pool_gauges(self) -> None:
-        if not self._refc:
-            return
         alloc = self.cache.allocator
-        parked = self.prefix.parked_blocks if self.prefix is not None else 0
+        parked = self._parked()
         self._pstats.blocks_referenced.set(alloc.referenced_blocks)
         self._pstats.blocks_cached.set(parked)
         self._pstats.blocks_leaked.set(alloc.leaked(parked))
@@ -1309,8 +1238,7 @@ class DecodeEngine:
     def _retire(self, i: int, slot: _Slot, reason: str) -> None:
         """Free the slot + its cache blocks and finish the stream
         (eos / length / cancelled all leave through here)."""
-        parked_before = (self.prefix.parked_blocks
-                         if self.prefix is not None else 0)
+        parked_before = self._parked()
         with self._lock:
             self._slots[i] = None
             self.cache.allocator.release(slot.blocks)
@@ -1357,8 +1285,7 @@ class DecodeEngine:
 
     def _release(self, req: DecodeRequest, slot_idx, error) -> None:
         self._flush_fanout()
-        parked_before = (self.prefix.parked_blocks
-                         if self.prefix is not None else 0)
+        parked_before = self._parked()
         released = 0
         with self._lock:
             for i, s in enumerate(self._slots):
@@ -1380,8 +1307,7 @@ class DecodeEngine:
         req.handle._fail(error)
 
     def _fail_all(self, error) -> None:
-        parked_before = (self.prefix.parked_blocks
-                         if self.prefix is not None else 0)
+        parked_before = self._parked()
         released = 0
         with self._lock:
             slots, self._slots = (list(self._slots),
@@ -1409,8 +1335,7 @@ class DecodeEngine:
         state.  Lock-light (counter reads race admission by at most one
         block — the ledger is a snapshot, not a barrier)."""
         alloc = self.cache.allocator
-        parked = (self.prefix.parked_blocks
-                  if self.prefix is not None else 0)
+        parked = self._parked()
         bb = self._block_bytes
         resident = sum(s is not None for s in self._slots)
         out = {"reserved": self.cache.nbytes,
@@ -1430,9 +1355,7 @@ class DecodeEngine:
     def _mem_pool_audit(self) -> int:
         """The leak sentinel's refcount invariant: blocks neither free
         nor referenced nor parked nor the trash block — must be 0."""
-        parked = (self.prefix.parked_blocks
-                  if self.prefix is not None else 0)
-        return self.cache.allocator.leaked(parked)
+        return self.cache.allocator.leaked(self._parked())
 
     def _note_blocks_released(self, n_blocks: int, parked_before: int,
                               kind: str, **extra) -> None:
@@ -1440,8 +1363,7 @@ class DecodeEngine:
         (refcount hit zero while advertised) park, the rest free."""
         if self._mem_pool is None or n_blocks <= 0:
             return
-        parked_now = (self.prefix.parked_blocks
-                      if self.prefix is not None else 0)
+        parked_now = self._parked()
         d = min(max(parked_now - parked_before, 0), n_blocks)
         bb = self._block_bytes
         if d:
@@ -1466,15 +1388,16 @@ class DecodeEngine:
     def _recover_oom(self, error) -> bool:
         """OOM forensics + recovery: a RESOURCE_EXHAUSTED escaping the
         step dispatch dumps a named post-mortem (full ledger, top
-        holders, event tail) and — when the refcounted lifecycle is on
-        and a stream is live — sheds the NEWEST stream through the
-        existing preemption path (counted), so the engine keeps
-        serving instead of failing every slot.  Returns False (caller
+        holders, event tail) and — when the engine was built with an
+        admission policy and a stream is live — sheds the NEWEST stream
+        through the existing preemption path (counted), so the engine
+        keeps serving instead of failing every slot.  Returns False (caller
         falls through to _fail_all) when unarmed or not an OOM."""
         if self._mem_pool is None or not _memory.is_oom(error):
             return False
         _memory.oom_forensics(error, "decode_step")
-        if not self._refc or not any(s is not None for s in self._slots):
+        if not (self.prefix is not None or self._overcommit_on) or \
+                not any(s is not None for s in self._slots):
             return False
         self._preempt_newest()
         _obs_stats.scope(f"decode.{self.name}").counter(
@@ -1511,41 +1434,37 @@ class DecodeEngine:
             "shed": self.stats.shed.value,
             "fanout_immediate": self.stats.fanout_immediate.value,
         }
-        if self._refc:
-            # the refcounted block lifecycle (flag-latched; absent
-            # flags-off so the payload shape stays byte-identical)
-            alloc = self.cache.allocator
-            parked = (self.prefix.parked_blocks
-                      if self.prefix is not None else 0)
-            ps = self._pstats
-            out["block_pool"] = {
-                "size": self.cache.num_blocks,
-                "free": alloc.free_blocks,
-                "referenced": alloc.referenced_blocks,
-                "cached": parked,
-                "leaked": alloc.leaked(parked),
-                "cow_forks": ps.cow_forks.value,
-                "overcommit": self._overcommit_on,
+        alloc = self.cache.allocator
+        parked = self._parked()
+        ps = self._pstats
+        out["block_pool"] = {
+            "size": self.cache.num_blocks,
+            "free": alloc.free_blocks,
+            "referenced": alloc.referenced_blocks,
+            "cached": parked,
+            "leaked": alloc.leaked(parked),
+            "cow_forks": ps.cow_forks.value,
+            "overcommit": self._overcommit_on,
+        }
+        if self.prefix is not None:
+            lk, ht = ps.prefix_lookups.value, ps.prefix_hits.value
+            out["prefix_cache"] = {
+                "entries": len(self.prefix),
+                "cached_blocks": parked,
+                "lookups": lk,
+                "hits": ht,
+                "hit_rate": round(ht / max(lk, 1), 4),
+                "saved_prefill_tokens": ps.saved_prefill_tokens.value,
+                "inserts": ps.prefix_inserts.value,
+                "evictions": ps.prefix_evictions.value,
+                "collisions": self.prefix.collisions,
             }
-            if self.prefix is not None:
-                lk, ht = ps.prefix_lookups.value, ps.prefix_hits.value
-                out["prefix_cache"] = {
-                    "entries": len(self.prefix),
-                    "cached_blocks": parked,
-                    "lookups": lk,
-                    "hits": ht,
-                    "hit_rate": round(ht / max(lk, 1), 4),
-                    "saved_prefill_tokens": ps.saved_prefill_tokens.value,
-                    "inserts": ps.prefix_inserts.value,
-                    "evictions": ps.prefix_evictions.value,
-                    "collisions": self.prefix.collisions,
-                }
-            if self._overcommit_on:
-                out["preemption"] = {
-                    "preempts": ps.preempts.value,
-                    "resumes": ps.preempt_resumes.value,
-                    "reprefill_tokens": ps.reprefill_tokens.value,
-                }
+        if self._overcommit_on:
+            out["preemption"] = {
+                "preempts": ps.preempts.value,
+                "resumes": ps.preempt_resumes.value,
+                "reprefill_tokens": ps.reprefill_tokens.value,
+            }
         snap = self.stats.step_ms.snapshot()
         if snap.get("count"):
             out["step_p50_ms"] = self.stats.step_ms.percentile(0.50)

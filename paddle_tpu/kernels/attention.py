@@ -1128,7 +1128,7 @@ def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
 
     ``k_scale``/``v_scale``: [L, N_blocks, H] f32 per-block-per-head
     abs-max pools when the cache stores int8 codes
-    (``FLAGS_decode_kv_dtype=int8``); both paths dequantize with
+    (``DecodeEngine(cache_dtype="int8")``); both paths dequantize with
     ``code * s/127`` — the kernel in VMEM after the block copy lands,
     the XLA path after the gather.
 

@@ -320,6 +320,26 @@ def test_decodez_payload_and_drain():
         eng.close()
 
 
+def test_an_engine_given_nothing_has_the_documented_defaults():
+    """``DecodeEngine(model, params)``: 8 slots, a queue of 64, 16-token
+    blocks, the ladder 16/32/64/128 (cut to the model's context), an f32
+    pool, neither admission policy — from nowhere but the constructor."""
+    lm = TransformerLM(LMConfig(vocab=48, d_model=32, n_head=2, d_ffn=48,
+                                n_layer=2, max_seq_len=128))
+    eng = DecodeEngine(lm, lm.init_params(seed=5))
+    try:
+        assert eng.name == "lm"
+        assert eng.max_slots == 8 and eng.max_queue == 64
+        assert eng.cache.block_tokens == 16
+        assert eng.prefill_ladder.sizes == (16, 32, 64, 128)
+        assert eng.cache.dtype == "float32" and not eng.cache.quantized
+        assert eng.prefix is None
+        assert eng.decodez()["block_pool"]["overcommit"] is False
+        assert eng.cache.num_blocks == 1 + 8 * (128 // 16)
+    finally:
+        eng.close()
+
+
 def test_seeded_sampling_replays_across_batch_compositions():
     """A seeded sampled stream depends only on (seed, token index) —
     identical whether it runs alone or sharing the batch with other

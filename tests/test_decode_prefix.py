@@ -1,11 +1,12 @@
 """Refcounted block lifecycle (ISSUE 18): refcounted allocator +
 copy-on-write, the hash-keyed prefix cache (verify-on-hit collision
 safety, LRU park/revive/reclaim), overcommit admission with preemption
-+ token-exact re-prefill resume, beam forking on the shared pool, the
-flags-off byte-identity pins, the ``decode.<name>.blocks_leaked``
-invariant, and the chaos drill: a replica hard-killed mid-preemption
-while its siblings' in-flight streams keep going and the supervisor's
-replacement comes back with a clean pool."""
++ token-exact re-prefill resume, beam forking on the shared pool, what
+an engine with neither policy does and reports, the one prefill path's
+four cases, the ``decode.<name>.blocks_leaked`` invariant, and the
+chaos drill: a replica hard-killed mid-preemption while its siblings'
+in-flight streams keep going and the supervisor's replacement comes back
+with a clean pool."""
 import os
 import sys
 import threading
@@ -188,7 +189,7 @@ def test_prefix_cache_lru_reclaims_oldest_and_repark_refreshes():
 
 # ---------------------------------------------------------------------------
 # engine: prefix hits (token parity + exact saved counter), leaks,
-# flags-off byte-identity
+# the plain engine, the one prefill path
 # ---------------------------------------------------------------------------
 
 def test_engine_prefix_hit_parity_and_exact_saved_tokens():
@@ -254,24 +255,179 @@ def test_engine_prefix_reclaim_under_pressure_and_no_leak():
         eng.close()
 
 
-def test_engine_flags_off_surface_is_byte_identical():
-    """Both flags off: no PrefixCache object, no ``block_pool`` /
-    ``prefix_cache`` / ``preemption`` cards on /decodez, and not one
-    ``decode.<name>.prefix_* / cow_* / preempt* / blocks_*`` series in
-    the metrics registry — the PR-12 surface, byte for byte."""
+def test_a_plain_engine_reports_a_whole_pool():
+    """Neither policy: the engine still keeps the pool's gauges and the
+    ``block_pool`` card, and after a mixed join/leave load every usable
+    block is free or referenced, none forked, none lost; the cards of
+    the two policies stay tied to their own policy."""
     _, _, eng = _engine("tpfx_off")
     try:
-        eng.generate(np.arange(1, 7, dtype=np.int32), max_new_tokens=3)
-        assert eng.prefix is None and eng._pstats is None
+        rng = np.random.RandomState(3)
+        handles = [eng.submit(rng.randint(1, TINY.vocab, size=n),
+                              SamplingParams(max_new_tokens=m))
+                   for n, m in ((6, 3), (11, 9), (3, 5), (16, 2), (8, 7))]
+        handles[2].cancel()
+        for h in handles:
+            assert h.result(timeout=120)["finish"] in ("length", "cancelled")
+        assert eng.drain(timeout=60)
+        assert eng.prefix is None
         z = eng.decodez()
-        for card in ("block_pool", "prefix_cache", "preemption"):
-            assert card not in z
-        names = obs.stats.default_registry().to_dict().keys()
-        bad = [n for n in names if n.startswith("decode.tpfx_off.")
-               and any(t in n for t in ("prefix", "cow", "preempt",
-                                        "blocks_referenced",
-                                        "blocks_cached", "blocks_leaked"))]
-        assert bad == []
+        pool = z["block_pool"]
+        assert pool["leaked"] == 0 and pool["cow_forks"] == 0
+        assert pool["free"] + pool["referenced"] == pool["size"] - 1
+        assert pool["cached"] == 0 and pool["overcommit"] is False
+        assert "prefix_cache" not in z and "preemption" not in z
+        gauges = obs.stats.default_registry().to_dict()
+        assert gauges["decode.tpfx_off.blocks_leaked"] == 0
+        assert gauges["decode.tpfx_off.blocks_cached"] == 0
+        assert gauges["decode.tpfx_off.blocks_referenced"] == 0
+    finally:
+        eng.close()
+
+
+def test_a_plain_engine_places_blocks_as_the_free_list_always_did(
+        monkeypatch):
+    """Neither policy: the blocks a request sequence is given, in order,
+    are the ones the engine before the one-lifecycle change (PR 31) gave
+    (recorded from it); no prompt is hashed, no block copied, no stream
+    preempted."""
+    from paddle_tpu.decode import cache as cache_mod
+    called = []
+    for name in ("chain_keys", "match", "insert"):
+        monkeypatch.setattr(
+            cache_mod.PrefixCache, name,
+            lambda self, *a, _n=name, **k: called.append(_n))
+    _, _, eng = _engine("tpfx_replay", num_blocks=14)   # 13 usable
+    eng._copy_block = lambda *a: called.append("_copy_block")
+    eng._preempt_newest = lambda: called.append("_preempt_newest")
+    granted, alloc = [], eng.cache.allocator.alloc
+
+    def recorded(n):
+        got = alloc(n)
+        granted.append(got)
+        return got
+    eng.cache.allocator.alloc = recorded
+    rng = np.random.RandomState(11)
+
+    def batch(shapes):
+        # submitted under the engine's lock: ONE admission sweep sees
+        # them all, so the order of grants is the order of submission
+        with eng._lock:
+            handles = [eng.submit(rng.randint(1, TINY.vocab, size=n),
+                                  SamplingParams(max_new_tokens=m))
+                       for n, m in shapes]
+        for h in handles:
+            assert h.result(timeout=120)["finish"] == "length"
+        assert eng.drain(timeout=60)
+    try:
+        batch(((6, 3), (9, 10), (5, 6)))    # 3, 5 and 3 blocks; the first
+        batch(((12, 4), (3, 2)))            # leaves first, the second last
+        assert granted == REPLAYED_GRANTS
+        assert called == []
+        assert eng.cache.allocator._free == REPLAYED_FREE_LIST
+    finally:
+        eng.close()
+
+
+# what the engine of PR 31 (commit 52aef04) granted for the sequence above
+REPLAYED_GRANTS = [[1, 2, 3], [4, 5, 6, 7, 8], [9, 10, 11], [12, 13, 1, 2],
+                   [3, 9]]
+REPLAYED_FREE_LIST = [10, 11, 4, 5, 6, 7, 8, 3, 9, 12, 13, 1, 2]
+
+
+def _filed_spans(monkeypatch):
+    """Every ``trace.span`` of the process, as (name, arguments), filed
+    when it closes."""
+    from paddle_tpu.observability import trace
+    filed = []
+
+    class Span:
+        def __init__(self, name, args):
+            self.name, self.args = name, dict(args)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            filed.append((self.name, self.args))
+
+        def annotate(self, **args):
+            self.args.update(args)
+
+    monkeypatch.setattr(trace, "span", lambda name, **a: Span(name, a))
+    return filed
+
+
+def _preempt_once(eng, after_tokens):
+    """Evict the one live stream, on the engine's own thread, before the
+    first step that finds it with ``after_tokens`` tokens."""
+    ensure, done = eng._ensure_blocks, []
+
+    def ensure_after_one_eviction():
+        live = [s for s in eng._slots if s is not None]
+        if not done and live and live[0].n_generated >= after_tokens:
+            done.append(live[0].req.rid)
+            eng._preempt_newest()
+        ensure()
+    eng._ensure_blocks = ensure_after_one_eviction
+    return done
+
+
+@pytest.mark.parametrize("case,policies,starts", [
+    ("fresh", {}, [None]),
+    ("prefix_hit", {"prefix_cache": True}, [8]),
+    ("resume", {"overcommit": True}, [None, 0]),
+    ("resume_behind_hit", {"prefix_cache": True, "overcommit": True},
+     [8, 8]),
+])
+def test_the_one_prefill_path_serves_every_case_token_for_token(
+        case, policies, starts, monkeypatch):
+    """Fresh prompt, suffix behind prefix hits, preemption resume from
+    position 0 and resume behind prefix hits all go through
+    ``DecodeEngine._prefill``: the stream's tokens are those of an
+    uninterrupted plain engine, and every prefill files ONE
+    ``decode::prefill`` span (children ``.feed`` / ``.wait`` / ``.emit``)
+    whose ``start`` says where the dispatch began (absent: a fresh
+    prompt)."""
+    warm = np.arange(1, 9, dtype=np.int32)              # 2 full blocks
+    prompt = np.concatenate([warm, [9, 10]]).astype(np.int32)
+    _, _, ref = _engine(f"tone_ref_{case}")
+    try:
+        want = ref.generate(prompt, max_new_tokens=9)["tokens"]
+    finally:
+        ref.close()
+    _, _, eng = _engine(f"tone_{case}", **policies)
+    try:
+        if "prefix_cache" in policies:
+            eng.generate(warm, max_new_tokens=2)        # registers 2 blocks
+        evicted = (_preempt_once(eng, after_tokens=4)
+                   if "overcommit" in policies else None)
+        before = eng.stats.prefills.value, eng.stats.tokens.value
+        filed = _filed_spans(monkeypatch)
+        handle = eng.submit(prompt, SamplingParams(max_new_tokens=9))
+        assert list(handle) == want
+        assert handle.result(timeout=60)["tokens"] == want
+        assert eng.drain(timeout=60)
+        monkeypatch.undo()
+        if evicted is not None:
+            assert evicted == [handle.rid]
+            assert eng._pstats.preempt_resumes.value == 1
+        prefills = [a for n, a in filed if n == "decode::prefill"]
+        assert [a.get("start") for a in prefills] == starts
+        for a in prefills:
+            assert a["rid"] == handle.rid and a["queue_ms"] >= 0
+            assert a["bucket"] >= a["prompt"] - a.get("start", 0) > 0
+        # the first dispatch makes the prompt resident, a resume the
+        # prompt and all but the last of the 4 tokens it had
+        assert [a["prompt"] for a in prefills] == [10, 13][:len(starts)]
+        names = [n for n, _ in filed if n.startswith("decode::prefill")]
+        assert names == ["decode::prefill.feed", "decode::prefill.wait",
+                         "decode::prefill.emit",
+                         "decode::prefill"] * len(starts)
+        # a resumed prefill counts a prefill and no token
+        assert eng.stats.prefills.value - before[0] == len(starts)
+        assert eng.stats.tokens.value - before[1] == len(want)
+        assert eng.decodez()["block_pool"]["leaked"] == 0
     finally:
         eng.close()
 

@@ -292,27 +292,23 @@ def test_injected_decode_oom_dumps_forensics_and_recovers(
     pool as top holder with preempt events in the tail, while the
     engine recovers through the existing preemption path — every
     stream completes, the recovery is counted, nothing crashes."""
-    _flags.set_flags({"decode_overcommit": True})
     _faults.inject("oom:decode_step:n=3,times=2")
+    eng, SP = _mk_engine("t_mem_oom", num_blocks=24, overcommit=True)
     try:
-        eng, SP = _mk_engine("t_mem_oom", num_blocks=24, overcommit=True)
-        try:
-            handles = [eng.submit(p, SP(max_new_tokens=16))
-                       for p in _prompts(8)]
-            results = [h.result(timeout=120) for h in handles]
-            assert all(r["finish"] == "length" for r in results)
-            rec = memory.last_oom()
-            assert rec is not None and rec["site"] == "decode_step"
-            assert rec["top_holders"][0]["pool"] == "decode_kv.t_mem_oom"
-            assert any(e["kind"] == "preempt" for e in rec["events"])
-            snap = stats.export_state()["metrics"]
-            assert snap["decode.t_mem_oom.oom_recovered"]["value"] >= 1
-            assert snap["memory.oom_dumps"]["value"] >= 1
-            assert eng._mem_pool_audit() == 0
-        finally:
-            eng.close()
+        handles = [eng.submit(p, SP(max_new_tokens=16))
+                   for p in _prompts(8)]
+        results = [h.result(timeout=120) for h in handles]
+        assert all(r["finish"] == "length" for r in results)
+        rec = memory.last_oom()
+        assert rec is not None and rec["site"] == "decode_step"
+        assert rec["top_holders"][0]["pool"] == "decode_kv.t_mem_oom"
+        assert any(e["kind"] == "preempt" for e in rec["events"])
+        snap = stats.export_state()["metrics"]
+        assert snap["decode.t_mem_oom.oom_recovered"]["value"] >= 1
+        assert snap["memory.oom_dumps"]["value"] >= 1
+        assert eng._mem_pool_audit() == 0
     finally:
-        _flags.set_flags({"decode_overcommit": False})
+        eng.close()
 
 
 def test_injected_serving_oom_dumps_forensics(mem_flag, clean_faults):

@@ -50,6 +50,21 @@ def test_flags_env_types_and_api():
         fluid.set_flags({"no_such_flag": 1})
 
 
+@pytest.mark.parametrize("name", [
+    # a DecodeEngine is set up by its constructor's arguments alone
+    "decode_max_slots", "decode_max_queue", "decode_block_tokens",
+    "decode_prefill_buckets", "decode_kv_dtype", "decode_prefix_cache",
+    "decode_overcommit",
+    # accepted for parity, read by nothing
+    "eager_delete_tensor_gb", "fraction_of_gpu_memory_to_use",
+    "cpu_deterministic", "paddle_num_threads"])
+def test_a_removed_flag_is_a_name_the_registry_never_had(name):
+    with pytest.raises(KeyError):
+        fluid.get_flags(name)
+    with pytest.raises(KeyError):
+        fluid.set_flags({"FLAGS_" + name: 1})
+
+
 def test_check_nan_inf_flag_catches_bad_values():
     prog, startup = Program(), Program()
     with program_guard(prog, startup), unique_name.guard():

@@ -430,17 +430,14 @@ def test_int8_overcommit_preempt_resume_is_loss_free():
         eng.close()
 
 
-def test_decode_kv_dtype_flag_latched_at_engine_build():
-    assert _flags.get_flags("decode_kv_dtype") == "float32"
-    _flags.set_flags({"FLAGS_decode_kv_dtype": "int8"})
+def test_cache_dtype_is_latched_at_engine_build():
+    eng = _engine("tq_flag", cache_dtype="int8")
     try:
-        eng = _engine("tq_flag")
-        try:
-            assert eng.cache.quantized
-        finally:
-            eng.close()
+        assert eng.cache.quantized and eng.cache.dtype == "int8"
+        assert len(eng.cache.state()) == 4      # codes and scale pools
+        assert eng.decodez()["cache"]["dtype"] == "int8"
     finally:
-        _flags.set_flags({"FLAGS_decode_kv_dtype": "float32"})
+        eng.close()
 
 
 def test_flags_off_surface_is_byte_identical():

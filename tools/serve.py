@@ -85,14 +85,13 @@ def build_parser() -> argparse.ArgumentParser:
                         "(decode.save_lm layout) on the streaming decode "
                         "plane instead of one-shot inference")
     p.add_argument("--decode-slots", type=int, default=None,
-                   help="decode-batch width (default: "
-                        "FLAGS_decode_max_slots)")
+                   help="decode-batch width (default: DecodeEngine's, 8)")
     p.add_argument("--decode-block-tokens", type=int, default=None,
                    help="paged KV cache block size in tokens (default: "
-                        "FLAGS_decode_block_tokens)")
+                        "DecodeEngine's, 16)")
     p.add_argument("--decode-prefill-buckets", default=None,
-                   help="prompt-length ladder, e.g. 16,32,64,128 "
-                        "(default: FLAGS_decode_prefill_buckets)")
+                   help="prompt-length ladder (default: DecodeEngine's, "
+                        "16,32,64,128)")
     p.add_argument("--no-warm", action="store_true",
                    help="skip the bucket-ladder warm pool (first requests "
                         "pay the compiles)")
@@ -136,20 +135,16 @@ def _serve_decode(args) -> int:
     import paddle_tpu as fluid  # noqa: F401 (registers lowerings)
     from paddle_tpu.core import flags as _flags
     from paddle_tpu.decode import DecodeEngine, DecodeServer, load_lm
-    from paddle_tpu.serving import BucketLadder
 
     if args.debug_port:
         _flags.set_flags({"debug_server_port": args.debug_port})
     lm, params = load_lm(args.model_dir)
-    kw = {}
-    if args.decode_slots is not None:
-        kw["max_slots"] = args.decode_slots
-    if args.decode_block_tokens is not None:
-        kw["block_tokens"] = args.decode_block_tokens
-    if args.decode_prefill_buckets is not None:
-        kw["prefill_buckets"] = BucketLadder.parse(
-            args.decode_prefill_buckets)
-    eng = DecodeEngine(lm, params, name=args.model, **kw)
+    # an option left out is None: the engine's own default
+    eng = DecodeEngine(lm, params, name=args.model,
+                       max_slots=args.decode_slots,
+                       block_tokens=args.decode_block_tokens,
+                       prefill_buckets=_bucket_list(
+                           args.decode_prefill_buckets))
     srv = DecodeServer(args.endpoint, engines={args.model: eng},
                        registry_ep=args.registry,
                        replica_id=args.replica_id)

@@ -37,8 +37,10 @@ package is that state plane, built on the repo's own primitives:
   thread as ``(const, state, *feed) → (outs, state')``, and says what of
   the block lifecycle it ``supports``; the engine has no branch on a
   model's kind.
-- **On-device sampling** (:mod:`model`): greedy / top-k / temperature
-  inside the decode dispatch; incremental beam search rides
+- **On-device sampling** (:mod:`model`): greedy (an argmax; the
+  vocabulary is sorted only in a launch that holds a sampled request) /
+  top-k / temperature inside the decode dispatch; incremental beam
+  search rides
   :class:`paddle_tpu.contrib.decoder.IncrementalBeamDecoder` (the
   reference beam machinery, one ``beam_search`` step per decode step),
   and :class:`~paddle_tpu.decode.beam.PagedBeamDecoder` runs its beams

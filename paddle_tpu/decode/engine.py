@@ -143,7 +143,15 @@ _FANOUT_MS_BUCKETS = (0.5, 1.0, 2.0, 3.0, 4.0, 5.0, 6.0, 8.0, 10.0, 12.5,
 class SamplingParams:
     """Per-request sampling config.  ``temperature <= 0`` is greedy;
     ``top_k == 0`` samples the full vocab (under the compiled
-    ``TOPK_MAX`` ceiling)."""
+    ``TOPK_MAX`` ceiling).
+
+    What it costs (:func:`paddle_tpu.decode.model._sample`): a greedy
+    request's token is an argmax and sorts nothing; one
+    ``temperature > 0`` request puts every launch it takes part in — its
+    prefill, and each decode step it shares with the batch — on the
+    whole-vocabulary sort.  Every request's tokens are the same either
+    way; counters ``greedy_steps`` / ``greedy_prefills`` beside
+    ``steps`` / ``prefills`` say how many launches went without."""
 
     def __init__(self, temperature: float = 0.0, top_k: int = 0,
                  max_new_tokens: int = 32, eos_id: Optional[int] = None,
@@ -424,6 +432,9 @@ class _EngineStats:
         sc = _obs_stats.scope(f"decode.{name}")
         self.tokens = sc.counter("tokens", "generated tokens (all streams)")
         self.prefills = sc.counter("prefills")
+        self.greedy_prefills = sc.counter(
+            "greedy_prefills", "prefills of a temperature <= 0 request: "
+            "sampled by argmax, no sort of the vocabulary")
         self.joins = sc.counter(
             "joins", "requests admitted into the running decode batch")
         self.leaves = sc.counter(
@@ -432,6 +443,9 @@ class _EngineStats:
             "shed", "requests refused by admission control (typed "
             "Overloaded/RequestTooLong)")
         self.steps = sc.counter("steps", "decode-step dispatches")
+        self.greedy_steps = sc.counter(
+            "greedy_steps", "decode steps with no temperature > 0 slot: "
+            "sampled by argmax, no sort of the vocabulary")
         self.queue = sc.gauge("queue_depth")
         self.active = sc.gauge("slots_active")
         self.blocks_free = sc.gauge("blocks_free")
@@ -896,6 +910,8 @@ class DecodeEngine:
         with _trace.span("decode::prefill.emit"):
             slot.t_last = time.perf_counter()
             self.stats.prefills.inc()
+            if not (req.sampling.temperature > 0.0):
+                self.stats.greedy_prefills.inc()
             prefill_ms = (slot.t_last - t0) * 1e3
             self.stats.prefill_ms.observe(prefill_ms)
             if _capacity.enabled():
@@ -1021,12 +1037,14 @@ class DecodeEngine:
             self._observer.step(
                 extra, int(positions[live].sum()) + len(live))
         with _trace.span("decode::step.book"):
-            self._book_step(live, toks_np, logits_np, t0)
+            self._book_step(live, toks_np, logits_np, t0,
+                            greedy=not (temps > 0.0).any())
         if not any(s is not None for s in self._slots):
             self._flush_fanout()   # the last stream left: no step follows
 
     def _book_step(self, live: List[int], toks_np: np.ndarray,
-                   logits_np: Optional[np.ndarray], t0: float) -> None:
+                   logits_np: Optional[np.ndarray], t0: float,
+                   greedy: bool) -> None:
         """Book one step's tokens at the read: counters, the per-slot
         state, ``handle._book``, retirement (slot and blocks free for
         the admission sweep that follows).  Waking the streams is left
@@ -1034,6 +1052,8 @@ class DecodeEngine:
         now = time.perf_counter()
         self._fanout_t_read = now
         self.stats.steps.inc()
+        if greedy:     # the predicate _sample's cond took on the device
+            self.stats.greedy_steps.inc()
         step_ms = (now - t0) * 1e3
         self.stats.step_ms.observe(step_ms)
         if _capacity.enabled():
@@ -1428,7 +1448,9 @@ class DecodeEngine:
             "queue_depth": pending,
             "tokens": self.stats.tokens.value,
             "steps": self.stats.steps.value,
+            "greedy_steps": self.stats.greedy_steps.value,
             "prefills": self.stats.prefills.value,
+            "greedy_prefills": self.stats.greedy_prefills.value,
             "joins": self.stats.joins.value,
             "leaves": self.stats.leaves.value,
             "shed": self.stats.shed.value,

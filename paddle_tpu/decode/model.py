@@ -537,19 +537,35 @@ def _sample(logits, seeds, steps, temperature, top_k):
     the static ``TOPK_MAX`` ceiling; sampling is Gumbel-max over the
     top slice with :func:`_hash_uniform` bits, so a request's sampled
     stream depends only on (its seed, its token indices) — replayable
-    across slot placements and batch compositions."""
+    across slot placements and batch compositions.
+
+    What a caller can rely on: a greedy row is an argmax (the first
+    maximal index) and costs no sort.  ``lax.top_k`` — on a TPU a sort
+    of the whole vocabulary — runs only in a launch that holds at least
+    one ``temperature > 0`` row (the ``lax.cond`` below: one program,
+    the branch taken on the device from this launch's own input), and
+    then every row of that launch pays for it.  The tokens are the same
+    either way: a greedy row's is column 0 of the sorted slice, whose
+    ties go to the lower index as argmax's do."""
     S, V = logits.shape
     kk = min(TOPK_MAX, V)
-    vals, idx = jax.lax.top_k(logits.astype(jnp.float32), kk)  # [S, kk]
-    lane = jnp.arange(kk, dtype=jnp.int32)[None, :]
-    want = jnp.where(top_k > 0, jnp.minimum(top_k, kk), kk)[:, None]
-    vals = jnp.where(lane < want, vals, -jnp.inf)
-    g = -jnp.log(-jnp.log(_hash_uniform(seeds, steps, kk)))
-    temp = jnp.maximum(temperature, 1e-6)[:, None]
-    choice = jnp.argmax(vals / temp + g, axis=-1)
-    greedy = idx[:, 0]                     # top_k output is sorted
-    sampled = jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
-    return jnp.where(temperature <= 0.0, greedy, sampled).astype(jnp.int32)
+    x = logits.astype(jnp.float32)
+    greedy = jnp.argmax(x, axis=-1).astype(jnp.int32)
+
+    def from_top_slice():
+        vals, idx = jax.lax.top_k(x, kk)                        # [S, kk]
+        lane = jnp.arange(kk, dtype=jnp.int32)[None, :]
+        want = jnp.where(top_k > 0, jnp.minimum(top_k, kk), kk)[:, None]
+        vals = jnp.where(lane < want, vals, -jnp.inf)
+        g = -jnp.log(-jnp.log(_hash_uniform(seeds, steps, kk)))
+        temp = jnp.maximum(temperature, 1e-6)[:, None]
+        choice = jnp.argmax(vals / temp + g, axis=-1)
+        return jnp.take_along_axis(
+            idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
+
+    sampled = jax.lax.cond(jnp.any(temperature > 0.0), from_top_slice,
+                           lambda: greedy)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
 # ---------------------------------------------------------------------------

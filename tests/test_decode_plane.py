@@ -368,6 +368,154 @@ def test_seeded_sampling_replays_across_batch_compositions():
         eng.close()
 
 
+def _sample_top_k_then_select(logits, seeds, steps, temperature, top_k):
+    """The sampling epilogue as it was before greedy rows took an argmax:
+    sort every row's top slice, then choose — the plain reference
+    :func:`_sample` is held to, token for token."""
+    import jax
+    from paddle_tpu.decode.model import TOPK_MAX, _hash_uniform
+    kk = min(TOPK_MAX, logits.shape[1])
+    vals, idx = jax.lax.top_k(logits.astype(jnp.float32), kk)
+    lane = jnp.arange(kk, dtype=jnp.int32)[None, :]
+    want = jnp.where(top_k > 0, jnp.minimum(top_k, kk), kk)[:, None]
+    vals = jnp.where(lane < want, vals, -jnp.inf)
+    g = -jnp.log(-jnp.log(_hash_uniform(seeds, steps, kk)))
+    temp = jnp.maximum(temperature, 1e-6)[:, None]
+    choice = jnp.argmax(vals / temp + g, axis=-1)
+    sampled = jnp.take_along_axis(idx, choice[:, None], axis=1)[:, 0]
+    return jnp.where(temperature <= 0.0, idx[:, 0],
+                     sampled).astype(jnp.int32)
+
+
+def _tied(logits):
+    """Every row's maximum three times over, at columns 5, 17 and 40."""
+    logits[:, [40, 17, 5]] = logits.max(axis=1, keepdims=True) + 1.0
+    return logits
+
+
+# name -> (rows S, vocabulary V, logits dtype, temperature per row,
+#          top_k per row, what to do to the logits first)
+_SAMPLE_CASES = {
+    "all_greedy": (6, 300, "float32", [0.0] * 6, [0, 1, 6, 100, 0, 6], None),
+    "all_sampled": (6, 300, "float32", [0.7, 1.0, 0.2, 1.5, 0.9, 3.0],
+                    [0, 1, 6, 100, 64, 65], None),
+    "mixed_rows": (6, 300, "float32", [0.0, 0.8, 0.0, -1.0, 1.3, 0.0],
+                   [0, 6, 6, 0, 1, 100], None),
+    "greedy_tied_maxima": (4, 300, "float32", [0.0, 0.0, -0.5, 0.0],
+                           [0, 6, 0, 1], _tied),
+    "tied_maxima_beside_a_sampled_row": (4, 300, "float32",
+                                         [0.0, 0.9, 0.0, 0.0], [0, 6, 0, 1],
+                                         _tied),
+    "one_row_greedy": (1, 300, "float32", [0.0], [0], None),
+    "one_row_sampled": (1, 300, "float32", [0.8], [6], None),
+    "vocabulary_below_topk_max": (5, 48, "float32",
+                                  [0.0, 0.9, 0.0, 1.1, 0.6],
+                                  [0, 0, 6, 100, 1], None),
+    "bf16_logits_greedy": (6, 300, "bfloat16", [0.0] * 6,
+                           [0, 1, 6, 100, 0, 6], None),
+    "bf16_logits_mixed": (6, 300, "bfloat16",
+                          [0.0, 0.8, 0.0, 0.0, 1.3, 0.0],
+                          [0, 6, 6, 0, 1, 100], None),
+}
+
+
+@pytest.mark.parametrize("case", list(_SAMPLE_CASES))
+def test_sample_is_the_top_k_then_select_reference_token_for_token(case):
+    """Greedy rows take an argmax and the sort runs only in a launch that
+    holds a sampled row — and no request can tell: the same tokens as
+    sorting every row first, over greedy, sampled and mixed launches,
+    tied maxima (the lowest index wins), one row (a prefill's shape), a
+    vocabulary below ``TOPK_MAX``, bf16 logits and ``top_k`` 0 / 1 / 6 /
+    beyond ``TOPK_MAX``, at three token indices."""
+    import jax
+    from paddle_tpu.decode.model import _sample
+    S, V, dtype, temps, topks, prepare = _SAMPLE_CASES[case]
+    rng = np.random.RandomState(len(case))
+    logits = (rng.randn(S, V) * 3).astype(np.float32)
+    if prepare is not None:
+        logits = prepare(logits)
+    logits = jnp.asarray(logits).astype(dtype)
+    if dtype == "bfloat16":    # rounding makes ties of its own: keep them
+        assert len(np.unique(np.asarray(logits[0], np.float32))) < V
+    seeds = jnp.asarray(rng.randint(0, 2 ** 31, S), jnp.uint32)
+    temps = jnp.asarray(temps, jnp.float32)
+    topks = jnp.asarray(topks, jnp.int32)
+    new, ref = jax.jit(_sample), jax.jit(_sample_top_k_then_select)
+    for index in (0, 1, 77):
+        steps = jnp.full((S,), index, jnp.int32)
+        got = np.asarray(new(logits, seeds, steps, temps, topks))
+        want = np.asarray(ref(logits, seeds, steps, temps, topks))
+        assert got.dtype == np.int32 and got.shape == (S,)
+        assert (got == want).all(), (case, index, got, want)
+    if prepare is _tied:
+        greedy_rows = np.asarray(temps) <= 0
+        assert (got[greedy_rows] == 5).all()
+
+
+def test_decode_step_sorts_the_vocabulary_only_inside_the_conditional():
+    """The compiled decode step holds its one sort of the vocabulary in a
+    branch of ``_sample``'s conditional: nothing the entry computation
+    runs in every launch sorts or takes a top-k."""
+    import jax
+    from hlo_text import sorts_outside_a_branch
+    lm = TransformerLM(TINY)
+    plist = lm.param_list(lm.init_params(seed=5))
+    cache = PagedKVCache(TINY.n_layer, TINY.n_head, TINY.head_dim, 9, 4)
+    S, MB = 3, 8
+    i32 = jnp.int32
+    feed = [jnp.zeros((S,), i32), jnp.zeros((S,), i32),
+            jnp.zeros((S, MB), i32), jnp.zeros((S,), jnp.uint32),
+            jnp.zeros((S,), i32), jnp.zeros((S,), jnp.float32),
+            jnp.zeros((S,), i32)]
+
+    def step(feed, state, const):
+        return lm.decode_step(const, state, *feed, attn_impl="xla")
+
+    text = jax.jit(step).lower(feed, cache.state(), plist).compile().as_text()
+    outside, inside = sorts_outside_a_branch(text)
+    assert not outside, f"sorted in every launch: {sorted(outside)}"
+    assert len(inside) == 1, inside
+    # and the reader sees a sort that IS outside one (the old epilogue)
+    outside, inside = sorts_outside_a_branch(
+        jax.jit(_sample_top_k_then_select).lower(
+            jnp.zeros((S, TINY.vocab)), *feed[3:]).compile().as_text())
+    assert outside and not inside
+
+
+def test_greedy_steps_are_counted_and_a_sampled_neighbour_changes_no_token():
+    """``greedy_steps`` / ``greedy_prefills`` count the launches whose
+    every row was greedy — all of a greedy run's, fewer once a sampled
+    request shares the batch — and a greedy stream's tokens are the same
+    whether or not a sampled stream sits beside it."""
+    lm, params, eng = _engine("greedy_ctr")
+    try:
+        prompt = np.arange(5, dtype=np.int32)
+        alone = eng.generate(prompt, max_new_tokens=8)
+        other = eng.generate(prompt[::-1].copy(), max_new_tokens=4)
+        assert eng.drain(timeout=30)
+        z = eng.decodez()
+        assert z["steps"] > 0 and z["greedy_steps"] == z["steps"]
+        assert z["greedy_prefills"] == z["prefills"] == 2
+        sampled = eng.submit(np.arange(7, dtype=np.int32),
+                             SamplingParams(max_new_tokens=12, seed=3,
+                                            temperature=0.9, top_k=6))
+        beside = eng.generate(prompt, max_new_tokens=8)
+        sampled.result(timeout=60)
+        assert eng.drain(timeout=30)
+        assert beside["tokens"] == alone["tokens"] != other["tokens"]
+        z2 = eng.decodez()
+        shared = z2["steps"] - z["steps"]
+        assert shared >= 11                # the sampled stream's own steps
+        assert z2["greedy_steps"] - z["greedy_steps"] <= shared - 11
+        assert z2["greedy_steps"] < z2["steps"]
+        assert z2["prefills"] == 4 and z2["greedy_prefills"] == 3
+        snap = obs.stats.default_registry().snapshot()
+        assert snap["decode.greedy_ctr.greedy_steps"] == \
+            z2["greedy_steps"]
+    finally:
+        eng.close()
+
+
 def test_cancel_frees_slot_and_blocks_mid_stream():
     lm, params, eng = _engine("cancel")
     try:

@@ -11,6 +11,11 @@ pool's shape.  Depth is cut to two layers and the vocabulary to 1,024 (the
 layout depends on neither); widths, block size and pool length are the
 benchmark's.
 
+Since PR 33 also: the sampling epilogue's sort of the vocabulary lies in
+a branch of its ``conditional`` in each of those programs, and at both
+served vocabularies (40,478 and 102,400 columns) XLA:TPU keeps that
+conditional — a launch whose rows are all greedy sorts nothing.
+
 The topology is described inside a fixture, never at import: one
 process at a time may load the TPU's library, and every xdist worker
 imports every test file.
@@ -25,8 +30,10 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.decode import LMConfig, PagedKVCache, TransformerLM
-from paddle_tpu.decode.model import _param_names
+from paddle_tpu.decode.model import _param_names, _sample
 from paddle_tpu.kernels import attention as AK
+
+from hlo_text import sorts_outside_a_branch
 
 # tlm-gpt1w (benchmark/configs/tlm-gpt1w.json) at two layers and a small
 # vocabulary (neither touches the pool; both are most of the compile
@@ -122,6 +129,10 @@ def test_pool_is_neither_copied_nor_relaid(one_chip, mosaic, name, kv_dtype):
     assert temp < pool_bytes / 4, (temp, pool_bytes)
     if name == "step":
         assert text.count("tpu_custom_call") == CFG.n_layer
+    # ... and whatever sorts the vocabulary lies in a branch that only a
+    # launch with a sampled row enters
+    outside, inside = sorts_outside_a_branch(text)
+    assert not outside and inside, (outside, inside)
 
 
 def test_kernel_refuses_nothing_at_full_context_width(one_chip, mosaic):
@@ -136,3 +147,23 @@ def test_kernel_refuses_nothing_at_full_context_width(one_chip, mosaic):
             sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+@pytest.mark.parametrize("rows,vocab", [(64, 40478), (1, 40478),
+                                        (64, 102400), (1, 102400)])
+def test_a_greedy_launch_sorts_nothing_at_the_served_vocabularies(
+        one_chip, rows, vocab):
+    """``_sample`` at both served vocabularies, a step's 64 rows and a
+    prefill's one: XLA:TPU keeps the conditional (it is not flattened
+    into a select that runs both sides), and every sort and ``TopK`` lies
+    in the branch that a launch with a ``temperature > 0`` row takes."""
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    with jax.enable_x64(False):
+        text = jax.jit(_sample).lower(
+            sds((rows, vocab), jnp.float32), sds((rows,), jnp.uint32),
+            sds((rows,), jnp.int32), sds((rows,), jnp.float32),
+            sds((rows,), jnp.int32)).compile().as_text()
+    assert " conditional(" in text
+    outside, inside = sorts_outside_a_branch(text)
+    assert not outside and inside, (outside, inside)

@@ -71,6 +71,14 @@ LATENT_FALLBACK_COUNTERS = (
     "mla.prefill_attn_fallbacks",
 )
 
+# the XLA fallbacks of the hybrid (state-space / window / shared-pool) LM's
+# kernels (kernels/ssm.py, kernels/diffattn.py)
+HYBRID_FALLBACK_COUNTERS = (
+    "ssm.scan_fallbacks",
+    "attn.diff_decode_fallbacks",
+    "attn.diff_prefill_fallbacks",
+)
+
 MOSAIC_CALL = "tpu_custom_call"
 
 
@@ -657,6 +665,80 @@ def phase_latent_lm(on_chip=True, vocab=8192, hidden=512, heads=8, nope=128,
         engine.close()
 
 
+def phase_hybrid_lm(vocab=8192, hidden=512, heads=8, kv_heads=4, ffn=1024,
+                    layers=8, window=32, max_seq_len=512, max_slots=4,
+                    block_tokens=16, prefill_bucket=128, prompt_len=77,
+                    new_tokens=48, dtype="bfloat16"):
+    """``decode.sambay.SambaYLM`` (Phi-4-mini-flash-reasoning's stack at its
+    head width, eight layers so that every kind exists) through
+    ``DecodeEngine``: one short stream, past the window, whose logits at
+    every generated position are held against the benchmark's plain
+    reference; no kernel fell back (on the chip none is interpreted);
+    ``/decodez`` shows the three kinds of state."""
+    import jax.numpy as jnp
+    from benchmark.reference import sambay as reference
+    from paddle_tpu.decode import DecodeEngine, SamplingParams
+    from paddle_tpu.decode.sambay import SambaYConfig, SambaYLM
+
+    cfg = SambaYConfig(
+        vocab_size=vocab, hidden_size=hidden, num_hidden_layers=layers,
+        num_attention_heads=heads, num_key_value_heads=kv_heads,
+        intermediate_size=ffn, sliding_window=window,
+        max_seq_len=max_seq_len, dtype=dtype)
+    model = SambaYLM(cfg)
+    params = model.init_params(seed=6)
+    c0 = counters()
+    engine = DecodeEngine(model, params, name="hybrid", max_slots=max_slots,
+                          block_tokens=block_tokens,
+                          prefill_buckets=[prefill_bucket],
+                          capture_logits=True, attn_impl="pallas",
+                          cache_dtype=dtype, prefix_cache=False,
+                          overcommit=False)
+    try:
+        prompt = np.random.RandomState(0).randint(
+            0, vocab, (prompt_len,)).astype("int32")
+        handle = engine.submit(prompt,
+                               SamplingParams(max_new_tokens=new_tokens))
+        result = handle.result(timeout=900.0)
+        toks = np.asarray(result["tokens"], np.int32)
+        check(toks.size == new_tokens and result.get("finish") == "length",
+              f"the hybrid stream ended early: {result}")
+        seq = np.concatenate([prompt, toks[:-1]])
+        want, _, _ = reference.forward(
+            {k: jnp.asarray(v) for k, v in params.items()}, cfg.to_dict(),
+            seq, seq.size, np.arange(prompt_len - 1, seq.size))
+        want = np.asarray(want)
+        got = np.stack(handle.logits).astype(np.float32)
+        err = np.sqrt(((got - want) ** 2).sum(-1) / (want ** 2).sum(-1))
+        scale = float(np.abs(want).max())
+        gap = want.max(-1) - np.take_along_axis(want, toks[:, None], 1)[:, 0]
+        # bf16 activations through 8 layers against float32 at the highest
+        # precision: a few percent of the logits' norm, and a token at most
+        # 5% of the logit scale under the reference's argmax
+        check(float(err.max()) <= (0.08 if dtype == "bfloat16" else 1e-3),
+              f"hybrid-LM logits are {err.max():.4f} of their norm off the "
+              f"reference")
+        check(float(gap.max()) <= 0.05 * scale,
+              f"a hybrid-LM token trails the reference's argmax by "
+              f"{gap.max():.4f} (logit scale {scale:.2f})")
+        fell = {n: counter_delta(c0, n) for n in HYBRID_FALLBACK_COUNTERS}
+        check(not any(fell.values()), f"a new kernel fell back: {fell}")
+        z = engine.decodez()
+        cache = z["cache"]
+        check(cache.get("kind") == "hybrid" and all(
+            cache.get(k, 0) > 0 for k in (
+                "kv_pool_bytes", "window_state_bytes",
+                "recurrent_state_bytes", "kv_live_tokens")),
+              f"/decodez does not show the three kinds of state: {cache}")
+        return {"tokens_checked": int(toks.size),
+                "tokens_exact": int((gap == 0).sum()),
+                "logit_err_max": float(err.max()),
+                "worst_logit_gap": float(gap.max()), "logit_scale": scale,
+                "steps": z["steps"], "cache": cache, "fallbacks": fell}
+    finally:
+        engine.close()
+
+
 # ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
@@ -814,6 +896,7 @@ def main() -> int:
     run_phase(report, "kernels", phase_kernels, place)
     run_phase(report, "decode_server", phase_decode_server)
     run_phase(report, "latent_lm", phase_latent_lm)
+    run_phase(report, "hybrid_lm", phase_hybrid_lm)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])
@@ -827,7 +910,8 @@ def main() -> int:
     c = counters()
     report["fallback_counters"] = {
         n: int(c.get(n, 0))
-        for n in FALLBACK_COUNTERS + LATENT_FALLBACK_COUNTERS}
+        for n in (FALLBACK_COUNTERS + LATENT_FALLBACK_COUNTERS
+                  + HYBRID_FALLBACK_COUNTERS)}
     report["jax_cache"] = {"hits": LOG.cache_hits, "compiles": LOG.compiles,
                            "compile_s": round(LOG.compile_s, 2)}
     failures += [f"phase {n}: {p.get('error')}"

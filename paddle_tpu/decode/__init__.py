@@ -37,6 +37,30 @@ package is that state plane, built on the repo's own primitives:
   thread as ``(const, state, *feed) → (outs, state')``, and says what of
   the block lifecycle it ``supports``; the engine has no branch on a
   model's kind.
+- **A third model, with three kinds of state** (:mod:`sambay`):
+  Phi-4-mini-flash-reasoning's decoder-hybrid-decoder stack — state-space
+  layers, window attention, ONE full-attention layer whose K/V rows seven
+  cross-attention layers read, gated memory units, differential attention
+  (``kernels/ssm.py``, ``kernels/diffattn.py``), its programs
+  ``lax.scan``s over stacked layer pairs.  Its cache
+  (:class:`~paddle_tpu.decode.cache.HybridStateCache`) holds, under the
+  one manager, blocks of a paged K/V pool (held to the stream's end,
+  addressed by block table), window rings (a slot's last W rows: bounded
+  by the window, not by the context) and recurrent rows (a slot's
+  state-space state and convolution tail), the last two addressed by
+  SLOT.  **The model protocol**: ``prefill(const, state, tokens, length,
+  [slot,] block_table, seed, temperature, top_k)`` and
+  ``decode_step(const, state, tokens, positions, block_tables, seeds,
+  steps, temperature, top_k)``, each ``→ ([token(s), logits, *extra],
+  state')`` with ``state`` the cache's own list; a model that sets
+  ``slot_state`` is given the engine's slot count in ``make_cache`` and
+  the joining request's slot in ``prefill``'s feed (its prefill overwrites
+  the slot's rows whole — the reset at a join); a decode step's row ``i``
+  is slot ``i``, and a slot without a stream scribbles on its own rows
+  only.  ``extra`` goes to the model's ``observer`` (``prefill(extra,
+  prompt, bucket)``; ``step(extra, contexts)`` with the live streams'
+  context lengths, which the engine holds on the host: a program returns
+  nothing for a count the host already has, and nothing for a check).
 - **On-device sampling** (:mod:`model`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
   top-k / temperature inside the decode dispatch; incremental beam
@@ -67,11 +91,12 @@ builds an engine gets no new arrays, threads, or sockets.
 """
 from __future__ import annotations
 
-from .cache import (BlockAllocator, PagedKVCache,  # noqa: F401
-                    PagedLatentCache, PrefixCache)
+from .cache import (BlockAllocator, HybridStateCache,  # noqa: F401
+                    PagedKVCache, PagedLatentCache, PrefixCache)
 from .model import (LMConfig, TransformerLM, load_lm,  # noqa: F401
                     save_lm)
 from .mla import MLAConfig, MLATransformerLM  # noqa: F401
+from .sambay import SambaYConfig, SambaYLM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -82,9 +107,10 @@ from ..serving.batcher import (Draining, Overloaded,  # noqa: F401
                                RequestTooLong)
 
 __all__ = [
-    "BlockAllocator", "PagedKVCache", "PagedLatentCache", "PrefixCache",
+    "BlockAllocator", "PagedKVCache", "PagedLatentCache", "HybridStateCache",
+    "PrefixCache",
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
-    "MLAConfig", "MLATransformerLM",
+    "MLAConfig", "MLATransformerLM", "SambaYConfig", "SambaYLM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

@@ -432,3 +432,93 @@ class PagedLatentCache(_PagedPool):
         snap.update(dtype=self.dtype, rank=self.rank, rope_dim=self.rope_dim,
                     row_width=self.row_width)
         return snap
+
+
+class HybridStateCache(_PagedPool):
+    """The per-stream state of a model that mixes state-space, window and
+    full attention layers — three kinds under one manager:
+
+    - ``kv`` ``[1, NB, bs, 2·kw]``: the paged K/V pool of the ONE
+      full-attention layer, a token's row ``[k | v]`` with the K/V heads
+      merged into the minor axis (whole lane tiles, class docs above).
+      Blocks, tables, the :class:`BlockAllocator` and the trash block are the
+      paged pools'; every layer that attends to that layer's keys reads this
+      pool, none copies it.
+    - ``rings`` ``[window layers, slots · W/rb, rb, 2·kw]``: a window layer
+      keeps a slot's last ``W`` rows at ``position mod W``, as ``W/rb``
+      blocks of ``rb`` rows that belong to the slot for good — the bytes do
+      not grow with a stream's context, and the paged decode kernel reads a
+      ring as a table of the slot's own blocks.
+    - ``h`` ``[state-space layers, slots, N, Di]`` float32 and ``conv``
+      ``[state-space layers, slots, K-1, Di]``: the recurrent state and the
+      convolution's tail, one row a slot.
+
+    The last two are addressed by SLOT, not by block list: a prefill is told
+    its slot and overwrites the slot's rows whole (that is the reset at a
+    join); a decode step's row ``i`` is slot ``i``; a slot without a stream
+    scribbles on its own rows only."""
+
+    kind = "hybrid"
+    RING_ROWS = 16
+
+    def __init__(self, kv_width: int, num_blocks: int, block_tokens: int,
+                 slots: int, window: int, window_layers: int,
+                 ssm_layers: int, d_inner: int, d_state: int, d_conv: int,
+                 dtype="bfloat16"):
+        if str(dtype) == "int8":
+            raise ValueError("the hybrid state has no int8 form: its rows "
+                             "carry no per-block scale")
+        super().__init__(1, num_blocks, block_tokens, dtype)
+        self.slots, self.window = int(slots), int(window)
+        self.ring_rows = min(self.window, self.RING_ROWS)
+        if self.window % self.ring_rows:
+            raise ValueError(f"a window of {window} is not whole blocks of "
+                             f"{self.ring_rows} rows")
+        self.ring_blocks = self.window // self.ring_rows
+        width = 2 * int(kv_width)
+        self.kv = jnp.zeros((1, self.num_blocks, self.block_tokens, width),
+                            dtype)
+        self.rings = jnp.zeros((int(window_layers),
+                                self.slots * self.ring_blocks,
+                                self.ring_rows, width), dtype)
+        self.h = jnp.zeros((int(ssm_layers), self.slots, int(d_state),
+                            int(d_inner)), jnp.float32)
+        self.conv = jnp.zeros((int(ssm_layers), self.slots, int(d_conv) - 1,
+                               int(d_inner)), dtype)
+        self.live_tokens = 0        # the model's observer keeps it
+
+    @staticmethod
+    def _bytes(a) -> int:
+        return int(a.size) * a.dtype.itemsize
+
+    @property
+    def kv_pool_bytes(self) -> int:
+        return self._bytes(self.kv)
+
+    @property
+    def window_state_bytes(self) -> int:
+        return self._bytes(self.rings)
+
+    @property
+    def recurrent_state_bytes(self) -> int:
+        return self._bytes(self.h) + self._bytes(self.conv)
+
+    @property
+    def nbytes(self) -> int:
+        return (self.kv_pool_bytes + self.window_state_bytes
+                + self.recurrent_state_bytes)
+
+    def state(self) -> list:
+        return [self.kv, self.rings, self.h, self.conv]
+
+    def update(self, new_state: list) -> None:
+        self.kv, self.rings, self.h, self.conv = new_state
+
+    def snapshot(self) -> dict:
+        snap = super().snapshot()
+        snap.update(dtype=self.dtype, slots=self.slots, window=self.window,
+                    kv_pool_bytes=self.kv_pool_bytes,
+                    window_state_bytes=self.window_state_bytes,
+                    recurrent_state_bytes=self.recurrent_state_bytes,
+                    kv_live_tokens=int(self.live_tokens))
+        return snap

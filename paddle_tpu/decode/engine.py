@@ -23,6 +23,23 @@ the decode plane: after the ladder + step are warm, a mixed join/leave
 load of varying prompt and output lengths is ZERO compiles — the
 acceptance pin.
 
+The model protocol.  A model gives ``make_cache`` (its cache, whose
+``state()`` list the engine threads through every dispatch and hands back
+to ``update()``), ``prefill`` and ``decode_step`` as ``(const, state,
+*feed) → ([token(s), logits, *extra], state')``, an ``observer`` for
+``extra``, and ``supports``.  State is of three kinds, and the engine
+knows none of them by name: blocks of a paged pool, held by block table
+to the stream's end (every model); rows that belong to a SLOT — a window
+layer's ring, bounded by the window however long the context, and a
+state-space layer's recurrent state (a model that sets ``slot_state``:
+:mod:`~paddle_tpu.decode.sambay`).  Such a model is given the slot count
+in ``make_cache`` and, in ``prefill``'s feed after the length, the slot
+the prompt fills: its prefill overwrites the slot's rows whole, which is
+the reset at a join; a decode step's row ``i`` is slot ``i``; a slot
+without a stream rides along and may scribble on its own rows only,
+which are dead until the next join.  Nothing else differs: same
+admission, same ladder, same step pipeline.
+
 Admission control (the batcher discipline): a bounded pending queue
 (``max_queue``) sheds with the serving plane's typed
 :class:`Overloaded`; an over-budget prompt/output (off the ladder, or
@@ -529,7 +546,14 @@ class DecodeEngine:
         # the model describes its cache and owns the state list its
         # three entry points thread (K/V pools, their scale pools, a
         # latent pool): the engine passes ``cache.state()`` through
-        self.cache = model.make_cache(num_blocks, bs, dtype=cache_dtype)
+        # A model that sets ``slot_state`` keeps state in rows addressed by
+        # slot beside its paged pool (recurrent state, window rings): its
+        # cache is sized by the slot count, and a prefill's feed says which
+        # slot the prompt fills (its program overwrites the slot's rows)
+        self._slot_state = bool(getattr(model, "slot_state", False))
+        self.cache = model.make_cache(
+            num_blocks, bs, dtype=cache_dtype,
+            **({"slots": self.max_slots} if self._slot_state else {}))
         ladder = (prefill_buckets if prefill_buckets is not None
                   else DEFAULT_PREFILL_BUCKETS)
         sizes = sorted({int(b) for b in
@@ -876,7 +900,8 @@ class DecodeEngine:
         n = L - start             # the positions this dispatch computes
         if start == 0:
             entry, program = self.model.prefill, "prefill"
-            where = [np.int32(L)]
+            where = [np.int32(L)] + ([np.int32(i)] if self._slot_state
+                                     else [])
         else:
             entry, program = self.model.prefill_suffix, "prefill_sfx"
             where = [np.int32(start), np.int32(L)]
@@ -1034,8 +1059,7 @@ class DecodeEngine:
         with _trace.span("decode::step.wait"):
             toks_np = np.asarray(toks)
             logits_np = np.asarray(logits) if self.capture_logits else None
-            self._observer.step(
-                extra, int(positions[live].sum()) + len(live))
+            self._observer.step(extra, positions[live] + 1)
         with _trace.span("decode::step.book"):
             self._book_step(live, toks_np, logits_np, t0,
                             greedy=not (temps > 0.0).any())

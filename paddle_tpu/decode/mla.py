@@ -243,8 +243,9 @@ class MLAObserver:
             sp.annotate(prefill_routed_assignments=assignments,
                         prefill_tokens_sq=prompt * prompt)
 
-    def step(self, extra, live_tokens: int) -> None:
+    def step(self, extra, contexts) -> None:
         with _trace.span("decode::step.observe") as sp:
+            live_tokens = int(np.sum(contexts))
             load = np.asarray(extra[0])
             assignments, touched = (int(load[:, 0].sum()),
                                     int(load[:, 1].sum()))

@@ -109,8 +109,9 @@ class NoObserver:
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         pass
 
-    def step(self, extra, live_tokens: int) -> None:
-        pass
+    def step(self, extra, contexts) -> None:
+        """``contexts``: the live streams' context lengths, this step's
+        token included (an int array, one entry a live stream)."""
 
 
 def _param_names(cfg: LMConfig) -> List[str]:

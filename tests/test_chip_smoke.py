@@ -112,6 +112,18 @@ def test_latent_lm_phase_tiny():
     assert rec["prefill"]["pool_copies"] == 0
 
 
+def test_hybrid_lm_phase_tiny():
+    out = chip_smoke.phase_hybrid_lm(
+        vocab=64, hidden=256, heads=4, kv_heads=2, ffn=128,
+        layers=8, window=8, max_seq_len=64, max_slots=2, block_tokens=16,
+        prefill_bucket=16, prompt_len=11, new_tokens=14, dtype="float32")
+    assert out["tokens_checked"] == 14 and out["tokens_exact"] == 14
+    assert out["logit_err_max"] < 1e-4
+    assert out["cache"]["kind"] == "hybrid"
+    assert out["cache"]["window_state_bytes"] == 2 * 2 * 8 * 256 * 4
+    assert not any(out["fallbacks"].values())
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

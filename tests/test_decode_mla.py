@@ -376,7 +376,7 @@ def test_the_observer_s_spans_carry_what_each_launch_added_to_the_counters(
     obs = m.observer("mla_o", m.make_cache(NB, BS, "float32"))
     before = stats.to_dict()
     obs.prefill([np.asarray([[30, 7, 9], [30, 8, 11]])], 10, 16)
-    obs.step([np.asarray([[6, 5, 2], [6, 4, 3]])], 57)
+    obs.step([np.asarray([[6, 5, 2], [6, 4, 3]])], np.asarray([30, 27]))
     after = stats.to_dict()
     assert [n for n, _ in filed] == ["decode::prefill.observe",
                                      "decode::step.observe"]

@@ -25,8 +25,9 @@ package is that state plane, built on the repo's own primitives:
   long new prompt never stalls in-flight streams.
 - **Pallas decode-attention kernel**
   (:func:`paddle_tpu.kernels.attention.decode_attention`): one query
-  token per slot against its gathered block list via scalar-prefetch
-  block tables, with a counted XLA-gather fallback and interpret-mode
+  token per slot against the live blocks of its block list, fetched by
+  the kernel itself in chunks from the scalar-prefetched tables and
+  lengths, with an XLA-gather path (``impl="xla"``) and interpret-mode
   CPU coverage (the ``kernels/sparse.py`` contract).
 - **A second model behind the same engine** (:mod:`mla`): DeepSeek-V2's
   block — latent (MLA) attention over ONE latent pool
@@ -60,7 +61,11 @@ package is that state plane, built on the repo's own primitives:
   only.  ``extra`` goes to the model's ``observer`` (``prefill(extra,
   prompt, bucket)``; ``step(extra, contexts)`` with the live streams'
   context lengths, which the engine holds on the host: a program returns
-  nothing for a count the host already has, and nothing for a check).
+  nothing for a count the host already has, and nothing for a check;
+  ``decodez()``: what the observer adds to ``/decodez`` —
+  :class:`~paddle_tpu.decode.model.TableWalkObserver`'s
+  ``step_live_blocks`` of ``step_table_blocks``, the share of the block
+  tables a :class:`TransformerLM`'s decode steps walked).
 - **On-device sampling** (:mod:`model`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
   top-k / temperature inside the decode dispatch; incremental beam

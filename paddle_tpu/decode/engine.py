@@ -27,7 +27,9 @@ The model protocol.  A model gives ``make_cache`` (its cache, whose
 ``state()`` list the engine threads through every dispatch and hands back
 to ``update()``), ``prefill`` and ``decode_step`` as ``(const, state,
 *feed) → ([token(s), logits, *extra], state')``, an ``observer`` for
-``extra``, and ``supports``.  State is of three kinds, and the engine
+``extra`` (made with the engine's name, the cache and the block tables'
+shape ``(slots, blocks a slot)``; what its ``decodez()`` returns joins
+the engine's), and ``supports``.  State is of three kinds, and the engine
 knows none of them by name: blocks of a paged pool, held by block table
 to the stream's end (every model); rows that belong to a SLOT — a window
 layer's ring, bounded by the window however long the context, and a
@@ -570,7 +572,8 @@ class DecodeEngine:
         self.stats = _EngineStats(name)
         # what the model's programs return beside token and logits (a
         # routed model's load figures) goes to the model's own observer
-        self._observer = model.observer(name, self.cache)
+        self._observer = model.observer(
+            name, self.cache, (self.max_slots, self.max_blocks_per_seq))
         # the two admission policies (module doc), latched here: an
         # engine with a prefix cache has ``self.prefix``
         self._overcommit_on = bool(overcommit)
@@ -1480,6 +1483,7 @@ class DecodeEngine:
             "shed": self.stats.shed.value,
             "fanout_immediate": self.stats.fanout_immediate.value,
         }
+        out.update(self._observer.decodez())
         alloc = self.cache.allocator
         parked = self._parked()
         ps = self._pstats

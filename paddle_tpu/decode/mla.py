@@ -261,6 +261,10 @@ class MLAObserver:
                         step_experts_touched=touched,
                         step_context_tokens=live_tokens)
 
+    def decodez(self) -> dict:
+        """Nothing of its own on ``/decodez``."""
+        return {}
+
 
 class _MoEOuts:
     """What a prefill or a step returns of its expert layers, collected a
@@ -321,7 +325,7 @@ class MLATransformerLM:
                                 cfg.qk_rope_head_dim, self._row, num_blocks,
                                 block_tokens, dtype=dtype)
 
-    def observer(self, name: str, cache) -> MLAObserver:
+    def observer(self, name: str, cache, table_shape) -> MLAObserver:
         return MLAObserver(name, cache)
 
     # -- parameters --------------------------------------------------------

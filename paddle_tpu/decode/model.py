@@ -46,6 +46,7 @@ from .cache import PagedKVCache
 from ..kernels.attention import decode_attention, paged_attention_xla
 from ..kernels.quant import (QMAX, SCALE_EPS, kv_dequantize, kv_head_amax,
                              kv_quantize)
+from ..observability import stats as _obs_stats
 
 _LN_EPS = 1e-5
 # static top-k ceiling compiled into the sampling epilogue: per-slot k
@@ -102,9 +103,27 @@ def _join_state(kc, vc, ks, vs) -> list:
     return [kc, vc] if ks is None else [kc, vc, ks, vs]
 
 
-class NoObserver:
+class TableWalkObserver:
     """The observer of a model whose programs return a token and logits
-    only (:meth:`TransformerLM.observer`)."""
+    only (:meth:`TransformerLM.observer`): what it counts, it counts from
+    the context lengths the host holds.  ``decode.<engine>.
+    step_live_blocks`` over ``step_table_blocks`` is the share of the
+    slots' block tables that the decode steps' attention kernel walked —
+    a live stream's ``ceil(context / block_tokens)`` blocks and an idle
+    slot's one (its table is all trash block, its position 0), of
+    ``slots x blocks a slot`` a step."""
+
+    def __init__(self, name: str, cache, table_shape):
+        sc = _obs_stats.scope(f"decode.{name}")
+        self.live_blocks = sc.counter(
+            "step_live_blocks", "table entries the decode steps' attention "
+            "fetched and computed (one layer): a live stream's blocks up "
+            "to its context, one of an idle slot")
+        self.table_blocks = sc.counter(
+            "step_table_blocks", "table entries the decode steps were "
+            "handed (one layer): slots x blocks a slot, a step")
+        self._block_tokens = int(cache.block_tokens)
+        self._slots, self._slot_blocks = (int(n) for n in table_shape)
 
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         pass
@@ -112,6 +131,15 @@ class NoObserver:
     def step(self, extra, contexts) -> None:
         """``contexts``: the live streams' context lengths, this step's
         token included (an int array, one entry a live stream)."""
+        contexts = np.asarray(contexts)
+        bs = self._block_tokens
+        self.live_blocks.inc(int(np.sum((contexts + bs - 1) // bs))
+                             + self._slots - int(contexts.size))
+        self.table_blocks.inc(self._slots * self._slot_blocks)
+
+    def decodez(self) -> dict:
+        return {"step_live_blocks": self.live_blocks.value,
+                "step_table_blocks": self.table_blocks.value}
 
 
 def _param_names(cfg: LMConfig) -> List[str]:
@@ -158,10 +186,13 @@ class TransformerLM:
         return PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim,
                             num_blocks, block_tokens, dtype=dtype)
 
-    def observer(self, name: str, cache) -> NoObserver:
-        """Reads what the programs return beside token and logits, for
-        the engine ``name``: here nothing."""
-        return NoObserver()
+    def observer(self, name: str, cache,
+                 table_shape) -> TableWalkObserver:
+        """What the engine ``name`` hands each launch's extra outputs
+        and context lengths to; ``table_shape``: the engine's (slots,
+        blocks a slot).  These programs return nothing beside token and
+        logits."""
+        return TableWalkObserver(name, cache, table_shape)
 
     # -- parameters --------------------------------------------------------
     def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -630,4 +661,5 @@ def load_lm(dirname: str):
 
 
 __all__ = ["LMConfig", "TransformerLM", "save_lm", "load_lm",
-           "paged_attention_xla", "TOPK_MAX", "MODEL_TYPES", "NoObserver"]
+           "paged_attention_xla", "TOPK_MAX", "MODEL_TYPES",
+           "TableWalkObserver"]

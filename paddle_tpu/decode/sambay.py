@@ -323,6 +323,10 @@ class SambaYObserver:
             sp.annotate(step_context_tokens=context,
                         step_window_tokens=window, step_streams=streams)
 
+    def decodez(self) -> dict:
+        """Nothing of its own on ``/decodez`` (its gauges ride ``cache``)."""
+        return {}
+
 
 class SambaYLM:
     """One decoder-hybrid-decoder LM: config + the jit-ready functions.  The
@@ -362,7 +366,7 @@ class SambaYLM:
             cfg.sliding_window, cfg.self_pairs, cfg.ssm_layers, cfg.d_inner,
             cfg.d_state, cfg.d_conv, dtype=dtype)
 
-    def observer(self, name: str, cache) -> SambaYObserver:
+    def observer(self, name: str, cache, table_shape) -> SambaYObserver:
         return SambaYObserver(name, cache, self.config)
 
     # -- parameters --------------------------------------------------------

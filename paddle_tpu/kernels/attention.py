@@ -936,77 +936,128 @@ def _head_sums(x, head_dim: int):
     return jnp.concatenate(chunks, axis=-1)
 
 
-def _decode_attn_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, *rest,
-                        block_tokens: int, head_dim: int, sm_scale: float,
+# blocks a chunk: 8 x 16 tokens x 768 f32 lanes = 384 KB each of K and V,
+# 1.5 MB double-buffered
+_DECODE_CHUNK_BLOCKS = 8
+
+
+def _decode_attn_kernel(bt_ref, cl_ref, ly_ref, q_ref, k_hbm, v_hbm, *rest,
+                        block_tokens: int, chunk: int,
+                        max_blocks: int, head_dim: int, sm_scale: float,
                         quantized: bool = False):
-    """Grid (S, max_blocks): slot-major, blocks sequential minor — the
-    online-softmax state for one slot lives in VMEM scratch across its
-    block iterations (the flash discipline applied to the block TABLE
-    axis).  The K/V index maps read the scalar-prefetched block table
-    and the static layer, so each grid step streams exactly ONE
-    [bs, H*D] block of the WHOLE pool — neither the layer's slice nor
-    the gathered block list is ever materialized.  Blocks past the
-    slot's context frontier are skipped (index maps clamp to the
-    frontier block, so the pipeline issues no copies for them either).
+    """Grid (S,): one grid step a slot.  The K and V pools stay in HBM,
+    whole (``memory_space=pl.ANY``); a slot's LIVE blocks —
+    ``ceil(context_len / bs)`` of its table, one for an idle slot — are
+    fetched by explicit async copies in chunks of ``chunk`` blocks into
+    a double buffer, block ``b`` of a chunk into ``buf[half, b]``, the
+    layer (``ly_ref``, prefetched with the tables) in the copy's source
+    index.  A table position past the frontier is neither copied nor
+    computed, and none past the table is read.
+
+    The next fetch is always in flight: before the kernel waits for a
+    chunk it starts the slot's next one into the other half, and on a
+    slot's LAST chunk the next slot's first (scratch and semaphores
+    persist over the sequential grid).  ``half_scr`` carries which half
+    that was from one grid step to the next, so every start is waited
+    for exactly once, by the slot that computes it.
 
     ``quantized``: the cache blocks are int8 codes and two extra
-    [1, H*D] scale refs follow the v ref (the slot's per-block-per-head
-    abs-max rows, a head's scale repeated over its D lanes, indexed by
-    slot and table position) — the block is dequantized IN VMEM right
-    after the copy lands (``code * s/127``), so HBM traffic per block
+    [1, MB, H*D] scale refs follow the v ref (the slot's
+    per-block-per-head abs-max rows, a head's scale repeated over its D
+    lanes, one row a table position) — a block is dequantized IN VMEM
+    after its copy landed (``code * s/127``), so HBM traffic per block
     is a quarter of f32's while scores still run in f32.
 
     One query row per slot leaves the MXU nothing to do, so everything
-    is VPU work on the block in its stored lane-dense [bs, H*D] layout:
+    is VPU work on a block in its stored lane-dense [bs, H*D] layout:
     q, the running max / sum and the PV accumulator are [1, H*D] rows
-    with a head's value repeated over its D lanes, scores are a
-    per-head lane sum of ``k * q`` (:func:`_head_sums`), and max / sum / PV
-    reduce over the block's tokens (the sublane axis).  (Mosaic refuses
-    the batched ``dot_general`` over the MIDDLE axis the first version
-    used: "failed to parse TPU_DotDimensionNumbersAttr parameter
-    'lhs_non_contracting_dims'", PERF.md Bring-up.)  Scores run in f32
-    natural units (a decode step is dispatch-bound — the flash kernel's
-    exp2/ones-lane folds buy nothing here)."""
+    with a head's value repeated over its D lanes (the loops' carry),
+    scores are a per-head lane sum of ``k * q`` (:func:`_head_sums`),
+    and max / sum / PV reduce over the block's tokens (the sublane
+    axis).  (Mosaic refuses the batched ``dot_general`` over the MIDDLE
+    axis the first version used: "failed to parse
+    TPU_DotDimensionNumbersAttr parameter 'lhs_non_contracting_dims'",
+    PERF.md Bring-up.)  Scores run in f32 natural units."""
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+        ks_ref, vs_ref, o_ref, kbuf, vbuf, sem, half_scr = rest
     else:
-        o_ref, m_scr, l_scr, acc_scr = rest
+        o_ref, kbuf, vbuf, sem, half_scr = rest
     s = pl.program_id(0)
-    j = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    layer = ly_ref[0]
+    bs = block_tokens
+
+    def live_blocks(slot):
+        return jnp.clip((cl_ref[slot] + bs - 1) // bs, 1, max_blocks)
+
+    def fetch(slot, c, half, wait: bool):
+        """Start (or wait for) the copies of chunk ``c`` of ``slot``: its
+        live blocks, each into its own place of ``half``."""
+        first = c * chunk
+
+        def one(b, carry):
+            blk = bt_ref[slot, first + b]
+            for i, (pool, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf))):
+                cp = pltpu.make_async_copy(
+                    pool.at[layer, blk], buf.at[half, b], sem.at[i, half, b])
+                cp.wait() if wait else cp.start()
+            return carry
+
+        jax.lax.fori_loop(
+            0, jnp.minimum(chunk, live_blocks(slot) - first), one, 0)
+
+    @pl.when(s == 0)
+    def _first():
+        half_scr[0] = 0
+        fetch(0, 0, 0, wait=False)
+
+    first_half = half_scr[0]
     cl = cl_ref[s]
-    last = jnp.maximum((cl - 1) // block_tokens, 0)
+    n_live = live_blocks(s)
+    n_chunks = (n_live + chunk - 1) // chunk
+    q = q_ref[0].astype(jnp.float32) * sm_scale            # [1, H*D]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def chunk_step(c, carry):
+        half = (first_half + c) % 2
+        # what is computed next: this slot's next chunk, or after its
+        # last the next slot's first (none after the last slot's last)
+        last = c + 1 == n_chunks
 
-    @pl.when(j <= last)
-    def _compute():
-        q = q_ref[0].astype(jnp.float32) * sm_scale        # [1, H*D]
-        k_blk = k_ref[0, 0].astype(jnp.float32)            # [bs, H*D]
-        v_blk = v_ref[0, 0].astype(jnp.float32)
-        if quantized:
-            k_blk = k_blk * (ks_ref[0, 0] * _INV_QMAX)     # [1, H*D]
-            v_blk = v_blk * (vs_ref[0, 0] * _INV_QMAX)
-        scores = _head_sums(k_blk * q, head_dim)           # [bs, H*D]
-        pos = j * block_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, scores.shape, 0)
-        scores = jnp.where(pos < cl, scores, NEG_INF)
-        m = m_scr[:]                                       # [1, H*D]
-        m_new = jnp.maximum(m, jnp.max(scores, axis=0, keepdims=True))
-        p = jnp.exp(scores - m_new)                        # [bs, H*D]
-        alpha = jnp.exp(m - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.sum(p * v_blk, axis=0,
-                                                  keepdims=True)
+        @pl.when(jnp.logical_or(jnp.logical_not(last), s + 1 < n_slots))
+        def _ahead():
+            fetch(jnp.where(last, jnp.minimum(s + 1, n_slots - 1), s),
+                  jnp.where(last, 0, c + 1), 1 - half, wait=False)
 
-    @pl.when(j == last)
-    def _finish():
-        o_ref[0] = (acc_scr[:]
-                    / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+        fetch(s, c, half, wait=True)
+
+        def block_step(b, carry):
+            m, l, acc = carry
+            j = c * chunk + b
+            k_blk = kbuf[half, b].astype(jnp.float32)      # [bs, H*D]
+            v_blk = vbuf[half, b].astype(jnp.float32)
+            if quantized:
+                k_blk = k_blk * (ks_ref[0, pl.ds(j, 1), :] * _INV_QMAX)
+                v_blk = v_blk * (vs_ref[0, pl.ds(j, 1), :] * _INV_QMAX)
+            scores = _head_sums(k_blk * q, head_dim)       # [bs, H*D]
+            pos = j * bs + jax.lax.broadcasted_iota(
+                jnp.int32, scores.shape, 0)
+            scores = jnp.where(pos < cl, scores, NEG_INF)
+            m_new = jnp.maximum(m, jnp.max(scores, axis=0, keepdims=True))
+            p = jnp.exp(scores - m_new)                    # [bs, H*D]
+            alpha = jnp.exp(m - m_new)
+            return (m_new, l * alpha + jnp.sum(p, axis=0, keepdims=True),
+                    acc * alpha + jnp.sum(p * v_blk, axis=0, keepdims=True))
+
+        return jax.lax.fori_loop(
+            0, jnp.minimum(chunk, n_live - c * chunk), block_step, carry)
+
+    row = (1, q.shape[-1])
+    _, l, acc = jax.lax.fori_loop(
+        0, n_chunks, chunk_step,
+        (jnp.full(row, NEG_INF, jnp.float32), jnp.zeros(row, jnp.float32),
+         jnp.zeros(row, jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    half_scr[0] = (first_half + n_chunks) % 2
 
 
 def paged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
@@ -1048,25 +1099,23 @@ def paged_attention_xla(q, k_cache, v_cache, block_tables, context_lens,
                       v.astype(jnp.float32)).astype(q.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("sm_scale", "interpret"))
 def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
                        layer, sm_scale, interpret, k_scale=None,
                        v_scale=None):
+    """``layer`` is an int32 scalar here, prefetched with the tables: the
+    layers of a model then share ONE trace and ONE lowering of the kernel
+    (a static layer made each its own — twelve a decode step, most of a
+    warm start's build of that program)."""
     S, H, D = q.shape
     bs, HD = k_cache.shape[2:]
     MB = block_tables.shape[1]
+    chunk = min(_DECODE_CHUNK_BLOCKS, MB)
     bt = block_tables.astype(jnp.int32)
     cl = context_lens.astype(jnp.int32)
     quantized = k_scale is not None
 
-    def live(s, j, cl):
-        # clamp skipped past-frontier blocks to the frontier block: the
-        # pipeline re-references the previous block, no copy issued
-        return jnp.minimum(j, jnp.maximum((cl[s] - 1) // bs, 0))
-
-    def kv_map(s, j, bt, cl):
-        return (layer, bt[s, live(s, j, cl)], 0, 0)
-
-    def row_map(s, j, bt, cl):
+    def row_map(s, bt, cl, ly):
         return (s, 0, 0)
 
     # q and the output as [S, 1, H*D] rows: a (1, 1, H*D) block's minor
@@ -1074,31 +1123,32 @@ def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
     # TPU (8, 128) rule)
     in_specs = [
         pl.BlockSpec((1, 1, HD), row_map),
-        pl.BlockSpec((1, 1, bs, HD), kv_map),
-        pl.BlockSpec((1, 1, bs, HD), kv_map),
+        pl.BlockSpec(memory_space=pl.ANY),
+        pl.BlockSpec(memory_space=pl.ANY),
     ]
-    operands = [bt, cl, q.reshape(S, 1, HD), k_cache, v_cache]
+    operands = [bt, cl, layer.reshape(1), q.reshape(S, 1, HD), k_cache,
+                v_cache]
     if quantized:
-        # the scale pools' minor dim is H, too narrow for a block of its
+        # the scale pools' minor dim is H, too narrow for a copy of its
         # own, so the slots' scale rows are gathered here ([S, MB, H],
-        # small) and spread over each head's D lanes; the kernel reads
-        # row (slot, table position) beside the code block it scales
-        def scale_map(s, j, bt, cl):
-            return (s, live(s, j, cl), 0, 0)
-
-        in_specs += [pl.BlockSpec((1, 1, 1, HD), scale_map)] * 2
-        operands += [
-            jnp.repeat(sc[layer, bt], D, axis=-1).reshape(S, MB, 1, HD)
-            for sc in (k_scale, v_scale)]
+        # small) and spread over each head's D lanes; a slot's rows come
+        # in as one pipelined block, row = table position
+        in_specs += [pl.BlockSpec((1, MB, HD), row_map)] * 2
+        operands += [jnp.repeat(sc[layer, bt], D, axis=-1)
+                     for sc in (k_scale, v_scale)]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, MB),
+        num_scalar_prefetch=3,
+        grid=(S,),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, 1, HD), row_map),
-        scratch_shapes=[pltpu.VMEM((1, HD), jnp.float32)] * 3,
+        scratch_shapes=[pltpu.VMEM((2, chunk, bs, HD), k_cache.dtype),
+                        pltpu.VMEM((2, chunk, bs, HD), v_cache.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2, chunk)),
+                        pltpu.SMEM((1,), jnp.int32)],
     )
-    kernel = functools.partial(_decode_attn_kernel, block_tokens=bs,
+    kernel = functools.partial(_decode_attn_kernel,
+                               block_tokens=bs, chunk=chunk, max_blocks=MB,
                                head_dim=D, sm_scale=sm_scale,
                                quantized=quantized)
     out = pl.pallas_call(
@@ -1114,17 +1164,21 @@ def _paged_attn_pallas(q, k_cache, v_cache, block_tables, context_lens,
 def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
                      layer, sm_scale=None, interpret=None, impl=None,
                      k_scale=None, v_scale=None):
-    """Paged decode attention: one query token per request against its
-    gathered block list (scalar-prefetch block tables — module doc,
-    ``_decode_attn_kernel``).
+    """Paged decode attention: one query token per request against the
+    live blocks of its block list (scalar-prefetched tables and lengths;
+    the kernel fetches a slot's blocks itself — ``_decode_attn_kernel``).
 
     q: [S, H, D] (S decode slots); k_cache/v_cache: the WHOLE pool
     [L, N_blocks, block_tokens, H*D], every layer of it, handed over as
-    it lies in HBM; ``layer``: static int, which layer's blocks to read
-    (it goes into the kernel's index map — slicing ``k_cache[layer]``
-    first would copy the layer); block_tables: [S, MB] int32
-    cache-block ids per slot; context_lens: [S] int32 valid tokens per
-    slot (positions ≥ context_len masked).  Returns [S, H, D].
+    it lies in HBM; ``layer``: int, which layer's blocks to read (it
+    goes, as a prefetched scalar, into the source index of the kernel's
+    copies — slicing ``k_cache[layer]`` first would copy the layer, and
+    a layer baked into the kernel would trace and lower one kernel a
+    layer); block_tables:
+    [S, MB] int32 cache-block ids per slot; context_lens: [S] int32
+    valid tokens per slot, at least 1 (positions ≥ context_len are
+    masked, table entries past ``ceil(context_len / block_tokens)`` never
+    read).  Returns [S, H, D].
 
     ``k_scale``/``v_scale``: [L, N_blocks, H] f32 per-block-per-head
     abs-max pools when the cache stores int8 codes
@@ -1150,5 +1204,5 @@ def decode_attention(q, k_cache, v_cache, block_tables, context_lens,
     if interpret is None:
         interpret = pallas_interpret()
     return _paged_attn_pallas(q, k_cache, v_cache, block_tables,
-                              context_lens, layer, sm_scale, interpret,
-                              k_scale=k_scale, v_scale=v_scale)
+                              context_lens, jnp.int32(layer), sm_scale,
+                              interpret, k_scale=k_scale, v_scale=v_scale)

@@ -373,7 +373,7 @@ def test_the_observer_s_spans_carry_what_each_launch_added_to_the_counters(
 
     monkeypatch.setattr(mla._trace, "span", lambda name, **a: Span(name))
     m, _, _ = _model()
-    obs = m.observer("mla_o", m.make_cache(NB, BS, "float32"))
+    obs = m.observer("mla_o", m.make_cache(NB, BS, "float32"), (4, 8))
     before = stats.to_dict()
     obs.prefill([np.asarray([[30, 7, 9], [30, 8, 11]])], 10, 16)
     obs.step([np.asarray([[6, 5, 2], [6, 4, 3]])], np.asarray([30, 27]))

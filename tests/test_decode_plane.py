@@ -22,6 +22,8 @@ from paddle_tpu.decode import (BlockAllocator, DecodeClient, DecodeEngine,
                                save_lm)
 from paddle_tpu.kernels import attention as AK
 
+from paged_walks import WALKS, dense_reference, walk_case
+
 TINY = LMConfig(vocab=48, d_model=32, n_head=2, d_ffn=48, n_layer=2,
                 max_seq_len=32)
 
@@ -113,6 +115,40 @@ def test_decode_attention_pallas_matches_xla_and_dense(H, D, layer):
     other = AK.decode_attention(q, kc, vc, bt, cl, (layer + 1) % 3,
                                 impl="pallas")
     assert float(jnp.max(jnp.abs(other - op))) > 1e-3
+
+
+# blocks a slot: not a multiple of the chunk (a ragged last chunk), a
+# multiple of it, fewer than one chunk (the chunk is clipped to the table)
+@pytest.mark.parametrize("MB", [20, 16, 5])
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_decode_attention_walks_live_blocks_in_chunks(walk, MB):
+    """Every layer of a 3-layer pool, against the gather path and the
+    dense reference of EVERY slot."""
+    bs = 4
+    chunk = min(AK._DECODE_CHUNK_BLOCKS, MB)
+    contexts = WALKS[walk](chunk * bs, MB * bs)
+    q, kc, vc, bt, cl = walk_case(np.random.RandomState(3), contexts, MB)
+    for layer in range(3):
+        op = AK.decode_attention(q, kc, vc, bt, cl, layer, impl="pallas")
+        ox = AK.paged_attention_xla(q, kc, vc, bt, cl, layer)
+        assert float(jnp.max(jnp.abs(ox - op))) < 1e-5
+        ref = dense_reference(q, kc, vc, bt, cl, layer)
+        assert np.abs(ref - np.asarray(op)).max() < 1e-5
+
+
+@pytest.mark.parametrize("MB", [20, 5])
+def test_decode_attention_reads_no_block_past_a_context(MB):
+    """Table entries past a slot's context name a block of NaN: a block
+    that is copied or computed though dead shows in the output (the
+    gather path reads them all: ``0 x NaN`` — it is no reference here)."""
+    bs = 4
+    chunk = min(AK._DECODE_CHUNK_BLOCKS, MB)
+    contexts = WALKS["all_of_these_in_adjacent_slots"](chunk * bs, MB * bs)
+    q, kc, vc, bt, cl = walk_case(np.random.RandomState(4), contexts, MB,
+                                   poison=True)
+    op = np.asarray(AK.decode_attention(q, kc, vc, bt, cl, 1, impl="pallas"))
+    assert np.isfinite(op).all()
+    assert np.abs(dense_reference(q, kc, vc, bt, cl, 1) - op).max() < 1e-5
 
 
 def test_decode_attention_kernel_fault_is_an_error(monkeypatch):
@@ -514,6 +550,46 @@ def test_greedy_steps_are_counted_and_a_sampled_neighbour_changes_no_token():
             z2["greedy_steps"]
     finally:
         eng.close()
+
+
+def test_the_table_walk_is_counted_from_the_contexts_the_host_holds():
+    """``step_live_blocks`` / ``step_table_blocks``: of the slots x blocks
+    a slot that a step's attention is handed, a live stream's blocks up to
+    its context and one of every idle slot are walked.  One stream alone
+    on three slots of eight 4-token blocks: a prompt of 5 and 7 tokens
+    more are six steps at contexts 6..11."""
+    lm, params, eng = _engine("walk")
+    try:
+        assert eng.decodez()["step_table_blocks"] == 0
+        eng.generate(np.arange(5, dtype=np.int32), max_new_tokens=7)
+        assert eng.drain(timeout=30)
+        z = eng.decodez()
+        assert z["steps"] == 6 and eng.max_blocks_per_seq == 8
+        assert z["step_table_blocks"] == 6 * 3 * 8
+        # contexts 6, 7, 8 hold two blocks, 9, 10, 11 three; two idle slots
+        assert z["step_live_blocks"] == 3 * 2 + 3 * 3 + 6 * 2
+        snap = obs.stats.default_registry().snapshot()
+        assert snap["decode.walk.step_live_blocks"] == z["step_live_blocks"]
+        assert snap["decode.walk.step_table_blocks"] == \
+            z["step_table_blocks"]
+    finally:
+        eng.close()
+
+
+def test_the_table_walk_observer_counts_a_step_by_hand():
+    """The observer alone, two live streams on four slots: a prefill adds
+    nothing (these programs return token and logits only), a step the
+    streams' blocks and the idle slots' one each."""
+    lm = TransformerLM(TINY)
+    watch = lm.observer("walk_o", lm.make_cache(9, 4), (4, 8))
+    watch.prefill([], 5, 8)
+    assert watch.decodez() == {"step_live_blocks": 0, "step_table_blocks": 0}
+    watch.step([], np.asarray([1, 4, 5, 32]))     # a full house
+    assert watch.decodez() == {"step_live_blocks": 1 + 1 + 2 + 8,
+                               "step_table_blocks": 32}
+    watch.step([], np.asarray([17, 3]))           # two idle slots
+    assert watch.decodez() == {"step_live_blocks": 12 + 5 + 1 + 2,
+                               "step_table_blocks": 64}
 
 
 def test_cancel_frees_slot_and_blocks_mid_stream():

@@ -137,7 +137,8 @@ def test_pool_is_neither_copied_nor_relaid(one_chip, mosaic, name, kv_dtype):
 
 def test_kernel_refuses_nothing_at_full_context_width(one_chip, mosaic):
     """The kernel alone, on a 12-layer pool, last layer: Mosaic accepts
-    the (1, 1, bs, H*Dh) block and the per-head lane sums."""
+    the copies of [bs, H*Dh] blocks out of the whole pool into the chunk
+    buffers and the per-head lane sums."""
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
     pool = sds((12, NB, BS, CFG.d_model), jnp.float32)
@@ -147,6 +148,50 @@ def test_kernel_refuses_nothing_at_full_context_width(one_chip, mosaic):
             sds((S, MB), jnp.int32), sds((S,), jnp.int32)).compile()
     assert "tpu_custom_call" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 20
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` equation under ``jaxpr``."""
+    grids = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            grids.append(tuple(eqn.params["grid_mapping"].grid))
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+@pytest.mark.parametrize("kv_dtype", ["float32", "int8"])
+def test_the_step_s_attention_grid_steps_by_slot_not_by_table_entry(
+        one_chip, mosaic, kv_dtype):
+    """A layer's kernel takes at most one grid step a chunk of a slot's
+    table — ``S x ceil(MB / chunk)``, 256 where the kernel that stepped by
+    table entry took ``S x MB`` = 2,048 — and what it skips inside a step
+    it skips from ``context_lens`` (the parity tests)."""
+    plist, state, feeds = _shapes(one_chip, kv_dtype)
+    fn = _program(TransformerLM(CFG), "step")
+    grids = _pallas_grids(jax.make_jaxpr(fn)(feeds["step"], state,
+                                             plist).jaxpr)
+    assert len(grids) == CFG.n_layer
+    most = S * -(-MB // AK._DECODE_CHUNK_BLOCKS)
+    assert all(int(np.prod(g)) <= most for g in grids), (grids, most)
+
+
+def test_the_step_s_layers_share_one_trace_of_the_kernel(one_chip, mosaic):
+    """The layer reaches the kernel as a prefetched scalar, so the step's
+    ``n_layer`` calls are ONE traced function (and one lowering to Mosaic:
+    jit lowers a shared jaxpr once) — a layer baked into the kernel made
+    each its own, and a warm start's build of the step program then took
+    3.7-4.0 s on the chip's host where the kernel this one replaced took
+    2.1-2.4 and this one 1.6-1.7 (PERF.md §6, PR 35)."""
+    plist, state, feeds = _shapes(one_chip, "float32")
+    fn = _program(TransformerLM(CFG), "step")
+    calls = [eqn.params["jaxpr"] for eqn in jax.make_jaxpr(fn)(
+        feeds["step"], state, plist).jaxpr.eqns
+        if eqn.primitive.name in ("pjit", "jit")
+        and eqn.params["name"] == "_paged_attn_pallas"]
+    assert len(calls) == CFG.n_layer
+    assert len({id(c) for c in calls}) == 1
 
 
 @pytest.mark.parametrize("rows,vocab", [(64, 40478), (1, 40478),

@@ -225,7 +225,7 @@ def test_the_observer_s_spans_carry_what_each_launch_added_to_the_counters(
     monkeypatch.setattr(sambay._trace, "span", lambda name, **a: Span(name))
     m, _, _ = model
     cache = m.make_cache(NB, BS, "float32", slots=SLOTS)
-    obs = m.observer("sy_o", cache)
+    obs = m.observer("sy_o", cache, (SLOTS, 8))
     before = stats.to_dict()
     obs.prefill([], 13, 16)
     obs.step([], np.asarray([51, 6]))

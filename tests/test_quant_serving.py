@@ -22,6 +22,8 @@ from paddle_tpu.inference import AnalysisConfig, create_predictor
 from paddle_tpu.kernels import attention as A
 from paddle_tpu.kernels import quant as Q
 
+from paged_walks import WALKS, dense_reference, walk_case
+
 L = fluid.layers
 rng = np.random.RandomState(11)
 
@@ -316,6 +318,34 @@ def test_quantized_paged_attention_pallas_matches_xla(layer):
     other = A.decode_attention(q, kq, vq, bt, cl, (layer + 1) % L,
                                impl="pallas", k_scale=ks, v_scale=vs)
     assert np.max(np.abs(np.asarray(other) - np.asarray(p_q))) > 1e-3
+
+
+@pytest.mark.parametrize("MB", [20, 5])
+@pytest.mark.parametrize("walk", list(WALKS))
+def test_quantized_paged_attention_walks_live_blocks_in_chunks(walk, MB):
+    """The int8 pool through the kernel's chunked walk (a slot's scale rows
+    come in whole, one a table position): contexts that end on, one past
+    and far from a chunk's edge, idle slots and the full table, every
+    layer of a 3-layer pool, against the gather path."""
+    bs, H, D, L = 4, 4, 64, 3
+    chunk = min(A._DECODE_CHUNK_BLOCKS, MB)
+    contexts = WALKS[walk](chunk * bs, MB * bs)
+    q, kf, vf, bt, cl = walk_case(np.random.RandomState(11), contexts, MB,
+                                  bs=bs, H=H, D=D, L=L)
+    heads = kf.shape[:3] + (H, D)
+    ks = jnp.max(jnp.abs(kf.reshape(heads)), axis=(2, 4))    # [L, NB, H]
+    vs = jnp.max(jnp.abs(vf.reshape(heads)), axis=(2, 4))
+    kq = Q.kv_quantize(kf.reshape(heads), ks[:, :, None, :]).reshape(kf.shape)
+    vq = Q.kv_quantize(vf.reshape(heads), vs[:, :, None, :]).reshape(vf.shape)
+    for layer in range(L):
+        x_q = A.decode_attention(q, kq, vq, bt, cl, layer, impl="xla",
+                                 k_scale=ks, v_scale=vs)
+        p_q = A.decode_attention(q, kq, vq, bt, cl, layer, impl="pallas",
+                                 k_scale=ks, v_scale=vs)
+        np.testing.assert_allclose(np.asarray(p_q), np.asarray(x_q),
+                                   rtol=1e-5, atol=1e-5)
+        ref = dense_reference(q, kf, vf, bt, cl, layer)
+        assert np.max(np.abs(np.asarray(p_q) - ref)) < 0.1
 
 
 def test_quantized_cache_layout_and_bytes():

@@ -1,0 +1,585 @@
+"""Driver of the ``falconh1_serve`` kind: a ``decode.falcon_h1.FalconH1LM``
+(every layer a Mamba-2 mixer and a grouped-query attention side by side on
+one normed input; a paged K/V pool and a recurrent row a slot IN EVERY LAYER;
+the published multipliers; an untied head) at the configuration's published
+widths behind ``DecodeServer``/``DecodeClient`` on the native transport, all
+in this one process, under the cell's traffic mix.
+
+The serve loop is ``drivers/serve.py``'s — same load generator, accounting,
+window, drain, program-state checks and ``bench time:`` line — for another
+model and another reference check.  What the accepted drivers expose is
+imported (``mla_serve.warm_up``, ``sambay_serve.trace_later`` and
+``sambay_serve.Sample``); ``replay``, ``judge``, ``pick`` and ``run`` read
+their module's own constants and model, so they are a copy (as
+``drivers/sambay_serve.py``'s are).  The engine keeps the model name ``lm``,
+so its programs are ``jit_fn_decode_lm_step`` and
+``jit_fn_decode_lm_prefill_<rung>`` and the readers of the serve metrics find
+them.
+
+``correct`` is decided after the window on what the timed path produced:
+:func:`replay` sends a sample of the window's requests, teacher-forced with
+the tokens the window produced, through the engine's own compiled programs
+(its executable cache is hit by key, nothing compiles) — the prefill and
+:data:`REPLAY_TOKENS` - 1 decode steps — and reads back the judged
+positions' logits and, after the last step, the slots' recurrent rows of
+every layer; :func:`judge` holds them against the plain reference's full
+forward (``benchmark/reference/falcon_h1.py``) under :data:`LIMITS`.
+``benchmark/falconh1_controls.py`` puts two lower-precision controls and
+five planted faults through the same functions; every one must come out not
+correct.
+
+The weights are drawn HERE (:func:`draw`), by the rules the configuration
+file's ``assumed`` states; the program gives names and shapes only, so a
+fault in the program's own initialiser cannot reach both sides of the
+comparison.  The published multipliers scale the branches by 0.0375, 0.088
+and 0.011: matrices drawn at fan-in^-0.5 would leave the attention and the
+scan invisible in the logits, so a matrix is drawn at fan-in^-0.5 OVER the
+multipliers that scale what it makes, times a gain a branch
+(:data:`GAINS`), and :data:`REFERENCE_RANGES` holds the plain reference's
+own readings — every branch's share of the residual stream, the attention
+scores' spread, the step sizes, the states — to what those rules are meant to
+give, whatever the program does.  The multipliers themselves stay as
+published on both sides.
+"""
+from __future__ import annotations
+
+import json
+import math
+import os
+import threading
+from typing import Callable, List, Optional
+
+import numpy as np
+
+from benchmark import harness, loadgen, trace_reduce
+from benchmark.drivers.mla_serve import warm_up  # noqa: F401  the same ladder
+from benchmark.drivers.sambay_serve import Sample, trace_later
+from benchmark.reference import falcon_h1 as reference
+
+MODEL = "lm"
+WEIGHT_SEED = 36            # fixed: traffic, not weights, comes from --seed
+SAMPLE = 6                  # requests compared with the reference
+REPLAY_TOKENS = 257         # the prefill's token and 256 decode steps
+JUDGE_FROM, JUDGE_EVERY = 192, 8    # decode steps judged: 192, 200, ..., 256
+# What the reference comparison allows, each a statistic that does not grow
+# with the sample.  Each stands between the sound program's largest reading
+# over the builder's runs and the smallest reading of a control that must
+# fail it (benchmark/falconh1_controls.py, three seeds).  PERF.md section 6
+# has every reading.
+LIMITS = {
+    # ||program - reference|| / ||reference|| of one position's logits (bf16
+    # through 6 layers against float32): the median and the 90th percentile
+    # over the judged decode positions.  Sound 0.0105-0.0108 / 0.0109-0.0113;
+    # 8-bit pool 0.0159-0.0174 / 0.0188-0.0198; here a bf16 recurrent state
+    # moves them too (0.0145-0.0154 / 0.0161-0.0181): every layer's mixer
+    # reads its state into the residual stream
+    "logit_err_decode_p50": 0.013,
+    "logit_err_decode_p90": 0.0145,
+    # the same at the prefills' last positions, the largest.  Sound
+    # 0.0107-0.0117; no state precision moves it (a prefill reads no state).
+    # Planted: rotary positions off by one 0.0933-0.0981, the attention
+    # branch dropped 0.569-0.574, mu left out 0.709-0.725, the state-space
+    # branch dropped 1.04-1.07
+    "logit_err_prefill_max": 0.03,
+    # ||program - reference|| / ||reference|| of one layer's recurrent rows
+    # of one stream after the last replayed step: the median over (stream,
+    # layer).  Sound 0.0077-0.0085 (the scan's INPUTS come through bf16
+    # activations); bf16 state 0.0214-0.0225; the 8-bit pool 0.0118-0.0136
+    # (its own limits are the two above).  Near the geometric middle of the sound
+    # and the bf16 readings: 1.5-1.6 times of room on either side
+    "state_err_p50": 0.013,
+    # a token's gap to the reference's argmax, of the reference's logit
+    # scale: the 99th percentile of the judged tokens.  Sound 0-0.0034
+    # (a near tie of the reference's two best that bf16 turns: it swings
+    # from seed to seed); no precision below moves it (teacher-forced with
+    # the window's own tokens).  Planted: ONE judged token of sixty another
+    # stream's 0.119-0.362; the limit leaves 9 times of room above the sound
+    # maximum and 4 below the smallest planted reading
+    "token_gap_p99": 0.03,
+}
+# What the plain reference's own layers must read for the numbers above to
+# guard anything (the configuration's ``assumed``), whatever the program
+# does: [low, high] of the smallest and the largest reading over (sample,
+# layer).
+REFERENCE_RANGES = {
+    # a branch's output over the residual stream it is added to, root mean
+    # square over the real positions: each mechanism is visible in the logits
+    "ref_ssm_rms": (0.1, 1.0),
+    "ref_attn_rms": (0.1, 1.0),
+    "ref_mlp_rms": (0.1, 1.0),
+    # the visible attention scores' standard deviation: of order 1
+    "ref_attn_logit_std": (0.5, 3.0),
+    # step sizes over the real positions: the share inside [1e-3, 1e-1]
+    "ref_step_size_in_range_share": (0.9, 1.0),
+    "ref_step_size_min": (1e-4, 1e-2),
+    "ref_step_size_max": (1e-2, 1.0),
+    # the recurrent rows' root mean square after the last replayed step: a
+    # state that neither dies nor saturates
+    "ref_state_rms": (1e-3, 10.0),
+}
+# kernels whose XLA fallback must never have been taken
+FALLBACK_COUNTERS = ("ssm.ssd_fallbacks", "attn.gqa_decode_fallbacks",
+                     "attn.gqa_prefill_fallbacks")
+# counters of decode.<model>.* whose window deltas the per-layer readers use
+WINDOW_COUNTERS = (
+    "steps", "prefills", "prefill_real_tokens", "prefill_pad_tokens",
+    "prefill_scan_chunks", "prefill_tokens_sq", "step_context_tokens",
+    "step_streams", "step_state_bytes")
+MODEL_KEYS = (
+    "vocab_size", "hidden_size", "num_hidden_layers", "num_attention_heads",
+    "num_key_value_heads", "head_dim", "intermediate_size", "rms_norm_eps",
+    "rope_theta", "mamba_d_ssm", "mamba_n_heads", "mamba_d_head",
+    "mamba_n_groups", "mamba_d_state", "mamba_d_conv", "mamba_chunk_size",
+    "embedding_multiplier", "lm_head_multiplier", "attention_in_multiplier",
+    "attention_out_multiplier", "key_multiplier", "ssm_in_multiplier",
+    "ssm_out_multiplier", "ssm_multipliers", "mlp_multipliers")
+
+
+def validate(cell, seconds: float) -> None:
+    loadgen.validate_serve_mix(cell.mix, cell.config, seconds)
+    try:
+        from paddle_tpu.decode import falcon_h1  # noqa: F401
+    except ImportError as e:
+        # a checkout from before this model: refuse before a device is
+        # touched, so that the run ends at once
+        raise harness.ConfigurationError(
+            f"the program in this checkout cannot run a configuration of "
+            f"kind {cell.kind!r}: {e}") from None
+
+
+def model_config(cfg: dict):
+    from paddle_tpu.decode.falcon_h1 import FalconH1Config
+    return FalconH1Config.from_dict(
+        {**{k: cfg[k] for k in MODEL_KEYS},
+         "max_seq_len": int(cfg["max_seq_len"]), "dtype": str(cfg["dtype"])})
+
+
+def reference_config(cfg: dict) -> dict:
+    return {k: cfg[k] for k in MODEL_KEYS}
+
+
+NORMS = ("ln1", "ln2", "final_norm", "ssm_norm")       # 1 + 0.1 N(0, 1)
+STEP_SIZE = reference.TRAINED_STEP_SIZE
+DECAY = (1.0, 16.0)         # -A a head, uniform (Mamba-2's initialisation)
+# what a branch's last matrix is drawn at, over its fan-in^-0.5 and its
+# multiplier: the attention averages values over the visible keys, so its
+# output is small before W_o; the gated group norm hands W_out unit rows;
+# silu(g) * up has a root mean square near a half.  And the dt columns of
+# the input projection: the step size's data-dependent part, in softplus's
+# argument.  Chosen once (PERF.md section 6) so that REFERENCE_RANGES hold.
+GAINS = {"wo": 1.5, "out_proj": 0.6, "mlp_down": 1.2, "dt": 0.2, "q": 1.5}
+
+
+def draw_rule(cfg: dict, leaf: str, shape: tuple):
+    """How :func:`draw` makes the tensor named ``leaf``: a kind (str) or the
+    standard deviations of a normal as ((columns, std), ...) over the last
+    axis.  Every matrix [.., in, out] is at in^-0.5 over the multipliers that
+    scale what it makes."""
+    if leaf in NORMS or leaf in ("conv_b", "dt_bias", "a_log", "d_skip"):
+        return leaf if leaf not in NORMS else "norm"
+    D = int(cfg["hidden_size"])
+    if leaf == "emb":
+        return ((shape[-1], 1.0 / float(cfg["embedding_multiplier"])),)
+    if leaf == "head":
+        return ((shape[-1], D ** -0.5 / float(cfg["lm_head_multiplier"])),)
+    fan = shape[-2] ** -0.5
+    if leaf == "in_proj":
+        Ds, H = int(cfg["mamba_d_ssm"]), int(cfg["mamba_n_heads"])
+        bw = int(cfg["mamba_n_groups"]) * int(cfg["mamba_d_state"])
+        base = fan / float(cfg["ssm_in_multiplier"])
+        return tuple((w, base * g / float(m)) for w, g, m in zip(
+            (Ds, Ds, bw, bw, H), (1.0, 1.0, 1.0, 1.0, GAINS["dt"]),
+            cfg["ssm_multipliers"]))
+    if leaf == "wqkv":
+        dh = int(cfg["head_dim"])
+        q, kv = (int(cfg["num_attention_heads"]) * dh,
+                 int(cfg["num_key_value_heads"]) * dh)
+        base = fan / float(cfg["attention_in_multiplier"])
+        return ((q, base * GAINS["q"]),
+                (kv, base / float(cfg["key_multiplier"])), (kv, base))
+    scale = {"out_proj": GAINS["out_proj"] / float(cfg["ssm_out_multiplier"]),
+             "wo": GAINS["wo"] / float(cfg["attention_out_multiplier"]),
+             "mlp_gate": 1.0 / float(cfg["mlp_multipliers"][0]),
+             "mlp_down": GAINS["mlp_down"] / float(cfg["mlp_multipliers"][1]),
+             }.get(leaf, 1.0)
+    return ((shape[-1], fan * scale),)
+
+
+def draw(key, rule, shape: tuple, dtype):
+    """One tensor by its :func:`draw_rule` (jit-able with all but ``key``
+    static): norm weights 1 + 0.1 N(0, 1); ``conv_b`` 0.02 N; ``a_log`` the
+    log of a decay uniform in :data:`DECAY` a head; ``d_skip`` ones;
+    ``dt_bias`` the inverse softplus of a step size log-uniform in
+    :data:`STEP_SIZE`; everything else normal at the rule's standard
+    deviations."""
+    import jax
+    import jax.numpy as jnp
+    f32 = jnp.float32
+    if rule == "d_skip":
+        w = jnp.ones(shape, f32)
+    elif rule == "a_log":
+        w = jnp.log(jax.random.uniform(key, shape, f32, *DECAY))
+    elif rule == "dt_bias":
+        lo, hi = (math.log(v) for v in STEP_SIZE)
+        dt = jnp.exp(jax.random.uniform(key, shape, f32) * (hi - lo) + lo)
+        w = dt + jnp.log(-jnp.expm1(-dt))
+    else:
+        w = jax.random.normal(key, shape, f32)
+        if rule == "norm":
+            w = 1.0 + 0.1 * w
+        elif rule == "conv_b":
+            w = 0.02 * w
+        else:
+            w = w * jnp.concatenate([jnp.full((n,), s, f32) for n, s in rule])
+    return w.astype(dtype)
+
+
+def make_params(cfg: dict, seed: int = WEIGHT_SEED) -> dict:
+    """Every weight on the device in the configuration's dtype, one jitted
+    :func:`draw` a tensor; the program gives the names and the shapes."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.decode.falcon_h1 import param_shapes
+    shapes = param_shapes(model_config(cfg))
+    make = jax.jit(draw, static_argnums=(1, 2, 3))
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+    dtype = jnp.dtype(str(cfg["dtype"]))
+    return {name: make(key, draw_rule(cfg, name.rsplit(".", 1)[-1],
+                                      tuple(shape)), tuple(shape), dtype)
+            for key, (name, (shape, _)) in zip(keys, shapes.items())}
+
+
+def build_server(cfg: dict, mix: dict, params):
+    from paddle_tpu.data import native
+    from paddle_tpu.decode import DecodeClient, DecodeEngine, DecodeServer
+    from paddle_tpu.decode.falcon_h1 import FalconH1LM
+    native.load()       # the native transport, built from source or an error
+    eng = mix["engine"]
+    engine = DecodeEngine(
+        FalconH1LM(model_config(cfg)), params, name=MODEL,
+        max_slots=int(eng["max_slots"]),
+        block_tokens=int(eng["block_tokens"]), num_blocks=int(eng["num_blocks"]),
+        prefill_buckets=[int(b) for b in eng["prefill_buckets"]],
+        max_queue=int(eng["max_queue"]), cache_dtype=str(cfg["kv_dtype"]),
+        prefix_cache=False, overcommit=False)
+    server = DecodeServer("127.0.0.1:0", engines={MODEL: engine})
+    server.start()
+    return engine, server, DecodeClient(endpoints=[server.endpoint])
+
+
+def judged_steps(n: int) -> List[int]:
+    """Of ``n`` teacher-forced tokens: the prefill's (0) and the decode
+    steps from :data:`JUDGE_FROM` on, every :data:`JUDGE_EVERY`-th — or, of a
+    shorter replay, its last steps at that spacing."""
+    first = JUDGE_FROM if n > JUDGE_FROM else max(1, (n - 1) % JUDGE_EVERY)
+    return [0] + list(range(first, n, JUDGE_EVERY))
+
+
+def replay(engine, asks, after_dispatch: Optional[Callable] = None,
+           const=None) -> List[Sample]:
+    """``asks``: (prompt, tokens the timed path produced) a request, at most
+    ``max_slots``; every request is replayed for as many tokens as the
+    shortest has (a slot whose stream has ended would go on scribbling on
+    its recurrent rows).  Every dispatch goes through the idle engine's
+    executor under the engine's own keys and shapes, so it runs the very
+    executables the window ran (a miss raises: nothing may compile here).
+    ``after_dispatch(state) -> state`` rewrites the state after every
+    dispatch and ``const`` replaces the weights (the controls and the planted
+    faults).  A sample's ``h`` is its slot's recurrent rows of every layer
+    [L, H, N, P] after the last step."""
+    from paddle_tpu.decode.cache import blocks_for
+    exe, cache = engine._exe, engine.cache
+    const = engine._plist if const is None else const
+    S, MB, bs = engine.max_slots, engine.max_blocks_per_seq, cache.block_tokens
+    n = min(len(produced) for _, produced in asks)
+    at = judged_steps(n)
+    k = len(asks)
+
+    def missed():
+        raise RuntimeError("replay missed the engine's executable cache")
+
+    def dispatch(key, feed):
+        outs, new_state = exe.run_callable(key, missed, feed,
+                                           state=cache.state(), const=const)
+        if after_dispatch is not None:
+            new_state = after_dispatch(new_state)
+        cache.update(new_state)
+        return outs
+
+    tables = np.zeros((S, MB), np.int32)
+    held, logits = [], [[] for _ in asks]
+    for i, (prompt, _) in enumerate(asks):
+        P = int(prompt.size)
+        blocks = cache.allocator.alloc(blocks_for(P + n, bs))
+        if blocks is None:
+            raise RuntimeError("replay: the idle engine's pool is short")
+        held.append(blocks)
+        tables[i, :len(blocks)] = blocks
+        bucket = engine.prefill_ladder.snap(P)
+        feed_tokens = np.zeros((1, bucket), np.int32)
+        feed_tokens[0, :P] = prompt
+        _, lg = dispatch(
+            f"decode/{engine.name}/prefill/{bucket}",
+            [feed_tokens, np.int32(P), np.int32(i), tables[i].copy(),
+             np.uint32(0), np.float32(0.0), np.int32(0)])[:2]
+        logits[i].append(np.asarray(lg))
+    zeros_u, zeros_i = np.zeros((S,), np.uint32), np.zeros((S,), np.int32)
+    zeros_f = np.zeros((S,), np.float32)
+    tokens, positions = zeros_i.copy(), zeros_i.copy()
+    for j in range(1, n):
+        for i, (prompt, produced) in enumerate(asks):
+            tokens[i], positions[i] = produced[j - 1], prompt.size + j - 1
+        lg = dispatch(
+            f"decode/{engine.name}/step",
+            [tokens.copy(), positions.copy(), tables.copy(), zeros_u, zeros_i,
+             zeros_f, zeros_i])[1]
+        if j in at:
+            lg = np.asarray(lg[:k])
+            for i in range(k):
+                logits[i].append(lg[i])
+    h = np.asarray(cache.state()[1][:, :k])         # [L, k, H, N, P]
+    for blocks in held:
+        cache.allocator.release(blocks)
+    return [Sample(np.asarray(prompt, np.int32),
+                   np.asarray(produced[:n], np.int32), np.asarray(at),
+                   np.stack(lg).astype(np.float32), h[:, i])
+            for i, ((prompt, produced), lg) in enumerate(zip(asks, logits))]
+
+
+def run_reference(params, cfg: dict, samples: List[Sample],
+                  length: Optional[int] = None) -> list:
+    """The plain reference's logits at every judged position of every sample,
+    every layer's state after the last one and every layer's own readings:
+    [(logits [len(at), V], S [L, H, N, P], stats [L, len(STATS)])].  One
+    padded length for all (the longest prompt of the mix plus the replayed
+    tokens), so one compile a part of a layer."""
+    ref_cfg = reference_config(cfg)
+    T = length or max(int(s.prompt.size) + len(s.produced) - 1
+                      for s in samples)
+    out = []
+    for s in samples:
+        P, n = int(s.prompt.size), len(s.produced)
+        L = P + n - 1
+        seq = np.zeros((T,), np.int32)
+        seq[:L] = np.concatenate([s.prompt, s.produced[:-1]])
+        out.append(tuple(np.asarray(a) for a in reference.forward(
+            params, ref_cfg, seq, L, P - 1 + s.at)))
+    return out
+
+
+def _err(got, want):
+    """||got - want|| / ||want|| over the last axis."""
+    d = got - want
+    return np.sqrt((d * d).sum(-1) / (want * want).sum(-1))
+
+
+def readings(samples: List[Sample], refs: list) -> dict:
+    """The statistics :data:`LIMITS` and :data:`REFERENCE_RANGES` bound, and
+    what they were taken over."""
+    prefill, decode, gaps, scales, states = [], [], [], [], []
+    for s, (ref_logits, ref_h, _) in zip(samples, refs):
+        err = _err(s.logits, ref_logits)
+        prefill.append(err[:1])
+        decode.append(err[1:])
+        chosen = np.take_along_axis(ref_logits, s.produced[s.at][:, None],
+                                    1)[:, 0]
+        gaps.append(ref_logits.max(-1) - chosen)
+        scales.append(np.abs(ref_logits).max())
+        layers = ref_h.shape[0]
+        states.append(_err(s.h.reshape(layers, -1).astype(np.float64),
+                           ref_h.reshape(layers, -1).astype(np.float64)))
+    prefill, decode = np.concatenate(prefill), np.concatenate(decode)
+    gaps, states = np.concatenate(gaps), np.concatenate(states)
+    scale = float(max(scales))
+    stats = np.stack([r[2] for r in refs]).astype(np.float64)   # [s, L, k]
+    rms = np.sqrt(np.stack([(r[1].astype(np.float64) ** 2).mean((-3, -2, -1))
+                            for r in refs]))                    # [s, L]
+    if not decode.size:         # a replay of one token: nothing was decoded
+        decode = prefill
+    own = {"ref_" + name: [float(stats[..., i].min()),
+                           float(stats[..., i].max())]
+           for i, name in enumerate(reference.STATS)}
+    return {"logit_err_prefill_max": float(prefill.max()),
+            "logit_err_decode_p50": harness.percentile(decode, 0.5),
+            "logit_err_decode_p90": harness.percentile(decode, 0.9),
+            "state_err_p50": harness.percentile(states, 0.5),
+            "token_gap_p99": harness.percentile(gaps, 0.99) / scale,
+            **own,
+            "ref_state_rms": [float(rms.min()), float(rms.max())],
+            "positions": int(prefill.size + decode.size),
+            "steps_replayed": int(len(samples[0].produced) - 1),
+            "judged_steps": [int(a) for a in samples[0].at],
+            "exact_tokens": int((gaps == 0).sum()), "logit_scale": scale,
+            "logit_err_decode_max": float(decode.max()),
+            "logit_err_prefill_p50": harness.percentile(prefill, 0.5),
+            "state_err_p90": harness.percentile(states, 0.9),
+            "state_err_max": float(states.max()),
+            "token_gap_max": float(gaps.max()) / scale,
+            "finite": bool(np.isfinite(prefill).all()
+                           and np.isfinite(decode).all()
+                           and np.isfinite(states).all()
+                           and np.isfinite(stats).all()
+                           and np.isfinite(rms).all())}
+
+
+def judge(checks, got: dict) -> None:
+    """One check a limit and one a range of the reference's own; a reading
+    that is not a number fails its check."""
+    for name, limit in LIMITS.items():
+        v = got[name]
+        checks.add(f"reference comparison: {name} within {limit:g}",
+                   got["finite"] and bool(v <= limit),
+                   f"read {v:.6g} over {got['positions']} positions, "
+                   f"{got['steps_replayed']} steps replayed")
+    for name, (low, high) in REFERENCE_RANGES.items():
+        least, most = got[name]
+        checks.add(f"the reference's own: {name} within [{low:g}, {high:g}]",
+                   got["finite"] and bool(low <= least and most <= high),
+                   f"read {least:.6g} to {most:.6g} over samples and layers")
+    print("bench reference readings:", json.dumps(got), flush=True)
+
+
+def pick(done: list, seed: int) -> list:
+    """A seeded sample of :data:`SAMPLE` finished requests that produced at
+    least :data:`REPLAY_TOKENS` tokens; where fewer did, the longest ones."""
+    order = np.random.default_rng(int(seed)).permutation(len(done))
+    long = [done[j] for j in order if len(done[j].tokens) >= REPLAY_TOKENS]
+    if len(long) < SAMPLE:
+        long = sorted((done[j] for j in order),
+                      key=lambda r: -len(r.tokens))
+    return long[:SAMPLE]
+
+
+def reference_length(mix: dict) -> int:
+    """The one padded length of every reference run of a cell: the mix's
+    longest prompt and the replayed tokens."""
+    return int(mix["prompt_tokens"]["max"]) + REPLAY_TOKENS - 1
+
+
+def check_sample(checks, cfg: dict, params, engine, result, seed: int,
+                 mix: dict) -> None:
+    done = [r for r in result.sent if result.in_window(r) and r.tokens
+            and r.failure is None]
+    if not done:
+        checks.add("reference comparison", False, "no finished request")
+        return
+    asks = [(r.prompt, list(r.tokens)[:REPLAY_TOKENS])
+            for r in pick(done, seed)]
+    samples = replay(engine, asks)
+    judge(checks, readings(samples, run_reference(
+        params, cfg, samples, reference_length(mix))))
+
+
+def window_counters(name: str) -> dict:
+    c = harness.program_counters()
+    return {k: float(c.get(f"decode.{name}.{k}", 0)) for k in WINDOW_COUNTERS}
+
+
+def run(cell, args, log, t_process_start: float, devices) -> dict:
+    cfg, mix = cell.config, cell.mix
+    seconds = float(args.seconds)
+    requests = loadgen.build_requests(mix, int(cfg["vocab_size"]), args.seed,
+                                      seconds)
+    params = make_params(cfg)
+    engine, server, client = build_server(cfg, mix, params)
+    acct, checks = harness.Accounting(), harness.Checks()
+    phases = harness.Phases(t_process_start)
+    state = {}
+    tracer = trace_reduce.Tracer(os.path.join(
+        cell.root, ".bench_trace", cell.name)) if args.trace else None
+    tracing = None
+    try:
+        warm_up(client, cfg, mix)
+        warm_mark = log.mark()
+
+        def on_window(event):
+            nonlocal tracing
+            st = engine.stats
+            state[event] = {"mark": log.mark(), "z": engine.decodez(),
+                            "counters": window_counters(MODEL),
+                            "walls": (st.step_ms.sum, st.step_ms.count,
+                                      st.prefill_ms.sum, st.prefill_ms.count)}
+            if event == "open" and tracer:
+                tracing = threading.Thread(
+                    target=trace_later, daemon=True, args=(tracer, mix,
+                                                           seconds))
+                tracing.start()
+
+        phases.mark("setup")
+        result = loadgen.run_load(client, MODEL, mix, requests, seconds,
+                                  on_window=on_window)
+        phases.mark("lead_in_and_window", at=result.w1)
+        if tracing:
+            tracing.join(timeout=300.0)
+            phases.within("stop_trace", tracer.stop_s)
+        peak = harness.device_facts(devices, cell.chips)
+        z_end = engine.decodez()
+        loadgen.account(result, acct)
+        phases.mark("drain")
+        check_sample(checks, cfg, params, engine, result, args.seed, mix)
+        phases.mark("reference_check")
+    finally:
+        server.stop()
+
+    setup_s = result.w0 - t_process_start
+    ttft, tbt = loadgen.latency_samples(result)
+    values = {"setup_s": setup_s,
+              "served_tokens_per_s": loadgen.served_tokens(result) / seconds}
+    print(f"bench latency: ttft_ms p50 {harness.percentile(ttft, 0.5):.2f} "
+          f"p90 {harness.percentile(ttft, 0.9):.2f} over {len(ttft)} requests; "
+          f"tbt_ms p50 {harness.percentile(tbt, 0.5):.2f} "
+          f"p95 {harness.percentile(tbt, 0.95):.2f} over {len(tbt)} gaps"
+          if ttft and tbt else "bench latency: no sample", flush=True)
+    z0, z1 = state["open"]["z"], state["close"]["z"]
+    dz = {k: z1[k] - z0[k] for k in ("tokens", "steps", "prefills")}
+    dc = {k: state["close"]["counters"][k] - state["open"]["counters"][k]
+          for k in WINDOW_COUNTERS}
+    dc.update(steps=float(dz["steps"]), prefills=float(dz["prefills"]))
+    a, b = state["open"]["walls"], state["close"]["walls"]
+    step_s, prefill_s = (b[0] - a[0]) / 1e3, (b[2] - a[2]) / 1e3
+    silence, pulse = loadgen.longest_silence(result), result.pulse
+    print(f"bench engine: in the window {b[1] - a[1]} steps took {step_s:.3f} s "
+          f"and {b[3] - a[3]} prefills {prefill_s:.3f} s by the engine's own "
+          f"clock; {seconds - step_s - prefill_s:.3f} s of the window were "
+          f"neither", flush=True)
+    print(f"bench stall: longest silence between token arrivals "
+          f"{silence[0]:.1f} ms at +{silence[1]:.2f} s; a thread that only "
+          f"sleeps overslept by at most {pulse[0]:.1f} ms at +{pulse[1]:.2f} s",
+          flush=True)
+    print("bench decodez: window deltas", json.dumps(dz), "end",
+          json.dumps({k: z_end[k] for k in ("joins", "leaves", "shed")}),
+          "cache", json.dumps(z_end["cache"]), flush=True)
+    print("bench counters: window deltas", json.dumps(dc), flush=True)
+    window_compiles = harness.check_program_state(
+        checks, state["open"]["mark"], state["close"]["mark"])
+    c = harness.program_counters()
+    bad = {n: int(c.get(n, 0)) for n in FALLBACK_COUNTERS if c.get(n, 0)}
+    checks.add("no new kernel fell back to XLA", not bad, json.dumps(bad))
+    checks.add("joins == leaves after the drain",
+               z_end["joins"] == z_end["leaves"],
+               f"{z_end['joins']} joins, {z_end['leaves']} leaves")
+    checks.add("the server shed nothing", z_end["shed"] == 0,
+               f"engine counter shed = {z_end['shed']}")
+    checks.add("no failure outside the window", acct.failed_outside == 0,
+               json.dumps(acct.outside_by_class))
+    phases.mark("report")
+    summary = None
+    if tracer:
+        tracer.read()       # after the drain: nothing is served any more
+        phases.mark("extract")
+        if tracer.raw:
+            tracer.add_host_spans(loadgen.host_spans(result))
+            summary = trace_reduce.reduce(
+                tracer.raw, (loadgen.SEND_SPAN, loadgen.RECV_SPAN))
+            phases.mark("reduce")
+    ctx = {"trace": summary, "decodez": dz, "memory": peak,
+           "lag_ms": result.lag_ms, "ttft_ms": ttft, "tbt_ms": tbt,
+           "end_to_end": values,
+           "compile": {"in_window": window_compiles,
+                       "cache_hits_in_setup": warm_mark[1]},
+           "config": cfg, "mix": mix, "chips": cell.chips, "seconds": seconds,
+           "window_counters": dc, "trace_raw": tracer.raw if tracer else None,
+           "xplane": tracer.xplane if tracer else None,
+           "device_kind": str(devices[0].device_kind)}
+    return {"acct": acct, "checks": checks, "values": values, "ctx": ctx,
+            "device": peak, "summary": summary, "phases": phases}

@@ -79,6 +79,14 @@ HYBRID_FALLBACK_COUNTERS = (
     "attn.diff_prefill_fallbacks",
 )
 
+# the XLA fallbacks of the parallel-hybrid (Mamba-2 beside grouped-query
+# attention) LM's kernels (kernels/ssd.py, kernels/gqa.py)
+PARALLEL_HYBRID_FALLBACK_COUNTERS = (
+    "ssm.ssd_fallbacks",
+    "attn.gqa_decode_fallbacks",
+    "attn.gqa_prefill_fallbacks",
+)
+
 MOSAIC_CALL = "tpu_custom_call"
 
 
@@ -739,6 +747,88 @@ def phase_hybrid_lm(vocab=8192, hidden=512, heads=8, kv_heads=4, ffn=1024,
         engine.close()
 
 
+def phase_parallel_hybrid_lm(vocab=8192, hidden=512, heads=4, kv_heads=2,
+                             head_dim=128, ffn=1024, layers=2, ssm_heads=4,
+                             ssm_head_dim=128, groups=2, d_state=128,
+                             chunk=128, max_seq_len=512, max_slots=4,
+                             block_tokens=16, prefill_bucket=256,
+                             prompt_len=150, new_tokens=40, dtype="bfloat16"):
+    """``decode.falcon_h1.FalconH1LM`` (Falcon-H1's layer at its head widths,
+    two layers, the published multipliers' places with other numbers) through
+    ``DecodeEngine``: one short stream — a prompt that ends inside its second
+    scan chunk — whose logits at every generated position are held against
+    the benchmark's plain reference; no kernel fell back (on the chip none is
+    interpreted); ``/decodez`` shows the two kinds of state."""
+    import jax.numpy as jnp
+    from benchmark.reference import falcon_h1 as reference
+    from paddle_tpu.decode import DecodeEngine, SamplingParams
+    from paddle_tpu.decode.falcon_h1 import FalconH1Config, FalconH1LM
+
+    cfg = FalconH1Config(
+        vocab_size=vocab, hidden_size=hidden, num_hidden_layers=layers,
+        num_attention_heads=heads, num_key_value_heads=kv_heads,
+        head_dim=head_dim, intermediate_size=ffn,
+        mamba_d_ssm=ssm_heads * ssm_head_dim, mamba_n_heads=ssm_heads,
+        mamba_d_head=ssm_head_dim, mamba_n_groups=groups,
+        mamba_d_state=d_state, mamba_chunk_size=chunk,
+        embedding_multiplier=2.0, lm_head_multiplier=0.5,
+        attention_out_multiplier=0.75, key_multiplier=0.5,
+        ssm_in_multiplier=0.5, ssm_out_multiplier=0.75,
+        ssm_multipliers=(0.9, 1.1, 0.8, 1.25, 0.7),
+        mlp_multipliers=(1.4, 0.65), max_seq_len=max_seq_len, dtype=dtype)
+    model = FalconH1LM(cfg)
+    params = model.init_params(seed=7)
+    c0 = counters()
+    engine = DecodeEngine(model, params, name="parallel_hybrid",
+                          max_slots=max_slots, block_tokens=block_tokens,
+                          prefill_buckets=[prefill_bucket],
+                          capture_logits=True, cache_dtype=dtype,
+                          prefix_cache=False, overcommit=False)
+    try:
+        prompt = np.random.RandomState(0).randint(
+            0, vocab, (prompt_len,)).astype("int32")
+        handle = engine.submit(prompt,
+                               SamplingParams(max_new_tokens=new_tokens))
+        result = handle.result(timeout=900.0)
+        toks = np.asarray(result["tokens"], np.int32)
+        check(toks.size == new_tokens and result.get("finish") == "length",
+              f"the parallel-hybrid stream ended early: {result}")
+        seq = np.concatenate([prompt, toks[:-1]])
+        want, _, _ = reference.forward(
+            {k: jnp.asarray(v) for k, v in params.items()}, cfg.to_dict(),
+            seq, seq.size, np.arange(prompt_len - 1, seq.size))
+        want = np.asarray(want)
+        got = np.stack(handle.logits).astype(np.float32)
+        err = np.sqrt(((got - want) ** 2).sum(-1) / (want ** 2).sum(-1))
+        scale = float(np.abs(want).max())
+        gap = want.max(-1) - np.take_along_axis(want, toks[:, None], 1)[:, 0]
+        # bf16 activations through two layers against float32 at the highest
+        # precision: a few percent of the logits' norm, and a token at most
+        # 5% of the logit scale under the reference's argmax
+        check(float(err.max()) <= (0.06 if dtype == "bfloat16" else 1e-3),
+              f"parallel-hybrid-LM logits are {err.max():.4f} of their norm "
+              f"off the reference")
+        check(float(gap.max()) <= 0.05 * scale,
+              f"a parallel-hybrid-LM token trails the reference's argmax by "
+              f"{gap.max():.4f} (logit scale {scale:.2f})")
+        fell = {n: counter_delta(c0, n)
+                for n in PARALLEL_HYBRID_FALLBACK_COUNTERS}
+        check(not any(fell.values()), f"a new kernel fell back: {fell}")
+        z = engine.decodez()
+        cache = z["cache"]
+        check(cache.get("kind") == "hybrid" and all(
+            cache.get(k, 0) > 0 for k in (
+                "kv_pool_bytes", "recurrent_state_bytes", "kv_live_tokens")),
+              f"/decodez does not show the two kinds of state: {cache}")
+        return {"tokens_checked": int(toks.size),
+                "tokens_exact": int((gap == 0).sum()),
+                "logit_err_max": float(err.max()),
+                "worst_logit_gap": float(gap.max()), "logit_scale": scale,
+                "steps": z["steps"], "cache": cache, "fallbacks": fell}
+    finally:
+        engine.close()
+
+
 # ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
@@ -897,6 +987,7 @@ def main() -> int:
     run_phase(report, "decode_server", phase_decode_server)
     run_phase(report, "latent_lm", phase_latent_lm)
     run_phase(report, "hybrid_lm", phase_hybrid_lm)
+    run_phase(report, "parallel_hybrid_lm", phase_parallel_hybrid_lm)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])
@@ -911,7 +1002,8 @@ def main() -> int:
     report["fallback_counters"] = {
         n: int(c.get(n, 0))
         for n in (FALLBACK_COUNTERS + LATENT_FALLBACK_COUNTERS
-                  + HYBRID_FALLBACK_COUNTERS)}
+                  + HYBRID_FALLBACK_COUNTERS
+                  + PARALLEL_HYBRID_FALLBACK_COUNTERS)}
     report["jax_cache"] = {"hits": LOG.cache_hits, "compiles": LOG.compiles,
                            "compile_s": round(LOG.compile_s, 2)}
     failures += [f"phase {n}: {p.get('error')}"

@@ -66,6 +66,17 @@ package is that state plane, built on the repo's own primitives:
   :class:`~paddle_tpu.decode.model.TableWalkObserver`'s
   ``step_live_blocks`` of ``step_table_blocks``, the share of the block
   tables a :class:`TransformerLM`'s decode steps walked).
+- **A fourth model, with state of two kinds in EVERY layer**
+  (:mod:`falcon_h1`): Falcon-H1's parallel-hybrid block — a Mamba-2
+  (state-space duality) mixer and a grouped-query attention with rotary
+  positions side by side on one normed input, the published µP
+  multipliers, an untied head (``kernels/ssd.py``, ``kernels/gqa.py``),
+  its programs ``lax.scan``s over the layers' stacked weights.  Its cache
+  is the same :class:`~paddle_tpu.decode.cache.HybridStateCache` with a
+  paged K/V pool of L layers, recurrent rows ``[L, slots, heads, N, P]``
+  and convolution tails of L layers, and no window rings: blocks are
+  released at a leave, a slot's rows are overwritten whole at a join.
+  Same protocol, same ``slot_state``; ``supports`` is empty for it too.
 - **On-device sampling** (:mod:`model`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
   top-k / temperature inside the decode dispatch; incremental beam
@@ -102,6 +113,7 @@ from .model import (LMConfig, TransformerLM, load_lm,  # noqa: F401
                     save_lm)
 from .mla import MLAConfig, MLATransformerLM  # noqa: F401
 from .sambay import SambaYConfig, SambaYLM  # noqa: F401
+from .falcon_h1 import FalconH1Config, FalconH1LM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -116,6 +128,7 @@ __all__ = [
     "PrefixCache",
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
     "MLAConfig", "MLATransformerLM", "SambaYConfig", "SambaYLM",
+    "FalconH1Config", "FalconH1LM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

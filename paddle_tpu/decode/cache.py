@@ -435,23 +435,28 @@ class PagedLatentCache(_PagedPool):
 
 
 class HybridStateCache(_PagedPool):
-    """The per-stream state of a model that mixes state-space, window and
-    full attention layers — three kinds under one manager:
+    """The per-stream state of a model that mixes state-space and attention
+    layers — up to three kinds under one manager, each with a layer count of
+    its own (a kind whose count is zero has no array):
 
-    - ``kv`` ``[1, NB, bs, 2·kw]``: the paged K/V pool of the ONE
-      full-attention layer, a token's row ``[k | v]`` with the K/V heads
-      merged into the minor axis (whole lane tiles, class docs above).
-      Blocks, tables, the :class:`BlockAllocator` and the trash block are the
-      paged pools'; every layer that attends to that layer's keys reads this
-      pool, none copies it.
+    - ``kv`` ``[kv layers, NB, bs, 2·kw]``: the paged K/V pool, a token's row
+      ``[k | v]`` with the K/V heads merged into the minor axis (whole lane
+      tiles, class docs above).  Blocks, tables, the :class:`BlockAllocator`
+      and the trash block are the paged pools'.  One layer where ONE
+      full-attention layer's rows are what every attending layer reads
+      (:mod:`~paddle_tpu.decode.sambay`: none copies it); every layer where
+      every layer attends (:mod:`~paddle_tpu.decode.falcon_h1`).
     - ``rings`` ``[window layers, slots · W/rb, rb, 2·kw]``: a window layer
       keeps a slot's last ``W`` rows at ``position mod W``, as ``W/rb``
       blocks of ``rb`` rows that belong to the slot for good — the bytes do
       not grow with a stream's context, and the paged decode kernel reads a
       ring as a table of the slot's own blocks.
-    - ``h`` ``[state-space layers, slots, N, Di]`` float32 and ``conv``
-      ``[state-space layers, slots, K-1, Di]``: the recurrent state and the
-      convolution's tail, one row a slot.
+    - ``h`` ``[state-space layers, slots, *state_shape]`` float32 and
+      ``conv`` ``[state-space layers, slots, K-1, conv_width]``: the
+      recurrent state and the convolution's tail, one row a slot.
+      ``state_shape`` is ``(N, Di)`` (Mamba-1: a decay a channel) unless
+      given (Mamba-2: ``(heads, N, head channels)``); the convolution is as
+      wide as ``d_inner`` unless ``conv_width`` says what else it covers.
 
     The last two are addressed by SLOT, not by block list: a prefill is told
     its slot and overwrites the slot's rows whole (that is the reset at a
@@ -464,27 +469,31 @@ class HybridStateCache(_PagedPool):
     def __init__(self, kv_width: int, num_blocks: int, block_tokens: int,
                  slots: int, window: int, window_layers: int,
                  ssm_layers: int, d_inner: int, d_state: int, d_conv: int,
-                 dtype="bfloat16"):
+                 dtype="bfloat16", kv_layers: int = 1, state_shape=None,
+                 conv_width: Optional[int] = None):
         if str(dtype) == "int8":
             raise ValueError("the hybrid state has no int8 form: its rows "
                              "carry no per-block scale")
-        super().__init__(1, num_blocks, block_tokens, dtype)
+        super().__init__(kv_layers, num_blocks, block_tokens, dtype)
         self.slots, self.window = int(slots), int(window)
-        self.ring_rows = min(self.window, self.RING_ROWS)
-        if self.window % self.ring_rows:
-            raise ValueError(f"a window of {window} is not whole blocks of "
-                             f"{self.ring_rows} rows")
-        self.ring_blocks = self.window // self.ring_rows
         width = 2 * int(kv_width)
-        self.kv = jnp.zeros((1, self.num_blocks, self.block_tokens, width),
-                            dtype)
-        self.rings = jnp.zeros((int(window_layers),
-                                self.slots * self.ring_blocks,
-                                self.ring_rows, width), dtype)
-        self.h = jnp.zeros((int(ssm_layers), self.slots, int(d_state),
-                            int(d_inner)), jnp.float32)
-        self.conv = jnp.zeros((int(ssm_layers), self.slots, int(d_conv) - 1,
-                               int(d_inner)), dtype)
+        self.kv = jnp.zeros((self.num_layers, self.num_blocks,
+                             self.block_tokens, width), dtype)
+        self.rings = None
+        if window_layers:
+            self.ring_rows = min(self.window, self.RING_ROWS)
+            if self.window % self.ring_rows:
+                raise ValueError(f"a window of {window} is not whole blocks "
+                                 f"of {self.ring_rows} rows")
+            self.ring_blocks = self.window // self.ring_rows
+            self.rings = jnp.zeros((int(window_layers),
+                                    self.slots * self.ring_blocks,
+                                    self.ring_rows, width), dtype)
+        rows = (int(ssm_layers), self.slots)
+        self.h = jnp.zeros(rows + tuple(
+            state_shape or (int(d_state), int(d_inner))), jnp.float32)
+        self.conv = jnp.zeros(rows + (int(d_conv) - 1,
+                                      int(conv_width or d_inner)), dtype)
         self.live_tokens = 0        # the model's observer keeps it
 
     @staticmethod
@@ -497,7 +506,7 @@ class HybridStateCache(_PagedPool):
 
     @property
     def window_state_bytes(self) -> int:
-        return self._bytes(self.rings)
+        return 0 if self.rings is None else self._bytes(self.rings)
 
     @property
     def recurrent_state_bytes(self) -> int:
@@ -509,10 +518,17 @@ class HybridStateCache(_PagedPool):
                 + self.recurrent_state_bytes)
 
     def state(self) -> list:
+        """``[kv, rings, h, conv]``; without window layers ``[kv, h,
+        conv]``."""
+        if self.rings is None:
+            return [self.kv, self.h, self.conv]
         return [self.kv, self.rings, self.h, self.conv]
 
     def update(self, new_state: list) -> None:
-        self.kv, self.rings, self.h, self.conv = new_state
+        if self.rings is None:
+            self.kv, self.h, self.conv = new_state
+        else:
+            self.kv, self.rings, self.h, self.conv = new_state
 
     def snapshot(self) -> dict:
         snap = super().snapshot()
@@ -521,4 +537,6 @@ class HybridStateCache(_PagedPool):
                     window_state_bytes=self.window_state_bytes,
                     recurrent_state_bytes=self.recurrent_state_bytes,
                     kv_live_tokens=int(self.live_tokens))
+        if self.rings is None:
+            del snap["window"], snap["window_state_bytes"]
         return snap

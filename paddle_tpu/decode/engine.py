@@ -34,7 +34,9 @@ knows none of them by name: blocks of a paged pool, held by block table
 to the stream's end (every model); rows that belong to a SLOT — a window
 layer's ring, bounded by the window however long the context, and a
 state-space layer's recurrent state (a model that sets ``slot_state``:
-:mod:`~paddle_tpu.decode.sambay`).  Such a model is given the slot count
+:mod:`~paddle_tpu.decode.sambay`, whose layers each keep one kind, and
+:mod:`~paddle_tpu.decode.falcon_h1`, whose every layer keeps both blocks
+and a row).  Such a model is given the slot count
 in ``make_cache`` and, in ``prefill``'s feed after the length, the slot
 the prompt fills: its prefill overwrites the slot's rows whole, which is
 the reset at a join; a decode step's row ``i`` is slot ``i``; a slot

@@ -24,7 +24,10 @@ the lanes, which is also how the cache keeps a stream's ``h``.
   elementwise over ``[S, N, Di]``, which XLA fuses into one pass over the
   state (read once, written once); there is nothing for a kernel to add.
 - :func:`causal_conv` / :func:`conv_step` — the depthwise causal convolution
-  before the scan, over a prompt and for one token given the tail.
+  before the scan, over a prompt and for one token given the tail.  Mamba-2's
+  mixer (``decode/falcon_h1.py``) convolves with these too; its recurrence —
+  a scalar decay a head, so a chunk regroups into matrix products — is
+  ``kernels/ssd.py``'s.
 """
 from __future__ import annotations
 

@@ -124,6 +124,21 @@ def test_hybrid_lm_phase_tiny():
     assert not any(out["fallbacks"].values())
 
 
+def test_parallel_hybrid_lm_phase_tiny():
+    out = chip_smoke.phase_parallel_hybrid_lm(
+        vocab=64, hidden=64, heads=2, kv_heads=1, head_dim=128, ffn=128,
+        layers=2, ssm_heads=4, ssm_head_dim=16, groups=2, d_state=32,
+        chunk=8, max_seq_len=64, max_slots=2, block_tokens=16,
+        prefill_bucket=16, prompt_len=11, new_tokens=14, dtype="float32")
+    assert out["tokens_checked"] == 14 and out["tokens_exact"] == 14
+    assert out["logit_err_max"] < 1e-4
+    assert out["cache"]["kind"] == "hybrid"
+    assert "window_state_bytes" not in out["cache"]
+    assert out["cache"]["recurrent_state_bytes"] == \
+        2 * 2 * (4 * 32 * 16 * 4 + 3 * (64 + 2 * 64) * 4)
+    assert not any(out["fallbacks"].values())
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

@@ -63,9 +63,14 @@ package is that state plane, built on the repo's own primitives:
   context lengths, which the engine holds on the host: a program returns
   nothing for a count the host already has, and nothing for a check;
   ``decodez()``: what the observer adds to ``/decodez`` —
-  :class:`~paddle_tpu.decode.model.TableWalkObserver`'s
-  ``step_live_blocks`` of ``step_table_blocks``, the share of the block
-  tables a :class:`TransformerLM`'s decode steps walked).
+  ``step_live_blocks`` of ``step_table_blocks``, the share of the tables
+  handed to the decode steps' attention kernels that their walks fetched:
+  a live stream's blocks up to its context and one of an idle slot, of
+  slots x blocks a slot — one layer's for a :class:`TransformerLM`
+  (:class:`~paddle_tpu.decode.model.TableWalkObserver`), summed over every
+  layer that reads for the two hybrid models (:mod:`sambay`: the pool's
+  readers, and the window layers' rings up to ``min(context, W)``;
+  :mod:`falcon_h1`: every layer)).
 - **A fourth model, with state of two kinds in EVERY layer**
   (:mod:`falcon_h1`): Falcon-H1's parallel-hybrid block — a Mamba-2
   (state-space duality) mixer and a grouped-query attention with rotary

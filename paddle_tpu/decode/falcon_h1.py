@@ -69,7 +69,7 @@ import numpy as np
 from jax import lax
 
 from .cache import HybridStateCache
-from .model import MODEL_TYPES, _sample
+from .model import MODEL_TYPES, _sample, walked_blocks
 from ..kernels import gqa as _gqa
 from ..kernels import ssd as _ssd
 from ..kernels import ssm as _ssm
@@ -254,10 +254,17 @@ class FalconH1Observer:
     ``.wait`` of its launch) whose arguments are what it added to the
     counters of the same names: the launch's own work, for a reader of a
     trace that times that launch.  A step's figures come from the live
-    streams' context lengths, which the engine holds on the host."""
+    streams' context lengths, which the engine holds on the host.
 
-    def __init__(self, name: str, cache, config: FalconH1Config):
+    ``step_live_blocks`` over ``step_table_blocks`` (``decodez()``) is the
+    share of the block tables handed to the decode steps' attention kernel
+    that its walk fetched, every layer counted: a live stream's ``ceil(
+    context / block_tokens)`` blocks of the engine's ``blocks a slot``, and
+    one of an idle slot."""
+
+    def __init__(self, name: str, cache, config: FalconH1Config, table_shape):
         self.config, self.cache = config, cache
+        self._slots, self._slot_blocks = (int(n) for n in table_shape)
         # a live stream's rows of every layer, read once and written once
         self.row_bytes = 2 * 4 * config.num_hidden_layers \
             * int(np.prod(config.state_shape))
@@ -282,6 +289,13 @@ class FalconH1Observer:
             "step_state_bytes", "bytes of recurrent rows the live streams' "
             "one-token updates read and wrote, every layer, summed over "
             "decode steps")
+        self.live_blocks = sc.counter(
+            "step_live_blocks", "blocks the decode steps' attention walk "
+            "fetched, summed over the layers: a live stream's up to its "
+            "context, one of an idle slot")
+        self.table_blocks = sc.counter(
+            "step_table_blocks", "table entries that walk was handed: "
+            "slots x blocks a slot a layer, a step")
         self.live_tokens = sc.gauge("kv_live_tokens")
         sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
         sc.gauge("recurrent_state_bytes").set(cache.recurrent_state_bytes)
@@ -309,10 +323,16 @@ class FalconH1Observer:
             self.cache.live_tokens = context
             sp.annotate(step_context_tokens=context, step_streams=streams,
                         step_state_bytes=moved)
+        layers = self.config.num_hidden_layers
+        self.live_blocks.inc(layers * walked_blocks(
+            contexts, self.cache.block_tokens, self._slots))
+        self.table_blocks.inc(layers * self._slots * self._slot_blocks)
 
     def decodez(self) -> dict:
-        """Nothing of its own on ``/decodez`` (its gauges ride ``cache``)."""
-        return {}
+        """The walk's share of its tables (the class's doc); the gauges
+        ride ``cache``."""
+        return {"step_live_blocks": self.live_blocks.value,
+                "step_table_blocks": self.table_blocks.value}
 
 
 class FalconH1LM:
@@ -352,7 +372,7 @@ class FalconH1LM:
             conv_width=cfg.conv_width)
 
     def observer(self, name: str, cache, table_shape) -> FalconH1Observer:
-        return FalconH1Observer(name, cache, self.config)
+        return FalconH1Observer(name, cache, self.config, table_shape)
 
     # -- parameters --------------------------------------------------------
     def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:

@@ -103,6 +103,15 @@ def _join_state(kc, vc, ks, vs) -> list:
     return [kc, vc] if ks is None else [kc, vc, ks, vs]
 
 
+def walked_blocks(contexts, block_rows: int, slots: int) -> int:
+    """Blocks ONE paged walk fetches in a decode step: a live stream's
+    ``ceil(context / block_rows)`` and an idle slot's one (its length is one
+    token).  ``contexts``: the live streams' lengths, one entry each."""
+    contexts = np.asarray(contexts)
+    return int(np.sum((contexts + block_rows - 1) // block_rows)) \
+        + slots - int(contexts.size)
+
+
 class TableWalkObserver:
     """The observer of a model whose programs return a token and logits
     only (:meth:`TransformerLM.observer`): what it counts, it counts from
@@ -131,10 +140,8 @@ class TableWalkObserver:
     def step(self, extra, contexts) -> None:
         """``contexts``: the live streams' context lengths, this step's
         token included (an int array, one entry a live stream)."""
-        contexts = np.asarray(contexts)
-        bs = self._block_tokens
-        self.live_blocks.inc(int(np.sum((contexts + bs - 1) // bs))
-                             + self._slots - int(contexts.size))
+        self.live_blocks.inc(
+            walked_blocks(contexts, self._block_tokens, self._slots))
         self.table_blocks.inc(self._slots * self._slot_blocks)
 
     def decodez(self) -> dict:

@@ -68,7 +68,7 @@ import numpy as np
 from jax import lax
 
 from .cache import HybridStateCache
-from .model import MODEL_TYPES, _sample
+from .model import MODEL_TYPES, _sample, walked_blocks
 from ..kernels import diffattn as _da
 from ..kernels import ssm as _ssm
 from ..observability import stats as _obs_stats
@@ -264,10 +264,19 @@ class SambaYObserver:
     ``.wait`` of its launch) whose arguments are what it added to the
     counters of the same names: the launch's own work, for a reader of a
     trace that times that launch.  A step's figures come from the live
-    streams' context lengths, which the engine holds on the host."""
+    streams' context lengths, which the engine holds on the host.
 
-    def __init__(self, name: str, cache, config: SambaYConfig):
+    ``step_live_blocks`` over ``step_table_blocks`` (``decodez()``) is the
+    share of the tables handed to the decode steps' attention kernels that
+    their walks fetched, every reading layer counted: the pool's readers
+    (the full layer and the cross layers) fetch a live stream's ``ceil(
+    context / block_tokens)`` blocks of the engine's ``blocks a slot``, a
+    window layer's ring ``ceil(min(context, W) / ring_rows)`` of its
+    ``W / ring_rows``, and each of them one block of an idle slot."""
+
+    def __init__(self, name: str, cache, config: SambaYConfig, table_shape):
         self.config, self.cache = config, cache
+        self._slots, self._slot_blocks = (int(n) for n in table_shape)
         sc = _obs_stats.scope(f"decode.{name}")
         self.prefill_real = sc.counter(
             "prefill_real_tokens", "real prompt tokens prefilled")
@@ -291,6 +300,15 @@ class SambaYObserver:
             "(context cut at the window), summed over steps (one layer)")
         self.streams = sc.counter(
             "step_streams", "live streams, summed over decode steps")
+        self.live_blocks = sc.counter(
+            "step_live_blocks", "blocks the decode steps' attention walks "
+            "fetched, summed over the pool's readers and the window layers' "
+            "rings: a live stream's up to its context (a ring's up to the "
+            "window), one of an idle slot")
+        self.table_blocks = sc.counter(
+            "step_table_blocks", "table entries those walks were handed: "
+            "slots x blocks a slot a pool reader, slots x blocks a ring a "
+            "window layer, a step")
         self.live_tokens = sc.gauge("kv_live_tokens")
         sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
         sc.gauge("window_state_bytes").set(cache.window_state_bytes)
@@ -322,10 +340,20 @@ class SambaYObserver:
             self.cache.live_tokens = context
             sp.annotate(step_context_tokens=context,
                         step_window_tokens=window, step_streams=streams)
+        cfg, cache = self.config, self.cache
+        readers = 1 + cfg.cross_pairs
+        pool = walked_blocks(contexts, cache.block_tokens, self._slots)
+        ring = walked_blocks(np.minimum(contexts, cfg.sliding_window),
+                             cache.ring_rows, self._slots)
+        self.live_blocks.inc(readers * pool + cfg.self_pairs * ring)
+        self.table_blocks.inc(self._slots * (
+            readers * self._slot_blocks + cfg.self_pairs * cache.ring_blocks))
 
     def decodez(self) -> dict:
-        """Nothing of its own on ``/decodez`` (its gauges ride ``cache``)."""
-        return {}
+        """The walks' share of their tables (the class's doc); the gauges
+        ride ``cache``."""
+        return {"step_live_blocks": self.live_blocks.value,
+                "step_table_blocks": self.table_blocks.value}
 
 
 class SambaYLM:
@@ -367,7 +395,7 @@ class SambaYLM:
             cfg.d_state, cfg.d_conv, dtype=dtype)
 
     def observer(self, name: str, cache, table_shape) -> SambaYObserver:
-        return SambaYObserver(name, cache, self.config)
+        return SambaYObserver(name, cache, self.config, table_shape)
 
     # -- parameters --------------------------------------------------------
     def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:

@@ -14,14 +14,19 @@ zeroed: the score is then one 128-deep contraction with the row's key tile
 - :func:`decode_attention` — one query token a slot against the slot's rows
   in a paged pool ``[L, NB, bs, 2·kw]``, handed over WHOLE with the layer as
   a prefetched scalar (a layer's index is a loop counter where the layers
-  are scanned).  The Pallas kernel walks a slot's context in chunks of
-  ``_CHUNK_BLOCKS`` blocks fetched by explicit async copies, the next chunk
-  in flight while this one is computed; a K/V head's tile is read once for
-  the ``2·group`` queries that share it; chunks past the context are
-  skipped.  Nothing in it knows a position: a *window ring* (a slot's last W
-  rows at ``position mod W``) is the same call with the slot's own blocks as
-  its table and ``min(context, W)`` as its length — softmax does not care
-  in which order the rows lie.  ``name`` names the call
+  are scanned).  The Pallas kernel (:func:`paged_walk`, which
+  ``kernels/gqa.py`` calls with its own query rows) takes one grid step a
+  slot and walks the slot's LIVE blocks — ``ceil(context / bs)`` of its
+  table, read from the prefetched lengths — in chunks of ``_CHUNK_BLOCKS``
+  blocks fetched by explicit async copies, block by block; nothing past the
+  frontier is copied and nothing past the table read.  The next fetch is
+  always in flight: a slot's next chunk, or on its last chunk the next
+  slot's first, so a slot whose context is one chunk (every ring) hides its
+  fetch too.  A K/V head's tile is read once for the ``2·group`` queries
+  that share it.  Nothing in it knows a position: a *window ring* (a
+  slot's last W rows at ``position mod W``) is the same call with the
+  slot's own blocks as its table and ``min(context, W)`` as its length —
+  softmax does not care in which order the rows lie.  ``name`` names the call
   (``diff_paged_decode_attn`` / ``diff_ring_decode_attn``), so that a trace
   tells the two uses apart.  The XLA fallback gathers a slot's whole table
   and counts into ``attn.diff_decode_fallbacks``.
@@ -50,6 +55,9 @@ NEG_INF = -1e30
 LANE = 128
 # blocks a chunk: 32 x 16 tokens = 512 rows of 2,560 bf16 lanes, 2.6 MB, twice
 _CHUNK_BLOCKS = 32
+# copies a trip of the loop that starts a chunk's: eight descriptors'
+# address arithmetic packs where one a trip serialises (PERF.md §6, PR 37)
+_COPY_UNROLL = 8
 _FLASH_BLOCK = 256
 
 
@@ -89,63 +97,134 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer,
 
 
 def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
-                   m_scr, l_scr, acc_scr, *, bs: int, chunk: int,
-                   n_chunks: int, n_kv: int, kw: int):
+                   half_scr, *, bs: int, chunk: int, max_blocks: int,
+                   n_kv: int, kw: int):
+    """Grid (S,): one grid step a slot.  The pool stays in HBM, whole
+    (``memory_space=pl.ANY``); a slot's LIVE blocks — ``ceil(context_len /
+    bs)`` of its table, one for an idle slot — are fetched in chunks of
+    ``chunk`` blocks into a double buffer, block ``b`` of a chunk to rows
+    ``b·bs`` of ``buf[half]``, the layer (``ly_ref``, prefetched with the
+    tables) in the copy's source index.  A table entry past the frontier is
+    not copied, none past the table is read.
+
+    The next fetch is always in flight: before the kernel waits for a chunk
+    it starts the slot's next one into the other half, and on a slot's LAST
+    chunk the next slot's first (buffer, semaphores and ``half_scr`` persist
+    over the sequential grid).  ``half_scr`` carries which half that was from
+    one grid step to the next, so every start is waited for exactly once, by
+    the slot that computes it.
+
+    What costs here is the scalar core's work a copy, which no vector work
+    hides across a loop's edge (PERF.md §6, PR 37): a chunk's copies are
+    started ``_COPY_UNROLL`` a trip and signal ONE semaphore a half, which
+    counts bytes — the wait is one descriptor a set bit of the chunk's block
+    count, six at most, not one a block.
+
+    A chunk is computed whole, as one K/V head's key tile against its QR
+    query rows on the MXU; the running max, sum and accumulator of the online
+    softmax are the chunk loop's carry (kept in scratch they serialised the
+    heads).  Rows of a ragged last chunk past the frontier's block hold what
+    an earlier slot left there: their scores are masked by position, and
+    their value lanes are zeroed before the product (``0 x NaN`` is NaN)."""
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    cl = cl_ref[s]
+    n_slots = pl.num_programs(0)
     layer = ly_ref[0]
     span = chunk * bs
-    live = (cl + span - 1) // span
+    QR = q_ref.shape[2]
 
-    def copies(c, slot):
-        return [pltpu.make_async_copy(
-            pool_ref.at[layer, bt_ref[s, c * chunk + b]],
-            buf.at[slot, pl.ds(b * bs, bs)], sem.at[slot, b])
-            for b in range(chunk)]
+    def live_blocks(slot):
+        return jnp.clip((cl_ref[slot] + bs - 1) // bs, 1, max_blocks)
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def start(slot, c, half):
+        """Start the copies of chunk ``c`` of ``slot``: its live blocks,
+        each to its own place of ``half``."""
+        first = c * chunk
+        n = jnp.minimum(chunk, live_blocks(slot) - first)
 
-    @pl.when(jnp.logical_and(j == 0, live > 0))
+        def one(b):
+            pltpu.make_async_copy(
+                pool_ref.at[layer, bt_ref[slot, first + b]],
+                buf.at[half, pl.ds(pl.multiple_of(b * bs, bs), bs)],
+                sem.at[half]).start()
+
+        def group(g, carry):
+            for u in range(_COPY_UNROLL):
+                one(g * _COPY_UNROLL + u)
+            return carry
+
+        def single(b, carry):
+            one(b)
+            return carry
+
+        whole = n // _COPY_UNROLL
+        lax.fori_loop(0, whole, group, 0)
+        lax.fori_loop(whole * _COPY_UNROLL, n, single, 0)
+
+    def wait(n, half):
+        """Wait for the ``n`` blocks started into ``half``."""
+        k = 1
+        while k <= chunk:
+            @pl.when((n & k) != 0)
+            def _(k=k):
+                part = buf.at[half, pl.ds(0, k * bs)]
+                pltpu.make_async_copy(part, part, sem.at[half]).wait()
+            k *= 2
+
+    @pl.when(s == 0)
     def _first():
-        for cp in copies(0, 0):
-            cp.start()
+        half_scr[0] = 0
+        start(0, 0, 0)
 
-    @pl.when(j + 1 < live)
-    def _ahead():
-        for cp in copies(j + 1, (j + 1) % 2):
-            cp.start()
+    first_half = half_scr[0]
+    cl = cl_ref[s]
+    n_live = live_blocks(s)
+    n_chunks = (n_live + chunk - 1) // chunk
 
-    @pl.when(j < live)
-    def _chunk():
-        slot = j % 2
-        for cp in copies(j, slot):
-            cp.wait()
-        pos = j * span + lax.broadcasted_iota(
-            jnp.int32, (q_ref.shape[2], span), 1)
-        for g in range(n_kv):
-            k = buf[slot, :, pl.ds(g * LANE, LANE)]             # [span, 128]
-            v = buf[slot, :, pl.ds(kw + g * LANE, LANE)]
+    def chunk_step(c, carry):
+        half = (first_half + c) % 2
+        # what is computed next: this slot's next chunk, or after its last
+        # the next slot's first (none after the last slot's last)
+        last = c + 1 == n_chunks
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), s + 1 < n_slots))
+        def _ahead():
+            start(jnp.where(last, jnp.minimum(s + 1, n_slots - 1), s),
+                  jnp.where(last, 0, c + 1), 1 - half)
+
+        blocks = jnp.minimum(chunk, n_live - c * chunk)
+        wait(blocks, half)
+
+        def zero(b, carry):
+            buf[half, pl.ds(pl.multiple_of(b * bs, bs), bs), pl.ds(kw, kw)] \
+                = jnp.zeros((bs, kw), buf.dtype)
+            return carry
+
+        lax.fori_loop(blocks, chunk, zero, 0)
+        pos = c * span + lax.broadcasted_iota(jnp.int32, (QR, span), 1)
+        out = []
+        for g, (m, l, acc) in enumerate(carry):
+            k = buf[half, :, pl.ds(g * LANE, LANE)]             # [span, 128]
+            v = buf[half, :, pl.ds(kw + g * LANE, LANE)]
             sc = lax.dot_general(q_ref[0, g], k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
             sc = jnp.where(pos < cl, sc, NEG_INF)
-            m = m_scr[g]
             m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
             p = jnp.exp(sc - m_new)
             alpha = jnp.exp(m - m_new)
-            m_scr[g] = m_new
-            l_scr[g] = l_scr[g] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-            acc_scr[g] = acc_scr[g] * alpha + jnp.dot(
-                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            out.append((m_new,
+                        l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                        acc * alpha + jnp.dot(
+                            p.astype(v.dtype), v,
+                            preferred_element_type=jnp.float32)))
+        return tuple(out)
 
-    @pl.when(j == n_chunks - 1)
-    def _finish():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                    ).astype(o_ref.dtype)
+    heads = lax.fori_loop(0, n_chunks, chunk_step, tuple(
+        (jnp.full((QR, 1), NEG_INF, jnp.float32),
+         jnp.zeros((QR, 1), jnp.float32),
+         jnp.zeros((QR, LANE), jnp.float32)) for _ in range(n_kv)))
+    for g, (_, l, acc) in enumerate(heads):
+        o_ref[0, g] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    half_scr[0] = (first_half + n_chunks) % 2
 
 
 def _component_rows(q, n_kv: int, dtype):
@@ -163,40 +242,55 @@ def _component_rows(q, n_kv: int, dtype):
     return rows.astype(dtype)
 
 
-def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv, name):
-    S, nh, pair = q.shape
-    bs, width = pool.shape[2], pool.shape[3]
-    kw = width // 2
-    MB = block_tables.shape[1]
-    chunk = min(_CHUNK_BLOCKS, MB)
-    n_chunks = -(-MB // chunk)
-    bt = block_tables.astype(jnp.int32)
-    if n_chunks * chunk != MB:      # a ragged last chunk reads block 0
-        bt = jnp.pad(bt, ((0, 0), (0, n_chunks * chunk - MB)))
-    qs = (q.astype(jnp.float32) * (pair // 2) ** -0.5)
-    rows = _component_rows(qs, n_kv, pool.dtype)
+@functools.partial(jax.jit, static_argnames=("n_kv", "name", "chunk",
+                                             "interpret"))
+def _walk_call(rows, pool, bt, cl, layer, *, n_kv, name, chunk, interpret):
+    """``layer`` is an int32 scalar, prefetched with the tables, and the
+    call is a jitted function of its own: every call of one shape and name —
+    the scanned layers, and an unscanned layer beside them — shares ONE
+    trace and ONE lowering of the kernel."""
+    S = rows.shape[0]
     QR = rows.shape[2]
-    kernel = functools.partial(_decode_kernel, bs=bs, chunk=chunk,
-                               n_chunks=n_chunks, n_kv=n_kv, kw=kw)
+    bs, width = pool.shape[2], pool.shape[3]
     spec = pl.BlockSpec((1, n_kv, QR, LANE),
-                        lambda s, j, bt, cl, ly: (s, 0, 0, 0))
-    out = pl.pallas_call(
-        kernel,
+                        lambda s, bt, cl, ly: (s, 0, 0, 0))
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, bs=bs, chunk=chunk,
+                          max_blocks=bt.shape[1], n_kv=n_kv, kw=width // 2),
         name=name,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,
-            grid=(S, n_chunks),
+            grid=(S,),
             in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=spec,
             scratch_shapes=[pltpu.VMEM((2, chunk * bs, width), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, chunk)),
-                            pltpu.VMEM((n_kv, QR, 1), jnp.float32),
-                            pltpu.VMEM((n_kv, QR, 1), jnp.float32),
-                            pltpu.VMEM((n_kv, QR, LANE), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((S, n_kv, QR, LANE), jnp.float32),
-        interpret=pallas_interpret(),
-    )(bt, context_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), rows, pool)
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct(rows.shape, jnp.float32),
+        interpret=interpret,
+    )(bt, cl, layer.reshape(1), rows, pool)
+
+
+def paged_walk(rows, pool, block_tables, context_lens, layer, n_kv: int,
+               name: str):
+    """The paged decode walk under ``name``: query rows [S, n_kv, QR, 128]
+    (scaled, in the pool's dtype; a K/V head's QR rows share its key tile)
+    against each slot's live rows of ``pool[layer]`` → [S, n_kv, QR, 128]
+    float32, one softmax a row.  ``kernels/gqa.py`` calls it with a group's
+    queries as the rows."""
+    MB = block_tables.shape[1]
+    chunk = min(_CHUNK_BLOCKS, MB)
+    return _walk_call(rows, pool, block_tables.astype(jnp.int32),
+                      context_lens.astype(jnp.int32),
+                      jnp.asarray(layer, jnp.int32), n_kv=n_kv, name=name,
+                      chunk=chunk, interpret=pallas_interpret())
+
+
+def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv, name):
+    S, nh, pair = q.shape
+    qs = (q.astype(jnp.float32) * (pair // 2) ** -0.5)
+    out = paged_walk(_component_rows(qs, n_kv, pool.dtype), pool,
+                     block_tables, context_lens, layer, n_kv, name)
     group = nh // n_kv
     return out[:, :, :2 * group].reshape(S, nh, 2, pair)
 
@@ -351,6 +445,6 @@ def prefill_attention(q, rows, n_kv: int, window=None):
     return _flash_pallas(q, rows, n_kv, window)
 
 
-__all__ = ["decode_attention", "decode_attention_xla", "prefill_attention",
-           "prefill_attention_xla", "row_attention", "flash_tiles", "visible",
-           "LANE"]
+__all__ = ["decode_attention", "decode_attention_xla", "paged_walk",
+           "prefill_attention", "prefill_attention_xla", "row_attention",
+           "flash_tiles", "visible", "LANE"]

@@ -10,14 +10,14 @@ rotation; nothing here knows a position but the causal mask.
 
 - :func:`decode_attention` — one query token a slot against the slot's rows
   in a paged pool ``[L, NB, bs, 2·kw]``, handed over WHOLE with the layer as
-  a prefetched scalar.  The Pallas kernel (``gqa_paged_decode_attn``) is the
-  walk of ``diffattn``'s: a slot's context in chunks of ``_CHUNK_BLOCKS``
-  blocks fetched by explicit async copies, the next chunk in flight while
-  this one is computed, chunks past the context skipped — with a K/V head's
-  ``group`` queries as the rows (padded to eight) of ONE product with the
-  head's key tile, so a tile is read once for the whole group.  The XLA
-  fallback gathers a slot's whole table and counts into
-  ``attn.gqa_decode_fallbacks``.
+  a prefetched scalar.  The Pallas kernel (``gqa_paged_decode_attn``) is
+  ``diffattn.paged_walk``, the one paged walk there is for rows of this
+  layout: one grid step a slot, the slot's live blocks only in chunks
+  fetched by explicit async copies, the next chunk (or the next slot's
+  first) always in flight — with a K/V head's ``group`` queries as the rows
+  (padded to eight) of ONE product with the head's key tile, so a tile is
+  read once for the whole group.  The XLA fallback gathers a slot's whole
+  table and counts into ``attn.gqa_decode_fallbacks``.
 - :func:`prefill_attention` — a prompt's causal flash attention
   (``gqa_flash_fwd``): a grid step is one query head's tile against one tile
   of its K/V head's rows, tiles above the diagonal are neither fetched nor
@@ -36,12 +36,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..observability import stats as _obs_stats
 from ..platform import pallas_interpret
-from .diffattn import _decode_kernel
+from .diffattn import paged_walk
 
 NEG_INF = -1e30
 LANE = 128
-# blocks a chunk: 32 x 16 tokens = 512 rows of 1,024 bf16 lanes, 1 MB, twice
-_CHUNK_BLOCKS = 32
 _FLASH_BLOCK = 256
 
 
@@ -75,37 +73,11 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer,
 def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv):
     S, nh, dh = q.shape
     group = nh // n_kv
-    bs, width = pool.shape[2], pool.shape[3]
-    MB = block_tables.shape[1]
-    chunk = min(_CHUNK_BLOCKS, MB)
-    n_chunks = -(-MB // chunk)
-    bt = block_tables.astype(jnp.int32)
-    if n_chunks * chunk != MB:      # a ragged last chunk reads block 0
-        bt = jnp.pad(bt, ((0, 0), (0, n_chunks * chunk - MB)))
-    QR = -(-group // 8) * 8
     rows = (q.astype(jnp.float32) * dh ** -0.5).reshape(S, n_kv, group, dh)
-    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, QR - group), (0, 0))
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, -group % 8), (0, 0))
                    ).astype(pool.dtype)
-    spec = pl.BlockSpec((1, n_kv, QR, dh),
-                        lambda s, j, bt, cl, ly: (s, 0, 0, 0))
-    out = pl.pallas_call(
-        functools.partial(_decode_kernel, bs=bs, chunk=chunk,
-                          n_chunks=n_chunks, n_kv=n_kv, kw=width // 2),
-        name="gqa_paged_decode_attn",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(S, n_chunks),
-            in_specs=[spec, pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=spec,
-            scratch_shapes=[pltpu.VMEM((2, chunk * bs, width), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2, chunk)),
-                            pltpu.VMEM((n_kv, QR, 1), jnp.float32),
-                            pltpu.VMEM((n_kv, QR, 1), jnp.float32),
-                            pltpu.VMEM((n_kv, QR, dh), jnp.float32)]),
-        out_shape=jax.ShapeDtypeStruct((S, n_kv, QR, dh), jnp.float32),
-        interpret=pallas_interpret(),
-    )(bt, context_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), rows, pool)
+    out = paged_walk(rows, pool, block_tables, context_lens, layer, n_kv,
+                     "gqa_paged_decode_attn")
     return out[:, :, :group].reshape(S, nh, dh)
 
 

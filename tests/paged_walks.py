@@ -1,5 +1,6 @@
 """Cases for the paged decode kernel's walk over a slot's live blocks
 (tests/test_decode_plane.py: f32 pool; tests/test_quant_serving.py: int8)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -53,3 +54,12 @@ def walk_case(rng, contexts, MB, bs=4, H=4, D=64, L=3, poison=False):
     q = jnp.asarray(rng.randn(S, H, D).astype("float32"))
     return (q, jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(bt),
             jnp.asarray(np.asarray(contexts, "int32")))
+
+
+def eqns_under(jaxpr):
+    """Every equation under ``jaxpr``, those of scanned bodies and jitted
+    calls among them (a step program's ``pallas_call``s and their grids)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from eqns_under(sub)

@@ -21,8 +21,10 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.decode.falcon_h1 import (FalconH1Config, FalconH1LM,
                                          param_shapes)
+from paddle_tpu.kernels import diffattn as DK
 from paddle_tpu.kernels import gqa as GK
 from paddle_tpu.kernels import ssd as SK
+from paged_walks import eqns_under
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(REPO, "benchmark", "configs",
@@ -54,7 +56,7 @@ def one_chip():
 def mosaic(monkeypatch):
     """As on the chip: off it the kernels interpret themselves (compile
     them), and tier-1 turns x64 on (the chip's processes never do)."""
-    for mod in (GK, SK):
+    for mod in (DK, GK, SK):
         monkeypatch.setattr(mod, "pallas_interpret", lambda: False)
     with jax.enable_x64(False):
         yield
@@ -129,3 +131,16 @@ def test_pool_and_rows_of_every_layer_are_neither_copied_nor_relaid(
         + mem.output_size_in_bytes - mem.alias_size_in_bytes
     assert 13.7e9 < live <= FITS_BYTES, live
     assert mem.temp_size_in_bytes < 0.6e9, mem.temp_size_in_bytes
+
+
+def test_the_step_s_walk_steps_by_slot(one_chip, mosaic):
+    """The scanned layers' paged walk takes one grid step a slot (the walk
+    that stepped by chunk took ``S x MB / 32`` = ``S x 8``); what a slot's
+    step fetches it reads from ``context_lens`` (the parity tests)."""
+    fn, feed, state, plist = _shapes(one_chip, None)
+
+    grids = [tuple(e.params["grid_mapping"].grid) for e in eqns_under(
+        jax.make_jaxpr(fn)(feed, state, plist).jaxpr)
+        if e.primitive.name == "pallas_call"
+        and e.params["name"] == "gqa_paged_decode_attn"]
+    assert len(grids) == 1 and int(np.prod(grids[0])) <= S, grids

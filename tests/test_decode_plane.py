@@ -592,6 +592,65 @@ def test_the_table_walk_observer_counts_a_step_by_hand():
                                "step_table_blocks": 64}
 
 
+def _tiny_sambay():
+    from paddle_tpu.decode.sambay import SambaYConfig, SambaYLM
+    # 8 layers: two window layers (rings of 32 rows: two 16-row blocks), the
+    # full layer and one cross layer (two readers of the pool)
+    return SambaYLM(SambaYConfig(
+        vocab_size=96, hidden_size=256, num_hidden_layers=8,
+        num_attention_heads=4, num_key_value_heads=2, intermediate_size=384,
+        sliding_window=32, max_seq_len=96, dtype="float32"))
+
+
+def _tiny_falcon_h1():
+    from paddle_tpu.decode.falcon_h1 import FalconH1Config, FalconH1LM
+    return FalconH1LM(FalconH1Config(        # three layers, each a reader
+        vocab_size=96, hidden_size=64, num_hidden_layers=3,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        intermediate_size=128, mamba_d_ssm=64, mamba_n_heads=4,
+        mamba_d_head=16, mamba_n_groups=2, mamba_d_state=32, mamba_d_conv=4,
+        mamba_chunk_size=8, max_seq_len=96, dtype="float32"))
+
+
+# model → (pool readers, window layers, blocks a ring)
+HYBRIDS = {"sambay": (_tiny_sambay, 2, 2, 2),
+           "falcon_h1": (_tiny_falcon_h1, 3, 0, 0)}
+
+
+@pytest.mark.parametrize("which", sorted(HYBRIDS))
+def test_a_hybrid_model_s_walks_are_counted_over_every_reading_layer(which):
+    """``step_live_blocks`` / ``step_table_blocks`` of the two hybrid
+    models: every layer that reads the pool walks a live stream's blocks up
+    to its context of the table's six, every window layer its ring's up to
+    ``min(context, W)`` of the ring's two, and each one block of the idle
+    slot.  One stream alone on two slots of 16-token blocks: a prompt of 13
+    and 24 tokens more are 23 steps at contexts 14..36."""
+    make, readers, rings, ring_blocks = HYBRIDS[which]
+    lm = make()
+    eng = DecodeEngine(lm, lm.init_params(1), name=f"walk_{which}",
+                       max_slots=2, block_tokens=16, num_blocks=14,
+                       prefill_buckets=[16], prefix_cache=False,
+                       overcommit=False)
+    try:
+        assert eng.decodez()["step_table_blocks"] == 0
+        eng.generate(np.arange(13, dtype=np.int32), max_new_tokens=24)
+        assert eng.drain(timeout=120)
+        z = eng.decodez()
+        assert z["steps"] == 23 and eng.max_blocks_per_seq == 6
+        assert z["step_table_blocks"] == 23 * 2 * (
+            readers * 6 + rings * ring_blocks)
+        # contexts 14..16 hold one block, 17..32 two, 33..36 three; a ring
+        # one block to 16 rows and both from there on; the idle slot one
+        pool = 3 * 1 + 16 * 2 + 4 * 3 + 23
+        ring = 3 * 1 + 20 * 2 + 23
+        assert z["step_live_blocks"] == readers * pool + rings * ring
+        snap = obs.stats.default_registry().snapshot()
+        for key in ("step_live_blocks", "step_table_blocks"):
+            assert snap[f"decode.walk_{which}.{key}"] == z[key]
+    finally:
+        eng.close()
+
+
 def test_cancel_frees_slot_and_blocks_mid_stream():
     lm, params, eng = _engine("cancel")
     try:

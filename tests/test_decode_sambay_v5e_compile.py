@@ -22,6 +22,7 @@ from jax.sharding import SingleDeviceSharding
 from paddle_tpu.decode.sambay import SambaYConfig, SambaYLM, param_shapes
 from paddle_tpu.kernels import diffattn as DK
 from paddle_tpu.kernels import ssm as SK
+from paged_walks import eqns_under
 
 CFG = SambaYConfig(
     vocab_size=1024, hidden_size=2560, num_hidden_layers=8,
@@ -103,3 +104,24 @@ def test_pool_rings_and_recurrent_rows_are_neither_copied_nor_relaid(
     # of the cross layers (scanned); prefill: the selective scan (scanned,
     # and layer L/2's), the window flash (scanned) and the full flash
     assert calls == (3 if bucket is None else 4), calls
+
+
+def test_the_step_s_three_walks_step_by_slot_and_the_pool_s_share_a_trace(
+        one_chip, mosaic):
+    """The rings' kernel, the full layer's and the cross layers' take one
+    grid step a slot (the walk that stepped by chunk took ``S x MB / 32``:
+    ``S x 16`` over the pool) — what a slot's step fetches it reads from
+    ``context_lens`` (the parity tests) — and the layer reaches the kernel as
+    a prefetched scalar, so the full layer and the scanned cross layers are
+    ONE traced function, lowered to Mosaic once."""
+    fn, feed, state, plist = _shapes(one_chip, None)
+    eqns = list(eqns_under(jax.make_jaxpr(fn)(feed, state, plist).jaxpr))
+    grids = [(e.params["name"], tuple(e.params["grid_mapping"].grid))
+             for e in eqns if e.primitive.name == "pallas_call"]
+    assert sorted(name for name, _ in grids) == [
+        "diff_paged_decode_attn"] * 2 + ["diff_ring_decode_attn"]
+    assert all(int(np.prod(g)) <= S for _, g in grids), grids
+    walks = [e.params["jaxpr"] for e in eqns
+             if e.primitive.name in ("pjit", "jit")
+             and e.params["name"] == "_walk_call"]
+    assert len(walks) == 3 and len({id(w) for w in walks}) == 2

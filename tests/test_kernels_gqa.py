@@ -8,6 +8,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.decode.falcon_h1 import rotary
+from paddle_tpu.kernels import diffattn as da
 from paddle_tpu.kernels import gqa
 from paddle_tpu.observability import stats
 from paged_walks import WALKS, walk_case
@@ -40,7 +41,7 @@ def test_paged_decode_kernel_walks_only_a_slot_s_live_rows(monkeypatch, walk):
     """Contexts by where they end against a chunk of the walk, idle slots
     (one token, an all-trash table) among them, in adjacent slots."""
     chunk, bs, MB = 2, 8, 6
-    monkeypatch.setattr(gqa, "_CHUNK_BLOCKS", chunk)
+    monkeypatch.setattr(da, "_CHUNK_BLOCKS", chunk)
     rng = np.random.RandomState(3)
     contexts = WALKS[walk](chunk * bs, MB * bs)
     _, kc, vc, bt, cl = walk_case(rng, contexts, MB, bs=bs, H=NKV, D=DH, L=3)

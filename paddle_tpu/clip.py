@@ -4,7 +4,10 @@ from __future__ import annotations
 
 from typing import List, Optional
 
-from .core.program import OP_ROLE_ATTR, OpRole
+from .core.program import GRAD_REWRITE_ATTR, OP_ROLE_ATTR, OpRole
+
+# Backward by role, the optimizer's by what they do (program.py)
+_REWRITE = {OP_ROLE_ATTR: OpRole.Backward, GRAD_REWRITE_ATTR: True}
 
 
 class BaseGradientClipAttr:
@@ -23,7 +26,7 @@ class GradientClipByValue(BaseGradientClipAttr):
                                dtype=grad.dtype, type=grad.type)
         block.append_op("clip", {"X": [grad.name]}, {"Out": [out.name]},
                         {"min": self.min, "max": self.max,
-                         OP_ROLE_ATTR: OpRole.Backward})
+                         **_REWRITE})
         return param, out
 
 
@@ -37,7 +40,7 @@ class GradientClipByNorm(BaseGradientClipAttr):
                                dtype=grad.dtype, type=grad.type)
         block.append_op("clip_by_norm", {"X": [grad.name]}, {"Out": [out.name]},
                         {"max_norm": self.clip_norm,
-                         OP_ROLE_ATTR: OpRole.Backward})
+                         **_REWRITE})
         return param, out
 
 
@@ -60,23 +63,23 @@ class GradientClipByGlobalNorm(BaseGradientClipAttr):
         for p, g in params_grads:
             sq = block.create_var(name=g.name + "@SQSUM", shape=(), dtype="float32")
             block.append_op("__global_norm_sq__", {"X": [g.name]},
-                            {"Out": [sq.name]}, {OP_ROLE_ATTR: OpRole.Backward})
+                            {"Out": [sq.name]}, dict(_REWRITE))
             sq_names.append(sq.name)
         total = block.create_var(name="@GLOBAL_NORM_SQ@" + params_grads[0][1].name,
                                  shape=(), dtype="float32")
         block.append_op("sum", {"X": sq_names}, {"Out": [total.name]},
-                        {OP_ROLE_ATTR: OpRole.Backward})
+                        dict(_REWRITE))
         factor = block.create_var(name=total.name + "@FACTOR", shape=(),
                                   dtype="float32")
         block.append_op("__global_norm_factor__", {"X": [total.name]},
                         {"Out": [factor.name]},
-                        {"clip_norm": self.clip_norm, OP_ROLE_ATTR: OpRole.Backward})
+                        {"clip_norm": self.clip_norm, **_REWRITE})
         out = []
         for p, g in params_grads:
             ng = block.create_var(name=g.name + "@CLIP", shape=g.shape,
                                   dtype=g.dtype, type=g.type)
             block.append_op("elementwise_mul", {"X": [g.name], "Y": [factor.name]},
-                            {"Out": [ng.name]}, {OP_ROLE_ATTR: OpRole.Backward})
+                            {"Out": [ng.name]}, dict(_REWRITE))
             out.append((p, ng))
         return out
 

@@ -65,6 +65,16 @@ from ..observability import trace as _obs_trace
 
 MAGIC = b"PTCC1\0"
 FORMAT_VERSION = 1
+# Version of the scope grammar ``core/lowering.py op_scope`` names every
+# lowered instruction by.  The names are metadata: the ProgramDesc of a
+# program without ``name_scope`` does not change with them, and JAX leaves
+# metadata out of its persistent cache's key
+# (``jax_compilation_cache_include_metadata_in_key`` is off; on, every
+# source line that moves would miss).  Without this an executable compiled
+# before its scopes is handed back in their place and a trace of it names
+# nothing.  Tier A carries the version in :func:`_env_digest`, tier B in the
+# compiled program's name (:func:`program_name`).  Bump it with the grammar.
+SCOPE_GRAMMAR = 1
 ENTRY_SUFFIX = ".ptcc"
 _HEADER_LEN = struct.Struct("<I")
 
@@ -181,6 +191,13 @@ def wire_jax_cache() -> str:
     return d
 
 
+def program_name(base: str) -> str:
+    """The name a lowered block is jitted under: JAX's persistent cache keys
+    on the module's name and not on its metadata, so the scope grammar's
+    version rides in the name (``jit_fn_s1``)."""
+    return f"{base}_s{SCOPE_GRAMMAR}"
+
+
 def _on_jax_event(event: str, **_kw) -> None:
     global _jax_hits
     if event == "/jax/compilation_cache/cache_hits":
@@ -210,6 +227,7 @@ def _env_digest() -> str:
             "platform": jax.default_backend(),
             "device_count": jax.device_count(),
             "x64": bool(jax.config.jax_enable_x64),
+            "scope_grammar": SCOPE_GRAMMAR,
         }
         _env_digest_cache = hashlib.sha256(
             json.dumps(env, sort_keys=True).encode()).hexdigest()

@@ -1109,6 +1109,8 @@ class Executor:
                 return fetches, final_state, rng
 
             multi._sparse_fused_used = fn._sparse_fused_used
+            multi.__name__ = multi.__qualname__ = \
+                _compile_cache.program_name("multi")
             return multi
         return build
 

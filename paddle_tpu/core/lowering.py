@@ -9,15 +9,29 @@ every op is traced through its registered lowering rule into a single
 JIT-compiles and fuses end-to-end.  Data-dependence ordering, memory reuse,
 kernel fusion, and stream scheduling — everything ``details/`` did by hand —
 is delegated to the XLA compiler.
+
+Every op is traced under ONE ``jax.named_scope``, so device time carries the
+program's names: ``<role>/<op_namescope...>/<op.type>[/<param>]`` with role
+``fwd`` | ``bwd`` | ``opt`` (:func:`op_scope`; a sub-block's ops inherit the
+role of their control-flow op).  A grad op that re-traces its forward
+(``registry.vjp_grad``, ``scoped_vjp``) traces the primal half of its
+``jax.vjp`` under the FORWARD op's scope and only the cotangent half under its
+own ``bwd/...``: XLA's CSE merges the re-traced forward with the original and
+keeps either one's metadata, so both copies must be named alike for the
+forward/backward split to be true.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence
 
-from . import registry
-from .program import Program, Block, EMPTY_VAR
+import jax
+
+from . import compile_cache, registry
+from .program import (Program, Block, EMPTY_VAR, GRAD_REWRITE_ATTR,
+                      OP_ROLE_ATTR, OP_ROLE_VAR_ATTR, OpRole)
 from .registry import GRAD_OP_SUFFIX, LowerContext
 from ..observability import stats as _obs_stats
 from ..observability import trace as _obs_trace
@@ -119,8 +133,59 @@ def _analyze_block(program: Program, block_idx: int,
     return plan
 
 
+def op_scope(op, top: bool = True, type: Optional[str] = None) -> str:
+    """The ``jax.named_scope`` an op is traced under — a function of the op
+    alone, so two lowerings of a program name their instructions alike.
+    ``top``: the op lies in the block the executor was handed (a sub-block's
+    ops carry no role of their own).  ``type``: name the scope after this op
+    type instead (the forward op's, for the primal half of a grad op)."""
+    parts = []
+    param = None
+    if top:
+        role = int(op.attr(OP_ROLE_ATTR, OpRole.Forward)) & ~OpRole.Loss
+        if type is not None or role == OpRole.Forward:
+            parts.append("fwd")
+        elif role == OpRole.Backward and not op.attr(GRAD_REWRITE_ATTR):
+            parts.append("bwd")
+        else:       # Optimize, LRSched, clipping, regularisation, RPC, Dist
+            parts.append("opt")
+            param = (op.attr(OP_ROLE_VAR_ATTR) or (None,))[0]
+    ns = (op.attr("op_namescope") or "").strip("/")
+    if ns:
+        parts.append(ns)
+    parts.append(type or op.type)
+    if param:
+        parts.append(param)
+    return "/".join(parts)
+
+
+def _retraced_forward(op) -> Optional[registry.OpDef]:
+    """The op whose forward lowering this grad op re-traces under ``jax.vjp``
+    (the default rule, or a rule of its own that says so), else None."""
+    if not op.type.endswith(GRAD_OP_SUFFIX) or registry.has(op.type):
+        return None
+    base_type = op.type[: -len(GRAD_OP_SUFFIX)]
+    if not registry.has(base_type):
+        return None
+    base = registry.get(base_type)
+    return base if base.grad is None or base.grad_retraces else None
+
+
+@contextlib.contextmanager
+def _halves_named(ctx: LowerContext, fwd_scope: str, bwd_scope: str):
+    """No scope around the grad op as a whole: ``registry.scoped_vjp`` names
+    the re-traced forward as the original was, and the cotangent half as the
+    grad op, so the copy that XLA's CSE keeps is named right either way."""
+    ctx.grad_scopes = (fwd_scope, bwd_scope)
+    try:
+        yield
+    finally:
+        ctx.grad_scopes = ("", "")
+
+
 def lower_ops(ctx: LowerContext, program: Program, block: Block, env: Dict) -> Dict:
-    """Trace every op in ``block`` through its lowering rule, mutating env."""
+    """Trace every op in ``block`` through its lowering rule, each under its
+    :func:`op_scope`, mutating env."""
     from ..ops.control_flow_ops import CONTROL_FLOW_OPS
 
     # FLAGS_sparse_fused_kernel peephole: lookup_table ops sharing one Ids
@@ -141,53 +206,60 @@ def lower_ops(ctx: LowerContext, program: Program, block: Block, env: Dict) -> D
     int8_plan = (_quant_kernels.plan_int8(block)
                  if _quant_kernels.enabled_for(ctx) else None)
 
+    top = block.parent_idx < 0
     for pos, op in enumerate(block.ops):
         if op.type in SKIP_OPS:
             continue
-        if fusion is not None and fusion.covers(pos) and fusion.lower(pos, env):
-            ctx.sparse_fused_used = True
-            continue
-        if int8_plan is not None and int8_plan.covers(pos) \
-                and int8_plan.lower(pos, env):
-            ctx.int8_fused_used = True
-            continue
-        if op.type in CONTROL_FLOW_OPS:
-            try:
-                CONTROL_FLOW_OPS[op.type](ctx, program, op, env, lower_ops)
-            except Exception as e:
-                raise type(e)(
-                    f"while lowering control-flow op {op!r} in block "
-                    f"{block.idx}: {e}") from e
-            continue
-        ins = {}
-        for slot, names in op.inputs.items():
-            if slot.endswith("@GRAD"):
-                # grad slots keep positional alignment; missing grads → None
-                vals = [env.get(n) if n and n != EMPTY_VAR else None for n in names]
-                if any(v is not None for v in vals):
-                    ins[slot] = vals
-            else:
-                vals = [env[n] for n in names if n and n != EMPTY_VAR]
-                if vals:
-                    ins[slot] = vals
-        try:
-            if op.type.endswith(GRAD_OP_SUFFIX) and not registry.has(op.type):
-                base = registry.get(op.type[: -len(GRAD_OP_SUFFIX)])
-                if base.grad is not None:
-                    outs = base.grad(ctx, ins, op.attrs)
-                else:
-                    outs = registry.vjp_grad(base, ctx, ins, op.attrs)
-            else:
-                outs = registry.get(op.type).lower(ctx, ins, op.attrs)
-        except Exception as e:
-            raise type(e)(f"while lowering op {op!r} in block {block.idx}: {e}") from e
-        for slot, names in op.outputs.items():
-            vals = outs.get(slot)
-            if vals is None:
+        scope = op_scope(op, top)
+        forward = (None if op.type in CONTROL_FLOW_OPS
+                   else _retraced_forward(op))
+        with (jax.named_scope(scope) if forward is None else _halves_named(
+                ctx, op_scope(op, top, type=forward.type), scope)):
+            if fusion is not None and fusion.covers(pos) \
+                    and fusion.lower(pos, env):
+                ctx.sparse_fused_used = True
                 continue
-            for name, val in zip(names, vals):
-                if name and name != EMPTY_VAR and val is not None:
-                    env[name] = val
+            if int8_plan is not None and int8_plan.covers(pos) \
+                    and int8_plan.lower(pos, env):
+                ctx.int8_fused_used = True
+                continue
+            if op.type in CONTROL_FLOW_OPS:
+                try:
+                    CONTROL_FLOW_OPS[op.type](ctx, program, op, env, lower_ops)
+                except Exception as e:
+                    raise type(e)(
+                        f"while lowering control-flow op {op!r} in block "
+                        f"{block.idx}: {e}") from e
+                continue
+            ins = {}
+            for slot, names in op.inputs.items():
+                if slot.endswith("@GRAD"):
+                    # grad slots keep positional alignment; missing grads → None
+                    vals = [env.get(n) if n and n != EMPTY_VAR else None for n in names]
+                    if any(v is not None for v in vals):
+                        ins[slot] = vals
+                else:
+                    vals = [env[n] for n in names if n and n != EMPTY_VAR]
+                    if vals:
+                        ins[slot] = vals
+            try:
+                if op.type.endswith(GRAD_OP_SUFFIX) and not registry.has(op.type):
+                    base = registry.get(op.type[: -len(GRAD_OP_SUFFIX)])
+                    if base.grad is not None:
+                        outs = base.grad(ctx, ins, op.attrs)
+                    else:
+                        outs = registry.vjp_grad(base, ctx, ins, op.attrs)
+                else:
+                    outs = registry.get(op.type).lower(ctx, ins, op.attrs)
+            except Exception as e:
+                raise type(e)(f"while lowering op {op!r} in block {block.idx}: {e}") from e
+            for slot, names in op.outputs.items():
+                vals = outs.get(slot)
+                if vals is None:
+                    continue
+                for name, val in zip(names, vals):
+                    if name and name != EMPTY_VAR and val is not None:
+                        env[name] = val
     return env
 
 
@@ -242,4 +314,5 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
         return fetches, new_state, ctx.rng_key
 
     fn._sparse_fused_used = used
+    fn.__name__ = fn.__qualname__ = compile_cache.program_name("fn")
     return fn

@@ -37,6 +37,12 @@ EMPTY_VAR = "@EMPTY@"  # positional placeholder for absent optional args
 # paddle/fluid/framework/op_proto_maker.cc, op_role/op_role_var attrs).
 OP_ROLE_ATTR = "op_role"
 OP_ROLE_VAR_ATTR = "op_role_var"
+# Gradient clipping and regularisation rewrite a gradient between the
+# backward pass and the optimizer.  By role they are Backward (they stay
+# on the trainer under the distribute transpiler); by what they do they are
+# part of the optimizer step, and the lowering's scopes (core/lowering.py
+# ``op_scope``) file an op that carries this attribute under ``opt``.
+GRAD_REWRITE_ATTR = "grad_rewrite"
 
 
 class OpRole:
@@ -151,8 +157,14 @@ class _NameScope:
         self._parent = parent
 
     def child(self, prefix: str) -> "_NameScope":
-        n = self._children.get(prefix, 0)
-        self._children[prefix] = n + 1
+        if self._parent is None:
+            # the root's siblings are counted with the unique names, so a
+            # program built under ``unique_name.guard()`` gets the model's
+            # own scope names however many were built before it
+            n = unique_name.count("name_scope/" + prefix)
+        else:
+            n = self._children.get(prefix, 0)
+            self._children[prefix] = n + 1
         return _NameScope(prefix if n == 0 else f"{prefix}_{n}", self)
 
 

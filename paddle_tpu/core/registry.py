@@ -17,6 +17,7 @@ GradOpDescMaker, ``grad_op_desc_maker.h``).
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 from typing import Any, Callable, Dict, List, Optional, Sequence
 
@@ -46,6 +47,9 @@ class LowerContext:
         self._rng_key0 = None
         self._rng_used = False
         self._lower_block_fn = lower_block_fn  # (block_idx, env) -> env
+        # (forward op's scope, grad op's own) while lower_ops dispatches a
+        # grad op that re-traces its forward: see scoped_vjp
+        self.grad_scopes = ("", "")
 
     def set_rng(self, key):
         self._rng_key = key
@@ -103,6 +107,7 @@ class OpDef:
         self.type = type
         self.lower = lower
         self.grad = grad            # custom grad lowering, else vjp default
+        self.grad_retraces = False  # the custom rule re-traces the forward
         self.stateful = stateful    # consumes rng / host state → needs custom grad
         self.input_slots = list(input_slots) if input_slots else None
         self.output_slots = list(output_slots) if output_slots else None
@@ -141,15 +146,19 @@ def register(
     return deco
 
 
-def register_grad(type: str):
+def register_grad(type: str, retraces: bool = False):
     """Decorator: attach a custom grad rule to an already-registered op.
 
     Signature: ``grad(ctx, ins, attrs) -> {in_slot + '@GRAD': [vals]}`` where
     ``ins`` contains the forward ins, forward outs, and ``slot@GRAD`` entries.
+    ``retraces``: the rule differentiates a re-trace of the forward lowering
+    (through :func:`scoped_vjp` or :func:`vjp_grad`), which names its own two
+    halves; any other rule is traced whole under the grad op's scope.
     """
 
     def deco(fn):
         _REGISTRY[type].grad = fn
+        _REGISTRY[type].grad_retraces = retraces
         return fn
 
     return deco
@@ -173,6 +182,28 @@ def all_ops() -> List[str]:
 # Default (vjp-based) grad lowering
 # ---------------------------------------------------------------------------
 
+def _scope(name: str):
+    return jax.named_scope(name) if name else contextlib.nullcontext()
+
+
+def scoped_vjp(ctx: LowerContext, fwd: Callable, *primals):
+    """``jax.vjp`` of a re-traced forward lowering, its two halves named
+    apart: the primal half under the FORWARD op's scope, the cotangent half
+    under the grad op's own (``ctx.grad_scopes``, set by ``lower_ops``).
+    XLA's CSE merges the re-traced forward with the original and keeps either
+    one's metadata, so both copies have to be named alike or a forward
+    matmul's time lands under ``bwd`` by the compiler's choice."""
+    fwd_scope, bwd_scope = ctx.grad_scopes
+    with _scope(fwd_scope):
+        out, vjp_fn = jax.vjp(fwd, *primals)
+
+    def pull(*cotangents):
+        with _scope(bwd_scope):
+            return vjp_fn(*cotangents)
+
+    return out, pull
+
+
 def vjp_grad(opdef: OpDef, ctx: LowerContext, ins: SlotVals, attrs: dict) -> SlotVals:
     """Differentiate the forward lowering rule with jax.vjp.
 
@@ -181,8 +212,10 @@ def vjp_grad(opdef: OpDef, ctx: LowerContext, ins: SlotVals, attrs: dict) -> Slo
     ``slot@GRAD`` for each differentiable forward input slot.  Integer and
     ``no_grad_slots`` inputs are held constant.  The forward is re-traced
     inside vjp; within one jitted block XLA CSE merges it with the original
-    forward, so there is no duplicated compute at run time.
+    forward, so there is no duplicated compute at run time (and
+    :func:`scoped_vjp` names the copy as the original).
     """
+    bwd_scope = ctx.grad_scopes[1]
     fwd_out_slots = set(attrs.get("__fwd_out_slots__", ()))
     if opdef.output_slots:
         fwd_out_slots |= set(opdef.output_slots)
@@ -207,7 +240,7 @@ def vjp_grad(opdef: OpDef, ctx: LowerContext, ins: SlotVals, attrs: dict) -> Slo
         fwd_attrs = {k: v for k, v in attrs.items() if not k.startswith("__")}
         return opdef.lower(ctx, full, fwd_attrs)
 
-    primals_out, vjp_fn = jax.vjp(fwd, {s: ins[s] for s in diff_slots})
+    primals_out, vjp_fn = scoped_vjp(ctx, fwd, {s: ins[s] for s in diff_slots})
 
     def make_cot(path_slot, j, primal):
         g_list = ins.get(path_slot + "@GRAD")
@@ -222,9 +255,10 @@ def vjp_grad(opdef: OpDef, ctx: LowerContext, ins: SlotVals, attrs: dict) -> Slo
         import numpy as _np
         return _np.zeros(jnp.shape(primal), dtype=jax.dtypes.float0)
 
-    cot = {
-        s: [make_cot(s, j, p) for j, p in enumerate(vals)]
-        for s, vals in primals_out.items()
-    }
+    with _scope(bwd_scope):
+        cot = {
+            s: [make_cot(s, j, p) for j, p in enumerate(vals)]
+            for s, vals in primals_out.items()
+        }
     (grads,) = vjp_fn(cot)
     return {s + "@GRAD": list(v) for s, v in grads.items()}

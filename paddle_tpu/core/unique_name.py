@@ -7,9 +7,14 @@ import contextlib
 _counters: dict = collections.defaultdict(int)
 
 
-def generate(key: str) -> str:
+def count(key: str) -> int:
+    """How often ``key`` was counted before, in the current namespace."""
     _counters[key] += 1
-    return f"{key}_{_counters[key] - 1}"
+    return _counters[key] - 1
+
+
+def generate(key: str) -> str:
+    return f"{key}_{count(key)}"
 
 
 def reset() -> None:

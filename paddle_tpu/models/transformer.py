@@ -11,6 +11,13 @@ MXU.  Padding handled by an additive attention bias computed from the
 ParallelExecutor, BuildStrategy.sharding_rules can shard the FFN and
 attention projection weights over an ``mp`` axis (tensor parallelism) while
 the batch is dp-sharded.
+
+Blocks are named with ``fluid.name_scope`` (a debug attribute of the ops:
+parameters, variables and arithmetic are untouched), which the lowering
+turns into the scope path device time is filed under (``core/lowering.py``):
+``src_embed``, ``tgt_embed``, ``enc_<i>`` / ``dec_<i>`` with ``self_attn``,
+``cross_attn``, ``ffn`` inside (a sub-layer's residual dropout and layer norm
+belong to it), ``out_proj``, ``loss``.
 """
 from __future__ import annotations
 
@@ -133,25 +140,31 @@ def _residual(x, sub, dropout_rate, prefix):
 
 def encoder_layer(x, bias, d_model, n_head, d_ffn, dropout, prefix,
                   kv_mask=None, impl="base"):
-    attn = multi_head_attention(x, x, x, bias, d_model, n_head, dropout,
-                                f"{prefix}.attn", kv_mask=kv_mask, impl=impl)
-    x = _residual(x, attn, dropout, f"{prefix}.attn")
-    f = ffn(x, d_model, d_ffn, f"{prefix}.ffn")
-    return _residual(x, f, dropout, f"{prefix}.ffn")
+    with fluid.name_scope("self_attn"):
+        attn = multi_head_attention(x, x, x, bias, d_model, n_head, dropout,
+                                    f"{prefix}.attn", kv_mask=kv_mask,
+                                    impl=impl)
+        x = _residual(x, attn, dropout, f"{prefix}.attn")
+    with fluid.name_scope("ffn"):
+        f = ffn(x, d_model, d_ffn, f"{prefix}.ffn")
+        return _residual(x, f, dropout, f"{prefix}.ffn")
 
 
 def decoder_layer(x, enc_out, self_bias, cross_bias, d_model, n_head, d_ffn,
                   dropout, prefix, src_mask=None, tgt_mask=None, impl="base"):
-    attn = multi_head_attention(x, x, x, self_bias, d_model, n_head, dropout,
-                                f"{prefix}.self", kv_mask=tgt_mask,
-                                causal=True, impl=impl)
-    x = _residual(x, attn, dropout, f"{prefix}.self")
-    cross = multi_head_attention(x, enc_out, enc_out, cross_bias, d_model,
-                                 n_head, dropout, f"{prefix}.cross",
-                                 kv_mask=src_mask, impl=impl)
-    x = _residual(x, cross, dropout, f"{prefix}.cross")
-    f = ffn(x, d_model, d_ffn, f"{prefix}.ffn")
-    return _residual(x, f, dropout, f"{prefix}.ffn")
+    with fluid.name_scope("self_attn"):
+        attn = multi_head_attention(x, x, x, self_bias, d_model, n_head,
+                                    dropout, f"{prefix}.self",
+                                    kv_mask=tgt_mask, causal=True, impl=impl)
+        x = _residual(x, attn, dropout, f"{prefix}.self")
+    with fluid.name_scope("cross_attn"):
+        cross = multi_head_attention(x, enc_out, enc_out, cross_bias, d_model,
+                                     n_head, dropout, f"{prefix}.cross",
+                                     kv_mask=src_mask, impl=impl)
+        x = _residual(x, cross, dropout, f"{prefix}.cross")
+    with fluid.name_scope("ffn"):
+        f = ffn(x, d_model, d_ffn, f"{prefix}.ffn")
+        return _residual(x, f, dropout, f"{prefix}.ffn")
 
 
 def _embed(ids, mask, vocab, d_model, max_len, prefix, dtype):
@@ -185,28 +198,37 @@ def transformer(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab, tgt_vocab,
         tgt_mask, n_head, T_tgt, causal=True)
     dec_cross_bias = None if fused else _attn_bias_from_mask(src_mask, n_head, T_tgt)
 
-    enc = _embed(src_ids, src_mask3, src_vocab, d_model, max_len, "src", dtype)
-    if dropout:
-        enc = fluid.layers.dropout(
-            enc, dropout, dropout_implementation="upscale_in_train")
+    with fluid.name_scope("src_embed"):
+        enc = _embed(src_ids, src_mask3, src_vocab, d_model, max_len, "src",
+                     dtype)
+        if dropout:
+            enc = fluid.layers.dropout(
+                enc, dropout, dropout_implementation="upscale_in_train")
     for i in range(n_layer):
-        enc = encoder_layer(enc, enc_bias, d_model, n_head, d_ffn, dropout,
-                            f"enc.{i}", kv_mask=src_mask, impl=attention_impl)
+        # the index is spelled out: name_scope would rename a repeated
+        # prefix itself (enc, enc_1), and not from zero
+        with fluid.name_scope(f"enc_{i}"):
+            enc = encoder_layer(enc, enc_bias, d_model, n_head, d_ffn,
+                                dropout, f"enc.{i}", kv_mask=src_mask,
+                                impl=attention_impl)
 
-    dec = _embed(tgt_ids, tgt_mask3, tgt_vocab, d_model, max_len, "tgt", dtype)
-    if dropout:
-        dec = fluid.layers.dropout(
-            dec, dropout, dropout_implementation="upscale_in_train")
+    with fluid.name_scope("tgt_embed"):
+        dec = _embed(tgt_ids, tgt_mask3, tgt_vocab, d_model, max_len, "tgt",
+                     dtype)
+        if dropout:
+            dec = fluid.layers.dropout(
+                dec, dropout, dropout_implementation="upscale_in_train")
     for i in range(n_layer):
-        dec = decoder_layer(dec, enc, dec_self_bias, dec_cross_bias, d_model,
-                            n_head, d_ffn, dropout, f"dec.{i}",
-                            src_mask=src_mask, tgt_mask=tgt_mask,
-                            impl=attention_impl)
+        with fluid.name_scope(f"dec_{i}"):
+            dec = decoder_layer(dec, enc, dec_self_bias, dec_cross_bias,
+                                d_model, n_head, d_ffn, dropout, f"dec.{i}",
+                                src_mask=src_mask, tgt_mask=tgt_mask,
+                                impl=attention_impl)
 
-    logits = fluid.layers.fc(
-        dec, tgt_vocab, num_flatten_dims=2, bias_attr=False,
-        param_attr=fluid.ParamAttr(name="tgt.out_proj"))
-    return logits
+    with fluid.name_scope("out_proj"):
+        return fluid.layers.fc(
+            dec, tgt_vocab, num_flatten_dims=2, bias_attr=False,
+            param_attr=fluid.ParamAttr(name="tgt.out_proj"))
 
 
 def build(src_vocab=30000, tgt_vocab=30000, max_len=64, d_model=512,
@@ -228,13 +250,14 @@ def build(src_vocab=30000, tgt_vocab=30000, max_len=64, d_model=512,
     logits = transformer(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab,
                          tgt_vocab, max_len, d_model, n_head, d_ffn, n_layer,
                          dropout, dtype, attention_impl)
-    lbl = fluid.layers.unsqueeze(lbl_ids, [2])
-    loss = fluid.layers.softmax_with_cross_entropy(logits, lbl)  # [B,T,1]
-    loss = fluid.layers.squeeze(loss, [2])
-    masked = fluid.layers.elementwise_mul(loss, tgt_mask)
-    tok_count = fluid.layers.reduce_sum(tgt_mask)
-    avg_cost = fluid.layers.elementwise_div(
-        fluid.layers.reduce_sum(masked), tok_count)
+    with fluid.name_scope("loss"):
+        lbl = fluid.layers.unsqueeze(lbl_ids, [2])
+        loss = fluid.layers.softmax_with_cross_entropy(logits, lbl)  # [B,T,1]
+        loss = fluid.layers.squeeze(loss, [2])
+        masked = fluid.layers.elementwise_mul(loss, tgt_mask)
+        tok_count = fluid.layers.reduce_sum(tgt_mask)
+        avg_cost = fluid.layers.elementwise_div(
+            fluid.layers.reduce_sum(masked), tok_count)
 
     if with_optimizer:
         lr = fluid.layers.learning_rate_scheduler.noam_decay(

@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..core.registry import register, register_grad
+from ..core.registry import register, register_grad, scoped_vjp
 from ..kernels import attention as A
 
 
@@ -92,7 +92,7 @@ def _fused_attention(ctx, ins, attrs):
     return {"Out": [out]}
 
 
-@register_grad("fused_attention")
+@register_grad("fused_attention", retraces=True)
 def _fused_attention_grad(ctx, ins, attrs):
     """Backward: differentiate the forward lowering (flash recompute /
     ring ppermute-transpose handled by jax)."""
@@ -106,6 +106,6 @@ def _fused_attention_grad(ctx, ins, attrs):
         return _fused_attention(ctx, {"Q": [q], "K": [k], "V": [v],
                                       **extra}, attrs)["Out"][0]
 
-    _, vjp_fn = jax.vjp(f, q, k, v)
+    _, vjp_fn = scoped_vjp(ctx, f, q, k, v)
     dq, dk, dv = vjp_fn(g)
     return {"Q@GRAD": [dq], "K@GRAD": [dk], "V@GRAD": [dv]}

@@ -5,8 +5,11 @@ between backward and the optimizer pass.
 """
 from __future__ import annotations
 
-from .core.program import OP_ROLE_ATTR, OpRole
+from .core.program import GRAD_REWRITE_ATTR, OP_ROLE_ATTR, OpRole
 from .core.types import VarType
+
+# Backward by role, the optimizer's by what they do (program.py)
+_REWRITE = {OP_ROLE_ATTR: OpRole.Backward, GRAD_REWRITE_ATTR: True}
 
 
 def _sparse_decay_var(param, grad, block, coeff, mode):
@@ -18,7 +21,7 @@ def _sparse_decay_var(param, grad, block, coeff, mode):
     block.append_op(
         "sparse_decay", {"Param": [param.name], "Grad": [grad.name]},
         {"Out": [decay.name]},
-        {"coeff": coeff, "mode": mode, OP_ROLE_ATTR: OpRole.Backward})
+        {"coeff": coeff, "mode": mode, **_REWRITE})
     return decay
 
 
@@ -38,7 +41,7 @@ class L2DecayRegularizer(WeightDecayRegularizer):
             name=grad.name + "@L2DECAY", shape=param.shape, dtype=param.dtype)
         block.append_op(
             "scale", {"X": [param.name]}, {"Out": [decay.name]},
-            {"scale": self._coeff, OP_ROLE_ATTR: OpRole.Backward})
+            {"scale": self._coeff, **_REWRITE})
         return decay
 
 
@@ -53,12 +56,12 @@ class L1DecayRegularizer(WeightDecayRegularizer):
             name=grad.name + "@L1SIGN", shape=param.shape, dtype=param.dtype)
         block.append_op(
             "sign", {"X": [param.name]}, {"Out": [sign.name]},
-            {OP_ROLE_ATTR: OpRole.Backward})
+            dict(_REWRITE))
         decay = block.create_var(
             name=grad.name + "@L1DECAY", shape=param.shape, dtype=param.dtype)
         block.append_op(
             "scale", {"X": [sign.name]}, {"Out": [decay.name]},
-            {"scale": self._coeff, OP_ROLE_ATTR: OpRole.Backward})
+            {"scale": self._coeff, **_REWRITE})
         return decay
 
 
@@ -76,7 +79,7 @@ def append_regularization_ops(params_grads, regularization=None):
             type=grad.type)
         block.append_op(
             "sum", {"X": [grad.name, decay.name]}, {"Out": [new_grad.name]},
-            {OP_ROLE_ATTR: OpRole.Backward})
+            dict(_REWRITE))
         out.append((param, new_grad))
     return out
 

@@ -521,6 +521,12 @@ class Executor:
         # whose training path needs the vjp-friendly scan) pick the test
         # branch; part of the executable cache key
         self._training = training
+        # latched by _build_entry once a call's arrays lie across several
+        # devices (a scope a ParallelExecutor placed, run through a plain
+        # Executor): jit then compiles ONE program over those devices and
+        # GSPMD partitions it, which no Mosaic kernel survives — lowerings
+        # that have no mesh to wrap a kernel over read it (ctx.spans_devices)
+        self._spans_devices = False
         _live_executors.add(self)
         # fleet observability opt-in: FLAGS_debug_server_port=0 (default)
         # makes this a flag read — no socket, no thread; same deal for
@@ -1072,6 +1078,7 @@ class Executor:
         def build(disable_sparse_fused=False):
             fn = build_block_fn(program, plan, training=self._training,
                                 mesh=self._mesh(),
+                                spans_devices=self._spans_devices,
                                 disable_sparse_fused=disable_sparse_fused)
             refeed = plan.donated_write_indices
             n_writes = len(plan.persist_writes)
@@ -1151,8 +1158,12 @@ class Executor:
         ShapeDtypeStructs — the AOT lowering's avals; any aval guessed
         wrong is recovered at dispatch (``_recover_disk_entry``).
         """
+        self._spans_devices = self._spans_devices or any(
+            isinstance(v, jax.Array) and len(v.sharding.device_set) > 1
+            for v in jax.tree_util.tree_leaves(args))
         raw_make = build_fn or (lambda: build_block_fn(
-            program, plan, training=self._training, mesh=self._mesh()))
+            program, plan, training=self._training, mesh=self._mesh(),
+            spans_devices=self._spans_devices))
         used_cell = []  # the raw fn's _sparse_fused_used dict, once built
 
         def make(**kw):
@@ -1273,6 +1284,7 @@ class Executor:
                 fn = build_block_fn(
                     program, entry.plan, training=self._training,
                     mesh=self._mesh(),
+                    spans_devices=self._spans_devices,
                     disable_sparse_fused=disable_sparse_fused)
             cell = getattr(fn, "_sparse_fused_used", None)
             if cell is not None:

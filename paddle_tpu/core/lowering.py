@@ -264,9 +264,14 @@ def lower_ops(ctx: LowerContext, program: Program, block: Block, env: Dict) -> D
 
 
 def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
-                   mesh=None, disable_sparse_fused: bool = False):
+                   mesh=None, disable_sparse_fused: bool = False,
+                   spans_devices: bool = False):
     """Return fn(feed_vals, donated_state, const_state, rng) ->
     (fetch_vals, new_persist_vals, rng_out).
+
+    ``spans_devices``: the caller's arrays lie across several devices, so
+    jit will compile this lowering as one partitioned program whether or
+    not a ``mesh`` is given (``ctx.spans_devices``).
 
     ``disable_sparse_fused``: lower WITHOUT the fused Pallas paths (the
     sparse-embedding kernels AND the int8 inference peephole) even when
@@ -293,6 +298,7 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
 
         ctx = LowerContext(block=block, mesh=mesh, lower_block_fn=lower_sub,
                            training=training)
+        ctx.spans_devices = spans_devices
         ctx.disable_sparse_fused = disable_sparse_fused
         ctx.disable_int8_fused = disable_sparse_fused
         ctx.set_rng(rng)

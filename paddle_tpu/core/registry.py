@@ -42,6 +42,9 @@ class LowerContext:
     def __init__(self, block=None, mesh=None, lower_block_fn=None, training=True):
         self.block = block
         self.mesh = mesh
+        # the program is compiled across several devices although no mesh
+        # is given (core/lowering.py build_block_fn)
+        self.spans_devices = False
         self.training = training
         self._rng_key = None
         self._rng_key0 = None

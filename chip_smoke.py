@@ -79,6 +79,15 @@ HYBRID_FALLBACK_COUNTERS = (
     "attn.diff_prefill_fallbacks",
 )
 
+# the XLA fallbacks of the window-and-full attention expert LM's kernels
+# (kernels/gqa.py's window flash forward and ring walk, kernels/moe.py with
+# the gate relu)
+WINDOW_EXPERT_FALLBACK_COUNTERS = (
+    "moe.grouped_reglu_fallbacks",
+    "attn.gqa_window_prefill_fallbacks",
+    "attn.gqa_ring_decode_fallbacks",
+)
+
 # the XLA fallbacks of the parallel-hybrid (Mamba-2 beside grouped-query
 # attention) LM's kernels (kernels/ssd.py, kernels/gqa.py)
 PARALLEL_HYBRID_FALLBACK_COUNTERS = (
@@ -829,6 +838,97 @@ def phase_parallel_hybrid_lm(vocab=8192, hidden=512, heads=4, kv_heads=2,
         engine.close()
 
 
+def phase_window_expert_lm(vocab=8192, hidden=512, heads=14, kv_heads=2,
+                           head_dim=128, expert_ffn=256, experts=16, top_k=4,
+                           layers=4, window=256, max_seq_len=1024,
+                           max_slots=4, block_tokens=16, prefill_bucket=512,
+                           prompt_len=400, new_tokens=40, dtype="bfloat16"):
+    """``decode.smallthinker.SmallThinkerLM`` (SmallThinker's layer at its
+    head width and its group of seven, one period: a position-free full
+    layer and three window layers with rotary keys, ReLU-gated experts routed
+    from the layer's pre-attention input) through ``DecodeEngine``: one
+    stream whose prompt is longer than the window and whose ring wraps as it
+    decodes, its logits at every generated position held against the
+    benchmark's plain reference (given the program's expert choices); no
+    kernel fell back (on the chip none is interpreted); ``/decodez`` shows
+    the pool and the rings, and no recurrent rows."""
+    import jax.numpy as jnp
+    from benchmark.reference import smallthinker as reference
+    from paddle_tpu.decode import DecodeEngine, SamplingParams
+    from paddle_tpu.decode.smallthinker import (SmallThinkerConfig,
+                                                SmallThinkerLM)
+
+    layout = (0, 1, 1, 1) * (layers // 4)
+    cfg = SmallThinkerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_hidden_layers=layers,
+        num_attention_heads=heads, num_key_value_heads=kv_heads,
+        head_dim=head_dim, moe_ffn_hidden_size=expert_ffn,
+        moe_num_primary_experts=experts,
+        moe_num_active_primary_experts=top_k, rope_layout=layout,
+        sliding_window_layout=layout, sliding_window_size=window,
+        max_seq_len=max_seq_len, dtype=dtype)
+    model = SmallThinkerLM(cfg)
+    params = model.init_params(seed=7)
+    c0 = counters()
+    engine = DecodeEngine(model, params, name="window_expert",
+                          max_slots=max_slots, block_tokens=block_tokens,
+                          prefill_buckets=[prefill_bucket],
+                          capture_logits=True, cache_dtype=dtype,
+                          prefix_cache=False, overcommit=False)
+    try:
+        prompt = np.random.RandomState(0).randint(
+            0, vocab, (prompt_len,)).astype("int32")
+        handle = engine.submit(prompt,
+                               SamplingParams(max_new_tokens=new_tokens))
+        result = handle.result(timeout=900.0)
+        toks = np.asarray(result["tokens"], np.int32)
+        check(toks.size == new_tokens and result.get("finish") == "length",
+              f"the window-expert stream ended early: {result}")
+        seq = np.concatenate([prompt, toks[:-1]])
+        weights = {k: jnp.asarray(v) for k, v in params.items()}
+        at = np.arange(prompt_len - 1, seq.size)
+        want, _, _ = reference.forward(weights, cfg.to_dict(), seq, seq.size,
+                                       at)
+        want = np.asarray(want)
+        got = np.stack(handle.logits).astype(np.float32)
+        err = np.sqrt(((got - want) ** 2).sum(-1) / (want ** 2).sum(-1))
+        scale = float(np.abs(want).max())
+        gap = want.max(-1) - np.take_along_axis(want, toks[:, None], 1)[:, 0]
+        # bf16 activations through four layers against float32 at the highest
+        # precision, the reference routing on its own (a near tie turned by
+        # bf16 moves one position's logits by more than rounding does): the
+        # median a few percent of the logits' norm, and a token at most 5% of
+        # the logit scale under the reference's argmax
+        check(float(np.median(err)) <= (0.06 if dtype == "bfloat16"
+                                        else 1e-3),
+              f"window-expert-LM logits are {np.median(err):.4f} of their "
+              f"norm off the reference (median)")
+        check(float(gap.max()) <= 0.05 * scale,
+              f"a window-expert-LM token trails the reference's argmax by "
+              f"{gap.max():.4f} (logit scale {scale:.2f})")
+        fell = {n: counter_delta(c0, n)
+                for n in WINDOW_EXPERT_FALLBACK_COUNTERS
+                + ("attn.gqa_decode_fallbacks",)}
+        check(not any(fell.values()), f"a new kernel fell back: {fell}")
+        z = engine.decodez()
+        cache = z["cache"]
+        check(cache.get("kind") == "hybrid" and all(
+            cache.get(k, 0) > 0 for k in (
+                "kv_pool_bytes", "window_state_bytes", "kv_live_tokens"))
+              and "recurrent_state_bytes" not in cache,
+              f"/decodez does not show the pool and the rings alone: {cache}")
+        check(z["step_ring_rows_live"] == (new_tokens - 1) * min(
+            window, prompt_len), f"the rings' live rows: {z}")
+        return {"tokens_checked": int(toks.size),
+                "tokens_exact": int((gap == 0).sum()),
+                "logit_err_max": float(err.max()),
+                "logit_err_median": float(np.median(err)),
+                "worst_logit_gap": float(gap.max()), "logit_scale": scale,
+                "steps": z["steps"], "cache": cache, "fallbacks": fell}
+    finally:
+        engine.close()
+
+
 # ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
@@ -988,6 +1088,7 @@ def main() -> int:
     run_phase(report, "latent_lm", phase_latent_lm)
     run_phase(report, "hybrid_lm", phase_hybrid_lm)
     run_phase(report, "parallel_hybrid_lm", phase_parallel_hybrid_lm)
+    run_phase(report, "window_expert_lm", phase_window_expert_lm)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])
@@ -1003,7 +1104,8 @@ def main() -> int:
         n: int(c.get(n, 0))
         for n in (FALLBACK_COUNTERS + LATENT_FALLBACK_COUNTERS
                   + HYBRID_FALLBACK_COUNTERS
-                  + PARALLEL_HYBRID_FALLBACK_COUNTERS)}
+                  + PARALLEL_HYBRID_FALLBACK_COUNTERS
+                  + WINDOW_EXPERT_FALLBACK_COUNTERS)}
     report["jax_cache"] = {"hits": LOG.cache_hits, "compiles": LOG.compiles,
                            "compile_s": round(LOG.compile_s, 2)}
     failures += [f"phase {n}: {p.get('error')}"

@@ -119,6 +119,8 @@ from .model import (LMConfig, TransformerLM, load_lm,  # noqa: F401
 from .mla import MLAConfig, MLATransformerLM  # noqa: F401
 from .sambay import SambaYConfig, SambaYLM  # noqa: F401
 from .falcon_h1 import FalconH1Config, FalconH1LM  # noqa: F401
+from .smallthinker import (SmallThinkerConfig,  # noqa: F401
+                           SmallThinkerLM)
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -133,7 +135,7 @@ __all__ = [
     "PrefixCache",
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
     "MLAConfig", "MLATransformerLM", "SambaYConfig", "SambaYLM",
-    "FalconH1Config", "FalconH1LM",
+    "FalconH1Config", "FalconH1LM", "SmallThinkerConfig", "SmallThinkerLM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

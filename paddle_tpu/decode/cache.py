@@ -489,16 +489,18 @@ class HybridStateCache(_PagedPool):
             self.rings = jnp.zeros((int(window_layers),
                                     self.slots * self.ring_blocks,
                                     self.ring_rows, width), dtype)
-        rows = (int(ssm_layers), self.slots)
-        self.h = jnp.zeros(rows + tuple(
-            state_shape or (int(d_state), int(d_inner))), jnp.float32)
-        self.conv = jnp.zeros(rows + (int(d_conv) - 1,
-                                      int(conv_width or d_inner)), dtype)
+        self.h = self.conv = None
+        if ssm_layers:
+            rows = (int(ssm_layers), self.slots)
+            self.h = jnp.zeros(rows + tuple(
+                state_shape or (int(d_state), int(d_inner))), jnp.float32)
+            self.conv = jnp.zeros(rows + (int(d_conv) - 1,
+                                          int(conv_width or d_inner)), dtype)
         self.live_tokens = 0        # the model's observer keeps it
 
     @staticmethod
     def _bytes(a) -> int:
-        return int(a.size) * a.dtype.itemsize
+        return 0 if a is None else int(a.size) * a.dtype.itemsize
 
     @property
     def kv_pool_bytes(self) -> int:
@@ -506,7 +508,7 @@ class HybridStateCache(_PagedPool):
 
     @property
     def window_state_bytes(self) -> int:
-        return 0 if self.rings is None else self._bytes(self.rings)
+        return self._bytes(self.rings)
 
     @property
     def recurrent_state_bytes(self) -> int:
@@ -517,18 +519,22 @@ class HybridStateCache(_PagedPool):
         return (self.kv_pool_bytes + self.window_state_bytes
                 + self.recurrent_state_bytes)
 
+    _KINDS = ("kv", "rings", "h", "conv")
+
     def state(self) -> list:
-        """``[kv, rings, h, conv]``; without window layers ``[kv, h,
-        conv]``."""
-        if self.rings is None:
-            return [self.kv, self.h, self.conv]
-        return [self.kv, self.rings, self.h, self.conv]
+        """``[kv, rings, h, conv]`` less the kinds this model has none of:
+        ``[kv, h, conv]`` without window layers, ``[kv, rings]`` without
+        state-space layers."""
+        return [a for a in (getattr(self, k) for k in self._KINDS)
+                if a is not None]
 
     def update(self, new_state: list) -> None:
-        if self.rings is None:
-            self.kv, self.h, self.conv = new_state
-        else:
-            self.kv, self.rings, self.h, self.conv = new_state
+        held = [k for k in self._KINDS if getattr(self, k) is not None]
+        if len(new_state) != len(held):
+            raise ValueError(f"this cache holds {held}; got "
+                             f"{len(new_state)} arrays")
+        for k, a in zip(held, new_state):
+            setattr(self, k, a)
 
     def snapshot(self) -> dict:
         snap = super().snapshot()
@@ -539,4 +545,6 @@ class HybridStateCache(_PagedPool):
                     kv_live_tokens=int(self.live_tokens))
         if self.rings is None:
             del snap["window"], snap["window_state_bytes"]
+        if self.h is None:
+            del snap["recurrent_state_bytes"]
         return snap

@@ -18,11 +18,28 @@ rotation; nothing here knows a position but the causal mask.
   (padded to eight) of ONE product with the head's key tile, so a tile is
   read once for the whole group.  The XLA fallback gathers a slot's whole
   table and counts into ``attn.gqa_decode_fallbacks``.
+- :func:`ring_decode_attention` — the same walk over a *window ring* (a
+  slot's last W rows at ``position mod W``, ``decode.cache.HybridStateCache``)
+  under a kernel name of its own, ``gqa_ring_decode_attn``, so that a trace
+  tells a ring's walk from the pool's: the slot's own ring blocks are its
+  table and ``min(context, W)`` its length — softmax does not care in which
+  order the rows lie.  Its fallback counts into
+  ``attn.gqa_ring_decode_fallbacks``.
 - :func:`prefill_attention` — a prompt's causal flash attention
   (``gqa_flash_fwd``): a grid step is one query head's tile against one tile
   of its K/V head's rows, tiles above the diagonal are neither fetched nor
   computed.  The XLA fallback builds the dense masked scores and counts into
   ``attn.gqa_prefill_fallbacks``.
+- :func:`group_prefill_attention` — the same with an optional window (key
+  ``j`` visible to query ``t`` iff ``0 ≤ t − j < window``) and the GROUP as
+  the unit: a grid step takes one K/V head's tile against the tiles of ALL
+  its ``group`` query heads, stacked as the rows of one product, so a K/V
+  tile is fetched once a group and not once a query head.  The grid's last
+  axis covers only the tiles a query tile's window reaches; tiles left of it
+  and above the diagonal are neither fetched nor computed, and only the
+  tiles the diagonal or the window's edge crosses build a mask
+  (``gqa_window_flash_fwd``; with no window ``gqa_group_flash_fwd``).  The
+  XLA fallback counts into ``attn.gqa_window_prefill_fallbacks``.
 """
 from __future__ import annotations
 
@@ -36,7 +53,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..observability import stats as _obs_stats
 from ..platform import pallas_interpret
-from .diffattn import paged_walk
+from .diffattn import _first_tile, flash_tiles, paged_walk, visible
 
 NEG_INF = -1e30
 LANE = 128
@@ -70,14 +87,15 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer,
     return jnp.einsum("sgrl,slgd->sgrd", p, v).reshape(S, nh, dh)
 
 
-def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv):
+def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv,
+                   name="gqa_paged_decode_attn"):
     S, nh, dh = q.shape
     group = nh // n_kv
     rows = (q.astype(jnp.float32) * dh ** -0.5).reshape(S, n_kv, group, dh)
     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, -group % 8), (0, 0))
                    ).astype(pool.dtype)
     out = paged_walk(rows, pool, block_tables, context_lens, layer, n_kv,
-                     "gqa_paged_decode_attn")
+                     name)
     return out[:, :, :group].reshape(S, nh, dh)
 
 
@@ -92,13 +110,25 @@ def decode_attention(q, pool, block_tables, context_lens, layer, n_kv: int):
     return _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv)
 
 
-def prefill_attention_xla(q, rows, n_kv: int):
+def ring_decode_attention(q, rings, ring_tables, live_rows, layer,
+                          n_kv: int):
+    """q [S, nh, dh], rings [window layers, slots · W/rb, rb, 2·kw] (all of
+    them, as they lie), ring_tables [S, W/rb] int32 (a slot's own ring
+    blocks), live_rows [S] int32 (``min(context, W)``, at least 1), layer
+    the window layer's index → [S, nh, dh] float32."""
+    if q.shape[-1] != LANE:
+        _obs_stats.scope("attn").counter("gqa_ring_decode_fallbacks").inc()
+        return decode_attention_xla(q, rings, ring_tables, live_rows, layer,
+                                    n_kv)
+    return _decode_pallas(q, rings, ring_tables, live_rows, layer, n_kv,
+                          "gqa_ring_decode_attn")
+
+
+def prefill_attention_xla(q, rows, n_kv: int, window=None):
     T, nh, dh = q.shape
     k, v = _split_rows(rows, n_kv)
     s = jnp.einsum("tgrd,jgd->grtj", _split_q(q, n_kv), k) * dh ** -0.5
-    t = jnp.arange(T)
-    p = jax.nn.softmax(jnp.where(t[:, None] >= t[None, :], s, NEG_INF),
-                       axis=-1)
+    p = jax.nn.softmax(jnp.where(visible(T, window), s, NEG_INF), axis=-1)
     return jnp.einsum("grtj,jgd->tgrd", p, v).reshape(T, nh, dh)
 
 
@@ -182,5 +212,118 @@ def prefill_attention(q, rows, n_kv: int):
     return _flash_pallas(q, rows, n_kv)
 
 
-__all__ = ["decode_attention", "decode_attention_xla", "prefill_attention",
-           "prefill_attention_xla", "flash_tile", "LANE"]
+def _group_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                        b: int, group: int, window):
+    """Grid (n_kv, T/b, key tiles a window reaches): q_ref [b, group·128] —
+    the group's query heads side by side, already scaled — against one K/V
+    head's key and value tiles [b, 128].  The group's tiles are stacked as
+    the ``group·b`` rows of ONE product with the key tile."""
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    kb = _first_tile(i, b, window) + j
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    def scores():
+        q = jnp.concatenate([q_ref[:, r * LANE:(r + 1) * LANE]
+                             for r in range(group)], axis=0)
+        return lax.dot_general(q, k_ref[:], (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+    def accumulate(s):
+        m = m_scr[:]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_scr[:] = m_new
+        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[:]
+        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+
+    # a tile wholly under the diagonal and wholly inside the window needs no
+    # mask: its last key is older than the tile's first query, and its first
+    # key is inside the window of the tile's last query
+    inside = kb < i
+    if window is not None:
+        inside = jnp.logical_and(inside, (i - kb + 1) * b - 1 < window)
+
+    @pl.when(inside)
+    def _whole():
+        accumulate(scores())
+
+    @pl.when(jnp.logical_and(kb <= i, jnp.logical_not(inside)))
+    def _edge():
+        s = scores()
+        tile = (b, s.shape[1])
+        qpos = i * b + jnp.concatenate(
+            [lax.broadcasted_iota(jnp.int32, tile, 0)] * group, axis=0)
+        kpos = kb * b + lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        keep = kpos <= qpos
+        if window is not None:
+            keep = jnp.logical_and(keep, qpos - kpos < window)
+        accumulate(jnp.where(keep, s, NEG_INF))
+
+    @pl.when(kb == i)       # the diagonal tile is a query tile's last
+    def _finish():
+        out = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+        for r in range(group):
+            o_ref[:, r * LANE:(r + 1) * LANE] = out[r * b:(r + 1) * b]
+
+
+def _group_flash_pallas(q, rows, n_kv, window):
+    T, nh, dh = q.shape
+    group = nh // n_kv
+    b, n_kw = flash_tiles(T, window)
+    qs = (q.astype(jnp.float32) * dh ** -0.5).astype(rows.dtype)
+
+    def kv_map(lane0):
+        def at(g, i, j):
+            return (jnp.minimum(_first_tile(i, b, window) + j, i), lane0 + g)
+        return at
+
+    out = pl.pallas_call(
+        functools.partial(_group_flash_kernel, b=b, group=group,
+                          window=window),
+        name=("gqa_group_flash_fwd" if window is None
+              else "gqa_window_flash_fwd"),
+        grid=(n_kv, T // b, n_kw),
+        in_specs=[pl.BlockSpec((b, group * dh), lambda g, i, j: (i, g)),
+                  pl.BlockSpec((b, dh), kv_map(0)),
+                  pl.BlockSpec((b, dh), kv_map(n_kv))],
+        out_specs=pl.BlockSpec((b, group * dh), lambda g, i, j: (i, g)),
+        out_shape=jax.ShapeDtypeStruct((T, nh * dh), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((group * b, 1), jnp.float32),
+                        pltpu.VMEM((group * b, 1), jnp.float32),
+                        pltpu.VMEM((group * b, dh), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=64 * 1024 * 1024,
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=pallas_interpret(),
+    )(qs.reshape(T, nh * dh), rows, rows)
+    return out.reshape(T, nh, dh)
+
+
+def group_prefill_attention(q, rows, n_kv: int, window=None):
+    """Causal attention of one prompt with an optional window, a K/V head's
+    tile fetched once for its whole group: q [T, nh, dh], rows [T, 2·kw] (the
+    prompt's own cache rows) → [T, nh, dh] float32.  Pad positions lie after
+    every real one, so the causal mask alone keeps them out of every real
+    row."""
+    T = q.shape[0]
+    b, _ = flash_tiles(T, window)
+    if q.shape[-1] != LANE or T % b or b % 8:
+        _obs_stats.scope("attn").counter(
+            "gqa_window_prefill_fallbacks").inc()
+        return prefill_attention_xla(q, rows, n_kv, window)
+    return _group_flash_pallas(q, rows, n_kv, window)
+
+
+__all__ = ["decode_attention", "decode_attention_xla",
+           "ring_decode_attention", "prefill_attention",
+           "prefill_attention_xla", "group_prefill_attention", "flash_tile",
+           "LANE"]

@@ -1,6 +1,6 @@
-"""Routed experts: top-k routing by sort and a grouped SwiGLU over the
-experts — the serving form of a sparse mixture, where every assignment is
-computed (no capacity, no token dropped).
+"""Routed experts: top-k routing by sort and a grouped gated unit (SwiGLU or
+ReGLU) over the experts — the serving form of a sparse mixture, where every
+assignment is computed (no capacity, no token dropped).
 
 - :func:`route_topk` — ``softmax`` scores over all experts in float32, the
   ``k`` largest (``lax.top_k`` keeps the lower index on a tie), weights the
@@ -9,20 +9,30 @@ computed (no capacity, no token dropped).
   order, each expert's rows padded to a multiple of the row tile, so a tile
   of rows belongs to exactly one expert.  Gathers and two small sorts; no
   scatter.
-- :func:`grouped_swiglu` — ``(silu(x Wg_e) * (x Wu_e)) Wd_e`` for every row
-  tile against its expert's three matrices.  The Pallas kernel
-  (``moe_grouped_swiglu``) walks the tiles; the scalar-prefetched tile→expert
-  map is its weight index map, so an expert's matrices stream from HBM once
-  while its tiles are consecutive, experts with no row are never read, and
-  the tiles past the last real one repeat its indices (no copy, no compute).
-  The XLA fallback is three ``lax.ragged_dot`` over the same padded rows and
-  counts into ``moe.grouped_swiglu_fallbacks``.
+- :func:`grouped_glu` — ``(act(x Wg_e) * (x Wu_e)) Wd_e`` for every row tile
+  against its expert's three matrices, ``act`` the gate's activation
+  (:data:`ACTS`: ``silu`` — SwiGLU — or ``relu`` — ReGLU).  ONE Pallas kernel,
+  named after the gate (``moe_grouped_swiglu`` / ``moe_grouped_reglu``), walks
+  the tiles; the scalar-prefetched tile→expert map is its weight index map,
+  so an expert's matrices stream from HBM once while its tiles are
+  consecutive, experts with no row are never read, and the tiles past the
+  last real one repeat its indices (no copy, no compute).  Where the layers
+  are scanned the matrices are handed over as the whole stack ``[layers, E,
+  …]`` with the layer's index: the map points at ``layer · E + expert`` and
+  no layer's experts are sliced out of the stack.  The XLA fallback is three
+  ``lax.ragged_dot`` over the same padded rows and counts into
+  ``moe.grouped_swiglu_fallbacks`` / ``moe.grouped_reglu_fallbacks``.
+  :func:`grouped_swiglu` is the call with the gate fixed at ``silu``.
+- :func:`combine` — ``sum_k w[t, k] * y[row of (t, k)]``.
 - :func:`routed_experts` — the three together, one function for a prefill's
   thousands of rows and a decode step's 64: ``sum_k w[t, k] * expert(x[t])``
-  and the dispatch's load figures.
+  and the dispatch's load figures.  A model whose routing is known before
+  the experts' input (the router reads an earlier activation) calls
+  :func:`plan_groups` there and :func:`planned_experts` here.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -116,41 +126,75 @@ def plan_groups(ids, valid, experts: int, tile: int) -> GroupPlan:
                      load)
 
 
-def _grouped_swiglu_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                           o_ref):
+# the gate's activation by name: the kernel's name and the fallback's counter
+# follow it
+ACTS = {"silu": (jax.nn.silu, "swiglu"), "relu": (jax.nn.relu, "reglu")}
+
+
+def _gate(act: str):
+    try:
+        return ACTS[act]
+    except KeyError:
+        raise ValueError(f"unknown gate activation {act!r}; one of "
+                         f"{sorted(ACTS)}") from None
+
+
+def _grouped_glu_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
+                        *, gate):
     @pl.when(pl.program_id(0) < na_ref[0])
     def _():
         x = x_ref[:]
         g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
         u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-        h = (jax.nn.silu(g) * u).astype(wd_ref.dtype)
-        o_ref[:] = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32)
+        h = (gate(g) * u).astype(wd_ref.dtype)
+        o_ref[:] = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32
+                           ).astype(o_ref.dtype)
 
 
-def grouped_swiglu_xla(x_rows, wg, wu, wd, plan: GroupPlan):
+def _one_layer(w, layer):
+    return w if layer is None else lax.dynamic_index_in_dim(
+        w, layer, keepdims=False)
+
+
+def grouped_glu_xla(x_rows, wg, wu, wd, plan: GroupPlan, act: str = "silu",
+                    layer=None, out_dtype=jnp.float32):
     """The fallback: the same padded rows through three ``lax.ragged_dot``."""
+    gate, _ = _gate(act)
+    wg, wu, wd = (_one_layer(w, layer) for w in (wg, wu, wd))
+
     def rd(a, w):
         return lax.ragged_dot(a, w, plan.padded_sizes,
                               preferred_element_type=jnp.float32)
-    h = (jax.nn.silu(rd(x_rows, wg)) * rd(x_rows, wu)).astype(wd.dtype)
-    return rd(h, wd)
+    h = (gate(rd(x_rows, wg)) * rd(x_rows, wu)).astype(wd.dtype)
+    return rd(h, wd).astype(out_dtype)
 
 
-def grouped_swiglu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
-                   interpret=None):
+def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
+                interpret=None, act: str = "silu", layer=None,
+                out_dtype=jnp.float32):
     """x_rows [R, D] (rows in expert order, :func:`plan_groups`), wg / wu
-    [E, D, F], wd [E, F, D] → [R, D] float32.  Rows of tiles past
-    ``plan.active_tiles`` are left as they are found: no assignment points at
-    them."""
+    [E, D, F], wd [E, F, D] → [R, D] ``out_dtype`` (every product accumulated
+    in float32; a prefill of twelve thousand positions has 82 thousand rows,
+    0.84 GB in float32); with ``layer`` (an int or a traced scalar) the
+    matrices are stacks ``[layers, E, …]`` and the layer's are used.  Rows of
+    tiles past ``plan.active_tiles`` are left as they are found: no
+    assignment points at them."""
+    gate, glu = _gate(act)
     if impl == "xla":
-        _obs_stats.scope("moe").counter("grouped_swiglu_fallbacks").inc()
-        return grouped_swiglu_xla(x_rows, wg, wu, wd, plan)
+        _obs_stats.scope("moe").counter(f"grouped_{glu}_fallbacks").inc()
+        return grouped_glu_xla(x_rows, wg, wu, wd, plan, act, layer,
+                               out_dtype)
     if impl not in (None, "pallas"):
-        raise ValueError(f"unknown grouped_swiglu impl {impl!r}")
+        raise ValueError(f"unknown grouped_glu impl {impl!r}")
     if interpret is None:
         interpret = pallas_interpret()
     R, D = x_rows.shape
-    F = wg.shape[2]
+    E, F = wg.shape[-3], wg.shape[-1]
+    tile_expert = plan.tile_expert
+    if layer is not None:
+        # a stack is its layers' experts end to end: nothing is sliced out
+        wg, wu, wd = (w.reshape((-1,) + w.shape[2:]) for w in (wg, wu, wd))
+        tile_expert = tile_expert + jnp.asarray(layer, jnp.int32) * E
 
     def rows(i, te, na):
         return (jnp.minimum(i, na[0] - 1), 0)
@@ -159,8 +203,8 @@ def grouped_swiglu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
         return (te[i], 0, 0)
 
     return pl.pallas_call(
-        _grouped_swiglu_kernel,
-        name="moe_grouped_swiglu",
+        functools.partial(_grouped_glu_kernel, gate=gate),
+        name=f"moe_grouped_{glu}",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R // tile,),
@@ -169,10 +213,20 @@ def grouped_swiglu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
                       pl.BlockSpec((1, D, F), up),
                       pl.BlockSpec((1, F, D), up)],
             out_specs=pl.BlockSpec((tile, D), rows)),
-        out_shape=jax.ShapeDtypeStruct((R, D), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
-    )(plan.tile_expert, plan.active_tiles, x_rows, wg, wu, wd)
+    )(tile_expert, plan.active_tiles, x_rows, wg, wu, wd)
+
+
+def grouped_swiglu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
+                   interpret=None):
+    """:func:`grouped_glu` with the gate ``silu``."""
+    return grouped_glu(x_rows, wg, wu, wd, plan, tile, impl, interpret)
+
+
+def grouped_swiglu_xla(x_rows, wg, wu, wd, plan: GroupPlan):
+    return grouped_glu_xla(x_rows, wg, wu, wd, plan)
 
 
 def row_tile(tokens: int, dtype) -> int:
@@ -180,24 +234,43 @@ def row_tile(tokens: int, dtype) -> int:
     return small if tokens <= 128 else _PREFILL_TILE
 
 
-def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None):
+def combine(y, weights, plan: GroupPlan):
+    """y [R, D] (:func:`grouped_glu`'s rows), weights [T, K] float32 → the
+    weighted sum of every token's chosen rows [T, D] float32; an assignment
+    with no row (its token was not valid) adds nothing."""
+    R = y.shape[0]
+    here = plan.row_of < R
+    picked = jnp.where(here[..., None],
+                       y[jnp.minimum(plan.row_of, R - 1)].astype(jnp.float32),
+                       0.0)                                      # [T, K, D]
+    return jnp.sum(weights[..., None] * picked, axis=1)
+
+
+def planned_experts(x, weights, plan: GroupPlan, wg, wu, wd, tile: int,
+                    impl=None, act: str = "silu", layer=None,
+                    out_dtype=jnp.float32):
+    """The experts of a dispatch whose :func:`plan_groups` is already made
+    (with the same ``tile``): x [T, D], weights [T, K] → [T, D] float32, the
+    experts' rows kept in ``out_dtype`` until they are weighed and summed."""
+    x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)], axis=0)
+    y = grouped_glu(x_pad[plan.row_token], wg, wu, wd, plan, tile, impl=impl,
+                    act=act, layer=layer, out_dtype=out_dtype)
+    return combine(y, weights, plan)
+
+
+def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
+                   act: str = "silu"):
     """x [T, D] (the experts' input, the model's activation dtype), ids /
     weights [T, K] from :func:`route_topk`, valid [T] bool → (sum over the
     chosen experts of ``w * expert(x)`` [T, D] float32, load [3] int32:
     assignments, experts touched, the largest load of one)."""
-    T, D = x.shape
-    E = wg.shape[0]
-    tile = row_tile(T, x.dtype)
-    plan = plan_groups(ids, valid, E, tile)
-    x_pad = jnp.concatenate([x, jnp.zeros((1, D), x.dtype)], axis=0)
-    y = grouped_swiglu(x_pad[plan.row_token], wg, wu, wd, plan, tile,
-                       impl=impl)
-    R = y.shape[0]
-    here = plan.row_of < R
-    picked = jnp.where(here[..., None],
-                       y[jnp.minimum(plan.row_of, R - 1)], 0.0)  # [T, K, D]
-    return jnp.sum(weights[..., None] * picked, axis=1), plan.load
+    tile = row_tile(x.shape[0], x.dtype)
+    plan = plan_groups(ids, valid, wg.shape[0], tile)
+    return planned_experts(x, weights, plan, wg, wu, wd, tile, impl=impl,
+                           act=act), plan.load
 
 
-__all__ = ["route_topk", "plan_groups", "plan_rows", "grouped_swiglu",
-           "grouped_swiglu_xla", "routed_experts", "GroupPlan", "row_tile"]
+__all__ = ["route_topk", "plan_groups", "plan_rows", "grouped_glu",
+           "grouped_glu_xla", "grouped_swiglu", "grouped_swiglu_xla", "combine",
+           "planned_experts", "routed_experts", "GroupPlan", "row_tile",
+           "ACTS"]

@@ -139,6 +139,20 @@ def test_parallel_hybrid_lm_phase_tiny():
     assert not any(out["fallbacks"].values())
 
 
+def test_window_expert_lm_phase_tiny():
+    out = chip_smoke.phase_window_expert_lm(
+        vocab=64, hidden=64, heads=2, kv_heads=1, head_dim=128,
+        expert_ffn=32, experts=8, top_k=3, layers=4, window=32,
+        max_seq_len=96, max_slots=2, block_tokens=16, prefill_bucket=64,
+        prompt_len=45, new_tokens=14, dtype="float32")
+    assert out["tokens_checked"] == 14 and out["tokens_exact"] == 14
+    assert out["logit_err_max"] < 1e-4
+    assert out["cache"]["kind"] == "hybrid"
+    assert out["cache"]["window_state_bytes"] == 3 * 2 * 32 * 256 * 4
+    assert "recurrent_state_bytes" not in out["cache"]
+    assert not any(out["fallbacks"].values())
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

@@ -49,7 +49,7 @@ def impl(request, monkeypatch):
     fallbacks, which the model itself never asks for; the decode step's
     attention takes the same choice as the engine's ``attn_impl``."""
     if request.param == "xla":
-        for mod, name in ((EK, "grouped_swiglu"), (MK, "prefill_attention")):
+        for mod, name in ((EK, "grouped_glu"), (MK, "prefill_attention")):
             def forced(*a, _orig=getattr(mod, name), **kw):
                 return _orig(*a, **dict(kw, impl="xla"))
             monkeypatch.setattr(mod, name, forced)

@@ -115,3 +115,144 @@ def test_another_head_size_falls_back_and_counts():
     got = gqa.prefill_attention(q, rows, 2)
     assert stats.to_dict()["attn.gqa_prefill_fallbacks"] == before + 1
     np.testing.assert_array_equal(got, gqa.prefill_attention_xla(q, rows, 2))
+
+
+# -- a group of seven (28 query heads over 4 K/V heads, cut to 14 over 2):
+# the window flash forward, the group as the rows of one product, the ring --
+GNH = 14
+
+
+def _dense_g(q, k, v, keep):
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    out = np.zeros(q.shape)
+    for h in range(q.shape[1]):
+        g = h // (q.shape[1] // k.shape[1])
+        s = np.where(keep, q[:, h] @ k[:, g].T / np.sqrt(DH), -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[:, h] = (p / p.sum(-1, keepdims=True)) @ v[:, g]
+    return out
+
+
+def _window_keep(T, window):
+    t = np.arange(T)
+    keep = t[:, None] >= t[None, :]
+    if window is not None:
+        keep &= t[:, None] - t[None, :] < window
+    return keep
+
+
+@pytest.mark.parametrize("T,tile,window", [
+    (64, 8, None), (64, 8, 20), (64, 8, 8), (64, 8, 64), (64, 8, 200),
+    (48, 16, 17), (16, 16, 5)],
+    ids=["no_window", "window_mid_tile", "window_one_tile", "window_at_T",
+         "window_past_T", "three_tiles_deep", "one_tile"])
+def test_group_flash_matches_dense_windowed_attention(monkeypatch, T, tile,
+                                                      window):
+    """Prompts shorter than, as long as and several times the window; a
+    window that ends inside a tile and on a tile's edge."""
+    monkeypatch.setattr(da, "_FLASH_BLOCK", tile)
+    rng = np.random.default_rng(T + (window or 0))
+    q = jnp.asarray(rng.standard_normal((T, GNH, DH)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((T, NKV, DH)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((T, NKV, DH)), jnp.float32)
+    before = stats.to_dict().get("attn.gqa_window_prefill_fallbacks", 0)
+    got = jax.jit(lambda q, r: gqa.group_prefill_attention(q, r, NKV, window)
+                  )(q, _rows(k, v))
+    assert stats.to_dict().get(
+        "attn.gqa_window_prefill_fallbacks", 0) == before
+    want = _dense_g(q, k, v, _window_keep(T, window))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        gqa.prefill_attention_xla(q, _rows(k, v), NKV, window), want,
+        rtol=1e-5, atol=1e-5)
+    if window is not None and window < T:
+        # and it is NOT causal attention without a window
+        assert np.abs(want - _dense_g(q, k, v, _window_keep(T, None))
+                      ).max() > 1e-2
+
+
+def test_group_flash_names_follow_the_window_and_fetch_a_tile_once_a_group(
+        monkeypatch):
+    monkeypatch.setattr(da, "_FLASH_BLOCK", 8)
+    from paged_walks import eqns_under
+    q = jnp.zeros((64, GNH, DH), jnp.bfloat16)
+    rows = jnp.zeros((64, 2 * KW), jnp.bfloat16)
+    for window, name, tiles in ((None, "gqa_group_flash_fwd", 8),
+                                (20, "gqa_window_flash_fwd", 4)):
+        calls = [e for e in eqns_under(jax.make_jaxpr(
+            lambda q, r: gqa.group_prefill_attention(q, r, NKV, window)
+        )(q, rows).jaxpr) if e.primitive.name == "pallas_call"]
+        assert [e.params["name"] for e in calls] == [name]
+        # one grid step a K/V head, not a query head, a (query, key) tile
+        assert tuple(calls[0].params["grid_mapping"].grid) == (NKV, 8, tiles)
+
+
+def test_group_flash_at_another_head_size_falls_back_and_counts():
+    rng = np.random.default_rng(9)
+    q = jnp.asarray(rng.standard_normal((8, 4, 16)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((8, 2 * 2 * 16)), jnp.float32)
+    before = stats.to_dict().get("attn.gqa_window_prefill_fallbacks", 0)
+    got = gqa.group_prefill_attention(q, rows, 2, 3)
+    assert stats.to_dict()["attn.gqa_window_prefill_fallbacks"] == before + 1
+    np.testing.assert_array_equal(
+        got, gqa.prefill_attention_xla(q, rows, 2, 3))
+
+
+@pytest.mark.parametrize("contexts", [
+    [5, 1, 31], [32, 32, 1], [33, 100, 64, 47]],
+    ids=["below_the_window", "at_the_window", "above_the_window"])
+def test_ring_walk_reads_a_slot_s_live_ring_rows_under_its_own_name(
+        monkeypatch, contexts):
+    """A ring of W = 32 rows in blocks of 8 a slot: a stream's rows lie at
+    ``position mod W``; the walk reads ``min(context, W)`` of them, which are
+    the window's keys whatever their order.  Three window layers; the walk is
+    of layer 1."""
+    W, rb, layers = 32, 8, 3
+    monkeypatch.setattr(da, "_CHUNK_BLOCKS", 2)
+    rng = np.random.default_rng(len(contexts))
+    S, nrb = len(contexts), W // rb
+    longest = max(contexts)
+    k = rng.standard_normal((S, longest, NKV, DH)).astype("float32")
+    v = rng.standard_normal((S, longest, NKV, DH)).astype("float32")
+    rings = rng.standard_normal((layers, S * nrb, rb, 2 * KW)
+                                ).astype("float32")
+    for s, n in enumerate(contexts):
+        for t in range(n):          # later positions overwrite earlier ones
+            rings[1, s * nrb + (t % W) // rb, t % rb] = np.concatenate(
+                [k[s, t].reshape(-1), v[s, t].reshape(-1)])
+    q = jnp.asarray(rng.standard_normal((S, GNH, DH)), jnp.float32)
+    tables = (np.arange(S)[:, None] * nrb + np.arange(nrb)).astype("int32")
+    live = np.minimum(contexts, W).astype("int32")
+    before = stats.to_dict().get("attn.gqa_ring_decode_fallbacks", 0)
+    fn = jax.jit(lambda q, r, t, n, l: gqa.ring_decode_attention(
+        q, r, t, n, l, NKV))
+    got = fn(q, jnp.asarray(rings), jnp.asarray(tables), jnp.asarray(live),
+             jnp.int32(1))
+    assert stats.to_dict().get("attn.gqa_ring_decode_fallbacks", 0) == before
+    from paged_walks import eqns_under
+    names = [e.params["name"] for e in eqns_under(jax.make_jaxpr(fn)(
+        q, jnp.asarray(rings), jnp.asarray(tables), jnp.asarray(live),
+        jnp.int32(1)).jaxpr) if e.primitive.name == "pallas_call"]
+    assert names == ["gqa_ring_decode_attn"]
+    xla = gqa.decode_attention_xla(q, jnp.asarray(rings), jnp.asarray(tables),
+                                   jnp.asarray(live), 1, NKV)
+    for s, n in enumerate(contexts):
+        lo = max(0, n - W)
+        want = _dense_g(np.asarray(q)[s:s + 1], k[s, lo:n], v[s, lo:n],
+                        np.ones((1, n - lo), bool))
+        np.testing.assert_allclose(got[s], want[0], rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(xla[s], want[0], rtol=1e-5, atol=1e-5)
+
+
+def test_ring_walk_at_another_head_size_falls_back_and_counts():
+    rng = np.random.default_rng(2)
+    q = jnp.asarray(rng.standard_normal((2, 4, 16)), jnp.float32)
+    rings = jnp.asarray(rng.standard_normal((1, 4, 8, 2 * 2 * 16)),
+                        jnp.float32)
+    tables = jnp.asarray([[0, 1], [2, 3]], jnp.int32)
+    live = jnp.asarray([3, 16], jnp.int32)
+    before = stats.to_dict().get("attn.gqa_ring_decode_fallbacks", 0)
+    got = gqa.ring_decode_attention(q, rings, tables, live, 0, 2)
+    assert stats.to_dict()["attn.gqa_ring_decode_fallbacks"] == before + 1
+    np.testing.assert_array_equal(
+        got, gqa.decode_attention_xla(q, rings, tables, live, 0, 2))

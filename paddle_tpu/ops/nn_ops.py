@@ -21,7 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..core.registry import register, register_grad
+from ..core.registry import _scope, get as get_opdef, register, \
+    register_grad, vjp_grad
+from ..observability import stats as _obs_stats
 
 
 def _pair(v):
@@ -280,23 +282,71 @@ def _cross_entropy(ctx, ins, attrs):
     return {"Y": [loss]}
 
 
+def _xent_stats(logits):
+    """``logits`` in the statistics' dtype and their log-sum-exp over the
+    classes: the one expression the forward and the grad rule share, so XLA's
+    CSE folds the grad rule's copy into the forward's."""
+    xf = logits.astype(_stat_dtype(logits))
+    return xf, jax.nn.logsumexp(xf, axis=-1, keepdims=True)
+
+
+def _xent_hard_label(label, attrs, dtype):
+    """Class index ``[..., 1]`` and the ``ignore_index`` mask (None: unset)."""
+    li = _squeeze_label(label).astype(jnp.int32)[..., None]
+    if attrs.get("ignore_index", -100) == -100:
+        return li, None
+    return li, (li != attrs["ignore_index"]).astype(dtype)
+
+
 @register("softmax_with_cross_entropy", no_grad_slots=("Label",))
 def _softmax_xent(ctx, ins, attrs):
+    """Nothing of the classes' width is written beside ``Softmax`` (which XLA
+    drops where nothing reads it): the label's logit is taken from the logits
+    as they arrive, not from a ``log_softmax`` tensor in the statistics'
+    dtype."""
     logits, label = ins["Logits"][0], ins["Label"][0]
-    sdt = _stat_dtype(logits)
-    lse = jax.nn.logsumexp(logits.astype(sdt), axis=-1, keepdims=True)
-    log_softmax = logits.astype(sdt) - lse
+    xf, lse = _xent_stats(logits)
     if attrs.get("soft_label", False):
-        loss = -jnp.sum(label * log_softmax, axis=-1, keepdims=True)
+        loss = -jnp.sum(label * (xf - lse), axis=-1, keepdims=True)
     else:
-        li = _squeeze_label(label).astype(jnp.int32)
-        picked = jnp.take_along_axis(log_softmax, li[..., None], axis=-1)
-        if attrs.get("ignore_index", -100) != -100:
-            mask = (li[..., None] != attrs["ignore_index"]).astype(log_softmax.dtype)
-            picked = picked * mask
-        loss = -picked
-    return {"Softmax": [jnp.exp(log_softmax).astype(logits.dtype)],
+        li, mask = _xent_hard_label(label, attrs, lse.dtype)
+        loss = lse - jnp.take_along_axis(logits, li, axis=-1).astype(lse.dtype)
+        if mask is not None:
+            loss = loss * mask
+    return {"Softmax": [jnp.exp(xf - lse).astype(logits.dtype)],
             "Loss": [loss.astype(logits.dtype)]}
+
+
+@register_grad("softmax_with_cross_entropy", retraces=True)
+def _softmax_xent_grad(ctx, ins, attrs):
+    """Closed form, from ``Logits``, ``Label`` and ``Loss@GRAD`` alone:
+    ``(softmax - onehot(label)) * g``, the exponential recomputed inside
+    whatever consumes the gradient.  ``ins["Softmax"]`` is not read: that
+    would pin a classes-wide write in the forward that nothing else needs.
+    A program that differentiates through ``Softmax`` takes ``vjp_grad``."""
+    counters = _obs_stats.scope("loss")
+    if ins.get("Softmax@GRAD"):
+        counters.counter("xent_vjp_fallback_grads").inc()
+        return vjp_grad(get_opdef("softmax_with_cross_entropy"), ctx, ins,
+                        attrs)
+    counters.counter("xent_closed_form_grads").inc()
+    logits, label = ins["Logits"][0], ins["Label"][0]
+    fwd_scope, bwd_scope = ctx.grad_scopes
+    with _scope(fwd_scope):  # named as the forward's, whichever CSE keeps
+        xf, lse = _xent_stats(logits)
+    with _scope(bwd_scope):
+        g = ins["Loss@GRAD"][0].astype(lse.dtype)
+        softmax = jnp.exp(xf - lse)
+        if attrs.get("soft_label", False):
+            d = softmax * jnp.sum(label, axis=-1, keepdims=True) - label
+        else:
+            li, mask = _xent_hard_label(label, attrs, lse.dtype)
+            classes = lax.broadcasted_iota(jnp.int32, logits.shape,
+                                           logits.ndim - 1)
+            d = softmax - (classes == li).astype(lse.dtype)
+            if mask is not None:
+                g = g * mask
+        return {"Logits@GRAD": [(d * g).astype(logits.dtype)]}
 
 
 @register("square_error_cost")

@@ -54,3 +54,11 @@ def _a_module_leaves_no_injected_fault_behind():
             metric = registry.get(name)
             if getattr(metric, "kind", "") == "counter":
                 metric.reset()
+    # two models served under one name register one histogram with different
+    # buckets (``decode.lm.expert_load_max`` of decode/mla.py and of
+    # decode/smallthinker.py), and the registry refuses the second: a
+    # module's served models take their histograms with them
+    with registry._lock:
+        for name, metric in list(registry._metrics.items()):
+            if name.startswith("decode.") and metric.kind == "histogram":
+                del registry._metrics[name]

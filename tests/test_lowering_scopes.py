@@ -52,9 +52,9 @@ def _feed():
             "src_mask": ones, "tgt_mask": ones}
 
 
-def _compiled_text(prog, startup, feed, fetch, steps=0):
-    """The optimised HLO of the block as the executor lowers it: one step, or
-    ``run_steps``' scan over ``steps`` of them."""
+def _lowered(prog, startup, feed, fetch, steps=0):
+    """The block as the executor lowers it: one step, or ``run_steps``' scan
+    over ``steps`` of them."""
     scope, exe = Scope(), Executor()
     with scope_guard(scope):
         exe.run(startup)
@@ -69,8 +69,12 @@ def _compiled_text(prog, startup, feed, fetch, steps=0):
             fn = build_block_fn(prog, plan)
         donated = [np.asarray(scope.find_var(n)) for n in plan.donated_reads]
         const = [np.asarray(scope.find_var(n)) for n in plan.const_reads]
-        return jax.jit(fn).lower(vals, donated, const,
-                                 jax.random.PRNGKey(0)).compile().as_text()
+        return jax.jit(fn).lower(vals, donated, const, jax.random.PRNGKey(0))
+
+
+def _compiled_text(*args, **kw):
+    """The optimised HLO of :func:`_lowered`'s block."""
+    return _lowered(*args, **kw).compile().as_text()
 
 
 def _instructions(text):
@@ -168,6 +172,41 @@ def test_an_adam_update_is_filed_under_its_parameter(lowered):
     assert {"fwd/enc_1/self_attn/layer_norm", "bwd/dec_0/cross_attn/mul_grad",
             "fwd/loss/softmax_with_cross_entropy", "fwd/src_embed/lookup_table",
             "bwd/out_proj/mul_grad", "opt/adam/tgt.word_emb"} <= scopes
+
+
+LOSS, LOSS_GRAD = ("fwd/loss/softmax_with_cross_entropy",
+                   "bwd/loss/softmax_with_cross_entropy_grad")
+
+
+def test_the_loss_grad_names_its_retraced_lse_as_the_forward_op_does():
+    """``softmax_with_cross_entropy_grad`` is a closed form with a rule of its
+    own: BEFORE any optimisation the log-sum-exp it recomputes stands beside
+    the forward's under the forward op's name, the cotangent half under the
+    grad op's, and nothing of the loss under any other."""
+    prog, startup, (_, loss, _) = _transformer(n_layer=1)
+    names = collections.Counter(re.findall(
+        r'loc\("jit\(fn_s1\)/([^"]*softmax_with_cross_entropy[^"]*)"',
+        _lowered(prog, startup, _feed(), loss).as_text(debug_info=True)))
+    assert {name.rsplit("/", 1)[0].split("/jit(")[0] for name in names} == \
+        {LOSS, LOSS_GRAD}
+    for primitive in ("reduce_max", "reduce_sum", "log"):
+        assert names[f"{LOSS}/{primitive}"] == 2, primitive
+        assert f"{LOSS_GRAD}/{primitive}" not in names
+    for primitive in ("iota", "eq", "exp", "mul"):      # softmax - onehot
+        assert names[f"{LOSS_GRAD}/{primitive}"] == 1, primitive
+    assert f"{LOSS}/iota" not in names
+
+
+def test_the_lse_that_cse_kept_is_the_forward_ops(lowered):
+    _, _, text = lowered
+    names = [n for _, n in _instructions(text)
+             if "softmax_with_cross_entropy" in n]
+    assert {"fwd" if f"/{LOSS}/" in n else
+            "bwd" if f"/{LOSS_GRAD}/" in n else n for n in names} == \
+        {"fwd", "bwd"}
+    assert any(n.endswith(f"/{LOSS}/log") for n in names)
+    assert not any(n.endswith(("/log", "/reduce_max")) for n in names
+                   if f"/{LOSS_GRAD}/" in n)
 
 
 def test_the_models_scope_names_do_not_depend_on_what_was_built_before():

@@ -180,7 +180,8 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
     setup_s = result.w0 - t_process_start
     ttft, tbt = loadgen.latency_samples(result)
     values = {"setup_s": setup_s,
-              "served_tokens_per_s": loadgen.served_tokens(result) / seconds}
+              "served_tokens_per_s": loadgen.served_tokens(result) / seconds,
+              "tbt_p50_ms": loadgen.window_gap_p50_ms(result)}
     if ttft:
         values["ttft_p50_ms"] = harness.percentile(ttft, 0.50)
     if tbt:

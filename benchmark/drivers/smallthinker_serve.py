@@ -559,7 +559,8 @@ def run(cell, args, log, t_process_start: float, devices) -> dict:
     setup_s = result.w0 - t_process_start
     ttft, tbt = loadgen.latency_samples(result)
     values = {"setup_s": setup_s,
-              "served_tokens_per_s": loadgen.served_tokens(result) / seconds}
+              "served_tokens_per_s": loadgen.served_tokens(result) / seconds,
+              "tbt_p50_ms": loadgen.window_gap_p50_ms(result)}
     print(f"bench latency: ttft_ms p50 {harness.percentile(ttft, 0.5):.2f} "
           f"p90 {harness.percentile(ttft, 0.9):.2f} over {len(ttft)} requests; "
           f"tbt_ms p50 {harness.percentile(tbt, 0.5):.2f} "

@@ -8,6 +8,13 @@ metric has ``benchmark/metrics/<metric>.json`` naming its reader.  No cell,
 configuration, mix or metric is known to this code by a literal, so a later
 PR adds any of them as new files and entries and edits nothing that is here.
 
+A metric is entered once.  Its entry in ``BENCHMARK.json`` says what it is
+(unit, direction, source, layer, the end-to-end metric it moves) and which
+cells read it; its file says how it is read (``what``, ``reader``, ``args``)
+and repeats nothing of the entry.  An entry with no ``workloads`` list is
+read in every cell that reports the end-to-end metric it moves, so a new cell
+joins those metrics by being listed under that end-to-end metric alone.
+
 Importing this module imports neither JAX nor the program.
 """
 from __future__ import annotations
@@ -51,13 +58,54 @@ def load_manifest(root: str) -> dict:
 
 
 def _under_paths(manifest: dict, rel: str) -> bool:
-    return any(rel == p or rel.startswith(p.rstrip("/") + "/")
-               for p in manifest["paths"])
+    """Whether ``rel``, a path from the root written with no ``..`` and no
+    detour, lies in one of the benchmark's own directories."""
+    return isinstance(rel, str) and os.path.normpath(rel) == rel and any(
+        rel == p or rel.startswith(p.rstrip("/") + "/")
+        for p in manifest["paths"])
 
 
-def metric_applies(metric: dict, workload: str) -> bool:
+def yardstick_module(root: str, manifest: dict, rel) -> Optional[str]:
+    """The module a metric's file names (its reader, or an argument that
+    ends in ``.py``), where it is a file under ``paths``: a metric may run no
+    code the benchmark does not own.  None otherwise."""
+    if not _under_paths(manifest, rel):
+        return None
+    path, top = os.path.join(root, rel), os.path.realpath(root)
+    inside = os.path.realpath(path).startswith(top + os.sep)   # no link out
+    return path if inside and os.path.isfile(path) else None
+
+
+# what a metric's own file holds; everything else about it is its entry's
+METRIC_FILE_KEYS = ("what", "reader", "args")
+
+
+def metric_applies(metric: dict, workload: str,
+                   end_to_end: Sequence[dict] = ()) -> bool:
+    """Whether ``workload`` reports ``metric``.  A list of cells decides
+    where there is one.  Without one, an end-to-end metric is every cell's,
+    and a per-layer metric belongs to every cell that reports the end-to-end
+    metric it ``moves`` (one of ``end_to_end``)."""
     cells = metric.get("workloads")
-    return cells is None or workload in cells
+    if cells is not None:
+        return workload in cells
+    moved = metric.get("moves")
+    return moved is None or any(
+        m.get("name") == moved and metric_applies(m, workload)
+        for m in end_to_end)
+
+
+def cell_metrics(manifest: dict, workload: str) -> tuple:
+    """The end-to-end and the per-layer entries that ``workload`` reports."""
+    e2e = [m for m in manifest["end_to_end"] if metric_applies(m, workload)]
+    return e2e, [m for m in manifest["per_layer"]
+                 if metric_applies(m, workload, e2e)]
+
+
+def bench_dir(root: str, config_file: str) -> str:
+    """The benchmark's own directory: where a configuration's file lives,
+    one level up."""
+    return os.path.dirname(os.path.dirname(os.path.join(root, config_file)))
 
 
 class Cell:
@@ -70,6 +118,7 @@ class Cell:
                 f"no workload {workload!r} in {MANIFEST}; it has "
                 f"{sorted(by_name)}")
         self.root = root
+        self.manifest = manifest
         self.entry = by_name[workload]
         self.name = workload
         self.chips = int(self.entry["chips"])
@@ -81,9 +130,7 @@ class Cell:
                 f"{self.entry['config']!r}, which {MANIFEST} does not list")
         self.config = _read_json(os.path.join(root, cfg_entry["file"]))
         self.config_name = cfg_entry["name"]
-        # the benchmark's own directory is where the configuration lives
-        self.bench_dir = os.path.dirname(os.path.dirname(
-            os.path.join(root, cfg_entry["file"])))
+        self.bench_dir = bench_dir(root, cfg_entry["file"])
         self.mix_name = self.entry["traffic"]
         self.mix = _read_json(os.path.join(self.bench_dir, "traffic",
                                            self.mix_name + ".json"))
@@ -92,27 +139,27 @@ class Cell:
             raise ConfigurationError(
                 f"{cfg_entry['file']}: 'kind' must name a driver module")
         self.kind = kind
-        self.end_to_end = [m for m in manifest["end_to_end"]
-                           if metric_applies(m, workload)]
-        self.per_layer = [m for m in manifest["per_layer"]
-                          if metric_applies(m, workload)]
+        self.end_to_end, self.per_layer = cell_metrics(manifest, workload)
 
     def driver(self):
         path = os.path.join(self.bench_dir, "drivers", self.kind + ".py")
         return load_module(path, f"benchmark_driver_{self.kind}")
 
     def metric_file(self, metric: str) -> dict:
-        return _read_json(os.path.join(self.bench_dir, "metrics",
-                                       metric + ".json"))
+        """A per-layer metric's own file, held to what ``check_manifest``
+        holds it to."""
+        faults: List[str] = []
+        spec = _metric_file(self.root, self.manifest, os.path.join(
+            self.bench_dir, "metrics", metric + ".json"), faults)
+        if faults:
+            raise ConfigurationError("; ".join(faults))
+        return spec
 
     def reader(self, metric: str) -> Callable:
         """The ``read(ctx)`` function of a per-layer metric, found through
         the metric's own file."""
         spec = self.metric_file(metric)
-        rel = spec.get("reader")
-        if not isinstance(rel, str):
-            raise ConfigurationError(
-                f"metrics/{metric}.json names no 'reader'")
+        rel = spec["reader"]
         mod = load_module(os.path.join(self.root, rel),
                           "benchmark_reader_" + re.sub(r"\W", "_", rel))
         return lambda ctx: mod.read(ctx, **spec.get("args", {}))
@@ -216,23 +263,82 @@ def check_manifest(root: str, manifest: dict) -> List[str]:
         for w in m.get("workloads", []):
             if w not in seen["workload"]:
                 faults.append(f"metric {m.get('name')!r} lists cell {w!r}")
+    files = {c.get("name"): c.get("file") for c in manifest["configs"]
+             if isinstance(c.get("file"), str)}
+    read_in: Dict[str, list] = {}
     for w in manifest["workloads"]:
         name = w.get("name")
-        mine_e2e = {m["name"] for m in manifest["end_to_end"]
-                    if metric_applies(m, name)}
-        mine_pl = [m for m in manifest["per_layer"] if metric_applies(m, name)]
+        e2e_here, mine_pl = cell_metrics(manifest, name)
+        mine_e2e = {m["name"] for m in e2e_here}
         if "setup_s" not in mine_e2e or len(mine_e2e) < 2:
             faults.append(f"cell {name!r} reports {sorted(mine_e2e)}")
         if not mine_pl:
             faults.append(f"cell {name!r} reports no per-layer metric")
         for m in mine_pl:
+            read_in.setdefault(m.get("name"), []).append(w)
             if m["moves"] not in mine_e2e:
                 faults.append(f"cell {name!r}: {m['name']!r} moves "
                               f"{m['moves']!r}, which the cell does not report")
+    entered = set()
+    for m in manifest["per_layer"]:
+        cells = read_in.get(m.get("name"))
+        if not cells:
+            faults.append(f"metric {m.get('name')!r} is read in no cell")
+            continue
+        if cells[0].get("config") not in files:
+            continue
+        spec = _metric_file(root, manifest, os.path.join(
+            bench_dir(root, files[cells[0]["config"]]), "metrics",
+            str(m.get("name")) + ".json"), faults)
+        if spec is None:
+            continue
+        # one reader with the same arguments that moves the same metric is
+        # one metric: a cell joins it by its list, not by a copy of it
+        same = (spec["reader"], json.dumps(spec.get("args") or {},
+                                           sort_keys=True), m.get("moves"))
+        if same in entered:
+            faults.append(f"metric {m.get('name')!r} has the reader, the "
+                          f"args and the 'moves' of another entry: a copy")
+        entered.add(same)
     rs = manifest["run_seconds"]
     if not (isinstance(rs, int) and 1 <= rs <= 51):
         faults.append(f"run_seconds {rs!r}")
     return faults
+
+
+def _metric_file(root: str, manifest: dict, path: str,
+                 faults: List[str]) -> Optional[dict]:
+    """A per-layer metric's own file, or None with the fault filed: it holds
+    ``what``, ``reader`` and, where the reader takes any, ``args`` — and
+    nothing of the manifest's entry, so the two cannot disagree.  The reader,
+    and any argument that names a module (a string ending in ``.py``: the
+    roofline reader's ``counts``), is a file under ``paths``."""
+    rel = os.path.relpath(path, root)
+    try:
+        spec = _read_json(path)
+    except ConfigurationError as e:
+        faults.append(str(e))
+        return None
+    extra = sorted(set(spec) - set(METRIC_FILE_KEYS))
+    if extra:
+        faults.append(f"{rel} carries {extra}: the manifest's entry says "
+                      f"that, the file only {list(METRIC_FILE_KEYS)}")
+    reader = spec.get("reader")
+    if not (isinstance(spec.get("what"), str) and spec["what"]):
+        faults.append(f"{rel} does not say 'what' it reads")
+    if not isinstance(spec.get("args", {}), dict):
+        faults.append(f"{rel}: 'args' is not an object")
+        return None
+    if yardstick_module(root, manifest, reader) is None:
+        faults.append(f"{rel} names no reader under paths: {reader!r}")
+        return None
+    for key, value in sorted(spec.get("args", {}).items()):
+        if isinstance(value, str) and value.endswith(".py") \
+                and yardstick_module(root, manifest, value) is None:
+            faults.append(f"{rel}: {key!r} names no module under paths: "
+                          f"{value!r}")
+            return None
+    return spec
 
 
 # ---------------------------------------------------------------------------

@@ -1,6 +1,7 @@
 """What the Falcon-H1 stack's new kernels must do, in operations and bytes:
 the counting functions of their roofline shares
-(``benchmark/metrics/kernel_roofline_falconh1.py``), beside
+(``benchmark/metrics/kernel_roofline.py``, which a metric's ``counts``
+argument points here), beside
 ``kernel_counts.py`` and under its rules.
 
 Only what a kernel MUST do is counted — the recurrence's own operations a

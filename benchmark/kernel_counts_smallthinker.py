@@ -1,6 +1,7 @@
 """What the SmallThinker stack's kernels must do, in operations and bytes:
 the counting functions of their roofline shares
-(``benchmark/metrics/kernel_roofline_smallthinker.py``), beside
+(``benchmark/metrics/kernel_roofline.py``, which a metric's ``counts``
+argument points here), beside
 ``kernel_counts.py`` and under its rules.
 
 Only what a kernel MUST do is counted, whatever implements it — the

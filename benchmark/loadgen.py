@@ -23,7 +23,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
-from benchmark.harness import Accounting, ConfigurationError
+from benchmark.harness import Accounting, ConfigurationError, percentile
 
 
 class Request:
@@ -352,6 +352,20 @@ def latency_samples(result: LoadResult):
         t = req.t_tokens
         tbt.extend((t[i] - t[i - 1]) * 1e3 for i in range(1, len(t)))
     return ttft, tbt
+
+
+def window_gap_p50_ms(result: LoadResult) -> Optional[float]:
+    """The median gap between consecutive tokens of one stream at the client,
+    over EVERY gap that ends inside the window — whichever request the stream
+    belongs to, the lead-in's too, as :func:`served_tokens` counts.  What a
+    reader of a long stream feels per token while the server is full.  One
+    gap a stream is all that a pause of the whole machine lengthens, so the
+    median stands where the window's rate loses the pause; None where no gap
+    ended in the window."""
+    gaps = [(req.t_tokens[i] - req.t_tokens[i - 1]) * 1e3
+            for req in result.sent for i in range(1, len(req.t_tokens))
+            if result.w0 <= req.t_tokens[i] < result.w1]
+    return percentile(gaps, 0.5) if gaps else None
 
 
 def served_tokens(result: LoadResult) -> int:

@@ -12,9 +12,13 @@ observes it and before it dispatches the next, so a launch's span is the
 first one that starts after the launch ends and before the next launch of the
 program starts.  A launch counts only if it lies wholly in the traced window
 and has its span: the kernel's seconds are summed over those launches, the
-work (``benchmark/kernel_counts.COUNTS[count]`` over the summed arguments)
-over the same ones, and a prompt-length mix that differs between the traced
-seconds and the whole window moves nothing.
+work (``COUNTS[count]`` over the summed arguments) over the same ones, and a
+prompt-length mix that differs between the traced seconds and the whole
+window moves nothing.  ``counts`` names the file whose ``COUNTS`` holds the
+model's counting functions, by its path from the checkout's root
+(``benchmark/kernel_counts.py`` where a metric names none): a model brings a
+counts file and metric files, and no reader.  A path that leaves the
+benchmark's own directories is refused (by ``check_manifest`` and here).
 
 The share, in %, is the larger of operations per second over the chip's bf16
 peak and bytes per second over its HBM peak.  Nothing where the run has no
@@ -25,10 +29,13 @@ One pass: launches, spans and leaf operations are sorted once and walked
 together.
 """
 import bisect
+import functools
 import re
 
-from benchmark import kernel_counts, peaks, trace_reduce
+from benchmark import harness, peaks, trace_reduce
 from benchmark.metrics import program_spans
+
+DEFAULT_COUNTS = "benchmark/kernel_counts.py"
 
 # the device plane's clock and the host's differ by under a millisecond on a
 # v5e; a launch lasts tens of them
@@ -77,13 +84,27 @@ def timed(raw, spans, program, kernel, span):
     return inside * 1e-9, work, len(kept)
 
 
-def share(ctx, spans, program, kernel, count, span):
+@functools.lru_cache(maxsize=None)
+def counting(counts):
+    """The ``COUNTS`` of the counts file at ``counts``, a path from the root
+    of the checkout to a file under the benchmark's ``paths``: the operations
+    and bytes are the yardstick's to count, never the program's."""
+    root = program_spans.ROOT
+    path = harness.yardstick_module(root, harness.load_manifest(root), counts)
+    if path is None:
+        raise harness.ConfigurationError(
+            f"'counts' names no module under paths: {counts!r}")
+    return harness.load_module(
+        path, "benchmark_counts_" + re.sub(r"\W", "_", counts)).COUNTS
+
+
+def share(ctx, spans, program, kernel, count, span, counts=DEFAULT_COUNTS):
     got = timed(ctx["trace_raw"], spans, program, kernel, span)
     if got is None or got[0] <= 0:
         return None
     seconds, work, _ = got
     try:
-        ops, moved = kernel_counts.COUNTS[count](ctx["config"], work)
+        ops, moved = counting(counts)[count](ctx["config"], work)
     except KeyError:
         return None
     peak = peaks.peaks_for(ctx["memory"]["kind"])
@@ -91,8 +112,8 @@ def share(ctx, spans, program, kernel, count, span):
                        moved / seconds / peak["hbm_bytes_per_s"])
 
 
-def read(ctx, program, kernel, count, span):
+def read(ctx, program, kernel, count, span, counts=DEFAULT_COUNTS):
     spans = program_spans.load() if ctx.get("trace_raw") else None
     if not spans:
         return None
-    return share(ctx, spans, program, kernel, count, span)
+    return share(ctx, spans, program, kernel, count, span, counts)

@@ -17,6 +17,7 @@ import time
 T_PROCESS_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
 
@@ -75,6 +76,10 @@ def main(argv=None) -> int:
 
     out = driver.run(cell, args, log, T_PROCESS_START, devices)
     acct, checks = out["acct"], out["checks"]
+    # every end-to-end number the driver took, whichever of them the manifest
+    # has this cell report: the others are there to be read in the log
+    print("bench values:", json.dumps(out["values"], default=float),
+          flush=True)
     print(acct.line(), flush=True)
     for ex in acct.examples:
         print("bench failure example:", ex, flush=True)

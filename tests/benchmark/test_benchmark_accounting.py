@@ -216,3 +216,50 @@ def test_classes_of_failure():
     acct.record(False, "error", "lead-in")
     assert (acct.attempted, acct.failed, acct.failed_outside) == (1, 1, 1)
     assert json.dumps(acct.by_class)
+
+
+def _stream(times):
+    r = loadgen.Request(0, np.zeros(4, np.int32), len(times))
+    r.t_send, r.t_tokens, r.tokens = times[0] - 0.5, list(times), [1] * len(times)
+    return r
+
+
+def test_the_window_gap_median_counts_every_gap_that_ends_in_the_window():
+    """Whichever request a stream belongs to (the lead-in's too): a gap counts
+    where its later token arrives inside the window, as served_tokens counts."""
+    early = _stream([8.0, 9.5, 10.5, 12.0])        # gaps 1500 | 1000, 1500 in
+    late = _stream([11.0, 13.0, 19.5, 21.0])       # gaps 2000, 6500 in | 1500
+    result = loadgen.LoadResult(10.0, 20.0, [early, late], [])
+    # inside: 1000, 1500, 2000, 6500
+    assert loadgen.window_gap_p50_ms(result) == pytest.approx(1750.0)
+    assert loadgen.window_gap_p50_ms(
+        loadgen.LoadResult(30.0, 40.0, [early, late], [])) is None
+    silent = loadgen.Request(1, np.zeros(4, np.int32), 4)   # never answered
+    silent.t_send = 11.0
+    assert loadgen.window_gap_p50_ms(
+        loadgen.LoadResult(10.0, 20.0, [early, late, silent], [])) \
+        == pytest.approx(1750.0)
+
+
+@pytest.mark.parametrize("pause_s", [0.0, 1.5, 4.5])
+def test_a_pause_of_the_machine_moves_the_rate_and_not_the_gap_median(pause_s):
+    """64 streams, a token each 25 ms for 45 s; the whole machine stands still
+    once for ``pause_s``: the window's rate loses the pause, each stream has
+    ONE long gap of 1,800, and the median does not move."""
+    step, w0, w1 = 0.025, 1.0, 46.0
+
+    def run(pause):
+        streams = []
+        for s in range(64):
+            t, ts = 0.5 + 1e-4 * s, []
+            while t < w1 + 1.0:
+                ts.append(t)
+                t += step + (pause if abs(t - 20.0) < step / 2 else 0.0)
+            streams.append(_stream(ts))
+        return loadgen.LoadResult(w0, w1, streams, [])
+
+    calm, paused = run(0.0), run(pause_s)
+    assert loadgen.window_gap_p50_ms(paused) == pytest.approx(
+        loadgen.window_gap_p50_ms(calm), rel=1e-6) == pytest.approx(25.0)
+    lost = 1.0 - loadgen.served_tokens(paused) / loadgen.served_tokens(calm)
+    assert lost == pytest.approx(pause_s / 45.0, abs=2e-3)

@@ -106,14 +106,13 @@ def test_the_new_cell_is_the_one_the_issue_names():
     assert {m["name"] for m in cell.end_to_end} == \
         {"served_tokens_per_s", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
-    assert {n for n in names if n.endswith("fh1")} == {
-        "decode_step_ms.served_fh1", "prefill_ms.served_fh1",
-        "step_host_ms.served_fh1", "step_emit_ms.served_fh1",
-        "device_idle_share.served_fh1", "idle_engine_host_share.served_fh1",
-        "idle_no_work_share.served_fh1", "hbm_peak_gb.served_fh1",
-        "hbm_temp_gb.served_fh1", "tokens_per_decode_step.served_fh1",
-        "top_device_op_share.served_fh1", "prefill_pad_share.served_fh1",
-        "live_context_tokens.served_fh1", "program_build_s.fh1",
+    # the decode-plane and device family under its one name, joined through
+    # served_tokens_per_s, and the model's own (a later PR may add to either)
+    family = {m["name"] for m in MANIFEST["per_layer"] if "workloads" not in m
+              and m["moves"] in {e["name"] for e in cell.end_to_end}}
+    assert "decode_step_ms.served" in family
+    assert names >= family | {
+        "prefill_pad_share.served", "live_context_tokens.served",
         "ssd_share.served_fh1", "gqa_attn_share.served_fh1",
         "ssd_scan_prefill_roofline.served_fh1",
         "ssd_state_step_roofline.served_fh1",
@@ -121,8 +120,13 @@ def test_the_new_cell_is_the_one_the_issue_names():
         "gqa_prefill_attn_roofline.served_fh1"}
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
-    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
-    assert len(MANIFEST["workloads"]) == 7 and len(MANIFEST["configs"]) == 5
+        if m["name"].endswith("fh1"):       # the model's own: this cell alone
+            assert m["workloads"] == [CELL]
+    # four chips where the measured thing exists only across chips: at most
+    # a quarter of the cells, and one always may (the contract)
+    assert 1 <= sum(w["chips"] == 4 for w in MANIFEST["workloads"]) \
+        <= max(1, len(MANIFEST["workloads"]) // 4)
+    assert cell.config_name in {c["name"] for c in MANIFEST["configs"]}
     from paddle_tpu.decode.falcon_h1 import param_shapes
     params = sum(int(np.prod(s)) for s, _ in param_shapes(
         cell.driver().model_config(cell.config)).values())
@@ -337,45 +341,3 @@ def test_the_counting_functions_against_hand_worked_numbers():
     assert set(kernel_counts_falconh1.COUNTS) == {
         "ssd_scan_prefill", "ssd_state_step", "gqa_decode_attn",
         "gqa_prefill_attn"}
-
-
-def test_the_new_reader_counts_work_over_the_very_launches_it_times():
-    from benchmark import peaks
-    ms = 1e6
-    raw = {"host": [["bench.window", 0.0, 100 * ms]], "devices": {"/device:TPU:0": {
-        "modules": [["jit_fn_decode_lm_step(1)", 10 * ms, 30 * ms],
-                    ["jit_fn_decode_lm_step(1)", 50 * ms, 30 * ms]],
-        "ops": [["%ssd_state_step.1 = f32[8]{0} custom-call()", 11 * ms, 2 * ms],
-                ["%gqa_paged_decode_attn.2 = f32[8]{0} custom-call()", 14 * ms, 1 * ms],
-                ["%ssd_state_step.1 = f32[8]{0} custom-call()", 20 * ms, 6 * ms],
-                ["%ssd_state_step.1 = f32[8]{0} custom-call()", 51 * ms, 10 * ms]]}}}
-    row = 6 * 2 * 4 * 32 * 256 * 128
-    spans = {"spans": [
-        ["decode::step.observe", 1, 40.1 * ms, 0.1 * ms,
-         {"step_context_tokens": 100000, "step_streams": 64,
-          "step_state_bytes": 64 * row}]]}
-    cfg = {"num_hidden_layers": 6, "num_attention_heads": 20,
-           "num_key_value_heads": 4, "head_dim": 128, "mamba_d_ssm": 4096,
-           "mamba_d_state": 256, "kv_dtype": "bfloat16"}
-    ctx = {"trace_raw": raw, "config": cfg, "memory": {"kind": "TPU v5 lite"}}
-    mod = harness.load_module(
-        os.path.join(REPO, "benchmark", "metrics",
-                     "kernel_roofline_falconh1.py"), "reader_under_test_fh1")
-    from benchmark.metrics import program_spans
-    peak = peaks.peaks_for("TPU v5 lite")
-    cell = harness.Cell(REPO, MANIFEST, CELL)
-    args = cell.metric_file("ssd_state_step_roofline.served_fh1")["args"]
-    # the second launch has no span (the trace stopped): not timed, not counted
-    old, program_spans.load = program_spans.load, lambda: spans
-    try:
-        assert mod.read(ctx, **args) == pytest.approx(
-            100 * 64 * row / 8e-3 / peak["hbm_bytes_per_s"])
-        walk = cell.metric_file("gqa_decode_attn_roofline.served_fh1")["args"]
-        assert mod.read(ctx, **walk) == pytest.approx(
-            100 * 100000 * 6 * 2048 / 1e-3 / peak["hbm_bytes_per_s"])
-        # the parent: no such kernel, no such count, no trace
-        assert mod.read(ctx, **dict(args, kernel="^absent")) is None
-        assert mod.read(ctx, **dict(args, count="absent")) is None
-        assert mod.read(dict(ctx, trace_raw=None), **args) is None
-    finally:
-        program_spans.load = old

@@ -298,3 +298,17 @@ def test_the_bench_time_line_names_every_phase_and_the_slow_readers():
     assert ("setup=33.5 lead_in_and_window=51.0 drain=1.5 | inside those: "
             "stop_trace=2.3 reader:step_host_ms.tbt=1.3") in line
     assert " | " not in harness.Phases(0.0).line()
+
+
+def test_end_to_end_value_hands_on_a_number_the_driver_took_and_invents_none():
+    """``served_tokens_per_s.tbt50``: the window's rate, recorded under another
+    name in a cell where it is not judged."""
+    from benchmark import harness
+    root = os.path.join(os.path.dirname(__file__), "..", "..")
+    mod = harness.load_module(os.path.join(
+        root, "benchmark", "metrics", "end_to_end_value.py"), "e2e_value")
+    ctx = {"end_to_end": {"served_tokens_per_s": 5555.5, "tbt_p50_ms": None}}
+    assert mod.read(ctx, metric="served_tokens_per_s") == 5555.5
+    assert mod.read(ctx, metric="tbt_p50_ms") is None
+    assert mod.read(ctx, metric="ttft_p50_ms") is None
+    assert mod.read({}, metric="served_tokens_per_s") is None

@@ -254,3 +254,27 @@ def test_counter_ratio_and_the_attention_counts():
     assert (ops, moved) == (2 * 16 * 320 * 2.0 * 7, 0.0)
     ops, moved = kernel_counts.mla_decode_attn(cfg, {"step_context_tokens": 10.0})
     assert ops == 2 * 16 * (576 + 512) * 70 and moved == 70 * 576 * 2
+
+
+def test_the_cell_reads_the_serve_family_and_its_own_metrics():
+    manifest = harness.load_manifest(REPO)
+    cell = harness.Cell(REPO, manifest, "dsv2l_doc_sat")
+    assert {m["name"] for m in cell.end_to_end} == \
+        {"served_tokens_per_s", "setup_s"}
+    # the decode-plane and device family under its one name, joined through
+    # served_tokens_per_s, and the model's own (a later PR may add to either)
+    family = {m["name"] for m in manifest["per_layer"] if "workloads" not in m
+              and m["moves"] in {e["name"] for e in cell.end_to_end}}
+    assert "decode_step_ms.served" in family
+    assert {m["name"] for m in cell.per_layer} >= family | {
+        "prefill_pad_share.served",
+        "moe_share.served_ds", "mla_share.served_ds",
+        "moe_prefill_roofline.served_ds", "moe_step_roofline.served_ds",
+        "mla_prefill_attn_roofline.served_ds",
+        "mla_decode_attn_roofline.served_ds",
+        "expert_load_max_over_mean.served_ds",
+        "experts_touched_per_step.served_ds"}
+    for m in cell.per_layer:
+        cell.reader(m["name"])              # every reader is found by name
+        if m["name"].endswith("served_ds"):     # the model's own: this cell alone
+            assert m["workloads"] == [cell.name]

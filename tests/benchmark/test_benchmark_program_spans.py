@@ -221,17 +221,19 @@ def test_the_counter_reader_scales_a_counter_and_skips_a_missing_one():
 
 SPAN_READERS = ("span_mean.py", "span_arg_percentile.py",
                 "idle_under_spans.py")
-SPAN_METRICS = [m["name"] for m in MANIFEST["per_layer"]
-                if m["source"] == "program_span"]
+# every (span metric, cell that reads it)
+SPAN_PAIRS = [(m["name"], w["name"]) for w in MANIFEST["workloads"]
+              for m in harness.cell_metrics(MANIFEST, w["name"])[1]
+              if m["source"] == "program_span"]
 
 
-@pytest.mark.parametrize("metric", SPAN_METRICS)
+@pytest.mark.parametrize("metric, workload", SPAN_PAIRS)
 def test_a_span_metric_reads_nothing_from_a_checkout_with_no_trace(
-        metric, tmp_path, monkeypatch):
+        metric, workload, tmp_path, monkeypatch):
     """No ``.bench_trace`` in the checkout: the metric's reader, found
-    through its own file, reports nothing rather than raising."""
-    (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == metric]
-    cell = harness.Cell(REPO, MANIFEST, entry["workloads"][0])
+    through its own file by each cell that reads it, reports nothing rather
+    than raising."""
+    cell = harness.Cell(REPO, MANIFEST, workload)
     assert os.path.basename(cell.metric_file(metric)["reader"]) in SPAN_READERS
     monkeypatch.setattr(program_spans, "ROOT", str(tmp_path))
     assert cell.reader(metric)({}) is None

@@ -5,6 +5,7 @@ lower-precision controls and three planted faults of
 ``benchmark/sambay_controls.py`` through the same functions; the new cell's
 entries; the counting functions against hand-worked numbers."""
 import importlib.util
+import json
 import os
 import sys
 
@@ -79,19 +80,43 @@ def test_the_new_cell_is_the_one_the_issue_names():
     assert mix["engine"]["prefill_buckets"][-1] == 3072
     assert (mix["engine"]["num_blocks"] - 1) \
         * mix["engine"]["block_tokens"] >= 262144
-    assert {m["name"] for m in cell.end_to_end} == \
-        {"served_tokens_per_s", "setup_s"}
+    # judged by the median gap between a stream's tokens: the window's rate
+    # swings with a pause of the machine by more than any bound here (PR 43)
+    # and is recorded per layer under another name
+    assert {m["name"] for m in cell.end_to_end} == {"tbt_p50_ms", "setup_s"}
+    rate = [m for m in MANIFEST["end_to_end"]
+            if m["name"] == "served_tokens_per_s"][0]
+    assert CELL not in rate["workloads"]
     names = {m["name"] for m in cell.per_layer}
-    assert {n for n in names if n.endswith(".served_p4f")} >= {
-        "decode_step_ms.served_p4f", "shared_kv_attn_share.served_p4f",
+    # the decode-plane and device family under its one name, joined through
+    # tbt_p50_ms, and the model's own (a later PR may add to either)
+    family = {m["name"] for m in MANIFEST["per_layer"] if "workloads" not in m
+              and m["moves"] in ("tbt_p50_ms", "setup_s")}
+    assert "decode_step_ms.tbt50" in family
+    assert names >= family | {
+        "served_tokens_per_s.tbt50", "prefill_pad_share.tbt50",
+        "live_context_tokens.tbt50", "shared_kv_attn_share.served_p4f",
         "swa_share.served_p4f", "ssm_share.served_p4f",
-        "live_context_tokens.served_p4f",
         "shared_kv_decode_attn_roofline.served_p4f",
         "swa_decode_attn_roofline.served_p4f",
         "ssm_scan_prefill_roofline.served_p4f",
         "swa_prefill_attn_roofline.served_p4f"}
+    # every reading the saturated serve cells take under `.served`, this cell
+    # takes under `.tbt50` from the same reader with the same arguments
+    served = {m["name"] for m in MANIFEST["per_layer"]
+              if m["moves"] == "served_tokens_per_s"
+              and m["name"].endswith(".served")}
+    for name in served:
+        twin = name[:-len("served")] + "tbt50"
+        assert twin in names, twin
+        a = json.load(open(os.path.join(REPO, "benchmark", "metrics",
+                                        name + ".json")))
+        b = cell.metric_file(twin)
+        assert (a["reader"], a.get("args")) == (b["reader"], b.get("args"))
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
+        if m["name"].endswith("p4f"):       # the model's own: this cell alone
+            assert m["workloads"] == [CELL]
     from paddle_tpu.decode.sambay import param_shapes
     params = sum(int(np.prod(s)) for s, _ in param_shapes(
         cell.driver().model_config(cell.config)).values())
@@ -282,46 +307,3 @@ def test_the_counting_functions_against_hand_worked_numbers():
     assert set(kernel_counts_sambay.COUNTS) == {
         "shared_kv_decode_attn", "swa_decode_attn", "ssm_scan_prefill",
         "swa_prefill_attn"}
-
-
-def test_the_new_reader_counts_work_over_the_very_launches_it_times():
-    from benchmark import peaks
-    ms = 1e6
-    raw = {"host": [["bench.window", 0.0, 100 * ms]], "devices": {"/device:TPU:0": {
-        "modules": [["jit_fn_decode_lm_step(1)", 10 * ms, 30 * ms],
-                    ["jit_fn_decode_lm_step(1)", 50 * ms, 30 * ms]],
-        "ops": [["%diff_paged_decode_attn.1 = f32[8]{0} custom-call()", 11 * ms, 2 * ms],
-                ["%diff_ring_decode_attn.2 = f32[8]{0} custom-call()", 14 * ms, 1 * ms],
-                ["%diff_paged_decode_attn.3 = f32[8]{0} custom-call()", 20 * ms, 8 * ms],
-                ["%diff_paged_decode_attn.1 = f32[8]{0} custom-call()", 51 * ms, 10 * ms]]}}}
-    spans = {"spans": [
-        ["decode::step.observe", 1, 40.1 * ms, 0.1 * ms,
-         {"step_context_tokens": 100000, "step_window_tokens": 30000,
-          "step_streams": 64}]]}
-    cfg = {"hidden_size": 2560, "num_attention_heads": 40,
-           "num_key_value_heads": 20, "num_hidden_layers": 32,
-           "kv_dtype": "bfloat16"}
-    ctx = {"trace_raw": raw, "config": cfg, "memory": {"kind": "TPU v5 lite"}}
-    mod = harness.load_module(
-        os.path.join(REPO, "benchmark", "metrics",
-                     "kernel_roofline_sambay.py"), "reader_under_test_p4f")
-    from benchmark.metrics import program_spans
-    peak = peaks.peaks_for("TPU v5 lite")
-    args = harness.Cell(REPO, MANIFEST, CELL).metric_file(
-        "shared_kv_decode_attn_roofline.served_p4f")["args"]
-    # the second launch has no span (the trace stopped): not timed, not counted
-    old, program_spans.load = program_spans.load, lambda: spans
-    try:
-        got = mod.read(ctx, **args)
-        assert got == pytest.approx(
-            100 * 100000 * 8 * 5120 / 10e-3 / peak["hbm_bytes_per_s"])
-        ring = harness.Cell(REPO, MANIFEST, CELL).metric_file(
-            "swa_decode_attn_roofline.served_p4f")["args"]
-        assert mod.read(ctx, **ring) == pytest.approx(
-            100 * 30000 * 8 * 5120 / 1e-3 / peak["hbm_bytes_per_s"])
-        # the parent: no such kernel, no such count, no trace
-        assert mod.read(ctx, **dict(args, kernel="^absent")) is None
-        assert mod.read(ctx, **dict(args, count="absent")) is None
-        assert mod.read(dict(ctx, trace_raw=None), **args) is None
-    finally:
-        program_spans.load = old

@@ -117,13 +117,15 @@ def test_the_new_cell_is_the_one_the_issue_names():
     assert {m["name"] for m in cell.end_to_end} == \
         {"served_tokens_per_s", "setup_s"}
     names = {m["name"] for m in cell.per_layer}
-    assert {n for n in names if n.endswith(("served_st", ".st"))} == {
-        "decode_step_ms.served_st", "prefill_ms.served_st",
-        "step_host_ms.served_st", "device_idle_share.served_st",
-        "idle_engine_host_share.served_st", "hbm_peak_gb.served_st",
-        "hbm_temp_gb.served_st", "tokens_per_decode_step.served_st",
-        "top_device_op_share.served_st", "prefill_pad_share.served_st",
-        "live_context_tokens.served_st", "program_build_s.st",
+    # the decode-plane and device family under its one name, joined through
+    # served_tokens_per_s (step_emit_ms and idle_no_work_share with it, which
+    # this cell's own PR had no room for), and the model's own (a later PR
+    # may add to either)
+    family = {m["name"] for m in MANIFEST["per_layer"] if "workloads" not in m
+              and m["moves"] in {e["name"] for e in cell.end_to_end}}
+    assert "decode_step_ms.served" in family
+    assert names >= family | {
+        "prefill_pad_share.served", "live_context_tokens.served",
         "moe_share.served_st", "window_attn_share.served_st",
         "full_attn_share.served_st", "expert_load_max_over_mean.served_st",
         "ring_live_share.served_st", "moe_prefill_roofline.served_st",
@@ -134,10 +136,12 @@ def test_the_new_cell_is_the_one_the_issue_names():
         "full_decode_attn_roofline.served_st"}
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
-        if m["name"].endswith(("served_st", ".st")):
+        if m["name"].endswith("served_st"):   # its own: this cell alone
             assert m["workloads"] == [CELL]
-    assert sum(w["chips"] == 4 for w in MANIFEST["workloads"]) == 1
-    assert len(MANIFEST["workloads"]) >= 8 and len(MANIFEST["configs"]) >= 6
+    # four chips where the measured thing exists only across chips: at most
+    # a quarter of the cells, and one always may (the contract)
+    assert 1 <= sum(w["chips"] == 4 for w in MANIFEST["workloads"]) \
+        <= max(1, len(MANIFEST["workloads"]) // 4)
     assert len(MANIFEST["per_layer"]) <= 128
     from paddle_tpu.decode.smallthinker import param_shapes
     shapes = param_shapes(cell.driver().model_config(cell.config))
@@ -156,7 +160,7 @@ def test_no_accepted_metric_starts_to_match_a_new_kernel():
     new = ("gqa_window_flash_fwd", "gqa_group_flash_fwd",
            "gqa_ring_decode_attn", "moe_grouped_reglu")
     for m in MANIFEST["per_layer"]:
-        if CELL in m.get("workloads", []):
+        if m.get("workloads") == [CELL]:
             continue
         args = harness.Cell(REPO, MANIFEST, m["workloads"][0] if
                             "workloads" in m else CELL
@@ -366,74 +370,6 @@ def test_the_counting_functions_against_hand_worked_numbers():
         "full_prefill_attn", "ring_decode_attn", "full_decode_attn"}
 
 
-def test_the_new_reader_counts_work_over_the_very_launches_it_times():
-    from benchmark import peaks
-    ms = 1e6
-    raw = {"host": [["bench.window", 0.0, 100 * ms]], "devices": {"/device:TPU:0": {
-        "modules": [["jit_fn_decode_lm_step(1)", 10 * ms, 30 * ms],
-                    ["jit_fn_decode_lm_step(1)", 50 * ms, 30 * ms],
-                    ["jit_fn_decode_lm_prefill_4096(2)", 82 * ms, 10 * ms]],
-        "ops": [["%moe_grouped_reglu.1 = bf16[8,8]{1,0} custom-call()", 11 * ms, 2 * ms],
-                ["%gqa_ring_decode_attn.2 = f32[8]{0} custom-call()", 14 * ms, 1 * ms],
-                ["%gqa_paged_decode_attn.3 = f32[8]{0} custom-call()", 16 * ms, 2 * ms],
-                ["%moe_grouped_reglu.1 = bf16[8,8]{1,0} custom-call()", 20 * ms, 6 * ms],
-                ["%moe_grouped_reglu.1 = bf16[8,8]{1,0} custom-call()", 51 * ms, 10 * ms],
-                ["%gqa_window_flash_fwd.4 = f32[8]{0} custom-call()", 83 * ms, 3 * ms],
-                ["%gqa_group_flash_fwd.5 = f32[8]{0} custom-call()", 86 * ms, 1 * ms],
-                ["%moe_grouped_reglu.6 = bf16[8,8]{1,0} custom-call()", 88 * ms, 4 * ms]]}}}
-    spans = {"spans": [
-        ["decode::step.observe", 1, 40.1 * ms, 0.1 * ms,
-         {"step_context_tokens": 200000, "step_ring_rows_live": 150000,
-          "step_streams": 64, "step_routed_assignments": 3072,
-          "step_experts_touched": 500}],
-        ["decode::prefill.observe", 1, 92.1 * ms, 0.1 * ms,
-         {"prefill_real_tokens": 4000, "prefill_tokens_sq": 16000000,
-          "prefill_window_pairs": 8002000,
-          "prefill_routed_assignments": 4000 * 6 * 8}]]}
-    cell = harness.Cell(REPO, MANIFEST, CELL)
-    ctx = {"trace_raw": raw, "config": cell.config,
-           "memory": {"kind": "TPU v5 lite"}}
-    mod = harness.load_module(
-        os.path.join(REPO, "benchmark", "metrics",
-                     "kernel_roofline_smallthinker.py"),
-        "reader_under_test_st")
-    from benchmark.metrics import program_spans
-    peak = peaks.peaks_for("TPU v5 lite")
-    expert, pair = 3 * 2560 * 768, 28 * 4 * 128
-
-    def args(name):
-        return cell.metric_file(name + ".served_st")["args"]
-
-    # the second step has no span (the trace stopped): not timed, not counted
-    old, program_spans.load = program_spans.load, lambda: spans
-    try:
-        assert mod.read(ctx, **args("moe_step_roofline")) == pytest.approx(
-            100 * (500 * expert * 2 + 3072 * 2560 * 4) / 8e-3
-            / peak["hbm_bytes_per_s"])
-        assert mod.read(ctx, **args("ring_decode_attn_roofline")) == \
-            pytest.approx(100 * 150000 * 6 * 2048 / 1e-3
-                          / peak["hbm_bytes_per_s"])
-        assert mod.read(ctx, **args("full_decode_attn_roofline")) == \
-            pytest.approx(100 * 200000 * 2 * 2048 / 2e-3
-                          / peak["hbm_bytes_per_s"])
-        assert mod.read(ctx, **args("moe_prefill_roofline")) == \
-            pytest.approx(100 * 2 * expert * 4000 * 48 / 4e-3
-                          / peak["bf16_flops_per_s"])
-        assert mod.read(ctx, **args("window_prefill_attn_roofline")) == \
-            pytest.approx(100 * pair * 8002000 * 6 / 3e-3
-                          / peak["bf16_flops_per_s"])
-        assert mod.read(ctx, **args("full_prefill_attn_roofline")) == \
-            pytest.approx(100 * pair * 8002000 * 2 / 1e-3
-                          / peak["bf16_flops_per_s"])
-        # the parent: no such kernel, no such count, no trace
-        one = args("moe_step_roofline")
-        assert mod.read(ctx, **dict(one, kernel="^absent")) is None
-        assert mod.read(ctx, **dict(one, count="absent")) is None
-        assert mod.read(dict(ctx, trace_raw=None), **one) is None
-    finally:
-        program_spans.load = old
-
-
 def test_the_counter_readers_read_the_window_s_deltas():
     cell = harness.Cell(REPO, MANIFEST, CELL)
     ctx = {"config": cell.config, "window_counters": {
@@ -443,8 +379,8 @@ def test_the_counter_readers_read_the_window_s_deltas():
         "step_context_tokens": 640.0, "step_streams": 64.0}}
     assert cell.reader("ring_live_share.served_st")(ctx) == 25.0
     assert cell.reader("expert_load_max_over_mean.served_st")(ctx) == 3.0
-    assert cell.reader("prefill_pad_share.served_st")(ctx) == 10.0
-    assert cell.reader("live_context_tokens.served_st")(ctx) == 10.0
+    assert cell.reader("prefill_pad_share.served")(ctx) == 10.0
+    assert cell.reader("live_context_tokens.served")(ctx) == 10.0
     # the parent has no such counter: nothing, and no error
     assert cell.reader("ring_live_share.served_st")(
         {"config": cell.config, "window_counters": {}}) is None

@@ -88,6 +88,14 @@ WINDOW_EXPERT_FALLBACK_COUNTERS = (
     "attn.gqa_ring_decode_fallbacks",
 )
 
+# the XLA fallbacks of the short-convolution expert LM's kernels
+# (kernels/gqa.py at heads of 64, kernels/moe.py with the gate silu)
+CONV_EXPERT_FALLBACK_COUNTERS = (
+    "moe.grouped_swiglu_fallbacks",
+    "attn.gqa_window_prefill_fallbacks",
+    "attn.gqa_decode_fallbacks",
+)
+
 # the XLA fallbacks of the parallel-hybrid (Mamba-2 beside grouped-query
 # attention) LM's kernels (kernels/ssd.py, kernels/gqa.py)
 PARALLEL_HYBRID_FALLBACK_COUNTERS = (
@@ -929,6 +937,93 @@ def phase_window_expert_lm(vocab=8192, hidden=512, heads=14, kv_heads=2,
         engine.close()
 
 
+def phase_conv_expert_lm(vocab=8192, hidden=512, heads=8, kv_heads=4,
+                         head_dim=64, ffn=1024, expert_ffn=256, experts=16,
+                         top_k=4, max_seq_len=1024, max_slots=4,
+                         block_tokens=16, prefill_bucket=512, prompt_len=400,
+                         new_tokens=24, dtype="bfloat16"):
+    """``decode.lfm2.LFM2LM`` (LFM2's layers at its head width of 64: one
+    dense short-convolution layer, then one period of an attention layer with
+    per-head q/k norms and three short-convolution layers, SwiGLU experts
+    behind a sigmoid router with a selection bias) through ``DecodeEngine``:
+    one stream prefilled into a padded rung and decoded through the pool and
+    the convolution tails, its logits at every generated position held
+    against the benchmark's plain reference; no kernel fell back (on the
+    chip none is interpreted); ``/decodez`` shows the pool and the tails, and
+    no rings."""
+    import jax.numpy as jnp
+    from benchmark.reference import lfm2_moe as reference
+    from paddle_tpu.decode import DecodeEngine, SamplingParams
+    from paddle_tpu.decode.lfm2 import LFM2Config, LFM2LM
+
+    raw = dict(
+        vocab_size=vocab, hidden_size=hidden, intermediate_size=ffn,
+        moe_intermediate_size=expert_ffn, num_hidden_layers=5,
+        num_attention_heads=heads, num_key_value_heads=kv_heads,
+        head_dim=head_dim, num_dense_layers=1, num_experts=experts,
+        num_experts_per_tok=top_k, norm_topk_prob=True, use_expert_bias=True,
+        routed_scaling_factor=1.0, norm_eps=1e-5, conv_L_cache=3,
+        conv_bias=False,
+        layer_types=["conv", "full_attention", "conv", "conv", "conv"],
+        rope_parameters={"rope_theta": 1e6})
+    cfg = LFM2Config.from_dict({**raw, "max_seq_len": max_seq_len,
+                                "dtype": dtype})
+    model = LFM2LM(cfg)
+    params = model.init_params(seed=7)
+    c0 = counters()
+    engine = DecodeEngine(model, params, name="conv_expert",
+                          max_slots=max_slots, block_tokens=block_tokens,
+                          prefill_buckets=[prefill_bucket],
+                          capture_logits=True, cache_dtype=dtype,
+                          prefix_cache=False, overcommit=False)
+    try:
+        prompt = np.random.RandomState(0).randint(
+            0, vocab, (prompt_len,)).astype("int32")
+        handle = engine.submit(prompt,
+                               SamplingParams(max_new_tokens=new_tokens))
+        result = handle.result(timeout=900.0)
+        toks = np.asarray(result["tokens"], np.int32)
+        check(toks.size == new_tokens and result.get("finish") == "length",
+              f"the conv-expert stream ended early: {result}")
+        seq = np.concatenate([prompt, toks[:-1]])
+        weights = {k: jnp.asarray(v) for k, v in params.items()}
+        at = np.arange(prompt_len - 1, seq.size)
+        want, _, _ = reference.forward(weights, raw, seq, seq.size, at)
+        want = np.asarray(want)
+        got = np.stack(handle.logits).astype(np.float32)
+        err = np.sqrt(((got - want) ** 2).sum(-1) / (want ** 2).sum(-1))
+        scale = float(np.abs(want).max())
+        gap = want.max(-1) - np.take_along_axis(want, toks[:, None], 1)[:, 0]
+        # bf16 activations through five layers against float32 at the highest
+        # precision, the reference routing on its own: the median a few
+        # percent of the logits' norm, and a token at most 5% of the logit
+        # scale under the reference's argmax
+        check(float(np.median(err)) <= (0.06 if dtype == "bfloat16"
+                                        else 1e-3),
+              f"conv-expert-LM logits are {np.median(err):.4f} of their "
+              f"norm off the reference (median)")
+        check(float(gap.max()) <= 0.05 * scale,
+              f"a conv-expert-LM token trails the reference's argmax by "
+              f"{gap.max():.4f} (logit scale {scale:.2f})")
+        fell = {n: counter_delta(c0, n) for n in CONV_EXPERT_FALLBACK_COUNTERS}
+        check(not any(fell.values()), f"a new kernel fell back: {fell}")
+        z = engine.decodez()
+        cache = z["cache"]
+        check(cache.get("kind") == "hybrid" and all(
+            cache.get(k, 0) > 0 for k in (
+                "kv_pool_bytes", "recurrent_state_bytes", "kv_live_tokens"))
+              and "window_state_bytes" not in cache,
+              f"/decodez does not show the pool and the tails alone: {cache}")
+        return {"tokens_checked": int(toks.size),
+                "tokens_exact": int((gap == 0).sum()),
+                "logit_err_max": float(err.max()),
+                "logit_err_median": float(np.median(err)),
+                "worst_logit_gap": float(gap.max()), "logit_scale": scale,
+                "steps": z["steps"], "cache": cache, "fallbacks": fell}
+    finally:
+        engine.close()
+
+
 # ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
@@ -1089,6 +1184,7 @@ def main() -> int:
     run_phase(report, "hybrid_lm", phase_hybrid_lm)
     run_phase(report, "parallel_hybrid_lm", phase_parallel_hybrid_lm)
     run_phase(report, "window_expert_lm", phase_window_expert_lm)
+    run_phase(report, "conv_expert_lm", phase_conv_expert_lm)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])
@@ -1105,7 +1201,8 @@ def main() -> int:
         for n in (FALLBACK_COUNTERS + LATENT_FALLBACK_COUNTERS
                   + HYBRID_FALLBACK_COUNTERS
                   + PARALLEL_HYBRID_FALLBACK_COUNTERS
-                  + WINDOW_EXPERT_FALLBACK_COUNTERS)}
+                  + WINDOW_EXPERT_FALLBACK_COUNTERS
+                  + CONV_EXPERT_FALLBACK_COUNTERS)}
     report["jax_cache"] = {"hits": LOG.cache_hits, "compiles": LOG.compiles,
                            "compile_s": round(LOG.compile_s, 2)}
     failures += [f"phase {n}: {p.get('error')}"

@@ -121,6 +121,7 @@ from .sambay import SambaYConfig, SambaYLM  # noqa: F401
 from .falcon_h1 import FalconH1Config, FalconH1LM  # noqa: F401
 from .smallthinker import (SmallThinkerConfig,  # noqa: F401
                            SmallThinkerLM)
+from .lfm2 import LFM2Config, LFM2LM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -136,6 +137,7 @@ __all__ = [
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
     "MLAConfig", "MLATransformerLM", "SambaYConfig", "SambaYLM",
     "FalconH1Config", "FalconH1LM", "SmallThinkerConfig", "SmallThinkerLM",
+    "LFM2Config", "LFM2LM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

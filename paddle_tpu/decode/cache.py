@@ -457,6 +457,10 @@ class HybridStateCache(_PagedPool):
       ``state_shape`` is ``(N, Di)`` (Mamba-1: a decay a channel) unless
       given (Mamba-2: ``(heads, N, head channels)``); the convolution is as
       wide as ``d_inner`` unless ``conv_width`` says what else it covers.
+      The tails are a kind of their own: ``conv_layers`` (as many as the
+      state-space layers unless given) counts the layers that keep one, so a
+      model of short convolutions and no recurrence holds ``conv`` without
+      ``h``.
 
     The last two are addressed by SLOT, not by block list: a prefill is told
     its slot and overwrites the slot's rows whole (that is the reset at a
@@ -470,7 +474,8 @@ class HybridStateCache(_PagedPool):
                  slots: int, window: int, window_layers: int,
                  ssm_layers: int, d_inner: int, d_state: int, d_conv: int,
                  dtype="bfloat16", kv_layers: int = 1, state_shape=None,
-                 conv_width: Optional[int] = None):
+                 conv_width: Optional[int] = None,
+                 conv_layers: Optional[int] = None):
         if str(dtype) == "int8":
             raise ValueError("the hybrid state has no int8 form: its rows "
                              "carry no per-block scale")
@@ -491,11 +496,12 @@ class HybridStateCache(_PagedPool):
                                     self.ring_rows, width), dtype)
         self.h = self.conv = None
         if ssm_layers:
-            rows = (int(ssm_layers), self.slots)
-            self.h = jnp.zeros(rows + tuple(
+            self.h = jnp.zeros((int(ssm_layers), self.slots) + tuple(
                 state_shape or (int(d_state), int(d_inner))), jnp.float32)
-            self.conv = jnp.zeros(rows + (int(d_conv) - 1,
-                                          int(conv_width or d_inner)), dtype)
+        tails = int(ssm_layers if conv_layers is None else conv_layers)
+        if tails:
+            self.conv = jnp.zeros((tails, self.slots, int(d_conv) - 1,
+                                   int(conv_width or d_inner)), dtype)
         self.live_tokens = 0        # the model's observer keeps it
 
     @staticmethod
@@ -524,7 +530,7 @@ class HybridStateCache(_PagedPool):
     def state(self) -> list:
         """``[kv, rings, h, conv]`` less the kinds this model has none of:
         ``[kv, h, conv]`` without window layers, ``[kv, rings]`` without
-        state-space layers."""
+        state-space layers, ``[kv, conv]`` with convolution tails alone."""
         return [a for a in (getattr(self, k) for k in self._KINDS)
                 if a is not None]
 
@@ -545,6 +551,6 @@ class HybridStateCache(_PagedPool):
                     kv_live_tokens=int(self.live_tokens))
         if self.rings is None:
             del snap["window"], snap["window_state_bytes"]
-        if self.h is None:
+        if self.h is None and self.conv is None:
             del snap["recurrent_state_bytes"]
         return snap

@@ -40,6 +40,19 @@ rotation; nothing here knows a position but the causal mask.
   tiles the diagonal or the window's edge crosses build a mask
   (``gqa_window_flash_fwd``; with no window ``gqa_group_flash_fwd``).  The
   XLA fallback counts into ``attn.gqa_window_prefill_fallbacks``.
+
+At ``dh = 64`` a lane tile of a row is a PAIR of K/V heads (``n_kv`` even),
+and the walk and the group flash forward run as they are over
+``n_kv / 2`` tiles: a pair's ``2·group`` query heads are the rows that share
+the tile, each widened to 128 lanes with the OTHER head's lanes zeroed
+(:func:`_pair_rows` — ``kernels/diffattn.py``'s two components a head, here
+two heads a tile), so a score is one 128-deep contraction with the key tile
+and nothing is sliced inside a tile; of the value product's 128 lanes a row
+keeps its own head's 64 (:func:`_unpair`).  The calls are named
+``gqa64_paged_decode_attn`` / ``gqa64_ring_decode_attn`` /
+``gqa64_group_flash_fwd`` / ``gqa64_window_flash_fwd``, so a trace tells
+them from the 128-wide ones.  :func:`prefill_attention` (a query head a grid
+step) stays 128-wide.
 """
 from __future__ import annotations
 
@@ -57,7 +70,45 @@ from .diffattn import _first_tile, flash_tiles, paged_walk, visible
 
 NEG_INF = -1e30
 LANE = 128
+HALF = LANE // 2
 _FLASH_BLOCK = 256
+
+
+def tiled(dh: int, n_kv: int) -> bool:
+    """Whether heads ``dh`` wide lie in whole lane tiles of a cached row: one
+    a tile, or a pair of K/V heads a tile."""
+    return dh == LANE or (dh == HALF and n_kv % 2 == 0)
+
+
+def _name(name: str, dh: int) -> str:
+    return name if dh == LANE else name.replace("gqa_", "gqa64_", 1)
+
+
+def _pair_rows(q, n_kv: int):
+    """q [N, nh, 64] → [N, n_kv / 2, 2·group, 128]: the query heads of a
+    pair of K/V heads as the rows that share the pair's lane tile, the first
+    head's in lanes 0-63 and the second's in lanes 64-127, the other half
+    zeros."""
+    N, nh, dh = q.shape
+    q = q.reshape(N, n_kv // 2, 2, nh // n_kv, dh)
+    z = jnp.zeros_like(q[:, :, :1])
+    rows = jnp.concatenate(
+        [jnp.concatenate([q[:, :, :1], z], axis=-1),
+         jnp.concatenate([z, q[:, :, 1:]], axis=-1)], axis=2)
+    return rows.reshape(N, n_kv // 2, 2 * (nh // n_kv), LANE)
+
+
+def _unpair(out, nh: int):
+    """:func:`_pair_rows`' rows after the value product [N, n_kv / 2, ≥
+    2·group, 128] → [N, nh, 64]: each row's own head's lanes — a select
+    between the two halves of every row (a stack of two half-lane slices
+    came back wrong from the TPU's compiler: PERF.md section 6, PR 44)."""
+    N, tiles = out.shape[:2]
+    group = nh // (2 * tiles)
+    out = out[:, :, :2 * group].reshape(N, tiles, 2, group, LANE)
+    second = lax.broadcasted_iota(jnp.int32, (1, 1, 2, 1, 1), 2) == 1
+    return jnp.where(second, out[..., HALF:], out[..., :HALF]
+                     ).reshape(N, nh, HALF)
 
 
 def _split_rows(rows, n_kv: int):
@@ -90,20 +141,24 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer,
 def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv,
                    name="gqa_paged_decode_attn"):
     S, nh, dh = q.shape
-    group = nh // n_kv
-    rows = (q.astype(jnp.float32) * dh ** -0.5).reshape(S, n_kv, group, dh)
-    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, -group % 8), (0, 0))
+    rows = q.astype(jnp.float32) * dh ** -0.5
+    rows = rows.reshape(S, n_kv, nh // n_kv, dh) if dh == LANE \
+        else _pair_rows(rows, n_kv)
+    tiles, shared = rows.shape[1:3]
+    rows = jnp.pad(rows, ((0, 0), (0, 0), (0, -shared % 8), (0, 0))
                    ).astype(pool.dtype)
-    out = paged_walk(rows, pool, block_tables, context_lens, layer, n_kv,
-                     name)
-    return out[:, :, :group].reshape(S, nh, dh)
+    out = paged_walk(rows, pool, block_tables, context_lens, layer, tiles,
+                     _name(name, dh))
+    if dh != LANE:
+        return _unpair(out, nh)
+    return out[:, :, :shared].reshape(S, nh, dh)
 
 
 def decode_attention(q, pool, block_tables, context_lens, layer, n_kv: int):
     """q [S, nh, dh], pool [L, NB, bs, 2·kw] (all of it, as it lies),
     block_tables [S, MB] int32, context_lens [S] int32 (at least 1), layer
     an int or a traced scalar → [S, nh, dh] float32."""
-    if q.shape[-1] != LANE:
+    if not tiled(q.shape[-1], n_kv):
         _obs_stats.scope("attn").counter("gqa_decode_fallbacks").inc()
         return decode_attention_xla(q, pool, block_tables, context_lens,
                                     layer, n_kv)
@@ -116,7 +171,7 @@ def ring_decode_attention(q, rings, ring_tables, live_rows, layer,
     them, as they lie), ring_tables [S, W/rb] int32 (a slot's own ring
     blocks), live_rows [S] int32 (``min(context, W)``, at least 1), layer
     the window layer's index → [S, nh, dh] float32."""
-    if q.shape[-1] != LANE:
+    if not tiled(q.shape[-1], n_kv):
         _obs_stats.scope("attn").counter("gqa_ring_decode_fallbacks").inc()
         return decode_attention_xla(q, rings, ring_tables, live_rows, layer,
                                     n_kv)
@@ -277,9 +332,15 @@ def _group_flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
 def _group_flash_pallas(q, rows, n_kv, window):
     T, nh, dh = q.shape
-    group = nh // n_kv
     b, n_kw = flash_tiles(T, window)
     qs = (q.astype(jnp.float32) * dh ** -0.5).astype(rows.dtype)
+    name = _name("gqa_group_flash_fwd" if window is None
+                 else "gqa_window_flash_fwd", dh)
+    if dh != LANE:
+        # a pair of K/V heads is one tile and its 2·group query heads the
+        # group that shares it
+        qs, n_kv, dh = _pair_rows(qs, n_kv), n_kv // 2, LANE
+    group = nh // n_kv
 
     def kv_map(lane0):
         def at(g, i, j):
@@ -289,8 +350,7 @@ def _group_flash_pallas(q, rows, n_kv, window):
     out = pl.pallas_call(
         functools.partial(_group_flash_kernel, b=b, group=group,
                           window=window),
-        name=("gqa_group_flash_fwd" if window is None
-              else "gqa_window_flash_fwd"),
+        name=name,
         grid=(n_kv, T // b, n_kw),
         in_specs=[pl.BlockSpec((b, group * dh), lambda g, i, j: (i, g)),
                   pl.BlockSpec((b, dh), kv_map(0)),
@@ -305,6 +365,8 @@ def _group_flash_pallas(q, rows, n_kv, window):
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=pallas_interpret(),
     )(qs.reshape(T, nh * dh), rows, rows)
+    if q.shape[-1] != LANE:
+        return _unpair(out.reshape(T, n_kv, group, LANE), nh)
     return out.reshape(T, nh, dh)
 
 
@@ -316,7 +378,7 @@ def group_prefill_attention(q, rows, n_kv: int, window=None):
     row."""
     T = q.shape[0]
     b, _ = flash_tiles(T, window)
-    if q.shape[-1] != LANE or T % b or b % 8:
+    if not tiled(q.shape[-1], n_kv) or T % b or b % 8:
         _obs_stats.scope("attn").counter(
             "gqa_window_prefill_fallbacks").inc()
         return prefill_attention_xla(q, rows, n_kv, window)
@@ -326,4 +388,4 @@ def group_prefill_attention(q, rows, n_kv: int, window=None):
 __all__ = ["decode_attention", "decode_attention_xla",
            "ring_decode_attention", "prefill_attention",
            "prefill_attention_xla", "group_prefill_attention", "flash_tile",
-           "LANE"]
+           "tiled", "LANE"]

@@ -2,9 +2,12 @@
 ReGLU) over the experts — the serving form of a sparse mixture, where every
 assignment is computed (no capacity, no token dropped).
 
-- :func:`route_topk` — ``softmax`` scores over all experts in float32, the
-  ``k`` largest (``lax.top_k`` keeps the lower index on a tie), weights the
-  chosen scores themselves, renormalised only when asked, times ``scale``.
+- :func:`route_topk` — scores over all experts in float32 (``softmax``, or
+  ``sigmoid``: an expert's own), the ``k`` largest (``lax.top_k`` keeps the
+  lower index on a tie) — of ``score + bias`` where a selection bias is
+  given, which chooses and does not weigh —, weights the chosen scores
+  themselves, renormalised only when asked (over ``sum + eps``), times
+  ``scale``.
 - :func:`plan_groups` — the sort: the valid tokens' assignments in expert
   order, each expert's rows padded to a multiple of the row tile, so a tile
   of rows belongs to exactly one expert.  Gathers and two small sorts; no
@@ -53,13 +56,27 @@ _VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024,
 _PREFILL_TILE = 128
 
 
-def route_topk(logits, k: int, scale: float = 1.0, normalize: bool = False):
-    """logits [T, E] (router outputs, any float) → (ids [T, k] int32,
-    weights [T, k] f32).  Scores and weights are float32."""
-    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    chosen, ids = lax.top_k(s, k)
+SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
+          "sigmoid": jax.nn.sigmoid}
+
+
+def route_topk(logits, k: int, scale: float = 1.0, normalize: bool = False,
+               score: str = "softmax", bias=None, eps: float = 0.0):
+    """logits [T, E] (router outputs, any float), bias [E] or None (added to
+    the scores for the choice alone) → (ids [T, k] int32, weights [T, k]
+    f32).  Scores and weights are float32."""
+    if score not in SCORES:
+        raise ValueError(f"unknown router score {score!r}; one of "
+                         f"{sorted(SCORES)}")
+    s = SCORES[score](logits.astype(jnp.float32))
+    if bias is None:
+        chosen, ids = lax.top_k(s, k)
+    else:
+        _, ids = lax.top_k(s + bias.astype(jnp.float32), k)
+        chosen = jnp.take_along_axis(s, ids, axis=-1)
     if normalize:
-        chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+        total = jnp.sum(chosen, axis=-1, keepdims=True)
+        chosen = chosen / (total + jnp.float32(eps) if eps else total)
     return ids.astype(jnp.int32), chosen * jnp.float32(scale)
 
 
@@ -273,4 +290,4 @@ def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
 __all__ = ["route_topk", "plan_groups", "plan_rows", "grouped_glu",
            "grouped_glu_xla", "grouped_swiglu", "grouped_swiglu_xla", "combine",
            "planned_experts", "routed_experts", "GroupPlan", "row_tile",
-           "ACTS"]
+           "ACTS", "SCORES"]

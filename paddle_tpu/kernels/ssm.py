@@ -158,26 +158,30 @@ def selective_step(h, x, delta, A, B, C):
     return jnp.sum(h * C.astype(jnp.float32)[:, :, None], axis=1), h
 
 
-def causal_conv(a, w, b):
+def causal_conv(a, w, b=None):
     """Depthwise causal convolution of a prompt: a [T, Di], w [K, Di] (w[K-1]
-    weighs the current position), b [Di] → [T, Di] float32, before the
-    activation; positions before the prompt are zeros."""
+    weighs the current position), b [Di] or None (a filter without a bias) →
+    [T, Di] float32, before the activation; positions before the prompt are
+    zeros."""
     K, T = w.shape[0], a.shape[0]
     a32 = a.astype(jnp.float32)
     padded = jnp.concatenate([jnp.zeros((K - 1, a.shape[1]), jnp.float32),
                               a32])
-    out = b.astype(jnp.float32)[None, :]
+    out = 0.0 if b is None else b.astype(jnp.float32)[None, :]
     for k in range(K):
         out = out + w[k].astype(jnp.float32)[None, :] * padded[k:k + T]
     return out
 
 
-def conv_step(tail, a, w, b):
+def conv_step(tail, a, w, b=None):
     """One token a row: tail [S, K-1, Di] (the last K-1 inputs, oldest
     first), a [S, Di] → (conv [S, Di] float32, tail')."""
     window = jnp.concatenate([tail, a[:, None, :].astype(tail.dtype)], axis=1)
-    out = b.astype(jnp.float32)[None, :] + jnp.einsum(
-        "skd,kd->sd", window.astype(jnp.float32), w.astype(jnp.float32))
+    def taps():
+        return jnp.einsum("skd,kd->sd", window.astype(jnp.float32),
+                          w.astype(jnp.float32))
+
+    out = taps() if b is None else b.astype(jnp.float32)[None, :] + taps()
     return out, window[:, 1:]
 
 

@@ -153,6 +153,20 @@ def test_window_expert_lm_phase_tiny():
     assert not any(out["fallbacks"].values())
 
 
+def test_conv_expert_lm_phase_tiny():
+    out = chip_smoke.phase_conv_expert_lm(
+        vocab=64, hidden=64, heads=4, kv_heads=2, head_dim=64, ffn=96,
+        expert_ffn=32, experts=8, top_k=2, max_seq_len=96, max_slots=2,
+        block_tokens=16, prefill_bucket=64, prompt_len=45, new_tokens=10,
+        dtype="float32")
+    assert out["tokens_checked"] == 10 and out["tokens_exact"] == 10
+    assert out["logit_err_max"] < 1e-4
+    assert out["cache"]["kind"] == "hybrid"
+    assert out["cache"]["recurrent_state_bytes"] == 4 * 2 * 2 * 64 * 4
+    assert "window_state_bytes" not in out["cache"]
+    assert not any(out["fallbacks"].values())
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

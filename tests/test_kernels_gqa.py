@@ -256,3 +256,110 @@ def test_ring_walk_at_another_head_size_falls_back_and_counts():
     assert stats.to_dict()["attn.gqa_ring_decode_fallbacks"] == before + 1
     np.testing.assert_array_equal(
         got, gqa.decode_attention_xla(q, rings, tables, live, 0, 2))
+
+
+# -- 64-wide heads (32 query heads over 8 K/V heads, cut to 16 over 4): a
+# lane tile of a row is a PAIR of K/V heads, a pair's 2 x 4 query heads the
+# rows that share it --
+H64, NH64, NKV64 = 64, 16, 4
+KW64 = NKV64 * H64
+
+
+def _dense64(q, k, v, keep):
+    q, k, v = (np.asarray(a, np.float64) for a in (q, k, v))
+    out = np.zeros(q.shape)
+    for h in range(q.shape[1]):
+        g = h // (q.shape[1] // k.shape[1])
+        s = np.where(keep, q[:, h] @ k[:, g].T / np.sqrt(H64), -np.inf)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        out[:, h] = (p / p.sum(-1, keepdims=True)) @ v[:, g]
+    return out
+
+
+def _rows64(k, v):
+    return jnp.concatenate([k.reshape(k.shape[0], KW64),
+                            v.reshape(v.shape[0], KW64)], axis=-1)
+
+
+@pytest.mark.parametrize("walk", sorted(WALKS))
+def test_paged_walk_at_heads_of_64_pairs_the_kv_heads_of_a_tile(monkeypatch,
+                                                                walk):
+    chunk, bs, MB = 2, 8, 6
+    monkeypatch.setattr(da, "_CHUNK_BLOCKS", chunk)
+    rng = np.random.RandomState(11)
+    contexts = WALKS[walk](chunk * bs, MB * bs)
+    _, kc, vc, bt, cl = walk_case(rng, contexts, MB, bs=bs, H=NKV64, D=H64,
+                                  L=2)
+    pool = jnp.concatenate([kc, vc], axis=-1)
+    S = len(contexts)
+    q = jnp.asarray(rng.randn(S, NH64, H64).astype("float32"))
+    before = stats.to_dict().get("attn.gqa_decode_fallbacks", 0)
+    got = jax.jit(lambda *a: gqa.decode_attention(*a, NKV64))(
+        q, pool, bt, cl, jnp.int32(1))
+    assert stats.to_dict().get("attn.gqa_decode_fallbacks", 0) == before
+    for s in range(S):
+        n = int(cl[s])
+        rows = np.asarray(pool)[1][np.asarray(bt)[s]].reshape(-1, 2 * KW64)[:n]
+        want = _dense64(np.asarray(q)[s:s + 1],
+                        rows[:, :KW64].reshape(n, NKV64, H64),
+                        rows[:, KW64:].reshape(n, NKV64, H64),
+                        np.ones((1, n), bool))
+        np.testing.assert_allclose(got[s:s + 1], want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        got, gqa.decode_attention_xla(q, pool, bt, cl, 1, NKV64), rtol=1e-5,
+        atol=1e-5)
+
+
+@pytest.mark.parametrize("T,tile,window", [(64, 8, None), (48, 16, 17),
+                                           (16, 16, None)],
+                         ids=["no_window", "window", "one_tile"])
+def test_group_flash_at_heads_of_64_matches_dense_attention(monkeypatch, T,
+                                                            tile, window):
+    monkeypatch.setattr(da, "_FLASH_BLOCK", tile)
+    rng = np.random.default_rng(T + (window or 0))
+    q = jnp.asarray(rng.standard_normal((T, NH64, H64)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((T, NKV64, H64)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((T, NKV64, H64)), jnp.float32)
+    before = stats.to_dict().get("attn.gqa_window_prefill_fallbacks", 0)
+    got = jax.jit(lambda q, r: gqa.group_prefill_attention(
+        q, r, NKV64, window))(q, _rows64(k, v))
+    assert stats.to_dict().get(
+        "attn.gqa_window_prefill_fallbacks", 0) == before
+    want = _dense64(q, k, v, _window_keep(T, window))
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(
+        gqa.prefill_attention_xla(q, _rows64(k, v), NKV64, window), want,
+        rtol=1e-5, atol=1e-5)
+
+
+def test_kernels_at_heads_of_64_have_names_of_their_own_and_a_tile_a_pair(
+        monkeypatch):
+    monkeypatch.setattr(da, "_FLASH_BLOCK", 8)
+    from paged_walks import eqns_under
+
+    def calls(fn, *args):
+        return [e for e in eqns_under(jax.make_jaxpr(fn)(*args).jaxpr)
+                if e.primitive.name == "pallas_call"]
+
+    q = jnp.zeros((64, NH64, H64), jnp.bfloat16)
+    rows = jnp.zeros((64, 2 * KW64), jnp.bfloat16)
+    for window, name in ((None, "gqa64_group_flash_fwd"),
+                         (20, "gqa64_window_flash_fwd")):
+        got = calls(lambda q, r: gqa.group_prefill_attention(
+            q, r, NKV64, window), q, rows)
+        assert [e.params["name"] for e in got] == [name]
+        # one grid step a PAIR of K/V heads
+        assert tuple(got[0].params["grid_mapping"].grid)[:2] == (NKV64 // 2, 8)
+    pool = jnp.zeros((1, 8, 8, 2 * KW64), jnp.bfloat16)
+    bt = jnp.zeros((2, 4), jnp.int32)
+    cl = jnp.ones((2,), jnp.int32)
+    qd = jnp.zeros((2, NH64, H64), jnp.bfloat16)
+    for fn, name in ((gqa.decode_attention, "gqa64_paged_decode_attn"),
+                     (gqa.ring_decode_attention, "gqa64_ring_decode_attn")):
+        got = calls(lambda q, p: fn(q, p, bt, cl, 0, NKV64), qd, pool)
+        assert [e.params["name"] for e in got] == [name]
+
+
+def test_an_odd_count_of_kv_heads_of_64_falls_back():
+    assert gqa.tiled(64, 8) and gqa.tiled(128, 3) and not gqa.tiled(64, 3)
+    assert not gqa.tiled(32, 4)

@@ -111,3 +111,71 @@ def test_a_layer_of_a_stack_is_reached_by_the_map_and_not_sliced(act, impl):
             if e.primitive.name == "pallas_call"]
         shapes = [tuple(v.aval.shape) for v in call.invars]
         assert (layers * E,) + tuple(wg.shape[2:]) in shapes
+
+
+# -- the router as one function: the score, a selection bias that chooses and
+# does not weigh, the renormalisation's epsilon --
+def _old_route_topk(logits, k, scale=1.0, normalize=False):
+    """``route_topk`` as its two softmax callers had it before the score
+    became an argument."""
+    s = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    chosen, ids = jax.lax.top_k(s, k)
+    if normalize:
+        chosen = chosen / jnp.sum(chosen, axis=-1, keepdims=True)
+    return ids.astype(jnp.int32), chosen * jnp.float32(scale)
+
+
+@pytest.mark.parametrize("k,scale,normalize,dtype", [
+    (6, 1.0, False, "float32"),         # decode/mla.py's call
+    (6, 1.0, True, "float32"),          # decode/smallthinker.py's
+    (3, 2.5, True, "bfloat16")], ids=["mla", "smallthinker", "scaled_bf16"])
+def test_the_softmax_callers_keep_their_results_to_the_bit(k, scale,
+                                                           normalize, dtype):
+    logits = jnp.asarray(np.random.default_rng(k).standard_normal((37, 64)),
+                         dtype)
+    for fn in (lambda f: f, jax.jit):
+        ids, w = fn(lambda l: moe.route_topk(l, k, scale, normalize))(logits)
+        ids0, w0 = fn(lambda l: _old_route_topk(l, k, scale, normalize))(
+            logits)
+        np.testing.assert_array_equal(ids, ids0)
+        np.testing.assert_array_equal(w, w0)
+
+
+def test_a_sigmoid_router_s_bias_chooses_and_does_not_weigh():
+    rng = np.random.default_rng(4)
+    r = rng.standard_normal((200, 16)).astype(np.float32)
+    bias = (rng.standard_normal(16) * 0.3).astype(np.float32)
+    ids, w = moe.route_topk(jnp.asarray(r), 4, 1.0, True, score="sigmoid",
+                            bias=jnp.asarray(bias), eps=1e-6)
+    s = 1.0 / (1.0 + np.exp(-r.astype(np.float64)))
+    want_ids = np.argsort(-(s + bias), axis=-1, kind="stable")[:, :4]
+    np.testing.assert_array_equal(np.sort(ids, -1), np.sort(want_ids, -1))
+    chosen = np.take_along_axis(s, np.asarray(ids), 1)
+    np.testing.assert_allclose(
+        w, chosen / (chosen.sum(-1, keepdims=True) + 1e-6), rtol=1e-5)
+    # the bias changed the chosen set of many tokens ...
+    plain_ids, plain_w = moe.route_topk(jnp.asarray(r), 4, 1.0, True,
+                                        score="sigmoid", eps=1e-6)
+    turned = (np.sort(plain_ids, -1) != np.sort(ids, -1)).any(-1)
+    assert 0.3 < turned.mean() < 1.0
+    # ... and where it did not, it moved no weight (the order of the four
+    # is the order of score + bias)
+    same = ~turned
+    np.testing.assert_allclose(np.sort(np.asarray(w)[same], -1),
+                               np.sort(np.asarray(plain_w)[same], -1),
+                               rtol=1e-6)
+    # weights of s + b would be another model's
+    other = np.take_along_axis(s + bias, np.asarray(ids), 1)
+    assert np.abs(other / other.sum(-1, keepdims=True) - w).max() > 0.01
+
+
+def test_the_router_s_score_scale_and_epsilon():
+    r = jnp.asarray([[0.0, 2.0, -1.0, 1.0]], jnp.float32)
+    ids, w = moe.route_topk(r, 2, 3.0, False, score="sigmoid")
+    np.testing.assert_array_equal(ids, [[1, 3]])
+    s = 1.0 / (1.0 + np.exp(-np.array([2.0, 1.0])))
+    np.testing.assert_allclose(w[0], 3.0 * s, rtol=1e-6)
+    _, wn = moe.route_topk(r, 2, 1.0, True, score="sigmoid", eps=0.5)
+    np.testing.assert_allclose(wn[0], s / (s.sum() + 0.5), rtol=1e-6)
+    with pytest.raises(ValueError, match="score"):
+        moe.route_topk(r, 2, score="tanh")

@@ -73,3 +73,19 @@ def test_one_token_update_and_convolution_step_continue_a_prompt():
         got, tail = ssm.conv_step(tail, x[t:t + 1], w, b)
         np.testing.assert_allclose(got[0], conv[t], rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(tail[0], x[T - K + 1:])
+
+
+def test_a_filter_without_a_bias_is_the_filter_with_a_bias_of_zeros():
+    rng = np.random.default_rng(8)
+    a = jnp.asarray(rng.standard_normal((9, 6)), jnp.float32)
+    w = jnp.asarray(rng.standard_normal((3, 6)), jnp.float32)
+    zeros = jnp.zeros((6,), jnp.float32)
+    whole = ssm.causal_conv(a, w)
+    np.testing.assert_array_equal(whole, ssm.causal_conv(a, w, zeros))
+    # a prompt's last two inputs are the tail the next token continues from
+    tail = a[None, 5:7]
+    got, new_tail = ssm.conv_step(tail, a[None, 7], w)
+    np.testing.assert_allclose(got[0], whole[7], rtol=1e-6, atol=1e-6)
+    np.testing.assert_array_equal(new_tail[0], a[6:8])
+    np.testing.assert_array_equal(
+        got, ssm.conv_step(tail, a[None, 7], w, zeros)[0])

@@ -19,6 +19,9 @@ checks what comes out by the repo's own means:
                  ``DecodeEngine`` behind ``DecodeServer``/``DecodeClient``;
                  requests join and leave mid-batch; tokens checked
                  against a full re-forward.
+- expert_walk:   the prefill form of the grouped expert kernel (an expert a
+                 grid step) against its XLA fallback at DeepSeek-V2-Lite's
+                 expert shapes, on a prompt's plan and on one expert's.
 - four_chip:     the trainer program through ``ParallelExecutor`` on a
                  dp=2 x mp=2 mesh, then one ZeRO step on dp=4 — only
                  where JAX sees >= 4 devices.
@@ -1025,6 +1028,71 @@ def phase_conv_expert_lm(vocab=8192, hidden=512, heads=8, kv_heads=4,
 
 
 # ---------------------------------------------------------------------------
+# phase 3f: the prefill form of the grouped expert kernel against its fallback
+# ---------------------------------------------------------------------------
+
+def phase_expert_walk(on_chip=True, tokens=2048, top_k=6, experts=64,
+                      hidden=2048, expert_ffn=1408, tol=1e-2):
+    """``kernels/moe.py grouped_glu`` on a prefill's plan (128-row tiles: an
+    expert a grid step, its rows copied in and out by the kernel) against
+    ``grouped_glu_xla`` — three ``lax.ragged_dot``, called directly, so no
+    fallback is counted — at DeepSeek-V2-Lite's expert shapes: the plan a
+    2,048-token prompt's top-6 of 64 makes, and one with every assignment on
+    ONE expert.  The comparison a kernel's first benchmark run waits for:
+    interpret mode cannot see what the TPU's compiler does (PERF.md §6, PR
+    44).  Returns the largest difference of each plan over the reference's
+    scale, and which walk the lowerings took."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import moe
+
+    tile = moe.row_tile(tokens, jnp.bfloat16)
+    check(tile == 128, f"{tokens} tokens plan {tile}-row tiles, not 128")
+    keys = jax.random.split(jax.random.PRNGKey(45), 5)
+    wg, wu = (jax.random.normal(k, (experts, hidden, expert_ffn),
+                                jnp.bfloat16) * 0.03 for k in keys[:2])
+    wd = jax.random.normal(keys[2], (experts, expert_ffn, hidden),
+                           jnp.bfloat16) * 0.03
+    x = jax.random.normal(keys[3], (tokens, hidden), jnp.bfloat16)
+    ids, _ = moe.route_topk(
+        jax.random.normal(keys[4], (tokens, experts), jnp.float32), top_k)
+    valid = jnp.ones((tokens,), bool)
+    x_pad = jnp.concatenate([x, jnp.zeros((1, hidden), x.dtype)])
+
+    kernel = jax.jit(lambda rows, plan: moe.grouped_glu(
+        rows, wg, wu, wd, plan, tile))
+    fallback = jax.jit(lambda rows, plan: moe.grouped_glu_xla(
+        rows, wg, wu, wd, plan))
+    before, out = counters(), {}
+    for name, chosen in (("prompt", ids), ("one_expert", ids * 0 + 3)):
+        plan = moe.plan_groups(chosen, valid, experts, tile)
+        rows = x_pad[plan.row_token]
+        if on_chip:
+            text = kernel.lower(rows, plan).compile().as_text()
+            check(MOSAIC_CALL in text and "moe_grouped_swiglu" in text,
+                  "no Mosaic call named moe_grouped_swiglu in the program")
+        live = int(np.sum(np.asarray(plan.padded_sizes)))
+        got = np.asarray(kernel(rows, plan)[:live], np.float32)
+        want = np.asarray(fallback(rows, plan)[:live], np.float32)
+        scale = float(np.abs(want).max())
+        out[name] = {"rows": live, "assignments": int(plan.load[0]),
+                     "experts_touched": int(plan.load[1]),
+                     "max_diff": float(np.abs(got - want).max()),
+                     "scale": scale}
+        check(np.isfinite(got).all() and scale > 0
+              and out[name]["max_diff"] <= tol * scale,
+              f"the expert walk differs from the fallback by "
+              f"{out[name]['max_diff']:.4g} of a scale of {scale:.4g} on "
+              f"the {name} plan")
+    out["walks"] = {w: counter_delta(before, f"moe.grouped_swiglu_{w}")
+                    for w in ("expert_walks", "tile_walks", "fallbacks")}
+    check(out["walks"]["expert_walks"] > 0 and not out["walks"]["tile_walks"]
+          and not out["walks"]["fallbacks"],
+          f"a 128-row plan was not walked by expert: {out['walks']}")
+    return out
+
+
+# ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
 
@@ -1185,6 +1253,7 @@ def main() -> int:
     run_phase(report, "parallel_hybrid_lm", phase_parallel_hybrid_lm)
     run_phase(report, "window_expert_lm", phase_window_expert_lm)
     run_phase(report, "conv_expert_lm", phase_conv_expert_lm)
+    run_phase(report, "expert_walk", phase_expert_walk)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])

@@ -14,19 +14,65 @@ assignment is computed (no capacity, no token dropped).
   scatter.
 - :func:`grouped_glu` — ``(act(x Wg_e) * (x Wu_e)) Wd_e`` for every row tile
   against its expert's three matrices, ``act`` the gate's activation
-  (:data:`ACTS`: ``silu`` — SwiGLU — or ``relu`` — ReGLU).  ONE Pallas kernel,
-  named after the gate (``moe_grouped_swiglu`` / ``moe_grouped_reglu``), walks
-  the tiles; the scalar-prefetched tile→expert map is its weight index map,
-  so an expert's matrices stream from HBM once while its tiles are
-  consecutive, experts with no row are never read, and the tiles past the
-  last real one repeat its indices (no copy, no compute).  Where the layers
-  are scanned the matrices are handed over as the whole stack ``[layers, E,
-  …]`` with the layer's index: the map points at ``layer · E + expert`` and
-  no layer's experts are sliced out of the stack.  The XLA fallback is three
+  (:data:`ACTS`: ``silu`` — SwiGLU — or ``relu`` — ReGLU).  ONE Pallas kernel
+  name a gate (``moe_grouped_swiglu`` / ``moe_grouped_reglu``) and two walks
+  of the plan, chosen by the plan's tile alone (counted, when the call is
+  lowered, in ``moe.grouped_<glu>_tile_walks`` / ``_expert_walks``):
+
+  * *a decode step's plan* (``row_tile``: at most 128 tokens, 16-row tiles)
+    is walked **a tile a grid step**: the scalar-prefetched tile→expert map
+    is the weight index map, so an expert's matrices stream from HBM once
+    while its tiles are consecutive and the tiles past the last real one
+    repeat its indices (no copy, no compute).  Six rows an expert are ONE
+    tile an expert: every grid step fetches the next expert's matrices under
+    this one's, nothing waits, and the walk reads 92% of 819 GB/s — so the
+    step keeps this walk to the character.
+  * *a prefill's plan* (128-row tiles) is walked **an expert a grid step**
+    (:func:`_expert_walk_kernel`): under the tile walk an expert's matrices
+    (17.3 MB at 2048 x 1408, 21 µs of HBM) started to arrive only during the
+    LAST tile of the expert before (12 µs of products), so three quarters of
+    every fetch stood exposed.  Here the matrices are the STEP's blocks —
+    the next expert's are in flight for the whole of this one's rows — and
+    the rows stay in HBM: the kernel copies an expert's contiguous run in
+    and its products out itself, a piece of up to :data:`_PIECE_TILES` tiles
+    at a time through a double buffer, the next piece's rows (this expert's,
+    or the next one's first) always in flight.  The products are the tile
+    walk's own, 128 rows at a time: results are equal to the bit.
+
+  In both, experts with no row are never read, and where the layers are
+  scanned the matrices are handed over as the whole stack ``[layers, E, …]``
+  with the layer's index: the map points at ``layer · E + expert`` and no
+  layer's experts are sliced out of the stack.  The XLA fallback is three
   ``lax.ragged_dot`` over the same padded rows and counts into
   ``moe.grouped_swiglu_fallbacks`` / ``moe.grouped_reglu_fallbacks``.
   :func:`grouped_swiglu` is the call with the gate fixed at ``silu``.
-- :func:`combine` — ``sum_k w[t, k] * y[row of (t, k)]``.
+
+  The kernel alone on one v5e, µs a call (PR 45; bf16, 64 experts, top-6,
+  float32 rows out at [2048, 1408], bf16 at the others; plans of uniform
+  routing, "skewed" 55 experts of 1–11 tiles; tile walk → expert walk, and
+  the larger of FLOP ÷ 197 T and bytes ÷ 819 G over the computed rows)::
+
+      plan (tiles)          [2048, 1408]       [2048, 1536]       [2560, 768]
+      one expert (128)      1527 → 1526 (1439) 1659 → 1659 (1570) 1059 → 1062 ( 981)
+      1,024 tokens ( 64)    1659 → 1666 (1475) 1737 → 1747 (1557) 1158 → 1166 (1024)
+      2,048 tokens (128)    2532 → 1852 (1598) 2615 → 1876 (1639) 1762 → 1307 (1127)
+      4,096 tokens (224)    3638 → 2653 (2518) 3816 → 2887 (2747) 2527 → 1837 (1717)
+      8,192 tokens (422)    5915 → 4920 (4744) 6301 → 5359 (5175) 4093 → 3395 (3235)
+      skewed 2,049 (133)    2378 → 2137 (1495) 2503 → 2237 (1631) 1653 → 1495 (1019)
+
+  A 128-row product runs at 94% of the peak with no fetch to wait for (the
+  one-expert row), so taller products buy nothing (256 rows: 1521; 512 rows
+  spill: 2748) and pieces are only how far ahead rows are fetched: pieces of
+  2 tiles read 3116 / 5402 at 4,096 / 8,192 (a second piece's rows queue
+  behind the next expert's 17 MB), of 8 no better than 4.  One tile an
+  expert (1,024 tokens) is all bytes under either walk.  What is left is
+  the skewed row: one expert's fetch ahead is all VMEM's two buffers allow,
+  so a one-tile expert still exposes half of the next one's fetch.
+- :func:`combine` — ``sum_k w[t, k] * y[row of (t, k)]``: for a decode
+  step's few tokens ONE gather of [T, K, D] summed over K, for a prefill's
+  thousands (the plan's tile again) K gathers of [T, D], each weighed and
+  added — [T, 6, D] pads six rows to a tile's eight and is copied once more
+  to be reshaped, 1.3 ms a layer at 3,072 tokens beside the kernel's 2.0.
 - :func:`routed_experts` — the three together, one function for a prefill's
   thousands of rows and a decode step's 64: ``sum_k w[t, k] * expert(x[t])``
   and the dispatch's load figures.  A model whose routing is known before
@@ -156,16 +202,186 @@ def _gate(act: str):
                          f"{sorted(ACTS)}") from None
 
 
+def _glu_tile(x, wg_ref, wu_ref, wd_ref, gate, dtype):
+    """One tile of rows through the step's expert: both walks' products."""
+    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+    h = (gate(g) * u).astype(wd_ref.dtype)
+    return jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32
+                   ).astype(dtype)
+
+
 def _grouped_glu_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
                         *, gate):
     @pl.when(pl.program_id(0) < na_ref[0])
     def _():
-        x = x_ref[:]
-        g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-        h = (gate(g) * u).astype(wd_ref.dtype)
-        o_ref[:] = jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32
-                           ).astype(o_ref.dtype)
+        o_ref[:] = _glu_tile(x_ref[:], wg_ref, wu_ref, wd_ref, gate,
+                             o_ref.dtype)
+
+
+# tiles of one piece of the expert walk: how many of an expert's rows are
+# fetched at once (4 MB in and 4 MB of float32 out, twice, beside two
+# experts' 35 MB of matrices); the table in the module's docstring
+_PIECE_TILES = 4
+
+
+def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, x_hbm, wg_ref, wu_ref,
+                        wd_ref, o_hbm, xbuf, obuf, sem, state, *, gate, tile,
+                        piece_tiles):
+    """Grid (E,): one grid step an expert that has rows, in the order of the
+    plan (``ex_ref`` the expert of a step, ``st_ref`` its first row,
+    ``nt_ref`` its tiles, ``n_ref`` how many steps have an expert); the
+    steps after the last repeat its indices and do nothing.  The expert's
+    three matrices are the step's blocks, so the NEXT expert's are fetched
+    for the whole of this one's rows.  The rows stay in HBM: the kernel
+    copies them in, a piece of up to ``piece_tiles`` tiles at a time, into
+    a double buffer — tile by tile, each copy of one static shape — and
+    the products' rows out again the same way.
+
+    The next piece's rows are always in flight: before a piece is waited
+    for, the next one is started into the other half — this expert's next,
+    or after its last the next expert's first (scratch and semaphores
+    persist over the sequential grid; ``state`` carries the half and the
+    tiles of the output copy still in flight).  A piece's output copy has
+    the whole of the next piece's products to land in."""
+    # scalar arithmetic by ``lax`` primitives on int32 constants: an operator
+    # on a traced scalar is a ``jnp`` function traced on its own, and ninety
+    # of them were most of this body's trace (0.3 s a kernel on the chip's
+    # host, twice a prefill program where a model has two stacks of experts)
+    zero, one, two, tile_, piece_, rows_ = (
+        jnp.int32(v) for v in (0, 1, 2, tile, piece_tiles,
+                               piece_tiles * tile))
+    j = pl.program_id(0)
+    n = n_ref[0]
+
+    def copy_rows(first_row, tiles, half, into: bool, wait: bool):
+        """Start (or wait for) the copies of ``tiles`` tiles of rows from
+        ``first_row``: HBM → ``xbuf[half]``, or ``obuf[half]`` → HBM."""
+        def one(t, carry):
+            off = lax.mul(t, tile_)
+            at = pl.ds(pl.multiple_of(lax.add(first_row, off), tile), tile)
+            here = pl.ds(pl.multiple_of(off, tile), tile)
+            if into:
+                cp = pltpu.make_async_copy(
+                    x_hbm.at[at], xbuf.at[half, here], sem.at[0, half])
+            else:
+                cp = pltpu.make_async_copy(
+                    obuf.at[half, here], o_hbm.at[at], sem.at[1, half])
+            cp.wait() if wait else cp.start()
+            return carry
+
+        lax.fori_loop(zero, tiles, one, 0)
+
+    def piece_of(step, p):
+        """(first row, tiles) of piece ``p`` of the expert of ``step``."""
+        return (lax.add(st_ref[step], lax.mul(p, rows_)),
+                lax.min(lax.sub(nt_ref[step], lax.mul(p, piece_)), piece_))
+
+    @pl.when(lax.eq(j, zero))
+    def _first():
+        state[0] = zero       # the half the next piece's rows arrive in
+        state[1] = zero       # tiles of the output copy in flight
+        state[2] = zero       # ... and their first row
+
+        @pl.when(lax.gt(n, zero))
+        def _():
+            copy_rows(*piece_of(zero, zero), zero, into=True, wait=False)
+
+    @pl.when(lax.lt(j, n))
+    def _expert():
+        first_half = state[0]
+        pieces = lax.div(lax.add(nt_ref[j], lax.sub(piece_, one)), piece_)
+        more = lax.lt(lax.add(j, one), n)       # an expert after this one
+
+        def piece(p, carry):
+            half = lax.rem(lax.add(first_half, p), two)
+            other = lax.sub(one, half)
+            row, tiles = piece_of(j, p)
+            last = lax.eq(lax.add(p, one), pieces)
+
+            @pl.when(lax.bitwise_not(last))
+            def _next_piece():
+                copy_rows(*piece_of(j, lax.add(p, one)), other, into=True,
+                          wait=False)
+
+            @pl.when(lax.bitwise_and(last, more))
+            def _next_expert():
+                copy_rows(*piece_of(lax.add(j, one), zero), other, into=True,
+                          wait=False)
+
+            copy_rows(row, tiles, half, into=True, wait=True)
+
+            def one_tile(t, carry):
+                at = pl.ds(pl.multiple_of(lax.mul(t, tile_), tile), tile)
+                obuf[half, at] = _glu_tile(xbuf[half, at], wg_ref, wu_ref,
+                                           wd_ref, gate, obuf.dtype)
+                return carry
+
+            lax.fori_loop(zero, tiles, one_tile, 0)
+            # the piece before this one's rows have left the other half
+            copy_rows(state[2], state[1], other, into=False, wait=True)
+            copy_rows(row, tiles, half, into=False, wait=False)
+            state[1] = tiles
+            state[2] = row
+            return carry
+
+        lax.fori_loop(zero, pieces, piece, 0)
+        state[0] = lax.rem(lax.add(first_half, pieces), two)
+
+        @pl.when(lax.bitwise_not(more))
+        def _drain():
+            copy_rows(state[2], state[1], lax.sub(one, state[0]), into=False,
+                      wait=True)
+
+
+@functools.partial(jax.jit, static_argnames=("tile", "act", "out_dtype",
+                                             "interpret"))
+def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
+                 out_dtype, interpret):
+    """The prefill form of :func:`grouped_glu`: see the module's docstring
+    and :func:`_expert_walk_kernel`.  ``sizes`` the plan's ``padded_sizes``.
+    A function of its own under ``jax.jit``: a program whose layers are not
+    scanned calls it once a layer, and the kernel's body (every ``pl.when``
+    and loop a trace of its own) is traced and lowered once for all of them
+    — six times over it was 1.9 s of every prefill program's first call, a
+    tenth of ``dsv2l_doc_sat``'s warm set-up."""
+    gate, glu = _gate(act)
+    R, D = x_rows.shape
+    E, F = sizes.shape[0], wg.shape[-1]
+    has = sizes > 0
+    n = jnp.sum(has, dtype=jnp.int32)
+    # the experts with rows first, in the plan's order; the steps after the
+    # last repeat it, so nothing is fetched for them
+    order = jnp.argsort(jnp.logical_not(has), stable=True).astype(jnp.int32)
+    step = order[jnp.minimum(jnp.arange(E, dtype=jnp.int32),
+                             jnp.maximum(n - 1, 0))]
+    first_row = (jnp.cumsum(sizes) - sizes).astype(jnp.int32)[step]
+    rows_max = _PIECE_TILES * tile
+
+    def expert(j, ex, st, nt, n):
+        return (ex[j], 0, 0)
+
+    return pl.pallas_call(
+        functools.partial(_expert_walk_kernel, gate=gate, tile=tile,
+                          piece_tiles=_PIECE_TILES),
+        name=f"moe_grouped_{glu}",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(E,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec((1, D, F), expert),
+                      pl.BlockSpec((1, D, F), expert),
+                      pl.BlockSpec((1, F, D), expert)],
+            out_specs=pl.BlockSpec(memory_space=pl.ANY),
+            scratch_shapes=[pltpu.VMEM((2, rows_max, D), x_rows.dtype),
+                            pltpu.VMEM((2, rows_max, D), out_dtype),
+                            pltpu.SemaphoreType.DMA((2, 2)),
+                            pltpu.SMEM((3,), jnp.int32)]),
+        out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
+        compiler_params=_VMEM_PARAMS,
+        interpret=interpret,
+    )(step + layer_base, first_row, sizes[step] // tile, n.reshape(1),
+      x_rows, wg, wu, wd)
 
 
 def _one_layer(w, layer):
@@ -207,11 +423,24 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
         interpret = pallas_interpret()
     R, D = x_rows.shape
     E, F = wg.shape[-3], wg.shape[-1]
-    tile_expert = plan.tile_expert
+    layer_base = 0
     if layer is not None:
         # a stack is its layers' experts end to end: nothing is sliced out
         wg, wu, wd = (w.reshape((-1,) + w.shape[2:]) for w in (wg, wu, wd))
-        tile_expert = tile_expert + jnp.asarray(layer, jnp.int32) * E
+        layer_base = jnp.asarray(layer, jnp.int32) * E
+    # the plan's tile chooses the walk: hundreds of rows an expert step by
+    # expert, a decode step's few by tile
+    by_expert = tile == _PREFILL_TILE
+    _obs_stats.scope("moe").counter(
+        f"grouped_{glu}_{'expert' if by_expert else 'tile'}_walks").inc()
+    if by_expert:
+        return _expert_walk(x_rows, wg, wu, wd, plan.padded_sizes,
+                            jnp.asarray(layer_base, jnp.int32), tile=tile,
+                            act=act, out_dtype=jnp.dtype(out_dtype),
+                            interpret=bool(interpret))
+    tile_expert = plan.tile_expert
+    if layer is not None:
+        tile_expert = tile_expert + layer_base
 
     def rows(i, te, na):
         return (jnp.minimum(i, na[0] - 1), 0)
@@ -251,16 +480,36 @@ def row_tile(tokens: int, dtype) -> int:
     return small if tokens <= 128 else _PREFILL_TILE
 
 
-def combine(y, weights, plan: GroupPlan):
+def _picked(y, row_of):
+    """y's rows ``row_of`` in float32; zero where there is no row."""
+    R = y.shape[0]
+    return jnp.where((row_of < R)[..., None],
+                     y[jnp.minimum(row_of, R - 1)].astype(jnp.float32), 0.0)
+
+
+@jax.jit
+def _combine_by_choice(y, weights, row_of):
+    """Traced once for all the layers of a program, as :func:`_expert_walk`
+    is."""
+    out = weights[:, 0, None] * _picked(y, row_of[:, 0])
+    for k in range(1, weights.shape[1]):
+        out = out + weights[:, k, None] * _picked(y, row_of[:, k])
+    return out
+
+
+def combine(y, weights, plan: GroupPlan, by_choice: bool = False):
     """y [R, D] (:func:`grouped_glu`'s rows), weights [T, K] float32 → the
     weighted sum of every token's chosen rows [T, D] float32; an assignment
-    with no row (its token was not valid) adds nothing."""
-    R = y.shape[0]
-    here = plan.row_of < R
-    picked = jnp.where(here[..., None],
-                       y[jnp.minimum(plan.row_of, R - 1)].astype(jnp.float32),
-                       0.0)                                      # [T, K, D]
-    return jnp.sum(weights[..., None] * picked, axis=1)
+    with no row (its token was not valid) adds nothing.  ``by_choice`` (a
+    prefill's thousands of tokens): K gathers of [T, D], each weighed and
+    added to the sum in the order of the choices, in place of ONE gather of
+    [T, K, D] — which pads every token's six rows to the eight of an (8, 128)
+    tile and is copied once more to be reshaped: 815 → 406 µs at 2,048
+    tokens of float32 rows, 751 → 207 of bf16 (PERF.md §6, PR 45)."""
+    if by_choice:
+        return _combine_by_choice(y, weights, plan.row_of)
+    rows = _picked(y, plan.row_of)                               # [T, K, D]
+    return jnp.sum(weights[..., None] * rows, axis=1)
 
 
 def planned_experts(x, weights, plan: GroupPlan, wg, wu, wd, tile: int,
@@ -272,7 +521,7 @@ def planned_experts(x, weights, plan: GroupPlan, wg, wu, wd, tile: int,
     x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)], axis=0)
     y = grouped_glu(x_pad[plan.row_token], wg, wu, wd, plan, tile, impl=impl,
                     act=act, layer=layer, out_dtype=out_dtype)
-    return combine(y, weights, plan)
+    return combine(y, weights, plan, by_choice=tile == _PREFILL_TILE)
 
 
 def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,

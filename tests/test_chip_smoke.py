@@ -167,6 +167,20 @@ def test_conv_expert_lm_phase_tiny():
     assert not any(out["fallbacks"].values())
 
 
+def test_expert_walk_phase_tiny():
+    out = chip_smoke.phase_expert_walk(
+        on_chip=False, tokens=200, top_k=2, experts=8, hidden=128,
+        expert_ffn=256)
+    assert out["prompt"]["assignments"] == 400
+    assert out["one_expert"]["experts_touched"] == 1
+    assert out["one_expert"]["rows"] == 512
+    # both plans have one shape: one lowering, by expert
+    assert out["walks"] == {"expert_walks": 1, "tile_walks": 0,
+                            "fallbacks": 0}
+    assert max(out[p]["max_diff"] / out[p]["scale"]
+               for p in ("prompt", "one_expert")) < 1e-2
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

@@ -27,7 +27,7 @@ from paddle_tpu.kernels import diffattn as DK
 from paddle_tpu.kernels import gqa as GK
 from paddle_tpu.kernels import moe as EK
 from paddle_tpu.observability import stats
-from paged_walks import eqns_under
+from paged_walks import check_both_walks_on, eqns_under
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(REPO, "benchmark", "configs",
@@ -199,3 +199,14 @@ def test_the_step_s_walk_steps_by_slot_over_pairs_of_kv_heads(one_chip,
     # 64 tokens x 4 experts in 16-row tiles, every expert's last tile padded
     assert tuple(calls["moe_grouped_swiglu"].params["grid_mapping"].grid) \
         == (EK.plan_rows(S, 4, 64, 16) // 16,)
+
+
+def test_mosaic_accepts_the_expert_walk_and_the_step_keeps_its_tiles(
+        one_chip, mosaic):
+    """[2048, 1536] x 64 experts at top-4, a layer of the stack of eight: the
+    12,288 rung's rows an expert a grid step, the step's 64 tokens a 16-row
+    tile a grid step."""
+    check_both_walks_on(one_chip, S, LADDER[-1], CFG.num_experts_per_tok,
+                        CFG.num_experts, CFG.hidden_size,
+                        CFG.moe_intermediate_size, "silu", jnp.bfloat16,
+                        layers=8)

@@ -21,6 +21,7 @@ from paddle_tpu.decode.mla import MLAConfig, MLATransformerLM, param_shapes
 from paddle_tpu.kernels import attention as AK
 from paddle_tpu.kernels import mla as MK
 from paddle_tpu.kernels import moe as EK
+from paged_walks import check_both_walks_on
 
 CFG = MLAConfig(
     vocab_size=1024, hidden_size=2048, num_hidden_layers=2,
@@ -108,3 +109,12 @@ def test_latent_pool_is_neither_copied_nor_relaid(one_chip, mosaic, bucket):
     calls = text.count("tpu_custom_call")
     # a layer's attention kernel, and the expert layer's grouped SwiGLU
     assert calls == CFG.num_hidden_layers + 1, calls
+
+
+def test_mosaic_accepts_the_expert_walk_and_the_step_keeps_its_tiles(
+        one_chip, mosaic):
+    """[2048, 1408] x 64 experts at top-6: the 8,192 rung's 57,344 rows an
+    expert a grid step, the step's 64 tokens a 16-row tile a grid step."""
+    check_both_walks_on(one_chip, S, 8192, CFG.num_experts_per_tok,
+                        CFG.n_routed_experts, CFG.hidden_size,
+                        CFG.moe_intermediate_size, "silu", jnp.float32)

@@ -26,7 +26,7 @@ from paddle_tpu.decode.smallthinker import (SmallThinkerConfig,
 from paddle_tpu.kernels import diffattn as DK
 from paddle_tpu.kernels import gqa as GK
 from paddle_tpu.kernels import moe as EK
-from paged_walks import eqns_under
+from paged_walks import check_both_walks_on, eqns_under
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 with open(os.path.join(REPO, "benchmark", "configs",
@@ -164,3 +164,15 @@ def test_the_step_s_walks_step_by_slot_under_their_own_names(one_chip,
     assert calls["gqa_ring_decode_attn"] == (S,)
     # 64 tokens x 6 experts in 16-row tiles, every expert's last tile padded
     assert calls["moe_grouped_reglu"] == (EK.plan_rows(S, 6, 64, 16) // 16,)
+
+
+def test_mosaic_accepts_the_expert_walk_and_the_step_keeps_its_tiles(
+        one_chip, mosaic):
+    """[2560, 768] x 64 experts at top-6, a layer of the stack of eight: the
+    12,288 rung's 81,920 rows an expert a grid step, the step's 64 tokens a
+    16-row tile a grid step."""
+    check_both_walks_on(one_chip, S, LADDER[-1],
+                        CFG.moe_num_active_primary_experts,
+                        CFG.moe_num_primary_experts, CFG.hidden_size,
+                        CFG.moe_ffn_hidden_size, "relu", jnp.bfloat16,
+                        layers=CFG.num_hidden_layers)

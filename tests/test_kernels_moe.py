@@ -2,7 +2,10 @@
 ``lax.ragged_dot`` and against the definition, a gate at a time: ONE kernel
 whose gate is an argument (``silu``: SwiGLU, ``relu``: ReGLU), named after
 it; and the stacked form, in which a layer's experts are reached through the
-tile→expert map and never sliced out of the stack."""
+tile→expert map and never sliced out of the stack.  Two walks, chosen by the
+plan's tile alone: a decode step's small tiles a grid step each, a prefill's
+128-row tiles an EXPERT a grid step (its rows copied in and out by the kernel
+in pieces of up to four tiles) — the same cases run under both."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +13,7 @@ import pytest
 
 from paddle_tpu.kernels import moe
 from paddle_tpu.observability import stats
-from paged_walks import eqns_under
+from paged_walks import eqns_under, moe_walks as _walks
 
 GATES = {"silu": lambda a: a / (1.0 + np.exp(-a)),
          "relu": lambda a: np.maximum(a, 0.0)}
@@ -29,6 +32,34 @@ def _case(seed, T=40, D=32, F=48, E=8, K=3, layers=None):
     return x, wg, wu, wd, ids, w
 
 
+# assignments an expert, for plans of 128-row tiles: an expert with no row,
+# one row, exactly a tile, a tile and a row, five tiles (a full piece of the
+# walk and a tile); the first and the last expert empty; all on one expert
+COUNTS = {
+    "mixed": [0, 1, 128, 129, 5 * 128 - 3, 7, 2, 0],
+    "one_expert": [0, 0, 0, 300, 0, 0, 0, 0],
+    "ends_empty": [0, 200, 3, 0, 0, 129, 256, 0],
+    "full_pieces": [512, 0, 1024, 0, 0, 0, 384, 2],
+}
+
+
+def _counted_case(seed, counts, D=32, F=48, K=2, layers=None):
+    """As :func:`_case` with the experts' loads given: ``counts[e]``
+    assignments on expert ``e``, shuffled over the tokens."""
+    rng = np.random.RandomState(seed)
+    E, N = len(counts), sum(counts)
+    assert N % K == 0
+    lead = () if layers is None else (layers,)
+    x = jnp.asarray(rng.randn(N // K, D), jnp.float32)
+    wg, wu = (jnp.asarray(rng.randn(*lead, E, D, F) * 0.2, jnp.float32)
+              for _ in range(2))
+    wd = jnp.asarray(rng.randn(*lead, E, F, D) * 0.2, jnp.float32)
+    ids = rng.permutation(np.repeat(np.arange(E), counts)).reshape(-1, K)
+    w = rng.rand(N // K, K) + 0.1
+    return (x, wg, wu, wd, jnp.asarray(ids, jnp.int32),
+            jnp.asarray(w / w.sum(-1, keepdims=True), jnp.float32))
+
+
 def _dense(x, wg, wu, wd, ids, w, valid, act):
     x, wg, wu, wd, w = (np.asarray(a, np.float64) for a in (x, wg, wu, wd, w))
     out = np.zeros(x.shape)
@@ -44,17 +75,130 @@ def test_grouped_kernel_matches_ragged_dot_and_the_definition(act):
     valid = jnp.asarray(np.arange(x.shape[0]) < 33)
     counter = f"moe.grouped_{NAMES[act]}_fallbacks"
     before = stats.to_dict().get(counter, 0)
+    walks = _walks(act)
     y0, load0 = moe.routed_experts(x, ids, w, valid, wg, wu, wd, impl="xla",
                                    act=act)
     assert stats.to_dict()[counter] == before + 1
     y1, load1 = jax.jit(lambda *a: moe.routed_experts(
         *a, impl="pallas", act=act))(x, ids, w, valid, wg, wu, wd)
     assert stats.to_dict()[counter] == before + 1
+    # forty tokens: the step's small tiles, walked a tile a grid step
+    assert _walks(act) == (walks[0], walks[1] + 1)
     np.testing.assert_allclose(y1, y0, atol=1e-4)
     np.testing.assert_allclose(y1, _dense(x, wg, wu, wd, ids, w, valid, act),
                                atol=1e-4)
     assert np.asarray(load0).tolist() == np.asarray(load1).tolist()
     assert int(load0[0]) == 33 * 3 and np.all(np.asarray(y1)[33:] == 0)
+
+
+@pytest.mark.parametrize("out_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("counts", sorted(COUNTS))
+@pytest.mark.parametrize("act", sorted(GATES))
+def test_the_expert_walk_matches_ragged_dot_and_the_definition(act, counts,
+                                                               out_dtype):
+    """A prefill's plan (128-row tiles) is walked an expert a grid step:
+    every assignment computed, whatever the experts' loads."""
+    x, wg, wu, wd, ids, w = _counted_case(5, COUNTS[counts])
+    T, E = x.shape[0], wg.shape[0]
+    valid = jnp.ones((T,), bool)
+    tile = moe.row_tile(T, x.dtype)
+    assert tile == 128
+    plan = moe.plan_groups(ids, valid, E, tile)
+    assert np.asarray(plan.padded_sizes).tolist() == [
+        -(-c // 128) * 128 for c in COUNTS[counts]]
+    fallbacks = f"moe.grouped_{NAMES[act]}_fallbacks"
+    before, walks = stats.to_dict().get(fallbacks, 0), _walks(act)
+
+    def fn(impl):
+        return jax.jit(lambda *a: moe.planned_experts(
+            a[0], a[1], plan, *a[2:], tile, impl=impl, act=act,
+            out_dtype=jnp.dtype(out_dtype)))(x, w, wg, wu, wd)
+
+    y1 = fn("pallas")
+    assert _walks(act) == (walks[0] + 1, walks[1])
+    assert stats.to_dict().get(fallbacks, 0) == before
+    want = _dense(x, wg, wu, wd, ids, w, valid, act)
+    # bf16 rows: eight bits of each of a token's K rows
+    atol = 1e-4 if out_dtype == "float32" else 2e-2 * np.abs(want).max()
+    np.testing.assert_allclose(y1, fn("xla"), atol=atol)
+    np.testing.assert_allclose(y1, want, atol=atol)
+
+
+@pytest.mark.parametrize("tile,counts", [(8, "mixed"), (128, "mixed"),
+                                         (128, "one_expert")])
+@pytest.mark.parametrize("act", sorted(GATES))
+def test_rows_past_the_last_active_tile_are_left_as_found(act, tile, counts):
+    """Neither walk computes or writes a row past the plan's last padded
+    one: the interpreter's own fill (NaN) is still there, whatever the rows
+    held coming in, and every row before is the fallback's."""
+    x, wg, wu, wd, ids, w = _counted_case(6, COUNTS[counts])
+    T, E = x.shape[0], wg.shape[0]
+    plan = moe.plan_groups(ids, jnp.ones((T,), bool), E, tile)
+    R, rows = plan.row_token.shape[0], int(np.sum(plan.padded_sizes))
+    assert 0 < rows < R and int(plan.active_tiles[0]) * tile == rows
+    x_rows = jnp.concatenate([x, jnp.zeros((1, x.shape[1]))])[plan.row_token]
+    x_rows = x_rows.at[rows:].set(1.0)
+    walks = _walks(act)
+    y = moe.grouped_glu(x_rows, wg, wu, wd, plan, tile, impl="pallas",
+                        act=act)
+    assert _walks(act) == (walks[0] + (tile == 128), walks[1] + (tile != 128))
+    assert np.isnan(np.asarray(y[rows:])).all()
+    np.testing.assert_allclose(
+        y[:rows], moe.grouped_glu_xla(x_rows, wg, wu, wd, plan, act)[:rows],
+        atol=1e-4)
+
+
+@pytest.mark.parametrize("tokens,dtype,tile,walk", [
+    (64, "bfloat16", 16, "tile"), (64, "float32", 8, "tile"),
+    (128, "bfloat16", 16, "tile"), (129, "bfloat16", 128, "expert"),
+    (2048, "bfloat16", 128, "expert")])
+@pytest.mark.parametrize("act", sorted(GATES))
+def test_the_plan_s_tile_chooses_the_walk_and_a_step_keeps_its_own(
+        act, tokens, dtype, tile, walk):
+    """A decode step's plans (at most 128 tokens: 16-row tiles, 8 in
+    float32) are walked a tile a grid step as they always were; a prefill's
+    an expert a grid step — counted when the call is lowered."""
+    E, K, D, F = 8, 2, 32, 48
+    assert moe.row_tile(tokens, dtype) == tile
+    sds = jax.ShapeDtypeStruct
+    walks = _walks(act)
+    jaxpr = jax.make_jaxpr(lambda *a: moe.routed_experts(*a, act=act))(
+        sds((tokens, D), dtype), sds((tokens, K), jnp.int32),
+        sds((tokens, K), jnp.float32), sds((tokens,), bool),
+        sds((E, D, F), dtype), sds((E, D, F), dtype), sds((E, F, D), dtype))
+    took = tuple(b - a for a, b in zip(walks, _walks(act)))
+    assert took == ((1, 0) if walk == "expert" else (0, 1))
+    call, = [e for e in eqns_under(jaxpr.jaxpr)
+             if e.primitive.name == "pallas_call"]
+    assert call.params["name"] == f"moe_grouped_{NAMES[act]}"
+    grid = tuple(call.params["grid_mapping"].grid)
+    R = moe.plan_rows(tokens, K, E, tile)
+    assert grid == ((E,) if walk == "expert" else (R // tile,))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("counts", sorted(COUNTS))
+def test_a_prefill_s_rows_are_combined_a_choice_at_a_time(counts, dtype):
+    """``combine`` by choice (K gathers of [T, D], what ``planned_experts``
+    asks for with a plan of 128-row tiles) is the one gather of [T, K, D]
+    to the last place, tokens that are not valid (no row: zero) included."""
+    _, _, _, _, ids, w = _counted_case(7, COUNTS[counts])
+    T, E = ids.shape[0], len(COUNTS[counts])
+    valid = jnp.asarray(np.arange(T) % 11 != 3)
+    plan = moe.plan_groups(ids, valid, E, 128)
+    R = plan.row_token.shape[0]
+    y = jnp.asarray(np.random.RandomState(8).randn(R, 32), dtype)
+    one = moe.combine(y, w, plan)
+    by_choice = jax.jit(lambda y, w: moe.combine(y, w, plan, by_choice=True)
+                        )(y, w)
+    assert by_choice.dtype == one.dtype == jnp.float32
+    np.testing.assert_allclose(by_choice, one, rtol=0, atol=4e-6)
+    assert np.all(np.asarray(by_choice)[~np.asarray(valid)] == 0)
+    want = np.einsum("tk,tkd->td", np.asarray(w, np.float64), np.where(
+        np.asarray(valid)[:, None, None],
+        np.asarray(y, np.float64)[np.minimum(np.asarray(plan.row_of),
+                                             R - 1)], 0.0))
+    np.testing.assert_allclose(by_choice, want, atol=1e-5)
 
 
 @pytest.mark.parametrize("act", sorted(GATES))
@@ -83,17 +227,25 @@ def test_an_unknown_gate_is_refused():
                            wd, act="gelu")
 
 
+@pytest.mark.parametrize("counts", [None, "mixed", "one_expert"])
 @pytest.mark.parametrize("impl", ["pallas", "xla"])
 @pytest.mark.parametrize("act", sorted(GATES))
-def test_a_layer_of_a_stack_is_reached_by_the_map_and_not_sliced(act, impl):
+def test_a_layer_of_a_stack_is_reached_by_the_map_and_not_sliced(act, impl,
+                                                                 counts):
     """Planned before the experts' input exists (the router read an earlier
     activation): ``plan_groups`` first, ``planned_experts`` with the stack and
-    a traced layer index after."""
+    a traced layer index after — a step's plan (``counts`` None) and a
+    prefill's, each by its walk."""
     layers, layer = 3, 2
-    x, wg, wu, wd, ids, w = _case(3, layers=layers)
+    if counts is None:
+        x, wg, wu, wd, ids, w = _case(3, layers=layers)
+    else:
+        x, wg, wu, wd, ids, w = _counted_case(3, COUNTS[counts],
+                                              layers=layers)
     T, E = x.shape[0], wg.shape[1]
     valid = jnp.asarray(np.arange(T) != 7)
     tile = moe.row_tile(T, x.dtype)
+    assert tile == (8 if counts is None else 128)
 
     def fn(x, ids, w, valid, wg, wu, wd, layer):
         plan = moe.plan_groups(ids, valid, E, tile)

@@ -22,6 +22,9 @@ checks what comes out by the repo's own means:
 - expert_walk:   the prefill form of the grouped expert kernel (an expert a
                  grid step) against its XLA fallback at DeepSeek-V2-Lite's
                  expert shapes, on a prompt's plan and on one expert's.
+- group_flash:   the serve cells' prefill attention (a K/V head's group a
+                 grid step, pad tiles skipped) against dense attention at
+                 SmallThinker's and LFM2's head layouts, 12,288 positions.
 - four_chip:     the trainer program through ``ParallelExecutor`` on a
                  dp=2 x mp=2 mesh, then one ZeRO step on dp=4 — only
                  where JAX sees >= 4 devices.
@@ -1092,6 +1095,66 @@ def phase_expert_walk(on_chip=True, tokens=2048, top_k=6, experts=64,
     return out
 
 
+def phase_group_flash(on_chip=True, tokens=12288, window=4096, block=1024,
+                      layouts=(("group_of_7", 28, 4, 128),
+                               ("pairs_of_64", 32, 8, 64)), tol=2e-2):
+    """``kernels/gqa.py group_prefill_attention`` against
+    ``prefill_attention_xla`` (dense float32 scores, called directly a block
+    of queries at a time, so no fallback is counted) at the serve cells' real
+    shapes: SmallThinker's group of seven 128-wide heads with and without
+    its window, LFM2's pairs of 64-wide K/V heads, each at the longest rung
+    with a real length short of it.  Every real row within ``tol`` of its
+    own scale in the reference (bf16 probabilities into the value product
+    read 0.6–1.0e-2 on the v5e), query tiles of padding exactly zero.
+    Interpret mode cannot see what the TPU's compiler does round a kernel
+    (PERF.md §6, PR 44).  Returns each case's largest difference and its
+    plan of tiles."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import gqa
+
+    length = int(0.87 * tokens)
+    dense = jax.jit(gqa.prefill_attention_xla, static_argnums=(2, 3, 4))
+    before, out = counters(), {}
+    for layout, nh, n_kv, dh in layouts:
+        kq, kr = jax.random.split(jax.random.PRNGKey(46 + dh))
+        q = jax.random.normal(kq, (tokens, nh, dh), jnp.bfloat16)
+        rows = jax.random.normal(kr, (tokens, 2 * n_kv * dh), jnp.bfloat16)
+        for w in ((window, None) if dh == 128 else (None,)):
+            name = f"{layout}_{'window' if w else 'full'}"
+            kernel = jax.jit(lambda q, r, n, w=w: gqa.group_prefill_attention(
+                q, r, n_kv, w, n))
+            if on_chip:
+                text = kernel.lower(q, rows, jnp.int32(length)
+                                    ).compile().as_text()
+                check(MOSAIC_CALL in text and "flash_fwd" in text,
+                      f"no Mosaic flash forward in {name}'s program")
+            got = np.asarray(kernel(q, rows, jnp.int32(length)))
+            want = np.concatenate([
+                np.asarray(dense(q[t:t + block], rows[:t + block], n_kv, w,
+                                 t)) for t in range(0, length, block)]
+            )[:length]
+            bq, bk, n_kw = gqa.flash_plan(tokens, w)
+            skipped = -(-length // bq) * bq
+            # a row far into the prompt is a mean of thousands of values: a
+            # difference is held against the ROW's own scale, so that a key
+            # tile left out of a late row cannot hide under an early row's
+            diff = np.abs(got[:length] - want).max(axis=(1, 2)) \
+                / np.abs(want).max(axis=(1, 2))
+            out[name] = {"plan": [bq, bk, n_kw],
+                         "max_row_diff": float(diff.max()),
+                         "pad_rows_zero": not got[skipped:].any()}
+            check(np.isfinite(got).all() and out[name]["max_row_diff"] <= tol,
+                  f"{name}: a row of the flash forward differs from dense "
+                  f"attention by {out[name]['max_row_diff']:.4g} of its scale")
+            check(out[name]["pad_rows_zero"],
+                  f"{name}: a query tile of padding is not zeros")
+    out["fallbacks"] = counter_delta(before,
+                                     "attn.gqa_window_prefill_fallbacks")
+    check(not out["fallbacks"], "the group flash forward fell back to XLA")
+    return out
+
+
 # ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
@@ -1254,6 +1317,7 @@ def main() -> int:
     run_phase(report, "window_expert_lm", phase_window_expert_lm)
     run_phase(report, "conv_expert_lm", phase_conv_expert_lm)
     run_phase(report, "expert_walk", phase_expert_walk)
+    run_phase(report, "group_flash", phase_group_flash)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])

@@ -514,9 +514,9 @@ class FalconH1LM:
         out_ssm, S, tail = self._ssm_prompt(w, u, valid, length, dense)
         q, rows = self._qkv(w, u, positions, cache_dtype)
         with jax.named_scope("attn"):
-            attend = (_gqa.prefill_attention_xla if dense
-                      else _gqa.prefill_attention)
-            o = attend(q, rows, cfg.num_key_value_heads)
+            o = _gqa.prefill_attention_xla(q, rows, cfg.num_key_value_heads) \
+                if dense else _gqa.prefill_attention(
+                    q, rows, cfg.num_key_value_heads, length)
         x = x + out_ssm + self._attn_out(w, o, x.dtype)
         return self._mlp(w, x), S, tail, rows
 

@@ -68,6 +68,7 @@ weighed and summed in float32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -544,8 +545,8 @@ class LFM2LM:
         valid = pos < length
         last = jnp.maximum(length - 1, 0)
         tile = _moe.row_tile(T, jnp.dtype(cfg.dtype))
-        attend = _gqa.prefill_attention_xla if dense \
-            else _gqa.group_prefill_attention
+        attend = _gqa.prefill_attention_xla if dense else functools.partial(
+            _gqa.group_prefill_attention, length=length)
 
         def mixer(w, x, carry, kind, at):
             u = self._rms(x, w["ln1"])

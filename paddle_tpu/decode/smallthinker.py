@@ -522,8 +522,10 @@ class SmallThinkerLM:
         last = jnp.maximum(length - 1, 0)
         tile = _moe.row_tile(T, jnp.dtype(cfg.dtype))
         attend = functools.partial(
-            _gqa.prefill_attention_xla if dense
-            else _gqa.group_prefill_attention, n_kv=cfg.num_key_value_heads)
+            _gqa.prefill_attention_xla, n_kv=cfg.num_key_value_heads) \
+            if dense else functools.partial(
+                _gqa.group_prefill_attention, n_kv=cfg.num_key_value_heads,
+                length=length)
 
         def layer(w, stacks, at, x, carry, kind):
             rope = kind == "window"

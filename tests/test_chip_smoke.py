@@ -181,6 +181,22 @@ def test_expert_walk_phase_tiny():
                for p in ("prompt", "one_expert")) < 1e-2
 
 
+def test_group_flash_phase_tiny(monkeypatch):
+    from paddle_tpu.kernels import gqa
+    monkeypatch.setattr(gqa, "_Q_TILE", 8)
+    monkeypatch.setattr(gqa, "_K_TILE", 32)
+    out = chip_smoke.phase_group_flash(
+        on_chip=False, tokens=96, window=20, block=32,
+        layouts=(("group_of_7", 14, 2, 128), ("pairs_of_64", 16, 4, 64)))
+    assert out["group_of_7_full"]["plan"] == [8, 32, 3]
+    assert out["group_of_7_window"]["plan"] == [8, 32, 2]
+    assert set(out) == {"group_of_7_window", "group_of_7_full",
+                        "pairs_of_64_full", "fallbacks"}
+    assert all(out[c]["pad_rows_zero"] and out[c]["max_row_diff"] < 2e-2
+               for c in out if c != "fallbacks")
+    assert out["fallbacks"] == 0
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

@@ -36,6 +36,13 @@ def _rows(k, v):
                             v.reshape(v.shape[0], KW)], axis=-1)
 
 
+def _tiles(monkeypatch, rows, columns):
+    """The group flash forward's plan at a test's size: query tiles of
+    ``rows``, key tiles of up to ``columns``."""
+    monkeypatch.setattr(gqa, "_Q_TILE", rows)
+    monkeypatch.setattr(gqa, "_K_TILE", columns)
+
+
 @pytest.mark.parametrize("walk", sorted(WALKS))
 def test_paged_decode_kernel_walks_only_a_slot_s_live_rows(monkeypatch, walk):
     """Contexts by where they end against a chunk of the walk, idle slots
@@ -85,10 +92,16 @@ def test_decode_over_rotated_keys_equals_dense_attention_at_those_positions():
     assert np.abs(np.asarray(got) - plain).max() > 1e-2
 
 
-@pytest.mark.parametrize("T,tile", [(32, 8), (16, 16), (24, 8)],
-                         ids=["four_tiles", "one_tile", "three_tiles"])
-def test_flash_prefill_matches_dense_causal_attention(monkeypatch, T, tile):
-    monkeypatch.setattr(gqa, "_FLASH_BLOCK", tile)
+@pytest.mark.parametrize("T,tile,wide,length", [
+    (32, 8, 8, None), (16, 16, 16, None), (24, 8, 8, None),
+    (64, 8, 32, None), (64, 8, 32, 27), (48, 8, 32, 40)],
+    ids=["four_tiles", "one_tile", "three_tiles", "wide_key_tiles",
+         "length_inside_a_tile", "length_on_a_tile_s_edge"])
+def test_flash_prefill_matches_dense_causal_attention(monkeypatch, T, tile,
+                                                      wide, length):
+    """``prefill_attention`` is the group flash forward with no window under
+    the name ``gqa_flash_fwd``: a group of five over rotated q and k."""
+    _tiles(monkeypatch, tile, wide)
     rng = np.random.default_rng(T)
     pos = jnp.arange(T)
     q = rotary(jnp.asarray(rng.standard_normal((T, NH, DH)), jnp.float32),
@@ -97,12 +110,18 @@ def test_flash_prefill_matches_dense_causal_attention(monkeypatch, T, tile):
                pos, THETA)
     v = jnp.asarray(rng.standard_normal((T, NKV, DH)), jnp.float32)
     before = stats.to_dict().get("attn.gqa_prefill_fallbacks", 0)
-    got = jax.jit(lambda q, r: gqa.prefill_attention(q, r, NKV))(
-        q, _rows(k, v))
+    fn = jax.jit(lambda q, r: gqa.prefill_attention(q, r, NKV, length))
+    got = fn(q, _rows(k, v))
     assert stats.to_dict().get("attn.gqa_prefill_fallbacks", 0) == before
+    from paged_walks import eqns_under
+    assert [e.params["name"] for e in eqns_under(
+        jax.make_jaxpr(fn)(q, _rows(k, v)).jaxpr)
+        if e.primitive.name == "pallas_call"] == ["gqa_flash_fwd"]
     keep = np.tril(np.ones((T, T), bool))
     want = _dense(q, k, v, keep)
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    real = T if length is None else length
+    np.testing.assert_allclose(got[:real], want[:real], rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[-(-real // tile) * tile:]).any()
     np.testing.assert_allclose(gqa.prefill_attention_xla(q, _rows(k, v), NKV),
                                want, rtol=1e-5, atol=1e-5)
 
@@ -141,27 +160,71 @@ def _window_keep(T, window):
     return keep
 
 
-@pytest.mark.parametrize("T,tile,window", [
-    (64, 8, None), (64, 8, 20), (64, 8, 8), (64, 8, 64), (64, 8, 200),
-    (48, 16, 17), (16, 16, 5)],
-    ids=["no_window", "window_mid_tile", "window_one_tile", "window_at_T",
-         "window_past_T", "three_tiles_deep", "one_tile"])
-def test_group_flash_matches_dense_windowed_attention(monkeypatch, T, tile,
-                                                      window):
+# (T, query rows, widest key tile, window, length): square tiles, then key
+# tiles wider than a query tile, then prompts with padding behind them
+FLASH_CASES = {
+    "no_window": (64, 8, 8, None, None),
+    "window_mid_tile": (64, 8, 8, 20, None),
+    "window_one_tile": (64, 8, 8, 8, None),
+    "window_at_T": (64, 8, 8, 64, None),
+    "window_past_T": (64, 8, 8, 200, None),
+    "three_tiles_deep": (48, 16, 16, 17, None),
+    "one_tile": (16, 16, 16, 5, None),
+    "wide_no_window": (64, 8, 32, None, None),
+    "wide_window_inside_a_key_tile": (96, 8, 32, 43, None),
+    "wide_window_on_a_key_tile_s_edge": (96, 8, 32, 33, None),
+    "wide_window_of_one_key_tile": (96, 8, 32, 32, None),
+    "wide_prompt_shorter_than_a_key_tile": (16, 8, 32, None, None),
+    "wide_T_not_a_multiple_of_the_widest": (48, 8, 32, 20, None),
+    "length_inside_a_tile": (64, 8, 32, None, 27),
+    "length_on_a_tile_s_edge": (64, 8, 32, 20, 40),
+    "length_on_a_key_tile_s_edge": (96, 8, 32, 43, 64),
+    "length_of_zero": (64, 8, 32, 20, 0),
+    "length_of_T": (64, 8, 32, None, 64),
+    "length_of_one": (64, 8, 16, 9, 1),
+    "square_tiles_with_a_length": (64, 8, 8, 20, 35),
+}
+
+
+def _check_flash(q, rows, n_kv, window, length, want, q_tile):
+    """The group flash forward against the dense result ``want``: every row
+    with no ``length``; with one, the real rows, and exact zeros in the query
+    tiles that hold only padding."""
+    T = q.shape[0]
+    before = stats.to_dict().get("attn.gqa_window_prefill_fallbacks", 0)
+    got = jax.jit(lambda q, r, n: gqa.group_prefill_attention(
+        q, r, n_kv, window, n))(
+            q, rows, None if length is None else jnp.int32(length))
+    assert stats.to_dict().get(
+        "attn.gqa_window_prefill_fallbacks", 0) == before
+    real = T if length is None else length
+    np.testing.assert_allclose(got[:real], want[:real], rtol=1e-5, atol=1e-5)
+    skipped = -(-real // q_tile) * q_tile
+    assert not np.asarray(got[skipped:]).any()
+    # a tile the prompt ends in is computed whole, pad rows and all
+    np.testing.assert_allclose(got[real:skipped], want[real:skipped],
+                               rtol=1e-5, atol=1e-5)
+    if length is not None:
+        # with no length the same real rows, to the bit
+        np.testing.assert_array_equal(
+            got[:real], gqa.group_prefill_attention(q, rows, n_kv, window
+                                                    )[:real])
+
+
+@pytest.mark.parametrize("case", list(FLASH_CASES))
+def test_group_flash_matches_dense_windowed_attention(monkeypatch, case):
     """Prompts shorter than, as long as and several times the window; a
-    window that ends inside a tile and on a tile's edge."""
-    monkeypatch.setattr(da, "_FLASH_BLOCK", tile)
+    window that ends inside a tile and on a tile's edge; key tiles as wide as
+    a query tile and four times as wide; real lengths inside a tile, on a
+    tile's edge, of nothing and of the whole rung."""
+    T, q_tile, k_tile, window, length = FLASH_CASES[case]
+    _tiles(monkeypatch, q_tile, k_tile)
     rng = np.random.default_rng(T + (window or 0))
     q = jnp.asarray(rng.standard_normal((T, GNH, DH)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((T, NKV, DH)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((T, NKV, DH)), jnp.float32)
-    before = stats.to_dict().get("attn.gqa_window_prefill_fallbacks", 0)
-    got = jax.jit(lambda q, r: gqa.group_prefill_attention(q, r, NKV, window)
-                  )(q, _rows(k, v))
-    assert stats.to_dict().get(
-        "attn.gqa_window_prefill_fallbacks", 0) == before
     want = _dense_g(q, k, v, _window_keep(T, window))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    _check_flash(q, _rows(k, v), NKV, window, length, want, min(T, q_tile))
     np.testing.assert_allclose(
         gqa.prefill_attention_xla(q, _rows(k, v), NKV, window), want,
         rtol=1e-5, atol=1e-5)
@@ -171,20 +234,75 @@ def test_group_flash_matches_dense_windowed_attention(monkeypatch, T, tile,
                       ).max() > 1e-2
 
 
+def test_the_dense_reference_takes_a_block_of_queries_at_a_time():
+    rng = np.random.default_rng(12)
+    q = jnp.asarray(rng.standard_normal((32, GNH, DH)), jnp.float32)
+    rows = jnp.asarray(rng.standard_normal((32, 2 * KW)), jnp.float32)
+    whole = gqa.prefill_attention_xla(q, rows, NKV, 11)
+    np.testing.assert_allclose(
+        gqa.prefill_attention_xla(q[8:24], rows[:24], NKV, 11, start=8),
+        whole[8:24], rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("T,q_tile,k_tile,window,plan", [
+    (12288, 256, 1024, None, (256, 1024, 12)),
+    (12288, 256, 1024, 4096, (256, 1024, 5)),
+    (12288, 256, 512, 4096, (256, 512, 9)),
+    (2560, 256, 1024, None, (256, 512, 5)),     # the widest that divides T
+    (1536, 256, 1024, None, (256, 768, 2)),
+    (512, 256, 1024, 4096, (256, 512, 1)),
+    (64, 256, 1024, None, (64, 64, 1)),
+    (48, 8, 32, 20, (8, 24, 2)),
+    (300, 256, 1024, None, (256, 256, 1))],      # no tile divides T: XLA's
+    ids=["full_12288", "window_12288", "window_12288_of_512", "T_2560",
+         "T_1536", "one_key_tile", "below_a_query_tile", "small",
+         "no_tile_divides_T"])
+def test_flash_plan_takes_the_widest_key_tile_that_divides_the_prompt(
+        monkeypatch, T, q_tile, k_tile, window, plan):
+    _tiles(monkeypatch, q_tile, k_tile)
+    assert gqa.flash_plan(T, window) == plan
+
+
+@pytest.mark.parametrize("window,length", [(None, 64), (20, 64), (None, 27),
+                                           (20, 40), (43, 1), (20, 0)])
+def test_the_walk_fetches_a_visible_tile_once_and_nothing_for_padding(
+        window, length):
+    """The K/V index map over a K/V head's grid steps, in the order the grid
+    walks them: a change of index is a fetch."""
+    T, bq, bk = 96, 8, 32
+    first, last = gqa._key_span(np.arange(T // bq), bq, bk, window, np)
+    n_kw = int((last - first).max()) + 1
+    walk = [int(gqa._key_tile(jnp.int32(i), jnp.int32(j), jnp.int32(length),
+                              bq, bk, window))
+            for i in range(T // bq) for j in range(n_kw)]
+    fetched = [t for at, t in enumerate(walk) if at == 0 or t != walk[at - 1]]
+    keep = _window_keep(T, window)
+    want = []       # every real query tile's visible key tiles, in order
+    for i in range(-(-length // bq)):
+        cols = np.flatnonzero(keep[i * bq:(i + 1) * bq].any(0))
+        want += sorted(set(cols // bk))
+    want = [t for at, t in enumerate(want) if at == 0 or t != want[at - 1]]
+    assert fetched == (want or fetched[:1])
+
+
 def test_group_flash_names_follow_the_window_and_fetch_a_tile_once_a_group(
         monkeypatch):
-    monkeypatch.setattr(da, "_FLASH_BLOCK", 8)
+    _tiles(monkeypatch, 8, 32)
     from paged_walks import eqns_under
-    q = jnp.zeros((64, GNH, DH), jnp.bfloat16)
-    rows = jnp.zeros((64, 2 * KW), jnp.bfloat16)
-    for window, name, tiles in ((None, "gqa_group_flash_fwd", 8),
-                                (20, "gqa_window_flash_fwd", 4)):
+    q = jnp.zeros((96, GNH, DH), jnp.bfloat16)
+    rows = jnp.zeros((96, 2 * KW), jnp.bfloat16)
+    for window, name, tiles in ((None, "gqa_group_flash_fwd", 3),
+                                (20, "gqa_window_flash_fwd", 2)):
         calls = [e for e in eqns_under(jax.make_jaxpr(
-            lambda q, r: gqa.group_prefill_attention(q, r, NKV, window)
-        )(q, rows).jaxpr) if e.primitive.name == "pallas_call"]
+            lambda q, r, n: gqa.group_prefill_attention(q, r, NKV, window, n)
+        )(q, rows, jnp.int32(50)).jaxpr) if e.primitive.name == "pallas_call"]
         assert [e.params["name"] for e in calls] == [name]
-        # one grid step a K/V head, not a query head, a (query, key) tile
-        assert tuple(calls[0].params["grid_mapping"].grid) == (NKV, 8, tiles)
+        # one grid step a K/V head, not a query head, a (query tile of 8,
+        # key tile of 32) pair: a K/V tile is fetched once a group
+        grid = calls[0].params["grid_mapping"]
+        assert tuple(grid.grid) == (NKV, 12, tiles)
+        # the prompt's length rides the call as a prefetched scalar
+        assert grid.num_index_operands == 1
 
 
 def test_group_flash_at_another_head_size_falls_back_and_counts():
@@ -310,23 +428,22 @@ def test_paged_walk_at_heads_of_64_pairs_the_kv_heads_of_a_tile(monkeypatch,
         atol=1e-5)
 
 
-@pytest.mark.parametrize("T,tile,window", [(64, 8, None), (48, 16, 17),
-                                           (16, 16, None)],
-                         ids=["no_window", "window", "one_tile"])
-def test_group_flash_at_heads_of_64_matches_dense_attention(monkeypatch, T,
-                                                            tile, window):
-    monkeypatch.setattr(da, "_FLASH_BLOCK", tile)
+@pytest.mark.parametrize("case", [
+    "no_window", "three_tiles_deep", "one_tile", "wide_no_window",
+    "wide_window_inside_a_key_tile", "wide_T_not_a_multiple_of_the_widest",
+    "length_inside_a_tile", "length_on_a_tile_s_edge", "length_of_zero",
+    "length_of_T"])
+def test_group_flash_at_heads_of_64_matches_dense_attention(monkeypatch,
+                                                            case):
+    T, q_tile, k_tile, window, length = FLASH_CASES[case]
+    _tiles(monkeypatch, q_tile, k_tile)
     rng = np.random.default_rng(T + (window or 0))
     q = jnp.asarray(rng.standard_normal((T, NH64, H64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((T, NKV64, H64)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((T, NKV64, H64)), jnp.float32)
-    before = stats.to_dict().get("attn.gqa_window_prefill_fallbacks", 0)
-    got = jax.jit(lambda q, r: gqa.group_prefill_attention(
-        q, r, NKV64, window))(q, _rows64(k, v))
-    assert stats.to_dict().get(
-        "attn.gqa_window_prefill_fallbacks", 0) == before
     want = _dense64(q, k, v, _window_keep(T, window))
-    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    _check_flash(q, _rows64(k, v), NKV64, window, length, want,
+                 min(T, q_tile))
     np.testing.assert_allclose(
         gqa.prefill_attention_xla(q, _rows64(k, v), NKV64, window), want,
         rtol=1e-5, atol=1e-5)
@@ -334,7 +451,7 @@ def test_group_flash_at_heads_of_64_matches_dense_attention(monkeypatch, T,
 
 def test_kernels_at_heads_of_64_have_names_of_their_own_and_a_tile_a_pair(
         monkeypatch):
-    monkeypatch.setattr(da, "_FLASH_BLOCK", 8)
+    _tiles(monkeypatch, 8, 8)
     from paged_walks import eqns_under
 
     def calls(fn, *args):

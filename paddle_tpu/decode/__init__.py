@@ -29,60 +29,35 @@ package is that state plane, built on the repo's own primitives:
   the kernel itself in chunks from the scalar-prefetched tables and
   lengths, with an XLA-gather path (``impl="xla"``) and interpret-mode
   CPU coverage (the ``kernels/sparse.py`` contract).
-- **A second model behind the same engine** (:mod:`mla`): DeepSeek-V2's
-  block — latent (MLA) attention over ONE latent pool
-  (:class:`~paddle_tpu.decode.cache.PagedLatentCache`, no V pool), YaRN
-  rotary positions, routed experts at top-k beside shared ones
-  (``kernels/mla.py``, ``kernels/moe.py``).  A model describes its cache
+- **The model side** (:mod:`adapter`): a served model is an
+  :class:`~paddle_tpu.decode.adapter.LMAdapter` — it describes its cache
   (``make_cache``), owns the state list its ``prefill`` / ``decode_step``
-  thread as ``(const, state, *feed) → (outs, state')``, and says what of
-  the block lifecycle it ``supports``; the engine has no branch on a
-  model's kind.
-- **A third model, with three kinds of state** (:mod:`sambay`):
-  Phi-4-mini-flash-reasoning's decoder-hybrid-decoder stack — state-space
-  layers, window attention, ONE full-attention layer whose K/V rows seven
-  cross-attention layers read, gated memory units, differential attention
-  (``kernels/ssm.py``, ``kernels/diffattn.py``), its programs
-  ``lax.scan``s over stacked layer pairs.  Its cache
-  (:class:`~paddle_tpu.decode.cache.HybridStateCache`) holds, under the
-  one manager, blocks of a paged K/V pool (held to the stream's end,
-  addressed by block table), window rings (a slot's last W rows: bounded
-  by the window, not by the context) and recurrent rows (a slot's
-  state-space state and convolution tail), the last two addressed by
-  SLOT.  **The model protocol**: ``prefill(const, state, tokens, length,
-  [slot,] block_table, seed, temperature, top_k)`` and
-  ``decode_step(const, state, tokens, positions, block_tables, seeds,
-  steps, temperature, top_k)``, each ``→ ([token(s), logits, *extra],
-  state')`` with ``state`` the cache's own list; a model that sets
-  ``slot_state`` is given the engine's slot count in ``make_cache`` and
-  the joining request's slot in ``prefill``'s feed (its prefill overwrites
-  the slot's rows whole — the reset at a join); a decode step's row ``i``
-  is slot ``i``, and a slot without a stream scribbles on its own rows
-  only.  ``extra`` goes to the model's ``observer`` (``prefill(extra,
-  prompt, bucket)``; ``step(extra, contexts)`` with the live streams'
-  context lengths, which the engine holds on the host: a program returns
-  nothing for a count the host already has, and nothing for a check;
-  ``decodez()``: what the observer adds to ``/decodez`` —
-  ``step_live_blocks`` of ``step_table_blocks``, the share of the tables
-  handed to the decode steps' attention kernels that their walks fetched:
-  a live stream's blocks up to its context and one of an idle slot, of
-  slots x blocks a slot — one layer's for a :class:`TransformerLM`
-  (:class:`~paddle_tpu.decode.model.TableWalkObserver`), summed over every
-  layer that reads for the two hybrid models (:mod:`sambay`: the pool's
-  readers, and the window layers' rings up to ``min(context, W)``;
-  :mod:`falcon_h1`: every layer)).
-- **A fourth model, with state of two kinds in EVERY layer**
-  (:mod:`falcon_h1`): Falcon-H1's parallel-hybrid block — a Mamba-2
-  (state-space duality) mixer and a grouped-query attention with rotary
-  positions side by side on one normed input, the published µP
-  multipliers, an untied head (``kernels/ssd.py``, ``kernels/gqa.py``),
-  its programs ``lax.scan``s over the layers' stacked weights.  Its cache
-  is the same :class:`~paddle_tpu.decode.cache.HybridStateCache` with a
-  paged K/V pool of L layers, recurrent rows ``[L, slots, heads, N, P]``
-  and convolution tails of L layers, and no window rings: blocks are
-  released at a leave, a slot's rows are overwritten whole at a join.
-  Same protocol, same ``slot_state``; ``supports`` is empty for it too.
-- **On-device sampling** (:mod:`model`): greedy (an argmax; the
+  thread as ``(const, state, *feed) → ([token(s), logits, *extra], state')``,
+  hands ``extra`` and the host's context lengths to its ``observer``, and
+  says what of the block lifecycle it ``supports``; the engine has no branch
+  on a model's kind.  State is blocks of a paged pool, held by block table,
+  and — for a model that sets ``slot_state`` — rows addressed by SLOT
+  (window rings, recurrent rows, convolution tails:
+  :class:`~paddle_tpu.decode.cache.HybridStateCache`), overwritten whole by
+  the prefill at a join.  The module also holds what the models share: the
+  layer math, the sampling epilogue, the paged pool's addressing (the trash
+  block's rule) and the observers' common ``decode.<engine>.*`` series —
+  among them ``step_live_blocks`` of ``step_table_blocks`` on ``/decodez``,
+  the share of the tables handed to the decode steps' attention kernels that
+  their walks fetched.
+- **Six models behind the one engine**: :mod:`model` (the repo's LM block:
+  K and V of every layer paged, a suffix prefill, so the one model that
+  ``supports`` prefix cache, overcommit and beams); :mod:`mla` (DeepSeek-V2:
+  latent attention over ONE latent pool, YaRN, routed experts beside shared
+  ones); :mod:`sambay` (Phi-4-mini-flash-reasoning: state-space layers,
+  window rings, ONE full-attention layer's pool that the cross-attention
+  layers read, differential attention); :mod:`falcon_h1` (Falcon-H1: a
+  Mamba-2 mixer and grouped-query attention side by side in every layer);
+  :mod:`smallthinker` (SmallThinker: periods of one full and three window
+  layers, 64 ReLU experts a layer, the router ahead of the attention);
+  :mod:`lfm2` (LFM2: gated short convolutions and 64-wide-head attention,
+  sigmoid-routed experts).  Each module's docstring is its model's.
+- **On-device sampling** (:func:`adapter.sample`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
   top-k / temperature inside the decode dispatch; incremental beam
   search rides

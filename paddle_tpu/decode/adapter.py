@@ -1,0 +1,484 @@
+"""What the served models have in common: the model protocol as a class, the
+layer math they share, the paged pool's addressing and the observers' common
+series.  A model module (:mod:`model`, :mod:`mla`, :mod:`sambay`,
+:mod:`falcon_h1`, :mod:`smallthinker`, :mod:`lfm2`) brings its config, its
+``param_shapes``, its layers, its two programs and the counters that are its
+own; it imports this module and no sibling.
+
+What is here is code the models had to the letter, or with another value in
+it (an epsilon, a theta, a histogram's buckets).  No function here takes a
+scope name or asks which model calls it: what differs in structure (``_qkv``,
+``_head``, the scans, every mixer) stays with its model.
+"""
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Callable, Dict, List, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ..observability import stats as _obs_stats
+
+# static top-k ceiling compiled into the sampling epilogue: per-slot k
+# varies at runtime UNDER it without a recompile (a fixed shape is the
+# whole decode-plane contract)
+TOPK_MAX = 64
+
+# ``model_type`` of a saved config → builder of its model from the config's
+# dict; a model module adds itself on import (``model.load_lm`` reads it)
+MODEL_TYPES: Dict[str, Callable] = {}
+
+
+# ---------------------------------------------------------------------------
+# shared layer math
+# ---------------------------------------------------------------------------
+
+def mm(x, w):
+    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+
+
+def rms_norm(x, g, eps: float):
+    """``x / rms(x) · g`` over the last axis, in float32, back in x's
+    dtype."""
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    return (x32 * jax.lax.rsqrt(var + eps)
+            * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def rotary(x, positions, theta: float):
+    """Rotate-half rotary positions over the whole head: x [N, heads, dh],
+    positions [N] → the same shape and dtype, computed in float32."""
+    half = x.shape[-1] // 2
+    inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
+                  * (-math.log(theta) / half))
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
+    x32 = x.astype(jnp.float32)
+    a, b = x32[..., :half], x32[..., half:]
+    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
+                           axis=-1).astype(x.dtype)
+
+
+def sub(w: dict, prefix: str) -> dict:
+    """The entries of ``w`` under ``prefix``, the prefix cut off."""
+    n = len(prefix)
+    return {k[n:]: v for k, v in w.items() if k.startswith(prefix)}
+
+
+# the three matrices of a layer's routed experts in a ``param_shapes``: a
+# program that scans over layers hands the grouped kernel their whole stack
+# and the layer's index, and scans over the rest
+EXPERT_LEAVES = ("e_gate", "e_up", "e_down")
+
+
+def unscanned(w: dict) -> dict:
+    """A layer stack's tensors less the experts' (those are not scanned)."""
+    return {k: v for k, v in w.items() if k not in EXPERT_LEAVES}
+
+
+def init_tensor(key, shape: tuple, init, dtype):
+    """One tensor of a ``param_shapes`` from a PRNG key (jit-able with
+    ``shape``, ``init`` and ``dtype`` static): a float is the std of a
+    normal, ``norm`` a norm weight (1 + 0.1 N), ``bias`` a bias (0.02 N)."""
+    w = jax.random.normal(key, shape, jnp.float32)
+    if isinstance(init, str):
+        scale, shift = {"norm": (0.1, 1.0), "bias": (0.02, 0.0)}[init]
+        w = shift + scale * w
+    else:
+        w = w * init
+    return w.astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# sampling
+# ---------------------------------------------------------------------------
+
+def _hash_uniform(seeds, steps, kk):
+    """Counter-hash uniforms in (0, 1): one murmur-style mix per
+    (request seed, token index, candidate lane) — the attention
+    dropout hash's recipe, keyed PER REQUEST.  A seeded stream is
+    replayable bit-for-bit regardless of which slot it lands on or
+    what else shares the decode batch (an engine-global PRNG key
+    could not promise that)."""
+    S = seeds.shape[0]
+    lane = jax.lax.broadcasted_iota(jnp.uint32, (S, kk), 1)
+    x = (seeds.astype(jnp.uint32)[:, None] * jnp.uint32(0x9E3779B1)
+         ^ steps.astype(jnp.uint32)[:, None] * jnp.uint32(0x85EBCA77)
+         ^ lane * jnp.uint32(0xC2B2AE3D))
+    x = x ^ (x >> 16)
+    x = x * jnp.uint32(0x7FEB352D)
+    x = x ^ (x >> 15)
+    x = x * jnp.uint32(0x846CA68B)
+    x = x ^ (x >> 16)
+    u = (jax.lax.bitcast_convert_type(x >> 8, jnp.int32)
+         .astype(jnp.float32) * jnp.float32(1.0 / (1 << 24)))
+    return jnp.clip(u, 1e-7, 1.0 - 1e-7)
+
+
+def sample(logits, seeds, steps, temperature, top_k):
+    """On-device sampling epilogue: logits [S, V], seeds [S] uint32
+    (per REQUEST), steps [S] int32 (each request's token index),
+    temperature [S] f32 (<= 0 ⇒ greedy), top_k [S] int32 (0 ⇒ full
+    vocab) → tokens [S] int32.  Per-slot knobs vary at runtime under
+    the static ``TOPK_MAX`` ceiling; sampling is Gumbel-max over the
+    top slice with :func:`_hash_uniform` bits, so a request's sampled
+    stream depends only on (its seed, its token indices) — replayable
+    across slot placements and batch compositions.
+
+    What a caller can rely on: a greedy row is an argmax (the first
+    maximal index) and costs no sort.  ``lax.top_k`` — on a TPU a sort
+    of the whole vocabulary — runs only in a launch that holds at least
+    one ``temperature > 0`` row (the ``lax.cond`` below: one program,
+    the branch taken on the device from this launch's own input), and
+    then every row of that launch pays for it.  The tokens are the same
+    either way: a greedy row's is column 0 of the sorted slice, whose
+    ties go to the lower index as argmax's do."""
+    S, V = logits.shape
+    kk = min(TOPK_MAX, V)
+    x = logits.astype(jnp.float32)
+    greedy = jnp.argmax(x, axis=-1).astype(jnp.int32)
+
+    def from_top_slice():
+        vals, idx = jax.lax.top_k(x, kk)                        # [S, kk]
+        lane = jnp.arange(kk, dtype=jnp.int32)[None, :]
+        want = jnp.where(top_k > 0, jnp.minimum(top_k, kk), kk)[:, None]
+        vals = jnp.where(lane < want, vals, -jnp.inf)
+        g = -jnp.log(-jnp.log(_hash_uniform(seeds, steps, kk)))
+        temp = jnp.maximum(temperature, 1e-6)[:, None]
+        choice = jnp.argmax(vals / temp + g, axis=-1)
+        return jnp.take_along_axis(
+            idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
+
+    sampled = jax.lax.cond(jnp.any(temperature > 0.0), from_top_slice,
+                           lambda: greedy)
+    return jnp.where(temperature <= 0.0, greedy, sampled)
+
+
+def sample_first(logits, seed, temperature, top_k):
+    """A prefill's sampling tail: logits [V] and the request's scalars →
+    its first token [] (token index 0), so a joining request streams a
+    token without waiting for a decode step."""
+    return sample(logits[None], seed[None], jnp.zeros((1,), jnp.int32),
+                  temperature[None], top_k[None])[0]
+
+
+# ---------------------------------------------------------------------------
+# the paged pool's addressing
+# ---------------------------------------------------------------------------
+# Block 0 of every pool is the TRASH block: no stream is ever given it.  A
+# program has fixed shapes, so it writes a row for every position of a
+# padded bucket and for every slot, with or without a stream; the rows that
+# belong to nobody all go to block 0, where no table of a live stream points,
+# and no mask or branch is needed around the write.  A slot without a stream
+# has a table of zeros, which is how a step tells it (``live``).
+
+def prompt_addresses(length, bucket: int, block_table, block_tokens: int):
+    """Where a bucket-padded prompt's rows go: ``pos`` [Tb] int32, ``valid``
+    [Tb] (a real position), ``blocks`` [Tb] (the block of each position — a
+    pad's is the trash block, and a position past a short table is clamped
+    onto its last entry) and ``last`` [] (the last real position, 0 for an
+    empty prompt).  The row inside the block is ``pos % block_tokens``."""
+    pos = jnp.arange(bucket, dtype=jnp.int32)
+    valid = pos < length
+    entry = jnp.minimum(pos // block_tokens, block_table.shape[0] - 1)
+    blocks = jnp.where(valid, block_table[entry], 0)
+    last = jnp.maximum(length - 1, 0)
+    return pos, valid, blocks, last
+
+
+def step_addresses(positions, block_tables, block_tokens: int):
+    """Where a decode step's rows go: ``cl`` [S] (each slot's context
+    length, this token included), ``live`` [S] (the slot holds a stream),
+    ``slots`` [S] int32 and ``blocks`` [S] (the block each slot's token lands
+    in — the trash block for a slot without a stream).  The row inside the
+    block is ``positions % block_tokens``."""
+    cl = positions + 1
+    live = block_tables[:, 0] != 0
+    slots = jnp.arange(positions.shape[0], dtype=jnp.int32)
+    blocks = block_tables[slots, positions // block_tokens]
+    return cl, live, slots, blocks
+
+
+def walked_blocks(contexts, block_rows: int, slots: int) -> int:
+    """Blocks ONE paged walk fetches in a decode step: a live stream's
+    ``ceil(context / block_rows)`` and an idle slot's one (its length is one
+    token).  ``contexts``: the live streams' lengths, one entry each."""
+    contexts = np.asarray(contexts)
+    return int(np.sum((contexts + block_rows - 1) // block_rows)) \
+        + slots - int(contexts.size)
+
+
+# ---------------------------------------------------------------------------
+# the protocol
+# ---------------------------------------------------------------------------
+
+class ConfigDict:
+    """``to_dict`` / ``from_dict`` of a config dataclass whose fields are
+    published keys.  ``model_type`` (a class attribute, not a field) is what
+    ``to_dict`` adds so that :func:`~paddle_tpu.decode.model.load_lm` finds
+    the model in :data:`MODEL_TYPES`."""
+
+    model_type = None
+
+    def to_dict(self) -> dict:
+        d = dataclasses.asdict(self)
+        if self.model_type is not None:
+            d["model_type"] = self.model_type
+        return d
+
+    @classmethod
+    def from_dict(cls, d: dict):
+        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
+                      if f.name in d})
+
+
+class LMAdapter:
+    """What a :class:`~paddle_tpu.decode.engine.DecodeEngine` asks of a
+    model: config + jit-ready functions over one parameter schema.
+
+    A model gives its cache (``make_cache``: its ``state()`` list is the
+    ``state`` the engine threads through every dispatch and hands back to
+    ``update()``), its programs ``prefill`` and ``decode_step`` as ``(const,
+    state, *feed) → ([token(s), logits, *extra], state')``, an ``observer``
+    for ``extra``, ``full_logits`` (the parity anchor: no cache, no kernel)
+    and ``supports``.  Params are a plain name → array dict; the engine
+    device-puts ``param_list(params)`` once and passes it as ``const``.
+
+    State is of two kinds, and the engine knows neither by name: blocks of a
+    paged pool, held by block table to the stream's end (every model), and
+    rows that belong to a SLOT — a window layer's ring, a state-space
+    layer's recurrent row, a convolution's tail (a model that sets
+    ``slot_state``).  Such a model is given the slot count in ``make_cache``
+    and, in ``prefill``'s feed after the length, the slot the prompt fills:
+    its prefill overwrites the slot's rows whole, which is the reset at a
+    join; a decode step's row ``i`` is slot ``i``; a slot without a stream
+    rides along and may scribble on its own rows only.
+
+    A subclass sets ``config_class``, ``observer_class``, ``param_shapes``
+    (``config → {name: (shape, init)}``, in ``const``'s order) and, where its
+    tensors have rules of their own, ``init_tensor``."""
+
+    # what of the engine's refcounted block lifecycle the model's entry
+    # points can serve (``prefix_cache``, ``overcommit``, ``beam``): an
+    # engine asked for another refuses at build
+    supports = frozenset()
+    # the engine adds the slot index to prefill's feed and the slot count to
+    # make_cache
+    slot_state = False
+    config_class = None
+    observer_class = None
+    param_shapes: Callable = None
+    init_tensor = staticmethod(init_tensor)
+
+    def __init__(self, config):
+        self.config = config
+
+    @classmethod
+    def from_dict(cls, raw: dict):
+        return cls(cls.config_class.from_dict(raw))
+
+    # -- parameters --------------------------------------------------------
+    def param_names(self) -> List[str]:
+        return list(self.param_shapes(self.config))
+
+    def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
+        """Seeded random weights, a tensor a key by ``init_tensor``."""
+        shapes = self.param_shapes(self.config)
+        keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
+        dt = jnp.dtype(self.config.dtype)
+        return {name: np.asarray(self.init_tensor(k, tuple(shape), init, dt))
+                for k, (name, (shape, init)) in zip(keys, shapes.items())}
+
+    def param_list(self, params: Dict) -> List:
+        """The ``const`` list in the fixed order the programs close over
+        (missing names fail loudly here, not inside a trace)."""
+        return [jnp.asarray(params[n]) for n in self.param_names()]
+
+    # -- state -------------------------------------------------------------
+    def make_cache(self, num_blocks: int, block_tokens: int,
+                   dtype: str = "float32", slots: Optional[int] = None):
+        """The state this model's streams need (:meth:`_make_cache`); a
+        ``slot_state`` model is refused without the engine's slot count."""
+        if self.slot_state and slots is None:
+            raise ValueError("this model's state lives in slot rows: "
+                             "make_cache needs the engine's slot count")
+        return self._make_cache(num_blocks, block_tokens, dtype, slots)
+
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots: Optional[int]):
+        raise NotImplementedError
+
+    def observer(self, name: str, cache, table_shape):
+        """What the engine ``name`` hands each launch's extra outputs and
+        context lengths to (an ``observer_class``): ``prefill(extra, prompt,
+        bucket)``, ``step(extra, contexts)`` (the live streams' context
+        lengths, this step's token included) and ``decodez()`` (what joins
+        the engine's ``/decodez``).  ``table_shape``: the engine's (slots,
+        blocks a slot)."""
+        return self.observer_class(name, cache, self.config, table_shape)
+
+    # -- programs ----------------------------------------------------------
+    def full_logits(self, plist, tokens, lengths=None):
+        """tokens [B, T] int32 → logits [B, T, V]; positions ≥ ``lengths``
+        [B] masked out where given."""
+        raise NotImplementedError
+
+    def prefill(self, plist, state, tokens, length, *feed):
+        """tokens [1, Tb] (bucket-padded), length [] int32, then ``slot`` []
+        int32 for a ``slot_state`` model, then block_table [MB] int32, seed
+        [] uint32, temperature [] f32, top_k [] int32 → ([next_token [],
+        logits [V], *extra], state')."""
+        raise NotImplementedError
+
+    def decode_step(self, plist, state, tokens, positions, block_tables,
+                    seeds, steps, temperature, top_k, attn_impl=None):
+        """tokens / positions [S], block_tables [S, MB], seeds [S] uint32,
+        steps [S] int32, temperature [S] f32, top_k [S] int32 →
+        ([next_tokens [S], logits [S, V], *extra], state')."""
+        raise NotImplementedError
+
+
+# ---------------------------------------------------------------------------
+# observers
+# ---------------------------------------------------------------------------
+
+class LaunchObserver:
+    """The ``decode.<engine>.*`` series every drawn model has.  A model's
+    observer derives from this (or from :class:`PoolObserver`), adds the
+    series that are its own, and writes ``prefill`` and ``step``: each a span
+    (``decode::prefill.observe`` / ``decode::step.observe``, inside the
+    ``.wait`` of its launch) whose arguments are what it added to the
+    counters of the same names — the launch's own work, for a reader of a
+    trace that times that launch."""
+
+    def __init__(self, name: str, cache, config, table_shape):
+        self.cache, self.config = cache, config
+        self._slots, self._slot_blocks = (int(n) for n in table_shape)
+        self.series = sc = _obs_stats.scope(f"decode.{name}")
+        self.prefill_real = sc.counter(
+            "prefill_real_tokens", "real prompt tokens prefilled")
+        self.prefill_pad = sc.counter(
+            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
+            "the prefill ladder")
+        self.prefill_sq = sc.counter(
+            "prefill_tokens_sq", "sum over prefills of the prompt length "
+            "squared (one layer's causal attention)")
+        self.context_tokens = sc.counter(
+            "step_context_tokens", "cached tokens a decode step's streams "
+            "hold, summed over steps (one layer that reads them)")
+
+    def count_prompt(self, prompt: int, bucket: int) -> None:
+        self.prefill_real.inc(prompt)
+        self.prefill_pad.inc(bucket - prompt)
+        self.prefill_sq.inc(prompt * prompt)
+
+    def decodez(self) -> dict:
+        """Nothing of its own on ``/decodez``."""
+        return {}
+
+
+class PoolObserver(LaunchObserver):
+    """… and the series of a model whose cache has a paged K/V pool
+    (:class:`~paddle_tpu.decode.cache.HybridStateCache`).  A step's figures
+    come from the live streams' context lengths, which the engine holds on
+    the host.  ``step_live_blocks`` over ``step_table_blocks``
+    (``decodez()``) is the share of the tables handed to the decode steps'
+    attention kernels that their walks fetched, every reading layer counted:
+    a live stream's ``ceil(context / block_tokens)`` blocks of the engine's
+    ``blocks a slot`` and one of an idle slot; which layers read is the
+    model's to say (:meth:`count_walks`)."""
+
+    def __init__(self, name: str, cache, config, table_shape):
+        super().__init__(name, cache, config, table_shape)
+        sc = self.series
+        self.streams = sc.counter(
+            "step_streams", "live streams, summed over decode steps")
+        self.live_blocks = sc.counter(
+            "step_live_blocks", "blocks the decode steps' attention walks "
+            "fetched, summed over the layers that read: a live stream's up "
+            "to its context (a ring's up to the window), one of an idle "
+            "slot")
+        self.table_blocks = sc.counter(
+            "step_table_blocks", "table entries those walks were handed: "
+            "slots x blocks a slot (a ring: blocks a ring) a reading layer, "
+            "a step")
+        self.live_tokens = sc.gauge("kv_live_tokens")
+        sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
+
+    def count_streams(self, contexts):
+        """A step's (cached tokens, live streams), counted."""
+        context, streams = int(np.sum(contexts)), len(contexts)
+        self.context_tokens.inc(context)
+        self.streams.inc(streams)
+        self.live_tokens.set(context)
+        self.cache.live_tokens = context
+        return context, streams
+
+    def pool_walk(self, contexts) -> int:
+        """Blocks one layer's walk over the pool fetches this step."""
+        return walked_blocks(contexts, self.cache.block_tokens, self._slots)
+
+    def count_walks(self, fetched: int, handed: int) -> None:
+        self.live_blocks.inc(fetched)
+        self.table_blocks.inc(handed)
+
+    def decodez(self) -> dict:
+        """The walks' share of their tables; the gauges ride ``cache``."""
+        return {"step_live_blocks": self.live_blocks.value,
+                "step_table_blocks": self.table_blocks.value}
+
+
+class RoutedLoadSeries:
+    """The routed-load series of a model with expert layers, fed by the
+    ``load`` its programs return: a row a *dispatch* (one layer's experts in
+    one program launch) of ``[assignments, experts touched, largest load,
+    …]``.  ``buckets``: the ``expert_load_max`` histogram's."""
+
+    def __init__(self, sc, buckets):
+        self.prefill_assignments = sc.counter(
+            "prefill_routed_assignments", "token-expert assignments "
+            "computed by prefills (real prompt tokens only), every layer")
+        self.step_assignments = sc.counter(
+            "step_routed_assignments", "token-expert assignments computed "
+            "by decode steps (live slots only), every layer")
+        self.step_dispatches = sc.counter(
+            "step_moe_dispatches", "expert layers run by decode steps")
+        self.step_touched = sc.counter(
+            "step_experts_touched", "experts with at least one row, summed "
+            "over the decode steps' dispatches")
+        self.step_load_max_sum = sc.counter(
+            "step_expert_load_max_sum", "largest load of one expert, summed "
+            "over the decode steps' dispatches")
+        self.load_max = sc.histogram(
+            "expert_load_max", buckets=buckets,
+            help_str="largest load of one expert a dispatch (rows)")
+
+    def count_prefill(self, load) -> int:
+        """A prefill's assignments, counted."""
+        assignments = int(load[:, 0].sum())
+        self.prefill_assignments.inc(assignments)
+        for m in load[:, 2]:
+            self.load_max.observe(float(m))
+        return assignments
+
+    def count_step(self, load):
+        """A step's (assignments, experts touched), counted."""
+        assignments, touched = int(load[:, 0].sum()), int(load[:, 1].sum())
+        self.step_assignments.inc(assignments)
+        self.step_dispatches.inc(int(load.shape[0]))
+        self.step_touched.inc(touched)
+        self.step_load_max_sum.inc(int(load[:, 2].sum()))
+        for m in load[:, 2]:
+            self.load_max.observe(float(m))
+        return assignments, touched
+
+
+__all__ = ["LMAdapter", "ConfigDict", "MODEL_TYPES", "TOPK_MAX", "mm",
+           "rms_norm", "rotary", "sub", "unscanned", "EXPERT_LEAVES",
+           "init_tensor", "sample", "sample_first", "prompt_addresses",
+           "step_addresses", "walked_blocks", "LaunchObserver",
+           "PoolObserver", "RoutedLoadSeries"]

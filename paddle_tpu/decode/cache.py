@@ -436,8 +436,9 @@ class PagedLatentCache(_PagedPool):
 
 class HybridStateCache(_PagedPool):
     """The per-stream state of a model that mixes state-space and attention
-    layers — up to three kinds under one manager, each with a layer count of
-    its own (a kind whose count is zero has no array):
+    layers — a paged K/V pool and, under the same manager, the kinds of slot
+    state the model says it HAS (``rings=``, ``recurrent=``, ``tails=``: a
+    kind that is not given has no array), each with a layer count of its own:
 
     - ``kv`` ``[kv layers, NB, bs, 2·kw]``: the paged K/V pool, a token's row
       ``[k | v]`` with the K/V heads merged into the minor axis (whole lane
@@ -446,21 +447,20 @@ class HybridStateCache(_PagedPool):
       full-attention layer's rows are what every attending layer reads
       (:mod:`~paddle_tpu.decode.sambay`: none copies it); every layer where
       every layer attends (:mod:`~paddle_tpu.decode.falcon_h1`).
-    - ``rings`` ``[window layers, slots · W/rb, rb, 2·kw]``: a window layer
-      keeps a slot's last ``W`` rows at ``position mod W``, as ``W/rb``
-      blocks of ``rb`` rows that belong to the slot for good — the bytes do
-      not grow with a stream's context, and the paged decode kernel reads a
-      ring as a table of the slot's own blocks.
-    - ``h`` ``[state-space layers, slots, *state_shape]`` float32 and
-      ``conv`` ``[state-space layers, slots, K-1, conv_width]``: the
-      recurrent state and the convolution's tail, one row a slot.
-      ``state_shape`` is ``(N, Di)`` (Mamba-1: a decay a channel) unless
-      given (Mamba-2: ``(heads, N, head channels)``); the convolution is as
-      wide as ``d_inner`` unless ``conv_width`` says what else it covers.
-      The tails are a kind of their own: ``conv_layers`` (as many as the
-      state-space layers unless given) counts the layers that keep one, so a
-      model of short convolutions and no recurrence holds ``conv`` without
-      ``h``.
+    - ``rings=(window layers, W)`` → ``rings`` ``[window layers, slots ·
+      W/rb, rb, 2·kw]``: a window layer keeps a slot's last ``W`` rows at
+      ``position mod W``, as ``W/rb`` blocks of ``rb`` rows that belong to
+      the slot for good — the bytes do not grow with a stream's context, and
+      the paged decode kernel reads a ring as a table of the slot's own
+      blocks.
+    - ``recurrent=(state-space layers, state shape)`` → ``h`` ``[layers,
+      slots, *state shape]`` float32: the recurrent state, one row a slot
+      (Mamba-1: ``(N, Di)``, a decay a channel; Mamba-2: ``(heads, N, head
+      channels)``).
+    - ``tails=(convolution layers, K, width)`` → ``conv`` ``[layers, slots,
+      K − 1, width]``: a causal convolution's last ``K − 1`` inputs, one row
+      a slot.  A kind of its own: a model of short convolutions and no
+      recurrence holds ``conv`` without ``h``.
 
     The last two are addressed by SLOT, not by block list: a prefill is told
     its slot and overwrites the slot's rows whole (that is the reset at a
@@ -471,37 +471,37 @@ class HybridStateCache(_PagedPool):
     RING_ROWS = 16
 
     def __init__(self, kv_width: int, num_blocks: int, block_tokens: int,
-                 slots: int, window: int, window_layers: int,
-                 ssm_layers: int, d_inner: int, d_state: int, d_conv: int,
-                 dtype="bfloat16", kv_layers: int = 1, state_shape=None,
-                 conv_width: Optional[int] = None,
-                 conv_layers: Optional[int] = None):
+                 slots: int, dtype="bfloat16", kv_layers: int = 1, *,
+                 rings: Optional[tuple] = None,
+                 recurrent: Optional[tuple] = None,
+                 tails: Optional[tuple] = None):
         if str(dtype) == "int8":
             raise ValueError("the hybrid state has no int8 form: its rows "
                              "carry no per-block scale")
         super().__init__(kv_layers, num_blocks, block_tokens, dtype)
-        self.slots, self.window = int(slots), int(window)
+        self.slots, self.window = int(slots), 0
         width = 2 * int(kv_width)
         self.kv = jnp.zeros((self.num_layers, self.num_blocks,
                              self.block_tokens, width), dtype)
-        self.rings = None
-        if window_layers:
-            self.ring_rows = min(self.window, self.RING_ROWS)
-            if self.window % self.ring_rows:
+        self.rings = self.h = self.conv = None
+        if rings is not None:
+            layers, window = (int(n) for n in rings)
+            self.window = window
+            self.ring_rows = min(window, self.RING_ROWS)
+            if window % self.ring_rows:
                 raise ValueError(f"a window of {window} is not whole blocks "
                                  f"of {self.ring_rows} rows")
-            self.ring_blocks = self.window // self.ring_rows
-            self.rings = jnp.zeros((int(window_layers),
-                                    self.slots * self.ring_blocks,
+            self.ring_blocks = window // self.ring_rows
+            self.rings = jnp.zeros((layers, self.slots * self.ring_blocks,
                                     self.ring_rows, width), dtype)
-        self.h = self.conv = None
-        if ssm_layers:
-            self.h = jnp.zeros((int(ssm_layers), self.slots) + tuple(
-                state_shape or (int(d_state), int(d_inner))), jnp.float32)
-        tails = int(ssm_layers if conv_layers is None else conv_layers)
-        if tails:
-            self.conv = jnp.zeros((tails, self.slots, int(d_conv) - 1,
-                                   int(conv_width or d_inner)), dtype)
+        if recurrent is not None:
+            layers, shape = recurrent
+            self.h = jnp.zeros((int(layers), self.slots)
+                               + tuple(int(n) for n in shape), jnp.float32)
+        if tails is not None:
+            layers, taps, conv_width = (int(n) for n in tails)
+            self.conv = jnp.zeros((layers, self.slots, taps - 1, conv_width),
+                                  dtype)
         self.live_tokens = 0        # the model's observer keeps it
 
     @staticmethod

@@ -23,26 +23,12 @@ the decode plane: after the ladder + step are warm, a mixed join/leave
 load of varying prompt and output lengths is ZERO compiles — the
 acceptance pin.
 
-The model protocol.  A model gives ``make_cache`` (its cache, whose
-``state()`` list the engine threads through every dispatch and hands back
-to ``update()``), ``prefill`` and ``decode_step`` as ``(const, state,
-*feed) → ([token(s), logits, *extra], state')``, an ``observer`` for
-``extra`` (made with the engine's name, the cache and the block tables'
-shape ``(slots, blocks a slot)``; what its ``decodez()`` returns joins
-the engine's), and ``supports``.  State is of three kinds, and the engine
-knows none of them by name: blocks of a paged pool, held by block table
-to the stream's end (every model); rows that belong to a SLOT — a window
-layer's ring, bounded by the window however long the context, and a
-state-space layer's recurrent state (a model that sets ``slot_state``:
-:mod:`~paddle_tpu.decode.sambay`, whose layers each keep one kind, and
-:mod:`~paddle_tpu.decode.falcon_h1`, whose every layer keeps both blocks
-and a row).  Such a model is given the slot count
-in ``make_cache`` and, in ``prefill``'s feed after the length, the slot
-the prompt fills: its prefill overwrites the slot's rows whole, which is
-the reset at a join; a decode step's row ``i`` is slot ``i``; a slot
-without a stream rides along and may scribble on its own rows only,
-which are dead until the next join.  Nothing else differs: same
-admission, same ladder, same step pipeline.
+The model protocol is a class:
+:class:`~paddle_tpu.decode.adapter.LMAdapter` (its cache, its two programs,
+its observer, ``supports``, and ``slot_state`` for a model that keeps rows
+by SLOT beside its paged blocks).  The engine knows no kind of state by
+name, and nothing but the feed of a ``slot_state`` model's prefill differs:
+same admission, same ladder, same step pipeline.
 
 Admission control (the batcher discipline): a bounded pending queue
 (``max_queue``) sheds with the serving plane's typed
@@ -547,13 +533,10 @@ class DecodeEngine:
         # shape)
         if cache_dtype is None:
             cache_dtype = DEFAULT_CACHE_DTYPE
-        # the model describes its cache and owns the state list its
-        # three entry points thread (K/V pools, their scale pools, a
-        # latent pool): the engine passes ``cache.state()`` through
-        # A model that sets ``slot_state`` keeps state in rows addressed by
-        # slot beside its paged pool (recurrent state, window rings): its
-        # cache is sized by the slot count, and a prefill's feed says which
-        # slot the prompt fills (its program overwrites the slot's rows)
+        # the model describes its cache and owns the state list its entry
+        # points thread: the engine passes ``cache.state()`` through.  A
+        # ``slot_state`` model's cache is sized by the slot count, and its
+        # prefill's feed says which slot the prompt fills (LMAdapter)
         self._slot_state = bool(getattr(model, "slot_state", False))
         self.cache = model.make_cache(
             num_blocks, bs, dtype=cache_dtype,

@@ -34,8 +34,7 @@ So a stream's state is of two kinds IN EVERY LAYER (:class:`~paddle_tpu.
 decode.cache.HybridStateCache` with ``kv_layers`` = the depth and no window
 rings): blocks of a paged pool of L layers, held by block table, and a
 recurrent row and a convolution tail a slot a layer, addressed by slot
-(``slot_state``: the engine says in ``prefill``'s feed which slot a prompt
-fills, and the prefill overwrites the slot's rows whole).
+(``slot_state``).
 
 Pad positions of a bucket have ``Δ = 0`` (the state passes through them); the
 convolution's tail is taken at the last real positions; every position runs
@@ -44,14 +43,11 @@ through every layer and only the last real one through the head.  Programs
 program holds one layer's code and the pool, the rows and the tails are the
 loop's carry, updated in place with the layer as an index.
 
-Entry points and protocol are :class:`~paddle_tpu.decode.model.
-TransformerLM`'s — ``full_logits``, ``prefill`` / ``decode_step`` as
-``(const, state, *feed) → (outs, state')``, ``make_cache``, ``observer``,
-``supports`` — so a :class:`~paddle_tpu.decode.engine.DecodeEngine` serves it
-as it is.  There is no snapshot of a slot's rows and no suffix prefill from a
-saved state, so ``supports`` is empty: a prefix cache, overcommit and beam
-sessions refuse this model at build.  There is one path: the kernels choose
-by shape alone (``attn_impl`` is accepted for the protocol's sake).
+The model is an :class:`~paddle_tpu.decode.adapter.LMAdapter`, so a
+:class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.  There
+is no snapshot of a slot's rows and no suffix prefill from a saved state, so
+``supports`` is empty: a prefix cache, overcommit and beam sessions refuse
+this model at build.
 
 Weights, residual stream, pool and tails are ``dtype`` (bf16 as deployed);
 matmuls accumulate in float32; softmax, norm statistics, ``Δ``, ``exp(ΔA)``
@@ -61,19 +57,20 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .adapter import (MODEL_TYPES, ConfigDict, LMAdapter, PoolObserver,
+                      init_tensor as _init_tensor, mm, prompt_addresses,
+                      rms_norm, rotary, sample, sample_first, step_addresses)
 from .cache import HybridStateCache
-from .model import MODEL_TYPES, _sample, walked_blocks
 from ..kernels import gqa as _gqa
 from ..kernels import ssd as _ssd
 from ..kernels import ssm as _ssm
-from ..observability import stats as _obs_stats
 from ..observability import trace as _trace
 
 MODEL_TYPE = "falcon_h1"
@@ -81,7 +78,7 @@ _DT_MIN, _DT_MAX = 1e-3, 1e-1
 
 
 @dataclasses.dataclass(frozen=True)
-class FalconH1Config:
+class FalconH1Config(ConfigDict):
     """The published keys this model reads, under their published names; the
     deployment's per-stream ``max_seq_len`` and the weights' ``dtype``."""
 
@@ -112,6 +109,7 @@ class FalconH1Config:
     mlp_multipliers: Tuple[float, ...] = (1.0, 1.0)
     max_seq_len: int = 128
     dtype: str = "bfloat16"
+    model_type = MODEL_TYPE
 
     def __post_init__(self):
         for name, n in (("ssm_multipliers", 5), ("mlp_multipliers", 2)):
@@ -155,14 +153,6 @@ class FalconH1Config:
     def state_shape(self) -> tuple:
         return (self.mamba_n_heads, self.mamba_d_state, self.mamba_d_head)
 
-    def to_dict(self) -> dict:
-        return dict(dataclasses.asdict(self), model_type=MODEL_TYPE)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "FalconH1Config":
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
-                      if f.name in d})
-
 
 def mup_vector(cfg: FalconH1Config) -> np.ndarray:
     """``µ``: ``ssm_multipliers[0..4]`` repeated over the segments ``[z | x |
@@ -202,7 +192,8 @@ def param_shapes(cfg: FalconH1Config) -> Dict[str, tuple]:
 
 def init_tensor(key, shape: tuple, init, dtype):
     """One tensor of :func:`param_shapes` from a PRNG key (jit-able with
-    ``shape``, ``init`` and ``dtype`` static)."""
+    ``shape``, ``init`` and ``dtype`` static): Mamba-2's own three here, the
+    rest by :func:`~paddle_tpu.decode.adapter.init_tensor`."""
     f32 = jnp.float32
     if init == "skip":
         w = jnp.ones(shape, f32)
@@ -214,17 +205,8 @@ def init_tensor(key, shape: tuple, init, dtype):
                      + math.log(_DT_MIN))
         w = dt + jnp.log(-jnp.expm1(-dt))
     else:
-        w = jax.random.normal(key, shape, f32)
-        if isinstance(init, str):
-            scale, shift = {"norm": (0.1, 1.0), "bias": (0.02, 0.0)}[init]
-            w = shift + scale * w
-        else:
-            w = w * init
+        return _init_tensor(key, shape, init, dtype)
     return w.astype(dtype)
-
-
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
 
 
 def _times(x, m: float):
@@ -234,79 +216,30 @@ def _times(x, m: float):
     return (x.astype(jnp.float32) * m).astype(x.dtype)
 
 
-def rotary(x, positions, theta: float):
-    """Rotate-half rotary positions over the whole head: x [N, heads, dh],
-    positions [N] → the same shape and dtype, computed in float32."""
-    half = x.shape[-1] // 2
-    inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
-                  * (-math.log(theta) / half))
-    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x32 = x.astype(jnp.float32)
-    a, b = x32[..., :half], x32[..., half:]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin],
-                           axis=-1).astype(x.dtype)
-
-
-class FalconH1Observer:
-    """``decode.<engine>.*`` series of this model.  Each call is a span
-    (``decode::prefill.observe`` / ``decode::step.observe``, inside the
-    ``.wait`` of its launch) whose arguments are what it added to the
-    counters of the same names: the launch's own work, for a reader of a
-    trace that times that launch.  A step's figures come from the live
-    streams' context lengths, which the engine holds on the host.
-
-    ``step_live_blocks`` over ``step_table_blocks`` (``decodez()``) is the
-    share of the block tables handed to the decode steps' attention kernel
-    that its walk fetched, every layer counted: a live stream's ``ceil(
-    context / block_tokens)`` blocks of the engine's ``blocks a slot``, and
-    one of an idle slot."""
+class FalconH1Observer(PoolObserver):
+    """``decode.<engine>.*`` series of this model: the common ones and the
+    pool's (every layer walks it), and its own of the state-space branch."""
 
     def __init__(self, name: str, cache, config: FalconH1Config, table_shape):
-        self.config, self.cache = config, cache
-        self._slots, self._slot_blocks = (int(n) for n in table_shape)
+        super().__init__(name, cache, config, table_shape)
         # a live stream's rows of every layer, read once and written once
         self.row_bytes = 2 * 4 * config.num_hidden_layers \
             * int(np.prod(config.state_shape))
-        sc = _obs_stats.scope(f"decode.{name}")
-        self.prefill_real = sc.counter(
-            "prefill_real_tokens", "real prompt tokens prefilled")
-        self.prefill_pad = sc.counter(
-            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
-            "the prefill ladder")
+        sc = self.series
         self.prefill_chunks = sc.counter(
             "prefill_scan_chunks", "chunks of mamba_chunk_size positions "
             "that hold a real position, summed over prefills (one layer)")
-        self.prefill_sq = sc.counter(
-            "prefill_tokens_sq", "sum over prefills of the prompt length "
-            "squared (one layer's causal attention)")
-        self.context_tokens = sc.counter(
-            "step_context_tokens", "cached tokens a decode step's streams "
-            "hold, summed over steps (one layer)")
-        self.streams = sc.counter(
-            "step_streams", "live streams, summed over decode steps")
         self.state_bytes = sc.counter(
             "step_state_bytes", "bytes of recurrent rows the live streams' "
             "one-token updates read and wrote, every layer, summed over "
             "decode steps")
-        self.live_blocks = sc.counter(
-            "step_live_blocks", "blocks the decode steps' attention walk "
-            "fetched, summed over the layers: a live stream's up to its "
-            "context, one of an idle slot")
-        self.table_blocks = sc.counter(
-            "step_table_blocks", "table entries that walk was handed: "
-            "slots x blocks a slot a layer, a step")
-        self.live_tokens = sc.gauge("kv_live_tokens")
-        sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
         sc.gauge("recurrent_state_bytes").set(cache.recurrent_state_bytes)
 
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         with _trace.span("decode::prefill.observe") as sp:
             chunks = -(-prompt // self.config.mamba_chunk_size)
-            self.prefill_real.inc(prompt)
-            self.prefill_pad.inc(bucket - prompt)
+            self.count_prompt(prompt, bucket)
             self.prefill_chunks.inc(chunks)
-            self.prefill_sq.inc(prompt * prompt)
             sp.annotate(prefill_real_tokens=prompt,
                         prefill_pad_tokens=bucket - prompt,
                         prefill_scan_chunks=chunks,
@@ -314,77 +247,39 @@ class FalconH1Observer:
 
     def step(self, extra, contexts) -> None:
         with _trace.span("decode::step.observe") as sp:
-            context, streams = int(np.sum(contexts)), len(contexts)
+            context, streams = self.count_streams(contexts)
             moved = streams * self.row_bytes
-            self.context_tokens.inc(context)
-            self.streams.inc(streams)
             self.state_bytes.inc(moved)
-            self.live_tokens.set(context)
-            self.cache.live_tokens = context
             sp.annotate(step_context_tokens=context, step_streams=streams,
                         step_state_bytes=moved)
         layers = self.config.num_hidden_layers
-        self.live_blocks.inc(layers * walked_blocks(
-            contexts, self.cache.block_tokens, self._slots))
-        self.table_blocks.inc(layers * self._slots * self._slot_blocks)
-
-    def decodez(self) -> dict:
-        """The walk's share of its tables (the class's doc); the gauges
-        ride ``cache``."""
-        return {"step_live_blocks": self.live_blocks.value,
-                "step_table_blocks": self.table_blocks.value}
+        self.count_walks(layers * self.pool_walk(contexts),
+                         layers * self._slots * self._slot_blocks)
 
 
-class FalconH1LM:
+class FalconH1LM(LMAdapter):
     """One parallel-hybrid LM: config + the jit-ready functions."""
 
-    supports = frozenset()
-    # the engine adds the slot index to prefill's feed and the slot count to
-    # make_cache: a layer's recurrent row and convolution tail live in slot
-    # rows
+    # a layer's recurrent row and convolution tail live in slot rows
     slot_state = True
+    config_class = FalconH1Config
+    observer_class = FalconH1Observer
+    param_shapes = staticmethod(param_shapes)
+    init_tensor = staticmethod(init_tensor)
 
     def __init__(self, config: FalconH1Config):
-        self.config = config
+        super().__init__(config)
         self._mu = mup_vector(config)
 
     # -- what an engine asks of a model ------------------------------------
-    @classmethod
-    def from_dict(cls, raw: dict) -> "FalconH1LM":
-        return cls(FalconH1Config.from_dict(raw))
-
-    def param_names(self) -> List[str]:
-        return list(param_shapes(self.config))
-
-    def make_cache(self, num_blocks: int, block_tokens: int,
-                   dtype: str = "float32", slots: Optional[int] = None
-                   ) -> HybridStateCache:
-        if slots is None:
-            raise ValueError("this model's state lives in slot rows: "
-                             "make_cache needs the engine's slot count")
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots: int) -> HybridStateCache:
         cfg = self.config
         L = cfg.num_hidden_layers
         return HybridStateCache(
-            cfg.kv_width, num_blocks, block_tokens, slots, window=0,
-            window_layers=0, ssm_layers=L, d_inner=cfg.mamba_d_ssm,
-            d_state=cfg.mamba_d_state, d_conv=cfg.mamba_d_conv, dtype=dtype,
-            kv_layers=L, state_shape=cfg.state_shape,
-            conv_width=cfg.conv_width)
-
-    def observer(self, name: str, cache, table_shape) -> FalconH1Observer:
-        return FalconH1Observer(name, cache, self.config, table_shape)
-
-    # -- parameters --------------------------------------------------------
-    def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
-        """Seeded random weights by :func:`init_tensor`."""
-        shapes = param_shapes(self.config)
-        keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
-        dt = jnp.dtype(self.config.dtype)
-        return {name: np.asarray(init_tensor(k, tuple(shape), init, dt))
-                for k, (name, (shape, init)) in zip(keys, shapes.items())}
-
-    def param_list(self, params: Dict) -> List:
-        return [jnp.asarray(params[n]) for n in self.param_names()]
+            cfg.kv_width, num_blocks, block_tokens, slots, dtype=dtype,
+            kv_layers=L, recurrent=(L, cfg.state_shape),
+            tails=(L, cfg.mamba_d_conv, cfg.conv_width))
 
     def _unpack(self, plist):
         """(the model's own tensors, the layers' stacked ones)."""
@@ -394,10 +289,7 @@ class FalconH1LM:
 
     # -- shared layer math -------------------------------------------------
     def _rms(self, x, g):
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(var + self.config.rms_norm_eps)
-                * g.astype(jnp.float32)).astype(x.dtype)
+        return rms_norm(x, g, self.config.rms_norm_eps)
 
     def _mlp(self, w, x):
         cfg = self.config
@@ -407,7 +299,7 @@ class FalconH1LM:
             up = jnp.dot(v, w["mlp_up"], preferred_element_type=jnp.float32)
             act = (jax.nn.silu(g * cfg.mlp_multipliers[0]) * up
                    ).astype(x.dtype)
-            return x + _times(_mm(act, w["mlp_down"]),
+            return x + _times(mm(act, w["mlp_down"]),
                               cfg.mlp_multipliers[1])
 
     def _ssm_in(self, w, u):
@@ -446,7 +338,7 @@ class FalconH1LM:
             y = y * lax.rsqrt(jnp.mean(jnp.square(y), -1, keepdims=True)
                               + cfg.rms_norm_eps)
             y = (y.reshape(N, -1) * w["ssm_norm"].astype(f32)).astype(z.dtype)
-            return _times(_mm(y, w["out_proj"]), cfg.ssm_out_multiplier)
+            return _times(mm(y, w["out_proj"]), cfg.ssm_out_multiplier)
 
     def _ssm_prompt(self, w, u, valid, length, dense: bool):
         """A prompt's rows u [T, D] → (the branch's output [T, D], S at the
@@ -477,7 +369,7 @@ class FalconH1LM:
         cfg = self.config
         N, dh = u.shape[0], cfg.head_dim
         with jax.named_scope("attn_qkv"):
-            qkv = _mm(_times(u, cfg.attention_in_multiplier), w["wqkv"])
+            qkv = mm(_times(u, cfg.attention_in_multiplier), w["wqkv"])
             q = qkv[:, :cfg.q_width].reshape(N, cfg.num_attention_heads, dh)
             k = _times(qkv[:, cfg.q_width:cfg.q_width + cfg.kv_width],
                        cfg.key_multiplier
@@ -492,7 +384,7 @@ class FalconH1LM:
     def _attn_out(self, w, o, dtype):
         with jax.named_scope("attn_out"):
             o = o.reshape(o.shape[0], -1).astype(dtype)
-            return _times(_mm(o, w["wo"]),
+            return _times(mm(o, w["wo"]),
                           self.config.attention_out_multiplier)
 
     def _embed(self, p, tokens):
@@ -555,13 +447,9 @@ class FalconH1LM:
         cfg = self.config
         p, lay = self._unpack(plist)
         kv, hs, conv = state
-        Tb = tokens.shape[1]
-        bs, MB = kv.shape[2], block_table.shape[0]
-        pos = jnp.arange(Tb, dtype=jnp.int32)
-        valid = pos < length
-        blocks = jnp.where(valid, block_table[jnp.minimum(pos // bs, MB - 1)],
-                           0)
-        last = jnp.maximum(length - 1, 0)
+        bs = kv.shape[2]
+        pos, valid, blocks, last = prompt_addresses(
+            length, tokens.shape[1], block_table, bs)
 
         def layer(carry, xs):
             x, kv = carry
@@ -584,29 +472,20 @@ class FalconH1LM:
                 (zero, slot, zero, zero))
         logits = self._head(p, x[last][None])[0]
         with jax.named_scope("sampling"):
-            tok = _sample(logits[None], seed[None],
-                          jnp.zeros((1,), jnp.int32), temperature[None],
-                          top_k[None])[0]
+            tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits], [kv, hs, conv]
 
     # -- decode step -------------------------------------------------------
     def decode_step(self, plist, state, tokens, positions, block_tables,
                     seeds, steps, temperature, top_k, attn_impl=None):
         """state ``[kv pool, S, conv]``, tokens / positions [S],
-        block_tables [S, MB] → ([next_tokens [S], logits [S, V]], state').
-        Row ``i`` is slot ``i``.  A slot without a stream feeds an all-zero
-        block table (block 0 is never a stream's): it writes the trash block
-        and scribbles on its own recurrent rows and tails, which the next
-        join's prefill overwrites."""
+        block_tables [S, MB] → ([next_tokens [S], logits [S, V]], state')."""
         del attn_impl           # one path: the kernels choose by shape alone
         cfg = self.config
         p, lay = self._unpack(plist)
         kv, hs, conv = state
-        S = tokens.shape[0]
         bs = kv.shape[2]
-        cl = positions + 1
-        slots = jnp.arange(S, dtype=jnp.int32)
-        blocks = block_tables[slots, positions // bs]
+        cl, _, _, blocks = step_addresses(positions, block_tables, bs)
 
         def layer(carry, xs):
             x, kv, hs, conv = carry
@@ -638,11 +517,11 @@ class FalconH1LM:
             (lay, jnp.arange(cfg.num_hidden_layers, dtype=jnp.int32)))
         logits = self._head(p, x)
         with jax.named_scope("sampling"):
-            toks = _sample(logits, seeds, steps, temperature, top_k)
+            toks = sample(logits, seeds, steps, temperature, top_k)
         return [toks, logits], [kv, hs, conv]
 
 
 MODEL_TYPES[MODEL_TYPE] = FalconH1LM.from_dict
 
 __all__ = ["FalconH1Config", "FalconH1LM", "FalconH1Observer",
-           "param_shapes", "init_tensor", "mup_vector", "rotary"]
+           "param_shapes", "init_tensor", "mup_vector"]

@@ -33,9 +33,8 @@ first ``num_hidden_layers`` entries).  So a stream's state is of two kinds
 (:class:`~paddle_tpu.decode.cache.HybridStateCache` with convolution tails and
 no recurrent rows): blocks of a paged pool of the attention layers, held by
 block table, and a tail a slot a convolution layer, addressed by slot
-(``slot_state``: the engine says in ``prefill``'s feed which slot a prompt
-fills, and the prefill overwrites the slot's tails with ``z`` at the prompt's
-last REAL positions, zeros where the prompt is shorter than the tail).
+(``slot_state``): a prefill leaves there ``z`` at the prompt's last REAL
+positions, zeros where the prompt is shorter than the tail.
 
 Programs ``lax.scan`` over the dense layers' stacked weights (``d.*`` ``[nd,
 …]``) and then over the periods' (``pa.*`` ``[P, …]`` the attention layers,
@@ -45,19 +44,15 @@ pool and tails are the loops' carry, updated in place with the layer as an
 index.  The experts' matrices are NOT scanned over: the grouped kernel is
 handed the whole stack and the layer's index.
 
-Entry points and protocol are :class:`~paddle_tpu.decode.model.
-TransformerLM`'s — ``full_logits``, ``prefill`` / ``decode_step`` as ``(const,
-state, *feed) → (outs, state')``, ``make_cache``, ``observer``, ``supports`` —
-so a :class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.
+The model is an :class:`~paddle_tpu.decode.adapter.LMAdapter`, so a
+:class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.
 Beside token and logits the programs return every expert layer's load figures
 ``[Le, 4]`` (assignments, experts touched, the largest load, the plan's
 padded rows), the chosen experts ``[Le, tokens, K]`` and, at the rows that
 reach the head, the routing weights, the router's input ``u`` and its logits
 (what a reference check holds the routing to).  There is no snapshot of a
 slot's tails and no suffix prefill from one, so ``supports`` is empty: a
-prefix cache, overcommit and beam sessions refuse this model at build.  There
-is one path: the kernels choose by shape alone (``attn_impl`` is accepted for
-the protocol's sake).
+prefix cache, overcommit and beam sessions refuse this model at build.
 
 Weights, residual stream, pool and tails are ``dtype`` (bf16 as deployed);
 matmuls accumulate in float32; the router's logits, scores and weights, the
@@ -69,29 +64,29 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .adapter import (EXPERT_LEAVES, MODEL_TYPES, ConfigDict, LMAdapter,
+                      PoolObserver, RoutedLoadSeries, init_tensor, mm,
+                      prompt_addresses, rms_norm, rotary, sample,
+                      sample_first, step_addresses, sub, unscanned)
 from .cache import HybridStateCache
-from .falcon_h1 import rotary
-from .model import MODEL_TYPES, _sample, walked_blocks
 from ..kernels import gqa as _gqa
 from ..kernels import moe as _moe
 from ..kernels import ssm as _ssm
-from ..observability import stats as _obs_stats
 from ..observability import trace as _trace
 
 MODEL_TYPE = "lfm2_moe"
-EXPERT_LEAVES = ("e_gate", "e_up", "e_down")
 ROUTE_EPS = 1e-6        # the renormalisation's: sum of the chosen + this
 
 
 @dataclasses.dataclass(frozen=True)
-class LFM2Config:
+class LFM2Config(ConfigDict):
     """The published keys this model reads, under their published names
     (``rope_theta`` is ``rope_parameters.rope_theta``; ``head_dim`` is
     ``hidden_size / num_attention_heads`` unless given); the deployment's
@@ -121,6 +116,7 @@ class LFM2Config:
     rope_theta: float = 1e6
     max_seq_len: int = 128
     dtype: str = "bfloat16"
+    model_type = MODEL_TYPE
 
     def __post_init__(self):
         L, nd = self.num_hidden_layers, self.num_dense_layers
@@ -175,16 +171,12 @@ class LFM2Config:
     def kv_width(self) -> int:
         return self.num_key_value_heads * self.head_dim
 
-    def to_dict(self) -> dict:
-        return dict(dataclasses.asdict(self), model_type=MODEL_TYPE)
-
     @classmethod
     def from_dict(cls, d: dict) -> "LFM2Config":
         d = dict(d)
         if "rope_theta" not in d and "rope_parameters" in d:
             d["rope_theta"] = d["rope_parameters"]["rope_theta"]
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
-                      if f.name in d})
+        return super().from_dict(d)
 
 
 def param_shapes(cfg: LFM2Config) -> Dict[str, tuple]:
@@ -218,46 +210,18 @@ def param_shapes(cfg: LFM2Config) -> Dict[str, tuple]:
     return out
 
 
-def init_tensor(key, shape: tuple, init, dtype):
-    """One tensor of :func:`param_shapes` from a PRNG key (jit-able with
-    ``shape``, ``init`` and ``dtype`` static)."""
-    w = jax.random.normal(key, shape, jnp.float32)
-    w = 1.0 + 0.1 * w if init == "norm" else w * init
-    return w.astype(dtype)
-
-
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def _sub(w: dict, prefix: str) -> dict:
-    n = len(prefix)
-    return {k[n:]: v for k, v in w.items() if k.startswith(prefix)}
-
-
-def _small(w: dict) -> dict:
-    """A layer stack's tensors less the experts' (those are not scanned)."""
-    return {k: v for k, v in w.items() if k not in EXPERT_LEAVES}
-
-
-class LFM2Observer:
-    """``decode.<engine>.*`` series of this model, fed by what its programs
-    return beside token and logits (``extra[0]``: each expert layer's
-    ``[assignments, experts touched, largest load, the plan's padded rows]``)
-    and by the live streams' context lengths, which the engine holds on the
-    host.  A *dispatch* is one layer's experts in one program launch.  Each
-    call is a span (``decode::prefill.observe`` / ``decode::step.observe``,
-    inside the ``.wait`` of its launch) whose arguments are what it added to
-    the counters of the same names: the launch's own work, for a reader of a
-    trace that times that launch."""
+class LFM2Observer(PoolObserver):
+    """``decode.<engine>.*`` series of this model: the common ones, the
+    pool's (an attention layer walks it) and the routed load (``extra[0]``:
+    each expert layer's ``[assignments, experts touched, largest load, the
+    plan's padded rows]``), and its own of the prefills' grouped plans."""
 
     def __init__(self, name: str, cache, config: LFM2Config, table_shape):
-        self.config, self.cache = config, cache
-        self._slots, self._slot_blocks = (int(n) for n in table_shape)
-        sc = _obs_stats.scope(f"decode.{name}")
-        self.prefill_assignments = sc.counter(
-            "prefill_routed_assignments", "token-expert assignments "
-            "computed by prefills (real prompt tokens only), every layer")
+        super().__init__(name, cache, config, table_shape)
+        sc = self.series
+        self.routed = RoutedLoadSeries(
+            sc, buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048,
+                         4096, 8192, 16384))
         self.prefill_dispatches = sc.counter(
             "prefill_moe_dispatches", "expert layers run by prefills")
         self.prefill_load_max_sum = sc.counter(
@@ -268,165 +232,75 @@ class LFM2Observer:
             "expert's assignments padded to whole row tiles")
         self.prefill_plan_pad = sc.counter(
             "prefill_plan_pad_rows", "of them, rows that hold no assignment")
-        self.step_assignments = sc.counter(
-            "step_routed_assignments", "token-expert assignments computed "
-            "by decode steps (live slots only), every layer")
-        self.step_dispatches = sc.counter(
-            "step_moe_dispatches", "expert layers run by decode steps")
-        self.step_touched = sc.counter(
-            "step_experts_touched", "experts with at least one row, summed "
-            "over the decode steps' dispatches")
-        self.step_load_max_sum = sc.counter(
-            "step_expert_load_max_sum", "largest load of one expert, summed "
-            "over the decode steps' dispatches")
-        self.load_max = sc.histogram(
-            "expert_load_max", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256,
-                                        512, 1024, 2048, 4096, 8192, 16384),
-            help_str="largest load of one expert a dispatch (rows)")
-        self.prefill_real = sc.counter(
-            "prefill_real_tokens", "real prompt tokens prefilled")
-        self.prefill_pad = sc.counter(
-            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
-            "the prefill ladder")
-        self.prefill_sq = sc.counter(
-            "prefill_tokens_sq", "sum over prefills of the prompt length "
-            "squared (one attention layer's causal attention)")
-        self.context_tokens = sc.counter(
-            "step_context_tokens", "cached tokens of the pool a decode "
-            "step's streams hold, summed over steps (one attention layer)")
-        self.streams = sc.counter(
-            "step_streams", "live streams, summed over decode steps")
-        self.live_blocks = sc.counter(
-            "step_live_blocks", "blocks the decode steps' attention walks "
-            "fetched, summed over the attention layers: a live stream's up "
-            "to its context, one of an idle slot")
-        self.table_blocks = sc.counter(
-            "step_table_blocks", "table entries those walks were handed: "
-            "slots x blocks a slot an attention layer, a step")
-        self.live_tokens = sc.gauge("kv_live_tokens")
-        sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
         sc.gauge("conv_state_bytes").set(cache.recurrent_state_bytes)
 
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         with _trace.span("decode::prefill.observe") as sp:
             load = np.asarray(extra[0])
-            assignments, rows = int(load[:, 0].sum()), int(load[:, 3].sum())
-            self.prefill_assignments.inc(assignments)
+            assignments = self.routed.count_prefill(load)
+            rows = int(load[:, 3].sum())
             self.prefill_dispatches.inc(int(load.shape[0]))
             self.prefill_load_max_sum.inc(int(load[:, 2].sum()))
             self.prefill_plan_rows.inc(rows)
             self.prefill_plan_pad.inc(rows - assignments)
-            for m in load[:, 2]:
-                self.load_max.observe(float(m))
-            self.prefill_real.inc(prompt)
-            self.prefill_pad.inc(bucket - prompt)
-            self.prefill_sq.inc(prompt * prompt)
+            self.count_prompt(prompt, bucket)
             sp.annotate(prefill_routed_assignments=assignments,
                         prefill_plan_rows=rows, prefill_real_tokens=prompt,
                         prefill_tokens_sq=prompt * prompt)
 
     def step(self, extra, contexts) -> None:
-        cfg, cache = self.config, self.cache
         with _trace.span("decode::step.observe") as sp:
-            load = np.asarray(extra[0])
-            assignments, touched = (int(load[:, 0].sum()),
-                                    int(load[:, 1].sum()))
-            context, streams = int(np.sum(contexts)), len(contexts)
-            self.step_assignments.inc(assignments)
-            self.step_dispatches.inc(int(load.shape[0]))
-            self.step_touched.inc(touched)
-            self.step_load_max_sum.inc(int(load[:, 2].sum()))
-            for m in load[:, 2]:
-                self.load_max.observe(float(m))
-            self.context_tokens.inc(context)
-            self.streams.inc(streams)
-            self.live_tokens.set(context)
-            cache.live_tokens = context
+            assignments, touched = self.routed.count_step(
+                np.asarray(extra[0]))
+            context, streams = self.count_streams(contexts)
             sp.annotate(step_routed_assignments=assignments,
                         step_experts_touched=touched,
                         step_context_tokens=context, step_streams=streams)
-        self.live_blocks.inc(cfg.periods * walked_blocks(
-            contexts, cache.block_tokens, self._slots))
-        self.table_blocks.inc(cfg.periods * self._slots * self._slot_blocks)
-
-    def decodez(self) -> dict:
-        """The walks' share of their tables; the gauges ride ``cache``."""
-        return {"step_live_blocks": self.live_blocks.value,
-                "step_table_blocks": self.table_blocks.value}
+        layers = self.config.periods
+        self.count_walks(layers * self.pool_walk(contexts),
+                         layers * self._slots * self._slot_blocks)
 
 
-class LFM2LM:
+class LFM2LM(LMAdapter):
     """One short-convolution and attention expert LM: config + the jit-ready
     functions."""
 
-    supports = frozenset()
-    # the engine adds the slot index to prefill's feed and the slot count to
-    # make_cache: a convolution layer's tail lives in slot rows
+    # a convolution layer's tail lives in slot rows
     slot_state = True
-
-    def __init__(self, config: LFM2Config):
-        self.config = config
+    config_class = LFM2Config
+    observer_class = LFM2Observer
+    param_shapes = staticmethod(param_shapes)
 
     # -- what an engine asks of a model ------------------------------------
-    @classmethod
-    def from_dict(cls, raw: dict) -> "LFM2LM":
-        return cls(LFM2Config.from_dict(raw))
-
-    def param_names(self) -> List[str]:
-        return list(param_shapes(self.config))
-
-    def make_cache(self, num_blocks: int, block_tokens: int,
-                   dtype: str = "float32", slots: Optional[int] = None
-                   ) -> HybridStateCache:
-        if slots is None:
-            raise ValueError("this model's state lives in slot rows: "
-                             "make_cache needs the engine's slot count")
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots: int) -> HybridStateCache:
         cfg = self.config
         return HybridStateCache(
-            cfg.kv_width, num_blocks, block_tokens, slots, window=0,
-            window_layers=0, ssm_layers=0, d_inner=0, d_state=0,
-            d_conv=cfg.conv_L_cache, dtype=dtype, kv_layers=cfg.periods,
-            conv_width=cfg.hidden_size, conv_layers=cfg.conv_layers)
-
-    def observer(self, name: str, cache, table_shape) -> LFM2Observer:
-        return LFM2Observer(name, cache, self.config, table_shape)
-
-    # -- parameters --------------------------------------------------------
-    def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
-        """Seeded random weights by :func:`init_tensor`."""
-        shapes = param_shapes(self.config)
-        keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
-        dt = jnp.dtype(self.config.dtype)
-        return {name: np.asarray(init_tensor(k, tuple(shape), init, dt))
-                for k, (name, (shape, init)) in zip(keys, shapes.items())}
-
-    def param_list(self, params: Dict) -> List:
-        return [jnp.asarray(params[n]) for n in self.param_names()]
+            cfg.kv_width, num_blocks, block_tokens, slots, dtype=dtype,
+            kv_layers=cfg.periods,
+            tails=(cfg.conv_layers, cfg.conv_L_cache, cfg.hidden_size))
 
     def _unpack(self, plist):
         """(the model's own tensors, the dense layers' stacks, the attention
         layers', the periods' convolution layers')."""
         p = dict(zip(self.param_names(), plist))
         return ({k: v for k, v in p.items() if "." not in k},
-                _sub(p, "d."), _sub(p, "pa."), _sub(p, "pc."))
+                sub(p, "d."), sub(p, "pa."), sub(p, "pc."))
 
     # -- shared layer math -------------------------------------------------
     def _rms(self, x, g):
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(var + self.config.norm_eps)
-                * g.astype(jnp.float32)).astype(x.dtype)
+        return rms_norm(x, g, self.config.norm_eps)
 
     def _conv_in(self, w, u):
         """u [N, D] → (z = B ⊙ x [N, D], the output gate C [N, D])."""
         D = self.config.hidden_size
-        bcx = _mm(u, w["conv_in"])
+        bcx = mm(u, w["conv_in"])
         z = bcx[:, :D].astype(jnp.float32) * bcx[:, 2 * D:].astype(jnp.float32)
         return z.astype(u.dtype), bcx[:, D:2 * D]
 
     def _conv_out(self, w, x, gate, c):
         y = (gate.astype(jnp.float32) * c).astype(x.dtype)
-        return x + _mm(y, w["conv_out"])
+        return x + mm(y, w["conv_out"])
 
     def _qkv(self, w, u, positions, dtype):
         """u [N, D] → q [N, nh, dh], the cache rows [k | v] [N, 2·kw]: q and
@@ -434,7 +308,7 @@ class LFM2LM:
         ``positions``."""
         cfg = self.config
         N, dh = u.shape[0], cfg.head_dim
-        qkv = _mm(u, w["wqkv"])
+        qkv = mm(u, w["wqkv"])
         q = qkv[:, :cfg.q_width].reshape(N, cfg.num_attention_heads, dh)
         k = qkv[:, cfg.q_width:cfg.q_width + cfg.kv_width].reshape(
             N, cfg.num_key_value_heads, dh)
@@ -445,15 +319,15 @@ class LFM2LM:
                                   axis=-1).astype(dtype)
 
     def _attn_out(self, w, x, o):
-        return x + _mm(o.reshape(o.shape[0], -1).astype(x.dtype), w["wo"])
+        return x + mm(o.reshape(o.shape[0], -1).astype(x.dtype), w["wo"])
 
     def _dense_ffn(self, w, x):
         h = self._rms(x, w["ln2"])
         with jax.named_scope("dense_ffn"):
-            g = _mm(h, w["w1"]).astype(jnp.float32)
-            a = (jax.nn.silu(g) * _mm(h, w["w3"]).astype(jnp.float32)
+            g = mm(h, w["w1"]).astype(jnp.float32)
+            a = (jax.nn.silu(g) * mm(h, w["w3"]).astype(jnp.float32)
                  ).astype(x.dtype)
-            return x + _mm(a, w["w2"])
+            return x + mm(a, w["w2"])
 
     def _expert_ffn(self, w, stacks, at, x, valid, tile: int, dense: bool):
         """x [N, D] → (x + the routed experts on ``RMS_ffn(x)``, (load [4],
@@ -526,7 +400,8 @@ class LFM2LM:
 
         (x, carry), got = lax.scan(
             period, (x, carry),
-            (_small(pa), _small(pc), jnp.arange(cfg.periods, dtype=jnp.int32)))
+            (unscanned(pa), unscanned(pc),
+             jnp.arange(cfg.periods, dtype=jnp.int32)))
         return x, carry, tuple(g.reshape((-1,) + g.shape[2:]) for g in got)
 
     # -- a prompt's layers -------------------------------------------------
@@ -608,13 +483,9 @@ class LFM2LM:
         overwritten whole."""
         p, pd, pa, pc = self._unpack(plist)
         kv, conv = state
-        Tb = tokens.shape[1]
-        bs, MB = kv.shape[2], block_table.shape[0]
-        pos = jnp.arange(Tb, dtype=jnp.int32)
-        valid = pos < length
-        blocks = jnp.where(valid, block_table[jnp.minimum(pos // bs, MB - 1)],
-                           0)
-        last = jnp.maximum(length - 1, 0)
+        bs = kv.shape[2]
+        pos, _, blocks, last = prompt_addresses(
+            length, tokens.shape[1], block_table, bs)
         zero = jnp.zeros((), slot.dtype)
 
         def rows_out(at, rows, carry):
@@ -631,9 +502,7 @@ class LFM2LM:
             tail_out, (kv, conv))
         logits = self._head(p, x[last][None])[0]
         with jax.named_scope("sampling"):
-            tok = _sample(logits[None], seed[None],
-                          jnp.zeros((1,), jnp.int32), temperature[None],
-                          top_k[None])[0]
+            tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits, load, ids, rw[:, None], u[:, None],
                 rl[:, None]], [kv, conv]
 
@@ -643,21 +512,14 @@ class LFM2LM:
         """state ``[kv pool, tails]``, tokens / positions [S], block_tables
         [S, MB] → ([next_tokens [S], logits [S, V], load [Le, 4], ids [Le, S,
         K], routing weights [Le, S, K], u [Le, S, D], router logits [Le, S,
-        E]], state').  Row ``i`` is
-        slot ``i``.  A slot without a stream feeds an all-zero block table
-        (block 0 is never a stream's): it writes the trash block, scribbles on
-        its own tails, which the next join's prefill overwrites, and is routed
-        to no expert."""
+        E]], state').  A slot without a stream is routed to no expert."""
         del attn_impl           # one path: the kernels choose by shape alone
         cfg = self.config
         p, pd, pa, pc = self._unpack(plist)
         kv, conv = state
         S = tokens.shape[0]
         bs = kv.shape[2]
-        cl = positions + 1
-        live = block_tables[:, 0] != 0
-        slots = jnp.arange(S, dtype=jnp.int32)
-        blocks = block_tables[slots, positions // bs]
+        cl, live, _, blocks = step_addresses(positions, block_tables, bs)
         tile = _moe.row_tile(S, jnp.dtype(cfg.dtype))
 
         def mixer(w, x, carry, kind, at):
@@ -687,7 +549,7 @@ class LFM2LM:
             lambda got: got)
         logits = self._head(p, x)
         with jax.named_scope("sampling"):
-            toks = _sample(logits, seeds, steps, temperature, top_k)
+            toks = sample(logits, seeds, steps, temperature, top_k)
         return [toks, logits, load, ids, rw, u, rl], [kv, conv]
 
 

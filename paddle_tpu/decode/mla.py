@@ -22,9 +22,7 @@ untied embedding and head, no bias anywhere.
   beside ``n_shared_experts`` shared ones as one wide SwiGLU
   (``kernels/moe.py``).  Every assignment is computed; none is dropped.
 
-The model has :class:`~paddle_tpu.decode.model.TransformerLM`'s entry points
-— ``full_logits`` and ``prefill`` / ``decode_step`` as ``(const, state,
-*feed) → (outs, state')`` with ``state`` its cache's list — so a
+The model is an :class:`~paddle_tpu.decode.adapter.LMAdapter`, so a
 :class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.  Its
 programs return, beside token and logits, each MoE layer's load figures and
 chosen experts and the first MoE layer's routed experts' input and output at
@@ -42,17 +40,18 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .adapter import (MODEL_TYPES, ConfigDict, LaunchObserver, LMAdapter,
+                      RoutedLoadSeries, mm, prompt_addresses, rms_norm,
+                      sample, sample_first, step_addresses)
 from .cache import PagedLatentCache
-from .model import MODEL_TYPES, _sample
 from ..kernels import mla as _mla
 from ..kernels import moe as _moe
-from ..observability import stats as _obs_stats
 from ..observability import trace as _trace
 
 MODEL_TYPE = "deepseek_v2"
@@ -62,7 +61,7 @@ _ROPE_DEFAULT = {"factor": 1.0, "original_max_position_embeddings": 4096,
 
 
 @dataclasses.dataclass(frozen=True)
-class MLAConfig:
+class MLAConfig(ConfigDict):
     """The published keys this model reads, under their published names,
     plus the deployment's per-stream ``max_seq_len`` and the weights'
     ``dtype``."""
@@ -89,14 +88,7 @@ class MLAConfig:
         default_factory=lambda: dict(_ROPE_DEFAULT))
     max_seq_len: int = 128
     dtype: str = "bfloat16"
-
-    def to_dict(self) -> dict:
-        return dict(dataclasses.asdict(self), model_type=MODEL_TYPE)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "MLAConfig":
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
-                      if f.name in d})
+    model_type = MODEL_TYPE
 
 
 def _yarn_mscale(scale: float, mscale: float) -> float:
@@ -176,94 +168,43 @@ def param_shapes(cfg: MLAConfig) -> Dict[str, tuple]:
     return out
 
 
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
 def _swiglu(x, wg, wu, wd):
     g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
     u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
-    return _mm((jax.nn.silu(g) * u).astype(x.dtype), wd)
+    return mm((jax.nn.silu(g) * u).astype(x.dtype), wd)
 
 
-class MLAObserver:
-    """``decode.<engine>.*`` series of a routed latent-attention model, fed
-    by what its programs return beside token and logits (``extra[0]``: each
-    MoE layer's ``[assignments, experts touched, largest load]``).  A
-    *dispatch* is one MoE layer of one program launch.  Each call is a span
-    (``decode::prefill.observe`` / ``decode::step.observe``, inside the
-    ``.wait`` of its launch) whose arguments are what it added to the
-    counters of the same names: the launch's own work, for a reader of a
-    trace that times that launch."""
+class MLAObserver(LaunchObserver):
+    """``decode.<engine>.*`` series of a routed latent-attention model: the
+    common ones, the routed load (``extra[0]``: each MoE layer's
+    ``[assignments, experts touched, largest load]``) and the latent pool's
+    two gauges."""
 
-    def __init__(self, name: str, cache):
-        sc = _obs_stats.scope(f"decode.{name}")
-        self.prefill_assignments = sc.counter(
-            "prefill_routed_assignments", "token-expert assignments "
-            "computed by prefills (real prompt tokens only)")
-        self.step_assignments = sc.counter(
-            "step_routed_assignments", "token-expert assignments computed "
-            "by decode steps (live slots only)")
-        self.step_dispatches = sc.counter(
-            "step_moe_dispatches", "MoE layers run by decode steps")
-        self.step_touched = sc.counter(
-            "step_experts_touched", "experts with at least one row, summed "
-            "over the decode steps' MoE dispatches")
-        self.step_load_max_sum = sc.counter(
-            "step_expert_load_max_sum", "largest load of one expert, summed "
-            "over the decode steps' MoE dispatches")
-        self.load_max = sc.histogram(
-            "expert_load_max", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256,
-                                        512, 1024, 2048, 4096, 8192),
-            help_str="largest load of one expert a MoE dispatch (rows)")
-        self.prefill_real = sc.counter(
-            "prefill_real_tokens", "real prompt tokens prefilled")
-        self.prefill_pad = sc.counter(
-            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
-            "the prefill ladder")
-        self.prefill_sq = sc.counter(
-            "prefill_tokens_sq", "sum over prefills of the prompt length "
-            "squared (causal attention's work)")
-        self.context_tokens = sc.counter(
-            "step_context_tokens", "cached tokens the decode steps' "
-            "attention read, summed over steps (one layer)")
-        self.live_tokens = sc.gauge("latent_live_tokens")
-        sc.gauge("latent_pool_bytes").set(cache.nbytes)
+    def __init__(self, name: str, cache, config, table_shape):
+        super().__init__(name, cache, config, table_shape)
+        self.routed = RoutedLoadSeries(
+            self.series, buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512,
+                                  1024, 2048, 4096, 8192))
+        self.live_tokens = self.series.gauge("latent_live_tokens")
+        self.series.gauge("latent_pool_bytes").set(cache.nbytes)
 
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         with _trace.span("decode::prefill.observe") as sp:
-            load = np.asarray(extra[0])
-            assignments = int(load[:, 0].sum())
-            self.prefill_assignments.inc(assignments)
-            for m in load[:, 2]:
-                self.load_max.observe(float(m))
-            self.prefill_real.inc(prompt)
-            self.prefill_pad.inc(bucket - prompt)
-            self.prefill_sq.inc(prompt * prompt)
+            assignments = self.routed.count_prefill(np.asarray(extra[0]))
+            self.count_prompt(prompt, bucket)
             sp.annotate(prefill_routed_assignments=assignments,
                         prefill_tokens_sq=prompt * prompt)
 
     def step(self, extra, contexts) -> None:
         with _trace.span("decode::step.observe") as sp:
             live_tokens = int(np.sum(contexts))
-            load = np.asarray(extra[0])
-            assignments, touched = (int(load[:, 0].sum()),
-                                    int(load[:, 1].sum()))
-            self.step_assignments.inc(assignments)
-            self.step_dispatches.inc(int(load.shape[0]))
-            self.step_touched.inc(touched)
-            self.step_load_max_sum.inc(int(load[:, 2].sum()))
-            for m in load[:, 2]:
-                self.load_max.observe(float(m))
+            assignments, touched = self.routed.count_step(
+                np.asarray(extra[0]))
             self.context_tokens.inc(live_tokens)
             self.live_tokens.set(live_tokens)
             sp.annotate(step_routed_assignments=assignments,
                         step_experts_touched=touched,
                         step_context_tokens=live_tokens)
-
-    def decodez(self) -> dict:
-        """Nothing of its own on ``/decodez``."""
-        return {}
 
 
 class _MoEOuts:
@@ -294,15 +235,17 @@ class _MoEOuts:
                 jnp.zeros((0, cfg.hidden_size), jnp.float32)]
 
 
-class MLATransformerLM:
+class MLATransformerLM(LMAdapter):
     """One latent-attention MoE LM: config + the jit-ready functions.  As
     with :class:`~paddle_tpu.decode.model.TransformerLM`, the one kernel
     choice is the engine's ``attn_impl`` for the decode step's attention."""
 
-    supports = frozenset()
+    config_class = MLAConfig
+    observer_class = MLAObserver
+    param_shapes = staticmethod(param_shapes)
 
     def __init__(self, config: MLAConfig):
-        self.config = config
+        super().__init__(config)
         self._inv_freq = jnp.asarray(yarn_inv_freq(
             config.qk_rope_head_dim, config.rope_theta, config.rope_scaling))
         self._rope_factor = rope_factor(config)
@@ -311,22 +254,12 @@ class MLATransformerLM:
                                    config.qk_rope_head_dim)
 
     # -- what an engine asks of a model ------------------------------------
-    @classmethod
-    def from_dict(cls, raw: dict) -> "MLATransformerLM":
-        return cls(MLAConfig.from_dict(raw))
-
-    def param_names(self) -> List[str]:
-        return list(param_shapes(self.config))
-
-    def make_cache(self, num_blocks: int, block_tokens: int,
-                   dtype: str = "float32") -> PagedLatentCache:
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots=None) -> PagedLatentCache:
         cfg = self.config
         return PagedLatentCache(cfg.num_hidden_layers, cfg.kv_lora_rank,
                                 cfg.qk_rope_head_dim, self._row, num_blocks,
                                 block_tokens, dtype=dtype)
-
-    def observer(self, name: str, cache, table_shape) -> MLAObserver:
-        return MLAObserver(name, cache)
 
     # -- parameters --------------------------------------------------------
     def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -341,18 +274,12 @@ class MLATransformerLM:
             out[name] = np.asarray(w, np.float32).astype(dt)
         return out
 
-    def param_list(self, params: Dict) -> List:
-        return [jnp.asarray(params[n]) for n in self.param_names()]
-
     def _unpack(self, plist) -> Dict[str, jnp.ndarray]:
         return dict(zip(self.param_names(), plist))
 
     # -- shared layer math -------------------------------------------------
     def _rms(self, x, w):
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * jax.lax.rsqrt(var + self.config.rms_norm_eps)
-                * w.astype(jnp.float32)).astype(x.dtype)
+        return rms_norm(x, w, self.config.rms_norm_eps)
 
     def _rope(self, x, pos):
         """x [..., dr] at positions pos [...] (broadcastable to x's leading
@@ -374,9 +301,9 @@ class MLATransformerLM:
         H, dn, dr, r = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                         cfg.qk_rope_head_dim, cfg.kv_lora_rank)
         with jax.named_scope("mla_wq"):
-            q = _mm(x, p[f"l{i}.wq"]).reshape(x.shape[0], H, dn + dr)
+            q = mm(x, p[f"l{i}.wq"]).reshape(x.shape[0], H, dn + dr)
         with jax.named_scope("mla_wkva"):
-            kva = _mm(x, p[f"l{i}.wkva"])
+            kva = mm(x, p[f"l{i}.wkva"])
             c = self._rms(kva[:, :r], p[f"l{i}.kv_norm"])
         with jax.named_scope("mla_rope"):
             k_pe = self._rope(kva[:, r:], pos)
@@ -410,7 +337,7 @@ class MLATransformerLM:
     def _attn_out(self, p, i, ctx):
         """ctx [N, H, dv] → [N, D]."""
         with jax.named_scope("mla_wo"):
-            return _mm(ctx.reshape(ctx.shape[0], -1), p[f"l{i}.wo"])
+            return mm(ctx.reshape(ctx.shape[0], -1), p[f"l{i}.wo"])
 
     def _ffn(self, p, i, x, valid):
         """x [N, D] → (ffn(x) [N, D], load [3] int32, ids [N, K] int32, the
@@ -503,13 +430,9 @@ class MLATransformerLM:
         (pool,) = state
         Tb = tokens.shape[1]
         bs = pool.shape[2]
-        MB = block_table.shape[0]
-        pos = jnp.arange(Tb, dtype=jnp.int32)
-        valid = pos < length
-        blocks = jnp.where(valid, block_table[jnp.minimum(pos // bs, MB - 1)],
-                           0)
+        pos, valid, blocks, last = prompt_addresses(length, Tb, block_table,
+                                                    bs)
         offsets = pos % bs
-        last = jnp.maximum(length - 1, 0)
         moe = _MoEOuts(cfg)
         x = p["emb"][tokens[0]]
         for i in range(cfg.num_hidden_layers):
@@ -532,9 +455,7 @@ class MLATransformerLM:
             x = self._layer(p, i, x, valid, attend, moe, last[None])
         logits = self._head(p, x[last][None])[0]
         with jax.named_scope("sampling"):
-            tok = _sample(logits[None], seed[None],
-                          jnp.zeros((1,), jnp.int32), temperature[None],
-                          top_k[None])[0]
+            tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits] + moe.outs(Tb), [pool]
 
     # -- decode step -------------------------------------------------------
@@ -543,18 +464,15 @@ class MLATransformerLM:
         """state ``[latent pool]``, tokens / positions [S], block_tables
         [S, MB] → ([next_tokens [S], logits [S, V], load [n_moe, 3], ids
         [n_moe, S, K], the first MoE layer's experts' input [S, D] and routed
-        output [S, D] float32], state').  A slot without a stream feeds an
-        all-zero block table (block 0 is never a stream's): it writes and
-        reads the trash block and is routed to no expert."""
+        output [S, D] float32], state').  A slot without a stream is routed
+        to no expert."""
         cfg = self.config
         p = self._unpack(plist)
         (pool,) = state
         S = tokens.shape[0]
         bs = pool.shape[2]
         r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
-        cl = positions + 1
-        live = block_tables[:, 0] != 0
-        blocks = block_tables[jnp.arange(S), positions // bs]
+        cl, live, _, blocks = step_addresses(positions, block_tables, bs)
         offsets = positions % bs
         moe = _MoEOuts(cfg)
         x = p["emb"][tokens]
@@ -586,7 +504,7 @@ class MLATransformerLM:
             x = self._layer(p, i, x, live, attend, moe)
         logits = self._head(p, x)
         with jax.named_scope("sampling"):
-            toks = _sample(logits, seeds, steps, temperature, top_k)
+            toks = sample(logits, seeds, steps, temperature, top_k)
         return [toks, logits] + moe.outs(S), [pool]
 
 

@@ -36,12 +36,14 @@ from __future__ import annotations
 import dataclasses
 import json
 import os
-from typing import Callable, Dict, List, Optional
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .adapter import (MODEL_TYPES, TOPK_MAX, ConfigDict, LMAdapter, sample,
+                      sample_first, walked_blocks)
 from .cache import PagedKVCache
 from ..kernels.attention import decode_attention, paged_attention_xla
 from ..kernels.quant import (QMAX, SCALE_EPS, kv_dequantize, kv_head_amax,
@@ -49,14 +51,10 @@ from ..kernels.quant import (QMAX, SCALE_EPS, kv_dequantize, kv_head_amax,
 from ..observability import stats as _obs_stats
 
 _LN_EPS = 1e-5
-# static top-k ceiling compiled into the sampling epilogue: per-slot k
-# varies at runtime UNDER it without a recompile (a fixed shape is the
-# whole decode-plane contract)
-TOPK_MAX = 64
 
 
 @dataclasses.dataclass(frozen=True)
-class LMConfig:
+class LMConfig(ConfigDict):
     """Geometry of a decoder-only TransformerLM."""
 
     vocab: int
@@ -70,14 +68,6 @@ class LMConfig:
     @property
     def head_dim(self) -> int:
         return self.d_model // self.n_head
-
-    def to_dict(self) -> dict:
-        return dataclasses.asdict(self)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "LMConfig":
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
-                      if f.name in d})
 
 
 def _pos_table(max_len: int, d_model: int) -> np.ndarray:
@@ -103,15 +93,6 @@ def _join_state(kc, vc, ks, vs) -> list:
     return [kc, vc] if ks is None else [kc, vc, ks, vs]
 
 
-def walked_blocks(contexts, block_rows: int, slots: int) -> int:
-    """Blocks ONE paged walk fetches in a decode step: a live stream's
-    ``ceil(context / block_rows)`` and an idle slot's one (its length is one
-    token).  ``contexts``: the live streams' lengths, one entry each."""
-    contexts = np.asarray(contexts)
-    return int(np.sum((contexts + block_rows - 1) // block_rows)) \
-        + slots - int(contexts.size)
-
-
 class TableWalkObserver:
     """The observer of a model whose programs return a token and logits
     only (:meth:`TransformerLM.observer`): what it counts, it counts from
@@ -122,7 +103,7 @@ class TableWalkObserver:
     slot's one (its table is all trash block, its position 0), of
     ``slots x blocks a slot`` a step."""
 
-    def __init__(self, name: str, cache, table_shape):
+    def __init__(self, name: str, cache, config, table_shape):
         sc = _obs_stats.scope(f"decode.{name}")
         self.live_blocks = sc.counter(
             "step_live_blocks", "table entries the decode steps' attention "
@@ -160,46 +141,30 @@ def _param_names(cfg: LMConfig) -> List[str]:
     return names
 
 
-class TransformerLM:
-    """One decoder-only LM: config + the three jit-ready functions.
+class TransformerLM(LMAdapter):
+    """One decoder-only LM: config + the three jit-ready functions
+    (:class:`~paddle_tpu.decode.adapter.LMAdapter`'s, and a suffix prefill:
+    the one model that ``supports`` the block lifecycle's policies)."""
 
-    Params are a plain name→array dict (``init_params`` /
-    ``save_lm``/``load_lm``); the engine device-puts them once and
-    passes them as ``const`` through ``Executor.run_callable``."""
-
-    # what of the engine's refcounted block lifecycle the model's entry
-    # points can serve (an engine asked for another refuses at build)
     supports = frozenset({"prefix_cache", "overcommit", "beam"})
+    config_class = LMConfig
+    observer_class = TableWalkObserver
 
     def __init__(self, config: LMConfig):
-        self.config = config
+        super().__init__(config)
         self._pos = jnp.asarray(_pos_table(config.max_seq_len,
                                            config.d_model))
 
     # -- what an engine asks of a model ------------------------------------
-    @classmethod
-    def from_dict(cls, raw: dict) -> "TransformerLM":
-        return cls(LMConfig.from_dict(raw))
-
     def param_names(self) -> List[str]:
         return _param_names(self.config)
 
-    def make_cache(self, num_blocks: int, block_tokens: int,
-                   dtype: str = "float32") -> PagedKVCache:
-        """The state this model's streams need: K and V of every layer,
-        paged.  Its ``state()`` is the ``state`` of the three entry
-        points."""
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots=None) -> PagedKVCache:
+        """K and V of every layer, paged."""
         cfg = self.config
         return PagedKVCache(cfg.n_layer, cfg.n_head, cfg.head_dim,
                             num_blocks, block_tokens, dtype=dtype)
-
-    def observer(self, name: str, cache,
-                 table_shape) -> TableWalkObserver:
-        """What the engine ``name`` hands each launch's extra outputs
-        and context lengths to; ``table_shape``: the engine's (slots,
-        blocks a slot).  These programs return nothing beside token and
-        logits."""
-        return TableWalkObserver(name, cache, table_shape)
 
     # -- parameters --------------------------------------------------------
     def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
@@ -224,11 +189,6 @@ class TransformerLM:
             p[f"l{i}.ln2.g"] = np.ones((D,), "float32")
             p[f"l{i}.ln2.b"] = np.zeros((D,), "float32")
         return p
-
-    def param_list(self, params: Dict) -> List:
-        """The ``const`` list in the fixed order the builders close
-        over (missing names fail loudly here, not inside a trace)."""
-        return [jnp.asarray(params[n]) for n in _param_names(self.config)]
 
     def _unpack(self, plist) -> Dict[str, jnp.ndarray]:
         return dict(zip(_param_names(self.config), plist))
@@ -413,9 +373,7 @@ class TransformerLM:
             h = self._post_attn(p, i, h, ctx)
         last = h[0, jnp.maximum(length - 1, 0)]
         logits = last @ p["out_proj"]
-        tok = _sample(logits[None], seed[None],
-                      jnp.zeros((1,), jnp.int32), temperature[None],
-                      top_k[None])[0]
+        tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits], _join_state(kc, vc, ks, vs)
 
     # -- suffix prefill (prefix-cache hits / preemption resume) ------------
@@ -496,9 +454,7 @@ class TransformerLM:
             h = self._post_attn(p, i, h, ctx.astype(h.dtype))
         last = h[jnp.maximum(n - 1, 0)]
         logits = last @ p["out_proj"]
-        tok = _sample(logits[None], seed[None],
-                      jnp.zeros((1,), jnp.int32), temperature[None],
-                      top_k[None])[0]
+        tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits], _join_state(kc, vc, ks, vs)
 
     # -- decode step (the continuous-batching hot dispatch) ----------------
@@ -507,8 +463,9 @@ class TransformerLM:
         """state as :meth:`prefill`, tokens [S] int32 (each slot's last
         token), positions [S] int32 (where that token sits),
         block_tables [S, MB] int32, seeds [S] uint32 + steps [S] int32
-        (per-request sampling identity — see :func:`_sample`) →
-        ([next_tokens [S], logits [S, V]], state'); with the int8 scale
+        (per-request sampling identity — see :func:`~paddle_tpu.decode.
+        adapter.sample`) → ([next_tokens [S], logits [S, V]], state'); with
+        the int8 scale
         pools threaded the paged attention dequantizes
         per-block-per-head in the kernel.
 
@@ -542,69 +499,8 @@ class TransformerLM:
                                        k_scale=ks, v_scale=vs)
             h = self._post_attn(p, i, h, ctx.astype(h.dtype))
         logits = h @ p["out_proj"]
-        toks = _sample(logits, seeds, steps, temperature, top_k)
+        toks = sample(logits, seeds, steps, temperature, top_k)
         return [toks, logits], _join_state(kc, vc, ks, vs)
-
-
-def _hash_uniform(seeds, steps, kk):
-    """Counter-hash uniforms in (0, 1): one murmur-style mix per
-    (request seed, token index, candidate lane) — the attention
-    dropout hash's recipe, keyed PER REQUEST.  A seeded stream is
-    replayable bit-for-bit regardless of which slot it lands on or
-    what else shares the decode batch (an engine-global PRNG key
-    could not promise that)."""
-    S = seeds.shape[0]
-    lane = jax.lax.broadcasted_iota(jnp.uint32, (S, kk), 1)
-    x = (seeds.astype(jnp.uint32)[:, None] * jnp.uint32(0x9E3779B1)
-         ^ steps.astype(jnp.uint32)[:, None] * jnp.uint32(0x85EBCA77)
-         ^ lane * jnp.uint32(0xC2B2AE3D))
-    x = x ^ (x >> 16)
-    x = x * jnp.uint32(0x7FEB352D)
-    x = x ^ (x >> 15)
-    x = x * jnp.uint32(0x846CA68B)
-    x = x ^ (x >> 16)
-    u = (jax.lax.bitcast_convert_type(x >> 8, jnp.int32)
-         .astype(jnp.float32) * jnp.float32(1.0 / (1 << 24)))
-    return jnp.clip(u, 1e-7, 1.0 - 1e-7)
-
-
-def _sample(logits, seeds, steps, temperature, top_k):
-    """On-device sampling epilogue: logits [S, V], seeds [S] uint32
-    (per REQUEST), steps [S] int32 (each request's token index),
-    temperature [S] f32 (<= 0 ⇒ greedy), top_k [S] int32 (0 ⇒ full
-    vocab) → tokens [S] int32.  Per-slot knobs vary at runtime under
-    the static ``TOPK_MAX`` ceiling; sampling is Gumbel-max over the
-    top slice with :func:`_hash_uniform` bits, so a request's sampled
-    stream depends only on (its seed, its token indices) — replayable
-    across slot placements and batch compositions.
-
-    What a caller can rely on: a greedy row is an argmax (the first
-    maximal index) and costs no sort.  ``lax.top_k`` — on a TPU a sort
-    of the whole vocabulary — runs only in a launch that holds at least
-    one ``temperature > 0`` row (the ``lax.cond`` below: one program,
-    the branch taken on the device from this launch's own input), and
-    then every row of that launch pays for it.  The tokens are the same
-    either way: a greedy row's is column 0 of the sorted slice, whose
-    ties go to the lower index as argmax's do."""
-    S, V = logits.shape
-    kk = min(TOPK_MAX, V)
-    x = logits.astype(jnp.float32)
-    greedy = jnp.argmax(x, axis=-1).astype(jnp.int32)
-
-    def from_top_slice():
-        vals, idx = jax.lax.top_k(x, kk)                        # [S, kk]
-        lane = jnp.arange(kk, dtype=jnp.int32)[None, :]
-        want = jnp.where(top_k > 0, jnp.minimum(top_k, kk), kk)[:, None]
-        vals = jnp.where(lane < want, vals, -jnp.inf)
-        g = -jnp.log(-jnp.log(_hash_uniform(seeds, steps, kk)))
-        temp = jnp.maximum(temperature, 1e-6)[:, None]
-        choice = jnp.argmax(vals / temp + g, axis=-1)
-        return jnp.take_along_axis(
-            idx, choice[:, None], axis=1)[:, 0].astype(jnp.int32)
-
-    sampled = jax.lax.cond(jnp.any(temperature > 0.0), from_top_slice,
-                           lambda: greedy)
-    return jnp.where(temperature <= 0.0, greedy, sampled)
 
 
 # ---------------------------------------------------------------------------
@@ -614,10 +510,6 @@ def _sample(logits, seeds, steps, temperature, top_k):
 _CONFIG_FILE = "decode_config.json"
 _PARAMS_FILE = "params.npz"
 
-
-# ``model_type`` of a saved config → builder of its model from the config's
-# dict; a model module other than this one adds itself on import
-MODEL_TYPES: Dict[str, Callable] = {}
 
 _BF16_KEY = "::bf16"     # npz has no bfloat16: such arrays go as uint16 views
 

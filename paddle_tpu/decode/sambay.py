@@ -28,8 +28,7 @@ encoding.  With ``L`` layers the mixers are, by index:
 So a stream's state is of three kinds (:class:`~paddle_tpu.decode.cache.
 HybridStateCache`): blocks of one paged pool that eight layers read, a
 window ring a slot a window layer, and a recurrent row a slot a state-space
-layer.  The last two are addressed by slot: ``slot_state`` tells the engine
-to say, in ``prefill``'s feed, which slot a prompt fills.
+layer.  The last two are addressed by slot (``slot_state``).
 
 **Two prefill depths.**  Nothing above layer L/2 + 1 writes a cache, so
 ``prefill`` runs the prompt through layers 0 … L/2 + 1 and only the last
@@ -44,13 +43,10 @@ real positions.
 (``ms.*``, ``mf.*``) between, so a program holds one pair's code, not L
 layers'.
 
-Entry points and protocol are :class:`~paddle_tpu.decode.model.
-TransformerLM`'s — ``full_logits``, ``prefill`` / ``decode_step`` as
-``(const, state, *feed) → (outs, state')``, ``make_cache``, ``observer``,
-``supports`` — so a :class:`~paddle_tpu.decode.engine.DecodeEngine` serves it
-as it is.  There is no suffix prefill over recurrent state, so ``supports``
-is empty: a prefix cache, overcommit and beam sessions refuse this model at
-build.
+The model is an :class:`~paddle_tpu.decode.adapter.LMAdapter`, so a
+:class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.  There
+is no suffix prefill over recurrent state, so ``supports`` is empty: a prefix
+cache, overcommit and beam sessions refuse this model at build.
 
 Weights, residual stream, pool and rings are ``dtype`` (bf16 as deployed);
 matmuls accumulate in float32; softmax, norm statistics, ``Δ``, ``exp(ΔA)``
@@ -60,18 +56,20 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .adapter import (MODEL_TYPES, ConfigDict, LMAdapter, PoolObserver,
+                      init_tensor as _init_tensor, mm, prompt_addresses,
+                      sample, sample_first, step_addresses, sub,
+                      walked_blocks)
 from .cache import HybridStateCache
-from .model import MODEL_TYPES, _sample, walked_blocks
 from ..kernels import diffattn as _da
 from ..kernels import ssm as _ssm
-from ..observability import stats as _obs_stats
 from ..observability import trace as _trace
 
 MODEL_TYPE = "phi4flash"
@@ -80,7 +78,7 @@ _DT_MIN, _DT_MAX, _DT_FLOOR = 1e-3, 1e-1, 1e-4
 
 
 @dataclasses.dataclass(frozen=True)
-class SambaYConfig:
+class SambaYConfig(ConfigDict):
     """The published keys this model reads, under their published names; the
     state-space sizes, which the published config leaves to the family's
     defaults; the deployment's per-stream ``max_seq_len`` and the weights'
@@ -101,6 +99,7 @@ class SambaYConfig:
     dt_rank: Optional[int] = None
     max_seq_len: int = 128
     dtype: str = "bfloat16"
+    model_type = MODEL_TYPE
 
     def __post_init__(self):
         L = self.num_hidden_layers
@@ -150,14 +149,6 @@ class SambaYConfig:
     @property
     def ssm_layers(self) -> int:
         return self.self_pairs + 1
-
-    def to_dict(self) -> dict:
-        return dict(dataclasses.asdict(self), model_type=MODEL_TYPE)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "SambaYConfig":
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
-                      if f.name in d})
 
 
 def lambda_init(i) -> np.ndarray:
@@ -226,7 +217,8 @@ def param_shapes(cfg: SambaYConfig) -> Dict[str, tuple]:
 
 def init_tensor(key, shape: tuple, init, dtype):
     """One tensor of :func:`param_shapes` from a PRNG key (jit-able with
-    ``shape``, ``init`` and ``dtype`` static)."""
+    ``shape``, ``init`` and ``dtype`` static): Mamba's own three and λ here,
+    the rest by :func:`~paddle_tpu.decode.adapter.init_tensor`."""
     f32 = jnp.float32
     if init == "skip":
         w = jnp.ones(shape, f32)
@@ -239,78 +231,33 @@ def init_tensor(key, shape: tuple, init, dtype):
                                  + math.log(_DT_MIN)), _DT_FLOOR)
         w = dt + jnp.log(-jnp.expm1(-dt))
     else:
-        w = jax.random.normal(key, shape, f32)
-        if isinstance(init, str):
-            scale, shift = {"norm": (0.1, 1.0), "bias": (0.02, 0.0),
-                            "lam": (0.1, 0.0)}[init]
-            w = shift + scale * w
-        else:
-            w = w * init
+        return _init_tensor(key, shape, 0.1 if init == "lam" else init,
+                            dtype)
     return w.astype(dtype)
 
 
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
+class SambaYObserver(PoolObserver):
+    """``decode.<engine>.*`` series of this model: the common ones and the
+    pool's, and its own of the selective scans and the window.
 
-
-def _sub(w: dict, prefix: str) -> dict:
-    n = len(prefix)
-    return {k[n:]: v for k, v in w.items() if k.startswith(prefix)}
-
-
-class SambaYObserver:
-    """``decode.<engine>.*`` series of this model.  Each call is a span
-    (``decode::prefill.observe`` / ``decode::step.observe``, inside the
-    ``.wait`` of its launch) whose arguments are what it added to the
-    counters of the same names: the launch's own work, for a reader of a
-    trace that times that launch.  A step's figures come from the live
-    streams' context lengths, which the engine holds on the host.
-
-    ``step_live_blocks`` over ``step_table_blocks`` (``decodez()``) is the
-    share of the tables handed to the decode steps' attention kernels that
-    their walks fetched, every reading layer counted: the pool's readers
-    (the full layer and the cross layers) fetch a live stream's ``ceil(
-    context / block_tokens)`` blocks of the engine's ``blocks a slot``, a
-    window layer's ring ``ceil(min(context, W) / ring_rows)`` of its
-    ``W / ring_rows``, and each of them one block of an idle slot."""
+    Its walks (:meth:`~paddle_tpu.decode.adapter.PoolObserver.count_walks`):
+    the pool's readers (the full layer and the cross layers) fetch a live
+    stream's ``ceil(context / block_tokens)`` blocks of the engine's ``blocks
+    a slot``, a window layer's ring ``ceil(min(context, W) / ring_rows)`` of
+    its ``W / ring_rows``, and each of them one block of an idle slot."""
 
     def __init__(self, name: str, cache, config: SambaYConfig, table_shape):
-        self.config, self.cache = config, cache
-        self._slots, self._slot_blocks = (int(n) for n in table_shape)
-        sc = _obs_stats.scope(f"decode.{name}")
-        self.prefill_real = sc.counter(
-            "prefill_real_tokens", "real prompt tokens prefilled")
-        self.prefill_pad = sc.counter(
-            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
-            "the prefill ladder")
+        super().__init__(name, cache, config, table_shape)
+        sc = self.series
         self.prefill_scan = sc.counter(
             "prefill_scan_tokens", "real prompt positions the selective "
             "scans of prefills ran, summed over the state-space layers")
         self.prefill_pairs = sc.counter(
             "prefill_window_pairs", "(query, visible key) pairs of one "
             "window layer, summed over prefills")
-        self.prefill_sq = sc.counter(
-            "prefill_tokens_sq", "sum over prefills of the prompt length "
-            "squared (the full layer's causal attention)")
-        self.context_tokens = sc.counter(
-            "step_context_tokens", "cached tokens of the shared pool a "
-            "decode step's streams hold, summed over steps (one reader)")
         self.window_tokens = sc.counter(
             "step_window_tokens", "ring rows a decode step's streams hold "
             "(context cut at the window), summed over steps (one layer)")
-        self.streams = sc.counter(
-            "step_streams", "live streams, summed over decode steps")
-        self.live_blocks = sc.counter(
-            "step_live_blocks", "blocks the decode steps' attention walks "
-            "fetched, summed over the pool's readers and the window layers' "
-            "rings: a live stream's up to its context (a ring's up to the "
-            "window), one of an idle slot")
-        self.table_blocks = sc.counter(
-            "step_table_blocks", "table entries those walks were handed: "
-            "slots x blocks a slot a pool reader, slots x blocks a ring a "
-            "window layer, a step")
-        self.live_tokens = sc.gauge("kv_live_tokens")
-        sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
         sc.gauge("window_state_bytes").set(cache.window_state_bytes)
         sc.gauge("recurrent_state_bytes").set(cache.recurrent_state_bytes)
 
@@ -320,54 +267,43 @@ class SambaYObserver:
             full = min(prompt, W)
             pairs = full * (full + 1) // 2 + (prompt - full) * W
             scan = prompt * self.config.ssm_layers
-            self.prefill_real.inc(prompt)
-            self.prefill_pad.inc(bucket - prompt)
+            self.count_prompt(prompt, bucket)
             self.prefill_scan.inc(scan)
             self.prefill_pairs.inc(pairs)
-            self.prefill_sq.inc(prompt * prompt)
             sp.annotate(prefill_scan_tokens=scan, prefill_window_pairs=pairs,
                         prefill_tokens_sq=prompt * prompt)
 
     def step(self, extra, contexts) -> None:
+        cfg, cache = self.config, self.cache
         with _trace.span("decode::step.observe") as sp:
-            context, streams = int(np.sum(contexts)), len(contexts)
-            window = int(np.minimum(
-                contexts, self.config.sliding_window).sum())
-            self.context_tokens.inc(context)
+            context, streams = self.count_streams(contexts)
+            window = int(np.minimum(contexts, cfg.sliding_window).sum())
             self.window_tokens.inc(window)
-            self.streams.inc(streams)
-            self.live_tokens.set(context)
-            self.cache.live_tokens = context
             sp.annotate(step_context_tokens=context,
                         step_window_tokens=window, step_streams=streams)
-        cfg, cache = self.config, self.cache
         readers = 1 + cfg.cross_pairs
-        pool = walked_blocks(contexts, cache.block_tokens, self._slots)
         ring = walked_blocks(np.minimum(contexts, cfg.sliding_window),
                              cache.ring_rows, self._slots)
-        self.live_blocks.inc(readers * pool + cfg.self_pairs * ring)
-        self.table_blocks.inc(self._slots * (
-            readers * self._slot_blocks + cfg.self_pairs * cache.ring_blocks))
-
-    def decodez(self) -> dict:
-        """The walks' share of their tables (the class's doc); the gauges
-        ride ``cache``."""
-        return {"step_live_blocks": self.live_blocks.value,
-                "step_table_blocks": self.table_blocks.value}
+        self.count_walks(
+            readers * self.pool_walk(contexts) + cfg.self_pairs * ring,
+            self._slots * (readers * self._slot_blocks
+                           + cfg.self_pairs * cache.ring_blocks))
 
 
-class SambaYLM:
+class SambaYLM(LMAdapter):
     """One decoder-hybrid-decoder LM: config + the jit-ready functions.  The
     one kernel choice is the engine's ``attn_impl`` for the decode step's
     attention over the pool and the rings."""
 
-    supports = frozenset()
-    # the engine adds the slot index to prefill's feed and the slot count to
-    # make_cache: two of this model's three kinds of state live in slot rows
+    # two of this model's three kinds of state live in slot rows
     slot_state = True
+    config_class = SambaYConfig
+    observer_class = SambaYObserver
+    param_shapes = staticmethod(param_shapes)
+    init_tensor = staticmethod(init_tensor)
 
     def __init__(self, config: SambaYConfig):
-        self.config = config
+        super().__init__(config)
         half = config.num_hidden_layers // 2
         self._lam_swa = lambda_init(np.arange(1, half, 2))
         self._lam_full = lambda_init(half + 1)
@@ -375,39 +311,14 @@ class SambaYLM:
             np.arange(half + 3, config.num_hidden_layers, 2))
 
     # -- what an engine asks of a model ------------------------------------
-    @classmethod
-    def from_dict(cls, raw: dict) -> "SambaYLM":
-        return cls(SambaYConfig.from_dict(raw))
-
-    def param_names(self) -> List[str]:
-        return list(param_shapes(self.config))
-
-    def make_cache(self, num_blocks: int, block_tokens: int,
-                   dtype: str = "float32", slots: Optional[int] = None
-                   ) -> HybridStateCache:
-        if slots is None:
-            raise ValueError("this model's state lives in slot rows: "
-                             "make_cache needs the engine's slot count")
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots: int) -> HybridStateCache:
         cfg = self.config
         return HybridStateCache(
-            cfg.kv_width, num_blocks, block_tokens, slots,
-            cfg.sliding_window, cfg.self_pairs, cfg.ssm_layers, cfg.d_inner,
-            cfg.d_state, cfg.d_conv, dtype=dtype)
-
-    def observer(self, name: str, cache, table_shape) -> SambaYObserver:
-        return SambaYObserver(name, cache, self.config, table_shape)
-
-    # -- parameters --------------------------------------------------------
-    def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
-        """Seeded random weights by :func:`init_tensor`."""
-        shapes = param_shapes(self.config)
-        keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
-        dt = jnp.dtype(self.config.dtype)
-        return {name: np.asarray(init_tensor(k, tuple(shape), init, dt))
-                for k, (name, (shape, init)) in zip(keys, shapes.items())}
-
-    def param_list(self, params: Dict) -> List:
-        return [jnp.asarray(params[n]) for n in self.param_names()]
+            cfg.kv_width, num_blocks, block_tokens, slots, dtype=dtype,
+            rings=(cfg.self_pairs, cfg.sliding_window),
+            recurrent=(cfg.ssm_layers, (cfg.d_state, cfg.d_inner)),
+            tails=(cfg.ssm_layers, cfg.d_conv, cfg.d_inner))
 
     def _unpack(self, plist) -> Dict[str, jnp.ndarray]:
         return dict(zip(self.param_names(), plist))
@@ -428,12 +339,12 @@ class SambaYLM:
         with jax.named_scope("mlp"):
             g = jnp.dot(u, w["mlp_gate"], preferred_element_type=jnp.float32)
             up = jnp.dot(u, w["mlp_up"], preferred_element_type=jnp.float32)
-            return x + _mm((jax.nn.silu(g) * up).astype(x.dtype),
+            return x + mm((jax.nn.silu(g) * up).astype(x.dtype),
                            w["mlp_down"])
 
     def _ssm_in(self, w, u):
         with jax.named_scope("ssm_in"):
-            az = _mm(u, w["in_proj"])
+            az = mm(u, w["in_proj"])
         Di = self.config.d_inner
         return az[:, :Di], az[:, Di:]
 
@@ -456,7 +367,7 @@ class SambaYLM:
         m = y + w["skip"].astype(jnp.float32) * c.astype(jnp.float32)
         with jax.named_scope("ssm_out"):
             gated = (m * jax.nn.silu(z.astype(jnp.float32))).astype(z.dtype)
-            return _mm(gated, w["out_proj"]), m.astype(z.dtype)
+            return mm(gated, w["out_proj"]), m.astype(z.dtype)
 
     def _ssm_prompt(self, w, u, valid, length, dense: bool):
         """A prompt's rows u [T, D] → (output [T, D], memory [T, Di], h at
@@ -494,7 +405,7 @@ class SambaYLM:
     def _qkv(self, w, u, dtype):
         """u [N, D] → q [N, nh, 2·dh], the cache rows [k | v] [N, 2·kw]."""
         cfg = self.config
-        qkv = _mm(u, w["wqkv"]) + w["bqkv"]
+        qkv = mm(u, w["wqkv"]) + w["bqkv"]
         D = cfg.hidden_size
         return (qkv[:, :D].reshape(-1, cfg.n_heads, 2 * cfg.head_dim),
                 qkv[:, D:].astype(dtype))
@@ -502,7 +413,7 @@ class SambaYLM:
     def _cross_q(self, w, u):
         cfg = self.config
         with jax.named_scope("cross_q"):
-            q = _mm(u, w["wq"]) + w["bq"]
+            q = mm(u, w["wq"]) + w["bq"]
         return q.reshape(-1, cfg.n_heads, 2 * cfg.head_dim)
 
     def _diff_out(self, w, o2, lam0, dtype):
@@ -519,12 +430,12 @@ class SambaYLM:
             o = o * lax.rsqrt(jnp.mean(jnp.square(o), -1, keepdims=True)
                               + _SUBLN_EPS) * w["subln"].astype(f32)
             o = ((1.0 - lam0) * o).reshape(o.shape[0], -1).astype(dtype)
-            return _mm(o, w["wo"]) + w["bo"]
+            return mm(o, w["wo"]) + w["bo"]
 
     def _gmu(self, w, u, m):
         with jax.named_scope("gmu"):
             g = jnp.dot(u, w["w1"], preferred_element_type=jnp.float32)
-            return _mm((jax.nn.silu(g) * m.astype(jnp.float32)
+            return mm((jax.nn.silu(g) * m.astype(jnp.float32)
                         ).astype(u.dtype), w["w2"])
 
     def _head(self, p, x):
@@ -546,7 +457,7 @@ class SambaYLM:
 
         def self_pair(x, xs):
             w, lam0 = xs
-            ws, ww = _sub(w, "s."), _sub(w, "w.")
+            ws, ww = sub(w, "s."), sub(w, "w.")
             got = {}
 
             def ssm_mixer(u):
@@ -568,8 +479,8 @@ class SambaYLM:
             return x, (got["h"], got["tail"], got["ring"])
 
         x, (hs, tails, rings) = lax.scan(
-            self_pair, x, (_sub(p, "sp."), jnp.asarray(self._lam_swa)))
-        ws, wf = _sub(p, "ms."), _sub(p, "mf.")
+            self_pair, x, (sub(p, "sp."), jnp.asarray(self._lam_swa)))
+        ws, wf = sub(p, "ms."), sub(p, "mf.")
         got = {}
 
         def ssm_mixer(u):
@@ -596,7 +507,7 @@ class SambaYLM:
         rows (the paths differ only there)."""
         def cross_pair(x, xs):
             w, lam0 = xs
-            wg, wc = _sub(w, "g."), _sub(w, "c.")
+            wg, wc = sub(w, "g."), sub(w, "c.")
 
             def cross_mixer(u):
                 with jax.named_scope("cross_attn"):
@@ -607,7 +518,7 @@ class SambaYLM:
             return self._block(wc, x, cross_mixer), None
 
         x, _ = lax.scan(cross_pair, x,
-                        (_sub(p, "cp."), jnp.asarray(self._lam_cross)))
+                        (sub(p, "cp."), jnp.asarray(self._lam_cross)))
         return x
 
     # -- full forward (the parity anchor) ----------------------------------
@@ -644,13 +555,8 @@ class SambaYLM:
         p = self._unpack(plist)
         kv, rings, hs, conv = state
         Tb = tokens.shape[1]
-        bs, MB = kv.shape[2], block_table.shape[0]
-        W = cfg.sliding_window
-        pos = jnp.arange(Tb, dtype=jnp.int32)
-        valid = pos < length
-        blocks = jnp.where(valid, block_table[jnp.minimum(pos // bs, MB - 1)],
-                           0)
-        last = jnp.maximum(length - 1, 0)
+        bs, W = kv.shape[2], cfg.sliding_window
+        pos, _, blocks, last = prompt_addresses(length, Tb, block_table, bs)
         # ring index r holds the last real position that is r mod W
         r = jnp.arange(W, dtype=jnp.int32)
         src = jnp.clip(r + W * ((length - 1 - r) // W), 0, Tb - 1)
@@ -676,31 +582,22 @@ class SambaYLM:
                                         cfg.n_kv)[None])
         logits = self._head(p, x)[0]
         with jax.named_scope("sampling"):
-            tok = _sample(logits[None], seed[None],
-                          jnp.zeros((1,), jnp.int32), temperature[None],
-                          top_k[None])[0]
+            tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits], [kv, rings, hs, conv]
 
     # -- decode step -------------------------------------------------------
     def decode_step(self, plist, state, tokens, positions, block_tables,
                     seeds, steps, temperature, top_k, attn_impl=None):
         """state ``[kv pool, rings, h, conv]``, tokens / positions [S],
-        block_tables [S, MB] → ([next_tokens [S], logits [S, V]], state').
-        Row ``i`` is slot ``i``.  A slot
-        without a stream feeds an all-zero block table (block 0 is never a
-        stream's): it writes the trash block and scribbles on its own ring
-        and recurrent rows, which the next join's prefill overwrites."""
+        block_tables [S, MB] → ([next_tokens [S], logits [S, V]], state')."""
         cfg = self.config
         p = self._unpack(plist)
         kv, rings, hs, conv = state
-        S = tokens.shape[0]
         bs, W = kv.shape[2], cfg.sliding_window
         rb = rings.shape[2]
         nrb = W // rb
-        cl = positions + 1
+        cl, _, slots, blocks = step_addresses(positions, block_tables, bs)
         wl = jnp.minimum(cl, W)
-        slots = jnp.arange(S, dtype=jnp.int32)
-        blocks = block_tables[slots, positions // bs]
         at = positions % W
         ring_tables = slots[:, None] * nrb + jnp.arange(nrb,
                                                         dtype=jnp.int32)
@@ -721,7 +618,7 @@ class SambaYLM:
         def self_pair(carry, xs):
             x, rings, hs, conv = carry
             w, i, lam0 = xs
-            ws, ww = _sub(w, "s."), _sub(w, "w.")
+            ws, ww = sub(w, "s."), sub(w, "w.")
             got = {}
 
             def swa_mixer(u):
@@ -742,9 +639,9 @@ class SambaYLM:
         P = cfg.self_pairs
         (x, rings, hs, conv), _ = lax.scan(
             self_pair, (x, rings, hs, conv),
-            (_sub(p, "sp."), jnp.arange(P, dtype=jnp.int32),
+            (sub(p, "sp."), jnp.arange(P, dtype=jnp.int32),
              jnp.asarray(self._lam_swa)))
-        ws, wf = _sub(p, "ms."), _sub(p, "mf.")
+        ws, wf = sub(p, "ms."), sub(p, "mf.")
         got = {}
 
         def full_mixer(u):
@@ -764,7 +661,7 @@ class SambaYLM:
             q, kv, block_tables, cl, 0, cfg.n_kv, impl=attn_impl))
         logits = self._head(p, x)
         with jax.named_scope("sampling"):
-            toks = _sample(logits, seeds, steps, temperature, top_k)
+            toks = sample(logits, seeds, steps, temperature, top_k)
         return [toks, logits], [kv, rings, hs, conv]
 
 

@@ -34,8 +34,7 @@ WINDOW layers:
 So a stream's state is of two kinds (:class:`~paddle_tpu.decode.cache.
 HybridStateCache` with no recurrent rows): blocks of a paged pool of the full
 layers, held by block table, and a ring a slot a window layer, addressed by
-slot (``slot_state``: the engine says in ``prefill``'s feed which slot a
-prompt fills).
+slot (``slot_state``).
 
 Programs ``lax.scan`` over the periods' stacked weights (``pf.*`` ``[P, …]``
 the full layers, ``pw.*`` ``[P, period − 1, …]`` the window layers, scanned
@@ -46,17 +45,14 @@ handed the whole stack and the layer's index (no layer's 0.38 GB of experts
 is sliced out of it).  Every position of a prompt runs through every layer
 (every layer writes a cache) and only the last real one through the head.
 
-Entry points and protocol are :class:`~paddle_tpu.decode.model.
-TransformerLM`'s — ``full_logits``, ``prefill`` / ``decode_step`` as
-``(const, state, *feed) → (outs, state')``, ``make_cache``, ``observer``,
-``supports`` — so a :class:`~paddle_tpu.decode.engine.DecodeEngine` serves it
-as it is.  Beside token and logits the programs return every layer's load
+The model is an :class:`~paddle_tpu.decode.adapter.LMAdapter`, so a
+:class:`~paddle_tpu.decode.engine.DecodeEngine` serves it as it is.
+Beside token and logits the programs return every layer's load
 figures ``[L, 3]``, the chosen experts ``[L, tokens, K]`` and, at the rows
 that reach the head, the router's input ``u`` and its logits (what a
 reference check holds the routing to).  There is no suffix prefill over a
 ring, so ``supports`` is empty: a prefix cache, overcommit and beam sessions
-refuse this model at build.  There is one path: the kernels choose by shape
-alone (``attn_impl`` is accepted for the protocol's sake).
+refuse this model at build.
 
 Weights, residual stream, pool and rings are ``dtype`` (bf16 as deployed);
 matmuls accumulate in float32; the router's logits, scores and weights, the
@@ -68,27 +64,28 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from .adapter import (EXPERT_LEAVES, MODEL_TYPES, ConfigDict, LMAdapter,
+                      PoolObserver, RoutedLoadSeries, init_tensor, mm,
+                      prompt_addresses, rms_norm, rotary, sample,
+                      sample_first, step_addresses, sub, unscanned,
+                      walked_blocks)
 from .cache import HybridStateCache
-from .falcon_h1 import rotary
-from .model import MODEL_TYPES, _sample, walked_blocks
 from ..kernels import gqa as _gqa
 from ..kernels import moe as _moe
-from ..observability import stats as _obs_stats
 from ..observability import trace as _trace
 
 MODEL_TYPE = "smallthinker"
-EXPERT_LEAVES = ("e_gate", "e_up", "e_down")
 
 
 @dataclasses.dataclass(frozen=True)
-class SmallThinkerConfig:
+class SmallThinkerConfig(ConfigDict):
     """The published keys this model reads, under their published names; the
     deployment's per-stream ``max_seq_len`` and the weights' ``dtype``.  The
     layouts may be the published model's whole: a cut in depth reads their
@@ -112,6 +109,7 @@ class SmallThinkerConfig:
     sliding_window_size: int = 32
     max_seq_len: int = 128
     dtype: str = "bfloat16"
+    model_type = MODEL_TYPE
 
     def __post_init__(self):
         L = self.num_hidden_layers
@@ -157,14 +155,6 @@ class SmallThinkerConfig:
     def kv_width(self) -> int:
         return self.num_key_value_heads * self.head_dim
 
-    def to_dict(self) -> dict:
-        return dict(dataclasses.asdict(self), model_type=MODEL_TYPE)
-
-    @classmethod
-    def from_dict(cls, d: dict) -> "SmallThinkerConfig":
-        return cls(**{f.name: d[f.name] for f in dataclasses.fields(cls)
-                      if f.name in d})
-
 
 def param_shapes(cfg: SmallThinkerConfig) -> Dict[str, tuple]:
     """name → (shape, init): a float is the std of a normal; ``norm`` a norm
@@ -186,81 +176,27 @@ def param_shapes(cfg: SmallThinkerConfig) -> Dict[str, tuple]:
     return out
 
 
-def init_tensor(key, shape: tuple, init, dtype):
-    """One tensor of :func:`param_shapes` from a PRNG key (jit-able with
-    ``shape``, ``init`` and ``dtype`` static)."""
-    w = jax.random.normal(key, shape, jnp.float32)
-    w = 1.0 + 0.1 * w if init == "norm" else w * init
-    return w.astype(dtype)
-
-
-def _mm(x, w):
-    return jnp.dot(x, w, preferred_element_type=jnp.float32).astype(x.dtype)
-
-
-def _sub(w: dict, prefix: str) -> dict:
-    n = len(prefix)
-    return {k[n:]: v for k, v in w.items() if k.startswith(prefix)}
-
-
-def _small(w: dict) -> dict:
-    """A layer stack's tensors less the experts' (those are not scanned)."""
-    return {k: v for k, v in w.items() if k not in EXPERT_LEAVES}
-
-
-class SmallThinkerObserver:
-    """``decode.<engine>.*`` series of this model, fed by what its programs
-    return beside token and logits (``extra[0]``: each layer's
-    ``[assignments, experts touched, largest load]``) and by the live streams'
-    context lengths, which the engine holds on the host.  A *dispatch* is one
-    layer's experts in one program launch.  Each call is a span
-    (``decode::prefill.observe`` / ``decode::step.observe``, inside the
-    ``.wait`` of its launch) whose arguments are what it added to the
-    counters of the same names: the launch's own work, for a reader of a
-    trace that times that launch.
+class SmallThinkerObserver(PoolObserver):
+    """``decode.<engine>.*`` series of this model: the common ones, the
+    pool's and the routed load (``extra[0]``: each layer's ``[assignments,
+    experts touched, largest load]``), and its own of the window.
 
     ``step_ring_rows_live`` over ``step_ring_rows_held`` is the share of the
     rings' bytes that the live streams use: a stream holds ``window`` rows a
     window layer whatever its context, and ``min(context, window)`` of them
-    are live."""
+    are live.  Its walks: a full layer's over the pool, a window layer's over
+    a ring up to the window."""
 
     def __init__(self, name: str, cache, config: SmallThinkerConfig,
                  table_shape):
-        self.config, self.cache = config, cache
-        self._slots, self._slot_blocks = (int(n) for n in table_shape)
-        sc = _obs_stats.scope(f"decode.{name}")
-        self.prefill_assignments = sc.counter(
-            "prefill_routed_assignments", "token-expert assignments "
-            "computed by prefills (real prompt tokens only), every layer")
-        self.step_assignments = sc.counter(
-            "step_routed_assignments", "token-expert assignments computed "
-            "by decode steps (live slots only), every layer")
-        self.step_dispatches = sc.counter(
-            "step_moe_dispatches", "expert layers run by decode steps")
-        self.step_touched = sc.counter(
-            "step_experts_touched", "experts with at least one row, summed "
-            "over the decode steps' dispatches")
-        self.step_load_max_sum = sc.counter(
-            "step_expert_load_max_sum", "largest load of one expert, summed "
-            "over the decode steps' dispatches")
-        self.load_max = sc.histogram(
-            "expert_load_max", buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256,
-                                        512, 1024, 2048, 4096, 8192, 16384),
-            help_str="largest load of one expert a dispatch (rows)")
-        self.prefill_real = sc.counter(
-            "prefill_real_tokens", "real prompt tokens prefilled")
-        self.prefill_pad = sc.counter(
-            "prefill_pad_tokens", "pad tokens added snapping prompts onto "
-            "the prefill ladder")
+        super().__init__(name, cache, config, table_shape)
+        sc = self.series
+        self.routed = RoutedLoadSeries(
+            sc, buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048,
+                         4096, 8192, 16384))
         self.prefill_pairs = sc.counter(
             "prefill_window_pairs", "(query, visible key) pairs of one "
             "window layer, summed over prefills")
-        self.prefill_sq = sc.counter(
-            "prefill_tokens_sq", "sum over prefills of the prompt length "
-            "squared (one full layer's causal attention)")
-        self.context_tokens = sc.counter(
-            "step_context_tokens", "cached tokens of the pool a decode "
-            "step's streams hold, summed over steps (one full layer)")
         self.ring_live = sc.counter(
             "step_ring_rows_live", "ring rows a decode step's streams read "
             "(context cut at the window), summed over steps (one window "
@@ -271,35 +207,16 @@ class SmallThinkerObserver:
         self.past_window = sc.counter(
             "step_streams_past_window", "live streams whose context is "
             "longer than the window, summed over decode steps")
-        self.streams = sc.counter(
-            "step_streams", "live streams, summed over decode steps")
-        self.live_blocks = sc.counter(
-            "step_live_blocks", "blocks the decode steps' attention walks "
-            "fetched, summed over the full layers' pool and the window "
-            "layers' rings: a live stream's up to its context (a ring's up "
-            "to the window), one of an idle slot")
-        self.table_blocks = sc.counter(
-            "step_table_blocks", "table entries those walks were handed: "
-            "slots x blocks a slot a full layer, slots x blocks a ring a "
-            "window layer, a step")
-        self.live_tokens = sc.gauge("kv_live_tokens")
-        sc.gauge("kv_pool_bytes").set(cache.kv_pool_bytes)
         sc.gauge("window_state_bytes").set(cache.window_state_bytes)
 
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         with _trace.span("decode::prefill.observe") as sp:
-            load = np.asarray(extra[0])
-            assignments = int(load[:, 0].sum())
+            assignments = self.routed.count_prefill(np.asarray(extra[0]))
             W = self.config.sliding_window_size
             full = min(prompt, W)
             pairs = full * (full + 1) // 2 + (prompt - full) * W
-            self.prefill_assignments.inc(assignments)
-            for m in load[:, 2]:
-                self.load_max.observe(float(m))
-            self.prefill_real.inc(prompt)
-            self.prefill_pad.inc(bucket - prompt)
+            self.count_prompt(prompt, bucket)
             self.prefill_pairs.inc(pairs)
-            self.prefill_sq.inc(prompt * prompt)
             sp.annotate(prefill_routed_assignments=assignments,
                         prefill_real_tokens=prompt,
                         prefill_window_pairs=pairs,
@@ -309,107 +226,61 @@ class SmallThinkerObserver:
         cfg, cache = self.config, self.cache
         W = cfg.sliding_window_size
         with _trace.span("decode::step.observe") as sp:
-            load = np.asarray(extra[0])
-            assignments, touched = (int(load[:, 0].sum()),
-                                    int(load[:, 1].sum()))
-            context, streams = int(np.sum(contexts)), len(contexts)
+            assignments, touched = self.routed.count_step(
+                np.asarray(extra[0]))
+            context, streams = self.count_streams(contexts)
             live = int(np.minimum(contexts, W).sum())
             past = int(np.sum(np.asarray(contexts) > W))
-            self.step_assignments.inc(assignments)
-            self.step_dispatches.inc(int(load.shape[0]))
-            self.step_touched.inc(touched)
-            self.step_load_max_sum.inc(int(load[:, 2].sum()))
-            for m in load[:, 2]:
-                self.load_max.observe(float(m))
-            self.context_tokens.inc(context)
             self.ring_live.inc(live)
             self.ring_held.inc(streams * W)
             self.past_window.inc(past)
-            self.streams.inc(streams)
-            self.live_tokens.set(context)
-            cache.live_tokens = context
             sp.annotate(step_routed_assignments=assignments,
                         step_experts_touched=touched,
                         step_context_tokens=context,
                         step_ring_rows_live=live, step_streams=streams)
-        pool = walked_blocks(contexts, cache.block_tokens, self._slots)
         ring = walked_blocks(np.minimum(contexts, W), cache.ring_rows,
                              self._slots)
-        self.live_blocks.inc(cfg.periods * pool + cfg.window_layers * ring)
-        self.table_blocks.inc(self._slots * (
-            cfg.periods * self._slot_blocks
-            + cfg.window_layers * cache.ring_blocks))
+        self.count_walks(
+            cfg.periods * self.pool_walk(contexts) + cfg.window_layers * ring,
+            self._slots * (cfg.periods * self._slot_blocks
+                           + cfg.window_layers * cache.ring_blocks))
 
     def decodez(self) -> dict:
-        """The walks' share of their tables and the rings' live share; the
-        gauges ride ``cache``."""
-        return {"step_live_blocks": self.live_blocks.value,
-                "step_table_blocks": self.table_blocks.value,
-                "step_ring_rows_live": self.ring_live.value,
-                "step_ring_rows_held": self.ring_held.value}
+        """… and the rings' live share."""
+        return dict(super().decodez(),
+                    step_ring_rows_live=self.ring_live.value,
+                    step_ring_rows_held=self.ring_held.value)
 
 
-class SmallThinkerLM:
+class SmallThinkerLM(LMAdapter):
     """One window-and-full attention expert LM: config + the jit-ready
     functions."""
 
-    supports = frozenset()
-    # the engine adds the slot index to prefill's feed and the slot count to
-    # make_cache: a window layer's ring lives in slot rows
+    # a window layer's ring lives in slot rows
     slot_state = True
-
-    def __init__(self, config: SmallThinkerConfig):
-        self.config = config
+    config_class = SmallThinkerConfig
+    observer_class = SmallThinkerObserver
+    param_shapes = staticmethod(param_shapes)
 
     # -- what an engine asks of a model ------------------------------------
-    @classmethod
-    def from_dict(cls, raw: dict) -> "SmallThinkerLM":
-        return cls(SmallThinkerConfig.from_dict(raw))
-
-    def param_names(self) -> List[str]:
-        return list(param_shapes(self.config))
-
-    def make_cache(self, num_blocks: int, block_tokens: int,
-                   dtype: str = "float32", slots: Optional[int] = None
-                   ) -> HybridStateCache:
-        if slots is None:
-            raise ValueError("this model's state lives in slot rows: "
-                             "make_cache needs the engine's slot count")
+    def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
+                    slots: int) -> HybridStateCache:
         cfg = self.config
         return HybridStateCache(
-            cfg.kv_width, num_blocks, block_tokens, slots,
-            cfg.sliding_window_size, cfg.window_layers, ssm_layers=0,
-            d_inner=0, d_state=0, d_conv=0, dtype=dtype,
-            kv_layers=cfg.periods)
-
-    def observer(self, name: str, cache, table_shape) -> SmallThinkerObserver:
-        return SmallThinkerObserver(name, cache, self.config, table_shape)
-
-    # -- parameters --------------------------------------------------------
-    def init_params(self, seed: int = 0) -> Dict[str, np.ndarray]:
-        """Seeded random weights by :func:`init_tensor`."""
-        shapes = param_shapes(self.config)
-        keys = jax.random.split(jax.random.PRNGKey(seed), len(shapes))
-        dt = jnp.dtype(self.config.dtype)
-        return {name: np.asarray(init_tensor(k, tuple(shape), init, dt))
-                for k, (name, (shape, init)) in zip(keys, shapes.items())}
-
-    def param_list(self, params: Dict) -> List:
-        return [jnp.asarray(params[n]) for n in self.param_names()]
+            cfg.kv_width, num_blocks, block_tokens, slots, dtype=dtype,
+            kv_layers=cfg.periods,
+            rings=(cfg.window_layers, cfg.sliding_window_size))
 
     def _unpack(self, plist):
         """(the model's own tensors, the full layers' stacks, the window
         layers' stacks)."""
         p = dict(zip(self.param_names(), plist))
         return ({k: v for k, v in p.items() if k[:3] not in ("pf.", "pw.")},
-                _sub(p, "pf."), _sub(p, "pw."))
+                sub(p, "pf."), sub(p, "pw."))
 
     # -- shared layer math -------------------------------------------------
     def _rms(self, x, g):
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        return (x32 * lax.rsqrt(var + self.config.rms_norm_eps)
-                * g.astype(jnp.float32)).astype(x.dtype)
+        return rms_norm(x, g, self.config.rms_norm_eps)
 
     def _route(self, w, u, valid, tile: int):
         """The layer's routing from its normed input u [N, D]: (logits [N, E]
@@ -444,7 +315,7 @@ class SmallThinkerLM:
         cfg = self.config
         N, dh = u.shape[0], cfg.head_dim
         with jax.named_scope("attn_qkv"):
-            qkv = _mm(u, w["wqkv"])
+            qkv = mm(u, w["wqkv"])
             q = qkv[:, :cfg.q_width].reshape(N, cfg.num_attention_heads, dh)
             k = qkv[:, cfg.q_width:cfg.q_width + cfg.kv_width]
             v = qkv[:, cfg.q_width + cfg.kv_width:]
@@ -457,7 +328,7 @@ class SmallThinkerLM:
 
     def _attn_out(self, w, x, o):
         with jax.named_scope("attn_out"):
-            return x + _mm(o.reshape(o.shape[0], -1).astype(x.dtype), w["wo"])
+            return x + mm(o.reshape(o.shape[0], -1).astype(x.dtype), w["wo"])
 
     def _head(self, p, x):
         with jax.named_scope("lm_head"):
@@ -502,7 +373,7 @@ class SmallThinkerLM:
 
         (x, carry), got = lax.scan(
             period, (x, carry),
-            (_small(pf), _small(pw),
+            (unscanned(pf), unscanned(pw),
              jnp.arange(cfg.periods, dtype=jnp.int32)))
         return x, carry, tuple(g.reshape((-1,) + g.shape[2:]) for g in got)
 
@@ -579,14 +450,9 @@ class SmallThinkerLM:
         p, pf, pw = self._unpack(plist)
         kv, rings = state
         Tb = tokens.shape[1]
-        bs, MB = kv.shape[2], block_table.shape[0]
-        W, rb = cfg.sliding_window_size, rings.shape[2]
+        bs, W, rb = kv.shape[2], cfg.sliding_window_size, rings.shape[2]
         nrb = W // rb
-        pos = jnp.arange(Tb, dtype=jnp.int32)
-        valid = pos < length
-        blocks = jnp.where(valid, block_table[jnp.minimum(pos // bs, MB - 1)],
-                           0)
-        last = jnp.maximum(length - 1, 0)
+        pos, _, blocks, last = prompt_addresses(length, Tb, block_table, bs)
         zero = jnp.zeros((), slot.dtype)
         # a prompt bucket inside the window lies in the ring as it is;
         # otherwise ring index r gets the last real position that is r mod W
@@ -613,9 +479,7 @@ class SmallThinkerLM:
             (kv, rings))
         logits = self._head(p, x[last][None])[0]
         with jax.named_scope("sampling"):
-            tok = _sample(logits[None], seed[None],
-                          jnp.zeros((1,), jnp.int32), temperature[None],
-                          top_k[None])[0]
+            tok = sample_first(logits, seed, temperature, top_k)
         return [tok, logits, load, ids, u[:, None], rl[:, None]], [kv, rings]
 
     # -- decode step -------------------------------------------------------
@@ -623,11 +487,8 @@ class SmallThinkerLM:
                     seeds, steps, temperature, top_k, attn_impl=None):
         """state ``[kv pool, rings]``, tokens / positions [S], block_tables
         [S, MB] → ([next_tokens [S], logits [S, V], load [L, 3], ids [L, S,
-        K], u [L, S, D], router logits [L, S, E]], state').  Row ``i`` is slot
-        ``i``.  A slot without a stream feeds an all-zero block table (block 0
-        is never a stream's): it writes the trash block, scribbles on its own
-        rings, which the next join's prefill overwrites, and is routed to no
-        expert."""
+        K], u [L, S, D], router logits [L, S, E]], state').  A slot without a
+        stream is routed to no expert."""
         del attn_impl           # one path: the kernels choose by shape alone
         cfg = self.config
         p, pf, pw = self._unpack(plist)
@@ -636,11 +497,8 @@ class SmallThinkerLM:
         bs, W, rb = kv.shape[2], cfg.sliding_window_size, rings.shape[2]
         nrb = W // rb
         n_kv = cfg.num_key_value_heads
-        cl = positions + 1
+        cl, live, slots, blocks = step_addresses(positions, block_tables, bs)
         wl = jnp.minimum(cl, W)
-        live = block_tables[:, 0] != 0
-        slots = jnp.arange(S, dtype=jnp.int32)
-        blocks = block_tables[slots, positions // bs]
         at_ring = positions % W
         ring_tables = slots[:, None] * nrb + jnp.arange(nrb, dtype=jnp.int32)
         ring_blocks = slots * nrb + at_ring // rb
@@ -672,7 +530,7 @@ class SmallThinkerLM:
             pf, pw, p["emb"][tokens], (kv, rings), layer)
         logits = self._head(p, x)
         with jax.named_scope("sampling"):
-            toks = _sample(logits, seeds, steps, temperature, top_k)
+            toks = sample(logits, seeds, steps, temperature, top_k)
         return [toks, logits, load, ids, u, rl], [kv, rings]
 
 

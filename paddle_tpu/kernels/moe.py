@@ -45,7 +45,6 @@ assignment is computed (no capacity, no token dropped).
   layer's experts are sliced out of the stack.  The XLA fallback is three
   ``lax.ragged_dot`` over the same padded rows and counts into
   ``moe.grouped_swiglu_fallbacks`` / ``moe.grouped_reglu_fallbacks``.
-  :func:`grouped_swiglu` is the call with the gate fixed at ``silu``.
 
   The kernel alone on one v5e, µs a call (PR 45; bf16, 64 experts, top-6,
   float32 rows out at [2048, 1408], bf16 at the others; plans of uniform
@@ -465,16 +464,6 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
     )(tile_expert, plan.active_tiles, x_rows, wg, wu, wd)
 
 
-def grouped_swiglu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
-                   interpret=None):
-    """:func:`grouped_glu` with the gate ``silu``."""
-    return grouped_glu(x_rows, wg, wu, wd, plan, tile, impl, interpret)
-
-
-def grouped_swiglu_xla(x_rows, wg, wu, wd, plan: GroupPlan):
-    return grouped_glu_xla(x_rows, wg, wu, wd, plan)
-
-
 def row_tile(tokens: int, dtype) -> int:
     small = 16 if jnp.dtype(dtype).itemsize < 4 else 8
     return small if tokens <= 128 else _PREFILL_TILE
@@ -537,6 +526,5 @@ def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
 
 
 __all__ = ["route_topk", "plan_groups", "plan_rows", "grouped_glu",
-           "grouped_glu_xla", "grouped_swiglu", "grouped_swiglu_xla", "combine",
-           "planned_experts", "routed_experts", "GroupPlan", "row_tile",
-           "ACTS", "SCORES"]
+           "grouped_glu_xla", "combine", "planned_experts", "routed_experts",
+           "GroupPlan", "row_tile", "ACTS", "SCORES"]

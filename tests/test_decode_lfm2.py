@@ -173,10 +173,8 @@ def test_a_prefix_cache_overcommit_and_beams_are_refused():
 
 
 def test_tails_are_a_kind_of_the_hybrid_cache_without_recurrent_rows():
-    cache = HybridStateCache(128, 8, BS, slots=3, window=0, window_layers=0,
-                             ssm_layers=0, d_inner=0, d_state=0, d_conv=3,
-                             dtype="float32", kv_layers=2, conv_width=64,
-                             conv_layers=7)
+    cache = HybridStateCache(128, 8, BS, slots=3, dtype="float32",
+                             kv_layers=2, tails=(7, 3, 64))
     kv, conv = cache.state()
     assert cache.h is None and cache.rings is None
     assert kv.shape == (2, 8, BS, 256) and conv.shape == (7, 3, 2, 64)
@@ -185,15 +183,12 @@ def test_tails_are_a_kind_of_the_hybrid_cache_without_recurrent_rows():
     assert float(cache.conv[0, 0, 0, 0]) == 2.0
     with pytest.raises(ValueError, match="holds"):
         cache.update([kv])
-    # the kinds stay tied where no count of the tails' own is given
-    both = HybridStateCache(128, 8, BS, slots=3, window=0, window_layers=0,
-                            ssm_layers=2, d_inner=16, d_state=4, d_conv=4,
-                            dtype="float32")
+    # recurrent rows and tails beside each other, each as it is given
+    both = HybridStateCache(128, 8, BS, slots=3, dtype="float32",
+                            recurrent=(2, (4, 16)), tails=(2, 4, 16))
     assert [a.shape for a in both.state()[1:]] == [(2, 3, 4, 16),
                                                    (2, 3, 3, 16)]
-    none = HybridStateCache(128, 8, BS, slots=3, window=0, window_layers=0,
-                            ssm_layers=0, d_inner=0, d_state=0, d_conv=0,
-                            dtype="float32")
+    none = HybridStateCache(128, 8, BS, slots=3, dtype="float32")
     assert len(none.state()) == 1 \
         and "recurrent_state_bytes" not in none.snapshot()
 

@@ -409,7 +409,7 @@ def _sample_top_k_then_select(logits, seeds, steps, temperature, top_k):
     sort every row's top slice, then choose — the plain reference
     :func:`_sample` is held to, token for token."""
     import jax
-    from paddle_tpu.decode.model import TOPK_MAX, _hash_uniform
+    from paddle_tpu.decode.adapter import TOPK_MAX, _hash_uniform
     kk = min(TOPK_MAX, logits.shape[1])
     vals, idx = jax.lax.top_k(logits.astype(jnp.float32), kk)
     lane = jnp.arange(kk, dtype=jnp.int32)[None, :]
@@ -464,7 +464,7 @@ def test_sample_is_the_top_k_then_select_reference_token_for_token(case):
     vocabulary below ``TOPK_MAX``, bf16 logits and ``top_k`` 0 / 1 / 6 /
     beyond ``TOPK_MAX``, at three token indices."""
     import jax
-    from paddle_tpu.decode.model import _sample
+    from paddle_tpu.decode.adapter import sample as _sample
     S, V, dtype, temps, topks, prepare = _SAMPLE_CASES[case]
     rng = np.random.RandomState(len(case))
     logits = (rng.randn(S, V) * 3).astype(np.float32)
@@ -753,7 +753,7 @@ def test_no_stream_is_woken_between_a_read_and_the_next_dispatch(
     token, FIN in order; and the tokens are the model's own — each the
     sampler's choice (by seed and index) from logits that equal the full
     re-forward's — so they are what the order before this one streamed."""
-    from paddle_tpu.decode.model import _sample
+    from paddle_tpu.decode.adapter import sample as _sample
     lm, params, eng = _engine("fan_many_" + ("s" if sampled else "g"),
                               capture_logits=True)
     try:

@@ -30,7 +30,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.decode import LMConfig, PagedKVCache, TransformerLM
-from paddle_tpu.decode.model import _param_names, _sample
+from paddle_tpu.decode.adapter import sample as _sample
+from paddle_tpu.decode.model import _param_names
 from paddle_tpu.kernels import attention as AK
 
 from hlo_text import sorts_outside_a_branch

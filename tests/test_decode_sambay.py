@@ -271,4 +271,4 @@ def test_what_the_engine_and_the_beam_session_refuse_for_it(model):
     with pytest.raises(ValueError, match="slot count"):
         m.make_cache(NB, BS, "float32")
     with pytest.raises(ValueError, match="not whole blocks"):
-        HybridStateCache(128, NB, BS, 2, 24, 2, 3, 512, 16, 4)
+        HybridStateCache(128, NB, BS, 2, rings=(2, 24))

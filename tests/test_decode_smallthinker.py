@@ -387,8 +387,14 @@ def test_what_the_engine_and_the_beam_session_refuse_for_it(model):
     (2, 0, ["kv", "rings"]), (0, 0, ["kv"])])
 def test_a_state_kind_with_no_layer_has_no_array(window_layers, ssm_layers,
                                                  held):
-    cache = HybridStateCache(32, 8, 16, 2, 32, window_layers, ssm_layers, 64,
-                             16, 4, dtype="float32", kv_layers=2)
+    kinds = {}
+    if window_layers:
+        kinds["rings"] = (window_layers, 32)
+    if ssm_layers:
+        kinds.update(recurrent=(ssm_layers, (16, 64)),
+                     tails=(ssm_layers, 4, 64))
+    cache = HybridStateCache(32, 8, 16, 2, dtype="float32", kv_layers=2,
+                             **kinds)
     state = cache.state()
     assert len(state) == len(held)
     assert [getattr(cache, k) is not None

@@ -7,7 +7,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from paddle_tpu.decode.falcon_h1 import rotary
+from paddle_tpu.decode.adapter import rotary
 from paddle_tpu.kernels import diffattn as da
 from paddle_tpu.kernels import gqa
 from paddle_tpu.observability import stats

@@ -82,6 +82,7 @@ MODULES = [
     # batching, streaming server/client): frozen so the generative
     # serving API + wire tags drift loudly
     "paddle_tpu.decode",
+    "paddle_tpu.decode.adapter",
     "paddle_tpu.decode.cache",
     "paddle_tpu.decode.model",
     "paddle_tpu.decode.mla",

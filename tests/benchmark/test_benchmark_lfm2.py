@@ -68,12 +68,13 @@ def served(driver):
     server.stop()
 
 
-def test_the_manifest_is_sound_with_the_new_cell_and_has_room():
+def test_the_manifest_is_sound_and_names_the_cell_and_its_configuration_once():
+    # (how much room the lists have is the contract's to say, and
+    # test_benchmark_manifest.py says it; where in them these two lie, no one's)
     assert harness.check_manifest(REPO, MANIFEST) == []
-    assert len(MANIFEST["workloads"]) == 9
-    assert len(MANIFEST["per_layer"]) <= 105
-    assert MANIFEST["workloads"][-1]["name"] == CELL
-    assert MANIFEST["configs"][-1]["name"] == "lfm2-24b-a2b-pp5s0"
+    assert [w["name"] for w in MANIFEST["workloads"]].count(CELL) == 1
+    assert [c["name"] for c in MANIFEST["configs"]].count(
+        "lfm2-24b-a2b-pp5s0") == 1
 
 
 def test_the_new_cell_is_the_one_the_issue_names():
@@ -134,7 +135,14 @@ def test_the_new_cell_is_the_one_the_issue_names():
     names = {m["name"] for m in cell.per_layer}
     family = {m["name"] for m in MANIFEST["per_layer"] if "workloads" not in m
               and m["moves"] in {e["name"] for e in cell.end_to_end}}
-    assert "decode_step_ms.served" in family and len(family) == 14
+    # the fourteen it was accepted with, by name: a later PR may add to them
+    assert family >= {
+        "decode_step_ms.served", "prefill_ms.served", "step_host_ms.served",
+        "step_emit_ms.served", "tokens_per_decode_step.served",
+        "device_idle_share.served", "idle_engine_host_share.served",
+        "idle_no_work_share.served", "top_device_op_share.served",
+        "hbm_peak_gb.served", "hbm_temp_gb.served", "warm_cache_hits",
+        "window_compiles", "program_build_s"}
     own = {n + ".served_lfm2" for n in (
         "moe_share", "conv_mixer_share", "attn_share", "moe_prefill_roofline",
         "moe_step_roofline", "gqa64_prefill_attn_roofline",

@@ -189,9 +189,9 @@ def test_the_partition_is_worked_out_and_printed_once_for_a_trace(monkeypatch):
     cell = harness.Cell(REPO, MANIFEST, "lm_chat_open")
     out = io.StringIO()
     with redirect_stdout(out):
-        host = cell.reader("idle_engine_host_share.tbt")({})
+        host = cell.reader("idle_engine_host_share.served")({})
         n_calls = len(calls)
-        no_work = cell.reader("idle_no_work_share.tbt")({})
+        no_work = cell.reader("idle_no_work_share.served")({})
     # idle [0,10) host, [60,70) wait, [70,80) host, [80,100) no_work
     assert (host, no_work) == (20.0, 20.0)
     assert len(calls) == n_calls > 0
@@ -218,7 +218,8 @@ PR26_CHAT_MODULES = {
     "jit_fn_decode_lm_step(17901050097464394881)":
         {"launches": 346.0, "seconds": 2.6664130890000006},
 }
-PROGRAM_METRICS = [("lm_chat_open", "decode_step_ms.tbt", "prefill_ms.ttft"),
+PROGRAM_METRICS = [("lm_chat_open", "decode_step_ms.served",
+                    "prefill_ms.served"),
                    ("lm_batch_sat", "decode_step_ms.served",
                     "prefill_ms.served")]
 
@@ -292,11 +293,11 @@ def test_the_bench_time_line_names_every_phase_and_the_slow_readers():
     phases.mark("lead_in_and_window", at=184.5)
     phases.within("stop_trace", 2.3)
     phases.mark("drain", at=186.0)
-    phases.within("reader:step_host_ms.tbt", 1.26)
+    phases.within("reader:step_host_ms.served", 1.26)
     line = phases.line()
     assert line.startswith("bench time: ")
     assert ("setup=33.5 lead_in_and_window=51.0 drain=1.5 | inside those: "
-            "stop_trace=2.3 reader:step_host_ms.tbt=1.3") in line
+            "stop_trace=2.3 reader:step_host_ms.served=1.3") in line
     assert " | " not in harness.Phases(0.0).line()
 
 
@@ -312,3 +313,40 @@ def test_end_to_end_value_hands_on_a_number_the_driver_took_and_invents_none():
     assert mod.read(ctx, metric="tbt_p50_ms") is None
     assert mod.read(ctx, metric="ttft_p50_ms") is None
     assert mod.read({}, metric="served_tokens_per_s") is None
+
+
+@pytest.mark.parametrize("metric, taken", [("ttft_p50_ms.open", "ttft_p50_ms"),
+                                           ("tbt_p95_ms.open", "tbt_p95_ms"),
+                                           ("tbt_p50_ms.open", "tbt_p50_ms")])
+def test_the_open_loop_cell_records_its_latencies_and_is_judged_by_its_rate(
+        metric, taken):
+    """``lm_chat_open`` since PR 49: the first-token wait and the gaps are
+    read from the run's own numbers under names of their own, move the one
+    end-to-end metric the cell keeps beside ``setup_s``, and are left out of
+    the line where the driver took none."""
+    cell = harness.Cell(REPO, MANIFEST, "lm_chat_open")
+    assert {m["name"] for m in cell.end_to_end} \
+        == {"served_tokens_per_s", "setup_s"}
+    (entry,) = [m for m in cell.per_layer if m["name"] == metric]
+    assert entry["moves"] == "served_tokens_per_s"
+    assert entry["workloads"] == ["lm_chat_open"]
+    ctx = {"end_to_end": {"ttft_p50_ms": 11.5, "tbt_p95_ms": 7.25,
+                          "tbt_p50_ms": 4.75, "served_tokens_per_s": 1140.0}}
+    assert cell.reader(metric)(ctx) == ctx["end_to_end"][taken]
+    assert cell.reader(metric)({"end_to_end": {"served_tokens_per_s": 1.0}}) \
+        is None
+
+
+@pytest.mark.parametrize("metric, q", [("tbt_p90_ms.open", 0.90),
+                                       ("tbt_p99_ms.open", 0.99)])
+def test_the_gaps_either_side_of_the_prefill_edge_are_read_from_the_samples(
+        metric, q):
+    """1,000 gaps of 5 ms and 50 that held a prefill (10 ms), 4.8% as in
+    ``lm_chat_open``: the 95th percentile lies on the edge between the two,
+    the 90th and the 99th well inside one kind each."""
+    cell = harness.Cell(REPO, MANIFEST, "lm_chat_open")
+    gaps = [5.0] * 1000 + [10.0] * 50
+    assert cell.reader(metric)({"tbt_ms": gaps}) \
+        == harness.percentile(gaps, q) == (5.0 if q < 0.95 else 10.0)
+    assert cell.reader(metric)({"tbt_ms": []}) is None
+

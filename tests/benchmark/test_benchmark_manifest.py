@@ -1,13 +1,27 @@
 """BENCHMARK.json and the files it names: the contract's limits that need no
 chip, every cell's configuration, mix and metric files found by name, and a
-configuration, a mix, a per-layer metric and a cell added as new files with
-no existing file edited."""
+configuration, a mix, per-layer metrics and a cell added as new files with
+no existing file edited — under ``benchmark/`` and under ``tests/benchmark/``.
+
+The rule for a configuration's test module, which the guard at the end of this
+file holds every ``test_benchmark_*.py`` to:
+it asserts on its own entries, found by name; it never indexes ``configs``,
+``workloads``, ``per_layer`` or ``end_to_end`` by position, and never compares
+their length, or a whole list of them, with a literal; the contract's limits
+(128 entries, 24 cells, a quarter of the cells on four chips) are asserted in
+this file, and no other module needs them."""
+import ast
+import glob
 import hashlib
+import importlib.util
+import inspect
+import itertools
 import json
 import os
 import shutil
 import sys
 import time
+import traceback
 
 import pytest
 
@@ -42,8 +56,9 @@ def test_manifest_meets_the_contract():
 
 def test_check_manifest_catches_a_bad_name_and_a_bad_unit():
     bad = json.loads(json.dumps(MANIFEST))
-    bad["workloads"][0]["name"] = "has space"
-    bad["end_to_end"][0]["unit"] = "tokens per second"
+    (cell,) = [w for w in bad["workloads"] if w["name"] == min(CELLS)]
+    (metric,) = [m for m in bad["end_to_end"] if m["name"] == "setup_s"]
+    cell["name"], metric["unit"] = "has space", "tokens per second"
     faults = harness.check_manifest(REPO, bad)
     assert any("has space" in f for f in faults)
     assert any("tokens per second" in f for f in faults)
@@ -96,7 +111,8 @@ def test_a_cell_finds_a_metrics_file_its_reader_and_its_args(metric, workload):
 
 
 def test_every_per_layer_entry_has_its_file_and_every_file_its_entry():
-    assert len(PER_LAYER) <= 128                # the contract's limit
+    assert len(PER_LAYER) <= 128                # the contract's limits
+    assert len(CELLS) <= 24
     files = [f for f in os.listdir(os.path.join(REPO, "benchmark", "metrics"))
              if f.endswith(".json")]
     assert sorted(files) == sorted(n + ".json" for n in PER_LAYER)
@@ -116,10 +132,9 @@ def test_an_entry_with_no_list_is_read_where_the_metric_it_moves_is(metric):
 
 def test_check_manifest_faults_a_copy_and_a_file_that_repeats_its_entry(tmp_path):
     root = str(tmp_path)
-    shutil.copytree(os.path.join(REPO, "benchmark"), os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
+    _copy_of(root, "benchmark")
     metrics = os.path.join(root, "benchmark", "metrics")
-    # (the same reader moving ANOTHER end-to-end metric, as decode_step_ms.tbt
+    # (the same reader moving ANOTHER end-to-end metric, as decode_step_ms.tbt50
     # beside .served, is a metric of its own: the manifest as it stands passes)
     assert harness.check_manifest(root, MANIFEST) == []
     # a second entry with the reader, the args and the 'moves' of one that is
@@ -209,64 +224,136 @@ def _digests(root):
     return out
 
 
-def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
-    root = str(tmp_path)
-    shutil.copytree(os.path.join(REPO, "benchmark"), os.path.join(root, "benchmark"),
-                    ignore=shutil.ignore_patterns("__pycache__"))
-    before = _digests(os.path.join(root, "benchmark"))
-    bench = os.path.join(root, "benchmark")
+# What a later ``model_config`` PR brings, shaped as the accepted ones were: a
+# configuration, a mix, a cell, a reader and a counts file of its own, and
+# twelve per-layer entries that its cell alone reads — {name: (reader, args)};
+# what else an entry says follows from its reader.
+NEW_CONFIG, NEW_MIX, NEW_CELL = "tlm-new", "new_mix", "lm_new_cell"
+NEW_READER, NEW_COUNTS = "benchmark/metrics/new_metric.py", \
+    "benchmark/kernel_counts_new.py"
+_IN_STEPS = {"program": "^jit_fn_decode_lm_step\\b",
+             "span": "decode::step\\.observe", "counts": NEW_COUNTS}
+_IN_PREFILLS = {"program": "^jit_fn_decode_lm_prefill_",
+                "span": "decode::prefill\\.observe", "counts": NEW_COUNTS}
+_ROOFLINE, _SCOPES, _RATIO = ("benchmark/metrics/" + f for f in (
+    "kernel_roofline.py", "scope_share.py", "counter_ratio.py"))
+NEW_METRICS = {
+    "steps_twice.served_new": (NEW_READER, {"scale": 2.0}),
+    "mixer_share.served_new": (_SCOPES, {"scopes": ["/new_mixer/"]}),
+    "experts_share.served_new": (_SCOPES, {"scopes": ["/new_experts/"]}),
+    "attn_share.served_new": (_SCOPES, {"scopes": ["/new_attn/"]}),
+    "experts_prefill_roofline.served_new": (_ROOFLINE, dict(
+        _IN_PREFILLS, kernel="^new_grouped", count="experts_prefill")),
+    "experts_step_roofline.served_new": (_ROOFLINE, dict(
+        _IN_STEPS, kernel="^new_grouped", count="experts_step")),
+    "prefill_attn_roofline.served_new": (_ROOFLINE, dict(
+        _IN_PREFILLS, kernel="^new_flash_fwd", count="prefill_attn")),
+    "decode_attn_roofline.served_new": (_ROOFLINE, dict(
+        _IN_STEPS, kernel="^new_paged_attn", count="decode_attn")),
+    "expert_load_max_over_mean.served_new": (_RATIO, {
+        "num": ["step_new_load_max_sum"], "den": ["step_new_assignments"],
+        "times_config": "n_layer"}),
+    "experts_touched_per_step.served_new": (_RATIO, {
+        "num": ["step_new_touched"], "den": ["steps"]}),
+    "tile_pad_share.served_new": (_RATIO, {
+        "num": ["prefill_new_pad_rows"], "den": ["prefill_new_rows"],
+        "scale": 100.0}),
+    "state_live_share.served_new": (_RATIO, {
+        "num": ["step_new_rows_live"], "den": ["step_new_rows_held"],
+        "scale": 100.0}),
+}
+_SAYS = {      # unit, better, source, layer: the accepted entries' own words
+    NEW_READER: ("count", "higher", "program_counter", "decode plane"),
+    _RATIO: ("ratio", "lower", "program_counter", "decode plane"),
+    _SCOPES: ("%", "higher", "device_trace", "kernels and XLA ops"),
+    _ROOFLINE: ("%", "higher", "device_trace", "kernels and XLA ops")}
 
+
+def _copy_of(root, *dirs):
+    for d in dirs:
+        shutil.copytree(os.path.join(REPO, d), os.path.join(root, d),
+                        ignore=shutil.ignore_patterns("__pycache__"))
+
+
+def _a_later_prs_files(root):
+    """Writes what that PR adds into ``root``'s copy of ``benchmark/`` — 16
+    files, none that was there — and returns its entries of the manifest: the
+    configuration, the cell and the per-layer metrics."""
+    bench = os.path.join(root, "benchmark")
     with open(os.path.join(bench, "configs", "tlm-gpt1w.json")) as f:
         cfg = json.load(f)
-    cfg.update(name="tlm-new", max_seq_len=256)
-    with open(os.path.join(bench, "configs", "tlm-new.json"), "w") as f:
+    cfg.update(name=NEW_CONFIG, max_seq_len=256)
+    with open(os.path.join(bench, "configs", NEW_CONFIG + ".json"), "w") as f:
         json.dump(cfg, f)
     with open(os.path.join(bench, "traffic", "batch_sat.json")) as f:
         mix = json.load(f)
     mix.update(callers=40)
     mix["prompt_tokens"]["max"] = 128
     mix["engine"]["prefill_buckets"] = [64, 128]
-    with open(os.path.join(bench, "traffic", "new_mix.json"), "w") as f:
+    with open(os.path.join(bench, "traffic", NEW_MIX + ".json"), "w") as f:
         json.dump(mix, f)
-    with open(os.path.join(bench, "metrics", "new_metric.py"), "w") as f:
-        f.write("def read(ctx, scale):\n    return scale * ctx['decodez']['steps']\n")
-    entry = {"name": "steps_twice.served", "unit": "count", "better": "higher",
-             "source": "program_counter", "layer": "decode plane",
-             "moves": "served_tokens_per_s", "workloads": ["lm_new_cell"]}
-    with open(os.path.join(bench, "metrics", "steps_twice.served.json"), "w") as f:
-        json.dump(dict(what="a new reader", args={"scale": 2.0},
-                       reader="benchmark/metrics/new_metric.py"), f)
+    with open(os.path.join(root, NEW_READER), "w") as f:
+        f.write("def read(ctx, scale):\n"
+                "    return scale * ctx['decodez']['steps']\n")
+    counts = sorted(a["count"] for _, a in NEW_METRICS.values()
+                    if "count" in a)
+    with open(os.path.join(root, NEW_COUNTS), "w") as f:
+        f.write("def _nothing(cfg, w):\n    return 0.0, 0.0\n\n\n"
+                f"COUNTS = dict.fromkeys({counts!r}, _nothing)\n")
+    per_layer = []
+    for name, (reader, args) in NEW_METRICS.items():
+        with open(os.path.join(bench, "metrics", name + ".json"), "w") as f:
+            json.dump(dict(what="a new metric", reader=reader, args=args), f)
+        unit, better, source, layer = _SAYS[reader]
+        per_layer.append({
+            "name": name, "unit": unit, "better": better, "source": source,
+            "layer": layer, "moves": "served_tokens_per_s",
+            "workloads": [NEW_CELL]})
+    config = {"name": NEW_CONFIG, "source": "https://example.org/config.json",
+              "file": f"benchmark/configs/{NEW_CONFIG}.json", "reduced": [],
+              "why": "a configuration added by a later PR"}
+    cell = {"name": NEW_CELL, "config": NEW_CONFIG, "traffic": NEW_MIX,
+            "chips": 1, "why": "a cell added by a later PR"}
+    return config, cell, per_layer
 
-    # a ninth cell of a configuration that is there: one entry of workloads
-    # and its name under served_tokens_per_s, no other line of the manifest
-    ninth = json.loads(json.dumps(MANIFEST))
-    ninth["workloads"].append({
-        "name": "lm_ninth", "config": "tlm-gpt1w", "traffic": "new_mix",
-        "chips": 1, "why": "a cell added by a later PR"})
-    for m in ninth["end_to_end"]:
+
+def _appended(config, cell, per_layer):
+    """``BENCHMARK.json`` as that PR leaves it: its entries at the END of
+    ``configs``, ``workloads`` and ``per_layer``, and the cell's name under
+    ``served_tokens_per_s`` — no other line of the manifest."""
+    manifest = json.loads(json.dumps(MANIFEST))
+    if config is not None:
+        manifest["configs"].append(config)
+    manifest["workloads"].append(cell)
+    for m in manifest["end_to_end"]:
         if m["name"] == "served_tokens_per_s":
-            m["workloads"] = m["workloads"] + ["lm_ninth"]
+            m["workloads"] = m["workloads"] + [cell["name"]]
+    manifest["per_layer"] += per_layer
+    return manifest
+
+
+def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
+    root = str(tmp_path)
+    _copy_of(root, "benchmark")
+    before = _digests(os.path.join(root, "benchmark"))
+    config, new_cell, entries = _a_later_prs_files(root)
+
+    # one more cell of a configuration that is there: one entry of workloads
+    # and its name under served_tokens_per_s, no other line of the manifest
+    ninth = _appended(None, {
+        "name": "lm_ninth", "config": "tlm-gpt1w", "traffic": NEW_MIX,
+        "chips": 1, "why": "a cell added by a later PR"}, [])
     assert harness.check_manifest(root, ninth) == []
     assert {m["name"] for m in harness.Cell(root, ninth, "lm_ninth").per_layer} \
         == SERVED_FAMILY
 
-    manifest = json.loads(json.dumps(MANIFEST))
-    manifest["configs"].append({
-        "name": "tlm-new", "source": "https://example.org/config.json",
-        "file": "benchmark/configs/tlm-new.json", "reduced": [],
-        "why": "a configuration added by a later PR"})
-    manifest["workloads"].append({
-        "name": "lm_new_cell", "config": "tlm-new", "traffic": "new_mix",
-        "chips": 1, "why": "a cell added by a later PR"})
-    for m in manifest["end_to_end"]:
-        if m["name"] == "served_tokens_per_s":
-            m["workloads"] = m["workloads"] + ["lm_new_cell"]
-    # a ninth cell listed under served_tokens_per_s ALONE — no per-layer
-    # entry edited or added, no file under metrics/ touched — reads the
-    # serve family of twelve and the two compile-cache counts
+    # a cell of a new configuration listed under served_tokens_per_s ALONE —
+    # no per-layer entry edited or added, no file under metrics/ read — reads
+    # the serve family of twelve and the two compile-cache counts
+    manifest = _appended(config, new_cell, [])
     assert harness.check_manifest(root, manifest) == []
     joined = {m["name"] for m in
-              harness.Cell(root, manifest, "lm_new_cell").per_layer}
+              harness.Cell(root, manifest, NEW_CELL).per_layer}
     assert joined == SERVED_FAMILY
     assert joined >= {
         "decode_step_ms.served", "prefill_ms.served", "step_host_ms.served",
@@ -277,13 +364,13 @@ def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
         "program_build_s"}
     assert manifest["per_layer"] == MANIFEST["per_layer"]
     # a metric of its own is one more entry and one more file
-    manifest["per_layer"].append(entry)
+    manifest = _appended(config, new_cell, entries)
     assert harness.check_manifest(root, manifest) == []
 
-    cell = harness.Cell(root, manifest, "lm_new_cell")
+    cell = harness.Cell(root, manifest, NEW_CELL)
     assert cell.config["max_seq_len"] == 256 and cell.mix["callers"] == 40
     cell.driver().validate(cell, float(manifest["run_seconds"]))
-    assert [m["name"] for m in cell.per_layer if m["name"] == entry["name"]]
+    assert {m["name"] for m in cell.per_layer} == joined | set(NEW_METRICS)
     # (the family's program_build_s reads the program's counter: importing
     # the program is not a reader's time, so it is done before the clock)
     harness.program_counters()
@@ -293,14 +380,15 @@ def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
         "compile": {"in_window": 0, "cache_hits_in_setup": 3}}, phases)
     assert [n for n, _ in phases.phases] == ["readers"]
     assert not phases.inside                    # no reader slow enough to name
-    assert values["steps_twice.served"] == 42.0
+    assert values["steps_twice.served_new"] == 42.0
     assert values["warm_cache_hits"] == 3.0
     # the cells that were there still load, and no file that was there changed
     for w in CELLS:
         harness.Cell(root, manifest, w)
     after = _digests(os.path.join(root, "benchmark"))
     assert {k: after[k] for k in before} == before
-    assert len(after) == len(before) + 4
+    # the configuration, the mix, the reader, the counts; a file an entry
+    assert len(after) == len(before) + 4 + len(entries)
 
 
 def test_select_metrics_leaves_out_what_has_no_value():
@@ -332,3 +420,194 @@ def test_percentile_and_spread_are_the_stated_rules():
     assert harness.percentile([7.0], 0.95) == 7.0
     # statistics.quantiles(n=4) of 1..6: quartiles 1.75 and 5.25, median 3.5
     assert harness.spread([1, 2, 3, 4, 5, 6]) == pytest.approx(1.0)
+
+
+# ---------------------------------------------------------------------------
+# the guard: the tests under tests/benchmark/ take a new cell as new files and
+# entries too (the rule is in this file's first lines)
+# ---------------------------------------------------------------------------
+HERE = os.path.relpath(os.path.dirname(os.path.abspath(__file__)), REPO)
+MODULES = sorted(os.path.basename(p) for p in
+                 glob.glob(os.path.join(REPO, HERE, "test_benchmark_*.py")))
+
+
+@pytest.fixture(scope="module")
+def later_checkout(tmp_path_factory):
+    """The checkout that later PR leaves: every directory of ``paths`` as it
+    is here, the PR's files beside them, and ``BENCHMARK.json`` with the PR's
+    entries at the end of its lists.  (root, manifest)."""
+    root = str(tmp_path_factory.mktemp("later_pr"))
+    _copy_of(root, *MANIFEST["paths"])
+    manifest = _appended(*_a_later_prs_files(root))
+    with open(os.path.join(root, harness.MANIFEST), "w") as f:
+        json.dump(manifest, f, indent=1)
+    return root, manifest
+
+
+def test_a_later_prs_entries_fit_and_change_no_accepted_cells_metrics(
+        later_checkout):
+    root, manifest = later_checkout
+    assert harness.check_manifest(root, manifest) == []
+    assert harness.load_manifest(root) == manifest
+    # the next served configuration, at twelve entries, still fits
+    assert len(NEW_METRICS) == 12
+    assert len(manifest["per_layer"]) == len(PER_LAYER) + 12 <= 128
+    assert [w["name"] for w in manifest["workloads"]] == CELLS + [NEW_CELL]
+    assert len(manifest["workloads"]) <= 24
+    for w in CELLS:     # (the rate's own entry has one more name in its list)
+        (e2e, per_layer), (was_e2e, was) = (
+            harness.cell_metrics(m, w) for m in (manifest, MANIFEST))
+        assert per_layer == was and [m["name"] for m in e2e] \
+            == [m["name"] for m in was_e2e], w
+    # and its cell reads the list-less served family through its name under
+    # served_tokens_per_s alone, beside its own twelve
+    assert {m["name"] for m in harness.cell_metrics(manifest, NEW_CELL)[1]} \
+        == SERVED_FAMILY | set(NEW_METRICS)
+
+
+def _manifest_readers(source):
+    """The test functions in a module's source that read the manifest: the
+    names of those that mention ``MANIFEST``, ``load_manifest`` or anything
+    the module derives from them at its top level (a list of cells, a helper
+    that loads one), in their body or their decorators."""
+    tree, derived = ast.parse(source), {"MANIFEST", "load_manifest"}
+
+    def mentions(node):
+        return {n.id for n in ast.walk(node) if isinstance(n, ast.Name)} \
+            | {n.attr for n in ast.walk(node) if isinstance(n, ast.Attribute)}
+
+    def defined(node):
+        if isinstance(node, ast.FunctionDef):
+            return {node.name}
+        if isinstance(node, ast.Assign):
+            return {n.id for t in node.targets for n in ast.walk(t)
+                    if isinstance(n, ast.Name)}
+        return set()
+
+    while True:
+        more = {name for node in tree.body if mentions(node) & derived
+                for name in defined(node)} - derived
+        if not more:
+            break
+        derived |= more
+    return [node.name for node in tree.body
+            if isinstance(node, ast.FunctionDef)
+            and node.name.startswith("test_") and node.name in derived]
+
+
+def _calls(fn):
+    """The calls pytest makes of a test function whose every argument a
+    ``parametrize`` mark supplies — one, of nothing, where it takes none;
+    None where it takes a fixture."""
+    calls = [{}]
+    for mark in getattr(fn, "pytestmark", []):
+        if mark.name != "parametrize":
+            continue
+        names, rows = mark.args
+        if isinstance(names, str):
+            names = [n.strip() for n in names.split(",")]
+        rows = [r.values if isinstance(r, type(pytest.param()))
+                else r if len(names) > 1 else (r,) for r in rows]
+        calls = [dict(c, **dict(zip(names, r)))
+                 for c, r in itertools.product(calls, rows)]
+    if calls and set(inspect.signature(fn).parameters) != set(calls[0]):
+        return None
+    return calls
+
+
+def _failures(path):
+    """Imports the test module at ``path`` — its ``REPO`` is the checkout it
+    lies in, so its ``MANIFEST`` and every list it derives at import are that
+    checkout's — and makes pytest's calls of its test functions that read the
+    manifest and take no fixture.  Functions that need an engine, a trace, a
+    chip or a temporary directory are not held to anything here, which in
+    this module leaves the guard itself out; of the others none is skipped.
+    Returns (what failed with their failing lines, how many calls were
+    made)."""
+    from benchmark.metrics import program_spans
+    with open(path) as f:
+        names = _manifest_readers(f.read())
+    failed, made, sys_path = [], 0, list(sys.path)
+    # (the readers import this process's benchmark package, which knows its
+    # checkout by program_spans.ROOT: a counts file is looked for under it)
+    root, program_spans.ROOT = program_spans.ROOT, os.path.dirname(
+        os.path.dirname(os.path.dirname(path)))
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "guarded_" + os.path.basename(path)[:-3], path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        assert not names or mod.REPO == program_spans.ROOT
+        for name in names:
+            fn = getattr(mod, name)
+            for kwargs in _calls(fn) or []:
+                made += 1
+                try:
+                    fn(**kwargs)
+                except Exception as e:      # noqa: BLE001 - each is reported
+                    at = [fr for fr in traceback.extract_tb(e.__traceback__)
+                          if fr.filename == path][-1]
+                    failed.append(f"{name}{list(kwargs.values())}"
+                                  f" line {at.lineno}: {at.line}"
+                                  f" -> {type(e).__name__}: {e}"[:600])
+    finally:
+        sys.path[:], program_spans.ROOT = sys_path, root
+    return failed, made
+
+
+@pytest.mark.parametrize("module", MODULES)
+def test_a_module_takes_a_configuration_and_a_cell_appended_by_a_later_pr(
+        module, later_checkout):
+    """One case a module under ``tests/benchmark/``, found by a glob: the
+    next configuration's module is held to the rule the day it is added."""
+    root, _ = later_checkout
+    failed, _ = _failures(os.path.join(root, HERE, module))
+    assert failed == [], "\n".join(
+        [f"{module} pins the manifest's size or order: with a configuration, "
+         "a cell and twelve per-layer entries appended,"] + failed)
+
+
+def test_the_guard_fails_a_module_that_pins_where_the_lists_end(
+        later_checkout):
+    """The guard's own control: the pins of the module that stopped PR 48,
+    true of the manifest as it is here, fail in the later checkout, each named
+    with its line; what reads by name passes, and what takes a fixture or
+    reads no manifest is not called."""
+    root, _ = later_checkout
+    # (where the lists end today, read here for once, to plant pins on it)
+    last_cell, last_config = CELLS[-1], MANIFEST["configs"][-1]["name"]
+    path = os.path.join(root, HERE, "pinning.py")
+    with open(path, "w") as f:
+        f.write(f'''import os
+from benchmark import harness
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+MANIFEST = harness.load_manifest(REPO)
+NAMES = [w["name"] for w in MANIFEST["workloads"]]
+def _family():
+    return [m for m in MANIFEST["per_layer"] if "workloads" not in m]
+def test_by_name():
+    assert NAMES.count({last_cell!r}) == 1 and len(NAMES) <= 24
+def test_the_count():
+    assert len(NAMES) == {len(CELLS)}
+def test_the_last_cell():
+    assert MANIFEST["workloads"][-1]["name"] == {last_cell!r}
+def test_the_last_configuration():
+    assert MANIFEST["configs"][-1]["name"] == {last_config!r}
+def test_room_for_three():
+    assert len(MANIFEST["per_layer"]) <= {len(PER_LAYER) + 3}
+def test_a_family_by_a_helper():
+    assert len(_family()) == {len(LISTLESS)}
+def test_with_a_fixture(tmp_path):
+    assert MANIFEST["workloads"][-1]["name"] == {last_cell!r}
+def test_of_something_else():
+    assert os.path.basename(REPO) == "repo"
+''')
+    try:
+        failed, made = _failures(path)
+    finally:
+        os.remove(path)
+    assert made == 6
+    assert [f.split("[")[0] for f in failed] == [
+        "test_the_count", "test_the_last_cell", "test_the_last_configuration",
+        "test_room_for_three"]
+    assert 'MANIFEST["workloads"][-1]["name"] ==' in failed[1]

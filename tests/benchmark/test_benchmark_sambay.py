@@ -84,8 +84,8 @@ def test_the_new_cell_is_the_one_the_issue_names():
     # swings with a pause of the machine by more than any bound here (PR 43)
     # and is recorded per layer under another name
     assert {m["name"] for m in cell.end_to_end} == {"tbt_p50_ms", "setup_s"}
-    rate = [m for m in MANIFEST["end_to_end"]
-            if m["name"] == "served_tokens_per_s"][0]
+    (rate,) = [m for m in MANIFEST["end_to_end"]
+               if m["name"] == "served_tokens_per_s"]
     assert CELL not in rate["workloads"]
     names = {m["name"] for m in cell.per_layer}
     # the decode-plane and device family under its one name, joined through

@@ -45,7 +45,7 @@ package is that state plane, built on the repo's own primitives:
   among them ``step_live_blocks`` of ``step_table_blocks`` on ``/decodez``,
   the share of the tables handed to the decode steps' attention kernels that
   their walks fetched.
-- **Six models behind the one engine**: :mod:`model` (the repo's LM block:
+- **Seven models behind the one engine**: :mod:`model` (the repo's LM block:
   K and V of every layer paged, a suffix prefill, so the one model that
   ``supports`` prefix cache, overcommit and beams); :mod:`mla` (DeepSeek-V2:
   latent attention over ONE latent pool, YaRN, routed experts beside shared
@@ -56,7 +56,11 @@ package is that state plane, built on the repo's own primitives:
   :mod:`smallthinker` (SmallThinker: periods of one full and three window
   layers, 64 ReLU experts a layer, the router ahead of the attention);
   :mod:`lfm2` (LFM2: gated short convolutions and 64-wide-head attention,
-  sigmoid-routed experts).  Each module's docstring is its model's.
+  sigmoid-routed experts); :mod:`kimi_linear` (Kimi-Linear: three gated
+  delta-rule layers — a decay a channel, a recurrent row and a convolution
+  tail a slot — to one position-free latent-attention layer, a latent row a
+  token beside them in ONE cache, and a SHARE of the router's experts).
+  Each module's docstring is its model's.
 - **On-device sampling** (:func:`adapter.sample`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
   top-k / temperature inside the decode dispatch; incremental beam
@@ -97,6 +101,7 @@ from .falcon_h1 import FalconH1Config, FalconH1LM  # noqa: F401
 from .smallthinker import (SmallThinkerConfig,  # noqa: F401
                            SmallThinkerLM)
 from .lfm2 import LFM2Config, LFM2LM  # noqa: F401
+from .kimi_linear import KimiLinearConfig, KimiLinearLM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -112,7 +117,7 @@ __all__ = [
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
     "MLAConfig", "MLATransformerLM", "SambaYConfig", "SambaYLM",
     "FalconH1Config", "FalconH1LM", "SmallThinkerConfig", "SmallThinkerLM",
-    "LFM2Config", "LFM2LM",
+    "LFM2Config", "LFM2LM", "KimiLinearConfig", "KimiLinearLM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

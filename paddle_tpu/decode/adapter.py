@@ -1,7 +1,8 @@
 """What the served models have in common: the model protocol as a class, the
 layer math they share, the paged pool's addressing and the observers' common
 series.  A model module (:mod:`model`, :mod:`mla`, :mod:`sambay`,
-:mod:`falcon_h1`, :mod:`smallthinker`, :mod:`lfm2`) brings its config, its
+:mod:`falcon_h1`, :mod:`smallthinker`, :mod:`lfm2`, :mod:`kimi_linear`) brings
+its config, its
 ``param_shapes``, its layers, its two programs and the counters that are its
 own; it imports this module and no sibling.
 
@@ -20,6 +21,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..kernels import mla as _mla
 from ..observability import stats as _obs_stats
 
 # static top-k ceiling compiled into the sampling epilogue: per-slot k
@@ -47,6 +49,14 @@ def rms_norm(x, g, eps: float):
     var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
     return (x32 * jax.lax.rsqrt(var + eps)
             * g.astype(jnp.float32)).astype(x.dtype)
+
+
+def swiglu(x, wg, wu, wd):
+    """``(silu(x W_g) ⊙ x W_u) W_d``: the gate's product in float32, the
+    unit's output in x's dtype."""
+    g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
+    u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
+    return mm((jax.nn.silu(g) * u).astype(x.dtype), wd)
 
 
 def rotary(x, positions, theta: float):
@@ -91,6 +101,114 @@ def init_tensor(key, shape: tuple, init, dtype):
     else:
         w = w * init
     return w.astype(dtype)
+
+
+class LatentAttention:
+    """Multi-head latent attention's layer math (DeepSeek-V2's), for the
+    models that have it (:mod:`mla`, :mod:`kimi_linear`).  A token's keys and
+    values are functions of one compressed row ``c`` (``rank`` wide, after its
+    own RMS norm) and one key slice ``k_pe`` (``rope_dim`` wide) shared by all
+    heads, and ``[c | k_pe | 0]`` is the row the pool keeps.  A prompt expands
+    ``k_nope`` and ``v`` from ``c`` and runs causal flash attention at ``nope
+    + rope_dim`` (q·k) and ``v_dim`` a head; a decode step never expands the
+    cache (``kernels/mla.py``).
+
+    ``rope(x, pos)`` rotates the ``rope_dim`` slices of queries and keys (x
+    [..., rope_dim], pos broadcastable to x's leading axes); ``None`` leaves
+    them as they are projected — **position-free keys**, a model whose
+    positions come from elsewhere.  ``w`` is ONE layer's tensors: ``wq``,
+    ``wkva``, ``kv_norm``, ``wkvb``, ``wo``."""
+
+    def __init__(self, heads: int, nope: int, rope_dim: int, v_dim: int,
+                 rank: int, eps: float, scale: float,
+                 rope: Optional[Callable] = None):
+        self.heads, self.nope, self.rope_dim = heads, nope, rope_dim
+        self.v_dim, self.rank, self.eps = v_dim, rank, eps
+        self.scale, self.rope = scale, rope
+        self.row = _mla.row_width(rank, rope_dim)
+
+    def project(self, w, x, pos):
+        """x [N, D] at positions pos [N] → q_nope [N, H, nope], q_pe [N, H,
+        rope_dim], c [N, rank] (normed), k_pe [N, rope_dim]: the last two are
+        what the cache holds."""
+        dn, r = self.nope, self.rank
+        with jax.named_scope("mla_wq"):
+            q = mm(x, w["wq"]).reshape(x.shape[0], self.heads,
+                                       dn + self.rope_dim)
+        with jax.named_scope("mla_wkva"):
+            kva = mm(x, w["wkva"])
+            c = rms_norm(kva[:, :r], w["kv_norm"], self.eps)
+        k_pe, q_pe = kva[:, r:], q[..., dn:]
+        if self.rope is not None:
+            with jax.named_scope("mla_rope"):
+                k_pe = self.rope(k_pe, pos)
+                q_pe = self.rope(q_pe, pos[:, None])
+        return q[..., :dn], q_pe, c, k_pe
+
+    def wkvb(self, w):
+        """``W_kvb``'s key part [rank, H, nope] and value part [rank, H,
+        v_dim]."""
+        kvb = w["wkvb"].reshape(self.rank, self.heads, self.nope + self.v_dim)
+        return kvb[..., :self.nope], kvb[..., self.nope:]
+
+    def expand(self, w, c):
+        """c [N, rank] → k_nope [N, H, nope], v [N, H, v_dim]."""
+        wk, wv = self.wkvb(w)
+        with jax.named_scope("mla_wkvb"):
+            k = jnp.einsum("nr,rhd->nhd", c, wk,
+                           preferred_element_type=jnp.float32)
+            v = jnp.einsum("nr,rhd->nhd", c, wv,
+                           preferred_element_type=jnp.float32)
+        return k.astype(c.dtype), v.astype(c.dtype)
+
+    def rows(self, c, k_pe, dtype):
+        """The cache rows of N tokens: ``[c | k_pe | 0]`` [N, row]."""
+        pad = self.row - c.shape[-1] - k_pe.shape[-1]
+        return jnp.concatenate(
+            [c, k_pe, jnp.zeros((c.shape[0], pad), c.dtype)],
+            axis=-1).astype(dtype)
+
+    def out(self, w, ctx):
+        """ctx [N, H, v_dim] → [N, D]."""
+        with jax.named_scope("mla_wo"):
+            return mm(ctx.reshape(ctx.shape[0], -1), w["wo"])
+
+    def prompt(self, w, q_nope, q_pe, c, k_pe, impl=None):
+        """One prompt's causal attention by the expanded formula, through
+        the flash forward → [N, D]."""
+        k_nope, v = self.expand(w, c)
+        q = jnp.concatenate([q_nope, q_pe], -1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_pe[:, None], q_pe.shape)], -1)
+        with jax.named_scope("mla_attn"):
+            ctx = _mla.prefill_attention(
+                q.transpose(1, 0, 2), k.transpose(1, 0, 2),
+                v.transpose(1, 0, 2), self.scale, impl=impl)
+        return self.out(w, ctx.transpose(1, 0, 2))
+
+    def step(self, w, q_nope, q_pe, pool, block_tables, context_lens, layer,
+             impl=None):
+        """One token a slot by the absorbed formula over the pool (this
+        step's rows already in it; ``layer`` an int or a traced scalar) →
+        [S, D]."""
+        wk, wv = self.wkvb(w)
+        dtype = q_nope.dtype
+        with jax.named_scope("mla_wkvb"):
+            q_abs = jnp.einsum(
+                "shd,rhd->shr", q_nope, wk,
+                preferred_element_type=jnp.float32).astype(dtype)
+        q_row = jnp.concatenate(
+            [q_abs, q_pe, jnp.zeros(
+                q_pe.shape[:2] + (self.row - self.rank - self.rope_dim,),
+                dtype)], -1)
+        with jax.named_scope("mla_attn"):
+            u = _mla.decode_attention(q_row, pool, block_tables, context_lens,
+                                      layer, self.rank, self.scale, impl=impl)
+        with jax.named_scope("mla_wkvb"):
+            ctx = jnp.einsum(
+                "shr,rhd->shd", u.astype(dtype), wv,
+                preferred_element_type=jnp.float32).astype(dtype)
+        return self.out(w, ctx)
 
 
 # ---------------------------------------------------------------------------
@@ -478,7 +596,8 @@ class RoutedLoadSeries:
 
 
 __all__ = ["LMAdapter", "ConfigDict", "MODEL_TYPES", "TOPK_MAX", "mm",
-           "rms_norm", "rotary", "sub", "unscanned", "EXPERT_LEAVES",
+           "rms_norm", "swiglu", "rotary", "sub", "unscanned",
+           "EXPERT_LEAVES", "LatentAttention",
            "init_tensor", "sample", "sample_first", "prompt_addresses",
            "step_addresses", "walked_blocks", "LaunchObserver",
            "PoolObserver", "RoutedLoadSeries"]

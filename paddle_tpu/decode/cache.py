@@ -436,17 +436,23 @@ class PagedLatentCache(_PagedPool):
 
 class HybridStateCache(_PagedPool):
     """The per-stream state of a model that mixes state-space and attention
-    layers — a paged K/V pool and, under the same manager, the kinds of slot
+    layers — a paged pool and, under the same manager, the kinds of slot
     state the model says it HAS (``rings=``, ``recurrent=``, ``tails=``: a
     kind that is not given has no array), each with a layer count of its own:
 
-    - ``kv`` ``[kv layers, NB, bs, 2·kw]``: the paged K/V pool, a token's row
-      ``[k | v]`` with the K/V heads merged into the minor axis (whole lane
-      tiles, class docs above).  Blocks, tables, the :class:`BlockAllocator`
-      and the trash block are the paged pools'.  One layer where ONE
-      full-attention layer's rows are what every attending layer reads
-      (:mod:`~paddle_tpu.decode.sambay`: none copies it); every layer where
-      every layer attends (:mod:`~paddle_tpu.decode.falcon_h1`).
+    - ``kv`` ``[kv layers, NB, bs, row]``: the paged pool.  What a token's row
+      holds is the model's to say: ``[k | v]`` of ``2·kw`` numbers, the K/V
+      heads merged into the minor axis (``kv_width=kw``, the default), or any
+      row of ``row_width`` lanes the model's attention reads back — a latent
+      row ``[c | k_pe | 0]`` of a model with latent attention, 576 numbers in
+      640 lanes, and no V pool (``row_width=640``: the rows of
+      :class:`PagedLatentCache`, here beside slot rows and under ONE
+      manager).  Whole lane tiles either way (class docs above).  Blocks,
+      tables, the :class:`BlockAllocator` and the trash block are the paged
+      pools'.  One layer where ONE full-attention layer's rows are what every
+      attending layer reads (:mod:`~paddle_tpu.decode.sambay`: none copies
+      it); every layer where every layer attends
+      (:mod:`~paddle_tpu.decode.falcon_h1`).
     - ``rings=(window layers, W)`` → ``rings`` ``[window layers, slots ·
       W/rb, rb, 2·kw]``: a window layer keeps a slot's last ``W`` rows at
       ``position mod W``, as ``W/rb`` blocks of ``rb`` rows that belong to
@@ -456,7 +462,8 @@ class HybridStateCache(_PagedPool):
     - ``recurrent=(state-space layers, state shape)`` → ``h`` ``[layers,
       slots, *state shape]`` float32: the recurrent state, one row a slot
       (Mamba-1: ``(N, Di)``, a decay a channel; Mamba-2: ``(heads, N, head
-      channels)``).
+      channels)``; a gated delta rule: ``(heads, value channels, key
+      channels)``, a decay a key channel).
     - ``tails=(convolution layers, K, width)`` → ``conv`` ``[layers, slots,
       K − 1, width]``: a causal convolution's last ``K − 1`` inputs, one row
       a slot.  A kind of its own: a model of short convolutions and no
@@ -474,13 +481,18 @@ class HybridStateCache(_PagedPool):
                  slots: int, dtype="bfloat16", kv_layers: int = 1, *,
                  rings: Optional[tuple] = None,
                  recurrent: Optional[tuple] = None,
-                 tails: Optional[tuple] = None):
+                 tails: Optional[tuple] = None,
+                 row_width: Optional[int] = None):
         if str(dtype) == "int8":
             raise ValueError("the hybrid state has no int8 form: its rows "
                              "carry no per-block scale")
+        if row_width is not None and rings is not None:
+            raise ValueError("a ring keeps [k | v] rows: a pool row of the "
+                             "model's own width has none beside it")
         super().__init__(kv_layers, num_blocks, block_tokens, dtype)
         self.slots, self.window = int(slots), 0
-        width = 2 * int(kv_width)
+        width = 2 * int(kv_width) if row_width is None else int(row_width)
+        self.row_width = width
         self.kv = jnp.zeros((self.num_layers, self.num_blocks,
                              self.block_tokens, width), dtype)
         self.rings = self.h = self.conv = None

@@ -46,11 +46,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .adapter import (MODEL_TYPES, ConfigDict, LaunchObserver, LMAdapter,
-                      RoutedLoadSeries, mm, prompt_addresses, rms_norm,
-                      sample, sample_first, step_addresses)
+from .adapter import (MODEL_TYPES, ConfigDict, LatentAttention,
+                      LaunchObserver, LMAdapter, RoutedLoadSeries,
+                      prompt_addresses, rms_norm, sample, sample_first,
+                      step_addresses, sub, swiglu)
 from .cache import PagedLatentCache
-from ..kernels import mla as _mla
 from ..kernels import moe as _moe
 from ..observability import trace as _trace
 
@@ -168,11 +168,6 @@ def param_shapes(cfg: MLAConfig) -> Dict[str, tuple]:
     return out
 
 
-def _swiglu(x, wg, wu, wd):
-    g = jnp.dot(x, wg, preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu, preferred_element_type=jnp.float32)
-    return mm((jax.nn.silu(g) * u).astype(x.dtype), wd)
-
 
 class MLAObserver(LaunchObserver):
     """``decode.<engine>.*`` series of a routed latent-attention model: the
@@ -249,9 +244,13 @@ class MLATransformerLM(LMAdapter):
         self._inv_freq = jnp.asarray(yarn_inv_freq(
             config.qk_rope_head_dim, config.rope_theta, config.rope_scaling))
         self._rope_factor = rope_factor(config)
-        self._scale = softmax_scale(config)
-        self._row = _mla.row_width(config.kv_lora_rank,
-                                   config.qk_rope_head_dim)
+        # the latent attention's layer math is the adapter's; the rotation
+        # (YaRN's) is this model's
+        self._attn = LatentAttention(
+            config.num_attention_heads, config.qk_nope_head_dim,
+            config.qk_rope_head_dim, config.v_head_dim, config.kv_lora_rank,
+            config.rms_norm_eps, softmax_scale(config), rope=self._rope)
+        self._scale, self._row = self._attn.scale, self._attn.row
 
     # -- what an engine asks of a model ------------------------------------
     def _make_cache(self, num_blocks: int, block_tokens: int, dtype: str,
@@ -293,52 +292,6 @@ class MLATransformerLM(LMAdapter):
         return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
                                axis=-1).astype(x.dtype)
 
-    def _latent(self, p, i, x, pos):
-        """x [N, D] at positions pos [N] → q_nope [N, H, dn], q_pe [N, H, dr]
-        (rotated), c [N, r] (normed), k_pe [N, dr] (rotated): the last two
-        are what the cache holds."""
-        cfg = self.config
-        H, dn, dr, r = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                        cfg.qk_rope_head_dim, cfg.kv_lora_rank)
-        with jax.named_scope("mla_wq"):
-            q = mm(x, p[f"l{i}.wq"]).reshape(x.shape[0], H, dn + dr)
-        with jax.named_scope("mla_wkva"):
-            kva = mm(x, p[f"l{i}.wkva"])
-            c = self._rms(kva[:, :r], p[f"l{i}.kv_norm"])
-        with jax.named_scope("mla_rope"):
-            k_pe = self._rope(kva[:, r:], pos)
-            q_pe = self._rope(q[..., dn:], pos[:, None])
-        return q[..., :dn], q_pe, c, k_pe
-
-    def _wkvb(self, p, i):
-        cfg = self.config
-        w = p[f"l{i}.wkvb"].reshape(cfg.kv_lora_rank,
-                                    cfg.num_attention_heads,
-                                    cfg.qk_nope_head_dim + cfg.v_head_dim)
-        return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
-
-    def _expand(self, p, i, c):
-        """c [N, r] → k_nope [N, H, dn], v [N, H, dv]."""
-        wk, wv = self._wkvb(p, i)
-        with jax.named_scope("mla_wkvb"):
-            k = jnp.einsum("nr,rhd->nhd", c, wk,
-                           preferred_element_type=jnp.float32)
-            v = jnp.einsum("nr,rhd->nhd", c, wv,
-                           preferred_element_type=jnp.float32)
-        return k.astype(c.dtype), v.astype(c.dtype)
-
-    def _rows(self, c, k_pe, dtype):
-        """The cache rows of N tokens: ``[c | k_pe | 0]`` [N, W]."""
-        pad = self._row - c.shape[-1] - k_pe.shape[-1]
-        return jnp.concatenate(
-            [c, k_pe, jnp.zeros((c.shape[0], pad), c.dtype)],
-            axis=-1).astype(dtype)
-
-    def _attn_out(self, p, i, ctx):
-        """ctx [N, H, dv] → [N, D]."""
-        with jax.named_scope("mla_wo"):
-            return mm(ctx.reshape(ctx.shape[0], -1), p[f"l{i}.wo"])
-
     def _ffn(self, p, i, x, valid):
         """x [N, D] → (ffn(x) [N, D], load [3] int32, ids [N, K] int32, the
         routed experts' part alone [N, D] float32); a dense layer has none
@@ -347,7 +300,7 @@ class MLATransformerLM(LMAdapter):
         L = f"l{i}."
         if not is_moe_layer(cfg, i):
             with jax.named_scope("dense_ffn"):
-                return _swiglu(x, p[L + "w_gate"], p[L + "w_up"],
+                return swiglu(x, p[L + "w_gate"], p[L + "w_up"],
                                p[L + "w_down"]), None, None, None
         with jax.named_scope("moe_router"):
             logits = jnp.dot(x, p[L + "router"],
@@ -359,7 +312,7 @@ class MLATransformerLM(LMAdapter):
             y, load = _moe.routed_experts(x, ids, w, valid, p[L + "e_gate"],
                                           p[L + "e_up"], p[L + "e_down"])
         with jax.named_scope("moe_shared"):
-            sh = _swiglu(x, p[L + "s_gate"], p[L + "s_up"], p[L + "s_down"])
+            sh = swiglu(x, p[L + "s_gate"], p[L + "s_up"], p[L + "s_down"])
         return (y + sh.astype(jnp.float32)).astype(x.dtype), load, ids, y
 
     def _layer(self, p, i, x, valid, attend, moe: Optional["_MoEOuts"] = None,
@@ -398,9 +351,9 @@ class MLATransformerLM(LMAdapter):
 
         x = p["emb"][tokens.reshape(B * T)]
         for i in range(cfg.num_hidden_layers):
-            def attend(h, i=i):
-                q_nope, q_pe, c, k_pe = self._latent(p, i, h, pos)
-                k_nope, v = self._expand(p, i, c)
+            def attend(h, lw=sub(p, f"l{i}.")):
+                q_nope, q_pe, c, k_pe = self._attn.project(lw, h, pos)
+                k_nope, v = self._attn.expand(lw, c)
                 q = heads(jnp.concatenate([q_nope, q_pe], -1))
                 k = heads(jnp.concatenate(
                     [k_nope, jnp.broadcast_to(k_pe[:, None], q_pe.shape)],
@@ -410,8 +363,8 @@ class MLATransformerLM(LMAdapter):
                 w = jax.nn.softmax(jnp.where(mask, s, -1e30), axis=-1)
                 ctx = jnp.einsum("bhqk,bhkd->bqhd", w,
                                  heads(v).astype(jnp.float32))
-                return self._attn_out(
-                    p, i, ctx.reshape(B * T, *ctx.shape[2:]).astype(h.dtype))
+                return self._attn.out(
+                    lw, ctx.reshape(B * T, *ctx.shape[2:]).astype(h.dtype))
             x = self._layer(p, i, x, valid, attend)
         return self._head(p, x).reshape(B, T, -1)
 
@@ -436,22 +389,13 @@ class MLATransformerLM(LMAdapter):
         moe = _MoEOuts(cfg)
         x = p["emb"][tokens[0]]
         for i in range(cfg.num_hidden_layers):
-            def attend(h, i=i):
+            def attend(h, i=i, w=sub(p, f"l{i}.")):
                 nonlocal pool
-                q_nope, q_pe, c, k_pe = self._latent(p, i, h, pos)
+                q_nope, q_pe, c, k_pe = self._attn.project(w, h, pos)
                 with jax.named_scope("mla_cache_write"):
                     pool = pool.at[i, blocks, offsets].set(
-                        self._rows(c, k_pe, pool.dtype))
-                k_nope, v = self._expand(p, i, c)
-                q = jnp.concatenate([q_nope, q_pe], -1)
-                k = jnp.concatenate(
-                    [k_nope, jnp.broadcast_to(k_pe[:, None], q_pe.shape)],
-                    -1)
-                with jax.named_scope("mla_attn"):
-                    ctx = _mla.prefill_attention(
-                        q.transpose(1, 0, 2), k.transpose(1, 0, 2),
-                        v.transpose(1, 0, 2), self._scale)
-                return self._attn_out(p, i, ctx.transpose(1, 0, 2))
+                        self._attn.rows(c, k_pe, pool.dtype))
+                return self._attn.prompt(w, q_nope, q_pe, c, k_pe)
             x = self._layer(p, i, x, valid, attend, moe, last[None])
         logits = self._head(p, x[last][None])[0]
         with jax.named_scope("sampling"):
@@ -471,36 +415,19 @@ class MLATransformerLM(LMAdapter):
         (pool,) = state
         S = tokens.shape[0]
         bs = pool.shape[2]
-        r, dr = cfg.kv_lora_rank, cfg.qk_rope_head_dim
         cl, live, _, blocks = step_addresses(positions, block_tables, bs)
         offsets = positions % bs
         moe = _MoEOuts(cfg)
         x = p["emb"][tokens]
         for i in range(cfg.num_hidden_layers):
-            def attend(h, i=i):
+            def attend(h, i=i, w=sub(p, f"l{i}.")):
                 nonlocal pool
-                q_nope, q_pe, c, k_pe = self._latent(p, i, h, positions)
+                q_nope, q_pe, c, k_pe = self._attn.project(w, h, positions)
                 with jax.named_scope("mla_cache_write"):
                     pool = pool.at[i, blocks, offsets].set(
-                        self._rows(c, k_pe, pool.dtype))
-                wk, wv = self._wkvb(p, i)
-                with jax.named_scope("mla_wkvb"):
-                    q_abs = jnp.einsum(
-                        "shd,rhd->shr", q_nope, wk,
-                        preferred_element_type=jnp.float32).astype(h.dtype)
-                q_row = jnp.concatenate(
-                    [q_abs, q_pe, jnp.zeros(
-                        q_pe.shape[:2] + (self._row - r - dr,), h.dtype)],
-                    -1)
-                with jax.named_scope("mla_attn"):
-                    u = _mla.decode_attention(q_row, pool, block_tables, cl,
-                                              i, r, self._scale,
-                                              impl=attn_impl)
-                with jax.named_scope("mla_wkvb"):
-                    ctx = jnp.einsum(
-                        "shr,rhd->shd", u.astype(h.dtype), wv,
-                        preferred_element_type=jnp.float32).astype(h.dtype)
-                return self._attn_out(p, i, ctx)
+                        self._attn.rows(c, k_pe, pool.dtype))
+                return self._attn.step(w, q_nope, q_pe, pool, block_tables,
+                                       cl, i, impl=attn_impl)
             x = self._layer(p, i, x, live, attend, moe)
         logits = self._head(p, x)
         with jax.named_scope("sampling"):

@@ -4,8 +4,9 @@ The pool holds, a token a layer, one row ``[c (rank) | k_pe (rope) | 0]``
 padded to a whole number of 128-lane tiles (DeepSeek-V2-Lite: 512 + 64 →
 640), as ``[L, NB, bs, W]``: a block's ``[bs, W]`` fills whole tiles, so the
 array keeps its row-major layout, scatters update it in place, and the
-kernel is handed the WHOLE pool with the layer as a static index (PR 28's
-finding for the K/V pools, kept here).
+kernel is handed the WHOLE pool and the layer as a prefetched scalar (PR 28's
+finding for the K/V pools, kept here; a program that scans over its layers
+has no constant to give).
 
 - :func:`decode_attention` — the absorbed path.  Every head's query is
   already in the row's coordinates (``[W_kvb,h^T q_nope | q_pe | 0]``), so a
@@ -48,12 +49,13 @@ def row_width(rank: int, rope: int) -> int:
     return -(-(rank + rope) // LANE) * LANE
 
 
-def decode_attention_xla(q, pool, block_tables, context_lens, layer: int,
+def decode_attention_xla(q, pool, block_tables, context_lens, layer,
                          rank: int, sm_scale: float):
     S, H, W = q.shape
     MB = block_tables.shape[1]
     bs = pool.shape[2]
-    rows = pool[layer, block_tables].reshape(S, MB * bs, W)
+    rows = lax.dynamic_index_in_dim(pool, layer, keepdims=False)[
+        block_tables].reshape(S, MB * bs, W)
     s = jnp.einsum("shw,stw->sht", q.astype(jnp.float32),
                    rows.astype(jnp.float32)) * sm_scale
     pos = jnp.arange(MB * bs, dtype=jnp.int32)
@@ -64,9 +66,10 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer: int,
                       rows[..., :rank].astype(jnp.float32))
 
 
-def _decode_kernel(bt_ref, cl_ref, q_ref, pool_ref, o_ref, buf, sem,
-                   m_scr, l_scr, acc_scr, *, layer: int, bs: int, chunk: int,
+def _decode_kernel(bt_ref, cl_ref, layer_ref, q_ref, pool_ref, o_ref, buf,
+                   sem, m_scr, l_scr, acc_scr, *, bs: int, chunk: int,
                    n_chunks: int, rank: int):
+    layer = layer_ref[0]
     s = pl.program_id(0)
     j = pl.program_id(1)
     cl = cl_ref[s]
@@ -120,18 +123,17 @@ def _decode_pallas(q, pool, block_tables, context_lens, layer, rank,
     bt = block_tables.astype(jnp.int32)
     if n_chunks * chunk != MB:      # a ragged last chunk reads trash block 0
         bt = jnp.pad(bt, ((0, 0), (0, n_chunks * chunk - MB)))
-    kernel = functools.partial(_decode_kernel, layer=layer, bs=bs,
-                               chunk=chunk, n_chunks=n_chunks, rank=rank)
+    kernel = functools.partial(_decode_kernel, bs=bs, chunk=chunk,
+                               n_chunks=n_chunks, rank=rank)
     return pl.pallas_call(
         kernel,
         name="mla_paged_decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,    # tables, lengths, the pool's layer
             grid=(S, n_chunks),
-            in_specs=[pl.BlockSpec((1, H, W), lambda s, j, bt, cl: (s, 0, 0)),
+            in_specs=[pl.BlockSpec((1, H, W), lambda s, j, *_: (s, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, H, rank),
-                                   lambda s, j, bt, cl: (s, 0, 0)),
+            out_specs=pl.BlockSpec((1, H, rank), lambda s, j, *_: (s, 0, 0)),
             scratch_shapes=[pltpu.VMEM((chunk * bs, W), pool.dtype),
                             pltpu.SemaphoreType.DMA((chunk,)),
                             pltpu.VMEM((H, 1), jnp.float32),
@@ -139,16 +141,17 @@ def _decode_pallas(q, pool, block_tables, context_lens, layer, rank,
                             pltpu.VMEM((H, rank), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, rank), jnp.float32),
         interpret=interpret,
-    )(bt, context_lens.astype(jnp.int32), q, pool)
+    )(bt, context_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
 
 
-def decode_attention(q, pool, block_tables, context_lens, layer: int,
-                     rank: int, sm_scale: float, impl=None, interpret=None):
+def decode_attention(q, pool, block_tables, context_lens, layer, rank: int,
+                     sm_scale: float, impl=None, interpret=None):
     """q [S, H, W] absorbed queries in the pool row's coordinates (lanes past
     ``rank + rope`` zero), pool [L, NB, bs, W] (all of it, as it lies),
-    block_tables [S, MB] int32, context_lens [S] int32 → ``sum_s p_s c_s``
+    block_tables [S, MB] int32, context_lens [S] int32, layer an int or a
+    traced scalar (a program that scans over its layers) → ``sum_s p_s c_s``
     [S, H, rank] float32 (the caller applies ``W_kvb``'s value part)."""
-    layer = int(layer)
     if impl == "xla":
         _obs_stats.scope("mla").counter("decode_attn_fallbacks").inc()
         return decode_attention_xla(q, pool, block_tables, context_lens,

@@ -77,6 +77,14 @@ assignment is computed (no capacity, no token dropped).
   and the dispatch's load figures.  A model whose routing is known before
   the experts' input (the router reads an earlier activation) calls
   :func:`plan_groups` there and :func:`planned_experts` here.
+
+**A share of a layer.**  :func:`plan_groups` (and :func:`routed_experts`)
+take ``first``: the stacks then hold experts ``first … first + held − 1`` of a
+layer whose router is wider — what one chip of an expert-parallel deployment
+holds.  The router's scores, its choice and its renormalisation stay over all
+the experts; only assignments to held experts get a row, the walks' maps stay
+the plan's (numbered from 0, as the stacks are), and what the experts held
+elsewhere would add is left out.  Nothing stands in for the other chips.
 """
 from __future__ import annotations
 
@@ -145,14 +153,24 @@ def plan_rows(tokens: int, k: int, experts: int, tile: int) -> int:
     return -(-rows // tile) * tile
 
 
-def plan_groups(ids, valid, experts: int, tile: int) -> GroupPlan:
+def plan_groups(ids, valid, experts: int, tile: int, first=0) -> GroupPlan:
     """ids [T, K] int32 (chosen experts), valid [T] bool (False: the token is
     padding or its slot has no stream — it is routed nowhere and enters no
-    count)."""
+    count).
+
+    ``first`` (an int or a traced scalar) makes the layer a SHARE of a wider
+    one: the router chose among all of its experts, and the ``experts``
+    planned here are ``first … first + experts − 1`` of them — the ones whose
+    matrices this chip holds, which the plan numbers from 0 as the stacks do.
+    An assignment to an expert held elsewhere gets no row and enters no
+    count; its weight stays what the router gave it, so the sum over the
+    shares is the whole layer's.  A layer held whole is the share from 0."""
     T, K = ids.shape
     N = T * K
     R = plan_rows(T, K, experts, tile)
-    key = jnp.where(valid[:, None], ids, experts).reshape(N).astype(jnp.int32)
+    local = ids - jnp.asarray(first, ids.dtype)
+    key = jnp.where(valid[:, None] & (local >= 0) & (local < experts),
+                    local, experts).reshape(N).astype(jnp.int32)
     order = jnp.argsort(key, stable=True).astype(jnp.int32)
     sorted_key = key[order]
     starts = jnp.searchsorted(
@@ -514,13 +532,15 @@ def planned_experts(x, weights, plan: GroupPlan, wg, wu, wd, tile: int,
 
 
 def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
-                   act: str = "silu"):
+                   act: str = "silu", first=0):
     """x [T, D] (the experts' input, the model's activation dtype), ids /
     weights [T, K] from :func:`route_topk`, valid [T] bool → (sum over the
     chosen experts of ``w * expert(x)`` [T, D] float32, load [3] int32:
-    assignments, experts touched, the largest load of one)."""
+    assignments, experts touched, the largest load of one).  With ``first``
+    the stacks hold experts ``first …`` of a wider layer's
+    (:func:`plan_groups`) and the sum is this share's part."""
     tile = row_tile(x.shape[0], x.dtype)
-    plan = plan_groups(ids, valid, wg.shape[0], tile)
+    plan = plan_groups(ids, valid, wg.shape[0], tile, first)
     return planned_experts(x, weights, plan, wg, wu, wd, tile, impl=impl,
                            act=act), plan.load
 

@@ -20,8 +20,9 @@ from paddle_tpu.decode import (FalconH1Config, FalconH1LM, LFM2Config, LFM2LM,
                                SambaYConfig, SambaYLM, SmallThinkerConfig,
                                SmallThinkerLM, TransformerLM)
 from paddle_tpu.decode import adapter
-from paddle_tpu.decode import (falcon_h1, lfm2, mla, model, sambay,
-                               smallthinker)
+from paddle_tpu.decode import (falcon_h1, kimi_linear, lfm2, mla, model,
+                               sambay, smallthinker)
+from paddle_tpu.decode import KimiLinearConfig, KimiLinearLM
 from paddle_tpu.observability import stats
 from paddle_tpu.observability import trace
 
@@ -92,6 +93,20 @@ ADAPTERS = {
              {"step_routed_assignments", "step_experts_touched",
               "step_context_tokens", "step_streams"}, BUCKETS_TO_16K, 4,
              (9, 24)),                                   # one attention layer
+    "kimi_linear": (lambda: KimiLinearLM(KimiLinearConfig(vocab_size=64)),
+                    kimi_linear,
+                    COMMON | POOL | ROUTED | {
+                        "step_choices", "prefill_choices", "step_state_bytes",
+                        "prefill_moe_dispatches", "prefill_experts_touched",
+                        "prefill_expert_load_max_sum", "prefill_plan_rows",
+                        "prefill_plan_pad_rows", "recurrent_state_bytes"},
+                    {"prefill_routed_assignments", "prefill_choices",
+                     "prefill_plan_rows", "prefill_real_tokens",
+                     "prefill_tokens_sq"},
+                    {"step_routed_assignments", "step_experts_touched",
+                     "step_choices", "step_context_tokens", "step_streams",
+                     "step_state_bytes"}, BUCKETS_TO_16K, 5,
+                    (9, 24)),                            # one latent layer
 }
 SLOTS, TABLE, NB, BS = 3, 8, 9, 8
 
@@ -161,8 +176,8 @@ def test_a_served_model_keeps_the_protocol(key, spans):
         assert tuple(b for b in edges if math.isfinite(b)) == buckets
     # ... and what its spans carry: the launch's own additions to the
     # counters of the same names
-    extra = [np.asarray([[30, 7, 9, 128][:columns],
-                         [30, 8, 11, 128][:columns]])] if columns else []
+    extra = [np.asarray([[30, 7, 9, 128, 40][:columns],
+                         [30, 8, 11, 128, 40][:columns]])] if columns else []
     before = stats.to_dict()
     obs.prefill(extra, 10, 16)
     obs.step(extra, np.asarray([30, 27]))
@@ -191,10 +206,11 @@ def test_a_served_model_keeps_the_protocol(key, spans):
 
 
 def test_no_adapter_imports_a_sibling_and_the_shared_names_are_the_adapter_s():
-    for mod in (mla, sambay, falcon_h1, smallthinker, lfm2):
+    for mod in (mla, sambay, falcon_h1, smallthinker, lfm2, kimi_linear):
         assert not [v for v in vars(mod).values()
                     if getattr(v, "__name__", "") in (
                         "paddle_tpu.decode.mla", "paddle_tpu.decode.sambay",
+                        "paddle_tpu.decode.kimi_linear",
                         "paddle_tpu.decode.falcon_h1",
                         "paddle_tpu.decode.smallthinker",
                         "paddle_tpu.decode.lfm2", "paddle_tpu.decode.model")]
@@ -204,7 +220,11 @@ def test_no_adapter_imports_a_sibling_and_the_shared_names_are_the_adapter_s():
     assert smallthinker.rotary is lfm2.rotary is falcon_h1.rotary \
         is adapter.rotary
     assert smallthinker.EXPERT_LEAVES is lfm2.EXPERT_LEAVES \
-        is adapter.EXPERT_LEAVES
+        is kimi_linear.EXPERT_LEAVES is adapter.EXPERT_LEAVES
+    # the latent attention's layer math is written once, and both models
+    # that have it use the adapter's
+    assert mla.LatentAttention is kimi_linear.LatentAttention \
+        is adapter.LatentAttention
 
 
 # -- the shared layer math against NumPy ------------------------------------

@@ -264,7 +264,11 @@ def test_grouped_swiglu_kernel_matches_its_fallback_and_counts_it():
     np.testing.assert_allclose(np.asarray(y0)[:33], dense[:33], atol=1e-4)
 
 
-def test_latent_decode_attention_kernel_matches_its_fallback():
+@pytest.mark.parametrize("layer_as", ["a_constant", "a_traced_scalar",
+                                      "a_scan_s_index"])
+def test_latent_decode_attention_kernel_matches_its_fallback(layer_as):
+    """One kernel, the pool's layer a prefetched scalar: a program that
+    unrolls its layers hands it a constant, one that scans an index."""
     rng = np.random.RandomState(1)
     S, H, rank, rope, bs, mb, nb, L = 5, 4, 32, 8, 4, 40, 64, 3
     W = MK.row_width(rank, rope)
@@ -277,7 +281,21 @@ def test_latent_decode_attention_kernel_matches_its_fallback():
     args = (jnp.asarray(q), pool, jnp.asarray(bt), jnp.asarray(cl), 2, rank,
             0.3)
     want = MK.decode_attention(*args, impl="xla")
-    got = MK.decode_attention(*args, impl="pallas")
+
+    def walk(layer):
+        return MK.decode_attention(*args[:4], layer, rank, 0.3,
+                                   impl="pallas")
+
+    if layer_as == "a_constant":
+        got = walk(2)
+    elif layer_as == "a_traced_scalar":
+        got = jax.jit(walk)(jnp.int32(2))
+    else:
+        _, every = jax.lax.scan(lambda c, layer: (c, walk(layer)), 0,
+                                jnp.arange(L, dtype=jnp.int32))
+        got = every[2]
+        # ... and each step of the scan read its own layer's rows
+        assert np.abs(np.asarray(every[0]) - np.asarray(got)).max() > 0.1
     assert got.shape == (S, H, rank)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5)
 

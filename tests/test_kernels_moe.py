@@ -331,3 +331,74 @@ def test_the_router_s_score_scale_and_epsilon():
     np.testing.assert_allclose(wn[0], s / (s.sum() + 0.5), rtol=1e-6)
     with pytest.raises(ValueError, match="score"):
         moe.route_topk(r, 2, score="tanh")
+
+
+# -- a share of a layer's experts (``first``) --------------------------------
+def _loop_plan(ids, valid, experts, tile, first=0):
+    """What a plan is, one assignment at a time: (row_token, row_of, tile
+    → expert of the tiles that hold a row, padded sizes, load)."""
+    ids, valid = np.asarray(ids), np.asarray(valid)
+    T, K = ids.shape
+    R = moe.plan_rows(T, K, experts, tile)
+    row_token, row_of = np.full(R, T), np.full((T, K), R)
+    counts, tiles, r = [], [], 0
+    for e in range(experts):
+        mine = [(t, k) for t in range(T) for k in range(K)
+                if valid[t] and ids[t, k] - first == e]
+        for j, (t, k) in enumerate(mine):
+            row_token[r + j], row_of[t, k] = t, r + j
+        counts.append(len(mine))
+        tiles += [e] * -(-len(mine) // tile)
+        r += -(-len(mine) // tile) * tile
+    counts = np.asarray(counts)
+    return (row_token, row_of, np.asarray(tiles), -(-counts // tile) * tile,
+            [counts.sum(), (counts > 0).sum(), counts.max()])
+
+
+@pytest.mark.parametrize("tile", [8, 128])
+def test_a_whole_layer_s_plan_is_the_share_from_zero_one_row_at_a_time(tile):
+    x, wg, wu, wd, ids, w = _case(11, T=300 if tile == 128 else 40)
+    valid = jnp.arange(ids.shape[0]) % 7 != 3
+    got = moe.plan_groups(ids, valid, 8, tile)
+    for a, b in zip(got, moe.plan_groups(ids, valid, 8, tile, first=0)):
+        np.testing.assert_array_equal(a, b)
+    row_token, row_of, tiles, padded, load = _loop_plan(ids, valid, 8, tile)
+    np.testing.assert_array_equal(got.row_token, row_token)
+    np.testing.assert_array_equal(got.row_of, row_of)
+    assert int(got.active_tiles[0]) == len(tiles)
+    np.testing.assert_array_equal(got.tile_expert[:len(tiles)], tiles)
+    np.testing.assert_array_equal(got.padded_sizes, padded)
+    np.testing.assert_array_equal(got.load, load)
+
+
+@pytest.mark.parametrize("tile,T", [(8, 40), (128, 300)],
+                         ids=["a_step_s_tiles", "a_prefill_s_tiles"])
+def test_the_shares_plan_their_own_experts_and_add_up_to_the_layer(tile, T):
+    x, wg, wu, wd, ids, w = _case(12, T=T, E=16, K=4)
+    valid = jnp.arange(T) % 5 != 2
+    whole = moe.planned_experts(
+        x, w, moe.plan_groups(ids, valid, 16, tile), wg, wu, wd, tile)
+    total, loads = 0.0, []
+    for first in (0, 4, 8, 12):
+        plan = moe.plan_groups(ids, valid, 4, tile, first=jnp.int32(first))
+        held = np.asarray((ids >= first) & (ids < first + 4)
+                          & valid[:, None])
+        # an assignment to an expert held elsewhere gets no row
+        R = plan.row_token.shape[0]
+        np.testing.assert_array_equal(np.asarray(plan.row_of) < R, held)
+        assert int(plan.load[0]) == held.sum()
+        sl = slice(first, first + 4)
+        total = total + moe.planned_experts(
+            x, w, plan, wg[sl], wu[sl], wd[sl], tile)
+        loads.append(np.asarray(plan.load))
+    np.testing.assert_allclose(total, whole, rtol=2e-5, atol=2e-5)
+    assert sum(int(a[0]) for a in loads) == int(valid.sum()) * 4
+    # routed_experts takes the share by its stacks and ``first``
+    part, load = moe.routed_experts(x, ids, w, valid, wg[4:8], wu[4:8],
+                                    wd[4:8], first=4)
+    np.testing.assert_array_equal(load, loads[1])
+    if tile == 8:
+        assert moe.row_tile(T, x.dtype) == 8
+        plan = moe.plan_groups(ids, valid, 4, tile, first=4)
+        np.testing.assert_allclose(part, moe.planned_experts(
+            x, w, plan, wg[4:8], wu[4:8], wd[4:8], tile), rtol=1e-6)

@@ -153,7 +153,10 @@ def test_a_prefill_overwrites_a_live_slot_beside_untouched_neighbours():
     raw, model, params, plist, prefill, step = build(raw_config())
     S = 3
     cache = model.make_cache(40, BS, "float32", slots=S)
-    rng = np.random.default_rng(4)
+    # not the draw of seed 4: its first prompt has a router tie at position 40
+    # (experts 2 and 7 of the sixth layer), which 1e-5 of the chunked scan's
+    # state turns — the reference here takes its own choices
+    rng = np.random.default_rng(5)
     seqs = [rng.integers(0, V, size=n).astype(np.int32) for n in (70, 9, 30)]
     tables = np.zeros((S, 24), np.int32)
     for i in range(S):

@@ -14,6 +14,7 @@ The configuration is the benchmark's whole
 quarter of the vocabulary, 64 slots, the mix's pool, 1,088-block tables.
 Nothing is allocated: the programs are compiled from shapes.
 """
+import base64
 import json
 import os
 import re
@@ -200,6 +201,41 @@ def test_state_stays_in_place_no_wide_score_no_fallback_and_the_cut_fits(
           f"{(mem.output_size_in_bytes - mem.alias_size_in_bytes) / 1e9:.3f} "
           f"GB")
     assert 11.0e9 < live <= FITS_BYTES, live
+
+
+def test_the_chunk_kernel_s_products_are_single_passes_of_bf16_pieces(
+        one_chip, mosaic):
+    """``kda_chunk_prefill`` as Mosaic is handed it, 32 heads of 128: every
+    product a plain bf16 x bf16 pass into float32 — none asks the compiler
+    for ``contract_precision<fp32>`` (six passes; ``kernels/kda.py`` names no
+    product that stays there), and a (chunk, head) takes 63 of them where
+    24 float32 products took 144."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    H, K = CFG.kda_heads, CFG.kda_dim
+    qkv = sds((2 * KK.CHUNK, H, K), jnp.bfloat16)
+    text = jax.jit(lambda *a: KK.kda_scan(*a, out_dtype=jnp.bfloat16)).lower(
+        qkv, qkv, qkv, sds(qkv.shape, jnp.float32),
+        sds(qkv.shape[:2], jnp.float32)).as_text()
+    assert text.count("tpu_custom_call") == 1
+    (body,) = re.findall(r'body\\22: \\22([A-Za-z0-9+/=]+)\\22', text)
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        asm = ir.Module.parse(base64.b64decode(body)).operation.get_asm(
+            enable_debug_info=False)
+    products = re.findall(r'"stable_mosaic\.tpu\.matmul"\(.*', asm)
+    assert len(products) == 63 * 4              # _SCAN_HEADS a grid step
+    assert "contract_precision" not in asm
+    for line in products:
+        lhs, rhs, acc = re.search(r": \((.*)\) ->", line).group(1).split(
+            ", ")
+        assert lhs.endswith("xbf16>") and rhs.endswith("xbf16>") \
+            and acc.endswith("xf32>"), line
 
 
 def test_the_step_s_kernels_step_by_slot_and_the_layer_is_a_scalar(one_chip,

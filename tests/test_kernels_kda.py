@@ -7,9 +7,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from paddle_tpu.kernels import kda
 from paddle_tpu.observability import stats
+
+
+def _decays(rng, shape, strongest, weakest=1e-3):
+    """Log-decays log-uniform in [weakest, strongest] nats."""
+    return -np.exp(rng.uniform(np.log(weakest), np.log(strongest),
+                               shape)).astype("float32")
 
 
 def draw(rng, T, H, K, V, strongest, weakest=1e-3, real=None):
@@ -21,8 +28,7 @@ def draw(rng, T, H, K, V, strongest, weakest=1e-3, real=None):
     k /= np.linalg.norm(k, axis=-1, keepdims=True)
     q = q / np.linalg.norm(q, axis=-1, keepdims=True) * K ** -0.5
     v = rng.standard_normal((T, H, V)).astype("float32")
-    a = -np.exp(rng.uniform(np.log(weakest), np.log(strongest),
-                            (T, H, K))).astype("float32")
+    a = _decays(rng, (T, H, K), strongest, weakest)
     b = (1 / (1 + np.exp(-rng.standard_normal((T, H))))).astype("float32")
     if real is not None:
         a[real:], b[real:] = 0.0, 0.0
@@ -39,8 +45,15 @@ def draw(rng, T, H, K, V, strongest, weakest=1e-3, real=None):
 @pytest.mark.parametrize("T,real", [(64, 64), (192, 150), (128, 65)],
                          ids=["one_chunk", "ends_inside_a_chunk",
                               "one_past_an_edge"])
-def test_the_chunked_form_is_the_recurrence(strongest, T, real):
-    args = draw(np.random.default_rng(T + real), T, 2, 16, 16, strongest,
+# the output's floor: 2e-6 at the published head (K = V = 128), where the
+# kernel's products, two bf16 pieces an operand, read 1.1e-6; 1e-5 at the toy
+# head, whose outputs are larger (to 0.62, sixteen channels under the same
+# scale) and read to 7.8e-6 — the served output is bf16, a step of 2e-3 of a
+# value.  The state's tolerance is one
+@pytest.mark.parametrize("K,atol", [(16, 1e-5), (128, 2e-6)],
+                         ids=["toy_head", "published_head"])
+def test_the_chunked_form_is_the_recurrence(strongest, T, real, K, atol):
+    args = draw(np.random.default_rng(T + real), T, 2, K, K, strongest,
                 real=real)
     before = stats.to_dict().get("kda.chunk_fallbacks", 0)
     o, S = kda.kda_scan(*args)
@@ -48,12 +61,68 @@ def test_the_chunked_form_is_the_recurrence(strongest, T, real):
     want_o, want_S = kda.kda_scan_xla(*args)
     assert np.isfinite(np.asarray(o)).all() and np.isfinite(
         np.asarray(S)).all()
-    np.testing.assert_allclose(o[:real], want_o[:real], rtol=2e-5, atol=2e-6)
+    np.testing.assert_allclose(o[:real], want_o[:real], rtol=2e-5, atol=atol)
     np.testing.assert_allclose(S, want_S, rtol=2e-5, atol=2e-5)
     # the pads left the state as the last real position made it
     short = tuple(x[:real] for x in args)
     np.testing.assert_allclose(S, kda.kda_scan_xla(*short)[1], rtol=2e-5,
                                atol=2e-5)
+
+
+@pytest.mark.parametrize("strongest", [1e-2, 1.6, 40.0],
+                         ids=["weakest", "the_draw_s_strongest",
+                              "past_float32"])
+@pytest.mark.parametrize("seed", [0, 1])
+def test_three_pieces_hold_a_float32_and_the_sums_of_64_lose_nothing(
+        strongest, seed):
+    a = jnp.asarray(_decays(np.random.default_rng(seed), (64, 128),
+                            strongest))
+    a1, a2, a3 = (x.astype(jnp.float32) for x in kda.pieces(a, 3))
+    np.testing.assert_array_equal((a3 + a2) + a1, a)      # bit for bit
+    w = jnp.asarray(kda.halving(64)[0])                   # rows sum to 64
+    np.testing.assert_array_equal(w.astype(jnp.bfloat16), w)
+    got = np.asarray(jax.jit(kda.dot_01)(w.astype(jnp.bfloat16), a))
+    whole = np.asarray(jnp.dot(w, a, precision=lax.Precision.HIGHEST))
+    exact = np.asarray(w, np.float64) @ np.asarray(a, np.float64)
+    # the float32 product at its highest precision rounds every sum it
+    # takes; three passes over pieces of eight bits round fewer: as near the
+    # product as that one at the worst, and within two of its last places
+    assert np.abs(got - exact).max() <= np.abs(whole - exact).max()
+    assert (np.abs(got - exact)
+            <= 2 * np.spacing(np.abs(exact).astype("float32"))).all()
+
+
+# the kernel's operand shapes: the inverse's and X·rhs / B·u, a level's
+# product, the state's two reads (one product of both since the split), the
+# state's write
+@pytest.mark.parametrize("x,y,dims", [
+    ((64, 64), (64, 64), kda.NN), ((64, 64), (64, 128), kda.NN),
+    ((128, 128), (64, 128), kda.NT), ((64, 128), (128, 128), kda.NT),
+    ((128, 128), (128, 128), kda.NT), ((128, 64), (64, 128), kda.NN)],
+    ids=["inverse", "X_rhs_and_B_u", "level", "state_read",
+         "both_state_reads", "state_write"])
+@pytest.mark.parametrize("decayed", [False, True],
+                         ids=["as_drawn", "under_a_decay"])
+def test_two_pieces_an_operand_keep_a_product_to_sixteen_bits(x, y, dims,
+                                                              decayed):
+    rng = np.random.default_rng(x[0] + y[1])
+    xs = rng.standard_normal(x).astype("float32")
+    ys = rng.standard_normal(y).astype("float32")
+    if decayed:             # a level's operands: exp of sums to 64 x 40 nats
+        xs *= np.exp(_decays(rng, x, 40.0) * rng.integers(0, 64, x))
+        ys *= np.exp(_decays(rng, y, 40.0) * rng.integers(0, 64, y))
+    got = jax.jit(lambda a, b: kda.dot_split(
+        kda.pieces(a), kda.pieces(b), dims))(xs, ys)
+    whole = lax.dot_general(xs, ys, dims, precision=lax.Precision.HIGHEST)
+    bound = lax.dot_general(np.abs(xs), np.abs(ys), dims,
+                            precision=lax.Precision.HIGHEST)
+    assert (np.abs(np.asarray(got) - np.asarray(whole))
+            <= 2.0 ** -15 * np.asarray(bound)).all()
+    # ... and one piece an operand is another result: it does not
+    one = lax.dot_general(xs.astype(jnp.bfloat16), ys.astype(jnp.bfloat16),
+                          dims, preferred_element_type=jnp.float32)
+    assert (np.abs(np.asarray(one) - np.asarray(whole))
+            > 2.0 ** -15 * np.asarray(bound)).mean() > 0.5
 
 
 def test_the_recurrence_is_the_equations_in_numpy():

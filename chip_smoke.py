@@ -22,6 +22,9 @@ checks what comes out by the repo's own means:
 - expert_walk:   the prefill form of the grouped expert kernel (an expert a
                  grid step) against its XLA fallback at DeepSeek-V2-Lite's
                  expert shapes, on a prompt's plan and on one expert's.
+- expert_plan:   the experts' plan (made by counting) against a NumPy stable
+                 sort, and ``routed_experts`` against the result under the
+                 sorted plan, to the bit, at the four expert cells' shapes.
 - group_flash:   the serve cells' prefill attention (a K/V head's group a
                  grid step, pad tiles skipped) against dense attention at
                  SmallThinker's and LFM2's head layouts, 12,288 positions.
@@ -1095,6 +1098,106 @@ def phase_expert_walk(on_chip=True, tokens=2048, top_k=6, experts=64,
     return out
 
 
+# ---------------------------------------------------------------------------
+# phase 3g: the experts' plan against a stable sort, at the four cells' shapes
+# ---------------------------------------------------------------------------
+
+def sorted_plan(ids, valid, experts, tile, first, rows):
+    """The six fields of ``kernels/moe.py GroupPlan`` by a NumPy stable sort:
+    the valid assignments to experts ``first … first + experts − 1`` in expert
+    order, each expert's run padded to the tile."""
+    T, K = ids.shape
+    local = ids.astype(np.int64) - first
+    key = np.where(valid[:, None] & (local >= 0) & (local < experts), local,
+                   experts).reshape(-1)
+    order = np.argsort(key, kind="stable")
+    counts = np.bincount(key, minlength=experts + 1)[:experts]
+    padded = -(-counts // tile) * tile
+    pend = np.cumsum(padded)
+    row_token, row_of, at = np.full(rows, T), np.full(T * K, rows), 0
+    for e in range(experts):
+        mine = order[at:at + counts[e]]
+        run = pend[e] - padded[e] + np.arange(counts[e])
+        row_token[run], row_of[mine] = mine // K, run
+        at += counts[e]
+    tiles = np.repeat(np.arange(experts), padded // tile)
+    tiles = np.concatenate([tiles, np.full(
+        rows // tile - len(tiles), tiles[-1] if len(tiles) else experts - 1)])
+    return [a.astype(np.int32) for a in (
+        row_token, row_of.reshape(T, K), tiles,
+        np.asarray([max(pend[-1] // tile, 1)]),
+        padded, np.asarray([counts.sum(), (counts > 0).sum(), counts.max()]))]
+
+
+# (tokens, choices a token, the router's width, first held expert): a decode
+# step's 64 slots and the 2,048 / 8,192 / 16,384 rungs of the four expert
+# cells, whose stacks hold 64 experts a layer
+EXPERT_PLAN_SHAPES = (
+    ("a_step_top4", 64, 4, 64, 0), ("a_step_top6", 64, 6, 64, 0),
+    ("a_step_top8_share", 64, 8, 256, 0),
+    ("2048_top6", 2048, 6, 64, 0), ("8192_top6", 8192, 6, 64, 0),
+    ("8192_top4", 8192, 4, 64, 0), ("16384_top8_share", 16384, 8, 256, 0))
+
+
+def phase_expert_plan(shapes=EXPERT_PLAN_SHAPES, experts=64, hidden=2048,
+                      expert_ffn=1408):
+    """``kernels/moe.py plan_groups`` — the plan by counting — against
+    :func:`sorted_plan` in all six fields, and ``routed_experts`` against
+    ``planned_experts`` under the sorted plan, equal to the bit, at the expert
+    cells' real shapes: uniform choices with a padded tail, and every
+    assignment on ONE expert.  On the chip because the TPU's compiler has
+    returned a wrong result for correct XLA code before (PERF.md §6, PR 44)
+    and a plan that is off by one row is a wrong token, not a slow one."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels import moe
+
+    keys = jax.random.split(jax.random.PRNGKey(52), 3)
+    wg, wu = (jax.random.normal(k, (experts, hidden, expert_ffn),
+                                jnp.bfloat16) * 0.03 for k in keys[:2])
+    wd = jax.random.normal(keys[2], (experts, expert_ffn, hidden),
+                           jnp.bfloat16) * 0.03
+    # the stacks are ARGUMENTS: closed over, their 1.1 GB would be constants
+    # of every shape's executables
+    stacks = (wg, wu, wd)
+    routed = jax.jit(lambda x, ids, w, valid, first, stacks:
+                     moe.routed_experts(x, ids, w, valid, *stacks,
+                                        first=first)[0])
+    planned = jax.jit(lambda x, w, plan, stacks, tile: moe.planned_experts(
+        x, w, moe.GroupPlan(*plan), *stacks, tile), static_argnums=4)
+    rng, out = np.random.RandomState(52), {}
+    for name, tokens, top_k, wide, first in shapes:
+        tile = moe.row_tile(tokens, jnp.bfloat16)
+        rows = moe.plan_rows(tokens, top_k, experts, tile)
+        x = jnp.asarray(rng.randn(tokens, hidden), jnp.bfloat16)
+        w = jnp.asarray(rng.rand(tokens, top_k), jnp.float32)
+        valid = np.arange(tokens) < tokens - tokens // 7
+        uniform = np.argsort(rng.rand(tokens, wide), axis=1)[:, :top_k]
+        for case, ids in (("uniform", uniform),
+                          ("one_expert", np.full_like(uniform, first + 3))):
+            ids = ids.astype(np.int32)
+            got = moe.plan_groups(jnp.asarray(ids), jnp.asarray(valid),
+                                  experts, tile, jnp.int32(first))
+            want = sorted_plan(ids, valid, experts, tile, first, rows)
+            differ = [f for f, a, b in zip(moe.GroupPlan._fields, got, want)
+                      if a.dtype != jnp.int32 or not np.array_equal(
+                          np.asarray(a), b.reshape(a.shape))]
+            check(not differ, f"the plan of {name} / {case} is not the "
+                              f"stable sort's in {differ}")
+            y = np.asarray(routed(x, jnp.asarray(ids), w, jnp.asarray(valid),
+                                  jnp.int32(first), stacks))
+            y_ref = np.asarray(planned(
+                x, w, tuple(jnp.asarray(a) for a in want), stacks, tile))
+            check(np.isfinite(y).all() and np.array_equal(y, y_ref),
+                  f"routed_experts of {name} / {case} is not what the sorted "
+                  f"plan gives: {np.abs(y - y_ref).max():.4g} apart")
+            out[f"{name}/{case}"] = {
+                "rows": rows, "assignments": int(want[5][0]),
+                "experts_touched": int(want[5][1]),
+                "output_scale": float(np.abs(y).max())}
+    return out
+
+
 def phase_group_flash(on_chip=True, tokens=12288, window=4096, block=1024,
                       layouts=(("group_of_7", 28, 4, 128),
                                ("pairs_of_64", 32, 8, 64)), tol=2e-2):
@@ -1317,6 +1420,7 @@ def main() -> int:
     run_phase(report, "window_expert_lm", phase_window_expert_lm)
     run_phase(report, "conv_expert_lm", phase_conv_expert_lm)
     run_phase(report, "expert_walk", phase_expert_walk)
+    run_phase(report, "expert_plan", phase_expert_plan)
     run_phase(report, "group_flash", phase_group_flash)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,

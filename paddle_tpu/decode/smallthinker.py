@@ -27,9 +27,9 @@ WINDOW layers:
   ``moe_num_active_primary_experts`` largest of the router's softmax scores
   (float32), renormalised over the chosen (``norm_topk_prob``), every
   assignment computed.  The router reads the layer's normed INPUT, so a
-  layer's routing — ``route_topk`` and ``plan_groups``, two sorts — is issued
-  before the layer's attention and is off the path from the attention to the
-  experts.
+  layer's routing — ``route_topk`` (a top-k) and ``plan_groups`` (a count of
+  the assignments an expert; no assignment is sorted) — is issued before the
+  layer's attention and is off the path from the attention to the experts.
 
 So a stream's state is of two kinds (:class:`~paddle_tpu.decode.cache.
 HybridStateCache` with no recurrent rows): blocks of a paged pool of the full

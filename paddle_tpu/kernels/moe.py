@@ -1,6 +1,6 @@
-"""Routed experts: top-k routing by sort and a grouped gated unit (SwiGLU or
-ReGLU) over the experts — the serving form of a sparse mixture, where every
-assignment is computed (no capacity, no token dropped).
+"""Routed experts: top-k routing, a plan of rows made by counting and a grouped
+gated unit (SwiGLU or ReGLU) over the experts — the serving form of a sparse
+mixture, where every assignment is computed (no capacity, no token dropped).
 
 - :func:`route_topk` — scores over all experts in float32 (``softmax``, or
   ``sigmoid``: an expert's own), the ``k`` largest (``lax.top_k`` keeps the
@@ -8,10 +8,47 @@ assignment is computed (no capacity, no token dropped).
   given, which chooses and does not weigh —, weights the chosen scores
   themselves, renormalised only when asked (over ``sum + eps``), times
   ``scale``.
-- :func:`plan_groups` — the sort: the valid tokens' assignments in expert
-  order, each expert's rows padded to a multiple of the row tile, so a tile
-  of rows belongs to exactly one expert.  Gathers and two small sorts; no
-  scatter.
+- :func:`plan_groups` — the plan of rows: the valid tokens' assignments in
+  expert order (a stable sort's: ties in the order ``t · K + k``), each
+  expert's rows padded to a multiple of the row tile, so a tile of rows
+  belongs to exactly one expert.  Made by COUNTING, not by sorting the
+  assignments: an assignment's place among its expert's is the number of
+  earlier assignments with its key — within a block of 256 every pair
+  compared, before the block a one-hot over the experts summed a block and
+  then over the blocks — so ``row_of`` is known where the assignment stands.
+  The experts' counts, their padded runs and the tile → expert map are
+  sums over comparisons with ``E`` entries; no table is indexed by a key
+  and nothing is searched.  What is left is the ONE inverse map, the token
+  of a row.  A decode step's plan (at most 1,024 assignments) compares every
+  row with every assignment; a prefill's takes one ``lax.sort`` — of the
+  ROWS: the assignments keyed by their rows together with, an expert, the
+  ``tile − 1`` rows that can pad its run, so the sorted tokens ARE the map
+  (``T·K + E·(tile − 1)`` keys, the plan's own length) and nothing is
+  gathered or scattered after it.  Integers throughout; the cost does not
+  depend on how the assignments fall.
+
+  The plan alone on one v5e, µs of device time a plan (PR 52; 64 experts
+  planned; forty plans in one program, so the host's dispatch is not in
+  it; the parent's two ``argsort``s, three ``searchsorted``s and seven
+  gathers → this one; uniform choices with a seventh of the tokens padding
+  | every assignment on one expert | every token invalid)::
+
+      a step, top-4 of 64               278 →  18 |   264 →  12 |   256 →  17
+      a step, top-6 of 64               308 →  15 |   308 →  15 |   311 →  13
+      a step, top-8 of 256, 64 held     317 →  17 |   314 →  15 |   312 →  14
+      2,048 x 6                         980 →  42 |   973 →  44 |   976 →  44
+      8,192 x 6                       2,409 → 115 | 2,442 → 122 | 2,413 → 118
+      12,288 x 6                      3,270 → 184 | 3,271 → 180 | 3,274 → 186
+      16,384 x 8 of 256, 64 held      5,165 → 254 | 5,174 → 258 | 5,171 → 256
+
+  The inverse map the other ways (the whole plan, at 2,048 × 6 / 8,192 × 6 /
+  16,384 × 8; the count with no inverse map reads 21–26 / 61–77 / 206–213):
+  one sort of ``(key, iota)`` and a gather of [R] from it 186 / 548 / 1,354;
+  a scatter of the tokens to their rows 82 / 305 / 843; this sort of the
+  rows 42 / 115 / 254.  The count as a product with a triangular 0/1 matrix
+  on the MXU (bf16 in, float32 out) read 37 / 106 / 246 where the
+  comparisons read 42 / 119 / 275 in the same call: within a tenth, and not
+  worth a float in an integer plan nor a [T·K, E] float32 temporary.
 - :func:`grouped_glu` — ``(act(x Wg_e) * (x Wu_e)) Wd_e`` for every row tile
   against its expert's three matrices, ``act`` the gate's activation
   (:data:`ACTS`: ``silu`` — SwiGLU — or ``relu`` — ReGLU).  ONE Pallas kernel
@@ -153,6 +190,13 @@ def plan_rows(tokens: int, k: int, experts: int, tile: int) -> int:
     return -(-rows // tile) * tile
 
 
+# assignments a block of the running count (:func:`_plan_by_count`), and the
+# most assignments whose rows are found by comparison, without a sort: a
+# decode step's (``row_tile``: at most 128 tokens, of up to eight choices)
+_COUNT_BLOCK = 256
+_COMPARED_ASSIGNMENTS = 1024
+
+
 def plan_groups(ids, valid, experts: int, tile: int, first=0) -> GroupPlan:
     """ids [T, K] int32 (chosen experts), valid [T] bool (False: the token is
     padding or its slot has no stream — it is routed nowhere and enters no
@@ -165,45 +209,81 @@ def plan_groups(ids, valid, experts: int, tile: int, first=0) -> GroupPlan:
     An assignment to an expert held elsewhere gets no row and enters no
     count; its weight stays what the router gave it, so the sum over the
     shares is the whole layer's.  A layer held whole is the share from 0."""
+    return _plan_by_count(ids, valid, jnp.asarray(first, jnp.int32),
+                          experts=experts, tile=tile)
+
+
+@functools.partial(jax.jit, static_argnames=("experts", "tile"))
+def _plan_by_count(ids, valid, first, *, experts, tile):
+    """:func:`plan_groups`' body, traced once for all the layers of a program
+    (as :func:`_expert_walk` is).  The plan is a stable sort's — assignments
+    in expert order, ties in the order ``t · K + k`` — made without sorting
+    the assignments: an assignment's place among its expert's is a COUNT of
+    the earlier ones with its key, so its row is known where it stands."""
     T, K = ids.shape
-    N = T * K
-    R = plan_rows(T, K, experts, tile)
-    local = ids - jnp.asarray(first, ids.dtype)
-    key = jnp.where(valid[:, None] & (local >= 0) & (local < experts),
-                    local, experts).reshape(N).astype(jnp.int32)
-    order = jnp.argsort(key, stable=True).astype(jnp.int32)
-    sorted_key = key[order]
-    starts = jnp.searchsorted(
-        sorted_key, jnp.arange(experts + 1, dtype=jnp.int32),
-        side="left").astype(jnp.int32)                  # [E + 1]
-    counts = starts[1:] - starts[:-1]                   # [E]
+    N, E = T * K, experts
+    R = plan_rows(T, K, E, tile)
+    local = ids - first.astype(ids.dtype)
+    key = jnp.where(valid[:, None] & (local >= 0) & (local < E),
+                    local, E).reshape(N).astype(jnp.int32)
+    held = key < E
+    # the running count, a block of assignments at a time: how many of the
+    # block's EARLIER assignments have this one's key (every pair of the
+    # block compared), and how many of the blocks before have it (a one-hot
+    # over the experts, summed a block, then over the blocks)
+    B = -(-N // _COUNT_BLOCK)
+    keyb = jnp.pad(key, (0, B * _COUNT_BLOCK - N),
+                   constant_values=E).reshape(B, _COUNT_BLOCK)
+    at = jnp.arange(_COUNT_BLOCK, dtype=jnp.int32)
+    earlier = jnp.sum((keyb[:, :, None] == keyb[:, None, :])
+                      & (at[:, None] < at[None, :]), axis=1,
+                      dtype=jnp.int32)                              # [B, blk]
+    one_hot = keyb[:, None, :] == jnp.arange(E, dtype=jnp.int32)[:, None]
+    in_block = jnp.sum(one_hot, axis=2, dtype=jnp.int32)            # [B, E]
+    counts = jnp.sum(in_block, axis=0, dtype=jnp.int32)             # [E]
+    before = jnp.cumsum(in_block, axis=0, dtype=jnp.int32) - in_block
     padded = -(-counts // tile) * tile
-    pend = jnp.cumsum(padded).astype(jnp.int32)         # inclusive ends
+    pend = jnp.cumsum(padded, dtype=jnp.int32)          # inclusive ends
     pstart = pend - padded
-    # rows → assignments
-    r = jnp.arange(R, dtype=jnp.int32)
-    e_r = jnp.minimum(jnp.searchsorted(pend, r, side="right"),
-                      experts - 1).astype(jnp.int32)
-    off = r - pstart[e_r]
-    real = (off >= 0) & (off < counts[e_r])
-    src = order[jnp.clip(starts[e_r] + off, 0, N - 1)] // K
-    row_token = jnp.where(real, src, T).astype(jnp.int32)
-    # assignments → rows
-    rank = jnp.argsort(order).astype(jnp.int32)         # place in sorted order
-    k_a = jnp.minimum(key, experts - 1)
-    row_flat = jnp.where(key < experts, pstart[k_a] + rank - starts[k_a], R)
-    # tiles → experts
-    n_tiles = R // tile
-    active = jnp.maximum(-(-pend[-1] // tile), 1).astype(jnp.int32)
-    last = jnp.minimum(jnp.arange(n_tiles, dtype=jnp.int32), active - 1)
+    # assignments → rows: the expert's first row, the count of the blocks
+    # before and the count within the block; no table is indexed by a key
+    row_flat = earlier + jnp.sum(
+        jnp.where(one_hot, (before + pstart)[:, :, None], 0), axis=1,
+        dtype=jnp.int32)
+    row_flat = jnp.where(held, row_flat.reshape(-1)[:N], R)
+    # rows → tokens, the one inverse map
+    token = jnp.arange(N, dtype=jnp.int32) // K
+    if N <= _COMPARED_ASSIGNMENTS:
+        # a decode step's: every row against every assignment, no sort
+        r = jnp.arange(R, dtype=jnp.int32)
+        row_token = T + jnp.sum(
+            jnp.where(row_flat[None, :] == r[:, None], token[None, :] - T, 0),
+            axis=1, dtype=jnp.int32)
+    else:
+        # ONE sort, of the ROWS: the assignments by their rows and, an
+        # expert, the ``tile − 1`` rows that may pad its run by theirs (those
+        # that do not, and the assignments with no row, last: ``R``) — the
+        # sorted tokens are the map itself, nothing is gathered or scattered
+        c = jnp.arange(tile - 1, dtype=jnp.int32)
+        pad_rows = jnp.where(c < (padded - counts)[:, None],
+                             (pstart + counts)[:, None] + c, R)
+        _, row_token = lax.sort(
+            (jnp.concatenate([row_flat, pad_rows.reshape(-1)]),
+             jnp.concatenate([jnp.where(held, token, T),
+                              jnp.full((E * (tile - 1),), T, jnp.int32)])),
+            num_keys=1, is_stable=False)
+        row_token = jnp.concatenate(
+            [row_token[:R],
+             jnp.full((max(R - row_token.shape[0], 0),), T, jnp.int32)])
+    # tiles → experts: how many experts end at or before the tile's first row
+    active = jnp.maximum(pend[-1] // tile, 1)
+    first_row = jnp.minimum(jnp.arange(R // tile, dtype=jnp.int32),
+                            active - 1) * tile
     tile_expert = jnp.minimum(
-        jnp.searchsorted(pend, last * tile, side="right"),
-        experts - 1).astype(jnp.int32)
-    load = jnp.stack([starts[experts], jnp.sum(counts > 0),
-                      jnp.max(counts)]).astype(jnp.int32)
-    return GroupPlan(row_token, row_flat.reshape(T, K).astype(jnp.int32),
-                     tile_expert, active.reshape(1), padded.astype(jnp.int32),
-                     load)
+        jnp.sum(pend <= first_row[:, None], axis=1, dtype=jnp.int32), E - 1)
+    load = jnp.stack([jnp.sum(counts), jnp.sum(counts > 0), jnp.max(counts)])
+    return GroupPlan(row_token, row_flat.reshape(T, K), tile_expert,
+                     active.reshape(1), padded, load.astype(jnp.int32))
 
 
 # the gate's activation by name: the kernel's name and the fallback's counter

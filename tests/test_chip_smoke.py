@@ -181,6 +181,19 @@ def test_expert_walk_phase_tiny():
                for p in ("prompt", "one_expert")) < 1e-2
 
 
+def test_expert_plan_phase_tiny():
+    out = chip_smoke.phase_expert_plan(
+        shapes=(("a_step", 24, 2, 8, 0), ("a_prompt_share", 200, 3, 16, 8)),
+        experts=8, hidden=128, expert_ffn=256)
+    assert sorted(out) == ["a_prompt_share/one_expert",
+                           "a_prompt_share/uniform", "a_step/one_expert",
+                           "a_step/uniform"]
+    assert out["a_step/one_expert"]["experts_touched"] == 1
+    assert out["a_step/uniform"]["assignments"] == 2 * (24 - 24 // 7)
+    assert out["a_prompt_share/one_expert"]["assignments"] == 3 * 172
+    assert all(v["output_scale"] > 0 for v in out.values())
+
+
 def test_group_flash_phase_tiny(monkeypatch):
     from paddle_tpu.kernels import gqa
     monkeypatch.setattr(gqa, "_Q_TILE", 8)

@@ -402,3 +402,82 @@ def test_the_shares_plan_their_own_experts_and_add_up_to_the_layer(tile, T):
         plan = moe.plan_groups(ids, valid, 4, tile, first=4)
         np.testing.assert_allclose(part, moe.planned_experts(
             x, w, plan, wg[4:8], wu[4:8], wd[4:8], tile), rtol=1e-6)
+
+
+def _sorted_plan(ids, valid, experts, tile, first):
+    """The plan by a NumPy stable sort: the valid, held assignments in expert
+    order (ties in the order ``t * K + k``), each expert's run padded to the
+    tile — all six fields of ``GroupPlan``."""
+    T, K = ids.shape
+    R = moe.plan_rows(T, K, experts, tile)
+    local = ids.astype(np.int64) - first
+    key = np.where(valid[:, None] & (local >= 0) & (local < experts), local,
+                   experts).reshape(-1)
+    order = np.argsort(key, kind="stable")
+    counts = np.bincount(key, minlength=experts + 1)[:experts]
+    padded = -(-counts // tile) * tile
+    pend = np.cumsum(padded)
+    row_token, row_of, at = np.full(R, T), np.full(T * K, R), 0
+    for e in range(experts):
+        mine = order[at:at + counts[e]]
+        rows = pend[e] - padded[e] + np.arange(counts[e])
+        row_token[rows], row_of[mine] = mine // K, rows
+        at += counts[e]
+    active = max(-(-int(pend[-1]) // tile), 1)
+    tile_expert = np.repeat(np.arange(experts), padded // tile)
+    # the tiles past the last real one repeat it; with no row at all the map
+    # points at the last expert, whose matrices no tile computes with
+    fill = tile_expert[-1] if len(tile_expert) else experts - 1
+    tile_expert = np.concatenate(
+        [tile_expert, np.full(R // tile - len(tile_expert), fill)])
+    return moe.GroupPlan(row_token, row_of.reshape(T, K), tile_expert,
+                         [active], padded,
+                         [counts.sum(), (counts > 0).sum(), counts.max()])
+
+
+# (tokens, choices a token, experts planned, the router's width, first, and
+# whether ``first`` reaches the plan as a traced scalar)
+PLAN_SHAPES = {
+    "a_step_6_of_64": (64, 6, 64, 64, 0, False),
+    "a_step_4_of_64": (64, 4, 64, 64, 0, False),
+    "a_step_8_of_256_share_0": (64, 8, 64, 256, 0, False),
+    "a_step_8_of_256_share_traced": (64, 8, 64, 256, 128, True),
+    "2048_by_6_of_64": (2048, 6, 64, 64, 0, False),
+    "3000_by_8_of_256_share_traced": (3000, 8, 64, 256, 64, True),
+}
+PLAN_CASES = (
+    [(s, t, v, i) for s in sorted(PLAN_SHAPES) if s.startswith("a_step")
+     for t in (8, 16, 128) for v in ("all", "tail", "none")
+     for i in ("uniform", "one_expert", "none_held")]
+    + [(s, 128, v, i) for s in sorted(PLAN_SHAPES) if s[0].isdigit()
+       for v in ("all", "tail", "none")
+       for i in ("uniform", "one_expert", "none_held")]
+    + [(s, 16, "tail", "uniform") for s in sorted(PLAN_SHAPES)
+       if s[0].isdigit()])
+
+
+@pytest.mark.parametrize("shape,tile,valid,ids", PLAN_CASES,
+                         ids=["-".join(map(str, c)) for c in PLAN_CASES])
+def test_the_plan_is_the_stable_sort_s_to_the_integer(shape, tile, valid,
+                                                       ids):
+    T, K, E, wide, first, traced = PLAN_SHAPES[shape]
+    rng = np.random.RandomState(len(shape) + tile)
+    if ids == "uniform":
+        chosen = np.argsort(rng.rand(T, wide), axis=1)[:, :K]
+    elif ids == "one_expert":
+        chosen = np.full((T, K), first + 3)
+    else:           # every choice an expert held elsewhere (or none's at all)
+        chosen = (first + E + rng.randint(0, E, (T, K))) % max(wide, 2 * E)
+        assert not ((chosen >= first) & (chosen < first + E)).any()
+    ok = {"all": np.ones(T, bool), "tail": np.arange(T) < T - T // 7,
+          "none": np.zeros(T, bool)}[valid]
+    chosen = chosen.astype(np.int32)
+    plan = (jax.jit(moe.plan_groups, static_argnums=(2, 3)) if traced
+            else moe.plan_groups)
+    got = plan(jnp.asarray(chosen), jnp.asarray(ok), E, tile,
+               jnp.int32(first) if traced else first)
+    want = _sorted_plan(chosen, ok, E, tile, first)
+    for name, a, b in zip(moe.GroupPlan._fields, got, want):
+        assert a.dtype == jnp.int32, name
+        np.testing.assert_array_equal(a, np.asarray(b).reshape(a.shape),
+                                      err_msg=name)

@@ -45,11 +45,14 @@ package is that state plane, built on the repo's own primitives:
   among them ``step_live_blocks`` of ``step_table_blocks`` on ``/decodez``,
   the share of the tables handed to the decode steps' attention kernels that
   their walks fetched.
-- **Seven models behind the one engine**: :mod:`model` (the repo's LM block:
+- **Eight models behind the one engine**: :mod:`model` (the repo's LM block:
   K and V of every layer paged, a suffix prefill, so the one model that
   ``supports`` prefix cache, overcommit and beams); :mod:`mla` (DeepSeek-V2:
   latent attention over ONE latent pool, YaRN, routed experts beside shared
-  ones); :mod:`sambay` (Phi-4-mini-flash-reasoning: state-space layers,
+  ones; and under ``model_type`` ``xing4_0`` the same body with a low-rank
+  query, a sigmoid router with a selection bias and FOUR residual streams a
+  token mixed by manifold-constrained hyper-connections, ``kernels/mhc.py``);
+  :mod:`sambay` (Phi-4-mini-flash-reasoning: state-space layers,
   window rings, ONE full-attention layer's pool that the cross-attention
   layers read, differential attention); :mod:`falcon_h1` (Falcon-H1: a
   Mamba-2 mixer and grouped-query attention side by side in every layer);
@@ -95,7 +98,8 @@ from .cache import (BlockAllocator, HybridStateCache,  # noqa: F401
                     PagedKVCache, PagedLatentCache, PrefixCache)
 from .model import (LMConfig, TransformerLM, load_lm,  # noqa: F401
                     save_lm)
-from .mla import MLAConfig, MLATransformerLM  # noqa: F401
+from .mla import (HyperMLAConfig, HyperMLATransformerLM,  # noqa: F401
+                  MLAConfig, MLATransformerLM)
 from .sambay import SambaYConfig, SambaYLM  # noqa: F401
 from .falcon_h1 import FalconH1Config, FalconH1LM  # noqa: F401
 from .smallthinker import (SmallThinkerConfig,  # noqa: F401
@@ -115,7 +119,8 @@ __all__ = [
     "BlockAllocator", "PagedKVCache", "PagedLatentCache", "HybridStateCache",
     "PrefixCache",
     "LMConfig", "TransformerLM", "save_lm", "load_lm",
-    "MLAConfig", "MLATransformerLM", "SambaYConfig", "SambaYLM",
+    "MLAConfig", "MLATransformerLM", "HyperMLAConfig",
+    "HyperMLATransformerLM", "SambaYConfig", "SambaYLM",
     "FalconH1Config", "FalconH1LM", "SmallThinkerConfig", "SmallThinkerLM",
     "LFM2Config", "LFM2LM", "KimiLinearConfig", "KimiLinearLM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",

@@ -116,8 +116,10 @@ class LatentAttention:
     ``rope(x, pos)`` rotates the ``rope_dim`` slices of queries and keys (x
     [..., rope_dim], pos broadcastable to x's leading axes); ``None`` leaves
     them as they are projected — **position-free keys**, a model whose
-    positions come from elsewhere.  ``w`` is ONE layer's tensors: ``wq``,
-    ``wkva``, ``kv_norm``, ``wkvb``, ``wo``."""
+    positions come from elsewhere.  ``w`` is ONE layer's tensors: ``wkva``,
+    ``kv_norm``, ``wkvb``, ``wo`` and the query's — one ``wq``, or where the
+    model has a ``q_lora_rank`` the low-rank pair round an RMS norm, ``wq_b ·
+    RMSNorm(wq_a · x; q_norm)`` (``wq_a``, ``q_norm``, ``wq_b``)."""
 
     def __init__(self, heads: int, nope: int, rope_dim: int, v_dim: int,
                  rank: int, eps: float, scale: float,
@@ -133,8 +135,9 @@ class LatentAttention:
         what the cache holds."""
         dn, r = self.nope, self.rank
         with jax.named_scope("mla_wq"):
-            q = mm(x, w["wq"]).reshape(x.shape[0], self.heads,
-                                       dn + self.rope_dim)
+            q = mm(x, w["wq"]) if "wq" in w else mm(
+                rms_norm(mm(x, w["wq_a"]), w["q_norm"], self.eps), w["wq_b"])
+            q = q.reshape(x.shape[0], self.heads, dn + self.rope_dim)
         with jax.named_scope("mla_wkva"):
             kva = mm(x, w["wkva"])
             c = rms_norm(kva[:, :r], w["kv_norm"], self.eps)

@@ -15,6 +15,8 @@ static-shape/recompile-cache policy SURVEY.md §7 calls out.
 """
 from __future__ import annotations
 
+import functools
+import math
 import re
 import threading
 import time
@@ -111,6 +113,81 @@ def _program_name(key: str) -> str:
     return "fn_" + re.sub(r"\W+", "_", key).strip("_")
 
 
+# the feed dtypes ``run_callable`` packs, each with the name it signs
+# under: four bytes wide, so an entry is a run of int32 words whatever
+# it holds
+_PACKED_DTYPES = {np.dtype(t): t for t in ("int32", "uint32", "float32")}
+
+
+@functools.lru_cache(maxsize=None)
+def _sig_names(prefix: str, n: int) -> tuple:
+    """The names ``run_callable`` signs its n feed / state / const
+    entries under: built once a length, not once a launch."""
+    return tuple(f"{prefix}{i}" for i in range(n))
+
+
+def _pack_feed(feed):
+    """One host-to-device transfer for a ``run_callable`` feed.
+
+    Host entries (NumPy arrays and scalars) whose canonical dtype is
+    int32 / uint32 / float32 are viewed as int32 words and laid end to
+    end in one buffer; ``_unpack_feed`` cuts it apart again inside the
+    compiled program, bit for bit.  Every other entry goes ``loose``: a
+    ``jax.Array`` as it is, anything else through its own
+    ``jnp.asarray`` as before.  Returns ``(sig, layout, packed, loose,
+    nbytes, transfers)``: ``sig`` the logical signature — the entries'
+    own shapes and canonical dtypes, what a feed converted entry by entry
+    would sign as; ``layout`` the static ``(offset, shape, dtype)`` per
+    packed entry, None per loose one; ``packed`` the device buffer (None
+    when nothing was packed); ``nbytes`` the logical entries' bytes."""
+    sig, layout, pieces, loose = [], [], [], []
+    words = nbytes = transfers = 0
+    for name, v in zip(_sig_names("", len(feed)), feed):
+        if isinstance(v, (np.ndarray, np.generic)):
+            dt = v.dtype if v.dtype in _PACKED_DTYPES \
+                else jax.dtypes.canonicalize_dtype(v.dtype)
+            dtype = _PACKED_DTYPES.get(dt)
+            if dtype is not None:
+                a = np.asarray(v, dt)
+                sig.append((name, a.shape, dtype))
+                layout.append((words, a.shape, dtype))
+                pieces.append(a.reshape(-1).view(np.int32))
+                words += a.size
+                continue
+        if not isinstance(v, jax.Array):
+            v = jnp.asarray(v)
+            transfers += 1
+        sig.append((name, tuple(v.shape), str(v.dtype)))
+        layout.append(None)
+        loose.append(v)
+        nbytes += _obs_step.approx_nbytes(v)
+    packed = None
+    if pieces:
+        packed = jax.device_put(np.concatenate(pieces))
+        transfers += 1
+    return (tuple(sig), tuple(layout), packed, loose, nbytes + 4 * words,
+            transfers)
+
+
+def _unpack_feed(layout, packed, loose):
+    """Inside the compiled program: the feed list ``_pack_feed`` took
+    apart, each packed entry a static slice of ``packed`` reshaped and
+    bitcast back to its dtype."""
+    loose = iter(loose)
+    feed = []
+    for spec in layout:
+        if spec is None:
+            feed.append(next(loose))
+            continue
+        off, shape, dtype = spec
+        piece = jax.lax.slice(packed, (off,), (off + math.prod(shape),))
+        piece = piece.reshape(shape)
+        if dtype != "int32":
+            piece = jax.lax.bitcast_convert_type(piece, dtype)
+        feed.append(piece)
+    return feed
+
+
 def _em():
     """Cached executor metric handles: registering through the registry
     on every run costs a lock + dict round trip per metric; the handles
@@ -131,6 +208,15 @@ def _em():
                 "compile-cache misses caused by a new feed-shape bucket "
                 "for an already-compiled program"),
             evictions=sc.counter("cache_evictions"),
+            const_sig_reuses=sc.counter(
+                "const_sig_reuses",
+                "run_callable launches that signed their constants by "
+                "identity with the last launch's instead of walking them"),
+            feed_transfers=sc.counter(
+                "feed_transfers",
+                "host-to-device transfers run_callable made for feeds: one "
+                "for the packed buffer and one per host entry it cannot "
+                "pack (beside steps: 1.0 a launch for an engine's feed)"),
             feed_bytes=sc.counter("feed_bytes"),
             fetch_bytes=sc.counter("fetch_bytes"),
             wall=sc.histogram("run_wall_ms"),
@@ -515,6 +601,9 @@ class Executor:
         # telemetry: feed signatures seen per (program, fetch, mode) base
         # key, to distinguish shape-bucket recompiles from first compiles
         self._seen_shapes: Dict = {}
+        # (weakrefs of the constants run_callable's last launch brought,
+        # their signature): see _const_sig
+        self._const_memo = None
         # lowering mode: inference executors (the Predictor) pass
         # training=False so ctx.training-gated lowerings (dropout off
         # without an is_test attr, Pallas RNN cells inside the fusion ops
@@ -805,6 +894,25 @@ class Executor:
         can pin "zero recompiles under mixed traffic" for callable
         dispatches exactly as it does for program dispatches.
 
+        ``feed`` reaches the device in ONE transfer (``_pack_feed``):
+        every host entry — a NumPy array or scalar — whose canonical
+        dtype is int32, uint32 or float32 is laid as int32 words in one
+        buffer, and the compiled program cuts the buffer by static
+        offsets and bitcasts each piece back, so ``fn`` gets the list it
+        was sent, bit for bit.  What passes through: a ``jax.Array``
+        entry as it is; a host entry of any other width (or a Python
+        value) through its own ``jnp.asarray``.  The signature is taken
+        from the LOGICAL entries — each one's own shape and canonical
+        dtype, read off the host values before anything is converted —
+        so the same arrays under the same key hit the same executable,
+        whoever sends them in that form (which entries were packed is
+        part of the cache key: a feed that signs the same but arrives
+        otherwise, one entry already a ``jax.Array``, is a miss and
+        builds its own).  ``const``'s part of it is not rebuilt while
+        every array IS the one the last launch brought
+        (``executor.const_sig_reuses``); ``executor.feed_transfers``
+        beside ``executor.steps`` counts the transfers a launch.
+
         ``state`` buffers are DONATED: they stay device-resident and
         update in place in HBM across dispatches (a paged KV cache
         never round-trips to host); the caller must carry the returned
@@ -826,15 +934,21 @@ class Executor:
         tel = _obs_trace.flags_on()
         t_run0 = time.perf_counter_ns() if tel else None
         with _obs_trace.span("executor::feed"):
-            feed = [v if isinstance(v, jax.Array) else jnp.asarray(v)
-                    for v in feed]
+            feed_sig, layout, packed, loose, feed_bytes, transfers = \
+                _pack_feed(feed)
         state = list(state)
         const = list(const)
-        sig = (self._feed_sig([str(i) for i in range(len(feed))], feed)
-               + self._feed_sig([f"s{i}" for i in range(len(state))], state)
-               + self._feed_sig([f"c{i}" for i in range(len(const))], const))
+        const_sig, reused = self._const_sig(const)
+        sig = (feed_sig
+               + self._feed_sig(_sig_names("s", len(state)), state)
+               + const_sig)
+        # which entries were packed is part of the key: the program is
+        # built round one layout, so a feed that signs the same but
+        # arrives otherwise (an entry already on the device) is a miss
+        # that is counted, not a silent second compile under a hit
+        arrival = tuple(spec is not None for spec in layout)
         base = ("callable", key, self._training)
-        mem_key = ("callable", key, sig, self._training)
+        mem_key = ("callable", key, sig, self._training, arrival)
         entry = self._cache.get(mem_key)
         cache_hit = entry is not None
         lowering_ms = 0.0
@@ -842,14 +956,19 @@ class Executor:
             t_low0 = time.perf_counter_ns()
             with _obs_trace.span("executor::lower", key=key):
                 fn = build_fn()
-                fn.__name__ = fn.__qualname__ = _program_name(key)
-                jitted = jax.jit(fn, donate_argnums=(1,))
+
+                def run(packed, loose, state, const):
+                    return fn(_unpack_feed(layout, packed, loose), state,
+                              const)
+
+                run.__name__ = run.__qualname__ = _program_name(key)
+                jitted = jax.jit(run, donate_argnums=(2,))
             lowering_ms = (time.perf_counter_ns() - t_low0) / 1e6
             entry = _CacheEntry(None, jitted)
             self._cache[mem_key] = entry
             self._evict_cache_overflow()
             if tel:
-                self._note_cache_miss(base, sig)
+                self._note_cache_miss(base, (sig, arrival))
         elif tel:
             _em().hits.inc()
         compile_ms = 0.0
@@ -857,7 +976,7 @@ class Executor:
         with _obs_trace.start_span("executor::dispatch", cat="executor",
                                    root=False), \
                 _obs_trace.span("executor::dispatch", key=key):
-            outs, new_state = entry.jitted(feed, state, const)
+            outs, new_state = entry.jitted(packed, loose, state, const)
         if t_disp0 is not None:
             # first call of a fresh executable: the synchronous part
             # is jax trace + XLA compile (execution is async)
@@ -867,6 +986,9 @@ class Executor:
         if tel:
             m = _em()
             m.steps.inc()
+            m.feed_transfers.inc(transfers)
+            if reused:
+                m.const_sig_reuses.inc()
             wall_ms = (time.perf_counter_ns() - t_run0) / 1e6
             m.wall.observe(wall_ms)
             _obs_step.record(_obs_step.StepStats(
@@ -874,10 +996,36 @@ class Executor:
                 cache_hit=cache_hit,
                 lowering_ms=round(lowering_ms, 3),
                 compile_ms=round(compile_ms, 3),
-                feed_bytes=sum(_obs_step.approx_nbytes(v) for v in feed),
+                feed_bytes=feed_bytes,
                 fetch_bytes=sum(_obs_step.approx_nbytes(v) for v in outs),
                 wall_ms=round(wall_ms, 3)))
         return outs, new_state
+
+    def _const_sig(self, const):
+        """``const``'s part of a callable's signature, and whether it
+        was reused.  The constants are the same arrays launch after
+        launch whatever the key (an engine's weights, under its step and
+        every prefill rung), so the last launch's are remembered with
+        their signature and a launch whose every array IS the one
+        remembered does not walk them again.  Remembered weakly: a dead
+        reference matches nothing, so an ``id`` cannot be recycled under
+        it, and weights a caller swapped in for one launch are not kept
+        on the device for this memo's sake."""
+        if not const:
+            return (), False
+        memo = self._const_memo
+        if memo is not None and len(memo[0]) == len(const):
+            for ref, v in zip(memo[0], const):
+                if ref() is not v:
+                    break
+            else:
+                return memo[1], True
+        sig = self._feed_sig(_sig_names("c", len(const)), const)
+        try:
+            self._const_memo = ([weakref.ref(v) for v in const], sig)
+        except TypeError:       # a constant no weakref can watch
+            self._const_memo = None
+        return sig, False
 
     def run_steps(
         self,

@@ -874,9 +874,9 @@ class DecodeEngine:
         fresh = start == 0 and req.resume_tokens is None
         ladder = self.prefill_ladder if fresh else self._resume_ladder
         bucket = ladder.snap(slot.seq.size - start)
-        with _trace.span("decode::prefill", rid=req.rid, bucket=bucket,
-                         prompt=int(slot.seq.size),
-                         queue_ms=queue_ms) as sp:
+        with _trace.cpu_span("decode::prefill", rid=req.rid, bucket=bucket,
+                             prompt=int(slot.seq.size),
+                             queue_ms=queue_ms) as sp:
             if not fresh:
                 sp.annotate(start=start)
             self._prefill_traced(i, slot, req, t0, bucket)
@@ -916,7 +916,7 @@ class DecodeEngine:
             f"decode/{self.name}/{program}/{bucket}", build, feed,
             state=self.cache.state(), const=self._plist)
         self.cache.update(new_state)
-        with _trace.span("decode::prefill.wait"):
+        with _trace.cpu_span("decode::prefill.wait"):
             first = int(np.asarray(tok))
             logits_np = np.asarray(logits) if self.capture_logits else None
             self._observer.prefill(extra, n, bucket)
@@ -979,7 +979,9 @@ class DecodeEngine:
             self._pstats.prefix_inserts.inc(inserted)
 
     def _decode_step(self) -> None:
-        with _trace.span("decode::step") as sp:
+        # the two launch spans and their waits carry the thread's CPU time
+        # while a profiler listens: step_off_cpu_ms reads queueing from it
+        with _trace.cpu_span("decode::step") as sp:
             self._decode_step_traced(sp)
 
     def _decode_step_traced(self, sp) -> None:
@@ -1044,7 +1046,7 @@ class DecodeEngine:
         # computes this step and this thread waits for it below with
         # the interpreter released
         self._flush_fanout(step_in_flight=True)
-        with _trace.span("decode::step.wait"):
+        with _trace.cpu_span("decode::step.wait"):
             toks_np = np.asarray(toks)
             logits_np = np.asarray(logits) if self.capture_logits else None
             self._observer.step(extra, positions[live] + 1)

@@ -14,7 +14,11 @@ benchmark's ``--trace 1``.  The spans then land in the same
 of a millisecond on a v5e), so an idle gap of the device can be put
 under the name of what the host was doing.  Nesting on a thread gives the
 parent; keyword arguments carry the identifiers (``rid``, ``bucket``,
-``key``).  While ``paddle_tpu.profiler`` is armed the span is also filed
+``key``).  A span opened with :func:`cpu_span` also carries ``cpu_ns``
+while a session is live, its thread's CPU time between its two ends, so a
+reader can tell running from queueing for the interpreter: the two launch
+spans of the decode engine and their ``.wait`` children are such.  While
+``paddle_tpu.profiler`` is armed the span is also filed
 in that module's event list under a ``runtime::`` prefix, so
 ``profiler.chrome_trace()`` shows the lower→dispatch pipeline beside
 user ``train_step`` spans.  Gated by ``FLAGS_runtime_stats`` alone.
@@ -34,8 +38,16 @@ Chrome/Perfetto JSON with real ``pid``/process-name metadata.  This is
 the cross-process tool; program spans are the on-chip one.
 
 Overhead discipline: with no profiler session and the profiler unarmed
-a program span costs about a microsecond (two small objects and an
-inactive annotation) and leaves nothing behind; with sampling off
+a program span costs 0.9–1.1 µs (two small objects and an inactive
+annotation), reads no clock and leaves nothing behind, and a
+:func:`cpu_span` 0.1 µs more (the question whether a session is live);
+with a session live 3.1–4.0 µs and 5.0–5.9 µs (two
+``time.thread_time_ns()`` of 0.3 µs and one ``set_metadata`` more) — this
+sandbox's CPU, an empty span, the best of three runs of five loops, PR 56.
+On the v5e's hosts the thread clock costs 5.8 µs a read in a quiet process
+and some 25 µs inside a serving cell, and advances 10 ms at a time (PERF.md
+§6, PR 56), which is why only four spans read it;
+with sampling off
 (``FLAGS_trace_sample_rate=0``, the default) ``start_span`` is a
 thread-local read plus two dict lookups and returns a shared no-op —
 no ring writes, no wire bytes.  Ring-span timestamps use
@@ -101,6 +113,19 @@ def span(name: str, **args):
     if not flags_on():
         return _NOOP
     return _RuntimeSpan(name, **args)
+
+
+class _CpuSpan(_RuntimeSpan):
+    cpu_clock = True
+
+
+def cpu_span(name: str, **args):
+    """:func:`span`, carrying ``cpu_ns`` while a profiler listens
+    (``profiler.RecordEvent``): for the few spans a reader subtracts CPU
+    time from, since every one costs two reads of the thread clock."""
+    if not flags_on():
+        return _NOOP
+    return _CpuSpan(name, **args)
 
 
 # ---------------------------------------------------------------------------

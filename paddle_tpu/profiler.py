@@ -109,23 +109,35 @@ class RecordEvent:
     The decorator opens a FRESH span per call (never the shared instance
     state), so decorated functions are re-entrant and thread-safe.
     Keyword arguments become the annotation's arguments (event stats in
-    the ``.xplane.pb``).
+    the ``.xplane.pb``).  A subclass that sets ``cpu_clock`` also carries
+    ``cpu_ns`` while a ``jax.profiler`` session is live: the CPU time of
+    ITS thread between its two ends (``time.thread_time_ns``), so a reader
+    has ``duration − cpu_ns``, the time the thread was off the CPU —
+    blocked, or queueing for the interpreter.  With no session no clock is
+    read and nothing is added; a span opened before the session started
+    carries none.  Not every span: the thread clock is a system call, 0.3
+    µs a read on a plain Linux; on the v5e's hosts 5.8 µs in a quiet
+    process and some 25 µs inside a serving cell (PERF.md §6, PR 56).
     """
 
     cat = "op"     # the event list's category and name prefix: the
     prefix = ""    # runtime's own spans (observability.trace) set both
+    cpu_clock = False
 
     def __init__(self, name: str, **args):
         self.name = name
         self._args = args
         self._ann = None
         self._t0 = None
+        self._cpu0 = None
 
     def __enter__(self):
         # the annotation is a no-op unless a jax.profiler session is live;
         # then the span lands beside the device ops, in their file
         self._ann = TraceAnnotation(self.name, **self._args)
         self._ann.__enter__()
+        if self.cpu_clock and TraceAnnotation.is_enabled():
+            self._cpu0 = time.thread_time_ns()
         if _state["enabled"]:
             self._t0 = time.perf_counter_ns()
         return self
@@ -142,6 +154,9 @@ class RecordEvent:
             self._t0 = None
         ann, self._ann = self._ann, None
         if ann is not None:
+            if self._cpu0 is not None:
+                ann.set_metadata(cpu_ns=time.thread_time_ns() - self._cpu0)
+                self._cpu0 = None
             ann.__exit__(*a)
         return False
 

@@ -336,8 +336,8 @@ REPLAYED_FREE_LIST = [10, 11, 4, 5, 6, 7, 8, 3, 9, 12, 13, 1, 2]
 
 
 def _filed_spans(monkeypatch):
-    """Every ``trace.span`` of the process, as (name, arguments), filed
-    when it closes."""
+    """Every ``trace.span`` and ``trace.cpu_span`` of the process, as (name,
+    arguments), filed when it closes."""
     from paddle_tpu.observability import trace
     filed = []
 
@@ -354,7 +354,8 @@ def _filed_spans(monkeypatch):
         def annotate(self, **args):
             self.args.update(args)
 
-    monkeypatch.setattr(trace, "span", lambda name, **a: Span(name, a))
+    for opener in ("span", "cpu_span"):
+        monkeypatch.setattr(trace, opener, lambda name, **a: Span(name, a))
     return filed
 
 

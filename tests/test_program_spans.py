@@ -1,11 +1,14 @@
 """The runtime's one span primitive (``observability.trace.span``): spans of
 the decode engine's thread and the executor's dispatch in the profiler's own
 file, a name for every compiled program, and the two counters at the same
-boundaries (``decode.<model>.queue_ms``, ``executor.build_ms``)."""
+boundaries (``decode.<model>.queue_ms``, ``executor.build_ms``); and the CPU
+time a ``cpu_span`` carries while a profiler listens (``cpu_ns``), every
+assertion on it one-sided in the direction a loaded machine pushes."""
 import glob
 import math
 import os
 import re
+import threading
 import time
 
 import jax
@@ -237,6 +240,127 @@ def test_a_span_leaves_nothing_behind_without_a_listener():
     assert profiler.events() == events
     assert trace.total_spans_recorded() == ring
     assert not hasattr(trace, "emit") and not hasattr(trace, "enabled")
+
+
+def _spin(seconds, clock=time.perf_counter):
+    end = clock() + seconds
+    while clock() < end:
+        pass
+
+
+def _spin_until(stop):
+    while not stop.is_set():
+        pass
+
+
+class _ClockedEvent(profiler.RecordEvent):
+    cpu_clock = True
+
+
+@pytest.fixture(scope="module")
+def clocked(tmp_path_factory):
+    """One session round a span that sleeps, one that spins, one that spins
+    while a second thread spins for the interpreter too, a nest, a plain
+    span, and one opened before the session began; with the CPU time the
+    test stamped round the lone spin itself."""
+    out = str(tmp_path_factory.mktemp("clocked"))
+    stop = threading.Event()
+    rival = threading.Thread(target=_spin_until, args=(stop,), daemon=True)
+    early = _ClockedEvent("user_early")
+    early.__enter__()
+    jax.profiler.start_trace(out)
+    try:
+        early.__exit__(None, None, None)
+        with _ClockedEvent("user_sleep"):
+            time.sleep(0.05)
+        with trace.cpu_span("clock::spin"):
+            c0 = time.thread_time_ns()
+            _spin(0.03, time.thread_time)
+            mine = time.thread_time_ns() - c0
+        with trace.cpu_span("clock::outer"):
+            _spin(0.005)
+            with trace.cpu_span("clock::inner"):
+                _spin(0.01)
+            with trace.span("clock::plain"), profiler.RecordEvent("user_plain"):
+                pass
+        rival.start()
+        with _ClockedEvent("user_contended"):
+            _spin(0.2)
+    finally:
+        stop.set()
+        jax.profiler.stop_trace()
+        rival.join(timeout=30)
+    assert not rival.is_alive()
+    (xplane,) = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                       "*.xplane.pb"))
+    by_name = {s[0]: s for spans in _read(xplane)["spans"].values()
+               for s in spans}
+    return by_name, mine
+
+
+def test_a_sleeping_span_was_off_the_cpu_and_a_spinning_one_on_it(clocked):
+    by_name, mine = clocked
+    _, lo, hi, args = by_name["user_sleep"]
+    assert hi - lo >= 50e6 and args["cpu_ns"] < (hi - lo) / 2
+    _, lo, hi, args = by_name["clock::spin"]
+    # at least what the test stamped inside it, and no more than it lasted
+    assert mine >= 30e6
+    assert mine - 1e6 <= args["cpu_ns"] <= hi - lo + 1e6
+
+
+def test_a_thread_that_queues_for_the_interpreter_reads_as_off_the_cpu(
+        clocked):
+    _, lo, hi, args = clocked[0]["user_contended"]
+    assert hi - lo >= 0.2e9
+    assert (hi - lo) - args["cpu_ns"] >= (hi - lo) / 5
+
+
+def test_a_nested_span_carries_no_more_cpu_time_than_its_parent(clocked):
+    by_name, _ = clocked
+    (_, olo, ohi, outer), (_, ilo, ihi, inner) = (
+        by_name["clock::outer"], by_name["clock::inner"])
+    assert olo <= ilo and ihi <= ohi
+    assert 0 < inner["cpu_ns"] <= outer["cpu_ns"] + 1e6
+
+
+def test_a_plain_span_and_one_opened_before_the_session_carry_none(clocked):
+    by_name, _ = clocked
+    assert "cpu_ns" not in by_name["clock::plain"][3]
+    assert "cpu_ns" not in by_name["user_plain"][3]
+    assert "cpu_ns" not in by_name.get("user_early", (0, 0, 0, {}))[3]
+
+
+def test_the_launch_spans_and_their_waits_carry_their_threads_cpu_time(
+        traced):
+    spans = _thread_with(traced, "decode::step")
+    clocked = {"decode::step", "decode::step.wait", "decode::prefill",
+               "decode::prefill.wait"}
+    assert {s[0] for s in spans if "cpu_ns" in s[3]} == clocked
+    for name, lo, hi, args in spans:
+        if name in clocked:
+            assert 0 <= args["cpu_ns"] <= hi - lo + 1e6
+    for launch in (s for s in spans
+                   if s[0] in ("decode::step", "decode::prefill")):
+        (wait,) = [k for k in _children(spans, launch)
+                   if k[0] == launch[0] + ".wait"]
+        assert wait[3]["cpu_ns"] <= launch[3]["cpu_ns"] + 1e6
+
+
+def test_no_cpu_clock_is_read_without_a_listener(monkeypatch):
+    assert not jax.profiler.TraceAnnotation.is_enabled()
+    calls = []
+    for clock in ("thread_time_ns", "process_time_ns"):
+        real = getattr(time, clock)
+        monkeypatch.setattr(time, clock, lambda real=real, clock=clock: (
+            calls.append(clock), real())[1])
+    engine = _engine("unheard")
+    try:
+        _serve(engine, prompts=(5, 12), new_tokens=3)
+    finally:
+        engine.close()
+    with _ClockedEvent("user_step"), trace.cpu_span("decode::step") as sp:
+        sp.annotate(live=0)
+    assert calls == []
 
 
 def test_a_span_is_filed_under_runtime_while_the_profiler_is_armed(capsys):

@@ -13,6 +13,7 @@
 //
 // Build: make -C native   (g++ -O2 -fPIC -shared -lz -lpthread)
 
+#include <atomic>
 #include <condition_variable>
 #include <cstdint>
 #include <cstdio>
@@ -319,7 +320,14 @@ void ptq_pool_destroy(void* pp) {
 // the Python layer above never loops on syscalls.
 // ---------------------------------------------------------------------------
 
-struct Conn { int fd; };
+// ``tail``, ``verdict`` and ``queued`` belong to the frame pusher below
+// (ptq_conn_send_frames); every other entry leaves them alone.
+struct Conn {
+  int fd;
+  std::string tail;   // what the socket would not take without blocking
+  int verdict = 0;    // of the frames the pusher has handled: 0, 1 or -1
+  size_t queued = 0;  // frames handed to the pusher and not yet handled
+};
 struct Listener { int fd; };
 
 static int write_all(int fd, const char* p, size_t n) {
@@ -435,6 +443,136 @@ int ptq_conn_send_frame_vec(void* cp, void** bufs, const size_t* lens,
   return 0;
 }
 
+// ---------------------------------------------------------------------------
+// The frame pusher: one frame to each of n connections in ONE foreign call
+// that does no I/O at all — the decode plane's token fan-out
+// (decode/engine.py), whose caller is the one thread every stream waits
+// for.  The call copies the frames onto a queue; ONE native thread, which
+// never needs the interpreter, writes them, each with a send that cannot
+// block.  A connection's frames are written in the order they were handed
+// in; what a socket will not take at once is remembered on the connection,
+// so nothing torn reaches the wire and a slow or dead reader costs the
+// others nothing.
+// ---------------------------------------------------------------------------
+
+struct PushJob {
+  Conn* c;
+  std::string frame;  // u32 length + body
+};
+
+struct Pusher {
+  std::mutex mu;  // guards jobs and every Conn's queued / verdict
+  std::condition_variable work, handled;
+  std::deque<PushJob> jobs;
+};
+
+// set once a frame has been pushed: a process that never pushes starts no
+// thread, and closing its connections waits for none
+static std::atomic<Pusher*> g_pusher{nullptr};
+
+// -> 0 the whole frame is in the socket, 1 kept on the tail, -1 peer gone
+static int push_one(Conn* c, const std::string& frame) {
+  size_t sent = 0;
+  if (c->tail.empty()) {
+    ssize_t w;
+    do {
+      w = ::send(c->fd, frame.data(), frame.size(),
+                 MSG_DONTWAIT | MSG_NOSIGNAL);
+    } while (w < 0 && errno == EINTR);
+    if (w < 0 && errno != EAGAIN && errno != EWOULDBLOCK) return -1;
+    sent = w < 0 ? 0 : static_cast<size_t>(w);
+    if (sent == frame.size()) return 0;
+  }
+  c->tail.append(frame, sent, std::string::npos);
+  return 1;
+}
+
+static void pusher_loop(Pusher* p) {
+  std::deque<PushJob> batch;
+  for (;;) {
+    {
+      std::unique_lock<std::mutex> lk(p->mu);
+      p->work.wait(lk, [p] { return !p->jobs.empty(); });
+      batch.swap(p->jobs);
+    }
+    for (auto& job : batch) {
+      // ``queued`` > 0 keeps the connection alive and its tail ours
+      int verdict = push_one(job.c, job.frame);
+      {
+        std::lock_guard<std::mutex> lk(p->mu);
+        if (verdict != 0 && job.c->verdict == 0) job.c->verdict = verdict;
+        --job.c->queued;
+      }
+      p->handled.notify_all();
+    }
+    batch.clear();
+  }
+}
+
+static Pusher* pusher() {
+  static Pusher* made = [] {
+    auto* p = new Pusher();  // never freed: its thread runs to the end
+    std::thread(pusher_loop, p).detach();
+    g_pusher.store(p, std::memory_order_release);
+    return p;
+  }();
+  return made;
+}
+
+// Wait until the pusher holds no frame of this connection.
+static void push_quiesce(Conn* c) {
+  Pusher* p = g_pusher.load(std::memory_order_acquire);
+  if (!p) return;
+  std::unique_lock<std::mutex> lk(p->mu);
+  p->handled.wait(lk, [c] { return c->queued == 0; });
+}
+
+// ``bodies`` holds the n frame bodies back to back, ``lens`` their lengths.
+// rcs[i] says what connection i's EARLIER frames met (the writing happens
+// behind the call):
+//   0  every one is in its socket, whole; this frame follows them;
+//   1  the socket would not take one at once: what was left of it is
+//      remembered on the connection and this frame is kept behind it —
+//      the caller pushes to this connection no more, and the thread that
+//      owns it writes the rest with ptq_conn_finish_frames;
+//  -1  the peer is gone (or the handle is null): this frame is dropped, for
+//      that connection alone.
+void ptq_conn_send_frames(void** conns, const char* bodies,
+                          const size_t* lens, size_t n, int* rcs) {
+  Pusher* p = pusher();
+  const char* body = bodies;
+  {
+    std::lock_guard<std::mutex> lk(p->mu);
+    for (size_t i = 0; i < n; body += lens[i], ++i) {
+      auto* c = static_cast<Conn*>(conns[i]);
+      rcs[i] = c ? c->verdict : -1;
+      if (rcs[i] < 0) continue;
+      uint32_t len = static_cast<uint32_t>(lens[i]);
+      std::string frame(reinterpret_cast<const char*>(&len), 4);  // LE hosts
+      frame.append(body, lens[i]);
+      ++c->queued;
+      p->jobs.push_back({c, std::move(frame)});
+    }
+  }
+  p->work.notify_one();
+}
+
+// Blocking: wait for the pusher to have handled every frame of this
+// connection, then write what it had to remember.  The thread that owns the
+// connection calls it before it writes anything itself, once the caller of
+// ptq_conn_send_frames has been told to push no more: its own frames then
+// follow the pushed ones, and the connection is ready to be pushed to again.
+int ptq_conn_finish_frames(void* cp) {
+  auto* c = static_cast<Conn*>(cp);
+  push_quiesce(c);
+  if (c->verdict < 0) return -1;
+  c->verdict = 0;
+  if (c->tail.empty()) return 0;
+  int rc = write_all(c->fd, c->tail.data(), c->tail.size());
+  c->tail.clear();
+  return rc;
+}
+
 char* ptq_conn_recv_frame(void* cp, size_t* len_out) {
   auto* c = static_cast<Conn*>(cp);
   char hdr[4];
@@ -462,6 +600,7 @@ void ptq_conn_shutdown(void* cp) {
 void ptq_conn_close(void* cp) {
   auto* c = static_cast<Conn*>(cp);
   ::shutdown(c->fd, SHUT_RDWR);
+  push_quiesce(c);  // the pusher's sends fail at once now; none is left
   ::close(c->fd);
   delete c;
 }

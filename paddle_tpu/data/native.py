@@ -71,6 +71,21 @@ def load() -> ctypes.CDLL:
     lib.ptq_conn_send_frame.restype = ctypes.c_int
     lib.ptq_conn_send_frame.argtypes = [ctypes.c_void_p, ctypes.c_char_p,
                                         ctypes.c_size_t]
+    # the decode plane's token fan-out (transport.push_frames): n frames
+    # for n connections onto the native writer thread's queue, no I/O in
+    # the call.  Bound through PyDLL: the call KEEPS the interpreter.  Its
+    # caller is the decode engine's thread, and every reader the writes
+    # wake wants the interpreter next — letting go of it for a few
+    # microseconds of copying would put that thread at the back of the herd
+    # it is about to set off
+    lib.ptq_conn_send_frames = ctypes.PyDLL(_SO).ptq_conn_send_frames
+    lib.ptq_conn_send_frames.restype = None
+    lib.ptq_conn_send_frames.argtypes = [
+        ctypes.POINTER(ctypes.c_void_p), ctypes.c_char_p,
+        ctypes.POINTER(ctypes.c_size_t), ctypes.c_size_t,
+        ctypes.POINTER(ctypes.c_int)]
+    lib.ptq_conn_finish_frames.restype = ctypes.c_int
+    lib.ptq_conn_finish_frames.argtypes = [ctypes.c_void_p]
     lib.ptq_conn_recv_frame.restype = ctypes.POINTER(ctypes.c_char)
     lib.ptq_conn_recv_frame.argtypes = [ctypes.c_void_p,
                                         ctypes.POINTER(ctypes.c_size_t)]

@@ -79,12 +79,11 @@ Token fan-out.  Reading a step and telling its streams are two
 things.  At the read the engine BOOKS the step (span
 ``decode::step.book``): counters, slot state, each token onto its
 handle's record, retirement — so the admission sweep that follows sees
-the freed slots and blocks.  The wake-ups (one ``queue.put`` a live
-stream, each setting off a server thread and a reader) go onto
-``_fanout`` and out under ``decode::step.emit`` right AFTER the next
-step's dispatch: that herd then runs while the device computes and this
-thread waits with the interpreter released, instead of holding the next
-dispatch back with the device idle.  The order ``… wait n → book n →
+the freed slots and blocks.  Telling the streams is left on ``_fanout``
+and done under ``decode::step.emit`` right AFTER the next step's
+dispatch: the readers it wakes then run while the device computes and
+this thread waits with the interpreter released, instead of holding the
+next dispatch back with the device idle.  The order ``… wait n → book n →
 admit → prefill(s) → retire → feed → dispatch n+1 → emit n → wait n+1``
 keeps step n read and observed before step n+1 is dispatched.  When no
 step will follow (the last stream left, an error, ``close()``) the list
@@ -94,11 +93,35 @@ a prefill's own first token goes out at once (it is the TTFT).  The
 price: a step's tokens reach their streams one feed + dispatch and the
 prefills admitted in between later — ``fanout_delay_ms`` says how much.
 
+Who writes a token's frame.  A stream served by :mod:`.server` on a
+native connection with ``chunk_tokens`` 1 is PUSHED: its handle carries
+the connection as a frame sink from ``submit`` on, and ``.emit`` hands
+the step's tokens and their connections to ONE foreign call
+(``transport.push_frames``) — a template and four bytes a token, the
+bytes the queue path sends — that does no I/O and keeps the interpreter:
+the native library's writer thread, which needs none, writes every
+T-frame behind it with sends that cannot block.  No connection thread
+wakes for a token: it sleeps from the request to the FIN, which (with
+the typed error) stays its to write.
+Every other reader keeps the queue path, one ``queue.put`` a token:
+in-process readers (``for tok in handle``, ``next_token``, the beam
+decoder, the canary), ``rpc_transport=python`` connections, chunked
+streams.  A pushed stream whose socket would not take a frame whole (a
+slow reader) moves to the queue path ONCE and FOR GOOD — the transport
+keeps what the socket refused, the stream's own connection thread writes
+it and drains the queue from there on, so no frame overtakes another and
+a slow reader never holds this thread; a push that finds the peer gone
+cancels the handle, so the slot and its blocks are freed at the next
+step.  The push lock orders the two writers: it is held across a push,
+and by whoever takes a stream off its sink.
+
 Observability: ``decode.<name>.*`` counters/gauges/histograms plus the
 ``/decodez`` debug page (:func:`DecodeEngine.decodez`); among them
 ``fanout_delay_ms`` (histogram: read of a step → its tokens handed out,
-for the steps handed out behind a dispatch) and ``fanout_immediate``
-(hand-outs with no step in flight).
+for the steps handed out behind a dispatch), ``fanout_immediate``
+(hand-outs with no step in flight), ``pushed_frames`` (token frames this
+thread wrote itself; ``decode::step.emit`` carries ``pushed=<frames>``)
+and ``push_fallbacks`` (streams moved to the queue path).
 """
 from __future__ import annotations
 
@@ -114,6 +137,7 @@ from .cache import PrefixCache, blocks_for
 from .model import TransformerLM
 from ..core.executor import Executor
 from ..distributed import faults as _faults
+from ..distributed import transport as _transport
 from ..kernels import quant as _quant_kernels
 from ..observability import audit as _audit
 from ..observability import capacity as _capacity
@@ -212,9 +236,18 @@ class DecodeHandle:
     stream, or :meth:`result` for the aggregate."""
 
     _DONE = object()
+    _UNSUNK = object()
 
     def __init__(self, rid: int):
         self.rid = rid
+        # a served stream's frame sink (module doc, "Token fan-out"): while
+        # it is set the engine's thread writes this stream's token frames
+        # itself and ``_q`` carries no token.  ``submit`` sets both before
+        # the request is queued; the sink is cleared — under the engine's
+        # push lock, for good — when the stream moves to the queue path
+        self._sink = None
+        self._push_lock: Optional[threading.Lock] = None
+        self._n_pushed = 0
         self._q: "queue.Queue" = queue.Queue()
         self._tokens: List[int] = []
         self._logits: List[np.ndarray] = []   # capture_logits engines only
@@ -235,6 +268,13 @@ class DecodeHandle:
 
     def _emit(self, token: int) -> None:
         self._q.put(int(token))
+
+    def _unsink(self) -> None:
+        """The engine's thread, under the push lock: the sink would not take
+        a frame whole, so from here to its FIN this stream's tokens go
+        through ``_q`` and its connection's thread writes them."""
+        self._sink = None
+        self._q.put(self._UNSUNK)
 
     def _finish(self, reason: str) -> None:
         self._final = {"tokens": list(self._tokens), "finish": reason,
@@ -272,6 +312,33 @@ class DecodeHandle:
                 raise self._err
             return None
         return item
+
+    def await_sink(self, timeout: float) -> bool:
+        """The wait of a pushed stream's connection thread, which writes no
+        token: True once the stream has finished (FIN is the caller's to
+        write; an engine error is raised), False once it has moved to the
+        queue path (:meth:`next_token` from here on).  Either way nothing
+        more is pushed when this returns or raises.  It wakes once a
+        ``timeout``; a stream whose pushed count did not advance in that
+        time is taken off its sink and raises TimeoutError — the wedged
+        engine's typed error frame, as :meth:`next_token` gives it."""
+        seen = self._n_pushed
+        while True:
+            try:
+                item = self._q.get(timeout=timeout)
+            except queue.Empty:
+                if self._n_pushed != seen:
+                    seen = self._n_pushed
+                    continue
+                with self._push_lock:
+                    self._sink = None
+                raise TimeoutError(
+                    f"decode request {self.rid}: no token within {timeout}s")
+            if item is self._UNSUNK:
+                return False
+            if self._err is not None:
+                raise self._err
+            return True
 
     def cancel(self) -> None:
         """Abandon the generation: the engine retires the request's
@@ -476,6 +543,13 @@ class _EngineStats:
         self.fanout_immediate = sc.counter(
             "fanout_immediate", "token hand-outs made with no step in "
             "flight (the last stream left, an error, close)")
+        self.pushed_frames = sc.counter(
+            "pushed_frames", "token frames the engine's thread wrote to "
+            "their connections itself (transport.push_frames)")
+        self.push_fallbacks = sc.counter(
+            "push_fallbacks", "streams moved from the pushed path to the "
+            "queue path for good: their socket would not take a frame "
+            "whole")
 
     def latency(self) -> _LatencyStats:
         """The flag-gated bundle (lazy: see :class:`_LatencyStats`)."""
@@ -598,10 +672,15 @@ class DecodeEngine:
         self._rid = itertools.count(1)
         self._closed = False
         # token fan-out (module doc): what the last step booked and no
-        # stream has been told yet, as ordered (bound method, argument)
-        # calls; only the engine thread touches it
+        # stream has been told yet, in order, as ``(handle, token, None)``
+        # or ``(handle, None, finish reason)``; only the engine thread
+        # touches it
         self._fanout: List[tuple] = []
         self._fanout_t_read = 0.0
+        # held across a push (sinks read, the foreign call, its verdicts) and
+        # by whoever takes a stream off its sink: a connection's thread never
+        # writes while a push to it can be under way
+        self._push_lock = threading.Lock()
         # memory anatomy (FLAGS_memory_attribution): the KV block pool
         # registers on the process MemoryLedger — pool bytes, per-state
         # block counts (incl. parked LRU blocks), bytes-per-resident-
@@ -638,10 +717,14 @@ class DecodeEngine:
                    self.cache.max_context(self.max_blocks_per_seq))
 
     def submit(self, prompt, sampling: Optional[SamplingParams] = None,
-               tenant: Optional[str] = None) -> DecodeHandle:
+               tenant: Optional[str] = None, sink=None) -> DecodeHandle:
         """Enqueue one generation.  ``tenant`` is an optional
         client-supplied id for per-tenant usage metering
-        (``FLAGS_tenant_accounting``; ignored when off).  Raises
+        (``FLAGS_tenant_accounting``; ignored when off).  ``sink`` is the
+        decode server's: where this thread writes the stream's token
+        frames itself (module doc, "Token fan-out"; ``.io`` a native
+        connection, ``.head`` a frame's bytes before the token's four);
+        in-process readers give none.  Raises
         :class:`RequestTooLong` (prompt off the prefill ladder or
         prompt+budget past the context bound) or :class:`Overloaded`
         (queue bound) — both typed, never queued."""
@@ -671,6 +754,7 @@ class DecodeEngine:
                 (self.cache.num_blocks - 1) * self.cache.block_tokens)
         req = DecodeRequest(next(self._rid), prompt, sampling,
                             tenant=tenant)
+        req.handle._sink, req.handle._push_lock = sink, self._push_lock
         if _tenant.enabled():
             _tenant.account(tenant, requests=1)
         with self._lock:
@@ -957,7 +1041,10 @@ class DecodeEngine:
             slot.last_token = first
             self.stats.tokens.inc()
             req.handle._book(first, logits_np)
-            req.handle._emit(first)   # at once: this wake-up is the TTFT
+            # at once (this is the TTFT), as a step's: pushed or queued
+            for handle, tok, _ in self._push_tokens(
+                    [(req.handle, first, None)]):
+                handle._emit(tok)
             self._maybe_finish(i, slot, first)
 
     def _register_prefix(self, slot: _Slot, seq: np.ndarray) -> None:
@@ -1099,7 +1186,7 @@ class DecodeEngine:
             handle = slot.req.handle
             handle._book(
                 tok, logits_np[i] if logits_np is not None else None)
-            self._fanout.append((handle._emit, tok))
+            self._fanout.append((handle, tok, None))
             self._maybe_finish(i, slot, tok)
 
     def _flush_fanout(self, step_in_flight: bool = False) -> None:
@@ -1109,9 +1196,14 @@ class DecodeEngine:
         the tokens go out before anything else is told to a handle."""
         if not self._fanout:
             return
-        with _trace.span("decode::step.emit"):
-            for tell, what in self._fanout:
-                tell(what)
+        with _trace.span("decode::step.emit") as sp:
+            queued = self._push_tokens(self._fanout)
+            for handle, tok, reason in queued:
+                if reason is None:
+                    handle._emit(tok)
+                else:
+                    handle._finish(reason)
+            sp.annotate(pushed=len(self._fanout) - len(queued))
             self._fanout.clear()
         if step_in_flight:
             self.stats.fanout_delay_ms.observe(
@@ -1121,10 +1213,47 @@ class DecodeEngine:
             with self._lock:
                 self._lock.notify_all()   # drain() waits for the hand-out
 
+    def _push_tokens(self, entries: List[tuple]) -> List[tuple]:
+        """Write the token frames of the ``entries`` whose handle has a sink
+        — every one in ONE foreign call that never blocks, a template and
+        four bytes a token — and return the entries left for the queue
+        path, in their order (a stream's FIN is among them, so it still
+        reads token … token, FIN).  The call does no I/O: a native thread
+        writes the frames behind it, so a verdict is on a stream's EARLIER
+        frames.  A sink that would not take one whole has the rest — and
+        this frame behind it — kept by the transport, and the stream is
+        moved to the queue path for good; a dead peer cancels the handle,
+        so its slot and blocks go at the next step."""
+        if not any(r is None and h._sink is not None for h, _, r in entries):
+            return entries          # in-process readers: no lock, no call
+        with self._push_lock:
+            sunk, queued = [], []
+            for e in entries:
+                (sunk if e[2] is None and e[0]._sink is not None
+                 else queued).append(e)
+            if not sunk:            # taken off their sinks a moment ago
+                return queued
+            verdicts = _transport.push_frames(
+                [h._sink.io for h, _, _ in sunk],
+                [h._sink.head + t.to_bytes(4, "little", signed=True)
+                 for h, t, _ in sunk])
+            for (handle, _, _), rc in zip(sunk, verdicts):
+                if rc == _transport.PUSH_DEAD:
+                    handle._sink = None
+                    handle.cancel()
+                    continue
+                handle._n_pushed += 1
+                if rc == _transport.PUSH_WOULD_BLOCK:
+                    handle._unsink()
+                    self.stats.push_fallbacks.inc()
+            self.stats.pushed_frames.inc(
+                len(verdicts) - verdicts.count(_transport.PUSH_DEAD))
+        return queued
+
     def _tell_finish(self, handle: DecodeHandle, reason: str) -> None:
         """FIN, never ahead of a token: behind whatever is pending."""
         if self._fanout:
-            self._fanout.append((handle._finish, reason))
+            self._fanout.append((handle, None, reason))
         else:
             handle._finish(reason)
 
@@ -1469,6 +1598,8 @@ class DecodeEngine:
             "leaves": self.stats.leaves.value,
             "shed": self.stats.shed.value,
             "fanout_immediate": self.stats.fanout_immediate.value,
+            "pushed_frames": self.stats.pushed_frames.value,
+            "push_fallbacks": self.stats.push_fallbacks.value,
         }
         out.update(self._observer.decodez())
         alloc = self.cache.allocator

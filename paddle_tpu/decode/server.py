@@ -13,7 +13,20 @@ message type:
 
   * ``T`` + ``serde.dumps_batch`` of ``[("tokens", int32[k])]`` — a
     chunk of ``chunk_tokens`` generated tokens (default 1: true
-    token-by-token streaming), riding the PR-3 zero-copy batched serde;
+    token-by-token streaming), riding the PR-3 zero-copy batched serde.
+    WHO writes it: on a native connection (``rpc_transport=native``) with
+    ``chunk_tokens`` 1 the stream is *pushed* — the request's handle
+    carries the connection as its frame sink and the ENGINE's thread
+    writes the step's T-frames for all such streams in one foreign call
+    (``transport.push_frames``; the same bytes), while the connection's
+    thread sleeps to the FIN, waking once a ``FLAGS_rpc_deadline`` to see
+    that tokens still go out.  A pushed stream whose socket would not
+    take a frame whole falls back, once and for good, to the other way:
+    the connection's thread drains the handle's queue and writes the
+    frames itself — as it does from the start on ``rpc_transport=python``
+    and for ``chunk_tokens`` > 1.  Nothing chooses but what is there to
+    see; ``decode.<name>.pushed_frames`` / ``push_fallbacks``
+    (``/decodez``) say what happened;
   * ``F`` + JSON ``{"n_tokens":, "finish": "eos"|"length"}`` — end of
     stream;
   * ``O`` / ``L`` + JSON — typed :class:`Overloaded` /
@@ -62,6 +75,29 @@ _TAG_TOO_LONG = b"L"
 _TAG_DRAINING = b"D"
 
 
+def _token_chunk(tokens) -> list:
+    """A T-frame's payload, as the transport's buffer list."""
+    return [_TAG_TOKENS] + serde.dumps_batch_vec(
+        [("tokens", np.asarray(tokens, np.int32))])
+
+
+class _FrameSink:
+    """What a pushed stream's handle carries (engine.py, "Token fan-out"):
+    the connection, and a one-token T-frame's body up to the token's four
+    little-endian bytes — made by the functions the queue path sends
+    through, so the engine's frames are its frames byte for byte."""
+
+    __slots__ = ("io", "head")
+
+    def __init__(self, io, trainer_id: int, name: str):
+        probe = 0x01020304
+        body = b"".join(bytes(b) for b in transport._pack_body_vec(
+            transport.OK, trainer_id, name, _token_chunk([probe])))
+        assert body.endswith(probe.to_bytes(4, "little"))
+        self.io = io
+        self.head = body[:-4]
+
+
 def replica_key(model: str, replica_id: str) -> str:
     """The registry lease key a decode replica announces under."""
     return f"decode/{model}/{replica_id}"
@@ -106,16 +142,25 @@ class DecodeService:
             tenant = body.get("tenant")
             if not isinstance(tenant, str) or not tenant:
                 tenant = None
+            # who writes the T-frames is decided by what is here to see: a
+            # token-by-token stream on a native connection is PUSHED — the
+            # engine's thread writes its frames, this thread sleeps to the
+            # FIN (_stream); a python-transport connection and a chunked
+            # stream are drained from the handle's queue by this thread
+            io = transport.serving_io()
+            sink = (_FrameSink(io, trainer_id, name)
+                    if chunk == 1 and isinstance(io, transport._NativeIO)
+                    else None)
             try:
                 handle = eng.submit(body.get("prompt") or [], sampling,
-                                    tenant=tenant)
+                                    tenant=tenant, sink=sink)
             except Overloaded as e:
                 return transport.OK, [
                     _TAG_OVERLOAD + json.dumps(e.to_dict()).encode("utf-8")]
             except RequestTooLong as e:
                 return transport.OK, [
                     _TAG_TOO_LONG + json.dumps(e.to_dict()).encode("utf-8")]
-            return transport.STREAM, self._stream(handle, chunk)
+            return transport.STREAM, self._stream(handle, chunk, sink)
         if msg_type == DECODE_ADMIN:
             body = json.loads(bytes(payload).decode("utf-8"))
             if body.get("cmd") == "status":
@@ -127,33 +172,44 @@ class DecodeService:
         return transport.ERR, f"decode: unknown msg {msg_type}".encode()
 
     @staticmethod
-    def _stream(handle, chunk_tokens: int):
-        """Frame generator: T-chunks as tokens arrive, then FIN.
+    def _stream(handle, chunk_tokens: int, sink: Optional[_FrameSink] = None):
+        """Frame generator: T-chunks as tokens arrive, then FIN.  With a
+        ``sink`` (a pushed stream) the T-frames are the engine's to write
+        and this generator only waits: for the stream's end, or for its
+        move to the queue path, which it then drains like any other.
+        Before it yields or raises anything it has the connection write
+        what a push left unfinished, so no frame of its own can overtake
+        or tear one.
 
         Two failure disciplines:
-        - every token wait is BOUNDED by FLAGS_rpc_deadline — a wedged
+        - every wait is BOUNDED by FLAGS_rpc_deadline — a wedged
           engine surfaces as a transport ERR frame, never a connection
           thread parked forever (the serving plane's INFER contract);
         - a client disconnect abandons this generator (the transport's
           STREAM path closes it), and the ``finally`` cancels the
           request — the engine frees the slot + cache blocks instead
-          of generating into the void."""
+          of generating into the void.  (A pushed stream's dead peer is
+          the engine's to see: its push cancels the handle.)"""
         from ..core import flags as _flags
         deadline = float(_flags.get_flags("rpc_deadline"))
         buf = []
         try:
-            while True:
+            pushed_to_the_end = False
+            if sink is not None:
+                try:
+                    pushed_to_the_end = handle.await_sink(deadline)
+                finally:
+                    sink.io.finish_frames()
+            while not pushed_to_the_end:
                 tok = handle.next_token(timeout=deadline)
                 if tok is None:
                     break
                 buf.append(tok)
                 if len(buf) >= chunk_tokens:
-                    yield [_TAG_TOKENS] + serde.dumps_batch_vec(
-                        [("tokens", np.asarray(buf, np.int32))])
+                    yield _token_chunk(buf)
                     buf = []
             if buf:
-                yield [_TAG_TOKENS] + serde.dumps_batch_vec(
-                    [("tokens", np.asarray(buf, np.int32))])
+                yield _token_chunk(buf)
             final = handle.result(timeout=0.0)
             yield [_TAG_FIN + json.dumps(
                 {"n_tokens": final["n_tokens"],

@@ -346,7 +346,9 @@ class _NativeIO:
 
     Handle lifetime: exactly one thread sends/receives on an IO at a time
     (client conns serialize under _Conn.lock; the server's serving thread
-    is the sole reader).  ``shutdown`` only wakes a blocked reader;
+    is the sole reader; while a decode stream is pushed — :func:`push_frames`
+    — the serving thread writes nothing and does not return, so the handle
+    outlives every push).  ``shutdown`` only wakes a blocked reader;
     ``close`` frees — both serialized by ``_hlock`` so a raced shutdown
     never touches a freed handle."""
 
@@ -384,6 +386,18 @@ class _NativeIO:
         if self._lib.ptq_conn_send_frame_vec(h, ptrs, lens, n) != 0:
             raise ConnectionError("native transport: vectored send failed")
 
+    def finish_frames(self) -> None:
+        """Blocking: wait until every frame :func:`push_frames` took for
+        this connection has been handled, and write what its socket would
+        not take at once (the rest of one frame, and those behind it).  The
+        serving thread calls it before it writes anything itself, once the
+        pusher will push no more."""
+        h = self._h
+        if not h:
+            raise ConnectionError("native transport: connection closed")
+        if self._lib.ptq_conn_finish_frames(h) != 0:
+            raise ConnectionError("native transport: send failed")
+
     def recv_frame(self) -> Optional[bytes]:
         h = self._h
         if not h:
@@ -409,6 +423,41 @@ class _NativeIO:
                 self._h = None
 
 
+PUSHED = 0          # push_frames verdicts, one a connection
+PUSH_WOULD_BLOCK = 1
+PUSH_DEAD = -1
+
+
+def push_frames(ios: Sequence[_NativeIO], bodies: Sequence[bytes]) -> List[int]:
+    """One frame for each of n native connections in ONE foreign call that
+    does no I/O (``ptq_conn_send_frames``): the frames go onto the queue of
+    the native library's writer thread, which needs no interpreter and
+    writes each with a send that cannot block — what the decode engine's
+    thread hands a step's tokens out through.  ``bodies[i]`` is a whole
+    frame body (what ``b"".join(_pack_body_vec(...))`` gives) for ``ios[i]``;
+    a connection's frames are written in the order they were pushed.
+
+    A verdict a connection, on what its EARLIER frames met: ``PUSHED`` —
+    all in the socket, whole; ``PUSH_WOULD_BLOCK`` — the socket would not
+    take one at once, the rest of it is remembered on the connection and
+    this frame is kept behind it: the caller pushes to this connection no
+    more, and its serving thread writes what is kept (``finish_frames``);
+    ``PUSH_DEAD`` — the peer is gone, this frame is dropped.  The serving
+    thread calls ``finish_frames`` before it writes anything itself, so its
+    frames follow the pushed ones; the callers keep every connection open
+    until then (_NativeIO)."""
+    n = len(ios)
+    conns = (ctypes.c_void_p * n)(*[io._h for io in ios])
+    lens = (ctypes.c_size_t * n)(*[len(b) for b in bodies])
+    rcs = (ctypes.c_int * n)()
+    _native_lib().ptq_conn_send_frames(conns, b"".join(bodies), lens, n, rcs)
+    verdicts = list(rcs)
+    if _telemetry_on():
+        _obs_stats.scope("rpc.server").counter("stream_frames").inc(
+            n - verdicts.count(PUSH_DEAD))
+    return verdicts
+
+
 def _connect_io(host: str, port: int, timeout: float):
     if _backend() == "native":
         return _NativeIO.connect(host, port, timeout)
@@ -418,6 +467,16 @@ def _connect_io(host: str, port: int, timeout: float):
 # ---------------------------------------------------------------------------
 # server
 # ---------------------------------------------------------------------------
+
+_serving = threading.local()
+
+
+def serving_io():
+    """The connection whose request this thread is handling (inside a
+    service's ``handle``), else None: a DECODE stream hands it to the
+    engine as the sink of its token frames (decode/server.py)."""
+    return getattr(_serving, "io", None)
+
 
 def _handle_request(service, msg_type: int, tid: int, name: str, payload):
     """One request against the service, with the observability messages
@@ -445,6 +504,7 @@ def _serve_io(io, service) -> None:
     from . import faults as _faults
     if _faults.active() and _faults.accept_fault():
         return               # injected refuse_accept: slam the connection
+    _serving.io = io         # this thread serves this connection to its end
     while True:
         body = io.recv_frame()
         if body is None:

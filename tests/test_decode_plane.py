@@ -673,16 +673,103 @@ def test_cancel_frees_slot_and_blocks_mid_stream():
 # token fan-out: a step's tokens go out behind the next step's dispatch
 # ---------------------------------------------------------------------------
 
+def _submitted(eng):
+    """The engine-side handle of every request submitted from here on, in
+    order: how a test gets at a served stream's."""
+    handles, submit = [], eng.submit
+
+    def submitting(*a, **kw):
+        handles.append(submit(*a, **kw))
+        return handles[-1]
+    eng.submit = submitting
+    return handles
+
+
+class _Stream:
+    """One generation as the fan-out tests read it: ``handle`` is the
+    engine's, ``read()`` gives the tokens streamed up to FIN (``error`` the
+    exception that ended the stream instead, if one did)."""
+
+    def __init__(self, handle=None):
+        self.handle, self.reader = handle, None
+        self.got, self.error = [], None
+
+    @property
+    def rid(self):
+        return self.handle.rid
+
+    def read(self):
+        if self.reader is not None:             # read over the wire
+            self.reader.join(timeout=120)
+            assert not self.reader.is_alive()
+        else:
+            try:
+                for tok in self.handle:
+                    self.got.append(tok)
+            except Exception as e:              # noqa: BLE001
+                self.error = e
+        return self.got
+
+
+@pytest.fixture(params=["queued", "pushed"])
+def open_streams(request):
+    """``open_streams(eng)`` -> ``submit(prompt, sampling) -> _Stream``, by
+    one of the two ways a token reaches a reader: ``queued`` — read in
+    process from the handle's queue; ``pushed`` — through ``DecodeServer``
+    and ``DecodeClient`` on the native transport, where the engine's thread
+    writes the token frames itself."""
+    import threading
+    import time
+    servers = []
+
+    def opener(eng):
+        if request.param == "queued":
+            return lambda prompt, sp: _Stream(eng.submit(prompt, sp))
+        srv = DecodeServer(engines={eng.name: eng}, own_engines=False)
+        srv.start()
+        servers.append(srv)
+        cli = DecodeClient(endpoints=[srv.endpoint])
+        handles = _submitted(eng)
+
+        def served(prompt, sp):
+            stream, seen = _Stream(), len(handles)
+
+            def read():
+                try:
+                    for tok in cli.generate_stream(
+                            eng.name, prompt, **sp.to_dict()):
+                        stream.got.append(tok)
+                except Exception as e:          # noqa: BLE001
+                    stream.error = e
+            stream.reader = threading.Thread(target=read, daemon=True)
+            stream.reader.start()
+            deadline = time.monotonic() + 30
+            while len(handles) == seen:     # one at a time: in the order asked
+                assert time.monotonic() < deadline
+                time.sleep(0.001)
+            stream.handle = handles[seen]
+            assert stream.handle._sink is not None
+            return stream
+        return served
+
+    opener.path = request.param     # for names: stats are kept by name
+    yield opener
+    for srv in servers:
+        srv.stop()
+
+
 def _recorded(eng, monkeypatch):
     """The engine thread's own order of events, as a list: every
     ``run_callable`` once it has returned (``("dispatch", kind)``), every
     step's read (``("read",)``: the observer runs right after it, inside
     the wait), and what each handle is told: ``("book", rid, k)``,
-    ``("emit", rid, k, live)`` with the slots live at that moment, and
-    ``("fin", rid, reason)``."""
+    ``("emit", rid, k, live)`` with the slots live at that moment — a
+    token handed to the handle's queue or, for a pushed stream, to the one
+    foreign call that writes its frame — and ``("fin", rid, reason)``."""
     from paddle_tpu.decode.engine import DecodeHandle
     log, booked, emitted = [], {}, {}
     run, observe = eng._exe.run_callable, eng._observer.step
+    push = eng._push_tokens
     book, emit, fin = (DecodeHandle._book, DecodeHandle._emit,
                        DecodeHandle._finish)
 
@@ -700,11 +787,20 @@ def _recorded(eng, monkeypatch):
         log.append(("book", self.rid, k))
         book(self, token, logits)
 
-    def _emit(self, token):
-        k = emitted[self.rid] = emitted.get(self.rid, -1) + 1
-        log.append(("emit", self.rid, k,
+    def _emitted(handle):
+        k = emitted[handle.rid] = emitted.get(handle.rid, -1) + 1
+        log.append(("emit", handle.rid, k,
                     sum(s is not None for s in eng._slots)))
+
+    def _emit(self, token):
+        _emitted(self)
         emit(self, token)
+
+    def _push_tokens(entries):
+        for handle, _, reason in entries:
+            if reason is None and handle._sink is not None:
+                _emitted(handle)
+        return push(entries)
 
     def _finish(self, reason):
         log.append(("fin", self.rid, reason))
@@ -712,23 +808,26 @@ def _recorded(eng, monkeypatch):
 
     monkeypatch.setattr(eng._exe, "run_callable", run_callable)
     monkeypatch.setattr(eng._observer, "step", step)
+    monkeypatch.setattr(eng, "_push_tokens", _push_tokens)
     monkeypatch.setattr(DecodeHandle, "_book", _book)
     monkeypatch.setattr(DecodeHandle, "_emit", _emit)
     monkeypatch.setattr(DecodeHandle, "_finish", _finish)
     return log
 
 
-def test_a_lone_streams_tokens_go_out_behind_the_next_dispatch(monkeypatch):
+def test_a_lone_streams_tokens_go_out_behind_the_next_dispatch(
+        monkeypatch, open_streams):
     """The whole order of one stream of four tokens: the prefill's token
     at once; each step's token after the NEXT step's dispatch and before
     its read; the last step's token and FIN at once, with no later
     request to set them off."""
-    lm, params, eng = _engine("fan_lone")
+    lm, params, eng = _engine("fan_lone_" + open_streams.path)
     try:
         log = _recorded(eng, monkeypatch)
-        h = eng.submit(np.arange(5, dtype=np.int32),
-                       SamplingParams(max_new_tokens=4))
-        streamed = list(h)                    # ends at FIN: no hang
+        st = open_streams(eng)(np.arange(5, dtype=np.int32),
+                               SamplingParams(max_new_tokens=4))
+        streamed, h = st.read(), st.handle    # ends at FIN: no hang
+        assert st.error is None
         out = h.result(timeout=30)
         rid = h.rid
         assert log == [
@@ -746,7 +845,7 @@ def test_a_lone_streams_tokens_go_out_behind_the_next_dispatch(monkeypatch):
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "seeded"])
 def test_no_stream_is_woken_between_a_read_and_the_next_dispatch(
-        monkeypatch, sampled):
+        monkeypatch, sampled, open_streams):
     """Five streams over three slots, joining and leaving: no token of a
     step is handed out between that step's read and the next step's
     dispatch unless the batch has emptied; every stream gets token ...
@@ -754,8 +853,9 @@ def test_no_stream_is_woken_between_a_read_and_the_next_dispatch(
     sampler's choice (by seed and index) from logits that equal the full
     re-forward's — so they are what the order before this one streamed."""
     from paddle_tpu.decode.adapter import sample as _sample
-    lm, params, eng = _engine("fan_many_" + ("s" if sampled else "g"),
-                              capture_logits=True)
+    lm, params, eng = _engine(
+        "fan_many_" + ("s_" if sampled else "g_") + open_streams.path,
+        capture_logits=True)
     try:
         log = _recorded(eng, monkeypatch)
         rng = np.random.RandomState(1)
@@ -765,8 +865,11 @@ def test_no_stream_is_woken_between_a_read_and_the_next_dispatch(
                               temperature=0.8 if sampled else 0.0,
                               top_k=6 if sampled else 0)
                for i, m in enumerate((6, 3, 8, 1, 5))]
-        handles = [eng.submit(p, sp) for p, sp in zip(prompts, sps)]
-        streamed = [list(h) for h in handles]
+        submit = open_streams(eng)
+        streams = [submit(p, sp) for p, sp in zip(prompts, sps)]
+        streamed = [st.read() for st in streams]
+        assert [st.error for st in streams] == [None] * 5
+        handles = [st.handle for st in streams]
         results = [h.result(timeout=120) for h in handles]
         assert eng.drain(timeout=30)
         behind = 0
@@ -811,11 +914,11 @@ def test_no_stream_is_woken_between_a_read_and_the_next_dispatch(
         eng.close()
 
 
-def test_drain_waits_for_the_last_hand_out(monkeypatch):
+def test_drain_waits_for_the_last_hand_out(monkeypatch, open_streams):
     """``drain()`` is true only once the last step's tokens and FIN have
     gone out, however long the hand-out takes after the slot is free."""
     import time
-    lm, params, eng = _engine("fan_drain")
+    lm, params, eng = _engine("fan_drain_" + open_streams.path)
     try:
         flush = eng._flush_fanout
 
@@ -824,8 +927,9 @@ def test_drain_waits_for_the_last_hand_out(monkeypatch):
                 time.sleep(0.3)            # slot free, tokens not yet out
             flush(step_in_flight)
         monkeypatch.setattr(eng, "_flush_fanout", slow_flush)
-        h = eng.submit(np.arange(4, dtype=np.int32),
-                       SamplingParams(max_new_tokens=3))
+        st = open_streams(eng)(np.arange(4, dtype=np.int32),
+                               SamplingParams(max_new_tokens=3))
+        h = st.handle
         deadline = time.monotonic() + 30
         while any(s is not None for s in eng._slots) or eng._pending \
                 or not h.tokens:
@@ -833,44 +937,49 @@ def test_drain_waits_for_the_last_hand_out(monkeypatch):
             time.sleep(0.001)
         assert eng.drain(timeout=30)
         assert h._done.is_set()
-        assert h.result(timeout=0)["tokens"] == list(h) and len(h.tokens) == 3
+        assert h.result(timeout=0)["tokens"] == st.read() \
+            and len(h.tokens) == 3 and st.error is None
     finally:
         eng.close()
 
 
-def test_close_and_an_engine_error_hand_out_what_was_computed_first():
+def test_close_and_an_engine_error_hand_out_what_was_computed_first(
+        open_streams):
     """A token the engine has read is never dropped: ``close()`` with a
     step's tokens still pending, and a dispatch that raises, both hand
     them out before the stream is failed."""
-    lm, params, eng = _engine("fan_close")
+    import time
+    lm, params, eng = _engine("fan_close_" + open_streams.path)
     try:
-        h = eng.submit(np.arange(4, dtype=np.int32),
-                       SamplingParams(max_new_tokens=25))
-        assert h.next_token(timeout=30) is not None
+        st = open_streams(eng)(np.arange(4, dtype=np.int32),
+                               SamplingParams(max_new_tokens=25))
+        h = st.handle
+        deadline = time.monotonic() + 30
+        while not h.tokens:                       # the stream has started
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
         eng.close()
-        got = [h.tokens[0]]
-        with pytest.raises(RuntimeError, match="closed"):
-            for tok in h:
-                got.append(tok)
+        got = st.read()
+        assert isinstance(st.error, RuntimeError) and "closed" in str(st.error)
         assert got == h.tokens and 1 <= len(got) < 25
     finally:
         eng.close()
-    lm, params, eng = _engine("fan_error")
+    lm, params, eng = _engine("fan_error_" + open_streams.path)
     try:
         run, calls = eng._exe.run_callable, []
 
         def run_callable(key, *a, **kw):
             calls.append(key)
-            if calls.count("decode/fan_error/step") == 3:
+            if calls.count(f"decode/{eng.name}/step") == 3:
                 raise ValueError("the third step is refused")
             return run(key, *a, **kw)
         eng._exe.run_callable = run_callable
-        h = eng.submit(np.arange(4, dtype=np.int32),
-                       SamplingParams(max_new_tokens=25))
-        got = []
-        with pytest.raises(ValueError, match="third step"):
-            for tok in h:
-                got.append(tok)
+        st = open_streams(eng)(np.arange(4, dtype=np.int32),
+                               SamplingParams(max_new_tokens=25))
+        got, h = st.read(), st.handle
+        # in process the engine's own exception; over the wire the ERR frame
+        assert isinstance(st.error, (ValueError, RuntimeError)) \
+            and "the third step is refused" in str(st.error)
         assert got == h.tokens and len(got) == 3   # the prefill's + two
         z = eng.decodez()
         assert z["joins"] == z["leaves"] == 1 and z["fanout_immediate"] == 1
@@ -883,24 +992,26 @@ def test_close_and_an_engine_error_hand_out_what_was_computed_first():
 
 
 def test_a_cancel_between_a_read_and_its_hand_out_keeps_the_order(
-        monkeypatch):
+        monkeypatch, open_streams):
     """The client goes away right after the engine has read a step and
     before that step's token is handed out: the token still goes out,
     FIN ("cancelled") after it, and the slot and its blocks are freed."""
-    lm, params, eng = _engine("fan_cancel")
+    lm, params, eng = _engine("fan_cancel_" + open_streams.path)
     try:
         log = _recorded(eng, monkeypatch)
         book, box = eng._book_step, {}
 
         def book_then_cancel(*a, **kw):
             book(*a, **kw)
-            if len(box["h"].tokens) == 3:
+            (slot,) = [s for s in eng._slots if s is not None]
+            if len(slot.req.handle.tokens) == 3:
                 assert eng._fanout            # its token is pending
-                box["h"].cancel()
+                slot.req.handle.cancel()
         monkeypatch.setattr(eng, "_book_step", book_then_cancel)
-        box["h"] = h = eng.submit(np.arange(4, dtype=np.int32),
-                                  SamplingParams(max_new_tokens=25))
-        got = list(h)
+        st = open_streams(eng)(np.arange(4, dtype=np.int32),
+                               SamplingParams(max_new_tokens=25))
+        got, h = st.read(), st.handle
+        assert st.error is None
         out = h.result(timeout=30)
         assert out["finish"] == "cancelled"
         assert got == out["tokens"] == h.tokens and len(got) == 3
@@ -979,6 +1090,284 @@ def test_streaming_server_and_client():
         assert st["wire"]["tokens"] >= 10
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# who writes a token's frame: the engine's thread (a pushed stream) or the
+# connection's (the queue path)
+# ---------------------------------------------------------------------------
+
+LONG = LMConfig(vocab=48, d_model=32, n_head=2, d_ffn=48, n_layer=2,
+                max_seq_len=512)
+
+
+def _long_engine(name, **kw):
+    lm = TransformerLM(LONG)
+    params = lm.init_params(seed=5)
+    kw.setdefault("max_slots", 4)
+    kw.setdefault("block_tokens", 16)
+    kw.setdefault("prefill_buckets", (8,))
+    return DecodeEngine(lm, params, name=name, **kw)
+
+
+def _raw_stream(endpoint, model, rcvbuf=None, **body):
+    """A DECODE request sent over a plain socket that the test reads (or
+    does not read) itself."""
+    import json
+    import socket
+    from paddle_tpu.decode import server as dserver
+    from paddle_tpu.distributed import transport
+    host, port = endpoint.rsplit(":", 1)
+    sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+    if rcvbuf:
+        sock.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+    sock.connect((host, int(port)))
+    sock.settimeout(60)
+    req = b"".join(transport._pack_body_vec(
+        dserver.DECODE, 0, model, [json.dumps(body).encode("utf-8")]))
+    sock.sendall(len(req).to_bytes(4, "little") + req)
+    return sock
+
+
+def _read_stream_frame(sock):
+    """-> ``("T", [tokens])`` | ``("F", fin dict)`` | ``("ERR", text)``."""
+    import json
+    from paddle_tpu.distributed import serde, transport
+
+    def exact(n):
+        got = b""
+        while len(got) < n:
+            chunk = sock.recv(n - len(got))
+            assert chunk, "the server closed the stream"
+            got += chunk
+        return got
+    body = exact(int.from_bytes(exact(4), "little"))
+    rtype, _, _, payload = transport._unpack_body(body)
+    if rtype == transport.ERR:
+        return "ERR", bytes(payload).decode("utf-8")
+    tag, rest = bytes(payload[:1]), payload[1:]
+    if tag == b"T":
+        return "T", [int(t) for t in serde.loads_batch(rest)[0][1]]
+    assert tag == b"F", tag
+    return "F", json.loads(bytes(rest).decode("utf-8"))
+
+
+@pytest.mark.parametrize("backend", ["native", "python"])
+def test_eight_concurrent_streams_read_the_same_tokens_either_way(
+        backend, monkeypatch):
+    """Through DecodeServer / DecodeClient eight streams at once read the
+    model's own tokens and FIN.  On the native transport every token frame
+    is the engine thread's (``pushed_frames`` == tokens, no fall-back, no
+    ``queue.put`` for a token, the transport's ``stream_frames`` counts them
+    all the same); on ``rpc_transport=python`` the connection threads drain
+    the queues as before and nothing is pushed."""
+    import threading
+    import paddle_tpu as fluid
+    from paddle_tpu.decode.engine import DecodeHandle
+    fluid.set_flags({"rpc_transport": backend})
+    name = "eight_" + backend
+    lm, params, eng = _engine(name, max_slots=8)
+    srv = DecodeServer(engines={name: eng})
+    srv.start()
+    try:
+        rng = np.random.RandomState(3)
+        prompts = [rng.randint(0, TINY.vocab, 2 + i).astype(np.int32)
+                   for i in range(8)]
+        budgets = [12, 3, 9, 1, 14, 7, 5, 10]
+        want = [eng.generate(p, max_new_tokens=m)["tokens"]
+                for p, m in zip(prompts, budgets)]
+        queued, emit = [], DecodeHandle._emit
+        monkeypatch.setattr(
+            DecodeHandle, "_emit",
+            lambda self, tok: (queued.append(self.rid), emit(self, tok)))
+        frames = "rpc.server.stream_frames"
+        z0 = eng.decodez()
+        f0 = obs.stats.default_registry().to_dict().get(frames, 0)
+        cli = DecodeClient(endpoints=[srv.endpoint])
+        outs = [None] * 8
+
+        def one(i):
+            outs[i] = cli.generate(name, prompts[i],
+                                   max_new_tokens=budgets[i])
+        threads = [threading.Thread(target=one, args=(i,), daemon=True)
+                   for i in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        assert [o["tokens"] for o in outs] == want
+        assert [(o["finish"], o["n_tokens"]) for o in outs] == \
+            [("length", m) for m in budgets]
+        z = eng.decodez()
+        tokens = z["tokens"] - z0["tokens"]
+        assert tokens == sum(budgets) and z["push_fallbacks"] == 0
+        f1 = obs.stats.default_registry().to_dict().get(frames, 0)
+        assert f1 - f0 == tokens + 8                 # every T-frame and FIN
+        if backend == "native":
+            assert z["pushed_frames"] - z0["pushed_frames"] == tokens
+            assert queued == []
+        else:
+            assert z["pushed_frames"] == 0 and len(queued) == tokens
+    finally:
+        srv.stop()
+        fluid.set_flags({"rpc_transport": "native"})
+
+
+def test_a_chunked_stream_is_drained_from_the_queue_as_before():
+    """``chunk_tokens`` > 1 is the caller's to ask for and the connection
+    thread's to serve: chunks of four, the rest, FIN; nothing pushed."""
+    lm, params, eng = _engine("chunked")
+    srv = DecodeServer(engines={"chunked": eng})
+    srv.start()
+    try:
+        want = eng.generate([1, 2, 3], max_new_tokens=10)["tokens"]
+        sock = _raw_stream(srv.endpoint, "chunked", prompt=[1, 2, 3],
+                           max_new_tokens=10, chunk_tokens=4)
+        got = [_read_stream_frame(sock) for _ in range(4)]
+        sock.close()
+        assert [kind for kind, _ in got] == ["T", "T", "T", "F"]
+        assert [len(toks) for _, toks in got[:3]] == [4, 4, 2]
+        assert sum((toks for _, toks in got[:3]), []) == want
+        assert got[3][1] == {"n_tokens": 10, "finish": "length"}
+        assert eng.decodez()["pushed_frames"] == 0
+    finally:
+        srv.stop()
+
+
+def test_a_reader_that_stops_reading_falls_back_once_and_holds_nobody():
+    """A caller with a small receive buffer that stops reading: its socket
+    fills, its stream is moved to the queue path — once, for good — and only
+    its own connection thread waits for it.  The engine's thread is never
+    held: the other streams run to their FIN meanwhile and the stalled
+    stream's own generation finishes too.  When the caller reads again it
+    gets every token in order, whole frames only, FIN last.  (A frame
+    carries the model's name; a name of 60,000 characters makes a frame
+    large enough that a few dozen fill a socket.)"""
+    import threading
+    import time
+    model = "m" * 60000
+    eng = _long_engine("stalled")
+    srv = DecodeServer(engines={model: eng})
+    srv.start()
+    try:
+        n = 300
+        want = eng.generate([5, 6, 7], max_new_tokens=n)["tokens"]
+        z0 = eng.decodez()
+        handles = _submitted(eng)
+        slow = _raw_stream(srv.endpoint, model, rcvbuf=4096,
+                           prompt=[5, 6, 7], max_new_tokens=n)
+        deadline = time.monotonic() + 30
+        while not handles:
+            assert time.monotonic() < deadline
+            time.sleep(0.001)
+        stalled = handles[0]
+        cli = DecodeClient(endpoints=[srv.endpoint])
+        outs = [None] * 2
+
+        def one(i):
+            outs[i] = cli.generate(model, [5, 6, 7], max_new_tokens=n)
+        threads = [threading.Thread(target=one, args=(i,), daemon=True)
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+        # nobody has read a byte of the stalled stream so far
+        assert [o["tokens"] for o in outs] == [want, want]
+        assert stalled.result(timeout=60)["tokens"] == want
+        assert eng.drain(timeout=30)
+        z = eng.decodez()
+        assert z["push_fallbacks"] - z0["push_fallbacks"] == 1
+        assert 0 < stalled._n_pushed < n and stalled._sink is None
+        assert z["pushed_frames"] - z0["pushed_frames"] == \
+            2 * n + stalled._n_pushed
+        got = [_read_stream_frame(slow) for _ in range(n + 1)]
+        slow.close()
+        assert [kind for kind, _ in got] == ["T"] * n + ["F"]
+        assert [toks for _, toks in got[:n]] == [[t] for t in want]
+        assert got[n][1] == {"n_tokens": n, "finish": "length"}
+        assert eng.decodez()["push_fallbacks"] - z0["push_fallbacks"] == 1
+    finally:
+        srv.stop()
+
+
+def test_a_reader_that_closes_mid_stream_frees_its_slot_and_blocks():
+    """A pushed stream's caller goes away: the push that finds the peer
+    dead cancels the handle, so the slot and its blocks are freed long
+    before the budget is spent."""
+    import time
+    eng = _long_engine("hangup")
+    srv = DecodeServer(engines={"hangup": eng})
+    srv.start()
+    try:
+        handles = _submitted(eng)
+        sock = _raw_stream(srv.endpoint, "hangup", prompt=[1, 2, 3],
+                           max_new_tokens=450)
+        assert _read_stream_frame(sock)[0] == "T"
+        assert _read_stream_frame(sock)[0] == "T"
+        sock.close()
+        out = handles[0].result(timeout=60)
+        assert out["finish"] == "cancelled" and out["n_tokens"] < 450
+        assert eng.drain(timeout=30)
+        deadline = time.monotonic() + 30
+        while eng.cache.allocator.free_blocks != eng.cache.num_blocks - 1:
+            assert time.monotonic() < deadline
+            time.sleep(0.01)
+        z = eng.decodez()
+        assert z["joins"] == z["leaves"] == 1
+        assert z["push_fallbacks"] == 0 and z["pushed_frames"] >= 2
+    finally:
+        srv.stop()
+
+
+@pytest.mark.parametrize("backend", ["native", "python"])
+def test_a_wedged_engine_still_yields_the_error_frame_within_the_deadline(
+        backend):
+    """The engine stops producing: the connection thread — asleep while the
+    engine pushes, or waiting on the queue — wakes once a
+    ``FLAGS_rpc_deadline``, sees no token since, and answers with the typed
+    ERR frame; the request is cancelled."""
+    import threading
+    import time
+    import paddle_tpu as fluid
+    fluid.set_flags({"rpc_transport": backend, "rpc_deadline": 1.0})
+    name = "wedged_" + backend
+    eng = _long_engine(name)
+    srv = DecodeServer(engines={name: eng})
+    srv.start()
+    gate = threading.Event()
+    try:
+        eng.generate([1, 2, 3], max_new_tokens=3)   # compiled: no wait is it
+        run, calls = eng._exe.run_callable, []
+
+        def run_callable(key, *a, **kw):
+            calls.append(key)
+            if calls.count(f"decode/{name}/step") == 4:
+                gate.wait(timeout=60)           # the wedge
+            return run(key, *a, **kw)
+        eng._exe.run_callable = run_callable
+        handles = _submitted(eng)
+        sock = _raw_stream(srv.endpoint, name, prompt=[1, 2, 3],
+                           max_new_tokens=100)
+        t0 = time.monotonic()
+        got = []
+        while not got or got[-1][0] == "T":
+            got.append(_read_stream_frame(sock))
+        waited = time.monotonic() - t0
+        # the prefill's token and two steps': the third step's waits behind
+        # the fourth dispatch, which never returns
+        assert [kind for kind, _ in got] == ["T"] * 3 + ["ERR"]
+        assert "no token within 1.0s" in got[-1][1]
+        assert 1.0 <= waited < 10.0
+        assert handles[0].cancelled
+        assert handles[0]._n_pushed == (3 if backend == "native" else 0)
+        gate.set()
+        assert handles[0].result(timeout=60)["finish"] == "cancelled"
+        sock.close()
+    finally:
+        gate.set()
+        srv.stop()
+        fluid.set_flags({"rpc_transport": "native", "rpc_deadline": 120.0})
 
 
 def test_save_load_lm_and_served_roundtrip(tmp_path):

@@ -460,7 +460,7 @@ def test_overcommit_preempt_resume_is_loss_free():
         victim = max((s for s in eng._slots if s is not None),
                      key=lambda s: s.req.rid).req.handle
         pending_at_preempt.append(
-            (sum(1 for tell, _ in eng._fanout if tell.__self__ is victim),
+            (sum(1 for handle, _, _ in eng._fanout if handle is victim),
              len(victim.tokens)))
         preempt()
     eng._preempt_newest = preempt_recorded

@@ -372,6 +372,168 @@ def test_vectored_io_flag_off_same_wire_bytes(loopback):
 
 
 # ---------------------------------------------------------------------------
+# push_frames: one frame to each of n native connections in one foreign call
+# (the decode plane's token fan-out)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def native_pairs():
+    """``make(n, rcvbuf=None)`` -> n (server-side ``_NativeIO``, peer
+    socket) pairs over a native listener; the peers are plain sockets, so a
+    test reads the wire's own bytes and decides when (and whether) to read."""
+    lib = transport._native_lib()
+    if lib is None:
+        pytest.skip("native transport unavailable")
+    lstn = lib.ptq_listener_create(b"127.0.0.1", 0)
+    port = lib.ptq_listener_port(lstn)
+    made = []
+
+    def make(n, rcvbuf=None):
+        out = []
+        for _ in range(n):
+            peer = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
+            if rcvbuf:      # before connect: it caps the window offered
+                peer.setsockopt(socket.SOL_SOCKET, socket.SO_RCVBUF, rcvbuf)
+            peer.connect(("127.0.0.1", port))
+            peer.settimeout(30)
+            io = transport._NativeIO(lib.ptq_listener_accept(lstn))
+            out.append((io, peer))
+        made.extend(out)
+        return out
+
+    try:
+        yield make
+    finally:
+        for io, peer in made:
+            peer.close()
+            io.close()
+        lib.ptq_listener_close(lstn)
+
+
+def _token_frame(tid, name, tokens):
+    return transport._pack_body_vec(
+        OK, tid, name, [b"T"] + serde.dumps_batch_vec(
+            [("tokens", np.asarray(tokens, np.int32))]))
+
+
+def _read_exact(sock, n):
+    got = b""
+    while len(got) < n:
+        chunk = sock.recv(n - len(got))
+        assert chunk, f"peer closed after {len(got)} of {n} bytes"
+        got += chunk
+    return got
+
+
+def _read_frames(sock, count):
+    out = []
+    for _ in range(count):
+        (n,) = np.frombuffer(_read_exact(sock, 4), "<u4")
+        out.append(_read_exact(sock, int(n)))
+    return out
+
+
+def test_push_frames_writes_the_bytes_send_frame_vec_writes(native_pairs):
+    """n frames for n connections in one call: every peer reads, byte for
+    byte, what ``send_frame_vec`` puts on the wire for the same buffers,
+    and a frame the connection's own thread sends after ``finish_frames``
+    follows the pushed ones."""
+    n = 8
+    pushed, vectored = native_pairs(n), native_pairs(n)
+    rounds = [[_token_frame(i, "lm" + "x" * i,
+                            [(-1) ** i * (1 << 20) * (i + r) + i])
+               for i in range(n)] for r in range(3)]
+    for frames in rounds:
+        rcs = transport.push_frames([io for io, _ in pushed],
+                                    [b"".join(f) for f in frames])
+        assert rcs == [transport.PUSHED] * n
+        for (io, _), f in zip(vectored, frames):
+            io.send_frame_vec(f)
+    for io, _ in pushed + vectored:
+        io.finish_frames()          # waits for the writer; nothing was cut
+        io.send_frame(b"FIN")
+    for frames in rounds + [[[b"FIN"]] * n]:
+        for (_, got), (_, want), f in zip(pushed, vectored, frames):
+            size = 4 + sum(len(b) for b in f)
+            wire = _read_exact(want, size)
+            assert _read_exact(got, size) == wire
+            assert wire[4:] == b"".join(f) and len(wire) < 64
+
+
+def test_push_frames_a_full_socket_blocks_nobody_and_tears_no_frame(
+        native_pairs):
+    """A peer that does not read: the call still returns at once (it does no
+    I/O), the next call reports "would block" for that connection alone,
+    the others' frames go on arriving, and when the peer reads again it
+    gets every frame whole and in order — the rest of the one its socket
+    cut and those kept behind it, written by ``finish_frames``."""
+    (slow_io, slow), = native_pairs(1, rcvbuf=4096)
+    others = native_pairs(3)
+    ios = [slow_io] + [io for io, _ in others]
+    body = bytes(range(256)) * 256                       # 64 KiB a frame
+    sent, verdicts = 0, None
+    for k in range(4000):
+        t0 = time.perf_counter()
+        verdicts = transport.push_frames(
+            ios, [k.to_bytes(4, "little") + body] * len(ios))
+        assert time.perf_counter() - t0 < 1.0
+        sent += 1
+        # the others' k-th frames are written after the slow one's: once
+        # they are read, the next verdict is on the slow one's k-th
+        for _, peer in others:
+            (frame,) = _read_frames(peer, 1)
+            assert frame[:4] == k.to_bytes(4, "little")
+        if verdicts[0] != transport.PUSHED:
+            break
+    assert verdicts == [transport.PUSH_WOULD_BLOCK] + [transport.PUSHED] * 3
+    # a connection that remembers something keeps later frames behind it
+    for k in range(sent, sent + 3):
+        verdicts = transport.push_frames(
+            ios, [k.to_bytes(4, "little") + body] * len(ios))
+        assert verdicts == [transport.PUSH_WOULD_BLOCK] + \
+            [transport.PUSHED] * 3
+        for _, peer in others:
+            _read_frames(peer, 1)
+    sent += 3
+    got = []
+    reader = threading.Thread(
+        target=lambda: got.extend(_read_frames(slow, sent + 1)), daemon=True)
+    reader.start()
+    slow_io.finish_frames()                              # blocking
+    slow_io.send_frame(b"FIN")
+    reader.join(timeout=60)
+    assert not reader.is_alive() and len(got) == sent + 1
+    for k, frame in enumerate(got[:sent]):
+        assert frame == k.to_bytes(4, "little") + body
+    assert got[sent] == b"FIN"
+    # the connection can be pushed to again, from a clean slate
+    assert transport.push_frames([slow_io], [b"again"]) == [transport.PUSHED]
+    assert _read_frames(slow, 1) == [b"again"]
+
+
+def test_push_frames_a_dead_peer_is_an_error_for_it_alone(native_pairs):
+    pairs = native_pairs(3)
+    ios = [io for io, _ in pairs]
+    pairs[1][1].close()
+    verdicts, pushed = None, 0
+    for _ in range(200):      # the reset may take a frame or two to come back
+        verdicts = transport.push_frames(ios, [b"frame"] * 3)
+        pushed += 1
+        if verdicts[1] == transport.PUSH_DEAD:
+            break
+        time.sleep(0.01)
+    assert verdicts == [transport.PUSHED, transport.PUSH_DEAD,
+                        transport.PUSHED]
+    closed = transport._NativeIO(None)     # a connection already closed
+    assert transport.push_frames([ios[0], closed], [b"a", b"b"]) == \
+        [transport.PUSHED, transport.PUSH_DEAD]
+    with pytest.raises(ConnectionError):
+        ios[1].finish_frames()
+    assert _read_frames(pairs[0][1], pushed + 1) == \
+        [b"frame"] * pushed + [b"a"]
+
+
+# ---------------------------------------------------------------------------
 # PServerLoop: batch-of-N counts as N toward the sync-round barrier
 # ---------------------------------------------------------------------------
 

@@ -15,10 +15,19 @@ and repeats nothing of the entry.  An entry with no ``workloads`` list is
 read in every cell that reports the end-to-end metric it moves, so a new cell
 joins those metrics by being listed under that end-to-end metric alone.
 
+Where cells read one metric with arguments that differ in a word (the counts
+file of a kernel's roofline, the scopes of a share, the configuration's key a
+counter is scaled by), the word is the cell's configuration's to supply: its
+file may carry ``"metric_args": {"<metric>": {<key>: <value>}}``, laid key by
+key over the ``args`` of the metric's file, which are the defaults.  A cell
+joins such an entry by its name in the entry's ``workloads`` and a line in the
+configuration file it brings; no metric file, reader or test is edited.
+
 Importing this module imports neither JAX nor the program.
 """
 from __future__ import annotations
 
+import ast
 import importlib.util
 import json
 import math
@@ -78,6 +87,65 @@ def yardstick_module(root: str, manifest: dict, rel) -> Optional[str]:
 
 # what a metric's own file holds; everything else about it is its entry's
 METRIC_FILE_KEYS = ("what", "reader", "args")
+# where a configuration's file keeps the arguments its cells supply
+METRIC_ARGS = "metric_args"
+
+
+def metric_args(config: dict, where: str) -> Dict[str, dict]:
+    """``{metric: {key: value}}`` as the configuration at ``where`` supplies
+    it; nothing where it supplies none."""
+    given = config.get(METRIC_ARGS, {})
+    if not (isinstance(given, dict)
+            and all(isinstance(v, dict) for v in given.values())):
+        raise ConfigurationError(
+            f"{where}: {METRIC_ARGS!r} is not an object of objects, a metric "
+            f"a key")
+    return given
+
+
+def resolved(root: str, manifest: dict, spec: dict, given: Optional[dict],
+             where: str, faults: List[str]) -> Optional[dict]:
+    """A metric's file ``spec`` as a cell reads it: its ``args`` overlaid key
+    by key with what the cell's configuration (at ``where``) gives the
+    metric.  An argument that arrives this way and names a module is held to
+    what one in the file is held to; None with the fault filed otherwise."""
+    given = given or {}
+    stray = _stray_module(root, manifest, given, where)
+    if stray:
+        faults.append(stray)
+        return None
+    return dict(spec, args={**(spec.get("args") or {}), **given})
+
+
+def _stray_module(root: str, manifest: dict, args: dict,
+                  where: str) -> Optional[str]:
+    """The fault of the first of ``args`` that names a module (a string
+    ending in ``.py``: the roofline reader's ``counts``) which is no file
+    under ``paths``; None where every one is."""
+    for key, value in sorted(args.items()):
+        if isinstance(value, str) and value.endswith(".py") \
+                and yardstick_module(root, manifest, value) is None:
+            return f"{where}: {key!r} names no module under paths: {value!r}"
+    return None
+
+
+def read_signature(path: str) -> Optional[tuple]:
+    """(the arguments ``read(ctx, ...)`` of the reader at ``path`` cannot do
+    without, those it takes — None for any), from the file's text: checking a
+    manifest runs no reader's code.  None where the file defines no ``read``."""
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    for node in tree.body:
+        if isinstance(node, ast.FunctionDef) and node.name == "read":
+            a = node.args
+            names = [x.arg for x in a.posonlyargs + a.args][1:]     # less ctx
+            needs = names[:len(names) - len(a.defaults)] + [
+                x.arg for x, d in zip(a.kwonlyargs, a.kw_defaults)
+                if d is None]
+            takes = None if a.kwarg else set(names) | {
+                x.arg for x in a.kwonlyargs}
+            return set(needs), takes
+    return None
 
 
 def metric_applies(metric: dict, workload: str,
@@ -130,6 +198,8 @@ class Cell:
                 f"{self.entry['config']!r}, which {MANIFEST} does not list")
         self.config = _read_json(os.path.join(root, cfg_entry["file"]))
         self.config_name = cfg_entry["name"]
+        self.config_file = cfg_entry["file"]
+        self.metric_args = metric_args(self.config, self.config_file)
         self.bench_dir = bench_dir(root, cfg_entry["file"])
         self.mix_name = self.entry["traffic"]
         self.mix = _read_json(os.path.join(self.bench_dir, "traffic",
@@ -146,11 +216,16 @@ class Cell:
         return load_module(path, f"benchmark_driver_{self.kind}")
 
     def metric_file(self, metric: str) -> dict:
-        """A per-layer metric's own file, held to what ``check_manifest``
-        holds it to."""
+        """A per-layer metric's own file as this cell reads it — its ``args``
+        overlaid with the configuration's ``metric_args`` of the metric — held
+        to what ``check_manifest`` holds it to."""
         faults: List[str] = []
         spec = _metric_file(self.root, self.manifest, os.path.join(
             self.bench_dir, "metrics", metric + ".json"), faults)
+        if spec is not None:
+            spec = resolved(
+                self.root, self.manifest, spec, self.metric_args.get(metric),
+                f"{self.config_file}, {METRIC_ARGS} of {metric!r}", faults)
         if faults:
             raise ConfigurationError("; ".join(faults))
         return spec
@@ -162,7 +237,7 @@ class Cell:
         rel = spec["reader"]
         mod = load_module(os.path.join(self.root, rel),
                           "benchmark_reader_" + re.sub(r"\W", "_", rel))
-        return lambda ctx: mod.read(ctx, **spec.get("args", {}))
+        return lambda ctx: mod.read(ctx, **spec["args"])
 
 
 def load_module(path: str, name: str):
@@ -279,27 +354,72 @@ def check_manifest(root: str, manifest: dict) -> List[str]:
             if m["moves"] not in mine_e2e:
                 faults.append(f"cell {name!r}: {m['name']!r} moves "
                               f"{m['moves']!r}, which the cell does not report")
-    entered = set()
-    for m in manifest["per_layer"]:
-        cells = read_in.get(m.get("name"))
-        if not cells:
-            faults.append(f"metric {m.get('name')!r} is read in no cell")
+    # what each configuration gives the metrics its cells read
+    given: Dict[str, dict] = {}
+    for config, rel in files.items():
+        try:
+            given[config] = metric_args(
+                _read_json(os.path.join(root, rel)), rel)
+        except ConfigurationError as e:
+            faults.append(str(e))
             continue
-        if cells[0].get("config") not in files:
+        reads = {n for n, cells in read_in.items()
+                 if any(w.get("config") == config for w in cells)}
+        for n in sorted(set(given[config]) - reads):
+            # (a misspelt name would read the file's defaults in silence)
+            faults.append(f"{rel}: {METRIC_ARGS} names {n!r}, which no cell "
+                          f"of {config!r} reads")
+    entered: Dict[tuple, str] = {}
+    signatures: Dict[str, Optional[tuple]] = {}
+    for m in manifest["per_layer"]:
+        name, cells = m.get("name"), read_in.get(m.get("name"))
+        if not cells:
+            faults.append(f"metric {name!r} is read in no cell")
+            continue
+        cells = [w for w in cells if w.get("config") in given]
+        if not cells:
             continue
         spec = _metric_file(root, manifest, os.path.join(
             bench_dir(root, files[cells[0]["config"]]), "metrics",
-            str(m.get("name")) + ".json"), faults)
+            str(name) + ".json"), faults)
         if spec is None:
             continue
-        # one reader with the same arguments that moves the same metric is
-        # one metric: a cell joins it by its list, not by a copy of it
-        same = (spec["reader"], json.dumps(spec.get("args") or {},
-                                           sort_keys=True), m.get("moves"))
-        if same in entered:
-            faults.append(f"metric {m.get('name')!r} has the reader, the "
-                          f"args and the 'moves' of another entry: a copy")
-        entered.add(same)
+        reader = spec["reader"]
+        if reader not in signatures:
+            signatures[reader] = read_signature(os.path.join(root, reader))
+        if signatures[reader] is None:
+            faults.append(f"metric {name!r}: {reader} defines no read(ctx, "
+                          f"...)")
+            continue
+        needs, takes = signatures[reader]
+        found: List[str] = []
+        for w in cells:
+            config = w["config"]
+            got = resolved(root, manifest, spec, given[config].get(name),
+                           f"{files[config]}, {METRIC_ARGS} of {name!r}",
+                           found)
+            if got is None:
+                continue
+            args = got["args"]
+            # what the reader cannot do without, and what it does not take:
+            # found here, not as a metric that is silently missing on the chip
+            lacks = sorted(needs - set(args))
+            extra = sorted(set(args) - takes) if takes is not None else []
+            if lacks:
+                found.append(f"metric {name!r} as {config!r} reads it: no "
+                             f"{lacks} for {reader}'s read")
+            if extra:
+                found.append(f"metric {name!r} as {config!r} reads it: "
+                             f"{extra} that it does not take")
+            # one reader with the same arguments that moves the same metric
+            # is one metric, whichever cell reads it and wherever its words
+            # come from: a cell joins it by its list, not by a copy of it
+            same = (reader, json.dumps(args, sort_keys=True), m.get("moves"))
+            if entered.setdefault(same, name) != name:
+                found.append(
+                    f"metric {name!r} is read with the reader, the args and "
+                    f"the 'moves' of {entered[same]!r}: a copy")
+        faults.extend(dict.fromkeys(found))     # once, however many cells
     rs = manifest["run_seconds"]
     if not (isinstance(rs, int) and 1 <= rs <= 51):
         faults.append(f"run_seconds {rs!r}")
@@ -309,7 +429,8 @@ def check_manifest(root: str, manifest: dict) -> List[str]:
 def _metric_file(root: str, manifest: dict, path: str,
                  faults: List[str]) -> Optional[dict]:
     """A per-layer metric's own file, or None with the fault filed: it holds
-    ``what``, ``reader`` and, where the reader takes any, ``args`` — and
+    ``what``, ``reader`` and, where the reader takes any, ``args`` (the
+    defaults of what a cell's configuration may supply: ``resolved``) — and
     nothing of the manifest's entry, so the two cannot disagree.  The reader,
     and any argument that names a module (a string ending in ``.py``: the
     roofline reader's ``counts``), is a file under ``paths``."""
@@ -332,12 +453,10 @@ def _metric_file(root: str, manifest: dict, path: str,
     if yardstick_module(root, manifest, reader) is None:
         faults.append(f"{rel} names no reader under paths: {reader!r}")
         return None
-    for key, value in sorted(spec.get("args", {}).items()):
-        if isinstance(value, str) and value.endswith(".py") \
-                and yardstick_module(root, manifest, value) is None:
-            faults.append(f"{rel}: {key!r} names no module under paths: "
-                          f"{value!r}")
-            return None
+    stray = _stray_module(root, manifest, spec.get("args", {}), rel)
+    if stray:
+        faults.append(stray)
+        return None
     return spec
 
 
@@ -559,7 +678,12 @@ def device_facts(devices, chips: int) -> dict:
 
 
 def result_line(correct: bool, acct: Accounting, metrics: Dict[str, dict],
-                device: dict, breakdown: Optional[dict] = None) -> str:
+                device: dict, breakdown: Optional[dict] = None,
+                checks: Optional[Checks] = None) -> str:
+    """The contract's one JSON object.  ``checks`` rides last, under a key of
+    its own: every condition of ``correct`` with what it read — a comparison's
+    name says its limit, its detail the number — so that the record of a run
+    that is not correct says which number went over."""
     device = {k: v for k, v in device.items()
               if k in ("platform", "kind", "count", "memory_peak_bytes",
                        "busy_s", "window_s")}
@@ -567,6 +691,8 @@ def result_line(correct: bool, acct: Accounting, metrics: Dict[str, dict],
            "failed": int(acct.failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    if checks is not None:
+        out["checks"] = [[n, ok, d] for n, ok, d in checks.items]
     return json.dumps(out)
 
 

@@ -105,8 +105,12 @@ def main(argv=None) -> int:
     else:
         metrics = harness.select_metrics(cell.end_to_end, out["values"])
     print(phases.line(), flush=True)
-    print(harness.result_line(checks.ok, acct, metrics, device, breakdown),
-          flush=True)
+    # what was compared, beside its limit, where the driver's record of a run
+    # that is not correct keeps it: the end of standard error, the result line
+    for line in checks.lines():
+        print(line, file=sys.stderr, flush=True)
+    print(harness.result_line(checks.ok, acct, metrics, device, breakdown,
+                              checks), flush=True)
     return 0
 
 

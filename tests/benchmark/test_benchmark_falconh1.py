@@ -120,8 +120,8 @@ def test_the_new_cell_is_the_one_the_issue_names():
         "gqa_prefill_attn_roofline.served_fh1"}
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
-        if m["name"].endswith("fh1"):       # the model's own: this cell alone
-            assert m["workloads"] == [CELL]
+        if m["name"].endswith("fh1"):       # the model's own: this cell is
+            assert CELL in m["workloads"]   # IN its list (another may join)
     # four chips where the measured thing exists only across chips: at most
     # a quarter of the cells, and one always may (the contract)
     assert 1 <= sum(w["chips"] == 4 for w in MANIFEST["workloads"]) \

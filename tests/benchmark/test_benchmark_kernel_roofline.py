@@ -1,7 +1,9 @@
 """``benchmark/metrics/kernel_roofline.py``, the one roofline reader, through
-each model's counts file: a metric's file names the reader and, in its
-``args``, the counts file (``benchmark/kernel_counts.py`` where it names
-none), and a cell reads its rooflines through that file alone.  One case a
+each model's counts file: a metric's file names the reader and its ``args``,
+and the counts file is named there or — where models share the kernel and
+the entry (PR 58) — in the cell's configuration's ``metric_args``
+(``benchmark/kernel_counts.py`` where neither names one); a cell reads its
+rooflines through what ``Cell.metric_file`` resolves alone.  One case a
 counts file: a hand-made trace of the model's kernels inside the launches of
 its programs, the observer's spans of those launches, and the share worked
 out by hand.  (The pairing of launches with spans is
@@ -43,7 +45,8 @@ TWO_STEPS = [("jit_fn_decode_lm_step(1)", 10, 30),
 
 
 def _mla():
-    """``dsv2l_doc_sat``: its metric files name no counts file."""
+    """``dsv2l_doc_sat``: neither its metric files nor its configuration
+    name a counts file."""
     raw = _trace(TWO_STEPS + [("jit_fn_decode_lm_prefill_1024(2)", 82, 10)], [
         _op("moe_grouped_swiglu.1", 11, 2, "f32[8,8]{1,0}"),
         _op("mla_paged_decode_attn.2", 14, 1),
@@ -57,10 +60,10 @@ def _mla():
                "prefill_tokens_sq": 900 ** 2}]]
     expert = 3 * 2048 * 1408
     return raw, spans, None, DEFAULT_COUNTS, {
-        "moe_step_roofline.served_ds": 100 * 6 * 60 * expert * 2 / 2e-3 / HBM,
-        "mla_decode_attn_roofline.served_ds":
+        "moe_step_roofline.served": 100 * 6 * 60 * expert * 2 / 2e-3 / HBM,
+        "mla_decode_attn_roofline.served":
             100 * 100000 * 7 * 576 * 2 / 1e-3 / HBM,
-        "moe_prefill_roofline.served_ds":
+        "moe_prefill_roofline.served":
             100 * 2 * expert * 6 * 6 * 900 / 8e-3 / BF16}
 
 
@@ -122,13 +125,13 @@ def _smallthinker():
                "prefill_routed_assignments": 4000 * 6 * 8}]]
     expert, pair = 3 * 2560 * 768, 28 * 4 * 128
     return raw, spans, None, "benchmark/kernel_counts_smallthinker.py", {
-        "moe_step_roofline.served_st":
+        "moe_step_roofline.served":
             100 * (500 * expert * 2 + 3072 * 2560 * 4) / 8e-3 / HBM,
         "ring_decode_attn_roofline.served_st":
             100 * 150000 * 6 * 2048 / 1e-3 / HBM,
         "full_decode_attn_roofline.served_st":
             100 * 200000 * 2 * 2048 / 2e-3 / HBM,
-        "moe_prefill_roofline.served_st":
+        "moe_prefill_roofline.served":
             100 * 2 * expert * 4000 * 48 / 4e-3 / BF16,
         "window_prefill_attn_roofline.served_st":
             100 * pair * 8002000 * 6 / 3e-3 / BF16,

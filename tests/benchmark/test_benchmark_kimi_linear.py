@@ -158,16 +158,27 @@ def test_the_new_cell_is_the_one_the_issue_names():
               and m["moves"] in {e["name"] for e in cell.end_to_end}}
     own = {n + ".served_kl" for n in (
         "kda_share", "kda_chunk_prefill_roofline", "kda_state_step_roofline",
+        "held_choice_share")}
+    # what it shares with the other models of latent attention and of
+    # experts since PR 58: one entry a metric, this cell in its list, the
+    # counts file and the configuration's key of the experts HELD in this
+    # configuration's `metric_args`; the scopes and counters bear
+    # decode/mla.py's names, the defaults
+    shared = {n + ".served" for n in (
         "mla_prefill_attn_roofline", "mla_decode_attn_roofline",
-        "moe_prefill_roofline", "moe_step_roofline", "held_choice_share")}
-    assert names == family | own | {"prefill_pad_share.served",
-                                    "live_context_tokens.served"}
+        "moe_prefill_roofline", "moe_step_roofline", "mla_share", "moe_share",
+        "expert_load_max_over_mean", "experts_touched_per_step",
+        "prefill_pad_share", "live_context_tokens")}
+    assert names == family | own | shared
+    assert set(cell.config["metric_args"]) <= shared
+    assert cell.metric_file("expert_load_max_over_mean.served")["args"][
+        "times_config"] == "num_experts"        # the 64 held, not the 256
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
-        if m["name"] in own:
+        if m["name"] in own | shared:
             assert CELL in m["workloads"] \
                 and m["moves"] == "served_tokens_per_s"
-        if m["name"].endswith("_roofline.served_kl"):
+        if m["name"].split(".")[0].endswith("_roofline"):
             spec = cell.metric_file(m["name"])
             assert spec["args"]["counts"] == \
                 "benchmark/kernel_counts_kimi_linear.py"

@@ -144,22 +144,34 @@ def test_the_new_cell_is_the_one_the_issue_names():
         "hbm_peak_gb.served", "hbm_temp_gb.served", "warm_cache_hits",
         "window_compiles", "program_build_s"}
     own = {n + ".served_lfm2" for n in (
-        "moe_share", "conv_mixer_share", "attn_share", "moe_prefill_roofline",
-        "moe_step_roofline", "gqa64_prefill_attn_roofline",
-        "gqa64_decode_attn_roofline", "expert_load_max_over_mean",
-        "prefill_expert_load_max_over_mean", "experts_touched_per_step",
+        "conv_mixer_share", "attn_share", "gqa64_prefill_attn_roofline",
+        "gqa64_decode_attn_roofline", "prefill_expert_load_max_over_mean",
         "moe_tile_pad_share")}
-    assert names == family | own | {"prefill_pad_share.served",
-                                    "live_context_tokens.served"}
+    # what it shares with the other models of experts since PR 58: one entry
+    # a metric, this cell in its list, the words that differ (the counts
+    # file, the scope, the configuration's key of the experts held, a step's
+    # layers counted together) in this configuration's `metric_args`
+    shared = {n + ".served" for n in (
+        "moe_share", "moe_prefill_roofline", "moe_step_roofline",
+        "expert_load_max_over_mean", "experts_touched_per_step",
+        "prefill_pad_share", "live_context_tokens")}
+    assert names == family | own | shared
+    assert set(cell.config["metric_args"]) <= shared
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
-        if m["name"] in own:                # its own: this cell alone
-            assert m["workloads"] == [CELL]
-        if m["name"].endswith("_roofline.served_lfm2"):
+        if m["name"] in own | shared:       # this cell is IN its list
+            assert CELL in m["workloads"]
+        if m["name"].split(".")[0].endswith("_roofline"):
             spec = cell.metric_file(m["name"])
             assert spec["args"]["counts"] == "benchmark/kernel_counts_lfm2.py"
             assert spec["args"]["count"] in kernel_counts_lfm2.COUNTS
             assert m["unit"] == "%"
+    assert cell.metric_file("moe_share.served")["args"] == {
+        "scopes": ["/moe/"]}
+    assert cell.metric_file("expert_load_max_over_mean.served")["args"][
+        "times_config"] == "num_experts"
+    assert cell.metric_file("experts_touched_per_step.served")["args"][
+        "den"] == ["steps"]
 
 
 def test_a_checkout_without_the_model_is_refused_before_a_device(driver,

@@ -11,6 +11,7 @@ their length, or a whole list of them, with a literal; the contract's limits
 (128 entries, 24 cells, a quarter of the cells on four chips) are asserted in
 this file, and no other module needs them."""
 import ast
+import functools
 import glob
 import hashlib
 import importlib.util
@@ -199,6 +200,157 @@ def test_check_manifest_faults_a_copy_and_a_file_that_repeats_its_entry(tmp_path
                for f in harness.check_manifest(root, bad))
 
 
+def _with_metric_args(root, config, change):
+    """Rewrites ``config``'s file in ``root``'s copy of the benchmark with
+    ``change`` applied to its ``metric_args`` (what a later PR would have
+    written into the configuration file it brings)."""
+    path = os.path.join(root, "benchmark", "configs", config + ".json")
+    with open(path) as f:
+        cfg = json.load(f)
+    cfg["metric_args"] = change(cfg.get("metric_args", {}))
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+
+
+@functools.lru_cache(maxsize=None)
+def _shared_entry(reader):
+    """(an entry that the cells of two configurations or more read with
+    ``reader``, a cell whose configuration gives it words of its own, that
+    configuration): found by what the manifest and the files say, so that the
+    controls below name no model."""
+    for m in MANIFEST["per_layer"]:
+        cells = [harness.Cell(REPO, MANIFEST, w) for n, w in PAIRS
+                 if n == m["name"]]
+        if len({c.config_name for c in cells}) < 2 \
+                or cells[0].metric_file(m["name"])["reader"] != reader:
+            continue
+        for cell in cells:
+            if m["name"] in cell.metric_args:
+                return m, cell, cell.config_name
+    raise AssertionError(f"no shared entry is read with {reader}")
+
+
+def _control_unread(root):
+    m, cell, config = _shared_entry(_SCOPES)
+    misspelt = m["name"] + "x"
+    _with_metric_args(root, config, lambda a: dict(a, **{misspelt: {}}))
+    return MANIFEST, [f"names {misspelt!r}, which no cell of {config!r} reads"]
+
+
+def _control_not_an_object(root):
+    m, cell, config = _shared_entry(_SCOPES)
+    _with_metric_args(root, config, lambda a: [m["name"]])
+    return MANIFEST, ["'metric_args' is not an object of objects"]
+
+
+def _control_a_copy_by_what_a_cell_resolves(root):
+    # the FILES of the two entries differ; the cell's configuration gives the
+    # shared one the scopes of the cell's own one
+    m, cell, config = _shared_entry(_SCOPES)
+    (own,) = [e for e in cell.per_layer if e["name"] != m["name"]
+              and e.get("workloads") == [cell.name]
+              and cell.metric_file(e["name"])["reader"] == _SCOPES][:1]
+    scopes = cell.metric_file(own["name"])["args"]["scopes"]
+    _with_metric_args(root, config, lambda a: dict(
+        a, **{m["name"]: dict(a[m["name"]], scopes=scopes)}))
+    return MANIFEST, ["a copy"]
+
+
+def _control_files_alike_that_resolve_apart(root):
+    # a second entry whose FILE is the shared one's, byte for byte, read by
+    # one cell whose configuration gives each of the two its own scopes: not
+    # a copy (the rule before PR 58 compared the files and called it one)
+    m, cell, config = _shared_entry(_SCOPES)
+    twin = dict(m, name=m["name"] + "_b", workloads=[cell.name])
+    metrics = os.path.join(root, "benchmark", "metrics")
+    shutil.copy(os.path.join(metrics, m["name"] + ".json"),
+                os.path.join(metrics, twin["name"] + ".json"))
+    _with_metric_args(root, config, lambda a: dict(
+        a, **{twin["name"]: {"scopes": ["/somewhere_else/"]}}))
+    manifest = json.loads(json.dumps(MANIFEST))
+    manifest["per_layer"].append(twin)
+    return manifest, []
+
+
+def _control_too_few_arguments(root):
+    # the shared file loses its defaults: the cells whose configurations give
+    # the entry nothing resolve to no `scopes`, those that give theirs do
+    m, cell, config = _shared_entry(_SCOPES)
+    path = os.path.join(root, "benchmark", "metrics", m["name"] + ".json")
+    with open(path) as f:
+        spec = json.load(f)
+    with open(path, "w") as f:
+        json.dump({k: v for k, v in spec.items() if k != "args"}, f)
+    bare = sorted({w["config"] for w in MANIFEST["workloads"]
+                   if (m["name"], w["name"]) in PAIRS
+                   and m["name"] not in harness.Cell(
+                       REPO, MANIFEST, w["name"]).metric_args})
+    assert bare and config not in bare
+    return MANIFEST, [f"as {c!r} reads it: no ['scopes'] for {_SCOPES}'s read"
+                      for c in bare]
+
+
+def _control_an_argument_the_reader_does_not_take(root):
+    m, cell, config = _shared_entry(_RATIO)
+    _with_metric_args(root, config, lambda a: dict(
+        a, **{m["name"]: dict(a[m["name"]], time_config="n")}))
+    return MANIFEST, [f"as {config!r} reads it: ['time_config'] that it "
+                      f"does not take"]
+
+
+CONTROLS = [_control_unread, _control_not_an_object,
+            _control_a_copy_by_what_a_cell_resolves,
+            _control_files_alike_that_resolve_apart,
+            _control_too_few_arguments,
+            _control_an_argument_the_reader_does_not_take]
+
+
+@pytest.mark.parametrize("control", CONTROLS,
+                         ids=[c.__name__[len("_control_"):] for c in CONTROLS])
+def test_check_manifest_holds_what_a_configuration_supplies(control, tmp_path):
+    """What a later PR writes into the configuration file it brings is held
+    before a chip is asked for: each control is ONE named fault a
+    configuration it touches (none where the rule must not fire)."""
+    root = str(tmp_path)
+    _copy_of(root, "benchmark")
+    assert harness.check_manifest(root, MANIFEST) == []
+    manifest, expect = control(root)
+    faults = harness.check_manifest(root, manifest)
+    assert len(faults) == len(expect), faults
+    for fault, words in zip(sorted(faults), sorted(expect)):
+        assert words in fault, (fault, words)
+
+
+BAD_COUNTS = ["paddle_tpu/kernels/moe.py",
+              "benchmark/../paddle_tpu/kernels/moe.py", "../kernel_counts.py",
+              "benchmark/kernel_counts_none.py",
+              os.path.join(REPO, "benchmark", "kernel_counts.py"),
+              "benchmark/linked_counts.py"]
+
+
+@pytest.mark.parametrize("counts", BAD_COUNTS)
+def test_a_counts_file_that_a_configuration_names_is_held_as_a_metric_files_is(
+        counts, tmp_path):
+    """The program's tree, a detour through "..", a path from "/", a file
+    that is not there, a link out of the checkout: ``check_manifest`` faults
+    it once, naming the configuration's file, and the reader is refused at
+    run time, before a number is printed."""
+    root = str(tmp_path)
+    _copy_of(root, "benchmark")
+    os.symlink(os.path.join(REPO, "bench.py"),
+               os.path.join(root, "benchmark", "linked_counts.py"))
+    m, cell, config = _shared_entry(_ROOFLINE)
+    assert cell.metric_args[m["name"]]["counts"].startswith("benchmark/")
+    _with_metric_args(root, config, lambda a: dict(
+        a, **{m["name"]: dict(a[m["name"]], counts=counts)}))
+    faults = harness.check_manifest(root, MANIFEST)
+    assert len(faults) == 1 and "'counts' names no module under paths" \
+        in faults[0] and f"configs/{config}.json" in faults[0] \
+        and m["name"] in faults[0], faults
+    with pytest.raises(harness.ConfigurationError, match="no module under"):
+        harness.Cell(root, MANIFEST, cell.name).reader(m["name"])
+
+
 def test_the_harness_knows_no_cell_config_mix_or_metric_by_a_literal():
     names = set(CELLS) | set(PER_LAYER)
     names |= {c["name"] for c in MANIFEST["configs"]}
@@ -227,7 +379,10 @@ def _digests(root):
 # What a later ``model_config`` PR brings, shaped as the accepted ones were: a
 # configuration, a mix, a cell, a reader and a counts file of its own, and
 # twelve per-layer entries that its cell alone reads — {name: (reader, args)};
-# what else an entry says follows from its reader.
+# what else an entry says follows from its reader.  And, since PR 58, what it
+# JOINS: a roofline, a share and a counter that the models of experts share,
+# by its cell's name at the end of their lists and by the words of its own in
+# the ``metric_args`` of the configuration file it brings — JOINED.
 NEW_CONFIG, NEW_MIX, NEW_CELL = "tlm-new", "new_mix", "lm_new_cell"
 NEW_READER, NEW_COUNTS = "benchmark/metrics/new_metric.py", \
     "benchmark/kernel_counts_new.py"
@@ -262,6 +417,12 @@ NEW_METRICS = {
         "num": ["step_new_rows_live"], "den": ["step_new_rows_held"],
         "scale": 100.0}),
 }
+JOINED = {
+    "moe_prefill_roofline.served": {"counts": NEW_COUNTS,
+                                    "kernel": "^new_grouped_all"},
+    "moe_share.served": {"scopes": ["/new_route/", "/new_experts/"]},
+    "expert_load_max_over_mean.served": {"times_config": "n_layer"},
+}
 _SAYS = {      # unit, better, source, layer: the accepted entries' own words
     NEW_READER: ("count", "higher", "program_counter", "decode plane"),
     _RATIO: ("ratio", "lower", "program_counter", "decode plane"),
@@ -282,7 +443,7 @@ def _a_later_prs_files(root):
     bench = os.path.join(root, "benchmark")
     with open(os.path.join(bench, "configs", "tlm-gpt1w.json")) as f:
         cfg = json.load(f)
-    cfg.update(name=NEW_CONFIG, max_seq_len=256)
+    cfg.update(name=NEW_CONFIG, max_seq_len=256, metric_args=JOINED)
     with open(os.path.join(bench, "configs", NEW_CONFIG + ".json"), "w") as f:
         json.dump(cfg, f)
     with open(os.path.join(bench, "traffic", "batch_sat.json")) as f:
@@ -295,8 +456,11 @@ def _a_later_prs_files(root):
     with open(os.path.join(root, NEW_READER), "w") as f:
         f.write("def read(ctx, scale):\n"
                 "    return scale * ctx['decodez']['steps']\n")
-    counts = sorted(a["count"] for _, a in NEW_METRICS.values()
-                    if "count" in a)
+    counts = {a["count"] for _, a in NEW_METRICS.values() if "count" in a}
+    for name in JOINED:         # the joined roofline's count, by its file
+        with open(os.path.join(bench, "metrics", name + ".json")) as f:
+            counts |= {json.load(f)["args"].get("count")} - {None}
+    counts = sorted(counts)
     with open(os.path.join(root, NEW_COUNTS), "w") as f:
         f.write("def _nothing(cfg, w):\n    return 0.0, 0.0\n\n\n"
                 f"COUNTS = dict.fromkeys({counts!r}, _nothing)\n")
@@ -319,17 +483,28 @@ def _a_later_prs_files(root):
 
 def _appended(config, cell, per_layer):
     """``BENCHMARK.json`` as that PR leaves it: its entries at the END of
-    ``configs``, ``workloads`` and ``per_layer``, and the cell's name under
-    ``served_tokens_per_s`` — no other line of the manifest."""
+    ``configs``, ``workloads`` and ``per_layer``, the cell's name under
+    ``served_tokens_per_s`` and — a cell of the configuration it brings — at
+    the end of the lists of the entries it JOINS; no other line of the
+    manifest."""
     manifest = json.loads(json.dumps(MANIFEST))
     if config is not None:
         manifest["configs"].append(config)
     manifest["workloads"].append(cell)
-    for m in manifest["end_to_end"]:
-        if m["name"] == "served_tokens_per_s":
+    joins = JOINED if config is not None else ()
+    for m in manifest["end_to_end"] + manifest["per_layer"]:
+        if m["name"] == "served_tokens_per_s" or m["name"] in joins:
             m["workloads"] = m["workloads"] + [cell["name"]]
     manifest["per_layer"] += per_layer
     return manifest
+
+
+def _less_the_joins(per_layer):
+    """``per_layer`` with the later PR's cell taken off the ends of the lists
+    it joined: what must be the manifest's own, entry for entry."""
+    return [dict(m, workloads=m["workloads"][:-1])
+            if m["name"] in JOINED and m["workloads"][-1] == NEW_CELL else m
+            for m in per_layer]
 
 
 def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
@@ -347,14 +522,15 @@ def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
     assert {m["name"] for m in harness.Cell(root, ninth, "lm_ninth").per_layer} \
         == SERVED_FAMILY
 
-    # a cell of a new configuration listed under served_tokens_per_s ALONE —
-    # no per-layer entry edited or added, no file under metrics/ read — reads
-    # the serve family of twelve and the two compile-cache counts
+    # a cell of a new configuration listed under served_tokens_per_s — no
+    # per-layer entry added, no file under metrics/ written — reads the serve
+    # family of twelve and the two compile-cache counts, and the three shared
+    # entries it is listed under with the words its configuration gives
     manifest = _appended(config, new_cell, [])
     assert harness.check_manifest(root, manifest) == []
     joined = {m["name"] for m in
               harness.Cell(root, manifest, NEW_CELL).per_layer}
-    assert joined == SERVED_FAMILY
+    assert joined == SERVED_FAMILY | set(JOINED)
     assert joined >= {
         "decode_step_ms.served", "prefill_ms.served", "step_host_ms.served",
         "step_emit_ms.served", "device_idle_share.served",
@@ -362,7 +538,7 @@ def test_a_config_a_mix_a_metric_and_a_cell_are_added_by_files_alone(tmp_path):
         "hbm_peak_gb.served", "hbm_temp_gb.served",
         "tokens_per_decode_step.served", "top_device_op_share.served",
         "program_build_s"}
-    assert manifest["per_layer"] == MANIFEST["per_layer"]
+    assert _less_the_joins(manifest["per_layer"]) == MANIFEST["per_layer"]
     # a metric of its own is one more entry and one more file
     manifest = _appended(config, new_cell, entries)
     assert harness.check_manifest(root, manifest) == []
@@ -411,6 +587,17 @@ def test_result_line_has_exactly_the_contracts_keys():
     assert set(line["device"]) == {"platform", "kind", "count",
                                    "memory_peak_bytes"}
     assert line["attempted"] == 1 and line["failed"] == 0
+    # what was compared rides last, under a key of its own
+    checks = harness.Checks()
+    checks.add("reference comparison: logit_err within 0.02", True, "0.0071")
+    checks.add("no compile inside the window", False, "1 backend compile(s)")
+    line = json.loads(harness.result_line(
+        False, acct, {}, {"platform": "tpu", "kind": "TPU v5 lite",
+                          "count": 1, "memory_peak_bytes": 5}, None, checks))
+    assert list(line)[-1] == "checks" and line["correct"] is False
+    assert line["checks"] == [
+        ["reference comparison: logit_err within 0.02", True, "0.0071"],
+        ["no compile inside the window", False, "1 backend compile(s)"]]
 
 
 def test_percentile_and_spread_are_the_stated_rules():
@@ -454,15 +641,36 @@ def test_a_later_prs_entries_fit_and_change_no_accepted_cells_metrics(
     assert len(manifest["per_layer"]) == len(PER_LAYER) + 12 <= 128
     assert [w["name"] for w in manifest["workloads"]] == CELLS + [NEW_CELL]
     assert len(manifest["workloads"]) <= 24
-    for w in CELLS:     # (the rate's own entry has one more name in its list)
+    # (the rate's own entry and the three joined ones have one more name at
+    # the end of their lists; nothing else about any entry differs)
+    assert _less_the_joins(manifest["per_layer"][:len(PER_LAYER)]) \
+        == MANIFEST["per_layer"]
+    for w in CELLS:
         (e2e, per_layer), (was_e2e, was) = (
             harness.cell_metrics(m, w) for m in (manifest, MANIFEST))
-        assert per_layer == was and [m["name"] for m in e2e] \
-            == [m["name"] for m in was_e2e], w
+        assert _less_the_joins(per_layer) == was \
+            and [m["name"] for m in e2e] == [m["name"] for m in was_e2e], w
+        # and every accepted cell reads every entry with the reader and the
+        # arguments it read it with: the joined entries' too
+        later, here = harness.Cell(root, manifest, w), \
+            harness.Cell(REPO, MANIFEST, w)
+        for m in was:
+            assert later.metric_file(m["name"]) \
+                == here.metric_file(m["name"]), (w, m["name"])
     # and its cell reads the list-less served family through its name under
-    # served_tokens_per_s alone, beside its own twelve
-    assert {m["name"] for m in harness.cell_metrics(manifest, NEW_CELL)[1]} \
-        == SERVED_FAMILY | set(NEW_METRICS)
+    # served_tokens_per_s alone, beside its own twelve and the three it joined
+    # with its own words
+    cell = harness.Cell(root, manifest, NEW_CELL)
+    assert {m["name"] for m in cell.per_layer} \
+        == SERVED_FAMILY | set(NEW_METRICS) | set(JOINED)
+    for name, words in JOINED.items():
+        with open(os.path.join(REPO, "benchmark", "metrics",
+                               name + ".json")) as f:
+            shared = json.load(f)
+        got = cell.metric_file(name)
+        assert got["reader"] == shared["reader"]
+        assert got["args"] == {**shared["args"], **words}
+        assert callable(cell.reader(name))
 
 
 def _manifest_readers(source):

@@ -242,6 +242,9 @@ def test_an_entry_is_sound_and_names_the_reader(metric):
     (entry,) = [m for m in MANIFEST["per_layer"] if m["name"] == metric]
     assert "workloads" not in entry and entry["unit"] == "ms" \
         and entry["better"] == "lower" and entry["layer"] == "decode plane"
+    # span arguments (`cpu_ns`) and the launches' ends, from one trace file:
+    # entered under the spans' label since PR 58 (device_trace before it)
+    assert entry["source"] == "program_span"
     moved = {"served": "served_tokens_per_s", "tbt50": "tbt_p50_ms"}
     assert entry["moves"] == moved[metric.rsplit(".", 1)[1]]
     cells = [w["name"] for w in MANIFEST["workloads"]

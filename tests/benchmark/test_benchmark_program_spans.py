@@ -220,7 +220,7 @@ def test_the_counter_reader_scales_a_counter_and_skips_a_missing_one():
 
 
 SPAN_READERS = ("span_mean.py", "span_arg_percentile.py",
-                "idle_under_spans.py")
+                "idle_under_spans.py", "engine_off_cpu.py")
 # every (span metric, cell that reads it)
 SPAN_PAIRS = [(m["name"], w["name"]) for w in MANIFEST["workloads"]
               for m in harness.cell_metrics(MANIFEST, w["name"])[1]

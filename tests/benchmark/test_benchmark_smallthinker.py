@@ -126,18 +126,30 @@ def test_the_new_cell_is_the_one_the_issue_names():
     assert "decode_step_ms.served" in family
     assert names >= family | {
         "prefill_pad_share.served", "live_context_tokens.served",
-        "moe_share.served_st", "window_attn_share.served_st",
-        "full_attn_share.served_st", "expert_load_max_over_mean.served_st",
-        "ring_live_share.served_st", "moe_prefill_roofline.served_st",
-        "moe_step_roofline.served_st",
+        "moe_share.served", "window_attn_share.served_st",
+        "full_attn_share.served_st", "expert_load_max_over_mean.served",
+        "ring_live_share.served_st", "moe_prefill_roofline.served",
+        "moe_step_roofline.served",
         "window_prefill_attn_roofline.served_st",
         "full_prefill_attn_roofline.served_st",
         "ring_decode_attn_roofline.served_st",
         "full_decode_attn_roofline.served_st"}
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
-        if m["name"].endswith("served_st"):   # its own: this cell alone
-            assert m["workloads"] == [CELL]
+        if "workloads" in m:                # listed: this cell is IN the list
+            assert CELL in m["workloads"]
+    # the four it shares with the other models of experts since PR 58, by the
+    # words of its own in the configuration's `metric_args`: the ReGLU kernel,
+    # its counts file, its two scopes, its key of the experts held
+    assert set(cell.config["metric_args"]) == {n + ".served" for n in (
+        "moe_share", "moe_prefill_roofline", "moe_step_roofline",
+        "expert_load_max_over_mean")}
+    for n in ("moe_prefill_roofline.served", "moe_step_roofline.served"):
+        args = cell.metric_file(n)["args"]
+        assert (args["kernel"], args["counts"]) == (
+            "^moe_grouped_reglu", "benchmark/kernel_counts_smallthinker.py")
+    assert cell.metric_file("moe_share.served")["args"] == {
+        "scopes": ["/route/", "/experts/"]}
     # four chips where the measured thing exists only across chips: at most
     # a quarter of the cells, and one always may (the contract)
     assert 1 <= sum(w["chips"] == 4 for w in MANIFEST["workloads"]) \
@@ -159,14 +171,17 @@ def test_no_accepted_metric_starts_to_match_a_new_kernel():
     import re
     new = ("gqa_window_flash_fwd", "gqa_group_flash_fwd",
            "gqa_ring_decode_attn", "moe_grouped_reglu")
-    for m in MANIFEST["per_layer"]:
-        if m.get("workloads") == [CELL]:
+    # as every OTHER cell reads its entries (a shared entry's kernel is a
+    # configuration's word since PR 58: this one's names `moe_grouped_reglu`)
+    for w in MANIFEST["workloads"]:
+        if w["name"] == CELL:
             continue
-        args = harness.Cell(REPO, MANIFEST, m["workloads"][0] if
-                            "workloads" in m else CELL
-                            ).metric_file(m["name"]).get("args") or {}
-        if "kernel" in args:
-            assert not any(re.search(args["kernel"], k) for k in new), m
+        cell = harness.Cell(REPO, MANIFEST, w["name"])
+        for m in cell.per_layer:
+            args = cell.metric_file(m["name"])["args"]
+            if "kernel" in args:
+                assert not any(re.search(args["kernel"], k) for k in new), \
+                    (w["name"], m["name"])
 
 
 def test_the_replay_agrees_with_the_reference(driver, served):
@@ -378,7 +393,7 @@ def test_the_counter_readers_read_the_window_s_deltas():
         "prefill_pad_tokens": 10.0, "prefill_real_tokens": 90.0,
         "step_context_tokens": 640.0, "step_streams": 64.0}}
     assert cell.reader("ring_live_share.served_st")(ctx) == 25.0
-    assert cell.reader("expert_load_max_over_mean.served_st")(ctx) == 3.0
+    assert cell.reader("expert_load_max_over_mean.served")(ctx) == 3.0
     assert cell.reader("prefill_pad_share.served")(ctx) == 10.0
     assert cell.reader("live_context_tokens.served")(ctx) == 10.0
     # the parent has no such counter: nothing, and no error

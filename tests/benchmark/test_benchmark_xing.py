@@ -41,8 +41,16 @@ CONFIG = "xing4-29b-a4b-s0"
 MANIFEST = harness.load_manifest(REPO)
 OWN = {n + ".served_xg" for n in (
     "mhc_share", "dense_ffn_share", "mhc_pre_prefill_roofline",
-    "mhc_post_prefill_roofline", "mla_prefill_attn_roofline",
-    "mla_decode_attn_roofline", "moe_prefill_roofline", "moe_step_roofline")}
+    "mhc_post_prefill_roofline")}
+# what it shares with the other models of latent attention and of experts
+# since PR 58: one entry a metric, this cell in its list, the counts file and
+# the configuration's key of the experts held in this configuration's
+# `metric_args`; the scopes and counters are decode/mla.py's, the defaults
+SHARED = {n + ".served" for n in (
+    "mla_prefill_attn_roofline", "mla_decode_attn_roofline",
+    "moe_prefill_roofline", "moe_step_roofline", "mla_share", "moe_share",
+    "expert_load_max_over_mean", "experts_touched_per_step",
+    "prefill_pad_share")}
 
 
 @pytest.fixture(scope="module")
@@ -91,7 +99,7 @@ def test_the_manifest_is_sound_and_names_the_cell_and_its_configuration_once():
     mine = [e for key in ("configs", "workloads", "per_layer")
             for e in MANIFEST[key]
             if e["name"] in (CONFIG, CELL) or e["name"] in OWN]
-    assert len(mine) == 10
+    assert len(mine) == 2 + len(OWN)
     for e in mine:
         for value in e.values():
             if isinstance(value, str):
@@ -154,16 +162,22 @@ def test_the_new_cell_is_the_one_the_issue_names():
     names = {m["name"] for m in cell.per_layer}
     family = {m["name"] for m in MANIFEST["per_layer"] if "workloads" not in m
               and m["moves"] in {e["name"] for e in cell.end_to_end}}
-    assert names == family | OWN
+    assert names == family | OWN | SHARED
+    assert set(cell.config["metric_args"]) <= SHARED
     for m in cell.per_layer:
         cell.reader(m["name"])              # every reader is found by name
+        if m["name"] in OWN | SHARED:       # this cell is IN its list
+            assert CELL in m["workloads"] \
+                and m["moves"] == "served_tokens_per_s"
         if m["name"] in OWN:
-            assert m["workloads"] == [CELL] \
-                and m["moves"] == "served_tokens_per_s" and m["unit"] == "%"
-        if m["name"].endswith("_roofline.served_xg"):
+            assert m["unit"] == "%"
+        if m["name"].split(".")[0].endswith("_roofline"):
             spec = cell.metric_file(m["name"])
             assert spec["args"]["counts"] == "benchmark/kernel_counts_xing.py"
             assert spec["args"]["count"] in kernel_counts_xing.COUNTS
+    assert cell.metric_file("expert_load_max_over_mean.served")["args"][
+        "times_config"] == "n_routed_experts"
+    assert cell.config["n_routed_experts"] == 64
 
 
 def test_a_checkout_without_the_model_is_refused_before_a_device(driver,

@@ -45,7 +45,7 @@ package is that state plane, built on the repo's own primitives:
   among them ``step_live_blocks`` of ``step_table_blocks`` on ``/decodez``,
   the share of the tables handed to the decode steps' attention kernels that
   their walks fetched.
-- **Eight models behind the one engine**: :mod:`model` (the repo's LM block:
+- **Nine models behind the one engine**: :mod:`model` (the repo's LM block:
   K and V of every layer paged, a suffix prefill, so the one model that
   ``supports`` prefix cache, overcommit and beams); :mod:`mla` (DeepSeek-V2:
   latent attention over ONE latent pool, YaRN, routed experts beside shared
@@ -62,7 +62,11 @@ package is that state plane, built on the repo's own primitives:
   sigmoid-routed experts); :mod:`kimi_linear` (Kimi-Linear: three gated
   delta-rule layers — a decay a channel, a recurrent row and a convolution
   tail a slot — to one position-free latent-attention layer, a latent row a
-  token beside them in ONE cache, and a SHARE of the router's experts).
+  token beside them in ONE cache, and a SHARE of the router's experts);
+  :mod:`command_a` (Command A+: ONE LayerNorm a layer and three branches
+  summed — 128 query heads on 8 K/V heads over window rings or a
+  position-free pool, a share of 128 sigmoid-routed experts, four shared
+  experts averaged — and a slice of the tied embedding).
   Each module's docstring is its model's.
 - **On-device sampling** (:func:`adapter.sample`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
@@ -106,6 +110,7 @@ from .smallthinker import (SmallThinkerConfig,  # noqa: F401
                            SmallThinkerLM)
 from .lfm2 import LFM2Config, LFM2LM  # noqa: F401
 from .kimi_linear import KimiLinearConfig, KimiLinearLM  # noqa: F401
+from .command_a import CommandAConfig, CommandALM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -123,6 +128,7 @@ __all__ = [
     "HyperMLATransformerLM", "SambaYConfig", "SambaYLM",
     "FalconH1Config", "FalconH1LM", "SmallThinkerConfig", "SmallThinkerLM",
     "LFM2Config", "LFM2LM", "KimiLinearConfig", "KimiLinearLM",
+    "CommandAConfig", "CommandALM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

@@ -1,7 +1,8 @@
 """What the served models have in common: the model protocol as a class, the
 layer math they share, the paged pool's addressing and the observers' common
 series.  A model module (:mod:`model`, :mod:`mla`, :mod:`sambay`,
-:mod:`falcon_h1`, :mod:`smallthinker`, :mod:`lfm2`, :mod:`kimi_linear`) brings
+:mod:`falcon_h1`, :mod:`smallthinker`, :mod:`lfm2`, :mod:`kimi_linear`,
+:mod:`command_a`) brings
 its config, its
 ``param_shapes``, its layers, its two programs and the counters that are its
 own; it imports this module and no sibling.
@@ -324,6 +325,42 @@ def step_addresses(positions, block_tables, block_tokens: int):
     return cl, live, slots, blocks
 
 
+# A window layer's ring is ``window / ring_rows`` blocks of ``ring_rows`` rows
+# that belong to a slot for good (``cache.HybridStateCache``): slot ``s``'s
+# are blocks ``s · blocks …`` of the layer's, and position ``p`` lies at row
+# ``p mod window`` of them.
+
+def ring_of_prompt(length, bucket: int, window: int, ring_rows: int):
+    """What a slot's ring holds after a bucket-padded prompt of ``length``
+    real positions, as ``fill(rows [bucket, width]) → [1, window / ring_rows,
+    ring_rows, width]`` (to be written over the slot's blocks whole): a
+    bucket inside the window lies in the ring as it is; otherwise ring row
+    ``r`` gets the last real position that is ``r mod window`` (rows past a
+    short prompt's end hold what the walk never reads)."""
+    direct = bucket <= window and bucket % ring_rows == 0
+    if not direct:
+        r = jnp.arange(window, dtype=jnp.int32)
+        src = jnp.clip(r + window * ((length - 1 - r) // window), 0,
+                       bucket - 1)
+
+    def fill(rows):
+        ring = rows if direct else rows[src]
+        return ring.reshape(1, -1, ring_rows, ring.shape[-1])
+
+    return fill
+
+
+def ring_step_addresses(positions, slots, window: int, ring_rows: int):
+    """Where a decode step's rows go in the rings, and what its walk reads:
+    ``tables`` [S, window / ring_rows] (a slot's own ring blocks, the walk's
+    table), ``blocks`` [S] (the block each slot's token lands in) and
+    ``rows`` [S] (its row inside the block)."""
+    nrb = window // ring_rows
+    at = positions % window
+    tables = slots[:, None] * nrb + jnp.arange(nrb, dtype=jnp.int32)
+    return tables, slots * nrb + at // ring_rows, at % ring_rows
+
+
 def walked_blocks(contexts, block_rows: int, slots: int) -> int:
     """Blocks ONE paged walk fetches in a decode step: a live stream's
     ``ceil(context / block_rows)`` and an idle slot's one (its length is one
@@ -598,9 +635,56 @@ class RoutedLoadSeries:
         return assignments, touched
 
 
+class RingSeries:
+    """The series of a model whose window layers keep RINGS (a slot's last
+    ``window`` rows a window layer), fed by the prompts' and the live
+    streams' lengths.  ``step_ring_rows_live`` over ``step_ring_rows_held`` is
+    the share of the rings' bytes that the live streams use: a stream holds
+    ``window`` rows a window layer whatever its context, and ``min(context,
+    window)`` of them are live."""
+
+    def __init__(self, sc, window: int):
+        self.window = int(window)
+        self.prefill_pairs = sc.counter(
+            "prefill_window_pairs", "(query, visible key) pairs of one "
+            "window layer, summed over prefills")
+        self.live = sc.counter(
+            "step_ring_rows_live", "ring rows a decode step's streams read "
+            "(context cut at the window), summed over steps (one window "
+            "layer)")
+        self.held = sc.counter(
+            "step_ring_rows_held", "ring rows the live streams hold (the "
+            "window a stream), summed over steps (one window layer)")
+        self.past_window = sc.counter(
+            "step_streams_past_window", "live streams whose context is "
+            "longer than the window, summed over decode steps")
+
+    def count_prompt(self, prompt: int) -> int:
+        """A prompt's (query, visible key) pairs of one window layer,
+        counted."""
+        full = min(prompt, self.window)
+        pairs = full * (full + 1) // 2 + (prompt - full) * self.window
+        self.prefill_pairs.inc(pairs)
+        return pairs
+
+    def count_step(self, contexts) -> int:
+        """A step's live ring rows of one window layer, counted."""
+        contexts = np.asarray(contexts)
+        live = int(np.minimum(contexts, self.window).sum())
+        self.live.inc(live)
+        self.held.inc(int(contexts.size) * self.window)
+        self.past_window.inc(int(np.sum(contexts > self.window)))
+        return live
+
+    def decodez(self) -> dict:
+        return {"step_ring_rows_live": self.live.value,
+                "step_ring_rows_held": self.held.value}
+
+
 __all__ = ["LMAdapter", "ConfigDict", "MODEL_TYPES", "TOPK_MAX", "mm",
            "rms_norm", "swiglu", "rotary", "sub", "unscanned",
            "EXPERT_LEAVES", "LatentAttention",
            "init_tensor", "sample", "sample_first", "prompt_addresses",
-           "step_addresses", "walked_blocks", "LaunchObserver",
-           "PoolObserver", "RoutedLoadSeries"]
+           "step_addresses", "ring_of_prompt", "ring_step_addresses",
+           "walked_blocks", "LaunchObserver",
+           "PoolObserver", "RoutedLoadSeries", "RingSeries"]

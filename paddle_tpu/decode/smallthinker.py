@@ -72,8 +72,9 @@ import numpy as np
 from jax import lax
 
 from .adapter import (EXPERT_LEAVES, MODEL_TYPES, ConfigDict, LMAdapter,
-                      PoolObserver, RoutedLoadSeries, init_tensor, mm,
-                      prompt_addresses, rms_norm, rotary, sample,
+                      PoolObserver, RingSeries, RoutedLoadSeries, init_tensor,
+                      mm, prompt_addresses, ring_of_prompt,
+                      ring_step_addresses, rms_norm, rotary, sample,
                       sample_first, step_addresses, sub, unscanned,
                       walked_blocks)
 from .cache import HybridStateCache
@@ -179,13 +180,9 @@ def param_shapes(cfg: SmallThinkerConfig) -> Dict[str, tuple]:
 class SmallThinkerObserver(PoolObserver):
     """``decode.<engine>.*`` series of this model: the common ones, the
     pool's and the routed load (``extra[0]``: each layer's ``[assignments,
-    experts touched, largest load]``), and its own of the window.
-
-    ``step_ring_rows_live`` over ``step_ring_rows_held`` is the share of the
-    rings' bytes that the live streams use: a stream holds ``window`` rows a
-    window layer whatever its context, and ``min(context, window)`` of them
-    are live.  Its walks: a full layer's over the pool, a window layer's over
-    a ring up to the window."""
+    experts touched, largest load]``) and the rings'
+    (:class:`~paddle_tpu.decode.adapter.RingSeries`).  Its walks: a full
+    layer's over the pool, a window layer's over a ring up to the window."""
 
     def __init__(self, name: str, cache, config: SmallThinkerConfig,
                  table_shape):
@@ -194,29 +191,14 @@ class SmallThinkerObserver(PoolObserver):
         self.routed = RoutedLoadSeries(
             sc, buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024, 2048,
                          4096, 8192, 16384))
-        self.prefill_pairs = sc.counter(
-            "prefill_window_pairs", "(query, visible key) pairs of one "
-            "window layer, summed over prefills")
-        self.ring_live = sc.counter(
-            "step_ring_rows_live", "ring rows a decode step's streams read "
-            "(context cut at the window), summed over steps (one window "
-            "layer)")
-        self.ring_held = sc.counter(
-            "step_ring_rows_held", "ring rows the live streams hold (the "
-            "window a stream), summed over steps (one window layer)")
-        self.past_window = sc.counter(
-            "step_streams_past_window", "live streams whose context is "
-            "longer than the window, summed over decode steps")
+        self.ring = RingSeries(sc, config.sliding_window_size)
         sc.gauge("window_state_bytes").set(cache.window_state_bytes)
 
     def prefill(self, extra, prompt: int, bucket: int) -> None:
         with _trace.span("decode::prefill.observe") as sp:
             assignments = self.routed.count_prefill(np.asarray(extra[0]))
-            W = self.config.sliding_window_size
-            full = min(prompt, W)
-            pairs = full * (full + 1) // 2 + (prompt - full) * W
+            pairs = self.ring.count_prompt(prompt)
             self.count_prompt(prompt, bucket)
-            self.prefill_pairs.inc(pairs)
             sp.annotate(prefill_routed_assignments=assignments,
                         prefill_real_tokens=prompt,
                         prefill_window_pairs=pairs,
@@ -229,11 +211,7 @@ class SmallThinkerObserver(PoolObserver):
             assignments, touched = self.routed.count_step(
                 np.asarray(extra[0]))
             context, streams = self.count_streams(contexts)
-            live = int(np.minimum(contexts, W).sum())
-            past = int(np.sum(np.asarray(contexts) > W))
-            self.ring_live.inc(live)
-            self.ring_held.inc(streams * W)
-            self.past_window.inc(past)
+            live = self.ring.count_step(contexts)
             sp.annotate(step_routed_assignments=assignments,
                         step_experts_touched=touched,
                         step_context_tokens=context,
@@ -247,9 +225,7 @@ class SmallThinkerObserver(PoolObserver):
 
     def decodez(self) -> dict:
         """… and the rings' live share."""
-        return dict(super().decodez(),
-                    step_ring_rows_live=self.ring_live.value,
-                    step_ring_rows_held=self.ring_held.value)
+        return dict(super().decodez(), **self.ring.decodez())
 
 
 class SmallThinkerLM(LMAdapter):
@@ -451,15 +427,9 @@ class SmallThinkerLM(LMAdapter):
         kv, rings = state
         Tb = tokens.shape[1]
         bs, W, rb = kv.shape[2], cfg.sliding_window_size, rings.shape[2]
-        nrb = W // rb
         pos, _, blocks, last = prompt_addresses(length, Tb, block_table, bs)
         zero = jnp.zeros((), slot.dtype)
-        # a prompt bucket inside the window lies in the ring as it is;
-        # otherwise ring index r gets the last real position that is r mod W
-        direct = Tb <= W and Tb % rb == 0
-        if not direct:
-            r = jnp.arange(W, dtype=jnp.int32)
-            src = jnp.clip(r + W * ((length - 1 - r) // W), 0, Tb - 1)
+        fill = ring_of_prompt(length, Tb, W, rb)
 
         def rows_out(kind, at, rows, carry):
             kv_, rings_ = carry
@@ -468,10 +438,9 @@ class SmallThinkerLM(LMAdapter):
                     kv_ = kv_.at[at, blocks, pos % bs].set(rows)
             else:
                 with jax.named_scope("ring_cache_write"):
-                    ring = rows if direct else rows[src]
-                    ring = ring.reshape(1, -1, rb, ring.shape[-1])
                     rings_ = lax.dynamic_update_slice(
-                        rings_, ring, (at, slot * nrb, zero, zero))
+                        rings_, fill(rows),
+                        (at, slot * (W // rb), zero, zero))
             return (kv_, rings_)
 
         x, (kv, rings), (load, ids, u, rl) = self._prompt_layers(
@@ -495,13 +464,11 @@ class SmallThinkerLM(LMAdapter):
         kv, rings = state
         S = tokens.shape[0]
         bs, W, rb = kv.shape[2], cfg.sliding_window_size, rings.shape[2]
-        nrb = W // rb
         n_kv = cfg.num_key_value_heads
         cl, live, slots, blocks = step_addresses(positions, block_tables, bs)
         wl = jnp.minimum(cl, W)
-        at_ring = positions % W
-        ring_tables = slots[:, None] * nrb + jnp.arange(nrb, dtype=jnp.int32)
-        ring_blocks = slots * nrb + at_ring // rb
+        ring_tables, ring_blocks, ring_at = ring_step_addresses(
+            positions, slots, W, rb)
         tile = _moe.row_tile(S, jnp.dtype(cfg.dtype))
 
         def layer(w, stacks, at, x, carry, kind):
@@ -512,7 +479,7 @@ class SmallThinkerLM(LMAdapter):
             q, rows = self._qkv(w, u, positions, rope, kv.dtype)
             if rope:
                 with jax.named_scope("ring_cache_write"):
-                    rings = rings.at[at, ring_blocks, at_ring % rb].set(rows)
+                    rings = rings.at[at, ring_blocks, ring_at].set(rows)
                 with jax.named_scope("attn_window"):
                     o = _gqa.ring_decode_attention(q, rings, ring_tables, wl,
                                                    at, n_kv)

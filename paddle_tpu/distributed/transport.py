@@ -554,10 +554,13 @@ def _serve_io(io, service) -> None:
                     bufs = _pack_body_vec(
                         OK, tid, name,
                         chunk if isinstance(chunk, list) else [chunk])
-                    _send_frame_any(io, bufs)
+                    # counted BEFORE it is sent: a reader that has the
+                    # stream's last frame finds every frame counted (a send
+                    # that fails ends the stream, one frame over)
                     if tel:
                         _obs_stats.scope("rpc.server").counter(
                             "stream_frames").inc()
+                    _send_frame_any(io, bufs)
             except ConnectionError:
                 # peer vanished mid-stream: close the generator NOW so
                 # its finally-cleanup (the decode plane cancels the

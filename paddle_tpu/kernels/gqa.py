@@ -69,6 +69,25 @@ pairs)::
      2048       -     472      348/297      265/235      200/203      199/194   27/20
      3072       -     952      690/603      501/444      341/310      325/327   36/30
 
+A group of 16 (128 query heads over 8 K/V heads of 128; PR 59, the kernel
+alone on one v5e, 10 calls back to back, best of three; without a ``length``
+/ with one of 0.87 ``T``; ``% peak`` the 256 x 1,024 plan's) keeps the plan:
+4,096 stacked query rows a product compile under the 64 MB of VMEM and are
+the fastest of the five read — fewer query rows (128, 64) lose 5–7%, a
+narrower key tile 20–50%; every row within 1.2e-2 of its own scale::
+
+        T  window      64x1024      128x512     128x1024      256x512     256x1024  % peak
+     4096       -    7433/6465    9995/8369    7318/6387    8444/7151    6958/6095   40/35
+     4096    4096    7447/6478   10021/8396    7329/6394    8472/7155    6969/6103   40/35
+     8192       -  22865/19070  33333/27077  22347/18587  27579/22460  21041/17520   53/48
+     8192    4096  19608/17157  26778/23165  19173/16784  22391/19468  18175/15952   46/43
+
+Its walks (32 slots of 1–8.8 k rows of 4,096 B, sixteen query rows a K/V
+head): the pool's 0.67 GB in 1,019 µs (659 GB/s, 80% of the HBM peak), the
+rings' 0.45 GB in 717 µs (634 GB/s).  A group past eight is NAMED in its
+kernels (``gqa16_window_flash_fwd`` … ``gqa16_paged_decode_attn``:
+:func:`_name`), as the pairs of 64 are.
+
 The exp2 units and the query tile stacked once are the 256x256 column (x1.1);
 the rest is the key tile's width: a grid step's update of the softmax state
 (``acc`` [group·256, 128] read, scaled and written; ``m``, ``l``, ``alpha`` a
@@ -121,6 +140,8 @@ LOG2E = 1.4426950408889634
 # in the module's docstring)
 _Q_TILE = 256
 _K_TILE = 1024
+# the largest group whose kernels bear the plain names (:func:`_name`)
+_NAMED_GROUP = 8
 
 
 def tiled(dh: int, n_kv: int) -> bool:
@@ -129,8 +150,18 @@ def tiled(dh: int, n_kv: int) -> bool:
     return dh == LANE or (dh == HALF and n_kv % 2 == 0)
 
 
-def _name(name: str, dh: int) -> str:
-    return name if dh == LANE else name.replace("gqa_", "gqa64_", 1)
+def _name(name: str, dh: int, group: int = 1) -> str:
+    """A kernel's name by its head layout: ``gqa64_`` a pair of 64-wide K/V
+    heads a lane tile; ``gqa<group>_`` a group past eight (16: ``gqa16_`` —
+    4,096 stacked query rows a product of the flash forward, two sublane
+    tiles of query rows a K/V head in the walks), so that a trace, and a
+    roofline that counts by the layout, tells it from a group of five or
+    seven."""
+    if dh != LANE:
+        return name.replace("gqa_", "gqa64_", 1)
+    if group > _NAMED_GROUP:
+        return name.replace("gqa_", f"gqa{group}_", 1)
+    return name
 
 
 def _pair_rows(q, n_kv: int):
@@ -197,7 +228,7 @@ def _decode_pallas(q, pool, block_tables, context_lens, layer, n_kv,
     rows = jnp.pad(rows, ((0, 0), (0, 0), (0, -shared % 8), (0, 0))
                    ).astype(pool.dtype)
     out = paged_walk(rows, pool, block_tables, context_lens, layer, tiles,
-                     _name(name, dh))
+                     _name(name, dh, nh // n_kv))
     if dh != LANE:
         return _unpair(out, nh)
     return out[:, :, :shared].reshape(S, nh, dh)
@@ -399,13 +430,13 @@ def _group_flash_pallas(q, rows, n_kv, window, length, name):
 
 
 def _flash(q, rows, n_kv, window, length, name, fallbacks):
-    T = q.shape[0]
+    T, nh, dh = q.shape
     bq, bk, _ = flash_plan(T, window)
-    if not tiled(q.shape[-1], n_kv) or T % bk or bq % 8:
+    if not tiled(dh, n_kv) or T % bk or bq % 8:
         _obs_stats.scope("attn").counter(fallbacks).inc()
         return prefill_attention_xla(q, rows, n_kv, window)
     return _group_flash_pallas(q, rows, n_kv, window, length,
-                               _name(name, q.shape[-1]))
+                               _name(name, dh, nh // n_kv))
 
 
 def prefill_attention(q, rows, n_kv: int, length=None):

@@ -115,6 +115,16 @@ mixture, where every assignment is computed (no capacity, no token dropped).
   the experts' input (the router reads an earlier activation) calls
   :func:`plan_groups` there and :func:`planned_experts` here.
 
+**An expert too wide for VMEM** (:func:`f_block`; PR 59: Command A+'s 4,096
+x 4,096 experts are 96 MB of bf16 each, and a v5e's VMEM holds neither two of
+them nor one twice) is walked a block of its intermediate axis at a time —
+``act(x Wg[:, f]) * (x Wu[:, f])`` against ``Wd[f, :]``, summed over the
+blocks ``f``: the tile walk takes the blocks as a second grid axis and sums
+them in a float32 scratch (:func:`_grouped_glu_fblock_kernel`), the expert
+walk is called a block at a time (the block a prefetched scalar of its index
+maps, one kernel for all of them) and the calls' float32 rows are summed in
+XLA.  An expert that fits is walked whole, as before.
+
 **A share of a layer.**  :func:`plan_groups` (and :func:`routed_experts`)
 take ``first``: the stacks then hold experts ``first … first + held − 1`` of a
 layer whose router is wider — what one chip of an expert-parallel deployment
@@ -122,6 +132,12 @@ holds.  The router's scores, its choice and its renormalisation stay over all
 the experts; only assignments to held experts get a row, the walks' maps stay
 the plan's (numbered from 0, as the stacks are), and what the experts held
 elsewhere would add is left out.  Nothing stands in for the other chips.
+:func:`plan_rows` bounds a share's plan by EVERY choice of the wider router
+(a token may choose nothing but held experts), eight times what an eighth
+share's rows are expected to be; a prefill that hands :func:`planned_experts`
+a ``row_block`` (:func:`share_block_rows`) has the plan's rows gathered,
+walked and weighed a block at a time, as many blocks as hold a real row — an
+even router's plan is one block, and no assignment is capped or dropped.
 """
 from __future__ import annotations
 
@@ -139,11 +155,26 @@ from ..platform import pallas_interpret
 
 # one expert's three matrices (5.8 MB each at 2048 x 1408 bf16),
 # double-buffered, pass the 16 MB default scoped-VMEM limit; a v5e has 128 MB
-_VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=100 * 1024 * 1024,
+_VMEM_LIMIT = 100 * 1024 * 1024
+_VMEM_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=_VMEM_LIMIT,
                                     dimension_semantics=("arbitrary",))
 # rows a tile: a decode step's few rows an expert take the smallest tile the
 # dtype packs; a prefill's hundreds fill 128-row tiles
 _PREFILL_TILE = 128
+# the most VMEM one grid step's three weight blocks may take, double-buffered:
+# an expert wider than that (4096 x 4096: 192 MB) is walked a block of its
+# intermediate axis at a time
+_WEIGHT_BLOCKS_BYTES = 64 * 1024 * 1024
+
+
+def f_block(D: int, F: int, itemsize: int) -> int:
+    """Columns of an expert's intermediate axis a grid step holds: all ``F``
+    where its three matrices fit VMEM twice over, else ``F`` halved until
+    they do."""
+    fb = F
+    while 6 * D * fb * itemsize > _WEIGHT_BLOCKS_BYTES and fb % 256 == 0:
+        fb //= 2
+    return fb
 
 
 SCORES = {"softmax": functools.partial(jax.nn.softmax, axis=-1),
@@ -316,19 +347,45 @@ def _grouped_glu_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
                              o_ref.dtype)
 
 
+def _grouped_glu_fblock_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref,
+                               o_ref, acc, *, gate):
+    """The tile walk of experts too wide for VMEM: grid (tiles, blocks of the
+    intermediate axis), a tile's products summed over the blocks in ``acc``
+    (float32) and written with the last."""
+    f = pl.program_id(1)
+
+    @pl.when(pl.program_id(0) < na_ref[0])
+    def _():
+        y = _glu_tile(x_ref[:], wg_ref, wu_ref, wd_ref, gate, jnp.float32)
+
+        @pl.when(f == 0)
+        def _first():
+            acc[:] = y
+
+        @pl.when(f > 0)
+        def _more():
+            acc[:] = acc[:] + y
+
+        @pl.when(f == pl.num_programs(1) - 1)
+        def _last():
+            o_ref[:] = acc[:].astype(o_ref.dtype)
+
+
 # tiles of one piece of the expert walk: how many of an expert's rows are
 # fetched at once (4 MB in and 4 MB of float32 out, twice, beside two
 # experts' 35 MB of matrices); the table in the module's docstring
 _PIECE_TILES = 4
 
 
-def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, x_hbm, wg_ref, wu_ref,
-                        wd_ref, o_hbm, xbuf, obuf, sem, state, *, gate, tile,
-                        piece_tiles):
+def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, f_ref, x_hbm, wg_ref,
+                        wu_ref, wd_ref, o_hbm, xbuf, obuf, sem, state, *,
+                        gate, tile, piece_tiles):
     """Grid (E,): one grid step an expert that has rows, in the order of the
     plan (``ex_ref`` the expert of a step, ``st_ref`` its first row,
-    ``nt_ref`` its tiles, ``n_ref`` how many steps have an expert); the
-    steps after the last repeat its indices and do nothing.  The expert's
+    ``nt_ref`` its tiles, ``n_ref`` how many steps have an expert, ``f_ref``
+    the block of the intermediate axis this call computes: the index maps'
+    alone); the steps after the last repeat its indices and do nothing.  The
+    expert's
     three matrices are the step's blocks, so the NEXT expert's are fetched
     for the whole of this one's rows.  The rows stay in HBM: the kernel
     copies them in, a piece of up to ``piece_tiles`` tiles at a time, into
@@ -445,6 +502,7 @@ def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
     gate, glu = _gate(act)
     R, D = x_rows.shape
     E, F = sizes.shape[0], wg.shape[-1]
+    fb = f_block(D, F, wg.dtype.itemsize)
     has = sizes > 0
     n = jnp.sum(has, dtype=jnp.int32)
     # the experts with rows first, in the plan's order; the steps after the
@@ -454,31 +512,45 @@ def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
                              jnp.maximum(n - 1, 0))]
     first_row = (jnp.cumsum(sizes) - sizes).astype(jnp.int32)[step]
     rows_max = _PIECE_TILES * tile
+    # an expert too wide for VMEM: a call a block of its intermediate axis,
+    # each over all the rows, the calls' float32 rows summed
+    part_dtype = out_dtype if fb == F else jnp.dtype(jnp.float32)
 
-    def expert(j, ex, st, nt, n):
-        return (ex[j], 0, 0)
+    def up(j, ex, st, nt, n, f):
+        return (ex[j], 0, f[0])
 
-    return pl.pallas_call(
-        functools.partial(_expert_walk_kernel, gate=gate, tile=tile,
-                          piece_tiles=_PIECE_TILES),
-        name=f"moe_grouped_{glu}",
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(E,),
-            in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec((1, D, F), expert),
-                      pl.BlockSpec((1, D, F), expert),
-                      pl.BlockSpec((1, F, D), expert)],
-            out_specs=pl.BlockSpec(memory_space=pl.ANY),
-            scratch_shapes=[pltpu.VMEM((2, rows_max, D), x_rows.dtype),
-                            pltpu.VMEM((2, rows_max, D), out_dtype),
-                            pltpu.SemaphoreType.DMA((2, 2)),
-                            pltpu.SMEM((3,), jnp.int32)]),
-        out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
-        compiler_params=_VMEM_PARAMS,
-        interpret=interpret,
-    )(step + layer_base, first_row, sizes[step] // tile, n.reshape(1),
-      x_rows, wg, wu, wd)
+    def down(j, ex, st, nt, n, f):
+        return (ex[j], f[0], 0)
+
+    def walk(f):
+        return pl.pallas_call(
+            functools.partial(_expert_walk_kernel, gate=gate, tile=tile,
+                              piece_tiles=_PIECE_TILES),
+            name=f"moe_grouped_{glu}",
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=5,
+                grid=(E,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                          pl.BlockSpec((1, D, fb), up),
+                          pl.BlockSpec((1, D, fb), up),
+                          pl.BlockSpec((1, fb, D), down)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.VMEM((2, rows_max, D), x_rows.dtype),
+                                pltpu.VMEM((2, rows_max, D), part_dtype),
+                                pltpu.SemaphoreType.DMA((2, 2)),
+                                pltpu.SMEM((3,), jnp.int32)]),
+            out_shape=jax.ShapeDtypeStruct((R, D), part_dtype),
+            compiler_params=_VMEM_PARAMS,
+            interpret=interpret,
+        )(step + layer_base, first_row, sizes[step] // tile, n.reshape(1),
+          jnp.asarray(f, jnp.int32).reshape(1), x_rows, wg, wu, wd)
+
+    if fb == F:
+        return walk(0)
+    # (rows no expert owns are never written, in any call: ``combine`` reads
+    # none of them)
+    return lax.fori_loop(0, F // fb, lambda f, y: y + walk(f),
+                         jnp.zeros((R, D), part_dtype)).astype(out_dtype)
 
 
 def _one_layer(w, layer):
@@ -538,6 +610,11 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
     tile_expert = plan.tile_expert
     if layer is not None:
         tile_expert = tile_expert + layer_base
+    fb = f_block(D, F, wg.dtype.itemsize)
+    if fb != F:
+        return _tile_walk_by_block(x_rows, wg, wu, wd, tile_expert,
+                                   plan.active_tiles, tile, fb, gate, glu,
+                                   out_dtype, interpret)
 
     def rows(i, te, na):
         return (jnp.minimum(i, na[0] - 1), 0)
@@ -560,6 +637,49 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
     )(tile_expert, plan.active_tiles, x_rows, wg, wu, wd)
+
+
+def _tile_walk_by_block(x_rows, wg, wu, wd, tile_expert, active_tiles,
+                        tile: int, fb: int, gate, glu: str, out_dtype,
+                        interpret):
+    """The tile walk of experts too wide for VMEM
+    (:func:`_grouped_glu_fblock_kernel`): every tile against its expert's
+    matrices a block of ``fb`` columns of the intermediate axis at a time.
+    The tiles past the last real one stay on its last block: nothing is
+    fetched for them."""
+    R, D = x_rows.shape
+    nf = wg.shape[-1] // fb
+
+    def rows(i, f, te, na):
+        return (jnp.minimum(i, na[0] - 1), 0)
+
+    def block(i, f, na):
+        return jnp.where(i < na[0], f, nf - 1)
+
+    def up(i, f, te, na):
+        return (te[i], 0, block(i, f, na))
+
+    def down(i, f, te, na):
+        return (te[i], block(i, f, na), 0)
+
+    return pl.pallas_call(
+        functools.partial(_grouped_glu_fblock_kernel, gate=gate),
+        name=f"moe_grouped_{glu}",
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(R // tile, nf),
+            in_specs=[pl.BlockSpec((tile, D), rows),
+                      pl.BlockSpec((1, D, fb), up),
+                      pl.BlockSpec((1, D, fb), up),
+                      pl.BlockSpec((1, fb, D), down)],
+            out_specs=pl.BlockSpec((tile, D), rows),
+            scratch_shapes=[pltpu.VMEM((tile, D), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT,
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(tile_expert, active_tiles, x_rows, wg, wu, wd)
 
 
 def row_tile(tokens: int, dtype) -> int:
@@ -599,13 +719,63 @@ def combine(y, weights, plan: GroupPlan, by_choice: bool = False):
     return jnp.sum(weights[..., None] * rows, axis=1)
 
 
+def share_block_rows(tokens: int, k: int, held: int, router: int,
+                     tile: int) -> int:
+    """Rows a block of a SHARE's plan (``planned_experts(row_block=)``): what
+    ``tokens`` tokens' ``k`` choices of ``router`` experts are expected to
+    leave on the ``held`` of them, an eighth more, and every held expert's
+    padding — so that an even router's plan is one block and a lopsided one
+    is several, each as large.  A bound on memory, never on the rows
+    computed."""
+    most = -(-tokens * k * held // router)
+    return plan_rows(-(-most * 9 // (8 * k)), k, held, tile)
+
+
+def _blocked_experts(x_pad, weights, plan: GroupPlan, wg, wu, wd, tile: int,
+                     act: str, layer, out_dtype, row_block: int):
+    """:func:`planned_experts` a block of ``row_block`` of the plan's rows at
+    a time, as many blocks as hold a real row (a loop whose length the plan
+    decides on the device): a block's rows are gathered, walked an expert a
+    grid step — an expert whose run straddles a block's edge is fetched in
+    both — and their weighted sum added to the tokens'.  Every row of the
+    plan is computed; what is bounded is the memory: ``row_block`` rows in
+    and out, whatever the static bound :func:`plan_rows` has to allow."""
+    T, K = weights.shape
+    R, B = plan.row_token.shape[0], row_block
+    row_token = jnp.pad(plan.row_token, (0, -R % B), constant_values=T)
+    pend = jnp.cumsum(plan.padded_sizes, dtype=jnp.int32)
+    pstart = pend - plan.padded_sizes
+
+    def block(b, out):
+        b0 = b * B
+        rows = x_pad[lax.dynamic_slice(row_token, (b0,), (B,))]
+        # the experts' runs cut at the block's edges: still in expert order
+        sizes = jnp.clip(pend, b0, b0 + B) - jnp.clip(pstart, b0, b0 + B)
+        y = grouped_glu(rows, wg, wu, wd, plan._replace(padded_sizes=sizes),
+                        tile, act=act, layer=layer, out_dtype=out_dtype)
+        # a row of another block (and ``R``: no row) is no row of this one
+        mine = (plan.row_of >= b0) & (plan.row_of < jnp.minimum(b0 + B, R))
+        return out + _combine_by_choice(
+            y, weights, jnp.where(mine, plan.row_of - b0, B))
+
+    return lax.fori_loop(0, -(-pend[-1] // B), block,
+                         jnp.zeros((T, x_pad.shape[1]), jnp.float32))
+
+
 def planned_experts(x, weights, plan: GroupPlan, wg, wu, wd, tile: int,
                     impl=None, act: str = "silu", layer=None,
-                    out_dtype=jnp.float32):
+                    out_dtype=jnp.float32, row_block=None):
     """The experts of a dispatch whose :func:`plan_groups` is already made
     (with the same ``tile``): x [T, D], weights [T, K] → [T, D] float32, the
-    experts' rows kept in ``out_dtype`` until they are weighed and summed."""
+    experts' rows kept in ``out_dtype`` until they are weighed and summed.
+    ``row_block`` (a multiple of ``tile``; :func:`share_block_rows`) bounds
+    the rows of a prefill's plan that lie gathered at once: a plan with more
+    is walked a block at a time (:func:`_blocked_experts`), to the same sum."""
     x_pad = jnp.concatenate([x, jnp.zeros((1, x.shape[1]), x.dtype)], axis=0)
+    if row_block is not None and impl is None and tile == _PREFILL_TILE \
+            and plan.row_token.shape[0] > row_block:
+        return _blocked_experts(x_pad, weights, plan, wg, wu, wd, tile, act,
+                                layer, out_dtype, row_block)
     y = grouped_glu(x_pad[plan.row_token], wg, wu, wd, plan, tile, impl=impl,
                     act=act, layer=layer, out_dtype=out_dtype)
     return combine(y, weights, plan, by_choice=tile == _PREFILL_TILE)
@@ -625,6 +795,7 @@ def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
                            act=act), plan.load
 
 
-__all__ = ["route_topk", "plan_groups", "plan_rows", "grouped_glu",
-           "grouped_glu_xla", "combine", "planned_experts", "routed_experts",
-           "GroupPlan", "row_tile", "ACTS", "SCORES"]
+__all__ = ["route_topk", "plan_groups", "plan_rows", "share_block_rows",
+           "f_block", "grouped_glu", "grouped_glu_xla", "combine",
+           "planned_experts", "routed_experts", "GroupPlan", "row_tile",
+           "ACTS", "SCORES"]

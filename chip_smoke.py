@@ -28,6 +28,11 @@ checks what comes out by the repo's own means:
 - group_flash:   the serve cells' prefill attention (a K/V head's group a
                  grid step, pad tiles skipped) against dense attention at
                  SmallThinker's and LFM2's head layouts, 12,288 positions.
+- proj_xent:     the trainer's output projection that keeps the loss's
+                 log-sum-exp (``kernels/xent.py proj_xent_fwd``) against
+                 ``jnp.matmul`` + ``logsumexp`` at the train cells' head,
+                 24,576 x 512 x 37,000, and the kernel-alone table: ms a
+                 call by tile shape beside XLA's two operations.
 - four_chip:     the trainer program through ``ParallelExecutor`` on a
                  dp=2 x mp=2 mesh, then one ZeRO step on dp=4 — only
                  where JAX sees >= 4 devices.
@@ -261,9 +266,16 @@ def phase_trainer(place, batch=32, max_len=256, vocab=32000, d_model=512,
     check(counter_delta(c0, "executor.cache_hits") == steps,
           "executor cache hits do not match the steps taken")
     exe.close()
+    # the output projection and its loss (fc_softmax_with_cross_entropy): on
+    # the chip both executables took the kernel that keeps the log-sum-exp
+    chose = {k: counter_delta(c0, f"loss.proj_xent_{k}")
+             for k in ("kernel", "fallbacks")}
+    if place is not None:
+        check(chose["kernel"] >= 2 and not chose["fallbacks"],
+              f"the output projection did not take proj_xent_fwd: {chose}")
     return {"first_loss": losses[0], "last_loss": losses[-1],
             "steps": len(losses), "executor_compiles": misses,
-            "tokens_per_step": batch * max_len}
+            "tokens_per_step": batch * max_len, "proj_xent": chose}
 
 
 # ---------------------------------------------------------------------------
@@ -1259,6 +1271,107 @@ def phase_group_flash(on_chip=True, tokens=12288, window=4096, block=1024,
 
 
 # ---------------------------------------------------------------------------
+# phase 3i: the output projection that keeps the loss's log-sum-exp
+# ---------------------------------------------------------------------------
+
+def phase_proj_xent(on_chip=True, batch=96, seq=256, d=512, vocab=37000,
+                    tiles=((2048, 512), (1024, 1024), (1024, 512),
+                           (2048, 1024), (2048, 2048), (512, 1024),
+                           (4096, 512), (1024, 2048)),
+                    calls=(4, 24), lse_rtol=1e-6):
+    """``kernels/xent.py proj_xent_fwd`` against ``proj_xent_xla``
+    (``jnp.matmul`` + ``logsumexp``, called directly, so no fallback is
+    counted) at the train cells' head — 96 sequences of 256 float32 rows, a
+    bf16 weight — compared ON the device: the logits to the product's last
+    bit (the two round the rows to bf16 alike and sum 512 products in their
+    own orders: at most the accumulation's few ulps of the LARGEST logit),
+    each row's log-sum-exp within ``lse_rtol``.  Interpret mode cannot see
+    what the TPU's compiler does round a kernel (PERF.md §6, PR 44).  Then
+    the kernel alone, ms a call for each of ``tiles`` (row block, vocabulary
+    tile; the first, the module's own, is compared) beside XLA's two
+    operations: N calls inside
+    ONE program (a loop whose step perturbs the rows, so nothing is hoisted,
+    and keeps both outputs alive), the difference of two lengths, because a
+    dispatch costs 0.6 ms (PR 52).  The rows reach the kernel as the op
+    hands them over, rounded to bf16 by XLA."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from paddle_tpu.kernels import xent
+
+    rows = batch * seq
+    kx, kw = jax.random.split(jax.random.PRNGKey(60))
+    x = jax.random.normal(kx, (rows, d), jnp.float32)
+    w = (jax.random.normal(kw, (d, vocab), jnp.float32)
+         * d ** -0.5).astype(jnp.bfloat16)
+
+    def kernel_of(tm, tn):
+        return lambda x, w: xent.proj_xent_fwd(
+            x.astype(jnp.bfloat16), w, seq=seq, tm=tm, tn=tn,
+            logits_dtype=jnp.float32)
+
+    # compared as [rows, vocab]; TIMED as the views the kernel hands on (a
+    # sequence's positions along the lanes: row-major they cost a transpose
+    # of the 3.64 GB, 11 ms, which no program that reads them pays)
+    kernel = jax.jit(lambda x, w: tuple(
+        o.reshape(rows, -1) for o in kernel_of(*tiles[0])(x, w)))
+    if on_chip:
+        text = kernel.lower(x, w).compile().as_text()
+        check(MOSAIC_CALL in text and "proj_xent_fwd" in text,
+              "no Mosaic call named proj_xent_fwd in the program")
+
+    @jax.jit
+    def compare(got, want):
+        (a, la), (b, lb) = got, want
+        diff = jnp.abs(a - b)
+        return {"logits_max_diff": diff.max(), "scale": jnp.abs(b).max(),
+                "logits_differing": (diff > 0).sum(),
+                "lse_max_rel": (jnp.abs(la - lb) / jnp.abs(lb)).max(),
+                "lse_mean": lb.mean()}
+
+    out = {k: float(v) for k, v in compare(
+        kernel(x, w), jax.jit(xent.proj_xent_xla)(x, w)).items()}
+    ulp = out["scale"] * 2.0 ** -23
+    out["logits_max_diff_ulps_of_scale"] = out["logits_max_diff"] / ulp
+    check(np.isfinite(out["logits_max_diff"]) and out["scale"] > 0
+          and out["logits_max_diff"] <= (4 * ulp if on_chip
+                                         else 2e-2 * out["scale"]),
+          f"the kernel's logits differ from the product by "
+          f"{out['logits_max_diff']:.4g} at a scale of {out['scale']:.4g}")
+    check(out["lse_max_rel"] <= (lse_rtol if on_chip else 1e-2),
+          f"a row's log-sum-exp differs by {out['lse_max_rel']:.3g} of "
+          f"itself")
+
+    def ms_a_call(fn):
+        @jax.jit
+        def loop(x, w, n):
+            def step(_, acc):
+                logits, lse = lax.optimization_barrier(fn(x + acc * 1e-30, w))
+                return acc + lse.reshape(-1)[0] + logits.reshape(-1)[0]
+            return lax.fori_loop(0, n, step, jnp.float32(0))
+
+        loop(x, w, 1).block_until_ready()
+        best = {}
+        for n in calls * 2:
+            t0 = time.perf_counter()
+            loop(x, w, n).block_until_ready()
+            best[n] = min(best.get(n, np.inf), time.perf_counter() - t0)
+        lo, hi = calls
+        return 1e3 * (best[hi] - best[lo]) / (hi - lo)
+
+    table = {"xla: matmul + logsumexp": ms_a_call(xent.proj_xent_xla),
+             "xla: matmul alone": ms_a_call(lambda x, w: (
+                 jnp.matmul(x, w), jnp.zeros((rows, 1), jnp.float32)))}
+    for tm, tn in tiles:
+        table[f"kernel {tm} x {tn}"] = ms_a_call(kernel_of(tm, tn))
+    out["ms_a_call"] = {k: round(v, 3) for k, v in table.items()}
+    # what the device was handed for each call, by the shapes
+    out["gflop_a_call"] = 2e-9 * rows * d * vocab
+    out["logits_gb"] = 4e-9 * rows * vocab
+    return out
+
+
+# ---------------------------------------------------------------------------
 # phase 4: four chips
 # ---------------------------------------------------------------------------
 
@@ -1422,6 +1535,7 @@ def main() -> int:
     run_phase(report, "expert_walk", phase_expert_walk)
     run_phase(report, "expert_plan", phase_expert_plan)
     run_phase(report, "group_flash", phase_group_flash)
+    run_phase(report, "proj_xent", phase_proj_xent)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])

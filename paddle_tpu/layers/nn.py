@@ -307,6 +307,35 @@ def softmax_with_cross_entropy(logits, label, soft_label=False,
     return loss
 
 
+def fc_softmax_with_cross_entropy(input, label, size, num_flatten_dims=1,
+                                  param_attr=None, ignore_index=-100,
+                                  name=None):
+    """``softmax_with_cross_entropy(fc(input, size, bias_attr=False), label)``
+    over hard labels as ONE op, so that the projection can hand the loss each
+    row's log-sum-exp while it writes the logits (``kernels/xent.py``: no
+    pass over the logits follows it).  Returns the loss ``[..., 1]``; the
+    logits and the log-sum-exp are the op's intermediates, which its grad op
+    reads."""
+    helper = LayerHelper("fc_softmax_with_cross_entropy", input=input,
+                         param_attr=param_attr, name=name)
+    dtype = helper.input_dtype()
+    in_features = int(np.prod(input.shape[num_flatten_dims:]))
+    w = helper.create_parameter(param_attr, [in_features, size], dtype)
+    lead = tuple(input.shape[:num_flatten_dims])
+    logits = helper.create_variable_for_type_inference(
+        dtype, shape=lead + (size,))
+    lse = helper.create_variable_for_type_inference(
+        "float32", shape=lead + (1,))
+    loss = helper.create_variable_for_type_inference(dtype, shape=lead + (1,))
+    helper.append_op(
+        "fc_softmax_with_cross_entropy",
+        {"X": [input], "W": [w], "Label": [label]},
+        {"Loss": [loss], "LSE": [lse], "Logits": [logits]},
+        {"x_num_col_dims": num_flatten_dims, "ignore_index": ignore_index},
+    )
+    return loss
+
+
 def square_error_cost(input, label):
     helper = LayerHelper("square_error_cost")
     out = helper.create_variable_for_type_inference(input.dtype, shape=input.shape)

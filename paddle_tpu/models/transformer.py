@@ -181,11 +181,12 @@ def _embed(ids, mask, vocab, d_model, max_len, prefix, dtype):
     return fluid.layers.elementwise_mul(emb, mask, axis=0)
 
 
-def transformer(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab, tgt_vocab,
-                max_len=256, d_model=512, n_head=8, d_ffn=2048,
-                n_layer=6, dropout=0.1, dtype="float32",
-                attention_impl="base"):
-    """Returns logits [B, T_tgt, tgt_vocab].
+def decoder_output(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab,
+                   tgt_vocab, max_len=256, d_model=512, n_head=8, d_ffn=2048,
+                   n_layer=6, dropout=0.1, dtype="float32",
+                   attention_impl="base"):
+    """The last decoder layer's output [B, T_tgt, d_model]: everything under
+    the output projection.
 
     masks: [B, T] float 1/0 validity (from @LEN companions or fed directly).
     """
@@ -224,7 +225,18 @@ def transformer(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab, tgt_vocab,
                                 d_model, n_head, d_ffn, dropout, f"dec.{i}",
                                 src_mask=src_mask, tgt_mask=tgt_mask,
                                 impl=attention_impl)
+    return dec
 
+
+def transformer(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab, tgt_vocab,
+                max_len=256, d_model=512, n_head=8, d_ffn=2048,
+                n_layer=6, dropout=0.1, dtype="float32",
+                attention_impl="base"):
+    """Returns logits [B, T_tgt, tgt_vocab]: :func:`decoder_output` under the
+    output projection."""
+    dec = decoder_output(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab,
+                         tgt_vocab, max_len, d_model, n_head, d_ffn, n_layer,
+                         dropout, dtype, attention_impl)
     with fluid.name_scope("out_proj"):
         return fluid.layers.fc(
             dec, tgt_vocab, num_flatten_dims=2, bias_attr=False,
@@ -247,12 +259,17 @@ def build(src_vocab=30000, tgt_vocab=30000, max_len=64, d_model=512,
     src_mask = fluid.layers.data("src_mask", [max_len])
     tgt_mask = fluid.layers.data("tgt_mask", [max_len])
 
-    logits = transformer(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab,
+    dec = decoder_output(src_ids, tgt_ids, src_mask, tgt_mask, src_vocab,
                          tgt_vocab, max_len, d_model, n_head, d_ffn, n_layer,
                          dropout, dtype, attention_impl)
+    # the projection and the loss's softmax are one op: the log-sum-exp is
+    # taken where the logits are written (kernels/xent.py)
+    with fluid.name_scope("out_proj"):
+        loss = fluid.layers.fc_softmax_with_cross_entropy(
+            dec, fluid.layers.unsqueeze(lbl_ids, [2]), tgt_vocab,
+            num_flatten_dims=2,
+            param_attr=fluid.ParamAttr(name="tgt.out_proj"))  # [B,T,1]
     with fluid.name_scope("loss"):
-        lbl = fluid.layers.unsqueeze(lbl_ids, [2])
-        loss = fluid.layers.softmax_with_cross_entropy(logits, lbl)  # [B,T,1]
         loss = fluid.layers.squeeze(loss, [2])
         masked = fluid.layers.elementwise_mul(loss, tgt_mask)
         tok_count = fluid.layers.reduce_sum(tgt_mask)

@@ -16,14 +16,20 @@ equivalent of the reference's recurrent grad machinery).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 from ..core.registry import _scope, get as get_opdef, register, \
     register_grad, vjp_grad
+from ..kernels import xent as _xent_kernel
 from ..observability import stats as _obs_stats
+from .attention_ops import _dp_only
+from .math_ops import flatten_to_2d
 
 
 def _pair(v):
@@ -347,6 +353,105 @@ def _softmax_xent_grad(ctx, ins, attrs):
             if mask is not None:
                 g = g * mask
         return {"Logits@GRAD": [(d * g).astype(logits.dtype)]}
+
+
+def _proj_xent_impl(backend, n_rows, d, seq, x_dtype, w_dtype, mesh=None,
+                    spans_devices=False):
+    """What ``fc_softmax_with_cross_entropy`` lowers to, from what the op can
+    observe: ``kernel`` (``kernels/xent.py proj_xent_fwd``: the projection
+    carries the log-sum-exp, no pass over the logits follows it) on a TPU
+    where every shard's rows fill whole row blocks of whole sequences, the
+    sequences (the last of the leading dimensions, ``seq``) and the
+    contraction whole lane tiles, the weight is bf16 and the mesh is one the
+    call can be wrapped over (``attention_ops._dp_only``'s rule, and for its
+    reason); ``xla`` — ``jnp.matmul`` and ``logsumexp`` — everywhere else.
+    The measurements are in the kernel's module."""
+    if backend != "tpu":
+        return "xla"
+    shards = mesh.shape["dp"] if mesh is not None and "dp" in mesh.shape else 1
+    if (_dp_only(mesh, n_rows, spans_devices)
+            and _xent_kernel.fits(n_rows // shards, d, seq)
+            and jnp.dtype(w_dtype) == jnp.bfloat16
+            and jnp.dtype(x_dtype) in (jnp.bfloat16, jnp.float32)):
+        return "kernel"
+    return "xla"
+
+
+def _proj_xent_kernel(mesh, x2, w, seq):
+    """The kernel, per shard under a mesh: the rows are what ``dp`` shards
+    and ``w`` is whole on every chip (GSPMD cannot partition a Mosaic call,
+    ``attention_ops._short``)."""
+    # the rows rounded to bf16 HERE, where the kernel would first: XLA then
+    # writes a bf16 copy for the call and goes on recomputing the float32
+    # rows inside the weight-gradient product from what it keeps in VMEM; a
+    # float32 operand it had to write to HBM, and read there from that product
+    call = functools.partial(_xent_kernel.proj_xent_fwd, seq=seq,
+                             logits_dtype=jnp.result_type(x2, w))
+    x2 = x2.astype(jnp.bfloat16)
+    if mesh is None:
+        return call(x2, w)
+    by_row = P("dp")
+    return jax.shard_map(call, mesh=mesh, check_vma=False,
+                         in_specs=(by_row, P()),
+                         out_specs=(by_row, by_row))(x2, w)
+
+
+@register("fc_softmax_with_cross_entropy", no_grad_slots=("Label",))
+def _fc_softmax_xent(ctx, ins, attrs):
+    """``softmax_with_cross_entropy(mul(X, W), Label)`` over hard labels as
+    one op, so that the projection can hand the loss its log-sum-exp:
+    ``Loss``, and ``LSE`` and ``Logits`` for the grad rule, which reads both
+    and recomputes neither."""
+    x, w, label = ins["X"][0], ins["W"][0], ins["Label"][0]
+    xnc = attrs.get("x_num_col_dims", 1)
+    x2 = flatten_to_2d(x, xnc)
+    lead, seq = x.shape[:xnc], x.shape[xnc - 1]
+    impl = _proj_xent_impl(jax.default_backend(), x2.shape[0], x2.shape[1],
+                           seq, x.dtype, w.dtype, ctx.mesh, ctx.spans_devices)
+    # which lowering the op chose, counted when it is lowered
+    _obs_stats.scope("loss").counter(
+        "proj_xent_kernel" if impl == "kernel" else "proj_xent_fallbacks").inc()
+    if impl == "kernel":
+        logits, lse = _proj_xent_kernel(ctx.mesh, x2, w, seq)
+        logits, lse = logits.reshape(lead + w.shape[1:]), lse.reshape(
+            lead + (1,))
+    else:
+        # the two ops' own expressions, the statistics in the leading
+        # dimensions' shape as the grad rule's half is: under a log-sum-exp
+        # taken over [N, V] and reshaped, XLA:TPU wrote ``softmax - onehot``
+        # out in bf16, once a gradient product
+        logits = jnp.matmul(x2, w).reshape(lead + w.shape[1:])
+        _, lse = _xent_stats(logits)
+    li, mask = _xent_hard_label(label, attrs, lse.dtype)
+    loss = lse - jnp.take_along_axis(logits, li, axis=-1).astype(lse.dtype)
+    if mask is not None:
+        loss = loss * mask
+    return {"Loss": [loss.astype(logits.dtype)], "LSE": [lse],
+            "Logits": [logits]}
+
+
+@register_grad("fc_softmax_with_cross_entropy")
+def _fc_softmax_xent_grad(ctx, ins, attrs):
+    """``softmax_with_cross_entropy``'s closed form, ``(softmax - onehot) *
+    g``, with the softmax from the forward's ``LSE`` — a log-sum-exp retraced
+    here would have no forward copy to fold into and bring the pass over the
+    logits back — then ``mul``'s two gradient products, in plain
+    ``jax.numpy`` so that XLA keeps ``softmax - onehot`` inside them as
+    their producer."""
+    x, w, label = ins["X"][0], ins["W"][0], ins["Label"][0]
+    logits, lse = ins["Logits"][0], ins["LSE"][0]
+    xnc = attrs.get("x_num_col_dims", 1)
+    g = ins["Loss@GRAD"][0].astype(lse.dtype)
+    li, mask = _xent_hard_label(label, attrs, lse.dtype)
+    if mask is not None:
+        g = g * mask
+    classes = lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    d = jnp.exp(logits.astype(lse.dtype) - lse) - (classes == li).astype(
+        lse.dtype)
+    d = flatten_to_2d((d * g).astype(logits.dtype), xnc)
+    dx = jnp.matmul(d, w.T).astype(x.dtype)
+    dw = jnp.matmul(flatten_to_2d(x, xnc).T, d).astype(w.dtype)
+    return {"X@GRAD": [dx.reshape(x.shape)], "W@GRAD": [dw]}
 
 
 @register("square_error_cost")

@@ -124,6 +124,10 @@ def op_flops_estimate(block, op, batch: int = 8) -> float:
             co, ci, kh, kw = ws
             groups = max(int(op.attr("groups", 1) or 1), 1)
             return 2.0 * out_elems * ci * kh * kw / groups
+    if op.type == "fc_softmax_with_cross_entropy":
+        xs = shape(op.input("X")[0]) if op.input("X") else None
+        if xs:      # the projection's product; the loss is its epilogue
+            return 2.0 * numel(op.output("Logits")[0]) * max(xs[-1], 1)
     if op.type == "fused_attention":
         qs = shape(op.input("Q")[0]) if op.input("Q") else None
         if qs and len(qs) >= 2:

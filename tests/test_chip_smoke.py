@@ -60,6 +60,8 @@ def test_trainer_phase_tiny():
                                    **TINY)
     assert out["last_loss"] < out["first_loss"]
     assert out["executor_compiles"] == 3 and out["steps"] == 3 + 2 * 2
+    # run and run_steps each lowered the head once; off the chip, its fallback
+    assert out["proj_xent"] == {"kernel": 0, "fallbacks": 2}
 
 
 def test_kernels_phase_tiny():
@@ -208,6 +210,20 @@ def test_group_flash_phase_tiny(monkeypatch):
     assert all(out[c]["pad_rows_zero"] and out[c]["max_row_diff"] < 2e-2
                for c in out if c != "fallbacks")
     assert out["fallbacks"] == 0
+
+
+def test_proj_xent_phase_tiny():
+    out = chip_smoke.phase_proj_xent(
+        on_chip=False, batch=2, seq=128, d=128, vocab=300,
+        tiles=((128, 128), (256, 256)), calls=(1, 2))
+    assert sorted(out["ms_a_call"]) == [
+        "kernel 128 x 128", "kernel 256 x 256", "xla: matmul + logsumexp",
+        "xla: matmul alone"]
+    # the CPU's float32 product does not round the rows to bf16: loose here,
+    # the product's last bits on the chip
+    assert out["logits_max_diff"] <= 2e-2 * out["scale"]
+    assert out["lse_max_rel"] < 1e-3 and out["logits_differing"] > 0
+    assert out["gflop_a_call"] == pytest.approx(2e-9 * 256 * 128 * 300)
 
 
 def test_four_chip_phase_tiny():

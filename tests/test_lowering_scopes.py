@@ -133,7 +133,9 @@ def test_a_forward_matmul_is_never_filed_under_bwd_after_cse(lowered):
     impl, prog, text = lowered
     dots = _dots(text)
     ops = collections.Counter(op_scope(op) for op in prog.global_block.ops)
-    kinds = {"mul": 1}
+    # the output projection's product is the fused op's: one dot forward,
+    # the two gradient products under its grad op
+    kinds = {"mul": 1, "fc_softmax_with_cross_entropy": 1}
     # a kernel's body (interpret mode) holds two products a head forward and
     # five backward, and the model has two heads.  Interpreted, a kernel is a
     # while loop, which XLA's CSE does not fold: the grad op's re-traced
@@ -170,19 +172,23 @@ def test_an_adam_update_is_filed_under_its_parameter(lowered):
     assert any("/opt/increment/" in n or "/opt/scale/" in n for n in names)
     scopes = {op_scope(op) for op in prog.global_block.ops}
     assert {"fwd/enc_1/self_attn/layer_norm", "bwd/dec_0/cross_attn/mul_grad",
-            "fwd/loss/softmax_with_cross_entropy", "fwd/src_embed/lookup_table",
-            "bwd/out_proj/mul_grad", "opt/adam/tgt.word_emb"} <= scopes
+            "fwd/out_proj/fc_softmax_with_cross_entropy",
+            "fwd/src_embed/lookup_table", "fwd/loss/reduce_sum",
+            "bwd/out_proj/fc_softmax_with_cross_entropy_grad",
+            "opt/adam/tgt.word_emb"} <= scopes
+    assert not {"fwd/loss/softmax_with_cross_entropy", "fwd/out_proj/mul",
+                "bwd/out_proj/mul_grad"} & scopes
 
 
-LOSS, LOSS_GRAD = ("fwd/loss/softmax_with_cross_entropy",
-                   "bwd/loss/softmax_with_cross_entropy_grad")
+LOSS, LOSS_GRAD = ("fwd/out_proj/fc_softmax_with_cross_entropy",
+                   "bwd/out_proj/fc_softmax_with_cross_entropy_grad")
 
 
-def test_the_loss_grad_names_its_retraced_lse_as_the_forward_op_does():
-    """``softmax_with_cross_entropy_grad`` is a closed form with a rule of its
-    own: BEFORE any optimisation the log-sum-exp it recomputes stands beside
-    the forward's under the forward op's name, the cotangent half under the
-    grad op's, and nothing of the loss under any other."""
+def test_the_loss_grad_reads_the_forwards_lse_and_retraces_none():
+    """``fc_softmax_with_cross_entropy_grad`` is a closed form with a rule of
+    its own that READS the forward's ``LSE``: BEFORE any optimisation the
+    log-sum-exp stands once, under the forward op's name, the cotangent half
+    under the grad op's, and nothing of the loss under any other."""
     prog, startup, (_, loss, _) = _transformer(n_layer=1)
     names = collections.Counter(re.findall(
         r'loc\("jit\(fn_s1\)/([^"]*softmax_with_cross_entropy[^"]*)"',
@@ -190,14 +196,16 @@ def test_the_loss_grad_names_its_retraced_lse_as_the_forward_op_does():
     assert {name.rsplit("/", 1)[0].split("/jit(")[0] for name in names} == \
         {LOSS, LOSS_GRAD}
     for primitive in ("reduce_max", "reduce_sum", "log"):
-        assert names[f"{LOSS}/{primitive}"] == 2, primitive
+        assert names[f"{LOSS}/{primitive}"] == 1, primitive
         assert f"{LOSS_GRAD}/{primitive}" not in names
+    assert (names[f"{LOSS}/dot_general"],
+            names[f"{LOSS_GRAD}/dot_general"]) == (1, 2)
     for primitive in ("iota", "eq", "exp", "mul"):      # softmax - onehot
         assert names[f"{LOSS_GRAD}/{primitive}"] == 1, primitive
     assert f"{LOSS}/iota" not in names
 
 
-def test_the_lse_that_cse_kept_is_the_forward_ops(lowered):
+def test_the_lse_is_the_forward_ops_in_the_compiled_text(lowered):
     _, _, text = lowered
     names = [n for _, n in _instructions(text)
              if "softmax_with_cross_entropy" in n]

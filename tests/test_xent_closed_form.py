@@ -4,6 +4,9 @@ the grad rule is the closed form ``(softmax - onehot) * g`` from ``Logits``,
 ``Label`` and ``Loss@GRAD`` alone.  Both against the expression they replaced
 (kept here, not in the package) and its ``jax.vjp``; a program that
 differentiates through ``Softmax`` still takes the old ``vjp_grad``."""
+import collections
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -12,12 +15,16 @@ import pytest
 import paddle_tpu as fluid
 from op_harness import check_grad
 from paddle_tpu.core import unique_name
-from paddle_tpu.core.executor import Executor, Scope, scope_guard
+from paddle_tpu.core.executor import (Executor, Scope, _as_device_array,
+                                      scope_guard)
+from paddle_tpu.core.lowering import analyze_block, build_block_fn
 from paddle_tpu.core.program import Program, program_guard
 from paddle_tpu.core.registry import LowerContext
+from paddle_tpu.kernels import xent
 from paddle_tpu.models import transformer
 from paddle_tpu.observability import stats
-from paddle_tpu.ops import nn_ops
+from paddle_tpu.ops import math_ops, nn_ops
+from paddle_tpu.parallel import BuildStrategy, ParallelExecutor
 
 L = fluid.layers
 V = 11
@@ -200,7 +207,11 @@ def test_a_gradient_through_softmax_takes_the_old_rule_and_trains(
                                rtol=2e-4, atol=1e-6)
 
 
-def test_the_transformers_loss_takes_the_closed_form():
+def test_the_transformers_loss_takes_the_fused_op_and_its_closed_form():
+    """``transformer.build`` has ONE head: the projection and the loss as
+    ``fc_softmax_with_cross_entropy`` (on the CPU its fallback, counted when
+    the op is lowered), whose grad rule is the closed form; neither of the
+    pair's rules is lowered any more."""
     prog, startup, (names, loss, _) = _build(lambda: transformer.build(
         src_vocab=32, tgt_vocab=32, max_len=8, d_model=16, n_head=2,
         d_ffn=32, n_layer=1, dropout=0.0, warmup_steps=10,
@@ -209,11 +220,336 @@ def test_the_transformers_loss_takes_the_closed_form():
     feed = {n: np.ones((4, 8), "float32") if n.endswith("mask")
             else rng.randint(0, 32, (4, 8)).astype("int64") for n in names}
     closed, fallback = _counters()
-    before = closed.value, fallback.value
+    chose = _xent_choice()
+    before = [c.value for c in (closed, fallback) + chose]
     with scope_guard(Scope()):
         exe = Executor()
         exe.run(startup)
         losses = [float(exe.run(prog, feed=feed, fetch_list=[loss])[0])
                   for _ in range(12)]
     assert np.isfinite(losses).all() and losses[-1] < losses[0]
-    assert (closed.value - before[0], fallback.value - before[1]) == (1, 0)
+    assert [c.value - b for c, b in zip((closed, fallback) + chose, before)] \
+        == [0, 0, 0, 1]
+    types = [op.type for op in prog.global_block.ops]
+    assert types.count("fc_softmax_with_cross_entropy") == 1
+    assert types.count("fc_softmax_with_cross_entropy_grad") == 1
+    assert "softmax_with_cross_entropy" not in types
+    assert prog.global_block.var("tgt.out_proj").shape == (16, 32)
+
+
+# ---------------------------------------------------------------------------
+# fc_softmax_with_cross_entropy: the projection and the loss as one op, held
+# to the ``mul`` + ``softmax_with_cross_entropy`` pair it stands for
+# ---------------------------------------------------------------------------
+
+D_IN = 7
+
+
+def _fused_case(dtype, lead, label_form, ignore):
+    logits, label, attrs, g = _case(dtype, lead, label_form, False, ignore)
+    rng = np.random.RandomState(len(lead))
+    x = jnp.asarray(rng.uniform(-1, 1, lead + (D_IN,)), dtype)
+    w = jnp.asarray(rng.uniform(-1, 1, (D_IN, V)), dtype)
+    return x, w, label, dict(attrs, x_num_col_dims=len(lead)), g
+
+
+def _pair(x, w, label, attrs):
+    """The two ops' lowerings, one after the other."""
+    ctx = LowerContext()
+    logits = math_ops._mul(ctx, {"X": [x], "Y": [w]}, {
+        "x_num_col_dims": attrs["x_num_col_dims"], "y_num_col_dims": 1})
+    return nn_ops._softmax_xent(
+        ctx, {"Logits": logits["Out"], "Label": [label]},
+        {"soft_label": False, "ignore_index": attrs["ignore_index"]})["Loss"][0]
+
+
+FUSED_CASES = [p for p in CASES if not p.values[3]]     # hard labels
+
+
+@pytest.mark.parametrize("dtype, lead, form, soft, ignore", FUSED_CASES)
+def test_the_fused_op_is_the_pair_it_stands_for(dtype, lead, form, soft,
+                                                ignore):
+    x, w, label, attrs, g = _fused_case(dtype, lead, form, ignore)
+    out = nn_ops._fc_softmax_xent(
+        LowerContext(), {"X": [x], "W": [w], "Label": [label]}, attrs)
+    assert sorted(out) == ["LSE", "Logits", "Loss"]
+    (loss,), (lse,), (logits,) = out["Loss"], out["LSE"], out["Logits"]
+    assert loss.dtype == logits.dtype == x.dtype
+    assert lse.dtype == jnp.promote_types(x.dtype, jnp.float32)
+    assert (loss.shape, lse.shape, logits.shape) == \
+        (lead + (1,), lead + (1,), lead + (V,))
+    # the fallback IS the pair's expression: equal to the bit
+    want, pull = jax.vjp(lambda x, w: _pair(x, w, label, attrs), x, w)
+    np.testing.assert_array_equal(np.asarray(loss, np.float64),
+                                  np.asarray(want, np.float64))
+    grads = nn_ops._fc_softmax_xent_grad(LowerContext(), {
+        "X": [x], "W": [w], "Label": [label], "Loss@GRAD": [g],
+        "Logits": [logits], "LSE": [lse],
+        "Loss": [jnp.full_like(loss, jnp.nan)]}, attrs)
+    assert sorted(grads) == ["W@GRAD", "X@GRAD"]
+    tol = GRAD_TOL[np.dtype(dtype).name] * (4 if dtype == "float32" else 1)
+    for got, want, like in zip((grads["X@GRAD"][0], grads["W@GRAD"][0]),
+                               pull(g), (x, w)):
+        assert got.dtype == like.dtype and got.shape == like.shape
+        np.testing.assert_allclose(np.asarray(got, np.float64),
+                                   np.asarray(want, np.float64),
+                                   rtol=8 * tol, atol=8 * tol)
+    if ignore:
+        rows = np.asarray(label).reshape(lead) == IGNORED
+        assert rows.any() and not np.asarray(loss, np.float64)[rows].any()
+
+
+def test_the_fused_grad_rule_reads_the_forwards_lse():
+    """Handed a log-sum-exp ``ln 2`` too large, the rule's softmax halves: it
+    took ``LSE`` as it was handed, and computed none of its own."""
+    x, w, label, attrs, g = _fused_case("float32", (6,), (1, "int32"), False)
+    out = nn_ops._fc_softmax_xent(
+        LowerContext(), {"X": [x], "W": [w], "Label": [label]}, attrs)
+
+    def dx(lse):
+        return nn_ops._fc_softmax_xent_grad(LowerContext(), {
+            "X": [x], "W": [w], "Label": [label], "Loss@GRAD": [g],
+            "Logits": out["Logits"], "LSE": [lse]}, attrs)["X@GRAD"][0]
+
+    classes = jnp.arange(V)[None, :] == label.reshape(-1, 1)
+    softmax = jax.nn.softmax(out["Logits"][0], axis=-1)
+    for shift, scale in ((0.0, 1.0), (np.log(2.0), 0.5)):
+        want = ((softmax * scale - classes) * g) @ w.T
+        np.testing.assert_allclose(np.asarray(dx(out["LSE"][0] + shift)),
+                                   np.asarray(want), rtol=2e-5, atol=2e-6)
+
+
+# one whole row block of the kernel's, in sequences of 256
+HEAD = dict(B=xent.ROW_BLOCK // 256, T=256, D=128, V=300)
+
+
+def _head(fused, ignore_index=-100, rows="float32", **size):
+    """The end of ``models/transformer.build`` over activations fed as data,
+    with the one op or with the pair, on the same parameter."""
+    s = dict(HEAD, **size)
+    x = L.data("x", [s["T"], s["D"]], dtype=rows, stop_gradient=False)
+    lbl = L.unsqueeze(L.data("lbl_ids", [s["T"]], dtype="int64"), [2])
+    attr = fluid.ParamAttr(name="tgt.out_proj")
+    with fluid.name_scope("out_proj"):
+        if fused:
+            loss = L.fc_softmax_with_cross_entropy(
+                x, lbl, s["V"], num_flatten_dims=2, param_attr=attr,
+                ignore_index=ignore_index)
+        else:
+            loss = L.softmax_with_cross_entropy(
+                L.fc(x, s["V"], num_flatten_dims=2, bias_attr=False,
+                     param_attr=attr), lbl, ignore_index=ignore_index)
+    cost = L.reduce_sum(L.elementwise_mul(L.squeeze(loss, [2]),
+                                          L.data("weight", [s["T"]])))
+    fluid.optimizer.SGD(0.1).minimize(cost)
+    return loss, cost
+
+
+def _head_feed(rows=jnp.float32, **size):
+    s = dict(HEAD, **size)
+    rng = np.random.RandomState(1)
+    lbl = rng.randint(0, s["V"], (s["B"], s["T"])).astype("int64")
+    lbl[:, ::5] = IGNORED
+    return {"x": jnp.asarray(rng.randn(s["B"], s["T"], s["D"]), rows),
+            "lbl_ids": lbl,
+            "weight": rng.uniform(0.5, 1.5, (s["B"], s["T"])).astype("float32")}
+
+
+def _run_head(fused, ignore_index, feed, rows="float32", **size):
+    prog, startup, (loss, cost) = _build(
+        lambda: _head(fused, ignore_index, rows, **size), 9)
+    fetch = [loss, cost, prog.global_block.var("x@GRAD"),
+             prog.global_block.var("tgt.out_proj@GRAD")]
+    with scope_guard(Scope()):
+        exe = Executor()
+        exe.run(startup)
+        return prog, [np.asarray(v, np.float64) for v in
+                      exe.run(prog, feed=feed, fetch_list=fetch)]
+
+
+def _xent_choice():
+    scope = stats.scope("loss")
+    return (scope.counter("proj_xent_kernel"),
+            scope.counter("proj_xent_fallbacks"))
+
+
+def _as_on_a_tpu(monkeypatch):
+    """The op's choice made as on a TPU; the kernel it then takes runs in
+    interpret mode here."""
+    real = nn_ops._proj_xent_impl
+    monkeypatch.setattr(nn_ops, "_proj_xent_impl",
+                        lambda backend, *a, **kw: real("tpu", *a, **kw))
+
+
+@pytest.mark.parametrize("ignore_index", [-100, IGNORED], ids=["all", "ignore"])
+def test_the_fused_program_equals_the_pairs_program(ignore_index):
+    """Loss, ``X@GRAD`` and ``W@GRAD`` through the executor, the same weights
+    (one parameter name, one seed); on the CPU the op takes the fallback and
+    counts it."""
+    size = dict(B=2, T=6, D=8, V=13)
+    feed = _head_feed(**size)
+    kernel, fallback = _xent_choice()
+    before = kernel.value, fallback.value
+    prog, fused = _run_head(True, ignore_index, feed, **size)
+    assert (kernel.value - before[0], fallback.value - before[1]) == (0, 1)
+    _, pair = _run_head(False, ignore_index, feed, **size)
+    for got, want in zip(fused, pair):
+        assert np.abs(want).max() > 0
+        np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    if ignore_index == IGNORED:
+        ignored = feed["lbl_ids"] == IGNORED
+        assert not fused[0][ignored].any() and not fused[2][ignored].any()
+        assert fused[0][~ignored].all()
+    types = [op.type for op in prog.global_block.ops]
+    assert "fc_softmax_with_cross_entropy_grad" in types
+    assert not {"mul", "softmax_with_cross_entropy", "mul_grad"} & set(types)
+
+
+def test_the_fused_programs_clone_for_test_runs_the_op_alone():
+    """A clone pruned to the loss — what an evaluation runs — keeps the op
+    with its three outputs and drops its grad op; its loss is the training
+    program's forward loss on the same weights."""
+    size = dict(B=2, T=6, D=8, V=13)
+    feed = _head_feed(**size)
+    prog, startup, (loss, _) = _build(lambda: _head(True, IGNORED, **size), 9)
+    test_prog = prog.clone().prune([loss.name])
+    ops = test_prog.global_block.ops
+    assert [op.type for op in ops].count("fc_softmax_with_cross_entropy") == 1
+    assert not any(op.type.endswith("_grad") or op.type == "sgd" for op in ops)
+    fused = [op for op in ops if op.type == "fc_softmax_with_cross_entropy"][0]
+    assert sorted(fused.outputs) == ["LSE", "Logits", "Loss"]
+    with scope_guard(Scope()):
+        exe = Executor()
+        exe.run(startup)
+        feed_eval = {k: v for k, v in feed.items() if k != "weight"}
+        (evaluated,) = exe.run(test_prog, feed=feed_eval, fetch_list=[loss])
+        (trained,) = exe.run(prog, feed=feed, fetch_list=[loss])
+        (after,) = exe.run(test_prog, feed=feed_eval, fetch_list=[loss])
+    np.testing.assert_array_equal(evaluated, trained)
+    assert np.abs(after - evaluated).max() > 0      # the step moved tgt.out_proj
+
+
+@pytest.mark.parametrize("rows", ["float32", "bfloat16"])
+def test_the_kernel_in_a_program_equals_the_fallback(monkeypatch, rows):
+    """The op's choice made as on a TPU, at rows that fill one row block:
+    the kernel (interpret mode) through the executor, forward and grad op,
+    against the same program on the fallback; rows that do not fill a block
+    fall back and are counted so."""
+    feed = _head_feed(jnp.float32 if rows == "float32" else jnp.bfloat16)
+    # a bf16 parameter under float32 activations, as transformer.build
+    # (dtype="bfloat16") hands them: the variable is declared bf16
+    _, want = _run_head(True, IGNORED, feed, "bfloat16")
+    kernel, fallback = _xent_choice()
+    _as_on_a_tpu(monkeypatch)
+    before = kernel.value, fallback.value
+    _, got = _run_head(True, IGNORED, feed, "bfloat16")
+    assert (kernel.value - before[0], fallback.value - before[1]) == (1, 0)
+    # the kernel rounds float32 rows to bf16, as the TPU's product does and
+    # the CPU's does not; bf16 rows differ by the product's last bit
+    for a, b in zip(got, want):
+        scale = np.abs(b).max()
+        assert scale > 0
+        np.testing.assert_allclose(a, b, rtol=0, atol=2e-2 * scale)
+    short = dict(B=HEAD["B"] - 1)           # a sequence short of a block
+    _run_head(True, IGNORED, _head_feed(**short), "bfloat16", **short)
+    assert (kernel.value - before[0], fallback.value - before[1]) == (1, 1)
+
+
+def test_the_kernel_under_a_dp_mesh_is_the_one_device_result(monkeypatch):
+    """``ParallelExecutor`` dp = 4 over CPU devices, a row block a shard: the
+    op wraps the kernel per shard (``w`` whole on each) and the loss and the
+    updated parameter are one device's; a plain ``Executor`` over the scope
+    the mesh placed takes the fallback, as ``fused_attention`` does there."""
+    size = dict(B=4 * HEAD["B"])
+    feed = _head_feed(**size)
+    _as_on_a_tpu(monkeypatch)
+    kernel, fallback = _xent_choice()
+    results = {}
+    for dp in (1, 4):
+        prog, startup, (loss, cost) = _build(
+            lambda: _head(True, IGNORED, "bfloat16", **size), 9)
+        scope, exe = Scope(), Executor()
+        exe.run(startup, scope=scope)
+        before = kernel.value, fallback.value
+        if dp == 1:
+            out = exe.run(prog, feed=feed, fetch_list=[loss, cost],
+                          scope=scope)
+        else:
+            pe = ParallelExecutor(
+                loss_name=cost.name, main_program=prog, scope=scope,
+                places=jax.devices()[:dp],
+                build_strategy=BuildStrategy(mesh_shape={"dp": dp}))
+            out = pe.run(feed=feed, fetch_list=[loss.name, cost.name])
+        assert (kernel.value - before[0], fallback.value - before[1]) == (1, 0)
+        results[dp] = [np.asarray(v, np.float64) for v in out] + [
+            np.asarray(scope.find_var("tgt.out_proj"), np.float64)]
+        if dp > 1:
+            test_prog = prog.clone().prune([loss.name])
+            feed_eval = {k: v for k, v in feed.items() if k != "weight"}
+            (evaluated,) = exe.run(test_prog, feed=feed_eval,
+                                   fetch_list=[loss], scope=scope)
+            assert exe._spans_devices and np.isfinite(evaluated).all()
+            assert (kernel.value - before[0],
+                    fallback.value - before[1]) == (1, 1)
+            pe.close()
+        exe.close()
+    # the parameter is bf16 and its gradient a sum over four shards, rounded
+    # to bf16 before the update: a few ulps
+    for (one, four), tol in zip(zip(results[1], results[4]),
+                                (2e-5, 2e-5, 2 ** -6)):
+        np.testing.assert_allclose(four, one, rtol=tol, atol=tol * 1e-1)
+
+
+def _lowered_names(prog, startup, feed, fetch):
+    """``op_name``s of the program's lowering BEFORE any optimisation."""
+    scope = Scope()
+    with scope_guard(scope):
+        Executor().run(startup)
+        names = sorted(feed)
+        plan = analyze_block(prog, 0, names, [f.name for f in fetch])
+        block = prog.global_block
+        vals = [_as_device_array(feed[n], block.var_or_none(n)) for n in names]
+        state = [[np.asarray(scope.find_var(n)) for n in reads]
+                 for reads in (plan.donated_reads, plan.const_reads)]
+        text = jax.jit(build_block_fn(prog, plan)).lower(
+            vals, *state, jax.random.PRNGKey(0)).as_text(debug_info=True)
+    return collections.Counter(
+        re.findall(r'loc\("jit\(fn_s1\)/([^"]*)"', text))
+
+
+def test_the_lowered_backward_holds_no_reduction_over_the_classes():
+    """The forward (here the fallback) reduces over the classes once for the
+    maximum and once for the sum; the grad op's half holds the exponential,
+    the one-hot and the two products, and NO reduction, logarithm or second
+    ``logsumexp``: it read ``LSE``."""
+    size = dict(B=2, T=6, D=8, V=13)
+    prog, startup, (_, cost) = _build(lambda: _head(True, **size), 9)
+    # both gradients fetched: jit drops what nothing reads before it lowers
+    names = _lowered_names(prog, startup, _head_feed(**size),
+                           [cost, prog.global_block.var("x@GRAD")])
+    fwd, bwd = ("fwd/out_proj/fc_softmax_with_cross_entropy",
+                "bwd/out_proj/fc_softmax_with_cross_entropy_grad")
+    ours = {n for n in names if "fc_softmax_with_cross_entropy" in n}
+    assert {n.rsplit("/", 1)[0].split("/jit(")[0] for n in ours} == {fwd, bwd}
+    for primitive in ("reduce_max", "reduce_sum", "log", "dot_general"):
+        assert names[f"{fwd}/{primitive}"] == 1, primitive
+    for primitive in ("exp", "iota", "eq"):
+        assert names[f"{bwd}/{primitive}"] == 1, primitive
+    assert names[f"{bwd}/dot_general"] == 2
+    assert not any(n.startswith(bwd) and n.rsplit("/", 1)[1] in
+                   ("reduce_max", "reduce_sum", "log", "logsumexp",
+                    "stop_gradient") for n in names)
+
+
+def test_the_pipelines_cost_of_the_fused_op_is_its_product():
+    """Stage balancing (``pipeline/transpiler.py``) counts the one op as the
+    ``mul`` it holds, not as its outputs' elements."""
+    from paddle_tpu.pipeline.transpiler import op_flops_estimate
+    size = dict(B=2, T=6, D=8, V=13)
+    cost = {}
+    for fused, kind in ((True, "fc_softmax_with_cross_entropy"),
+                        (False, "mul")):
+        prog, _, _ = _build(lambda: _head(fused, **size), 9)
+        (op,) = [op for op in prog.global_block.ops if op.type == kind]
+        cost[fused] = op_flops_estimate(prog.global_block, op, batch=2)
+    assert cost[True] == cost[False] == 2.0 * 2 * 6 * 13 * 8

@@ -66,7 +66,11 @@ package is that state plane, built on the repo's own primitives:
   :mod:`command_a` (Command A+: ONE LayerNorm a layer and three branches
   summed — 128 query heads on 8 K/V heads over window rings or a
   position-free pool, a share of 128 sigmoid-routed experts, four shared
-  experts averaged — and a slice of the tied embedding).
+  experts averaged — and a slice of the tied embedding); :mod:`nemotron_h`
+  (Nemotron-H / Nemotron 3 Nano: ONE mixer a layer by a pattern string —
+  Mamba-2 of 64-wide heads kept two to a lane tile, ungated relu² experts
+  held by share beside a shared one, position-free grouped-query attention
+  — with a layer count a kind of state).
   Each module's docstring is its model's.
 - **On-device sampling** (:func:`adapter.sample`): greedy (an argmax; the
   vocabulary is sorted only in a launch that holds a sampled request) /
@@ -111,6 +115,7 @@ from .smallthinker import (SmallThinkerConfig,  # noqa: F401
 from .lfm2 import LFM2Config, LFM2LM  # noqa: F401
 from .kimi_linear import KimiLinearConfig, KimiLinearLM  # noqa: F401
 from .command_a import CommandAConfig, CommandALM  # noqa: F401
+from .nemotron_h import NemotronHConfig, NemotronHLM  # noqa: F401
 from .engine import (DecodeEngine, DecodeHandle,  # noqa: F401
                      DecodeRequest, SamplingParams)
 from .beam import PagedBeamDecoder  # noqa: F401
@@ -128,7 +133,7 @@ __all__ = [
     "HyperMLATransformerLM", "SambaYConfig", "SambaYLM",
     "FalconH1Config", "FalconH1LM", "SmallThinkerConfig", "SmallThinkerLM",
     "LFM2Config", "LFM2LM", "KimiLinearConfig", "KimiLinearLM",
-    "CommandAConfig", "CommandALM",
+    "CommandAConfig", "CommandALM", "NemotronHConfig", "NemotronHLM",
     "DecodeEngine", "DecodeHandle", "DecodeRequest", "SamplingParams",
     "PagedBeamDecoder",
     "DecodeServer", "DecodeService", "DecodeClient",

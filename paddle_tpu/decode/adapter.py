@@ -2,7 +2,7 @@
 layer math they share, the paged pool's addressing and the observers' common
 series.  A model module (:mod:`model`, :mod:`mla`, :mod:`sambay`,
 :mod:`falcon_h1`, :mod:`smallthinker`, :mod:`lfm2`, :mod:`kimi_linear`,
-:mod:`command_a`) brings
+:mod:`command_a`, :mod:`nemotron_h`) brings
 its config, its
 ``param_shapes``, its layers, its two programs and the counters that are its
 own; it imports this module and no sibling.

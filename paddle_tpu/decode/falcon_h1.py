@@ -151,7 +151,10 @@ class FalconH1Config(ConfigDict):
 
     @property
     def state_shape(self) -> tuple:
-        return (self.mamba_n_heads, self.mamba_d_state, self.mamba_d_head)
+        """A stream's recurrent row of one layer, as ``kernels/ssd.py`` keeps
+        it ([heads, N, head channels] at every width but 64)."""
+        return _ssd.state_layout(self.mamba_n_heads, self.mamba_d_state,
+                                 self.mamba_d_head)
 
 
 def mup_vector(cfg: FalconH1Config) -> np.ndarray:

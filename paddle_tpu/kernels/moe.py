@@ -167,12 +167,13 @@ _PREFILL_TILE = 128
 _WEIGHT_BLOCKS_BYTES = 64 * 1024 * 1024
 
 
-def f_block(D: int, F: int, itemsize: int) -> int:
+def f_block(D: int, F: int, itemsize: int, matrices: int = 3) -> int:
     """Columns of an expert's intermediate axis a grid step holds: all ``F``
-    where its three matrices fit VMEM twice over, else ``F`` halved until
-    they do."""
+    where its ``matrices`` (three of a gated unit, two of an ungated one) fit
+    VMEM twice over, else ``F`` halved until they do."""
     fb = F
-    while 6 * D * fb * itemsize > _WEIGHT_BLOCKS_BYTES and fb % 256 == 0:
+    while 2 * matrices * D * fb * itemsize > _WEIGHT_BLOCKS_BYTES \
+            and fb % 256 == 0:
         fb //= 2
     return fb
 
@@ -322,41 +323,81 @@ def _plan_by_count(ids, valid, first, *, experts, tile):
 ACTS = {"silu": (jax.nn.silu, "swiglu"), "relu": (jax.nn.relu, "reglu")}
 
 
+def _relu2(v):
+    return jnp.square(jax.nn.relu(v))
+
+
+# ... and the activation of a unit with NO gate, ``act(x W_upᵀ) W_down`` — two
+# matrices an expert (``wg`` is None wherever three are taken), under a kernel
+# name and counters of its own.  BOTH matrices lie ``[E, F, D]``, the first as
+# a checkpoint keeps it (``[out, in]``) and multiplied transposed: an
+# intermediate width that is no whole number of lane tiles (1,856 = 14.5 x
+# 128) is then the SUBLANE axis of both (116 bf16 tiles) and nothing is
+# padded — as the lane axis of ``[E, D, F]`` XLA keeps it in a layout of its
+# own and copies the whole stack to the kernel's every call (3.2 GB of
+# temporaries at 5 x 64 x 2,688 x 1,856 in a compile for a described v5e).
+UNGATED = {"relu2": (_relu2, "relu2")}
+_NT = (((1,), (1,)), ((), ()))
+
+
 def _gate(act: str):
     try:
-        return ACTS[act]
+        return ACTS[act] if act in ACTS else UNGATED[act]
     except KeyError:
         raise ValueError(f"unknown gate activation {act!r}; one of "
-                         f"{sorted(ACTS)}") from None
+                         f"{sorted(ACTS)}, or ungated {sorted(UNGATED)}"
+                         ) from None
 
 
-def _glu_tile(x, wg_ref, wu_ref, wd_ref, gate, dtype):
-    """One tile of rows through the step's expert: both walks' products."""
-    g = jnp.dot(x, wg_ref[0], preferred_element_type=jnp.float32)
-    u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
-    h = (gate(g) * u).astype(wd_ref.dtype)
+def _unit_weights(act: str, wg, wu, wd) -> tuple:
+    """The matrices the unit ``act`` multiplies by, in the kernels' order: a
+    gated unit's three, an ungated one's two (and no ``wg``)."""
+    if (act in UNGATED) != (wg is None):
+        raise ValueError(f"{act!r} is a unit of "
+                         f"{'two' if act in UNGATED else 'three'} matrices")
+    return (wu, wd) if wg is None else (wg, wu, wd)
+
+
+def _weight_specs(matrices: int, up, down) -> list:
+    """The block specs of a unit's matrices: gate and up ``[D, F]`` and down
+    ``[F, D]`` of a gated unit; both ``[F, D]`` of an ungated one."""
+    return [up, up, down] if matrices == 3 else [down, down]
+
+
+def _glu_tile(x, w_refs, gate, dtype):
+    """One tile of rows through the step's expert: both walks' products.
+    ``w_refs``: gate, up and down — or, of an ungated unit, up and down."""
+    wu_ref, wd_ref = w_refs[-2:]
+    if len(w_refs) == 3:
+        g = jnp.dot(x, w_refs[0][0], preferred_element_type=jnp.float32)
+        u = jnp.dot(x, wu_ref[0], preferred_element_type=jnp.float32)
+        h = (gate(g) * u).astype(wd_ref.dtype)
+    else:
+        h = gate(lax.dot_general(x, wu_ref[0], _NT,
+                                 preferred_element_type=jnp.float32)
+                 ).astype(wd_ref.dtype)
     return jnp.dot(h, wd_ref[0], preferred_element_type=jnp.float32
                    ).astype(dtype)
 
 
-def _grouped_glu_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref, o_ref,
-                        *, gate):
+def _grouped_glu_kernel(te_ref, na_ref, x_ref, *refs, gate):
+    *w_refs, o_ref = refs
+
     @pl.when(pl.program_id(0) < na_ref[0])
     def _():
-        o_ref[:] = _glu_tile(x_ref[:], wg_ref, wu_ref, wd_ref, gate,
-                             o_ref.dtype)
+        o_ref[:] = _glu_tile(x_ref[:], w_refs, gate, o_ref.dtype)
 
 
-def _grouped_glu_fblock_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref,
-                               o_ref, acc, *, gate):
+def _grouped_glu_fblock_kernel(te_ref, na_ref, x_ref, *refs, gate):
     """The tile walk of experts too wide for VMEM: grid (tiles, blocks of the
     intermediate axis), a tile's products summed over the blocks in ``acc``
     (float32) and written with the last."""
+    *w_refs, o_ref, acc = refs
     f = pl.program_id(1)
 
     @pl.when(pl.program_id(0) < na_ref[0])
     def _():
-        y = _glu_tile(x_ref[:], wg_ref, wu_ref, wd_ref, gate, jnp.float32)
+        y = _glu_tile(x_ref[:], w_refs, gate, jnp.float32)
 
         @pl.when(f == 0)
         def _first():
@@ -377,8 +418,7 @@ def _grouped_glu_fblock_kernel(te_ref, na_ref, x_ref, wg_ref, wu_ref, wd_ref,
 _PIECE_TILES = 4
 
 
-def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, f_ref, x_hbm, wg_ref,
-                        wu_ref, wd_ref, o_hbm, xbuf, obuf, sem, state, *,
+def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, f_ref, x_hbm, *refs,
                         gate, tile, piece_tiles):
     """Grid (E,): one grid step an expert that has rows, in the order of the
     plan (``ex_ref`` the expert of a step, ``st_ref`` its first row,
@@ -402,6 +442,7 @@ def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, f_ref, x_hbm, wg_ref,
     # on a traced scalar is a ``jnp`` function traced on its own, and ninety
     # of them were most of this body's trace (0.3 s a kernel on the chip's
     # host, twice a prefill program where a model has two stacks of experts)
+    *w_refs, o_hbm, xbuf, obuf, sem, state = refs
     zero, one, two, tile_, piece_, rows_ = (
         jnp.int32(v) for v in (0, 1, 2, tile, piece_tiles,
                                piece_tiles * tile))
@@ -467,8 +508,8 @@ def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, f_ref, x_hbm, wg_ref,
 
             def one_tile(t, carry):
                 at = pl.ds(pl.multiple_of(lax.mul(t, tile_), tile), tile)
-                obuf[half, at] = _glu_tile(xbuf[half, at], wg_ref, wu_ref,
-                                           wd_ref, gate, obuf.dtype)
+                obuf[half, at] = _glu_tile(xbuf[half, at], w_refs, gate,
+                                           obuf.dtype)
                 return carry
 
             lax.fori_loop(zero, tiles, one_tile, 0)
@@ -490,8 +531,8 @@ def _expert_walk_kernel(ex_ref, st_ref, nt_ref, n_ref, f_ref, x_hbm, wg_ref,
 
 @functools.partial(jax.jit, static_argnames=("tile", "act", "out_dtype",
                                              "interpret"))
-def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
-                 out_dtype, interpret):
+def _expert_walk(x_rows, ws, sizes, layer_base, *, tile, act, out_dtype,
+                 interpret):
     """The prefill form of :func:`grouped_glu`: see the module's docstring
     and :func:`_expert_walk_kernel`.  ``sizes`` the plan's ``padded_sizes``.
     A function of its own under ``jax.jit``: a program whose layers are not
@@ -501,8 +542,8 @@ def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
     tenth of ``dsv2l_doc_sat``'s warm set-up."""
     gate, glu = _gate(act)
     R, D = x_rows.shape
-    E, F = sizes.shape[0], wg.shape[-1]
-    fb = f_block(D, F, wg.dtype.itemsize)
+    E, F = sizes.shape[0], ws[-1].shape[-2]
+    fb = f_block(D, F, ws[-1].dtype.itemsize, len(ws))
     has = sizes > 0
     n = jnp.sum(has, dtype=jnp.int32)
     # the experts with rows first, in the plan's order; the steps after the
@@ -530,10 +571,9 @@ def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=5,
                 grid=(E,),
-                in_specs=[pl.BlockSpec(memory_space=pl.ANY),
-                          pl.BlockSpec((1, D, fb), up),
-                          pl.BlockSpec((1, D, fb), up),
-                          pl.BlockSpec((1, fb, D), down)],
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)]
+                + _weight_specs(len(ws), pl.BlockSpec((1, D, fb), up),
+                                pl.BlockSpec((1, fb, D), down)),
                 out_specs=pl.BlockSpec(memory_space=pl.ANY),
                 scratch_shapes=[pltpu.VMEM((2, rows_max, D), x_rows.dtype),
                                 pltpu.VMEM((2, rows_max, D), part_dtype),
@@ -543,7 +583,7 @@ def _expert_walk(x_rows, wg, wu, wd, sizes, layer_base, *, tile, act,
             compiler_params=_VMEM_PARAMS,
             interpret=interpret,
         )(step + layer_base, first_row, sizes[step] // tile, n.reshape(1),
-          jnp.asarray(f, jnp.int32).reshape(1), x_rows, wg, wu, wd)
+          jnp.asarray(f, jnp.int32).reshape(1), x_rows, *ws)
 
     if fb == F:
         return walk(0)
@@ -560,15 +600,19 @@ def _one_layer(w, layer):
 
 def grouped_glu_xla(x_rows, wg, wu, wd, plan: GroupPlan, act: str = "silu",
                     layer=None, out_dtype=jnp.float32):
-    """The fallback: the same padded rows through three ``lax.ragged_dot``."""
+    """The fallback: the same padded rows through three ``lax.ragged_dot``
+    (two of an ungated unit)."""
     gate, _ = _gate(act)
-    wg, wu, wd = (_one_layer(w, layer) for w in (wg, wu, wd))
+    ws = [_one_layer(w, layer) for w in _unit_weights(act, wg, wu, wd)]
 
     def rd(a, w):
         return lax.ragged_dot(a, w, plan.padded_sizes,
                               preferred_element_type=jnp.float32)
-    h = (gate(rd(x_rows, wg)) * rd(x_rows, wu)).astype(wd.dtype)
-    return rd(h, wd).astype(out_dtype)
+    if len(ws) == 3:
+        h = gate(rd(x_rows, ws[0])) * rd(x_rows, ws[1])
+    else:
+        h = gate(rd(x_rows, jnp.swapaxes(ws[0], -1, -2)))
+    return rd(h.astype(ws[-1].dtype), ws[-1]).astype(out_dtype)
 
 
 def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
@@ -591,11 +635,12 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
     if interpret is None:
         interpret = pallas_interpret()
     R, D = x_rows.shape
-    E, F = wg.shape[-3], wg.shape[-1]
+    ws = _unit_weights(act, wg, wu, wd)
+    E, F = wd.shape[-3], wd.shape[-2]
     layer_base = 0
     if layer is not None:
         # a stack is its layers' experts end to end: nothing is sliced out
-        wg, wu, wd = (w.reshape((-1,) + w.shape[2:]) for w in (wg, wu, wd))
+        ws = tuple(w.reshape((-1,) + w.shape[2:]) for w in ws)
         layer_base = jnp.asarray(layer, jnp.int32) * E
     # the plan's tile chooses the walk: hundreds of rows an expert step by
     # expert, a decode step's few by tile
@@ -603,16 +648,16 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
     _obs_stats.scope("moe").counter(
         f"grouped_{glu}_{'expert' if by_expert else 'tile'}_walks").inc()
     if by_expert:
-        return _expert_walk(x_rows, wg, wu, wd, plan.padded_sizes,
+        return _expert_walk(x_rows, ws, plan.padded_sizes,
                             jnp.asarray(layer_base, jnp.int32), tile=tile,
                             act=act, out_dtype=jnp.dtype(out_dtype),
                             interpret=bool(interpret))
     tile_expert = plan.tile_expert
     if layer is not None:
         tile_expert = tile_expert + layer_base
-    fb = f_block(D, F, wg.dtype.itemsize)
+    fb = f_block(D, F, wu.dtype.itemsize, len(ws))
     if fb != F:
-        return _tile_walk_by_block(x_rows, wg, wu, wd, tile_expert,
+        return _tile_walk_by_block(x_rows, ws, tile_expert,
                                    plan.active_tiles, tile, fb, gate, glu,
                                    out_dtype, interpret)
 
@@ -628,27 +673,25 @@ def grouped_glu(x_rows, wg, wu, wd, plan: GroupPlan, tile: int, impl=None,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R // tile,),
-            in_specs=[pl.BlockSpec((tile, D), rows),
-                      pl.BlockSpec((1, D, F), up),
-                      pl.BlockSpec((1, D, F), up),
-                      pl.BlockSpec((1, F, D), up)],
+            in_specs=[pl.BlockSpec((tile, D), rows)]
+            + _weight_specs(len(ws), pl.BlockSpec((1, D, F), up),
+                            pl.BlockSpec((1, F, D), up)),
             out_specs=pl.BlockSpec((tile, D), rows)),
         out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
         compiler_params=_VMEM_PARAMS,
         interpret=interpret,
-    )(tile_expert, plan.active_tiles, x_rows, wg, wu, wd)
+    )(tile_expert, plan.active_tiles, x_rows, *ws)
 
 
-def _tile_walk_by_block(x_rows, wg, wu, wd, tile_expert, active_tiles,
-                        tile: int, fb: int, gate, glu: str, out_dtype,
-                        interpret):
+def _tile_walk_by_block(x_rows, ws, tile_expert, active_tiles, tile: int,
+                        fb: int, gate, glu: str, out_dtype, interpret):
     """The tile walk of experts too wide for VMEM
     (:func:`_grouped_glu_fblock_kernel`): every tile against its expert's
     matrices a block of ``fb`` columns of the intermediate axis at a time.
     The tiles past the last real one stay on its last block: nothing is
     fetched for them."""
     R, D = x_rows.shape
-    nf = wg.shape[-1] // fb
+    nf = ws[-1].shape[-2] // fb
 
     def rows(i, f, te, na):
         return (jnp.minimum(i, na[0] - 1), 0)
@@ -668,10 +711,9 @@ def _tile_walk_by_block(x_rows, wg, wu, wd, tile_expert, active_tiles,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(R // tile, nf),
-            in_specs=[pl.BlockSpec((tile, D), rows),
-                      pl.BlockSpec((1, D, fb), up),
-                      pl.BlockSpec((1, D, fb), up),
-                      pl.BlockSpec((1, fb, D), down)],
+            in_specs=[pl.BlockSpec((tile, D), rows)]
+            + _weight_specs(len(ws), pl.BlockSpec((1, D, fb), up),
+                            pl.BlockSpec((1, fb, D), down)),
             out_specs=pl.BlockSpec((tile, D), rows),
             scratch_shapes=[pltpu.VMEM((tile, D), jnp.float32)]),
         out_shape=jax.ShapeDtypeStruct((R, D), out_dtype),
@@ -679,7 +721,7 @@ def _tile_walk_by_block(x_rows, wg, wu, wd, tile_expert, active_tiles,
             vmem_limit_bytes=_VMEM_LIMIT,
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(tile_expert, active_tiles, x_rows, wg, wu, wd)
+    )(tile_expert, active_tiles, x_rows, *ws)
 
 
 def row_tile(tokens: int, dtype) -> int:
@@ -790,7 +832,7 @@ def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
     the stacks hold experts ``first …`` of a wider layer's
     (:func:`plan_groups`) and the sum is this share's part."""
     tile = row_tile(x.shape[0], x.dtype)
-    plan = plan_groups(ids, valid, wg.shape[0], tile, first)
+    plan = plan_groups(ids, valid, wd.shape[0], tile, first)
     return planned_experts(x, weights, plan, wg, wu, wd, tile, impl=impl,
                            act=act), plan.load
 
@@ -798,4 +840,4 @@ def routed_experts(x, ids, weights, valid, wg, wu, wd, impl=None,
 __all__ = ["route_topk", "plan_groups", "plan_rows", "share_block_rows",
            "f_block", "grouped_glu", "grouped_glu_xla", "combine",
            "planned_experts", "routed_experts", "GroupPlan", "row_tile",
-           "ACTS", "SCORES"]
+           "ACTS", "UNGATED", "SCORES"]

@@ -481,3 +481,109 @@ def test_the_plan_is_the_stable_sort_s_to_the_integer(shape, tile, valid,
         assert a.dtype == jnp.int32, name
         np.testing.assert_array_equal(a, np.asarray(b).reshape(a.shape),
                                       err_msg=name)
+
+
+# -- the ungated unit: act(x W_upᵀ) W_down, two matrices, both [E, F, D] ------
+def _ungated_case(seed, T=40, D=32, F=48, E=8, K=3, layers=None, counts=None,
+                  dtype=jnp.float32):
+    rng = np.random.RandomState(seed)
+    lead = () if layers is None else (layers,)
+    if counts is not None:
+        E, T = len(counts), sum(counts) // K
+    x = jnp.asarray(rng.randn(T, D), dtype)
+    wu = jnp.asarray(rng.randn(*lead, E, F, D) * D ** -0.5, dtype)
+    wd = jnp.asarray(rng.randn(*lead, E, F, D) * F ** -0.5, dtype)
+    if counts is None:
+        ids, w = moe.route_topk(jnp.asarray(rng.randn(T, E), jnp.float32), K,
+                                normalize=True)
+    else:
+        ids = jnp.asarray(rng.permutation(np.repeat(
+            np.arange(E), counts)).reshape(-1, K), jnp.int32)
+        w = rng.rand(T, K) + 0.1
+        w = jnp.asarray(w / w.sum(-1, keepdims=True), jnp.float32)
+    return x, wu, wd, ids, w
+
+
+def _dense_relu2(x, wu, wd, ids, w):
+    x, wu, wd, w = (np.asarray(a, np.float64) for a in (x, wu, wd, w))
+    out = np.zeros(x.shape)
+    for e in range(wu.shape[0]):
+        share = (w * (np.asarray(ids) == e)).sum(-1, keepdims=True)
+        out += share * ((np.maximum(x @ wu[e].T, 0.0) ** 2) @ wd[e])
+    return out
+
+
+# (tokens or an expert's counts, D, F): a step's tile walk and a prefill's
+# expert walk at a small size, and both at NVIDIA-Nemotron-3-Nano's published
+# expert — 2,688 x 1,856, no whole number of lane tiles
+UNGATED_CASES = {
+    "step": (dict(T=40), 8), "prefill": (dict(counts=COUNTS["mixed"], K=2),
+                                         128),
+    "step_published": (dict(T=24, D=2688, F=1856, E=4, K=2), 8),
+    "prefill_published": (dict(counts=[130, 0, 1, 125], D=2688, F=1856,
+                               K=2), 128)}
+
+
+@pytest.mark.parametrize("impl", ["pallas", "xla"])
+@pytest.mark.parametrize("case", sorted(UNGATED_CASES))
+def test_the_ungated_unit_is_relu2_of_two_matrices(case, impl):
+    kw, tile = UNGATED_CASES[case]
+    x, wu, wd, ids, w = _ungated_case(11, **kw)
+    valid = jnp.ones((x.shape[0],), bool)
+    plan = moe.plan_groups(ids, valid, wu.shape[0], tile)
+
+    def fn(x, wu, wd, w):
+        return moe.planned_experts(x, w, plan, None, wu, wd, tile, impl=impl,
+                                   act="relu2")
+
+    before = stats.snapshot()
+    y = np.asarray(jax.jit(fn)(x, wu, wd, w))
+    want = _dense_relu2(x, wu, wd, ids, w)
+    assert np.abs(y - want).max() < 2e-5 * np.abs(want).max()
+    names = [e.params["name"] for e in eqns_under(jax.make_jaxpr(fn)(
+        x, wu, wd, w).jaxpr) if e.primitive.name == "pallas_call"]
+    assert names == (["moe_grouped_relu2"] if impl == "pallas" else [])
+    after = stats.snapshot()
+    walk = "expert" if tile == 128 else "tile"
+    counter = "moe.grouped_relu2_fallbacks" if impl == "xla" \
+        else f"moe.grouped_relu2_{walk}_walks"
+    assert after.get(counter, 0) > before.get(counter, 0)
+    # a gated unit in its place is another result
+    gated = _dense(x, np.swapaxes(wu, -1, -2), np.swapaxes(wu, -1, -2), wd,
+                   ids, w, np.ones(x.shape[0]), "silu")
+    assert np.abs(y - gated).max() > 0.05 * np.abs(want).max()
+
+
+def test_the_ungated_unit_takes_no_gate_and_a_gated_one_needs_it():
+    x, wu, wd, ids, w = _ungated_case(12)
+    valid = jnp.ones((x.shape[0],), bool)
+    with pytest.raises(ValueError, match="two matrices"):
+        moe.routed_experts(x, ids, w, valid, wu, wu, wd, act="relu2")
+    with pytest.raises(ValueError, match="three matrices"):
+        moe.routed_experts(x, ids, w, valid, None, wu, wd, act="silu")
+    # two matrices fit VMEM where three of the same width do not
+    assert moe.f_block(4096, 4096, 2, matrices=2) == 2048
+    assert moe.f_block(4096, 4096, 2) == 1024
+    assert moe.f_block(2688, 1856, 2, matrices=2) == 1856
+
+
+@pytest.mark.parametrize("tile,kw", [(8, dict(T=40)),
+                                     (128, dict(counts=COUNTS["ends_empty"],
+                                                K=2))],
+                         ids=["step", "prefill"])
+def test_a_layer_of_an_ungated_stack_and_a_share_of_it(tile, kw):
+    """The stack is handed over whole with the layer's index, in bf16 rows
+    out, and the held half of the experts computes its own part."""
+    x, wu, wd, ids, w = _ungated_case(13, layers=3, **kw)
+    valid = jnp.ones((x.shape[0],), bool)
+    E = wu.shape[1]
+    whole = _dense_relu2(x, wu[1], wd[1], ids, w)
+    parts = []
+    for first in (0, E // 2):
+        plan = moe.plan_groups(ids, valid, E // 2, tile, first=first)
+        parts.append(np.asarray(moe.planned_experts(
+            x, w, plan, None, wu[:, first:first + E // 2],
+            wd[:, first:first + E // 2], tile, act="relu2", layer=1)))
+    assert np.abs(parts[0] + parts[1] - whole).max() \
+        < 2e-5 * np.abs(whole).max()
+    assert np.abs(parts[0]).max() > 0 and np.abs(parts[1]).max() > 0

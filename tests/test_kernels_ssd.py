@@ -125,3 +125,104 @@ def test_state_step_kernel_equals_the_xla_form():
     np.testing.assert_allclose(y, y0, rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(new[0], new0, rtol=1e-5, atol=1e-5)
     np.testing.assert_array_equal(new[1], S[1])
+
+
+# -- 64-wide heads: two of one group's heads a lane tile ---------------------
+# (H, P, G, N): a small pair layout, and NVIDIA-Nemotron-3-Nano's published
+# heads (64 of 64 channels, 8 groups of 8, a state of 128)
+PAIRED = {"small": (8, 64, 2, 16), "nemotron_h": (64, 64, 8, 128)}
+
+
+def test_the_kept_layout_pairs_64_wide_heads_and_keeps_the_others():
+    assert K.state_layout(64, 128, 64) == (32, 128, 128)
+    assert K.state_layout(32, 256, 128) == (32, 256, 128)
+    assert K.state_layout(4, 32, 16) == (4, 32, 16)
+    S = jnp.arange(2 * 4 * 3 * 64, dtype=jnp.float32).reshape(2, 4, 3, 64)
+    kept = K.pack_state(S)
+    assert kept.shape == (2, 2, 3, 128)
+    # pair j holds head 2j on lanes 0-63 and head 2j + 1 on lanes 64-127
+    np.testing.assert_array_equal(kept[1, 1, :, :64], S[1, 2])
+    np.testing.assert_array_equal(kept[1, 1, :, 64:], S[1, 3])
+    np.testing.assert_array_equal(K.unpack_state(kept, 64), S)
+    wide = jnp.ones((4, 32, 128))
+    assert K.pack_state(wide) is wide and K.unpack_state(wide, 128) is wide
+
+
+@pytest.mark.parametrize("shape,T,length", [
+    ("small", 32, None), ("small", 32, 17), ("small", 16, 5),
+    ("nemotron_h", 256, 200)],
+    ids=["small_whole", "small_one_past", "small_short", "published"])
+def test_the_paired_scan_equals_the_recurrence(shape, T, length):
+    H, P, G, N = PAIRED[shape]
+    chunk = 128 if shape == "nemotron_h" else 8
+    args = _inputs(T, H, P, G, N, seed=T + H, length=length)
+    before = stats.snapshot().get("ssm.ssd_fallbacks", 0)
+    fn = lambda *a: K.ssd_scan(*a, chunk=chunk)     # noqa: E731
+    y, kept = jax.jit(fn)(*args)
+    assert stats.snapshot().get("ssm.ssd_fallbacks", 0) == before
+    assert kept.shape == (H // 2, N, 2 * P)
+    names = [e.params["name"] for e in jax.make_jaxpr(fn)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert names == ["ssd64_chunk_scan"]
+    y0, S0 = K.ssd_scan_xla(*args)
+    n = T if length is None else length
+    scale = float(jnp.abs(y0[:n]).max())
+    np.testing.assert_allclose(y[:n], y0[:n], rtol=1e-4, atol=1e-4 * scale)
+    np.testing.assert_allclose(K.unpack_state(kept, P), S0, rtol=1e-4,
+                               atol=1e-4 * float(jnp.abs(S0).max()))
+
+
+def test_a_pair_never_straddles_two_groups():
+    """Three heads a group cannot be paired inside it: the scan and the step
+    fall back (and keep the paired layout all the same)."""
+    H, P, G, N = 6, 64, 2, 16
+    args = _inputs(16, H, P, G, N, seed=5)
+    before = stats.snapshot().get("ssm.ssd_fallbacks", 0)
+    y, kept = K.ssd_scan(*args, chunk=8)
+    assert stats.snapshot().get("ssm.ssd_fallbacks", 0) == before + 1
+    y0, S0 = K.ssd_scan_xla(*args)
+    np.testing.assert_array_equal(y, y0)
+    np.testing.assert_array_equal(K.unpack_state(kept, P), S0)
+
+
+@pytest.mark.parametrize("shape", sorted(PAIRED))
+def test_the_paired_step_equals_the_xla_form_in_place(shape):
+    H, P, G, N = PAIRED[shape]
+    r = np.random.default_rng(11)
+    slots = 3
+    S = jnp.asarray(r.standard_normal((2, slots, H, N, P)), jnp.float32)
+    x = jnp.asarray(r.standard_normal((slots, H, P)), jnp.float32)
+    dt = jnp.asarray(r.uniform(1e-3, 0.2, (slots, H)), jnp.float32)
+    A = -jnp.asarray(r.uniform(1, 8, (H,)), jnp.float32)
+    B = jnp.asarray(r.standard_normal((slots, G, N)), jnp.float32)
+    C = jnp.asarray(r.standard_normal((slots, G, N)), jnp.float32)
+    kept = K.pack_state(S)
+    fn = lambda *a: K.ssd_state_step(a[0], 1, *a[1:])   # noqa: E731
+    before = stats.snapshot().get("ssm.ssd_fallbacks", 0)
+    y, new = jax.jit(fn)(kept, x, dt, A, B, C)
+    assert stats.snapshot().get("ssm.ssd_fallbacks", 0) == before
+    names = [e.params["name"] for e in jax.make_jaxpr(fn)(
+        kept, x, dt, A, B, C).eqns if e.primitive.name == "pallas_call"]
+    assert names == ["ssd64_state_step"]
+    y0, new0 = K.ssd_step_xla(S[1], x, dt, A, B, C)
+    np.testing.assert_allclose(y, y0, rtol=1e-5, atol=1e-4)
+    np.testing.assert_allclose(K.unpack_state(new[1], P), new0, rtol=1e-5,
+                               atol=1e-5)
+    np.testing.assert_array_equal(new[0], kept[0])
+
+
+def test_a_prompt_s_paired_rows_are_handed_to_the_step():
+    H, P, G, N = PAIRED["small"]
+    T, P0 = 24, 16
+    x, dt, A, B, C = _inputs(T, H, P, G, N, seed=9)
+    y_all, S_all = K.ssd_scan_xla(x, dt, A, B, C)
+    _, kept = K.ssd_scan(x[:P0], dt[:P0], A, B[:P0], C[:P0], chunk=8)
+    states = jnp.zeros((1, 2) + kept.shape, jnp.float32).at[0, 1].set(kept)
+    for t in range(P0, T):
+        def rows(a):
+            return jnp.broadcast_to(a[t][None], (2,) + a.shape[1:])
+        y, states = K.ssd_state_step(states, 0, rows(x), rows(dt), A,
+                                     rows(B), rows(C))
+        np.testing.assert_allclose(y[1], y_all[t], rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(K.unpack_state(states[0, 1], P), S_all,
+                               rtol=1e-4, atol=1e-4)

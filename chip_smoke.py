@@ -33,6 +33,11 @@ checks what comes out by the repo's own means:
                  ``jnp.matmul`` + ``logsumexp`` at the train cells' head,
                  24,576 x 512 x 37,000, and the kernel-alone table: ms a
                  call by tile shape beside XLA's two operations.
+- latent_walk:   the latent decode walk (``kernels/mla.py``'s
+                 ``mla_paged_decode_attn``) against its XLA lowering at the
+                 three latent cells' shapes, and the kernel-alone table: ms
+                 a call as it is and with the copies taken out, beside the
+                 bytes' time at the HBM peak.
 - four_chip:     the trainer program through ``ParallelExecutor`` on a
                  dp=2 x mp=2 mesh, then one ZeRO step on dp=4 — only
                  where JAX sees >= 4 devices.
@@ -1270,6 +1275,20 @@ def phase_group_flash(on_chip=True, tokens=12288, window=4096, block=1024,
     return out
 
 
+def ms_a_call_between(run, calls) -> float:
+    """ms a call of ``run(n)`` — N calls inside ONE program, blocking — as
+    the difference of two lengths ``calls`` (the best of two timings each),
+    because a dispatch costs 0.6 ms (PR 52)."""
+    run(1)
+    best = {}
+    for n in calls * 2:
+        t0 = time.perf_counter()
+        run(n)
+        best[n] = min(best.get(n, np.inf), time.perf_counter() - t0)
+    lo, hi = calls
+    return 1e3 * (best[hi] - best[lo]) / (hi - lo)
+
+
 # ---------------------------------------------------------------------------
 # phase 3i: the output projection that keeps the loss's log-sum-exp
 # ---------------------------------------------------------------------------
@@ -1350,14 +1369,8 @@ def phase_proj_xent(on_chip=True, batch=96, seq=256, d=512, vocab=37000,
                 return acc + lse.reshape(-1)[0] + logits.reshape(-1)[0]
             return lax.fori_loop(0, n, step, jnp.float32(0))
 
-        loop(x, w, 1).block_until_ready()
-        best = {}
-        for n in calls * 2:
-            t0 = time.perf_counter()
-            loop(x, w, n).block_until_ready()
-            best[n] = min(best.get(n, np.inf), time.perf_counter() - t0)
-        lo, hi = calls
-        return 1e3 * (best[hi] - best[lo]) / (hi - lo)
+        return ms_a_call_between(
+            lambda n: loop(x, w, n).block_until_ready(), calls)
 
     table = {"xla: matmul + logsumexp": ms_a_call(xent.proj_xent_xla),
              "xla: matmul alone": ms_a_call(lambda x, w: (
@@ -1368,6 +1381,112 @@ def phase_proj_xent(on_chip=True, batch=96, seq=256, d=512, vocab=37000,
     # what the device was handed for each call, by the shapes
     out["gflop_a_call"] = 2e-9 * rows * d * vocab
     out["logits_gb"] = 4e-9 * rows * vocab
+    return out
+
+
+# ---------------------------------------------------------------------------
+# phase 3j: the latent decode walk alone, at the three latent cells' shapes
+# ---------------------------------------------------------------------------
+
+def phase_latent_walk(on_chip=True, slots=64, heads=(32, 16), rank=512,
+                      rope=64, block=16, layers=2,
+                      pools=(("7k", 1088, 7268), ("2k", 512, 2300)),
+                      calls=(8, 40), tol=2e-2):
+    """``kernels/mla.py decode_attention`` (``mla_paged_decode_attn``: one
+    grid step a slot, live blocks only, the next fetch in flight) alone, at
+    the three latent cells' shapes: ``slots`` absorbed queries of 32
+    (``kl48b_longdoc_sat``, ``xg29b_doc_sat``) and 16 (``dsv2l_doc_sat``)
+    heads against a pool of 640-lane bf16 rows; a pool is (name, table
+    blocks, mean live tokens a slot), the contexts log-normal about the mean
+    as the cells' prompts are, each slot's blocks scattered over the pool.
+    First against ``decode_attention_xla`` ON the device (every head within
+    ``tol`` of the result's scale: the kernel rounds its weights to the
+    pool's bf16 for the value product, the lowering keeps float32).  Then ms
+    a call (``ms_a_call_between``, the layers in turn) as it is and with the
+    copies taken out (the schedule's ``start`` / ``wait`` made empty: what
+    the scalar core and the products cost with every fetch hidden), beside
+    the bytes' time at the HBM peak; a tree whose kernel has no schedule to
+    empty (before PR 62) is timed as it is.  The table in the kernel's
+    docstring is this phase's."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+    from paddle_tpu.kernels import diffattn, mla
+
+    W = mla.row_width(rank, rope)
+    rng = np.random.RandomState(62)
+    blocks = 1 + slots * max(mb for _, mb, _ in pools)
+    pool = jax.random.normal(jax.random.PRNGKey(62),
+                             (layers, blocks, block, W), jnp.bfloat16)
+    scale = (rank // 4 + rope) ** -0.5
+
+    def case(H, mb, mean):
+        cl = np.clip(rng.lognormal(np.log(mean) - 0.18, 0.6, slots),
+                     1, mb * block).astype(np.int32)
+        bt = (1 + rng.permutation(slots * mb)).reshape(slots, mb)
+        q = np.zeros((slots, H, W), np.float32)
+        q[..., :rank + rope] = rng.randn(slots, H, rank + rope) * 0.5
+        return (jnp.asarray(q, jnp.bfloat16), jnp.asarray(bt, jnp.int32),
+                jnp.asarray(cl))
+
+    def walk(q, pool, bt, cl, layer):
+        return mla.decode_attention(q, pool, bt, cl, layer, rank, scale)
+
+    kernel = jax.jit(walk)
+    lowering = jax.jit(lambda q, pool, bt, cl: mla.decode_attention_xla(
+        q, pool, bt, cl, 1, rank, scale))
+
+    def ms_a_call(q, bt, cl):
+        @jax.jit
+        def loop(q, pool, bt, cl, n):
+            def step(i, acc):
+                out = walk(q + (acc * 1e-30).astype(q.dtype), pool, bt, cl,
+                           i % layers)
+                return acc + out[0, 0, 0]
+            return lax.fori_loop(0, n, step, jnp.float32(0))
+
+        return ms_a_call_between(
+            lambda n: loop(q, pool, bt, cl, n).block_until_ready(), calls)
+
+    def copies_taken_out(*args, **kwargs):
+        live_blocks, *_ = schedule(*args, **kwargs)
+        return (live_blocks,) + (lambda *a: None,) * 3
+
+    schedule = getattr(diffattn, "walk_schedule", None)
+    before, out = counters(), {}
+    for H in heads:
+        for name, mb, mean in pools:
+            q, bt, cl = case(H, mb, mean)
+            if on_chip:
+                text = kernel.lower(q, pool, bt, cl, 1).compile().as_text()
+                check(MOSAIC_CALL in text and "mla_paged_decode_attn" in text,
+                      "no Mosaic call named mla_paged_decode_attn")
+            got = np.asarray(kernel(q, pool, bt, cl, 1))
+            want = np.asarray(lowering(q, pool, bt, cl))
+            live = int(np.asarray(cl).sum())
+            row = {"live_tokens": live, "max_diff": float(
+                np.abs(got - want).max()), "scale": float(np.abs(want).max())}
+            check(np.isfinite(got).all() and row["scale"] > 0
+                  and row["max_diff"] <= tol * row["scale"],
+                  f"the walk differs from its lowering by "
+                  f"{row['max_diff']:.4g} of a scale of {row['scale']:.4g} "
+                  f"at {H} heads over the {name} pool")
+            row["ms_a_call"] = round(ms_a_call(q, bt, cl), 4)
+            if schedule is not None:
+                diffattn.walk_schedule = copies_taken_out
+                mla._walk_call.clear_cache()
+                try:
+                    row["ms_copies_taken_out"] = round(
+                        ms_a_call(q, bt, cl), 4)
+                finally:
+                    diffattn.walk_schedule = schedule
+                    mla._walk_call.clear_cache()
+            # the roofline's count: rank + rope numbers a live token
+            row["ms_at_hbm_peak"] = round(
+                1e3 * live * (rank + rope) * 2 / 819e9, 4)
+            out[f"{H}_heads_{name}"] = row
+    out["fallbacks"] = counter_delta(before, "mla.decode_attn_fallbacks")
+    check(out["fallbacks"] == 0, "the latent walk fell back to XLA")
     return out
 
 
@@ -1536,6 +1655,7 @@ def main() -> int:
     run_phase(report, "expert_plan", phase_expert_plan)
     run_phase(report, "group_flash", phase_group_flash)
     run_phase(report, "proj_xent", phase_proj_xent)
+    run_phase(report, "latent_walk", phase_latent_walk)
     if len(devices) >= 4 and trainer is not None:
         run_phase(report, "four_chip", phase_four_chip, place, devices,
                   trainer["first_loss"])

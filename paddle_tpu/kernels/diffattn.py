@@ -96,51 +96,44 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer,
     return jnp.einsum("sgrcl,slgv->sgrcv", p, v).reshape(S, nh, 2, pair)
 
 
-def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
-                   half_scr, *, bs: int, chunk: int, max_blocks: int,
-                   n_kv: int, kw: int):
-    """Grid (S,): one grid step a slot.  The pool stays in HBM, whole
-    (``memory_space=pl.ANY``); a slot's LIVE blocks — ``ceil(context_len /
-    bs)`` of its table, one for an idle slot — are fetched in chunks of
-    ``chunk`` blocks into a double buffer, block ``b`` of a chunk to rows
-    ``b·bs`` of ``buf[half]``, the layer (``ly_ref``, prefetched with the
-    tables) in the copy's source index.  A table entry past the frontier is
-    not copied, none past the table is read.
+def walk_schedule(bt_ref, cl_ref, ly_ref, pool_ref, buf, sem, *, bs: int,
+                  chunk: int, max_blocks: int,
+                  whole_chunks_unrolled: bool = False):
+    """The copy schedule of a paged decode walk on a grid of slots — what
+    :func:`_decode_kernel` here and ``kernels/mla.py``'s close over; the
+    chunk's arithmetic is each kernel's own.  The pool stays in HBM, whole
+    (``memory_space=pl.ANY``); ``buf`` is the double buffer ``[2, chunk·bs,
+    width]``, ``sem`` one DMA semaphore a half, ``ly_ref`` the pool's layer
+    (prefetched with the tables; in every copy's source index).  Returns
 
-    The next fetch is always in flight: before the kernel waits for a chunk
-    it starts the slot's next one into the other half, and on a slot's LAST
-    chunk the next slot's first (buffer, semaphores and ``half_scr`` persist
-    over the sequential grid).  ``half_scr`` carries which half that was from
-    one grid step to the next, so every start is waited for exactly once, by
-    the slot that computes it.
+    - ``live_blocks(slot)``: ``ceil(context_len / bs)`` of the slot's table,
+      one for an idle slot — a table entry past the frontier is not copied,
+      none past the table is read;
+    - ``start(slot, c, half)``: start the copies of chunk ``c`` of ``slot``,
+      its live blocks, block ``b`` to rows ``b·bs`` of ``buf[half]``;
+    - ``wait(n, half)``: wait for the ``n`` blocks started into ``half``;
+    - ``start_ahead(s, c, n_chunks, half)``: the look-ahead rule — while
+      chunk ``c`` of slot ``s`` (in ``half``) is waited for and computed, the
+      slot's next chunk is in flight into the other half, and on its LAST
+      chunk the next slot's first (none after the last slot's last).
 
     What costs here is the scalar core's work a copy, which no vector work
     hides across a loop's edge (PERF.md §6, PR 37): a chunk's copies are
     started ``_COPY_UNROLL`` a trip and signal ONE semaphore a half, which
     counts bytes — the wait is one descriptor a set bit of the chunk's block
-    count, six at most, not one a block.
-
-    A chunk is computed whole, as one K/V head's key tile against its QR
-    query rows on the MXU; the running max, sum and accumulator of the online
-    softmax are the chunk loop's carry (kept in scratch they serialised the
-    heads).  Rows of a ragged last chunk past the frontier's block hold what
-    an earlier slot left there: their scores are masked by position, and
-    their value lanes are zeroed before the product (``0 x NaN`` is NaN)."""
-    s = pl.program_id(0)
+    count, six at most, not one a block.  ``whole_chunks_unrolled`` (the
+    latent pool's 20 KB blocks, PERF.md §6, PR 62): a chunk whose every block
+    is live is started as straight-line code under one test of the half, so a
+    copy's place in the buffer is a constant — 11–13 instruction bundles a
+    copy where the loop's take 26 (the v5e's compiler, the chip described and
+    not attached), 14.5 ns where they took 22 (on the chip)."""
     n_slots = pl.num_programs(0)
     layer = ly_ref[0]
-    span = chunk * bs
-    QR = q_ref.shape[2]
 
     def live_blocks(slot):
         return jnp.clip((cl_ref[slot] + bs - 1) // bs, 1, max_blocks)
 
-    def start(slot, c, half):
-        """Start the copies of chunk ``c`` of ``slot``: its live blocks,
-        each to its own place of ``half``."""
-        first = c * chunk
-        n = jnp.minimum(chunk, live_blocks(slot) - first)
-
+    def start_some(slot, first, n, half):
         def one(b):
             pltpu.make_async_copy(
                 pool_ref.at[layer, bt_ref[slot, first + b]],
@@ -160,8 +153,33 @@ def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
         lax.fori_loop(0, whole, group, 0)
         lax.fori_loop(whole * _COPY_UNROLL, n, single, 0)
 
+    def start_whole(slot, first, half):
+        """A whole chunk's copies as straight-line code, a copy's place in
+        the buffer a constant: the half is decided once, not a copy."""
+        for h in range(2):
+            @pl.when(half == h)
+            def _(h=h):
+                for b in range(chunk):
+                    pltpu.make_async_copy(
+                        pool_ref.at[layer, bt_ref[slot, first + b]],
+                        buf.at[h, pl.ds(b * bs, bs)], sem.at[h]).start()
+
+    def start(slot, c, half):
+        first = c * chunk
+        n = jnp.minimum(chunk, live_blocks(slot) - first)
+        if not whole_chunks_unrolled:
+            start_some(slot, first, n, half)
+            return
+
+        @pl.when(n == chunk)
+        def _whole():
+            start_whole(slot, first, half)
+
+        @pl.when(n < chunk)
+        def _some():
+            start_some(slot, first, n, half)
+
     def wait(n, half):
-        """Wait for the ``n`` blocks started into ``half``."""
         k = 1
         while k <= chunk:
             @pl.when((n & k) != 0)
@@ -169,6 +187,44 @@ def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
                 part = buf.at[half, pl.ds(0, k * bs)]
                 pltpu.make_async_copy(part, part, sem.at[half]).wait()
             k *= 2
+
+    def start_ahead(s, c, n_chunks, half):
+        last = c + 1 == n_chunks
+
+        @pl.when(jnp.logical_or(jnp.logical_not(last), s + 1 < n_slots))
+        def _ahead():
+            start(jnp.where(last, jnp.minimum(s + 1, n_slots - 1), s),
+                  jnp.where(last, 0, c + 1), 1 - half)
+
+    return live_blocks, start, wait, start_ahead
+
+
+def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
+                   half_scr, *, bs: int, chunk: int, max_blocks: int,
+                   n_kv: int, kw: int):
+    """Grid (S,): one grid step a slot, its LIVE blocks fetched in chunks of
+    ``chunk`` blocks into a double buffer by :func:`walk_schedule`, the layer
+    (``ly_ref``, prefetched with the tables) in the copy's source index.
+
+    The next fetch is always in flight: before the kernel waits for a chunk
+    it starts the slot's next one into the other half, and on a slot's LAST
+    chunk the next slot's first (buffer, semaphores and ``half_scr`` persist
+    over the sequential grid).  ``half_scr`` carries which half that was from
+    one grid step to the next, so every start is waited for exactly once, by
+    the slot that computes it.
+
+    A chunk is computed whole, as one K/V head's key tile against its QR
+    query rows on the MXU; the running max, sum and accumulator of the online
+    softmax are the chunk loop's carry (kept in scratch they serialised the
+    heads).  Rows of a ragged last chunk past the frontier's block hold what
+    an earlier slot left there: their scores are masked by position, and
+    their value lanes are zeroed before the product (``0 x NaN`` is NaN)."""
+    s = pl.program_id(0)
+    span = chunk * bs
+    QR = q_ref.shape[2]
+    live_blocks, start, wait, start_ahead = walk_schedule(
+        bt_ref, cl_ref, ly_ref, pool_ref, buf, sem, bs=bs, chunk=chunk,
+        max_blocks=max_blocks)
 
     @pl.when(s == 0)
     def _first():
@@ -182,15 +238,7 @@ def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
 
     def chunk_step(c, carry):
         half = (first_half + c) % 2
-        # what is computed next: this slot's next chunk, or after its last
-        # the next slot's first (none after the last slot's last)
-        last = c + 1 == n_chunks
-
-        @pl.when(jnp.logical_or(jnp.logical_not(last), s + 1 < n_slots))
-        def _ahead():
-            start(jnp.where(last, jnp.minimum(s + 1, n_slots - 1), s),
-                  jnp.where(last, 0, c + 1), 1 - half)
-
+        start_ahead(s, c, n_chunks, half)
         blocks = jnp.minimum(chunk, n_live - c * chunk)
         wait(blocks, half)
 
@@ -446,5 +494,6 @@ def prefill_attention(q, rows, n_kv: int, window=None):
 
 
 __all__ = ["decode_attention", "decode_attention_xla", "paged_walk",
-           "prefill_attention", "prefill_attention_xla", "row_attention",
+           "walk_schedule", "prefill_attention", "prefill_attention_xla",
+           "row_attention",
            "flash_tiles", "visible", "LANE"]

@@ -11,13 +11,50 @@ has no constant to give).
 - :func:`decode_attention` — the absorbed path.  Every head's query is
   already in the row's coordinates (``[W_kvb,h^T q_nope | q_pe | 0]``), so a
   score is one dot with the cached row and the value is the row's first
-  ``rank`` lanes: 16 query heads over ONE shared row, nothing expanded.  The
-  Pallas kernel (``mla_paged_decode_attn``) walks a slot's context in chunks
-  of ``_CHUNK_BLOCKS`` blocks; a chunk's blocks are fetched from HBM by
-  explicit async copies (a 16-token block is 20 KB: one grid step a block
-  would cost more in step overhead than in bytes), chunks past a slot's
-  context are skipped.  The XLA fallback gathers the slot's whole table and
-  counts into ``mla.decode_attn_fallbacks``.
+  ``rank`` lanes: 16 or 32 query heads over ONE shared row, nothing expanded.
+  The Pallas kernel (``mla_paged_decode_attn``) has the walk of
+  ``kernels/diffattn.py`` (its ``walk_schedule``, shared; the chunk's
+  arithmetic is this kernel's own): a grid of slots, one grid step a slot,
+  which walks the slot's LIVE blocks — ``ceil(context / bs)`` of its table,
+  read from the prefetched lengths, so nothing past the frontier is copied,
+  nothing past the table read and no table padded — in chunks of
+  ``_CHUNK_BLOCKS`` blocks, fetched block by block (a 16-token block is 20
+  KB) by explicit async copies into a double buffer.  The next fetch is
+  always in flight: a slot's next chunk, or on its last chunk the next
+  slot's first, is started before the current one is waited for.  The
+  running maximum, sum and accumulator of the online softmax are the chunk
+  loop's carry.  The XLA fallback gathers the slot's whole table and counts
+  into ``mla.decode_attn_fallbacks``.
+
+  The kernel alone, ms a call of 64 slots and one layer on a v5e (PERF.md §6,
+  PR 62; ``chip_smoke.py phase_latent_walk``: N calls in one program, the
+  difference of two lengths; contexts log-normal about the mean; "at the
+  peak" is 576 numbers a live token over 819 GB/s, what
+  ``mla_decode_attn_roofline`` counts — the 640-lane row's ceiling is 90%):
+
+  ==========================================  =======  =======  =======  =======
+  heads, mean live tokens a slot              32, 7 k  32, 2 k  16, 7 k  16, 2 k
+  ==========================================  =======  =======  =======  =======
+  at the HBM peak                               0.572    0.187    0.658    0.203
+  (i) before PR 62: grid (slots, table's
+  chunks), every chunk's 32 copies started
+  and all waited for, the state in scratch      1.795    0.655    1.975    0.672
+  the new schedule, chunks of 32, every
+  copy started from the loop                    1.087    0.387    1.206    0.402
+  ... whole chunks' copies straight-line        0.890    0.330    0.991    0.341
+  (ii) as it is: ... and chunks of 64           0.774    0.305    0.866    0.317
+  (iii) as it is, the copies taken out          0.415    0.173    0.445    0.170
+  chunks of 128                                 0.795    0.338    0.891    0.362
+  ==========================================  =======  =======  =======  =======
+
+  (ii) is (iii) plus 14.5 ns a block: the copies' transfer is hidden, what a
+  call pays is the products (vector loads of the chunk, twice: as keys and
+  as values; 16 and 32 heads cost the same) PLUS the scalar core's work to
+  start each copy, which the products do not hide: put into one block of
+  straight-line code with the products (the halves two allocations, so that
+  nothing orders them), the v5e's compiler still schedules the 64 starts
+  behind the products, not among them (its bundles read off a compile for
+  the described chip; PERF.md §6, PR 62).
 - :func:`prefill_attention` — the expanded path of a prompt: causal flash
   attention with 192-wide scores and 128-wide values
   (``kernels/attention.py``'s flash forward, which takes a narrower ``v``),
@@ -35,13 +72,18 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import attention as _attn
+from . import diffattn as _diff
 from ..observability import stats as _obs_stats
 from ..platform import pallas_interpret
 
 NEG_INF = _attn.NEG_INF
 LANE = 128
-# blocks a grid step: 32 x 16 tokens = 512 rows of 640 bf16 lanes, 640 KB
-_CHUNK_BLOCKS = 32
+# blocks a chunk: 64 x 16 tokens = 1,024 rows of 640 bf16 lanes, 1.3 MB, twice
+# (the double buffer).  By the table above: at 32 the products cost a quarter
+# more a row (0.516 against 0.415 ms with the copies out); at 128 a slot's
+# ragged last chunk, computed whole and started from the loop, costs more
+# than the products give back
+_CHUNK_BLOCKS = 64
 
 
 def row_width(rank: int, rope: int) -> int:
@@ -66,83 +108,93 @@ def decode_attention_xla(q, pool, block_tables, context_lens, layer,
                       rows[..., :rank].astype(jnp.float32))
 
 
-def _decode_kernel(bt_ref, cl_ref, layer_ref, q_ref, pool_ref, o_ref, buf,
-                   sem, m_scr, l_scr, acc_scr, *, bs: int, chunk: int,
-                   n_chunks: int, rank: int):
-    layer = layer_ref[0]
+def _decode_kernel(bt_ref, cl_ref, ly_ref, q_ref, pool_ref, o_ref, buf, sem,
+                   half_scr, *, bs: int, chunk: int, max_blocks: int,
+                   rank: int):
+    """Grid (S,): one grid step a slot, its LIVE blocks fetched in chunks of
+    ``chunk`` blocks into the double buffer by ``diffattn.walk_schedule`` —
+    the slot's next chunk, or on its last the next slot's first, started
+    before the current one is waited for (``half_scr`` carries the parity
+    over the sequential grid).  A chunk is computed whole: every head's
+    query against the chunk's rows, which are key and value at once.  The
+    running max, sum and accumulator are the chunk loop's carry.  Rows of a
+    ragged last chunk past the frontier's block hold what an earlier slot
+    left there: their scores are masked by position, and the rows are zeroed
+    before the value product (``0 x NaN`` is NaN)."""
     s = pl.program_id(0)
-    j = pl.program_id(1)
+    span = chunk * bs
+    H, W = q_ref.shape[1], q_ref.shape[2]
+    live_blocks, start, wait, start_ahead = _diff.walk_schedule(
+        bt_ref, cl_ref, ly_ref, pool_ref, buf, sem, bs=bs, chunk=chunk,
+        max_blocks=max_blocks, whole_chunks_unrolled=True)
+
+    @pl.when(s == 0)
+    def _first():
+        half_scr[0] = 0
+        start(0, 0, 0)
+
+    first_half = half_scr[0]
     cl = cl_ref[s]
-    live = (cl + chunk * bs - 1) // (chunk * bs)
+    n_live = live_blocks(s)
+    n_chunks = (n_live + chunk - 1) // chunk
+    q = q_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def chunk_step(c, carry):
+        m, l, acc = carry
+        half = (first_half + c) % 2
+        start_ahead(s, c, n_chunks, half)
+        blocks = jnp.minimum(chunk, n_live - c * chunk)
+        wait(blocks, half)
 
-    @pl.when(j < live)
-    def _chunk():
-        copies = []
-        for b in range(chunk):
-            cp = pltpu.make_async_copy(
-                pool_ref.at[layer, bt_ref[s, j * chunk + b]],
-                buf.at[pl.ds(b * bs, bs)], sem.at[b])
-            cp.start()
-            copies.append(cp)
-        for cp in copies:
-            cp.wait()
-        rows = buf[:]                                   # [chunk * bs, W]
-        sc = lax.dot_general(q_ref[0], rows, (((1,), (1,)), ((), ())),
+        def zero(b, carry):
+            buf[half, pl.ds(pl.multiple_of(b * bs, bs), bs)] = jnp.zeros(
+                (bs, W), buf.dtype)
+            return carry
+
+        lax.fori_loop(blocks, chunk, zero, 0)
+        rows = buf[half]                                # [span, W]
+        sc = lax.dot_general(q, rows, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
-        pos = j * chunk * bs + lax.broadcasted_iota(jnp.int32, sc.shape, 1)
+        pos = c * span + lax.broadcasted_iota(jnp.int32, (H, span), 1)
         sc = jnp.where(pos < cl, sc, NEG_INF)
-        m = m_scr[:]
         m_new = jnp.maximum(m, jnp.max(sc, axis=-1, keepdims=True))
         p = jnp.exp(sc - m_new)
         alpha = jnp.exp(m - m_new)
-        m_scr[:] = m_new
-        l_scr[:] = l_scr[:] * alpha + jnp.sum(p, axis=-1, keepdims=True)
-        acc_scr[:] = acc_scr[:] * alpha + jnp.dot(
-            p.astype(rows.dtype), rows[:, :rank],
-            preferred_element_type=jnp.float32)
+        return (m_new, l * alpha + jnp.sum(p, axis=-1, keepdims=True),
+                acc * alpha + jnp.dot(p.astype(rows.dtype), rows[:, :rank],
+                                      preferred_element_type=jnp.float32))
 
-    @pl.when(j == n_chunks - 1)
-    def _finish():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)
-                    ).astype(o_ref.dtype)
+    _, l, acc = lax.fori_loop(0, n_chunks, chunk_step, (
+        jnp.full((H, 1), NEG_INF, jnp.float32),
+        jnp.zeros((H, 1), jnp.float32),
+        jnp.zeros((H, rank), jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    half_scr[0] = (first_half + n_chunks) % 2
 
 
-def _decode_pallas(q, pool, block_tables, context_lens, layer, rank,
-                   interpret):
+@functools.partial(jax.jit, static_argnames=("rank", "chunk", "interpret"))
+def _walk_call(q, pool, bt, cl, layer, *, rank, chunk, interpret):
+    """``layer`` is an int32 scalar, prefetched with the tables, and the
+    call is a jitted function of its own: the scanned layers and an unscanned
+    one beside them share ONE trace and ONE lowering of the kernel."""
     S, H, W = q.shape
     bs = pool.shape[2]
-    MB = block_tables.shape[1]
-    chunk = min(_CHUNK_BLOCKS, MB)
-    n_chunks = -(-MB // chunk)
-    bt = block_tables.astype(jnp.int32)
-    if n_chunks * chunk != MB:      # a ragged last chunk reads trash block 0
-        bt = jnp.pad(bt, ((0, 0), (0, n_chunks * chunk - MB)))
-    kernel = functools.partial(_decode_kernel, bs=bs, chunk=chunk,
-                               n_chunks=n_chunks, rank=rank)
     return pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, bs=bs, chunk=chunk,
+                          max_blocks=bt.shape[1], rank=rank),
         name="mla_paged_decode_attn",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=3,    # tables, lengths, the pool's layer
-            grid=(S, n_chunks),
-            in_specs=[pl.BlockSpec((1, H, W), lambda s, j, *_: (s, 0, 0)),
+            grid=(S,),
+            in_specs=[pl.BlockSpec((1, H, W), lambda s, *_: (s, 0, 0)),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((1, H, rank), lambda s, j, *_: (s, 0, 0)),
-            scratch_shapes=[pltpu.VMEM((chunk * bs, W), pool.dtype),
-                            pltpu.SemaphoreType.DMA((chunk,)),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, 1), jnp.float32),
-                            pltpu.VMEM((H, rank), jnp.float32)]),
+            out_specs=pl.BlockSpec((1, H, rank), lambda s, *_: (s, 0, 0)),
+            scratch_shapes=[pltpu.VMEM((2, chunk * bs, W), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,)),
+                            pltpu.SMEM((1,), jnp.int32)]),
         out_shape=jax.ShapeDtypeStruct((S, H, rank), jnp.float32),
         interpret=interpret,
-    )(bt, context_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q, pool)
+    )(bt, cl, layer.reshape(1), q, pool)
 
 
 def decode_attention(q, pool, block_tables, context_lens, layer, rank: int,
@@ -162,8 +214,11 @@ def decode_attention(q, pool, block_tables, context_lens, layer, rank: int,
         interpret = pallas_interpret()
     # the scale rides the query: [S, H, W] is small beside the scores
     qs = (q.astype(jnp.float32) * sm_scale).astype(pool.dtype)
-    return _decode_pallas(qs, pool, block_tables, context_lens, layer, rank,
-                          interpret)
+    return _walk_call(qs, pool, block_tables.astype(jnp.int32),
+                      context_lens.astype(jnp.int32),
+                      jnp.asarray(layer, jnp.int32), rank=rank,
+                      chunk=min(_CHUNK_BLOCKS, block_tables.shape[1]),
+                      interpret=interpret)
 
 
 def prefill_attention(q, k, v, sm_scale: float, impl=None):

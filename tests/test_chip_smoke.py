@@ -226,6 +226,21 @@ def test_proj_xent_phase_tiny():
     assert out["gflop_a_call"] == pytest.approx(2e-9 * 256 * 128 * 300)
 
 
+def test_latent_walk_phase_tiny():
+    out = chip_smoke.phase_latent_walk(
+        on_chip=False, slots=3, heads=(4,), rank=32, rope=8, block=4,
+        pools=(("long", 40, 90), ("short", 6, 10)), calls=(1, 2))
+    assert sorted(out) == ["4_heads_long", "4_heads_short", "fallbacks"]
+    assert out["fallbacks"] == 0
+    for name in ("4_heads_long", "4_heads_short"):
+        row = out[name]
+        # the kernel rounds a chunk's weights to the pool's bf16
+        assert row["max_diff"] <= 1e-2 * row["scale"]
+        assert row["ms_at_hbm_peak"] == pytest.approx(
+            1e3 * row["live_tokens"] * 40 * 2 / 819e9, abs=1e-4)
+        assert {"ms_a_call", "ms_copies_taken_out"} <= set(row)
+
+
 def test_four_chip_phase_tiny():
     """dp=2 x mp=2 and ZeRO dp=4 on four devices of the CPU mesh, loss
     parity against the one-device run of the same program."""

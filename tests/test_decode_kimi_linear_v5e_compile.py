@@ -253,11 +253,13 @@ def test_the_step_s_kernels_step_by_slot_and_the_layer_is_a_scalar(one_chip,
     assert upd == (S, 2) and len(by_name["kda_state_step"]) == 4
     assert all(dict(e.params["input_output_aliases"]) == {6: 1}
                for e in by_name["kda_state_step"])
-    # the latent walk: by slot and chunk of 32 blocks, three prefetched
-    # scalars (tables, lengths AND the layer)
+    # the latent walk: a grid step a slot (its live blocks in chunks of 64
+    # inside it, into a double buffer), three prefetched scalars (tables,
+    # lengths AND the layer)
     (walk,) = by_name["mla_paged_decode_attn"]
-    assert tuple(walk.params["grid_mapping"].grid) == (S, MB // 32)
+    assert tuple(walk.params["grid_mapping"].grid) == (S,)
     assert walk.params["grid_mapping"].num_index_operands == 3
+    assert walk.params["grid_mapping"].num_scratch_operands == 3
     # 64 tokens x 8 choices, of which any may be held: 16-row tiles
     assert tuple(by_name["moe_grouped_swiglu"][0].params[
         "grid_mapping"].grid) == (EK.plan_rows(S, 8, 64, 16) // 16,)

@@ -1,5 +1,6 @@
 """The latent pool stays in place, and Mosaic accepts the three new kernels
-at DeepSeek-V2-Lite's widths — checked with the TPU's own compiler for a v5e
+(and, since PR 62, the decode walk on its grid of slots) at
+DeepSeek-V2-Lite's widths — checked with the TPU's own compiler for a v5e
 that is described and not attached (no chip, no chip time), as
 ``test_decode_pool_v5e_compile.py`` does for the K/V pools.
 
@@ -58,7 +59,7 @@ def mosaic(monkeypatch):
         yield
 
 
-def _shapes(one_chip, bucket):
+def _shapes(one_chip, bucket, MB=MB):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
@@ -109,6 +110,23 @@ def test_latent_pool_is_neither_copied_nor_relaid(one_chip, mosaic, bucket):
     calls = text.count("tpu_custom_call")
     # a layer's attention kernel, and the expert layer's grouped SwiGLU
     assert calls == CFG.num_hidden_layers + 1, calls
+
+
+@pytest.mark.parametrize("blocks", [MB, MB + 8], ids=[
+    "the_cell_s_table", "a_table_that_is_no_multiple_of_the_chunk"])
+def test_the_step_holds_one_latent_walk_a_layer_and_pads_no_table(
+        one_chip, mosaic, blocks):
+    """Mosaic takes ``mla_paged_decode_attn`` on its grid of slots (a double
+    buffer of 2 x 1.3 MB, the softmax state of 16 heads in the loop's carry)
+    inside the whole step; every latent layer calls it once, on the table as
+    the engine hands it over — nothing pads the table to whole chunks."""
+    fn, feed, state, plist = _shapes(one_chip, None, blocks)
+    text = jax.jit(fn, donate_argnums=(1,)).lower(
+        feed, state, plist).compile().as_text()
+    walks = re.findall(r"%mla_paged_decode_attn\S* = \S+ custom-call\(.*?"
+                       r"operand_layout_constraints=\{(s32\[[\d,]+\])", text)
+    assert walks == [f"s32[{S},{blocks}]"] * CFG.num_hidden_layers, walks
+    assert not re.search(r"s32\[%d,\d+\]\S* pad\(" % S, text)
 
 
 def test_mosaic_accepts_the_expert_walk_and_the_step_keeps_its_tiles(

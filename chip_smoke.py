@@ -73,9 +73,6 @@ import numpy as np
 # re-introduced one could not pass unnoticed.)
 FALLBACK_COUNTERS = (
     "decode.attn_fallbacks",
-    "sparse_fused.gather_fallbacks",
-    "sparse_fused.update_fallbacks",
-    "sparse_fused.runtime_disables",
     "quant.matmul_fallbacks",
     "quant.lower_fallbacks",
     "quant.runtime_disables",
@@ -221,9 +218,9 @@ def transformer_feed(batch, max_len, vocab, seed=0):
 def phase_trainer(place, batch=32, max_len=256, vocab=32000, d_model=512,
                   n_head=8, d_ffn=2048, n_layer=6, dtype="bfloat16",
                   dropout=0.1, steps=6, scan_steps=4):
-    """Transformer-base at the bench config's width (bench.py
-    bench_transformer), trained on one fixed batch so the loss must
-    fall.  ``warmup_steps`` is cut to 8 (a schedule hyper-parameter, not
+    """Transformer-base (the widths and depth of
+    benchmark/configs/transformer-base-wmt.json), trained on one fixed
+    batch so the loss must fall.  ``warmup_steps`` is cut to 8 (a schedule hyper-parameter, not
     geometry): at the default 4000 the first steps' learning rate is
     1e-7 and nothing could be seen to move."""
     import paddle_tpu as fluid
@@ -334,9 +331,9 @@ def _kernel_vs_xla(place, on_chip, make, feed, tol):
 
 
 def _rnn_stack_case(place, on_chip, cell, batch, seq, hidden, layers):
-    """The recurrent stack of the stacked-LSTM bench model (bench.py
-    bench_stacked_lstm: fc -> cell, every second layer reversed, so a
-    reversed layer feeds further ops) for ``cell`` "lstm" or "gru", with
+    """The recurrent stack of models/stacked_lstm.py (fc -> cell, every
+    second layer reversed, so a reversed layer feeds further ops) for
+    ``cell`` "lstm" or "gru", with
     the loss on the hidden states themselves.  (Under the model's own
     classifier loss the LSTM weight gradients at initialization are
     ~2e-6, and XLA's default-precision result for them is 9-15% from

@@ -288,14 +288,13 @@ class _CacheEntry:
         self.fingerprint = None
         self.aot_ms = None
         # set by _recover_fused_fault: this entry was re-lowered without
-        # the fused sparse kernels after a dispatch-level compile fault
+        # the int8 kernels after a dispatch-level compile fault
         # (recovery is once-per-entry — a second fault re-raises)
         self.fused_disabled = False
-        # the lowering's trace-time latch dict ({"sparse_fused": bool},
-        # build_block_fn._sparse_fused_used): did THIS entry's lowering
-        # actually emit fused sparse kernels?  None for executables with
-        # no reachable trace (disk hydrates).  Recovery gates on it —
-        # the live flag value can lie in both directions
+        # the lowering's trace-time latch dict ({"int8_fused": bool},
+        # build_block_fn._int8_fused_used): did THIS entry's lowering
+        # actually emit int8 kernels?  None for executables with no
+        # reachable trace (disk hydrates).  Recovery gates on it
         self.fused_used = None
         # cost/memory attribution record (observability/perf.py) when
         # FLAGS_perf_attribution harvested this executable; else None
@@ -784,7 +783,7 @@ class Executor:
                         feed_vals, donated_state, const_state, rng)
                 except Exception as e2:
                     # an AOT/disk entry recovered to a lazy re-lower that
-                    # STILL faults: last chance is a fused-kernel compile
+                    # STILL faults: last chance is an int8-kernel compile
                     # fault — drop the kernels once, counted
                     jitted = self._recover_fused_fault(entry, program, e2,
                                                        donated_state)
@@ -1044,7 +1043,7 @@ class Executor:
         (params, optimizer moments, BN stats, RNG) advances exactly as K
         ``run`` calls would.  The TPU-native replacement for the
         reference's C++ executor loop over a pre-fed data queue — and the
-        steady-state loop bench.py measures.
+        loop the one-chip train cell times (benchmark/drivers/train.py).
         """
         with _obs_trace.span("executor::run_steps"):
             return self._run_steps(program, feed, fetch_list, scope,
@@ -1172,7 +1171,7 @@ class Executor:
                         stacked, donated_state, const_state, rng)
                 except Exception as e2:
                     # see run(): AOT/disk recovery faulting again can
-                    # only be saved by dropping the fused kernels once
+                    # only be saved by dropping the int8 kernels once
                     jitted = self._recover_fused_fault(
                         entry, program, e2, donated_state,
                         build_fn=self._make_scan_builder(program,
@@ -1223,11 +1222,11 @@ class Executor:
     def _make_scan_builder(self, program: Program, plan):
         """Builder for run_steps' K-step ``lax.scan`` wrapper (the
         executable the cache stores for mode="run_steps")."""
-        def build(disable_sparse_fused=False):
+        def build(disable_int8_fused=False):
             fn = build_block_fn(program, plan, training=self._training,
                                 mesh=self._mesh(),
                                 spans_devices=self._spans_devices,
-                                disable_sparse_fused=disable_sparse_fused)
+                                disable_int8_fused=disable_int8_fused)
             refeed = plan.donated_write_indices
             n_writes = len(plan.persist_writes)
             extra_idx = [i for i in range(n_writes)
@@ -1263,7 +1262,7 @@ class Executor:
                     final_state[i] = extra[slot]
                 return fetches, final_state, rng
 
-            multi._sparse_fused_used = fn._sparse_fused_used
+            multi._int8_fused_used = fn._int8_fused_used
             multi.__name__ = multi.__qualname__ = \
                 _compile_cache.program_name("multi")
             return multi
@@ -1312,11 +1311,11 @@ class Executor:
         raw_make = build_fn or (lambda: build_block_fn(
             program, plan, training=self._training, mesh=self._mesh(),
             spans_devices=self._spans_devices))
-        used_cell = []  # the raw fn's _sparse_fused_used dict, once built
+        used_cell = []  # the raw fn's _int8_fused_used dict, once built
 
         def make(**kw):
             fn = raw_make(**kw)
-            cell = getattr(fn, "_sparse_fused_used", None)
+            cell = getattr(fn, "_int8_fused_used", None)
             if cell is not None:
                 used_cell[:] = [cell]
             return fn
@@ -1391,15 +1390,15 @@ class Executor:
         way), and the run proceeds as a plain compile.
 
         Failures of lazy-jit entries — which already retrace per call —
-        re-raise untouched UNLESS their lowering emitted fused sparse
-        kernels (entry.fused_used latch): a
-        fused-kernel Mosaic/XLA compile fault only surfaces at this
-        layer (the per-op try/except in kernels/sparse.py covers trace
-        time only), so the counted-fallback contract is completed here
-        by ONE re-lower with the fused kernels disabled.  A fault AFTER
-        execution started (donated buffers already consumed: a retry
-        would read deleted arrays) always re-raises; aval/sharding and
-        compile faults raise before any donation."""
+        re-raise untouched UNLESS their lowering emitted int8 kernels
+        (entry.fused_used latch): a Mosaic/XLA compile fault of such a
+        kernel only surfaces at this layer (the try/except in
+        kernels/quant.py covers trace time only), so the counted-fallback
+        contract is completed here by ONE re-lower with the int8 kernels
+        disabled.  A fault AFTER execution started (donated buffers
+        already consumed: a retry would read deleted arrays) always
+        re-raises; aval/sharding and compile faults raise before any
+        donation."""
         if any(isinstance(v, jax.Array) and v.is_deleted()
                for v in donated_state):
             raise exc
@@ -1421,20 +1420,20 @@ class Executor:
 
     def _entry_builder(self, entry, program, build_fn=None):
         """Block-fn builder for fault-recovery re-lowers; accepts
-        ``disable_sparse_fused`` (both producers — the default
+        ``disable_int8_fused`` (both producers — the default
         build_block_fn closure and _make_scan_builder's build — do).
         The rebuilt fn's trace-time used-latch replaces the entry's (a
         disk-hydrated entry has none until its lazy rebuild traces)."""
-        def mk(disable_sparse_fused=False):
+        def mk(disable_int8_fused=False):
             if build_fn is not None:
-                fn = build_fn(disable_sparse_fused=disable_sparse_fused)
+                fn = build_fn(disable_int8_fused=disable_int8_fused)
             else:
                 fn = build_block_fn(
                     program, entry.plan, training=self._training,
                     mesh=self._mesh(),
                     spans_devices=self._spans_devices,
-                    disable_sparse_fused=disable_sparse_fused)
-            cell = getattr(fn, "_sparse_fused_used", None)
+                    disable_int8_fused=disable_int8_fused)
+            cell = getattr(fn, "_int8_fused_used", None)
             if cell is not None:
                 entry.fused_used = cell
             return fn
@@ -1442,34 +1441,26 @@ class Executor:
 
     def _recover_fused_fault(self, entry, program, exc, donated_state,
                              build_fn=None):
-        """Last line of the FLAGS_sparse_fused_kernel counted-fallback
-        contract: a compile fault that only surfaces at dispatch (Mosaic
-        on a real TPU — invisible to the trace-time try/except in
-        kernels/sparse.py) re-lowers the step ONCE with the fused
-        kernels disabled, counted in sparse_fused.runtime_disables.
-        Reached for lazy-jit entries directly from _recover_disk_entry,
-        and from the run()/run_steps() second-level retry when an
-        AOT/disk entry's fused re-lower faults again.  Gated on the
-        ENTRY's trace-time latch (entry.fused_used — the flag's live
-        value can lie in both directions: flipped since the trace, or
-        on for a program with no sparse lookups); anything whose
-        lowering emitted no fused kernels re-raises untouched."""
+        """Last line of the int8 kernels' counted-fallback contract: a
+        compile fault that only surfaces at dispatch (Mosaic on a real
+        TPU — invisible to the trace-time try/except in kernels/quant.py)
+        re-lowers the step ONCE with the int8 kernels disabled, counted
+        in quant.runtime_disables.  Reached for lazy-jit entries directly
+        from _recover_disk_entry, and from the run()/run_steps()
+        second-level retry when an AOT/disk entry's re-lower faults
+        again.  Gated on the ENTRY's trace-time latch
+        (entry.fused_used): anything whose lowering emitted no int8
+        kernels re-raises untouched."""
         from ..kernels import quant as _quant_kernels
-        from ..kernels import sparse as _sparse_kernels
         cell = entry.fused_used
-        if entry.fused_disabled or not (
-                cell and (cell.get("sparse_fused")
-                          or cell.get("int8_fused"))):
+        if entry.fused_disabled or not (cell and cell.get("int8_fused")):
             raise exc
         if any(isinstance(v, jax.Array) and v.is_deleted()
                for v in donated_state):
             raise exc
-        if cell.get("sparse_fused"):
-            _sparse_kernels.count_runtime_disable()
-        if cell.get("int8_fused"):
-            _quant_kernels.count_runtime_disable()
+        _quant_kernels.count_runtime_disable()
         mk = self._entry_builder(entry, program, build_fn)
-        jitted = jax.jit(mk(disable_sparse_fused=True), donate_argnums=(1,))
+        jitted = jax.jit(mk(disable_int8_fused=True), donate_argnums=(1,))
         entry.jitted = jitted
         entry.from_disk = False
         entry.aot_ms = None

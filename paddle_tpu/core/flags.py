@@ -90,20 +90,6 @@ define_flag("sparse_dense_update_max_elems", 32_000_000,
             "sorted merge_rows path whose cost is independent of height. "
             "Read at trace time: set it before the first Executor.run of "
             "a program (cached executables keep the path they compiled)")
-define_flag("sparse_fused_kernel", False,
-            "lower the sparse-embedding hot path through the fused Pallas "
-            "kernels (paddle_tpu/kernels/sparse.py): lookup_table ops "
-            "sharing one id batch gather through ONE multi-table launch, "
-            "and the lazy sparse optimizers (adam/momentum/adagrad) "
-            "replace their per-table gather/scatter/moment-sweep chain "
-            "with ONE sorted-segment row-wise update launch that touches "
-            "only the looked-up rows (in-place via input_output_aliases). "
-            "Off-TPU the kernels run in Pallas interpret mode.  Each "
-            "stage independently falls back to the masked-dense / sorted "
-            "merge_rows paths on any build fault (counted in "
-            "sparse_fused.*_fallbacks — a fault can never fail a step). "
-            "Read at trace time like sparse_dense_update_max_elems; off "
-            "(default) keeps the update path byte-identical")
 define_flag("runtime_stats", True,
             "collect runtime telemetry (paddle_tpu/observability): "
             "executor compile-cache and StepStats records, lowering/RPC/"

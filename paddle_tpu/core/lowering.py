@@ -188,15 +188,6 @@ def lower_ops(ctx: LowerContext, program: Program, block: Block, env: Dict) -> D
     :func:`op_scope`, mutating env."""
     from ..ops.control_flow_ops import CONTROL_FLOW_OPS
 
-    # FLAGS_sparse_fused_kernel peephole: lookup_table ops sharing one Ids
-    # input lower through a single fused Pallas gather launch
-    # (kernels/sparse.py).  Mesh-lowered blocks keep the plain XLA gathers
-    # — GSPMD shards those natively but cannot partition a custom call —
-    # and fault-recovery re-lowers (ctx.disable_sparse_fused) skip it.
-    from ..kernels import sparse as _sparse_kernels
-    fusion = (_sparse_kernels.plan_lookup_fusion(block)
-              if _sparse_kernels.enabled_for(ctx) else None)
-
     # int8 inference peephole: mul/fused_fc ops the quantize_int8
     # calibration pass stamped (quant_int8 attr + WInt8/WScale sidecar
     # inputs) lower through the fused-dequant int8 Pallas matmul
@@ -215,10 +206,6 @@ def lower_ops(ctx: LowerContext, program: Program, block: Block, env: Dict) -> D
                    else _retraced_forward(op))
         with (jax.named_scope(scope) if forward is None else _halves_named(
                 ctx, op_scope(op, top, type=forward.type), scope)):
-            if fusion is not None and fusion.covers(pos) \
-                    and fusion.lower(pos, env):
-                ctx.sparse_fused_used = True
-                continue
             if int8_plan is not None and int8_plan.covers(pos) \
                     and int8_plan.lower(pos, env):
                 ctx.int8_fused_used = True
@@ -264,7 +251,7 @@ def lower_ops(ctx: LowerContext, program: Program, block: Block, env: Dict) -> D
 
 
 def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
-                   mesh=None, disable_sparse_fused: bool = False,
+                   mesh=None, disable_int8_fused: bool = False,
                    spans_devices: bool = False):
     """Return fn(feed_vals, donated_state, const_state, rng) ->
     (fetch_vals, new_persist_vals, rng_out).
@@ -273,19 +260,16 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
     jit will compile this lowering as one partitioned program whether or
     not a ``mesh`` is given (``ctx.spans_devices``).
 
-    ``disable_sparse_fused``: lower WITHOUT the fused Pallas paths (the
-    sparse-embedding kernels AND the int8 inference peephole) even when
-    enabled — the executor's dispatch-fault recovery re-lowers a step
-    this way when its compile died with fused kernels in it
-    (kernels/sparse.py / kernels/quant.py counted-fallback contract)."""
+    ``disable_int8_fused``: lower WITHOUT the int8 inference peephole even
+    where the program is calibrated for it — the executor's dispatch-fault
+    recovery re-lowers a step this way when its compile died with the
+    int8 kernels in it (kernels/quant.py counted-fallback contract)."""
     block = program.blocks[plan.block_idx]
     donated, const = plan.donated_reads, plan.const_reads
-    # trace-time latch: did THIS lowering actually emit fused sparse /
-    # int8 kernels?  The executor's dispatch-fault recovery gates on it
-    # (the flag alone lies in both directions: it may have changed since
-    # the entry traced, and a flag-on program may contain no sparse
-    # lookups)
-    used = {"sparse_fused": False, "int8_fused": False}
+    # trace-time latch: did THIS lowering actually emit int8 kernels?  The
+    # executor's dispatch-fault recovery gates on it (a calibrated program
+    # may still have lowered every stamped op through XLA)
+    used = {"int8_fused": False}
 
     def fn(feed_vals, donated_state, const_state, rng):
         # host-side timing of the op-by-op jax trace: runs once per XLA
@@ -299,8 +283,7 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
         ctx = LowerContext(block=block, mesh=mesh, lower_block_fn=lower_sub,
                            training=training)
         ctx.spans_devices = spans_devices
-        ctx.disable_sparse_fused = disable_sparse_fused
-        ctx.disable_int8_fused = disable_sparse_fused
+        ctx.disable_int8_fused = disable_int8_fused
         ctx.set_rng(rng)
         env: Dict = {}
         env.update(zip(plan.feed_names, feed_vals))
@@ -308,8 +291,6 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
         env.update(zip(const, const_state))
         with _obs_trace.span("lowering::trace"):
             lower_ops(ctx, program, block, env)
-        if getattr(ctx, "sparse_fused_used", False):
-            used["sparse_fused"] = True
         if getattr(ctx, "int8_fused_used", False):
             used["int8_fused"] = True
         fetches = [env[n] for n in plan.fetch_names]
@@ -319,6 +300,6 @@ def build_block_fn(program: Program, plan: BlockPlan, training: bool = True,
                 (time.perf_counter_ns() - t0) / 1e6)
         return fetches, new_state, ctx.rng_key
 
-    fn._sparse_fused_used = used
+    fn._int8_fused_used = used
     fn.__name__ = fn.__qualname__ = compile_cache.program_name("fn")
     return fn

@@ -20,6 +20,8 @@ sentinel rows are no-ops on device — no dynamic shapes anywhere.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import tree_util
@@ -105,7 +107,8 @@ def dense_grad_and_mask(sr: SelectedRows, dtype=None):
         # extra trailing column of the same scatter-add instead of a
         # second scatter.  For DeepFM's two tables this halves the
         # per-step scatter count of the update path (4 -> 2).
-        flat = vals.reshape(vals.shape[0], -1)
+        # not reshape(n, -1): an empty id batch has no size to divide
+        flat = vals.reshape(vals.shape[0], math.prod(vals.shape[1:]))
         ones = jnp.ones((flat.shape[0], 1), flat.dtype)
         aug = jnp.concatenate([flat, ones], axis=1)
         buf = jnp.zeros((sr.height, aug.shape[1]), aug.dtype)

@@ -28,7 +28,7 @@ package is that state plane, built on the repo's own primitives:
   token per slot against the live blocks of its block list, fetched by
   the kernel itself in chunks from the scalar-prefetched tables and
   lengths, with an XLA-gather path (``impl="xla"``) and interpret-mode
-  CPU coverage (the ``kernels/sparse.py`` contract).
+  CPU coverage.
 - **The model side** (:mod:`adapter`): a served model is an
   :class:`~paddle_tpu.decode.adapter.LMAdapter` — it describes its cache
   (``make_cache``), owns the state list its ``prefill`` / ``decode_step``

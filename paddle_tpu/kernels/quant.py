@@ -30,8 +30,8 @@ two such codes dequantizes with ``s_x * s_w[j] / 127^2`` per out
 channel j — exactly what the epilogue applies, so the kernel reproduces
 the QAT fake-quant reference to f32 rounding.
 
-Fallback contract (the ``kernels/sparse.py`` discipline): every entry
-point degrades on any build/trace fault — ``int8_fc`` returns ``None``
+Fallback contract: every entry point degrades on any build/trace
+fault — ``int8_fc`` returns ``None``
 (counted ``quant.matmul_fallbacks``) and the caller takes
 ``int8_fc_xla``, the same quantized math as plain XLA ops (counted
 ``quant.xla_dequant``); the peephole returns False (counted

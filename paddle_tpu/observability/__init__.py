@@ -132,14 +132,13 @@ def enabled() -> bool:
 def export(step_tail: int = 32) -> dict:
     """One JSON-ready bundle: metrics snapshot + step-stats summary/tail.
 
-    The shape bench.py dumps per config into ``step_stats.json`` and the
-    debug server serves on ``/stepz``.
+    The shape the debug server serves on ``/stepz``.
     """
     return {"stats": stats.to_dict(),
             "step_stats": step_stats.recorder().export(tail=step_tail)}
 
 
 def reset() -> None:
-    """Zero all metrics and drop the step ring (bench isolates configs)."""
+    """Zero all metrics and drop the step ring."""
     stats.reset()
     step_stats.clear()

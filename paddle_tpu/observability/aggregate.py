@@ -318,7 +318,7 @@ class FleetAggregator:
         return fleet_prometheus_text(self.merged())
 
     def export(self) -> dict:
-        """JSON-ready merge + pull-error map (bench.py artifact form)."""
+        """JSON-ready merge + pull-error map."""
         merged = self.merged()
         merged["pull_errors"] = dict(self.last_errors)
         return merged

@@ -93,8 +93,8 @@ def _pm():
 
 def cost_dict(compiled) -> dict:
     """``cost_analysis()`` as a plain dict; {} when the executable
-    cannot report.  Public: bench.py attributes its timed executables
-    through this."""
+    cannot report.  Public: ``pipeline/transpiler.py xla_stage_flops``
+    reads each stage's cost through this."""
     return dict(compiled.cost_analysis() or {})
 
 
@@ -183,9 +183,9 @@ class PerfRecord:
 def roofline_numbers(flops: float, bytes_accessed: float,
                      seconds: Optional[float],
                      peaks: Optional[dict] = None) -> dict:
-    """The shared roofline arithmetic (executor records AND bench.py
-    configs use this): achieved rates from ``seconds``, arithmetic
-    intensity, position vs the platform peak table.
+    """The roofline arithmetic of the executor's records: achieved rates
+    from ``seconds``, arithmetic intensity, position vs the platform
+    peak table.
 
     ``peaks`` defaults to ``platform.platform_peaks()``; pass
     ``{"flops": None}``-shaped dicts to skip the peak comparison.

@@ -14,7 +14,7 @@ distributions that survive past a trace window).  Design points:
   with no allocation — safe on hot paths;
 - exports: ``snapshot()`` (plain dict), ``to_prometheus_text()``
   (text exposition format, scrape-ready), ``dump_json()`` (artifact
-  files, e.g. bench.py's per-config ``step_stats.json``).
+  files).
 
 Collection is gated by ``FLAGS_runtime_stats`` at the *instrumentation
 sites* (executor/transport/lowering), not here: the registry itself has
@@ -427,7 +427,7 @@ class StatsRegistry:
 
     def reset(self) -> None:
         """Zero every metric IN PLACE (handles held by call sites stay
-        valid — bench.py resets between configs)."""
+        valid)."""
         with self._lock:
             metrics = list(self._metrics.values())
         for m in metrics:

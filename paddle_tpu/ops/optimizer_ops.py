@@ -19,7 +19,6 @@ from ..core.registry import register
 from ..core.selected_rows import (
     SelectedRows, dense_grad_and_mask, gather_rows, merge_rows,
     prefer_dense_update, scatter_set_rows)
-from ..kernels import sparse as sparse_kernels
 
 
 def _lr(ins, dtype=None):
@@ -58,17 +57,6 @@ def _momentum(ctx, ins, attrs):
     mu = jnp.asarray(attrs.get("mu", 0.9), v.dtype)
     lr = _lr(ins, v.dtype)
     if _is_sparse(g):
-        if sparse_kernels.enabled_for(ctx):
-            # g stays in its own dtype: the sorted reference merges
-            # duplicates BEFORE casting, so only f32-valued grads are
-            # fused (others fall back inside, counted)
-            fused = sparse_kernels.fused_momentum(
-                p, v, g, lr, attrs.get("mu", 0.9),
-                attrs.get("use_nesterov", False))
-            if fused is not None:
-                ctx.sparse_fused_used = True
-                p_new, v_new = fused
-                return {"ParamOut": [p_new], "VelocityOut": [v_new]}
         if prefer_dense_update(g):
             gd, t = dense_grad_and_mask(g, v.dtype)
             v_new = jnp.where(t, mu * v + gd, v)
@@ -112,20 +100,6 @@ def _adam(ctx, ins, attrs):
         # (reference adam_op.h SelectedRows path)
         lr = (_lr(ins, m1.dtype)
               * jnp.sqrt(1 - b2p.reshape(())) / (1 - b1p.reshape(())))
-        if sparse_kernels.enabled_for(ctx):
-            fused = sparse_kernels.fused_adam(
-                p, m1, m2, g, lr, attrs.get("beta1", 0.9),
-                attrs.get("beta2", 0.999), attrs.get("epsilon", 1e-8))
-            if fused is not None:
-                ctx.sparse_fused_used = True
-                p_new, m1n, m2n = fused
-                return {
-                    "ParamOut": [p_new],
-                    "Moment1Out": [m1n],
-                    "Moment2Out": [m2n],
-                    "Beta1PowOut": [b1p * beta1],
-                    "Beta2PowOut": [b2p * beta2],
-                }
         if prefer_dense_update(g):
             gd, t = dense_grad_and_mask(g, m1.dtype)
             m1n = jnp.where(t, beta1 * m1 + (1 - beta1) * gd, m1)
@@ -172,13 +146,6 @@ def _adagrad(ctx, ins, attrs):
     p, g, mom = ins["Param"][0], ins["Grad"][0], ins["Moment"][0]
     eps = jnp.asarray(attrs.get("epsilon", 1e-6), mom.dtype)
     if _is_sparse(g):
-        if sparse_kernels.enabled_for(ctx):
-            fused = sparse_kernels.fused_adagrad(
-                p, mom, g, _lr(ins, mom.dtype), attrs.get("epsilon", 1e-6))
-            if fused is not None:
-                ctx.sparse_fused_used = True
-                p_new, mom_new = fused
-                return {"ParamOut": [p_new], "MomentOut": [mom_new]}
         if prefer_dense_update(g):
             gd, t = dense_grad_and_mask(g, mom.dtype)
             mom_new = jnp.where(t, mom + gd * gd, mom)

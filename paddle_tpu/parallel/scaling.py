@@ -1,4 +1,4 @@
-"""Weak-scaling efficiency harness (BASELINE's "8→64 chip scaling eff").
+"""Weak-scaling efficiency harness (the "8→64 chip scaling eff" target).
 
 Reference precedent: ``benchmark/fluid/fluid_benchmark.py:137`` runs the
 same model over 1..N GPUs and reports throughput ratios.  On this repo's

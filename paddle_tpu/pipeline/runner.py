@@ -447,7 +447,7 @@ class PipelineTrainer:
         """Naive sequential stage execution: every microbatch's forward
         and backward dispatched stage by stage on ONE thread, no
         overlap, no scan amortization — the baseline the pipeline
-        schedules are measured against (bench.py ``pipeline``)."""
+        schedules are measured against."""
         pp, M = self.pp, self.M
         _, per_mb = self._split_feed(feed)
         acts: Dict[tuple, np.ndarray] = {}

@@ -18,8 +18,8 @@ maps model name → active version.  The hot-swap sequence
 4. **drain** A (every accepted request answered) and retire it.
 
 No request is dropped and no dispatch leaves the warmed ladder, which
-is the measured acceptance (`bench.py serving`: zero dropped, zero
-recompiles during a swap under load).
+is what ``tests/test_serving.py`` holds a swap under load to (zero
+dropped, zero recompiles).
 """
 from __future__ import annotations
 

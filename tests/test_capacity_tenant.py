@@ -6,8 +6,7 @@ attribution, the space-saving heavy-hitter sketch, /tenantz), the
 flags-off byte-identity guarantees on wire + heartbeat + metric
 surface, the lease-data headroom chain into ElasticController and the
 supervisor, the fleet STATS_PULL merge, and the operator surfaces
-(dump_metrics modes, fleet status table, bench_compare informational
-carry-through)."""
+(dump_metrics modes, fleet status table)."""
 import json
 import time
 
@@ -566,25 +565,3 @@ def test_fleet_status_role_table_renders_headroom(capsys):
     assert "-" in capsys.readouterr().out
 
 
-def test_bench_compare_headroom_informational_not_gating():
-    import sys
-    sys.path.insert(0, "tools")
-    try:
-        import bench_compare as bc
-    finally:
-        sys.path.pop(0)
-    old = {"configs": {"decode": {"decode_tokens_per_sec": 100.0,
-                                  "headroom_frac": 0.50}}}
-    new = {"configs": {"decode": {"decode_tokens_per_sec": 101.0,
-                                  "headroom_frac": 0.05}}}
-    cmp = bc.compare(old, new)
-    # a headroom collapse informs but NEVER gates
-    assert cmp["verdict"] == "ok"
-    assert not any("headroom" in r for r in cmp["regressions"])
-    ent = cmp["configs"]["decode"]
-    assert ent["info"]["headroom_frac"] == {"old": 0.50, "new": 0.05}
-    # absent from both rounds: no info key at all (old-round compat)
-    plain = bc.compare(
-        {"configs": {"decode": {"decode_tokens_per_sec": 100.0}}},
-        {"configs": {"decode": {"decode_tokens_per_sec": 101.0}}})
-    assert "info" not in plain["configs"]["decode"]

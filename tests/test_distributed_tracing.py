@@ -6,7 +6,7 @@ tools/stitch_trace.py), the 2-process trainer+pserver stitched-trace
 acceptance scenario, and flight-recorder dumps on unhandled exceptions /
 SIGTERM / Heartbeat dirty exits — plus the satellites (profiler lane
 ids + metadata, tools/timeline.py pid preservation, dump_metrics
---tracez/--flight, bench trace artifact)."""
+--tracez/--flight)."""
 import importlib.util
 import json
 import os
@@ -628,25 +628,3 @@ def test_fleet_aggregator_pull_traces_and_stitch():
         srv.stop()
 
 
-def test_bench_trace_artifact(tmp_path, monkeypatch):
-    sys.path.insert(0, REPO)
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    fluid.set_flags({"trace_sample_rate": 1.0})
-    with trace_mod.start_span("bench::rpc_round", cat="bench"):
-        pass
-    path = str(tmp_path / "bench_trace.json")
-    monkeypatch.setenv("PADDLE_TPU_BENCH_TRACE_PATH", path)
-    out = {}
-    bench._write_bench_trace(out)
-    assert out["trace_path"] == path and out["trace_spans"] >= 1
-    doc = json.load(open(path))
-    assert any(e.get("name") == "bench::rpc_round"
-               for e in doc["traceEvents"])
-    # empty path disables
-    monkeypatch.setenv("PADDLE_TPU_BENCH_TRACE_PATH", "")
-    out2 = {}
-    bench._write_bench_trace(out2)
-    assert "trace_path" not in out2

@@ -1,5 +1,6 @@
 """Executor/Scope tests (reference executor tests + book/fit_a_line)."""
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import unique_name
@@ -140,58 +141,71 @@ def test_save_load_inference_model(tmp_path):
     assert np.allclose(ref, got, atol=1e-6)
 
 
-def test_run_steps_matches_eager_loop():
-    """Executor.run_steps: K scanned steps over stacked feeds must match
-    K eager run() calls exactly (params, fetches, RNG-free program)."""
-    import paddle_tpu as fluid
-    from paddle_tpu.core import unique_name
-    from paddle_tpu.core.executor import Executor, Scope, scope_guard
-    from paddle_tpu.core.program import Program, program_guard
-
-    def build():
-        x = fluid.layers.data("x", [5])
-        y = fluid.layers.data("y", [1])
-        p = fluid.layers.fc(x, 1, param_attr=fluid.ParamAttr(name="w"))
-        loss = fluid.layers.mean(fluid.layers.square_error_cost(p, y))
-        fluid.optimizer.Momentum(0.05, 0.9).minimize(loss)
-        return loss
-
-    rng = np.random.RandomState(0)
-    K = 6
+def _fc_momentum(rng, K):
+    x = fluid.layers.data("x", [5])
+    y = fluid.layers.data("y", [1])
+    p = fluid.layers.fc(x, 1, param_attr=fluid.ParamAttr(name="w"))
+    loss = fluid.layers.mean(fluid.layers.square_error_cost(p, y))
+    fluid.optimizer.Momentum(0.05, 0.9).minimize(loss)
     xs = rng.randn(K, 8, 5).astype("float32")
-    ys = xs.sum(2, keepdims=True).astype("float32")
+    return loss, {"x": xs, "y": xs.sum(2, keepdims=True).astype("float32")}
 
-    def eager():
+
+def _mnist_momentum(rng, K):
+    from paddle_tpu.models import mnist
+    _, loss, _ = mnist.build(with_optimizer=False)
+    fluid.optimizer.Momentum(0.05, 0.9).minimize(loss)
+    return loss, {"pixel": rng.rand(K, 4, 1, 28, 28).astype("float32"),
+                  "label": rng.randint(0, 10, (K, 4, 1)).astype("int64")}
+
+
+def _deepfm_sparse_adam(rng, K):
+    from paddle_tpu.models import deepfm
+    _, loss, _ = deepfm.build(sparse_dim=40, lr=1e-3)
+    return loss, {"dense": rng.rand(K, 4, 13).astype("float32"),
+                  "sparse": rng.randint(0, 40, (K, 4, 26)).astype("int64"),
+                  "label": (rng.rand(K, 4, 1) > 0.5).astype("float32")}
+
+
+@pytest.mark.parametrize("build,K", [
+    (_fc_momentum, 6),
+    (_mnist_momentum, 1), (_mnist_momentum, 4),
+    (_deepfm_sparse_adam, 1), (_deepfm_sparse_adam, 4),
+])
+def test_run_steps_matches_eager_loop(build, K):
+    """Executor.run_steps: K scanned steps over stacked feeds must match
+    K eager run() calls (fetched losses and every parameter after;
+    RNG-free programs) — a scan of one step, a convolutional program,
+    and Adam over dense and SelectedRows gradients included."""
+    def drive(scanned):
         prog, startup = Program(), Program()
         prog.random_seed = 11
         with program_guard(prog, startup), unique_name.guard():
-            loss = build()
+            loss, feed = build(np.random.RandomState(0), K)
         scope, exe = Scope(), Executor()
         with scope_guard(scope):
             exe.run(startup)
-            losses = [float(exe.run(prog, feed={"x": xs[i], "y": ys[i]},
-                                    fetch_list=[loss.name])[0])
-                      for i in range(K)]
-            w = np.asarray(scope.find_var("w")).copy()
-        return losses, w
+            if scanned:
+                (losses,) = exe.run_steps(prog, feed=feed,
+                                          fetch_list=[loss.name])
+            else:
+                losses = [exe.run(prog,
+                                  feed={n: v[i] for n, v in feed.items()},
+                                  fetch_list=[loss.name])[0]
+                          for i in range(K)]
+            params = {p.name: np.asarray(scope.find_var(p.name)).copy()
+                      for p in prog.all_parameters()}
+        return [float(v) for v in losses], params
 
-    def scanned():
-        prog, startup = Program(), Program()
-        prog.random_seed = 11
-        with program_guard(prog, startup), unique_name.guard():
-            loss = build()
-        scope, exe = Scope(), Executor()
-        with scope_guard(scope):
-            exe.run(startup)
-            (stacked_loss,) = exe.run_steps(
-                prog, feed={"x": xs, "y": ys}, fetch_list=[loss.name])
-            w = np.asarray(scope.find_var("w")).copy()
-        return [float(v) for v in stacked_loss], w
-
-    el, ew = eager()
-    sl, sw = scanned()
+    el, ep = drive(scanned=False)
+    sl, sp = drive(scanned=True)
+    assert len(sl) == K and sorted(sp) == sorted(ep) and ep
     np.testing.assert_allclose(sl, el, rtol=1e-5)
-    np.testing.assert_allclose(sw, ew, rtol=1e-5)
+    for name, want in ep.items():
+        # Adam divides by the gradient's own size: an element whose
+        # gradient is rounding noise moves by a fraction of lr = 1e-3
+        np.testing.assert_allclose(sp[name], want, rtol=1e-5, atol=1e-5,
+                                   err_msg=name)
 
 
 def test_lod_tensor_feed_shim():

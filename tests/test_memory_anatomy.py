@@ -6,7 +6,7 @@ dimension, OOM forensics + recovery on the decode plane, the chaos
 guarantees (no pools, no series, no threads, no rider bytes), the
 lease-data memory-headroom chain into ElasticController and the
 supervisor, and the operator surfaces (/allocz, dump_metrics --allocz,
-fleet status mem column, bench_compare informational carry-through)."""
+fleet status mem column)."""
 import json
 import threading
 import time
@@ -477,27 +477,3 @@ def test_fleet_status_role_table_renders_mem_column(capsys):
     assert "-" in capsys.readouterr().out
 
 
-def test_bench_compare_kv_bytes_informational_not_gating():
-    import sys
-    sys.path.insert(0, "tools")
-    try:
-        import bench_compare as bc
-    finally:
-        sys.path.pop(0)
-    assert "kv_bytes_per_token" in bc.LOWER_BETTER_KEYS
-    assert "kv_bytes_per_token" in bc.INFORMATIONAL_KEYS
-    assert "unattributed_bytes" in bc.INFORMATIONAL_KEYS
-    old = {"configs": {"decode": {"decode_tokens_per_sec": 100.0,
-                                  "kv_bytes_per_token": 512.0,
-                                  "unattributed_bytes": 100}}}
-    new = {"configs": {"decode": {"decode_tokens_per_sec": 101.0,
-                                  "kv_bytes_per_token": 2048.0,
-                                  "unattributed_bytes": 90000}}}
-    cmp = bc.compare(old, new)
-    # a KV-cost blowup informs but NEVER gates
-    assert cmp["verdict"] == "ok"
-    assert not any("kv_bytes" in r for r in cmp["regressions"])
-    ent = cmp["configs"]["decode"]
-    assert ent["info"]["kv_bytes_per_token"] == {"old": 512.0,
-                                                 "new": 2048.0}
-    assert ent["info"]["unattributed_bytes"] == {"old": 100, "new": 90000}

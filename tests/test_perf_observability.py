@@ -2,10 +2,9 @@
 cost/memory attribution with roofline positions (/profilez), live
 device-memory telemetry (/memz), the run-scalar JSONL log +
 tools/runlog_report.py, the NaN/Inf post-step sentinel
-(FLAGS_numerics_check), and the tools/bench_compare.py regression gate
-— plus the satellite coverage (StepStats ring percentile edge cases,
-fleet histogram merge with mismatched bucket layouts, /statusz device
-inventory, dump_metrics --memz/--profilez)."""
+(FLAGS_numerics_check) — plus the satellite coverage (StepStats ring
+percentile edge cases, fleet histogram merge with mismatched bucket
+layouts, /statusz device inventory, dump_metrics --memz/--profilez)."""
 import json
 import os
 import socket
@@ -28,7 +27,6 @@ from paddle_tpu.observability.step_stats import StepStats, StepStatsRecorder
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(REPO, "tools"))
-import bench_compare  # noqa: E402
 import runlog_report  # noqa: E402
 
 
@@ -658,74 +656,6 @@ def test_sentinel_off_keeps_counters_quiet():
     assert snap.get("numerics.checked_steps", 0) == 0
 
 
-# ---------------------------------------------------------------------------
-# (d) bench regression gate
-# ---------------------------------------------------------------------------
-
-def _round(configs):
-    return {"metric": "x", "value": 1.0, "configs": configs}
-
-
-def test_bench_compare_flags_regression_passes_noise(tmp_path, capsys):
-    old = _round({"resnet50": {"images_per_sec": 1000.0},
-                  "transformer": {"tokens_per_sec": 50000.0}})
-    new = _round({"resnet50": {"images_per_sec": 800.0},      # -20%
-                  "transformer": {"tokens_per_sec": 51500.0}})  # +3%
-    cmp = bench_compare.compare(old, new)
-    assert cmp["verdict"] == "regression"
-    assert cmp["configs"]["resnet50"]["status"] == "regression"
-    assert cmp["configs"]["resnet50"]["delta"] == pytest.approx(-0.2)
-    assert cmp["configs"]["transformer"]["status"] == "within_noise"
-
-    # CLI: exit 1 on the regression, 0 once the delta is within noise
-    a, b = str(tmp_path / "a.json"), str(tmp_path / "b.json")
-    with open(a, "w") as f:
-        json.dump(old, f)
-    with open(b, "w") as f:
-        json.dump(new, f)
-    assert bench_compare.main([a, b]) == 1
-    out = capsys.readouterr().out
-    assert "verdict=regression" in out and "resnet50" in out
-    assert bench_compare.main([a, b, "--threshold", "0.25"]) == 0
-    within = _round({"resnet50": {"images_per_sec": 960.0},
-                     "transformer": {"tokens_per_sec": 50000.0}})
-    with open(b, "w") as f:
-        json.dump(within, f)
-    assert bench_compare.main([a, b]) == 0
-
-
-def test_bench_compare_skip_and_analysis_awareness():
-    """A skipped config is reported but never a regression; analysis
-    entries compare informationally and cannot drive the verdict."""
-    old = _round({"a": {"images_per_sec": 100.0},
-                  "b": {"images_per_sec": 100.0},
-                  "scaling_dp8": {"eff_flops": 0.99},
-                  "c": {"tokens_per_sec": 10.0}})
-    new = _round({"a": {"skipped": "budget"},
-                  "b": {"images_per_sec": 99.0},
-                  "scaling_dp8": {"eff_flops": 0.50, "analysis": True},
-                  "c": {"error": "timeout"}})
-    cmp = bench_compare.compare(old, new)
-    assert cmp["verdict"] == "ok"
-    assert cmp["configs"]["a"]["status"] == "incomparable"
-    assert "skipped" in cmp["configs"]["a"]["reason"]
-    assert cmp["configs"]["c"]["status"] == "incomparable"
-    assert cmp["configs"]["scaling_dp8"]["status"] == \
-        "regression_analysis_only"
-
-
-def test_bench_compare_zero_baseline_is_incomparable():
-    """A zero baseline value is a broken round: surfaced as
-    incomparable, never laundered into a within-noise verdict."""
-    cmp = bench_compare.compare(
-        {"configs": {"a": {"images_per_sec": 0.0}}},
-        {"configs": {"a": {"images_per_sec": 50.0}}})
-    ent = cmp["configs"]["a"]
-    assert ent["status"] == "incomparable"
-    assert "degenerate baseline" in ent["reason"]
-    assert cmp["incomparable"] == ["a"] and cmp["verdict"] == "empty"
-
-
 def test_run_steps_grad_norm_folds(tmp_path):
     """run_steps records carry grad_global_norm too: [K, ...]-shaped
     @GRAD fetches fold into a per-step norm, like run()'s do."""
@@ -744,35 +674,9 @@ def test_run_steps_grad_norm_folds(tmp_path):
         assert r["scalars"]["loss"] == losses[i]
 
 
-def test_bench_compare_loads_driver_wrapper_and_finds_baseline(tmp_path):
-    """load_round parses the BENCH_r*.json driver wrapper (summary as
-    the tail's last JSON line); find_baseline passes over all-skip and
-    summary-less rounds to the newest MEASURED one."""
-    summary = _round({"resnet50": {"images_per_sec": 2500.0}})
-    wrapper = {"round": 3, "tail": "noise\n" + json.dumps(summary) + "\n"}
-    with open(str(tmp_path / "BENCH_r03.json"), "w") as f:
-        json.dump(wrapper, f)
-    # r04: timed out — no summary in the tail
-    with open(str(tmp_path / "BENCH_r04.json"), "w") as f:
-        json.dump({"round": 4, "tail": "died"}, f)
-    # r05: every real config skipped; only the analysis entry "measured"
-    allskip = _round({"resnet50": {"skipped": "budget"},
-                      "scaling_dp8": {"eff_flops": 1.0}})
-    with open(str(tmp_path / "BENCH_r05.json"), "w") as f:
-        json.dump({"round": 5, "tail": json.dumps(allskip)}, f)
-
-    assert bench_compare.load_round(
-        str(tmp_path / "BENCH_r03.json"))["configs"]["resnet50"][
-            "images_per_sec"] == 2500.0
-    base = bench_compare.find_baseline(str(tmp_path))
-    assert base and os.path.basename(base) == "BENCH_r03.json"
-    with pytest.raises(ValueError):
-        bench_compare.load_round(str(tmp_path / "BENCH_r04.json"))
-
-
 def test_roofline_numbers_shared_arithmetic():
-    """bench.py's per-config roofline entries use this same function:
-    peaks fixed, bound classification from arithmetic intensity."""
+    """The one roofline arithmetic (executor records use it too): peaks
+    fixed, bound classification from arithmetic intensity."""
     peaks = {"flops": 100e9, "hbm_bytes_per_s": 10e9}
     # intensity 100 f/B >> balance 10 → compute-bound
     r = perf.roofline_numbers(1e9, 1e7, 0.1, peaks=peaks)

@@ -251,6 +251,61 @@ def test_int8_predictor_survives_forced_kernel_fault(tmp_path,
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
 
 
+@pytest.mark.parametrize("int8,scanned", [
+    (False, False), (True, False), (True, True),
+], ids=["nothing_latched", "run", "run_steps"])
+def test_dispatch_fault_relowers_an_int8_entry_once(tmp_path, int8, scanned):
+    """The contract's last line, at DISPATCH (a Mosaic fault of a real
+    TPU, which the trace-time try/except cannot see): a lazy-jit entry
+    whose lowering latched int8 kernels — the block or run_steps' scan
+    of it — is re-lowered ONCE without them, counted, and a second fault
+    re-raises; an entry that latched none re-raises the fault untouched."""
+    from paddle_tpu.observability import stats as obs
+
+    d = str(tmp_path / "dispatch")
+    _save_fc_mlp(d)
+    cfg = AnalysisConfig(d)
+    if int8:
+        cfg.enable_int8()
+    pred = create_predictor(cfg)
+    exe, prog = pred._exe, pred.program()
+    xv = rng.randn(8, 8).astype("float32")
+
+    def call():
+        if scanned:
+            return exe.run_steps(prog, feed={"x": xv[None]},
+                                 fetch_list=pred._fetch_names,
+                                 scope=pred._scope)[0][0]
+        return pred.run({"x": xv})[0]
+
+    def disables():
+        return obs.to_dict().get("quant.runtime_disables", 0)
+
+    want = call()
+    (entry,) = exe._cache.values()
+    build_fn = exe._make_scan_builder(prog, entry.plan) if scanned else None
+    before = disables()
+    assert entry.fused_used == {"int8_fused": int8}
+    if not int8:
+        with pytest.raises(RuntimeError, match="injected"):
+            exe._recover_disk_entry(entry, prog,
+                                    RuntimeError("injected fault"), [])
+        assert not entry.fused_disabled and disables() == before
+        return
+    jitted = exe._recover_disk_entry(entry, prog,
+                                     RuntimeError("mosaic fault"), [],
+                                     build_fn=build_fn)
+    assert entry.jitted is jitted and entry.fused_disabled
+    assert disables() == before + 1
+    # the stamped ops' own f32 lowering now carries the call
+    assert 0 < np.max(np.abs(call() - want)) < 0.05
+    assert entry.fused_used == {"int8_fused": False}
+    with pytest.raises(RuntimeError, match="again"):
+        exe._recover_fused_fault(entry, prog, RuntimeError("faults again"),
+                                 [], build_fn=build_fn)
+    assert disables() == before + 1
+
+
 def test_int8_inference_flag_is_the_fleet_default(tmp_path):
     """FLAGS_int8_inference quantizes every predictor as if each config
     called enable_int8(); off (default) no config is touched."""

@@ -1,4 +1,4 @@
-"""Weak-scaling efficiency guard (BASELINE "8→64 chip scaling eff").
+"""Weak-scaling efficiency guard (the "8→64 chip scaling eff" target).
 
 Per-device compiled cost of the SPMD Transformer step must stay ~constant
 as the dp mesh grows at fixed per-device batch — an accidentally

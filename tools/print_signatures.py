@@ -67,7 +67,6 @@ MODULES = [
     # and the /allocz payload drift loudly
     "paddle_tpu.observability.memory",
     "golden",          # tools/golden.py (tools/ on sys.path here)
-    "bench_compare",   # tools/bench_compare.py (tools/ on sys.path here)
     "runlog_report",   # tools/runlog_report.py
     # pipeline parallelism plane (stage transpiler, schedules, drivers,
     # permute transport, RPC stage workers): frozen so the stage-program
@@ -138,10 +137,6 @@ MODULES = [
     # admin-tooling drift is loud
     "paddle_tpu.core.compile_cache",
     "cache_admin",  # tools/cache_admin.py (tools/ is on sys.path here)
-    # the fused sparse-embedding kernel surface (FLAGS_sparse_fused_kernel
-    # gather/update entry points + the lowering peephole planner): frozen
-    # so the optimizer-wiring contract drifts loudly
-    "paddle_tpu.kernels.sparse",
     # the low-precision serving surface (fused-dequant int8 matmul,
     # calibration plan, KV qdq helpers, /quantz payload): frozen so the
     # scale semantics and fallback contract drift loudly
